@@ -32,7 +32,7 @@ if os.environ.get("TDP_CPU_SIM"):
 
 import jax
 
-from torchdistpackage_tpu.compat import axis_size
+from jax.lax import axis_size
 
 import jax.numpy as jnp
 import optax

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from torchdistpackage_tpu import setup_distributed, tpc

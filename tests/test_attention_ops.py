@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchdistpackage_tpu.dist import tpc
@@ -123,7 +123,7 @@ def test_context_parallel_gqa_matches_serial(devices8, impl):
         return ulysses_attention(q, k, v, axis="context", causal=True)
 
     from jax.sharding import PartitionSpec as P
-    from torchdistpackage_tpu.compat import shard_map
+    from jax import shard_map
 
     sm = shard_map(
         f, mesh=mesh,

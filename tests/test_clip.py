@@ -7,19 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchdistpackage_tpu.compat import HAS_VMA
-
-# These golden/parity compositions depend on varying-manual-axes shard_map
-# semantics (jax.shard_map, jax >= 0.6-era).  The legacy
-# jax.experimental.shard_map fallback (compat.py) runs check_rep=False,
-# which reassociates the grad reductions — numerically fine for training,
-# but the tight-tolerance serial-parity goldens here cannot hold.
-requires_vma = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="needs varying-manual-axes shard_map (jax>=0.6); legacy "
-    "fallback reassociates reductions — parity goldens cannot hold",
-)
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchdistpackage_tpu.dist import tpc
@@ -30,7 +18,6 @@ from torchdistpackage_tpu.parallel.clip import (
 )
 
 
-@requires_vma
 def test_global_norm_mixed_shardings(devices8):
     tpc.setup_process_groups([("data", 2), ("pipe", 2), ("tensor", 2)], devices=devices8)
     mesh = tpc.get_view()

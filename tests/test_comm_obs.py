@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from torchdistpackage_tpu.dist import tpc
 from torchdistpackage_tpu.dist.comm_bench import bench_collective
 from torchdistpackage_tpu.obs import (

@@ -11,11 +11,6 @@ Budget discipline (PR-6 convention): module-scope A/B fixtures run ONE
 training pair per arm family; the parity-matrix arms fold fwd+grad into
 single ``value_and_grad(has_aux=True)`` programs; everything else is a
 sub-second toy.
-
-No ``requires_vma`` marks here on purpose: quantization noise dominates
-legacy shard_map's reassociation noise by orders of magnitude, so the
-loose-tolerance goldens hold on both paths (the tight serial goldens that
-can't are in test_zero/test_tensor_parallel, already marked).
 """
 
 import dataclasses
@@ -28,7 +23,7 @@ import optax
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from torchdistpackage_tpu.dist import tpc
 from torchdistpackage_tpu.dist.compressed import (
     GROUP,

@@ -77,7 +77,7 @@ def test_grad_reduce_overrides_moe_dp_semantics(devices8):
     moe_dp.md).  Here that is a per-param axis override: expert grads reduce
     over moe_dp only; shared grads over the full data group."""
     import jax.numpy as jnp
-    from torchdistpackage_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from torchdistpackage_tpu.parallel.data_parallel import (
@@ -152,7 +152,7 @@ def test_int8_ring_pmean_bounded_error(devices8):
     """The quantized ring mean equals the exact pmean within the symmetric
     int8 bound, and every rank holds bit-identical results (a rank keeping
     its own chunk exact would make replicated params drift)."""
-    from torchdistpackage_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from torchdistpackage_tpu.dist.compressed import int8_ring_pmean
@@ -304,7 +304,7 @@ def test_int8_ring_singleton_axis_is_invariance_typed(devices8):
     """A 1-member data axis must still yield an invariance-typed result —
     the bare-return regression failed check_vma at the sharded out_specs
     (caught by review; the grad path is DataParallel(mesh=('data',1) x tp))."""
-    from torchdistpackage_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from torchdistpackage_tpu.dist.compressed import int8_ring_pmean

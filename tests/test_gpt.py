@@ -11,19 +11,7 @@ import numpy as np
 import optax
 import pytest
 
-from torchdistpackage_tpu.compat import HAS_VMA
-
-# These golden/parity compositions depend on varying-manual-axes shard_map
-# semantics (jax.shard_map, jax >= 0.6-era).  The legacy
-# jax.experimental.shard_map fallback (compat.py) runs check_rep=False,
-# which reassociates the grad reductions — numerically fine for training,
-# but the tight-tolerance serial-parity goldens here cannot hold.
-requires_vma = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="needs varying-manual-axes shard_map (jax>=0.6); legacy "
-    "fallback reassociates reductions — parity goldens cannot hold",
-)
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchdistpackage_tpu.dist import tpc
@@ -117,7 +105,6 @@ def test_tp_matches_serial(devices8, params, sp):
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_tp_sp_pp_dp_training_matches_serial(devices8, params):
     """The full composition: DP=2 x PP=2 x TP=2 (+SP), pipelined GPT loss in a
     DataParallel train step, vs the serial model on the full batch."""
@@ -216,7 +203,6 @@ def _ppermute_bytes(fn, *args):
 
 @pytest.mark.parametrize("num_chunks", [1, 2])
 @pytest.mark.heavy
-@requires_vma
 def test_gpt_1f1b_tp_nosp_sharded_transfers_match_serial(
         devices8, params, num_chunks):
     """The scatter_gather_tensors analogue (reference comm.py:108-155): under
@@ -292,7 +278,6 @@ def test_gpt_1f1b_tp_nosp_sharded_transfers_match_serial(
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_gpt_1f1b_remat_flash_matches_serial(devices8):
     """The remat='flash' policy (save the Pallas kernel's o/lse, skip its
     fwd re-run in backward) under the pipelined stack — scan over the block
@@ -392,7 +377,6 @@ def test_gpt_ring_cp_remat_flash_matches_serial(devices8, params):
         "remat='flash' saved nothing beyond plain remat under ring CP")
 
 
-@requires_vma
 def test_gpt_1f1b_training_matches_serial(devices8, params):
     """Full-composition 1F1B: DP=2 x PP=2 x TP=2 (+SP) with the interleaved
     schedule supplying (loss, grads) directly to the DataParallel step; two
@@ -472,7 +456,6 @@ def test_gpt_1f1b_training_matches_serial(devices8, params):
 @pytest.mark.parametrize("impl,xent_chunk", [
     ("ring", None), ("ulysses", None), ("ring", 2),
 ])
-@requires_vma
 def test_gpt_context_parallel_matches_serial(devices8, params, impl, xent_chunk):
     """Context parallelism wired into the MODEL family (VERDICT r2 item 4):
     a GPT with ``attn_impl='ring'|'ulysses'`` + ``context_axis`` runs with
@@ -569,7 +552,6 @@ def test_gpt_ring_training_matches_serial(devices8, params):
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_gpt_1f1b_with_ring_cp_matches_serial(devices8, params):
     """DP x PP x CP: the 1F1B pipeline with ring-attention stages — sequence
     sharded over 'context' THROUGH the pipeline (stage 0 embeds local chunks
@@ -874,7 +856,6 @@ def test_gpt_remat_grads_match():
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_gpt_remat_flash_policy_matches_and_saves_residuals():
     """remat='flash' (save the flash kernel's o/lse, skip its fwd re-run in
     the backward) must be numerically identical to remat=True, and the
@@ -1181,7 +1162,6 @@ def test_gpt_zigzag_ring_matches_serial(devices8, params):
     )
 
 
-@requires_vma
 def test_gpt_interleaved_1f1b_matches_serial(devices8, params):
     """INTERLEAVED 1F1B (virtual pipeline stages, num_chunks=2): chunk v of
     stage s holds layer slab v*P+s, transfers ride CIRCULAR ppermutes (the

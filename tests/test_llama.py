@@ -10,19 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchdistpackage_tpu.compat import HAS_VMA
-
-# These golden/parity compositions depend on varying-manual-axes shard_map
-# semantics (jax.shard_map, jax >= 0.6-era).  The legacy
-# jax.experimental.shard_map fallback (compat.py) runs check_rep=False,
-# which reassociates the grad reductions — numerically fine for training,
-# but the tight-tolerance serial-parity goldens here cannot hold.
-requires_vma = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="needs varying-manual-axes shard_map (jax>=0.6); legacy "
-    "fallback reassociates reductions — parity goldens cannot hold",
-)
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchdistpackage_tpu.dist import tpc
@@ -144,7 +132,6 @@ def test_llama_tp_matches_serial(devices8, sp):
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_llama_pipeline_1f1b_matches_serial(devices8):
     """PP=2 x TP=2 1F1B (sharded transfers auto-on for non-SP TP) on the
     Llama block stack vs the serial microbatched loss."""
@@ -252,7 +239,6 @@ def test_mixtral_style_moe_ep_matches_serial(devices8):
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_llama_zero_interleaved_hybrid_matches_serial(devices8):
     """The north-star composition on the Llama family: hybrid ZeRO
     (data_intra master shards) x INTERLEAVED 1F1B (V=2) x DP at tiny

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchdistpackage_tpu.dist import tpc
@@ -234,10 +234,7 @@ def test_fused_ep_matches_sorted(devices8):
     """Under EP only the expert-FFN leg fuses (the all_to_all exchange
     needs the [E, C, D] grouped layout — it IS the wire payload):
     dispatch='pallas' through a moe_dp=2 x moe_ep=2 shard_map must match
-    'sorted' forward and grads.  Unlike the serial-parity goldens this
-    A/B needs no VMA gate: both arms run the SAME shard_map machinery,
-    so the legacy fallback's reassociated reductions cancel out.
-    Fast-tier EP holder for the slow-tier
+    'sorted' forward and grads.  Fast-tier EP holder for the slow-tier
     test_sorted_dispatch_under_ep_matches_serial."""
     tpc.setup_process_groups([("data", 4)], devices=devices8[:4])
     tpc.build_moe_mesh(moe_ep_size=2)

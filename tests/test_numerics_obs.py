@@ -20,7 +20,7 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from torchdistpackage_tpu.obs import (
     DEFAULT_THRESHOLDS,
     JsonlSink,

@@ -18,7 +18,7 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from torchdistpackage_tpu.dist import overlap, tpc
 from torchdistpackage_tpu.obs.comm_ledger import (
     ledger_from_compiled,

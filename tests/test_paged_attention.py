@@ -258,9 +258,7 @@ def test_compiled_decode_drops_gathered_temp(bundle):
 
     texts = {}
     for impl in ("pallas", "gather"):
-        comps = [e["compiled"]
-                 for e in bundle["tel"][impl]._compiled.values()
-                 if e["compiled"] is not None]
+        comps = bundle["tel"][impl].compiled_programs()
         assert comps, f"{impl}: Telemetry captured no compiled signature"
         # the hook's static ledger parses the same executable
         assert static_ledger(comps[0]) is not None

@@ -195,11 +195,7 @@ def test_tp_dp_paged_parity(bundles, family, devices8):
     """The same goldens on a tensor=2 x data=2 mesh: KV heads + vocab
     shard over 'tensor' exactly as training, slots + block pool split over
     'data' — four requests, two per data group, all bit-equal to the
-    serial ``generate()``.
-
-    No ``requires_vma`` gate: decode is forward-only (no grad reductions
-    for legacy check_rep=False shard_map to reassociate), so the bit
-    golden holds on the jax 0.4.x fallback too."""
+    serial ``generate()``."""
     b = bundles(family)
     cfg = b["cfg"]
     tpc.setup_process_groups(

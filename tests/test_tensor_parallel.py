@@ -12,19 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchdistpackage_tpu.compat import HAS_VMA
-
-# These golden/parity compositions depend on varying-manual-axes shard_map
-# semantics (jax.shard_map, jax >= 0.6-era).  The legacy
-# jax.experimental.shard_map fallback (compat.py) runs check_rep=False,
-# which reassociates the grad reductions — numerically fine for training,
-# but the tight-tolerance serial-parity goldens here cannot hold.
-requires_vma = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="needs varying-manual-axes shard_map (jax>=0.6); legacy "
-    "fallback reassociates reductions — parity goldens cannot hold",
-)
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchdistpackage_tpu.dist import tpc
@@ -114,7 +102,6 @@ def test_tp_transformer_matches_serial(devices8, serial_golden, sp):
         )
 
 
-@requires_vma
 def test_tp_dp_composition(devices8):
     """TP=2 x DP=4 train step: grads pmean over data, TP collectives inside —
     params must follow the serial trajectory."""

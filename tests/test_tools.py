@@ -194,21 +194,6 @@ def test_check_tensors_emit_lands_on_timeline():
 # -------------------------------------------------------- bench trend gate
 
 
-def test_bench_trend_gates_checked_in_rounds(capsys):
-    """Tier-1 gate over the repo's own BENCH_r0*.json artifacts: the
-    checked-in trajectory must hold no >5% regression (a round that loses
-    throughput now FAILS the suite instead of riding through unchallenged
-    — the promotion ISSUE 7 asked for)."""
-    from torchdistpackage_tpu.tools.bench_trend import main
-
-    repo = pathlib.Path(__file__).resolve().parent.parent
-    assert list(repo.glob("BENCH_r0*.json")), "no bench rounds checked in"
-    rc = main(["--dir", str(repo)])
-    captured = capsys.readouterr()
-    assert rc == 0, f"bench trend regression:\n{captured.err}"
-    assert "train-throughput" in captured.out
-
-
 def test_bench_trend_regression_detection_and_numerics_columns(tmp_path):
     """The gate actually bites (a forged losing round exits nonzero) and
     the PR-7 ``grad_norm_final`` numerics column renders next to the

@@ -61,7 +61,7 @@ def test_partition_params_balanced_and_stable():
 
 
 def test_axis_unique_key(devices8):
-    from torchdistpackage_tpu.compat import shard_map
+    from jax import shard_map
 
     tpc.setup_process_groups([("data", 4), ("tensor", 2)], devices=devices8[:8])
     mesh = tpc.get_view()

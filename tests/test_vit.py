@@ -6,20 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchdistpackage_tpu.compat import HAS_VMA
-
-# These golden/parity compositions depend on varying-manual-axes shard_map
-# semantics (jax.shard_map, jax >= 0.6-era).  The legacy
-# jax.experimental.shard_map fallback (compat.py) runs check_rep=False,
-# which reassociates the grad reductions — numerically fine for training,
-# but the tight-tolerance serial-parity goldens here cannot hold.
-requires_vma = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="needs varying-manual-axes shard_map (jax>=0.6); legacy "
-    "fallback reassociates reductions — parity goldens cannot hold",
-)
 import optax
-from torchdistpackage_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from torchdistpackage_tpu.dist import tpc
@@ -175,7 +163,6 @@ def test_vit_ring_cp_matches_serial(devices8):
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_vit_1f1b_training_matches_serial(devices8):
     """ViT under the 1F1B pipeline x DP x TP(+SP): the reference's PP
     capability is demonstrated on a VISION classifier
@@ -254,7 +241,6 @@ def test_vit_1f1b_training_matches_serial(devices8):
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_vit_1f1b_with_cp_matches_serial(devices8):
     """ViT x CP x PP (VERDICT r3 weak #7).  Unlike GPT-CP (loss is a mean
     over context-LOCAL tokens -> context behaves as a data axis), the ViT

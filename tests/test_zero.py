@@ -9,18 +9,6 @@ import numpy as np
 import optax
 import pytest
 
-from torchdistpackage_tpu.compat import HAS_VMA
-
-# These golden/parity compositions depend on varying-manual-axes shard_map
-# semantics (jax.shard_map, jax >= 0.6-era).  The legacy
-# jax.experimental.shard_map fallback (compat.py) runs check_rep=False,
-# which reassociates the grad reductions — numerically fine for training,
-# but the tight-tolerance serial-parity goldens here cannot hold.
-requires_vma = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="needs varying-manual-axes shard_map (jax>=0.6); legacy "
-    "fallback reassociates reductions — parity goldens cannot hold",
-)
 from jax.sharding import PartitionSpec as P
 
 from torchdistpackage_tpu.dist import tpc
@@ -161,7 +149,6 @@ def test_hybrid_zero(devices8):
 
 @pytest.mark.parametrize("num_chunks", [1, 2])
 @pytest.mark.heavy
-@requires_vma
 def test_zero_1f1b_hybrid(devices8, num_chunks):
     """North-star composition (VERDICT r2 item 3): hybrid ZeRO x 1F1B
     pipeline x DP.  Mesh data=4 (hybrid intra=2) x pipe=2; the 1F1B schedule
@@ -264,7 +251,6 @@ def test_zero_1f1b_hybrid(devices8, num_chunks):
     )
 
 
-@requires_vma
 def test_zero_with_tp(devices8):
     """ZeRO over data axis composed with TP=2 sharded transformer params."""
     import functools
@@ -508,7 +494,6 @@ def test_zero_override_must_contain_shard_axis():
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_zero_moe_1f1b_full_stack(devices8):
     """The full expert-model stack: ZeRO(moe_dp) x EP x MoE-DP x PP(1F1B),
     aux ON — sharded optimizer state, expert-override grad reduction, and
@@ -620,7 +605,6 @@ def test_zero_moe_1f1b_full_stack(devices8):
 
 
 @pytest.mark.heavy
-@requires_vma
 def test_zero_1f1b_tp_nosp_sharded_transfers(devices8):
     """ZeRO x non-SP TP x PP over the TP-SHARDED inter-stage transfers:
     the sharded optimizer consumes the pipeline's (loss, grads) while the
@@ -710,7 +694,7 @@ def test_int8_ring_reduce_scatter_matches_psum_scatter(devices8):
     exact psum_scatter (within the symmetric-quantization bound), for a
     leading and a non-leading scatter dim, and falls back exactly on
     ragged tiles."""
-    from torchdistpackage_tpu.compat import shard_map
+    from jax import shard_map
 
     from torchdistpackage_tpu.dist.compressed import int8_ring_reduce_scatter
 
@@ -799,7 +783,7 @@ def test_zero_int8_wire_format_in_jaxpr(devices8):
     jaxpr must contain s8 ppermutes with grad_compress='int8' and none
     without (the non-compressed path may still ppermute activations in
     other tests' pipelines — here the MLP has no other ring traffic)."""
-    from torchdistpackage_tpu.compat import shard_map
+    from jax import shard_map
 
     tpc.setup_process_groups([("data", 8)], devices=devices8)
     mesh = tpc.get_view()

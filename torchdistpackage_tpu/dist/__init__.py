@@ -6,6 +6,7 @@ from .topology import (
     PIPE_AXIS,
     TENSOR_AXIS,
     ParallelContext,
+    check_placement,
     is_using_pp,
     test_comm,
     tpc,

@@ -762,7 +762,8 @@ def plan(
 
     - ``comm_model``: a calibrated :class:`CommModel` grounds the comm
       terms (and the int8 arms) in measurement; default = the
-      per-generation table model for ``device_kind``.
+      per-generation table model for ``device_kind`` (no kind named =
+      the table's ``cpu`` placeholder row; an unlisted kind raises).
     - ``effective_flops``: sustained per-device FLOP/s.  Feed the value a
       measured step implies (``bench.py --autoplan`` does: HLO FLOPs /
       measured step time) to close the loop; default = 40% of the chip's
@@ -787,7 +788,7 @@ def plan(
     if memory == "auto":
         use_model = not isinstance(config, dict) and _jax_importable()
     model = comm_model or CommModel.from_defaults(
-        device_kind=device_kind or "unknown")
+        device_kind=device_kind or "cpu")
     fpt_val = float(fpt) if fpt else flops_per_token(d)
     eff, compute_basis = _resolve_effective_flops(
         effective_flops, device_kind)
@@ -912,7 +913,7 @@ def plan_prefill_tier(
     kv_heads = d.kv_heads or d.nheads
     head_dim = d.dim // d.nheads
     model = comm_model or CommModel.from_defaults(
-        device_kind=device_kind or "unknown")
+        device_kind=device_kind or "cpu")
     eff, compute_basis = _resolve_effective_flops(
         effective_flops, device_kind)
     # forward-only prefill: the 6N+12LSD accounting is fwd+bwd, and the

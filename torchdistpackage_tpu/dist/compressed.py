@@ -57,7 +57,7 @@ from typing import Any, Dict, List, Sequence, Tuple
 
 import jax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
 
 
@@ -72,13 +72,9 @@ def _mark_varying(x, axis: str):
     """Mark ``x`` varying over ``axis`` if it isn't already (idempotent —
     same contract as parallel.data_parallel._mark_varying, duplicated here
     to keep dist/ import-independent of parallel/)."""
-    from ..compat import pvary, typeof
-
-    if axis in getattr(typeof(x), "vma", frozenset()):
+    if axis in jax.typeof(x).vma:
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis,), to="varying")
-    return pvary(x, (axis,))
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def _group_size(n: int) -> int:

@@ -60,7 +60,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -522,6 +522,19 @@ def is_using_pp() -> bool:
     return tpc.is_using_pp()
 
 
+def check_placement(tree, mesh: Mesh) -> None:
+    """Raise unless every array leaf of ``tree`` lives on exactly the
+    devices of ``mesh`` — an array left on the default device would still
+    compute, on one chip, while the mesh's other chips sat idle."""
+    want = set(mesh.devices.flat)
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        have = leaf.sharding.device_set
+        if have != want:
+            raise AssertionError(
+                f"{jax.tree_util.keystr(path)} is on {sorted(d.id for d in have)}, "
+                f"the mesh is {sorted(d.id for d in want)}")
+
+
 def test_comm(mesh: Optional[Mesh] = None) -> Dict[str, bool]:
     """Smoke-test collectives over every mesh axis — analogue of
     ``test_comm`` (process_topo.py:267-316).
@@ -537,7 +550,7 @@ def test_comm(mesh: Optional[Mesh] = None) -> Dict[str, bool]:
     touch non-addressable shards; a replicated scalar is always local —
     executed cross-process in ``tests/test_multiprocess.py``).
     """
-    from ..compat import shard_map
+    from jax import shard_map
     import jax.numpy as jnp
 
     if mesh is None:

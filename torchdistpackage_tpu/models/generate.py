@@ -37,7 +37,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
 
 from ..parallel.tensor_parallel.layers import (

@@ -54,6 +54,9 @@ GENERATION_DEFAULTS: List[Tuple[str, Dict[str, float]]] = [
     ("v4", {"ici_bw_GBps": 300.0, "ici_lat_us": 1.0}),
     ("v3", {"ici_bw_GBps": 140.0, "ici_lat_us": 1.5}),
     ("v2", {"ici_bw_GBps": 62.5, "ici_lat_us": 2.0}),
+    # the CPU sim has no fabric: a placeholder so table-based planning runs
+    # in tests; calibrate() gives the sim's real numbers
+    ("cpu", {"ici_bw_GBps": 100.0, "ici_lat_us": 1.0}),
 ]
 DCN_DEFAULTS = {"dcn_bw_GBps": 25.0, "dcn_lat_us": 10.0}
 
@@ -267,19 +270,20 @@ class CommModel:
         parameters except ``dcn_axes`` (multi-slice axes), which get DCN
         defaults.  ``device_kind`` defaults to the first jax device."""
         if device_kind is None:
-            try:
-                import jax
+            import jax
 
-                device_kind = jax.devices()[0].device_kind
-            except Exception:
-                device_kind = "unknown"
+            device_kind = jax.devices()[0].device_kind
         dk = device_kind.lower()
         gen = next(
             (params for sub, params in GENERATION_DEFAULTS if sub in dk), None
         )
+        if gen is None:
+            raise ValueError(
+                f"no comm-table row for device_kind={device_kind!r}; add one "
+                "to GENERATION_DEFAULTS or calibrate()")
         ici = AxisCost(
-            alpha_s=(gen["ici_lat_us"] if gen else 1.0) * 1e-6,
-            beta_Bps=(gen["ici_bw_GBps"] if gen else 100.0) * 1e9,
+            alpha_s=gen["ici_lat_us"] * 1e-6,
+            beta_Bps=gen["ici_bw_GBps"] * 1e9,
             kind="table",
         )
         dcn = AxisCost(

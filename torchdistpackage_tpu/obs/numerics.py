@@ -37,10 +37,6 @@ global-norm implementation — ``parallel/clip.py`` delegates to it, so a
 step that both clips and monitors computes the grouped squared-sum
 reduction once (XLA CSEs the identical subgraphs) and the clipped-step
 trajectory is bitwise-unchanged vs pre-fold HEAD (parity-tested).
-
-Known limitation: on legacy jax (no vma tracking) the per-leaf psum axes
-come back empty, so norms of TP-sharded leaves are per-shard only — the
-same ``requires_vma`` caveat the tight-tolerance parity goldens carry.
 """
 
 from __future__ import annotations
@@ -74,8 +70,8 @@ F16_MAX = 65504.0
 
 def _vma_axes(x) -> Tuple[str, ...]:
     """Mesh axes a traced value varies over (sorted; empty outside
-    shard_map or on legacy jax without vma tracking)."""
-    from ..compat import typeof
+    shard_map)."""
+    from jax import typeof
 
     return tuple(sorted(getattr(typeof(x), "vma", frozenset())))
 

@@ -67,6 +67,8 @@ from .events import EventLog, set_default_event_log
 
 # Peak dense bf16 FLOP/s per chip by device_kind substring (public specs).
 # The one lookup table for the whole repo — bench.py imports it from here.
+# The CPU has no peak worth a utilization (None); a kind with no row is an
+# error, not a silently dropped MFU.
 PEAK_BF16_FLOPS = [
     ("v6", 918e12),  # Trillium
     ("v5p", 459e12),
@@ -75,6 +77,7 @@ PEAK_BF16_FLOPS = [
     ("v4", 275e12),
     ("v3", 123e12),
     ("v2", 46e12),
+    ("cpu", None),
 ]
 
 
@@ -83,7 +86,9 @@ def peak_flops_for(device_kind: str) -> Optional[float]:
     for sub, peak in PEAK_BF16_FLOPS:
         if sub in dk:
             return peak
-    return None
+    raise ValueError(
+        f"no peak-FLOP/s row for device_kind={device_kind!r}; add one to "
+        "obs.telemetry.PEAK_BF16_FLOPS")
 
 
 def compiled_cost(compiled) -> Dict[str, float]:
@@ -266,21 +271,14 @@ class Telemetry:
         self.history: List[Dict[str, Any]] = []
         self._history_max = history_max
 
-        try:
-            self._backend = jax.default_backend()
-            dev = jax.devices()[0]
-            self._chip = dev.device_kind
-            self._n_devices = jax.device_count()
-            self._n_processes = jax.process_count()
-            self._is_master = jax.process_index() == 0
-        except Exception:
-            self._backend, self._chip = "unknown", "unknown"
-            self._n_devices = self._n_processes = 1
-            self._is_master = True
+        self._backend = jax.default_backend()
+        self._chip = jax.devices()[0].device_kind
+        self._n_devices = jax.device_count()
+        self._n_processes = jax.process_count()
+        self._is_master = jax.process_index() == 0
         self.peak_flops = (
             peak_flops if peak_flops is not None
-            else (peak_flops_for(self._chip) if self._backend != "cpu" else None)
-        )
+            else peak_flops_for(self._chip))
 
         self._compiled: Dict[Tuple, Dict[str, Any]] = {}
         self._wrap_n = 0  # wrap_step counter: scopes the AOT cache per fn
@@ -433,6 +431,13 @@ class Telemetry:
             n_signatures=len(self._compiled),
         )
         return entry
+
+    def compiled_programs(self) -> List[Any]:
+        """The executables :meth:`wrap_step` AOT-compiled, one per
+        signature — what the ledgers parsed, for callers that read the
+        program text themselves."""
+        return [e["compiled"] for e in self._compiled.values()
+                if e["compiled"] is not None]
 
     # ------------------------------------------------------------ recording
 

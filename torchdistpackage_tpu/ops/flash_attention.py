@@ -40,51 +40,30 @@ NEG_INF = -1e30  # finite "minus infinity": avoids (-inf) - (-inf) NaNs
 
 _LANES = 128  # m/l scratch keeps a full lane dim for layout friendliness
 
-# Tuned (block_q, block_k) by device_kind substring, measured by the
-# autotuner (tools/flash_tune.py — run it on a new chip generation and add
-# a row; current data: docs/FLASH_TUNE_v5e.json).  _FALLBACK_TILES covers
-# unmeasured chips and the CPU interpreter, and stays conservative on
-# purpose: (1024, 1024) was measured fastest on v5e ONLY — an unmeasured
-# generation gets the safe small tiles (no VMEM-pressure surprises), and
-# earns larger ones the day flash_tune.py runs on it.
-_TUNED_TILES = (
+# (block_q, block_k) by device_kind substring.  The v5e rows were measured
+# by the autotuner (tools/flash_tune.py, docs/FLASH_TUNE_v5e.json); the cpu
+# row is the Pallas interpreter's.  A chip with no row is an error: run
+# tools/flash_tune.py on it and add one.
+_TILES = (
     ("v5 lite", (1024, 1024)),
     ("v5e", (1024, 1024)),
+    ("cpu", (256, 512)),
 )
-_FALLBACK_TILES = (256, 512)
 
 
-@functools.lru_cache(maxsize=None)
-def _tiles_for(device_kind: str) -> Tuple[int, int]:
+def tiles_for(device_kind: str) -> Tuple[int, int]:
     dk = device_kind.lower()
-    for sub, tiles in _TUNED_TILES:
+    for sub, tiles in _TILES:
         if sub in dk:
             return tiles
-    if jax.default_backend() != "cpu":
-        # once per kind (lru_cache): a mis-tiled accelerator run must be
-        # visible, or fallback-served chips silently bench below potential
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "flash_attention: no autotuned tile row for device_kind=%r; "
-            "serving conservative fallback %s — run tools/flash_tune.py on "
-            "this chip and add a _TUNED_TILES row", device_kind,
-            _FALLBACK_TILES)
-    return _FALLBACK_TILES
+    raise ValueError(
+        f"flash_attention: no tile row for device_kind={device_kind!r}; run "
+        "tools/flash_tune.py on this chip and add a _TILES row")
 
 
 def default_tiles() -> Tuple[int, int]:
-    """(block_q, block_k) for the attached chip — autotuned when measured,
-    :data:`_FALLBACK_TILES` otherwise.  The device kind is re-read on every
-    call (only the per-kind lookup is cached): a process can switch
-    backends mid-run (bench.py's CPU fallback does exactly that), so a
-    transient failure or an interpreter-mode first trace must not pin the
-    wrong tiles."""
-    try:
-        dk = jax.devices()[0].device_kind
-    except Exception:
-        return _FALLBACK_TILES
-    return _tiles_for(dk)
+    """(block_q, block_k) for the attached chip, from :data:`_TILES`."""
+    return tiles_for(jax.devices()[0].device_kind)
 
 
 def _interpret() -> bool:
@@ -136,7 +115,7 @@ def mha_reference(
 def _out_struct(shape, dtype, like):
     """ShapeDtypeStruct carrying the vma of ``like`` — required for
     pallas_call under shard_map (check_vma=True)."""
-    from ..compat import typeof
+    from jax import typeof
 
     vma = getattr(typeof(like), "vma", None)
     if vma:

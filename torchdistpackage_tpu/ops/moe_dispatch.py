@@ -44,9 +44,13 @@ oracle and as the backward: :func:`fused_moe_ffn` is a ``jax.custom_vjp``
 whose bwd differentiates the oracle (same math, so grads are exact to the
 oracle's own tolerance; the int routing args get float0 cotangents).
 
-On CPU the kernel runs in Pallas interpreter mode automatically (the
-``_interpret`` switch shared with ops/flash_attention.py), so every test
-exercises the code path the TPU compiles.
+The kernel runs in the Pallas interpreter only (on CPU, automatically: the
+``_interpret`` switch shared with ops/flash_attention.py).  It does not
+lower for TPU as written — the ``comb`` BlockSpec ``(1, c_tile)`` over
+``[E, Cp]`` breaks the (8, 128) rule, it reads and writes rows of
+``ANY``-space refs directly where Mosaic wants a DMA, and it asks for a
+whole expert's ``w1``/``w2`` as one VMEM block — so
+:func:`resolve_moe_dispatch` never picks it and it has no chip number.
 """
 
 from __future__ import annotations
@@ -222,11 +226,8 @@ def _kernel(idx_ref, comb_ref, x_ref, *refs, Cp, c_tile, Tp, D, swiglu,
     @pl.when((e == 0) & (c == 0))
     def _zero_out():
         def body(i, _):
-            pl.store(
-                o_ref,
-                (pl.ds(i * _ZERO_TILE, _ZERO_TILE), slice(None)),
-                jnp.zeros((_ZERO_TILE, D), jnp.float32),
-            )
+            o_ref[pl.ds(i * _ZERO_TILE, _ZERO_TILE), :] = jnp.zeros(
+                (_ZERO_TILE, D), jnp.float32)
             return 0
 
         jax.lax.fori_loop(0, Tp // _ZERO_TILE, body, 0)
@@ -242,9 +243,9 @@ def _kernel(idx_ref, comb_ref, x_ref, *refs, Cp, c_tile, Tp, D, swiglu,
 
         def gather(i, _):
             t = idx_ref[base + i]
-            row = pl.load(x_ref, (pl.ds(t, 1), slice(None)))
+            row = x_ref[pl.ds(t, 1), :]
             row = jnp.where(comb[i] != 0.0, row.astype(jnp.float32), 0.0)
-            pl.store(xs_ref, (pl.ds(i, 1), slice(None)), row)
+            xs_ref[pl.ds(i, 1), :] = row
             return 0
 
         jax.lax.fori_loop(0, c_tile, gather, 0)
@@ -268,9 +269,8 @@ def _kernel(idx_ref, comb_ref, x_ref, *refs, Cp, c_tile, Tp, D, swiglu,
 
             @pl.when(comb[i] != 0.0)
             def _add():
-                cur = pl.load(o_ref, (pl.ds(t, 1), slice(None)))
                 upd = comb[i] * jax.lax.dynamic_slice_in_dim(out, i, 1, 0)
-                pl.store(o_ref, (pl.ds(t, 1), slice(None)), cur + upd)
+                o_ref[pl.ds(t, 1), :] = o_ref[pl.ds(t, 1), :] + upd
 
             return 0
 
@@ -313,7 +313,7 @@ def _pallas_moe_ffn(
     operands = []
     in_specs = [
         pl.BlockSpec((1, c_tile), lambda e, c, i: (e, c)),  # comb
-        pl.BlockSpec(memory_space=pltpu.ANY),               # tokens
+        pl.BlockSpec(memory_space=pl.ANY),               # tokens
     ]
     operands.extend([comb, x])
 
@@ -339,7 +339,7 @@ def _pallas_moe_ffn(
         num_scalar_prefetch=1,
         grid=(E, Cp // c_tile),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.VMEM((c_tile, D), jnp.float32)],
     )
     kernel = functools.partial(
@@ -529,14 +529,16 @@ def fused_expert_ffn(experts: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
 
 
 def resolve_moe_dispatch(dispatch: Optional[str]) -> str:
-    """``'auto'``/None -> ``'pallas'`` on TPU, ``'auto'`` (the existing
-    size-based dense/sorted selection) elsewhere — the interpreter-mode
-    kernel is correct on CPU but slow, so CPU tests opt in explicitly.
-    Explicit values pass through validated.  The choice is recorded on
-    the event timeline (``moe_dispatch_selected``) so an A/B that
-    silently fell back to the jnp paths is visible in the artifact."""
+    """``'auto'``/None -> ``'sorted'`` on TPU (the one dispatch with a chip
+    record: it beat ``'dense'`` there), ``'auto'`` (the size-based
+    dense/sorted selection) elsewhere.  ``'auto'`` never picks ``'pallas'``:
+    the fused kernel runs in the Pallas interpreter only — its BlockSpecs
+    and its row accesses to ``ANY``-space refs do not lower for TPU — so an
+    explicit ``'pallas'`` on a TPU raises at lowering.  Explicit values
+    pass through validated.  The choice is recorded on the event timeline
+    (``moe_dispatch_selected``)."""
     if dispatch in (None, "auto"):
-        chosen = "pallas" if jax.default_backend() == "tpu" else "auto"
+        chosen = "sorted" if jax.default_backend() == "tpu" else "auto"
         from ..obs.events import emit_event
 
         emit_event("moe_dispatch_selected", requested="auto", chosen=chosen,

@@ -49,9 +49,8 @@ Tuning: ``fetch_width`` (pool blocks streamed per grid step — each is an
 independent BlockSpec input, so Mosaic pipelines the DMAs) and
 ``q_pad_to`` (pad the in-kernel q rows to a tile-friendly multiple; the
 K+1 verify shape lands at awkward row counts like G*(K+1)) come from the
-per-chip autotuned table (tools/flash_tune.py ``--paged``,
-docs/PAGED_TUNE_v5e.json), with conservative fallbacks for unmeasured
-chips and the interpreter.
+per-chip table :data:`_PAGED_PARAMS` (tools/flash_tune.py ``--paged``
+measures candidates for a row).
 """
 
 from __future__ import annotations
@@ -71,46 +70,35 @@ NEG_INF = -1e30  # finite "minus infinity": avoids (-inf) - (-inf) NaNs
 
 _LANES = 128  # m/l scratch keeps a full lane dim for layout friendliness
 
-#: Per-chip tuned kernel parameters, measured by tools/flash_tune.py
-#: ``--paged`` (docs/PAGED_TUNE_v5e.json).  ``fetch_width`` = pool blocks
-#: streamed per grid step; ``q_pad_to`` = q-row padding multiple (the
-#: K+1 verify shape's G*(K+1) rows are rarely tile-aligned).
-_TUNED_PAGED = (
+#: Kernel parameters by device_kind substring.  ``fetch_width`` = pool
+#: blocks streamed per grid step; ``q_pad_to`` = q-row padding multiple (the
+#: K+1 verify shape's G*(K+1) rows are rarely tile-aligned).  The v5e row
+#: compiles and matches the gather oracle on the chip but was never TUNED
+#: there: tools/flash_tune.py ``--paged`` has not run on a v5e, so read it as
+#: "works", not "fastest".  The cpu row is the Pallas interpreter's.  A chip
+#: with no row is an error.
+_PAGED_PARAMS = (
     ("v5 lite", {"fetch_width": 4, "q_pad_to": 8}),
     ("v5e", {"fetch_width": 4, "q_pad_to": 8}),
+    ("cpu", {"fetch_width": 1, "q_pad_to": 8}),
 )
-#: Conservative fallback for unmeasured chips and the CPU interpreter:
-#: one block per step, minimal f32 sublane padding.
-_FALLBACK_PAGED = {"fetch_width": 1, "q_pad_to": 8}
 
 
-@functools.lru_cache(maxsize=None)
-def _paged_params_for(device_kind: str) -> dict:
+def paged_params_for(device_kind: str) -> dict:
     dk = device_kind.lower()
-    for sub, params in _TUNED_PAGED:
+    for sub, params in _PAGED_PARAMS:
         if sub in dk:
             return dict(params)
-    if jax.default_backend() != "cpu":
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "paged_attention: no autotuned row for device_kind=%r; serving "
-            "conservative fallback %s — run tools/flash_tune.py --paged on "
-            "this chip and add a _TUNED_PAGED row", device_kind,
-            _FALLBACK_PAGED)
-    return dict(_FALLBACK_PAGED)
+    raise ValueError(
+        f"paged_attention: no parameter row for device_kind={device_kind!r}; "
+        "run tools/flash_tune.py --paged on this chip and add a "
+        "_PAGED_PARAMS row")
 
 
 def default_paged_params() -> dict:
-    """``{fetch_width, q_pad_to}`` for the attached chip — autotuned when
-    measured, :data:`_FALLBACK_PAGED` otherwise.  Device kind re-read per
-    call (only the per-kind lookup is cached), mirroring
-    ``flash_attention.default_tiles``."""
-    try:
-        dk = jax.devices()[0].device_kind
-    except Exception:
-        return dict(_FALLBACK_PAGED)
-    return _paged_params_for(dk)
+    """``{fetch_width, q_pad_to}`` for the attached chip, from
+    :data:`_PAGED_PARAMS`."""
+    return paged_params_for(jax.devices()[0].device_kind)
 
 
 def resolve_attn_impl(impl: Optional[str]) -> str:
@@ -168,13 +156,13 @@ def _kernel(
         def _compute(i=i, blk=blk):
             if quantized:
                 k8 = kv_refs[4 * i][0, 0]
-                ks = kv_refs[4 * i + 1][0, 0]
+                ks = kv_refs[4 * i + 1][0, 0]  # [1, bs]
                 v8 = kv_refs[4 * i + 2][0, 0]
-                vs = kv_refs[4 * i + 3][0, 0]
+                vs = kv_refs[4 * i + 3][0, 0]  # [1, bs]
                 kblk = k8.astype(jnp.float32)
                 s = jnp.dot(q.astype(jnp.float32), kblk.T,
                             preferred_element_type=jnp.float32)
-                s = s * ks[None, :]
+                s = s * ks
             else:
                 kblk = kv_refs[2 * i][0, 0]
                 s = jnp.dot(q, kblk.T,
@@ -194,7 +182,7 @@ def _kernel(
             l_ref[...] = jnp.broadcast_to(
                 l * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
             if quantized:
-                pv = p * vs[None, :]
+                pv = p * vs
                 upd = jnp.dot(pv, v8.astype(jnp.float32),
                               preferred_element_type=jnp.float32)
             else:
@@ -265,14 +253,13 @@ def paged_decode_attention(
     def qidx(b, h, j, tab, off):
         return (b, h, 0, 0)
 
-    def kvidx(b, h, j, tab, off, i=0, ndim=4):
+    def kvidx(b, h, j, tab, off, i=0):
         # clamp dead steps onto the last live block: consecutive grid
         # steps then revisit the same index and Mosaic skips the re-fetch
         # — attention HBM traffic scales with the slot's ACTUAL length
         hi1 = (off[b] + S_in + bs - 1) // bs - 1
         blk = jnp.minimum(jnp.minimum(j * fw + i, hi1), mb - 1)
-        idx = tab[b, blk]
-        return (idx, h, 0, 0) if ndim == 4 else (idx, h, 0)
+        return (tab[b, blk], h, 0, 0)
 
     in_specs = [pl.BlockSpec((1, 1, rows, hd), qidx)]
     operands = [qr]
@@ -283,9 +270,12 @@ def paged_decode_attention(
                 in_specs.append(pl.BlockSpec(
                     (1, 1, bs, hd), functools.partial(kvidx, i=i)))
                 operands.append(p8)
+                # scales ride as [nb, Hkv, 1, bs]: a (1, bs) block over a
+                # (1, bs) minor pair is legal on TPU, (1, bs) over
+                # (Hkv, bs) is not
                 in_specs.append(pl.BlockSpec(
-                    (1, 1, bs), functools.partial(kvidx, i=i, ndim=3)))
-                operands.append(ps)
+                    (1, 1, 1, bs), functools.partial(kvidx, i=i)))
+                operands.append(ps[:, :, None, :])
             else:
                 in_specs.append(pl.BlockSpec(
                     (1, 1, bs, hd), functools.partial(kvidx, i=i)))
@@ -552,7 +542,7 @@ def modeled_attend_temp_bytes(
     if impl == "gather":
         return 2 * batch * kv_heads * max_blocks * block_size * head_dim * itemsize
     if impl == "pallas":
-        fw = int(fetch_width or _FALLBACK_PAGED["fetch_width"])
+        fw = int(fetch_width or paged_params_for("cpu")["fetch_width"])
         rows = groups * s_in
         blocks = 2 * 2 * fw * block_size * head_dim * itemsize  # k+v, 2-buf
         return batch * kv_heads * (2 * rows * head_dim * itemsize + blocks)

@@ -34,7 +34,7 @@ from typing import Optional
 
 import jax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
 
 from .flash_attention import NEG_INF, flash_attention_with_lse, mha_reference

@@ -51,7 +51,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size
+from jax.lax import axis_size
 from .flash_attention import NEG_INF
 
 __all__ = [

@@ -36,9 +36,9 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..dist.topology import DATA_AXIS, tpc
@@ -89,25 +89,11 @@ def _key_str(path) -> str:
 
 def _vma(x) -> frozenset:
     """The set of mesh axes a traced value is varying over."""
-    from ..compat import typeof
-
-    return frozenset(getattr(typeof(x), "vma", frozenset()))
+    return frozenset(getattr(jax.typeof(x), "vma", frozenset()))
 
 
 def _vaxes(x, axes) -> Tuple[str, ...]:
-    """The subset of ``axes`` to treat ``x`` as varying over.
-
-    Modern jax: filtered by the value's actual vma.  Legacy jax has no
-    varying-ness tracking (``_vma`` is always empty) and its
-    ``check_rep=False`` AD never inserts implicit reductions — so a grad/
-    loss computed from data-sharded inputs IS varying over every data-like
-    axis, and skipping the reduction (what the empty-vma filter would do)
-    silently trains unsynced replicas.  Assume all requested axes there.
-    """
-    from ..compat import HAS_VMA
-
-    if not HAS_VMA:
-        return tuple(axes)
+    """The subset of ``axes`` that ``x`` actually varies over."""
     return tuple(a for a in axes if a in _vma(x))
 
 
@@ -116,11 +102,7 @@ def _mark_varying(x, axes: Tuple[str, ...]):
     axes = tuple(a for a in axes if a not in _vma(x))
     if not axes:
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    from ..compat import pvary
-
-    return pvary(x, axes)
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def pvary_params(params: PyTree, axes: Tuple[str, ...]) -> PyTree:
@@ -195,8 +177,7 @@ def reduce_gradients(
                 matched = True
                 break
         # only reduce over axes the grad actually varies on (a grad can
-        # already be unvarying over an axis, e.g. after implicit psum);
-        # legacy jax can't track that and reduces over all requested axes
+        # already be unvarying over an axis, e.g. after implicit psum)
         vaxes = _vaxes(g, axes)
         if not matched:
             mean_axes = tuple(a for a in vaxes if op_of(a) == "mean")
@@ -254,9 +235,7 @@ def _opt_state_specs(opt_state, params, param_specs, spec_of):
     """PartitionSpec tree for an optimizer state: any subtree whose pytree
     structure mirrors the params (adam's mu/nu, sgd momentum, ...) gets the
     param specs; every other leaf (step counters, scalars) falls back to its
-    observed placement.  Matching structurally rather than by placement
-    keeps sharded-TP steps correct even when the moments were materialized
-    replicated (legacy-jax eager ``opt.init``)."""
+    observed placement."""
     pdef = jax.tree_util.tree_structure(params)
     multi = pdef.num_leaves > 1  # a 1-leaf params tree would match any leaf
 
@@ -616,9 +595,7 @@ class DataParallel:
                 # sharding when created via opt.init(placed_params); prefer
                 # the structural mapping (moment subtrees that mirror the
                 # param pytree get the PARAM specs) and fall back to actual
-                # placement — on legacy jax an eager opt.init materializes
-                # moments replicated even for sharded params, and a P()
-                # in_spec would then feed full-size moments to sharded grads
+                # placement
                 opt_specs = _opt_state_specs(
                     opt_state, params, in_param_specs, spec_of)
                 # the numerics stats dict is all psum-reduced scalars —

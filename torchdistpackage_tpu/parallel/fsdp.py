@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import axis_size
+from jax.lax import axis_size
 from ..dist.topology import DATA_AXIS, tpc
 from .zero import _norm_spec, zero_partition_spec
 
@@ -399,7 +399,7 @@ class FSDP:
                 f"step supports None or 'int8'")
         mesh = self.mesh
         ax = self.shard_axis
-        from ..compat import shard_map
+        from jax import shard_map
         from .data_parallel import _vaxes, pvary_params, step_cache_key
 
         compiled: dict = {}

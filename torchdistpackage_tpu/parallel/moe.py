@@ -36,7 +36,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -79,10 +79,11 @@ class MoEConfig:
     #              exists in HBM.  topk router only; under ep_axis the
     #              all_to_all exchange keeps the 'sorted' layout (it IS
     #              the wire payload) and only the expert FFN fuses.
-    #   'auto'   — 'pallas' on the TPU backend; elsewhere 'sorted' when
-    #              the dense tensors would exceed _DENSE_DISPATCH_MAX
-    #              elements (all three paths are exercised by CI — the
-    #              kernel in Pallas interpreter mode).
+    #   'auto'   — 'sorted' on the TPU backend (the path with a chip
+    #              record); elsewhere 'sorted' when the dense tensors would
+    #              exceed _DENSE_DISPATCH_MAX elements (all three paths are
+    #              exercised by CI — the kernel in Pallas interpreter mode;
+    #              it does not lower for TPU, ops/moe_dispatch.py).
     dispatch: str = "auto"
     # Expert FFN activation: 'gelu' | 'swiglu' (stacked [E, 2, D, F]
     # gate/up — the Mixtral-style expert; structural dispatch on w1.ndim,
@@ -113,22 +114,13 @@ class MoEConfig:
 _DENSE_DISPATCH_MAX = 1 << 24
 
 
-def _use_sorted(cfg: MoEConfig, T: int, capacity: int) -> bool:
-    if cfg.dispatch in ("auto", "pallas"):
+def _use_sorted(dispatch: str, T: int, E: int, capacity: int) -> bool:
+    """``dispatch``: cfg.dispatch after ``resolve_moe_dispatch``."""
+    if dispatch in ("auto", "pallas"):
         # 'pallas' reaches here only where the kernel doesn't apply (the
-        # EP exchange layout, or the expert_choice router under 'auto')
-        return T * cfg.num_experts * capacity > _DENSE_DISPATCH_MAX
-    return cfg.dispatch == "sorted"
-
-
-def _use_pallas(cfg: MoEConfig) -> bool:
-    """Resolve cfg.dispatch for the topk branch ('auto' -> backend
-    choice, recorded as a ``moe_dispatch_selected`` event at trace time)."""
-    if cfg.router != "topk":
-        return False
-    from ..ops.moe_dispatch import resolve_moe_dispatch
-
-    return resolve_moe_dispatch(cfg.dispatch) == "pallas"
+        # EP exchange layout)
+        return T * E * capacity > _DENSE_DISPATCH_MAX
+    return dispatch == "sorted"
 
 
 def _top_k_route(
@@ -357,7 +349,12 @@ def moe_forward(
     probs = jax.nn.softmax(
         (tokens @ params["router"]["w"]).astype(jnp.float32), axis=-1
     )  # [T, E] in fp32 for routing stability
-    pallas = _use_pallas(cfg)
+    from ..ops.moe_dispatch import resolve_moe_dispatch
+
+    # 'auto' -> the backend's choice, recorded as a ``moe_dispatch_selected``
+    # event at trace time; MoEConfig refuses 'pallas' off the topk router
+    dispatch = resolve_moe_dispatch(cfg.dispatch)
+    pallas = dispatch == "pallas"
     if cfg.router == "expert_choice":
         if causal:
             raise ValueError(
@@ -382,7 +379,7 @@ def moe_forward(
             )
             if return_metrics else None
         )
-        if _use_sorted(cfg, T, capacity):
+        if _use_sorted(dispatch, T, E, capacity):
             # index path: the EC pick IS a gather spec — tok_idx[e, c] names
             # the token in slot c of expert e; no [T, E, C] tensors exist
             gate_ec, tok_idx = jax.lax.top_k(probs.T, capacity)  # [E, C]
@@ -432,7 +429,7 @@ def moe_forward(
         # under EP the exchange needs a materialized [E, C, D] layout (it
         # IS the all_to_all payload): keep the sorted dispatch and fuse
         # only the expert FFN leg (fused_expert_ffn below)
-        if pallas or _use_sorted(cfg, T, capacity):
+        if pallas or _use_sorted(dispatch, T, E, capacity):
             kept = jnp.sum(keep, axis=-1)  # [T, k] 1 iff the choice fit
             # flat destination slot e*C + c; dropped choices go to a
             # dumpster row (index E*C) that is sliced off / zeroed

@@ -35,7 +35,7 @@ from typing import Any, Callable, List, Sequence
 
 import jax
 
-from ...compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
 
 from ...dist.topology import PIPE_AXIS

@@ -33,7 +33,7 @@ from typing import Any, Callable, Optional
 
 import jax
 
-from ...compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
 
 from ...dist.topology import PIPE_AXIS
@@ -81,7 +81,7 @@ def _zeros_like_shapes(shapes):
     from ..data_parallel import _mark_varying
 
     def z(a):
-        from ...compat import typeof
+        from jax import typeof
 
         aval = a if isinstance(a, jax.ShapeDtypeStruct) else typeof(a)
         x = jnp.zeros(aval.shape, aval.dtype)
@@ -701,8 +701,7 @@ def pipeline_1f1b(
             dp = jax.tree.map(lambda a, b: a + b, dp_stage, dp_last)
         return loss_m, dp, dx
 
-    # ---- carry init (zeros with the right vma, via abstract eval; legacy
-    # jax's ShapeDtypeStruct has no vma kwarg and nothing to carry anyway)
+    # ---- carry init (zeros with the right vma, via abstract eval)
     _zvma = _vma(zero_state)
 
     def _stacked_struct(a):

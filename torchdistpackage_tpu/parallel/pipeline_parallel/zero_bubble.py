@@ -77,7 +77,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from ...compat import axis_size
+from jax.lax import axis_size
 from ...dist.topology import PIPE_AXIS
 from .pipeline_sched import (
     _gather_state,

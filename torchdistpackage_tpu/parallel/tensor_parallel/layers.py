@@ -30,7 +30,7 @@ from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 
-from ...compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 

@@ -26,7 +26,7 @@ from typing import Optional
 
 import jax
 
-from ...compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
 
 from ...dist.topology import TENSOR_AXIS

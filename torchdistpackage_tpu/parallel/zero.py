@@ -40,9 +40,9 @@ from typing import Any, Callable, Optional, Tuple, Union
 
 import jax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..dist.topology import DATA_AXIS, tpc

@@ -338,8 +338,9 @@ class ServingEngine:
     moe_dispatch: MoE dispatch for the expert-FFN layers of a MoE family
         (ignored otherwise): ``'gather'`` pins the ragged grouped-GEMM
         serving oracle, ``'pallas'`` the fused dispatch kernel
-        (ops/moe_dispatch.py), ``None`` defers to ``cfg.moe_dispatch``
-        (whose ``'auto'`` picks pallas on TPU).  Recorded in
+        (ops/moe_dispatch.py; Pallas interpreter only — it does not lower
+        for TPU), ``None`` defers to ``cfg.moe_dispatch`` (whose ``'auto'``
+        means the ragged path here).  Recorded in
         ``serving_summary()['moe']['dispatch']``; both arms feed the same
         live expert-load stats (the summary's ``moe`` subsection and the
         Router's imbalance-weighted load index).
@@ -681,7 +682,7 @@ class ServingEngine:
     def _mesh_step(self, step):
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
 
         dp = self.dp_axis
         row = P(dp) if dp else P()
@@ -774,7 +775,7 @@ class ServingEngine:
             return jax.jit(step)
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
 
         dp = self.dp_axis
         row = P(dp) if dp else P()
@@ -801,7 +802,7 @@ class ServingEngine:
             return jax.jit(cow)
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
 
         row = P(self.dp_axis) if self.dp_axis else P()
         cache_specs = self._cache_specs(self.cache)

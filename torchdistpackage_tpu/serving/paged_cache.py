@@ -54,7 +54,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from ..models.generate import (
     _cached_attention,
     _embed_at,

@@ -1,10 +1,10 @@
-"""Bench trajectory: compare the checked-in ``BENCH_r0*.json`` rounds.
+"""Bench trajectory: compare the ``BENCH_r0*.json`` rounds in a directory.
 
-Every driver round leaves a ``BENCH_r0N.json`` artifact behind (``{"n",
-"tail", "parsed"}`` — the bench harness's stdout tail holds one JSON line
-per measured metric).  Nothing consumed that trajectory until now: a
-slow regression could ride through five rounds unchallenged as long as
-each round individually "worked".  This tool is the first consumer —
+A driver round is a ``BENCH_r0N.json`` artifact (``{"n", "tail",
+"parsed"}`` — the bench harness's stdout tail holds one JSON line per
+measured metric).  None is checked in any more (the rounds of 2026-07/08
+were deleted with the remote-chip records they carried; ROADMAP keeps their
+numbers), so the tool runs on a directory the caller names —
 
     python -m torchdistpackage_tpu.tools.bench_trend [--dir REPO]
         [--threshold 0.05] [--glob 'BENCH_r*.json']
