@@ -42,14 +42,23 @@ def _ev(kind, t, **fields):
             "process": 0, **fields}
 
 
+#: where each phase's span lies inside a synthetic tick, as shares of its
+#: length: measured starts and ends with gaps between them (the host's own
+#: work, e.g. building the call's arrays between ``sched`` and ``prefill``)
+_TICK_LAYOUT = {"audit": (0.0, 0.1), "sched": (0.1, 0.2),
+                "prefill": (0.25, 0.45), "draft": (0.45, 0.5),
+                "decode": (0.5, 0.85), "fetch": (0.85, 0.95)}
+
+
 def _tick(n, t0, t1, *, prefill=(), decode=(), spec=False, queue=0,
           busy=0, **extra):
     dur = t1 - t0
-    phases = {"audit": 0.1 * dur, "sched": 0.1 * dur, "prefill": 0.2 * dur,
-              "draft": 0.05 * dur, "decode": 0.4 * dur, "fetch": 0.1 * dur,
-              "host": 0.05 * dur}
+    spans = [[f"tdp:engine.{name}", t0 + a * dur, t0 + b * dur]
+             for name, (a, b) in _TICK_LAYOUT.items()]
+    phases = {name: (b - a) * dur for name, (a, b) in _TICK_LAYOUT.items()}
+    phases["host"] = dur - sum(phases.values())
     return _ev("engine_tick", t1, tick=n, t_start=t0, tick_s=dur,
-               phases=phases, queue_depth=queue, busy=busy,
+               phases=phases, spans=spans, queue_depth=queue, busy=busy,
                admitted=extra.pop("admitted", 0), expired=0,
                prefill_slots=len(prefill), decode_slots=len(decode),
                batch_util=len(decode) / 4, pool_util=0.5,
@@ -151,19 +160,35 @@ def test_tick_trace_events_phase_lanes_and_counters():
     out = tick_trace_events(events)
     assert validate_trace({"traceEvents": out}) == []
     xs = [e for e in out if e["ph"] == "X"]
-    assert {e["tid"] for e in xs} == set(TICK_TIDS.values())
-    # lanes are laid back-to-back from the tick start: within one tick,
-    # each phase starts where the previous ended
-    tick1 = sorted((e for e in xs if e["args"]["tick"] == 1),
-                   key=lambda e: e["ts"])
-    for a, b in zip(tick1, tick1[1:]):
-        assert b["ts"] == round(a["ts"] + a["dur"], 2) or \
-            abs(b["ts"] - (a["ts"] + a["dur"])) < 0.01
+    # one lane a measured phase; ``host`` is the remainder and has neither
+    # a start nor an end, so no lane and no lane name
+    lanes = {t for p, t in TICK_TIDS.items() if p != "host"}
+    assert {e["tid"] for e in xs} == lanes
+    assert {e["tid"] for e in out if e["ph"] == "M"} == lanes
+    # the lanes are MEASURED: each span sits where the event's ``spans``
+    # put it, so the gap between sched's end and prefill's start (5% of the
+    # tick) is there to read, not closed up from the tick's start
+    t0 = min(e["t_start"] for e in events if e["kind"] == "engine_tick")
+    tick1 = {e["name"]: e for e in xs if e["args"]["tick"] == 1}
+    (ev1,) = [e for e in events if e.get("tick") == 1]
+    for name, s0, s1 in ev1["spans"]:
+        lane = tick1[name.rpartition(".")[2]]
+        assert abs(lane["ts"] - (s0 - t0) * 1e6) < 0.01
+        assert abs(lane["dur"] - (s1 - s0) * 1e6) < 0.01
+    gap = tick1["prefill"]["ts"] - (tick1["sched"]["ts"] + tick1["sched"]["dur"])
+    assert abs(gap - 0.05 * ev1["tick_s"] * 1e6) < 0.01
     counters = {e["name"] for e in out if e["ph"] == "C"}
     assert {"serving_queue_depth", "serving_slots", "serving_utilization",
             "serving_rates"} <= counters
     # negative timestamps would make Perfetto refuse the file
     assert all(e.get("ts", 0) >= 0 for e in out if e["ph"] != "M")
+    # a record without ``spans`` (a file from before they were measured)
+    # draws nothing for the lanes and keeps its counters
+    old = [{k: v for k, v in e.items() if k != "spans"} for e in events]
+    drawn = tick_trace_events(old)
+    assert not [e for e in drawn if e["ph"] == "X"]
+    assert len([e for e in drawn if e["ph"] == "C"]) == len(
+        [e for e in out if e["ph"] == "C"])
 
 
 def test_chrome_trace_events_appends_serving_and_elides_tick_instants():

@@ -1,0 +1,338 @@
+"""The one span primitive (utils/profiling.py) and what the serving engine
+records with it: ``tdp:engine.*`` spans on the profiler's clock, the tick
+phases summed from them, the prefill waste counted where it happens, the
+``first`` mark of a compiling call, and a stable name on every Pallas
+kernel."""
+
+import glob
+import importlib
+import pathlib
+import re
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from torchdistpackage_tpu.models import GPTConfig, init_gpt_params
+from torchdistpackage_tpu.serving import Request, ServingEngine
+from torchdistpackage_tpu.serving.tracing import TICK_PHASES
+from torchdistpackage_tpu.utils import span, spans
+
+CFG = GPTConfig(vocab_size=64, dim=32, nheads=4, nlayers=2, max_seq=64,
+                ffn_mult=2, dtype=jnp.float32)
+SLOTS, CHUNK = 4, 8
+PHASE_SPANS = {f"tdp:engine.{p}" for p in TICK_PHASES if p != "host"}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_gpt_params(jax.random.PRNGKey(0), CFG)
+
+
+def _engine(params, **kw):
+    return ServingEngine(params, CFG, num_slots=SLOTS, block_size=8,
+                         chunk=CHUNK, max_ctx=64, **kw)
+
+
+def _by_name(records, name):
+    return [r for r in records if r[2] == name]
+
+
+# ------------------------------------------------------------ the primitive
+
+
+def test_span_records_parent_attrs_and_survives_its_opener():
+    spans.clear()
+    with span("t:outer", tick=7) as outer:
+        with span("t:inner") as inner:
+            inner.attrs["rows"] = 32   # attrs may be filled until the close
+    del outer, inner
+    (i_rec, o_rec) = spans.snapshot()   # closed in order: inner first
+    assert i_rec[2:3] + o_rec[2:3] == ("t:inner", "t:outer")
+    assert i_rec[1] == o_rec[0] and o_rec[1] is None
+    assert o_rec[5] == {"tick": 7} and i_rec[5] == {"rows": 32}
+    assert o_rec[3] <= i_rec[3] <= i_rec[4] <= o_rec[4]
+    spans.clear()
+    assert spans.snapshot() == []
+
+
+def test_span_ring_is_bounded_and_parents_are_per_thread():
+    assert spans.maxlen == 1 << 17
+    spans.clear()
+    seen = {}
+
+    def other():
+        with span("t:thread") as sp:
+            seen["parent"] = sp.parent
+
+    with span("t:main"):
+        th = threading.Thread(target=other)
+        th.start()
+        th.join(timeout=30)
+    assert not th.is_alive()
+    assert seen["parent"] is None   # the main thread's open span is not its parent
+    assert {r[2]: r[1] for r in spans.snapshot()} == {
+        "t:thread": None, "t:main": None}
+
+
+def test_trace_annotation_has_one_owner_in_the_package():
+    """``span`` is the one way the package opens a host span."""
+    root = pathlib.Path(__file__).resolve().parent.parent / "torchdistpackage_tpu"
+    users = [str(p.relative_to(root)) for p in root.rglob("*.py")
+             if "TraceAnnotation(" in p.read_text()]
+    assert users == ["utils/profiling.py"]
+
+
+# ------------------------------------------------------------- the engine
+
+
+def test_engine_spans_lie_on_the_profilers_clock(params, tmp_path):
+    """A real ``jax.profiler`` capture around three ticks: the host plane
+    holds each ``tdp:engine.tick`` with its phases inside it in time, and
+    every event's duration agrees with the ring's span to 1 ms: one clock."""
+    from jax.profiler import ProfileData
+
+    eng = _engine(params)
+    eng.submit(Request(tokens=[1, 2, 3, 4, 5], max_new_tokens=8))
+    eng.step()   # compiles outside the capture
+    spans.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    ring = [r for r in spans.snapshot() if r[2].startswith("tdp:engine.")]
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    traced = [(e.name, e.start_ns, e.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if not plane.name.startswith("/device:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("tdp:engine.")]
+    traced.sort(key=lambda e: (e[1], -e[2]))   # by start, a parent first
+    ring.sort(key=lambda r: (r[3], -r[4]))
+    assert [e[0] for e in traced] == [r[2] for r in ring]
+    assert [e[0] for e in traced].count("tdp:engine.tick") == 3
+    for (name, _, dur_ns), rec in zip(traced, ring):
+        assert abs(dur_ns * 1e-9 - (rec[4] - rec[3])) < 1e-3, name
+    ticks = [e for e in traced if e[0] == "tdp:engine.tick"]
+    for _, t0, dur in ticks:
+        inside = {e[0] for e in traced
+                  if e[0] != "tdp:engine.tick"
+                  and t0 <= e[1] and e[1] + e[2] <= t0 + dur}
+        assert inside == {"tdp:engine.audit", "tdp:engine.sched",
+                          "tdp:engine.decode", "tdp:engine.fetch"}
+    # every phase event lies inside some tick of the trace
+    for name, s, d in traced:
+        if name != "tdp:engine.tick":
+            assert any(t0 <= s and s + d <= t0 + dur for _, t0, dur in ticks)
+
+
+def test_tick_children_do_not_overlap_and_phases_are_their_sums(params):
+    spans.clear()
+    eng = _engine(params, spec_k=2)   # the speculative tick drafts too
+    eng.submit(Request(tokens=list(range(1, 13)), max_new_tokens=6))
+    eng.run_until_idle(max_ticks=50)
+    ring = spans.snapshot()
+    ticks = _by_name(ring, "tdp:engine.tick")
+    assert [t[5]["tick"] for t in ticks] == [
+        r["tick"] for r in eng.tick_records]
+    seen = set()
+    for tick, rec in zip(ticks, eng.tick_records):
+        kids = sorted((r for r in ring if r[1] == tick[0]),
+                      key=lambda r: r[3])
+        assert {k[2] for k in kids} <= PHASE_SPANS
+        seen |= {k[2] for k in kids}
+        for k in kids:
+            assert tick[3] <= k[3] <= k[4] <= tick[4]
+        for a, b in zip(kids, kids[1:]):
+            assert a[4] <= b[3]
+        sums = dict.fromkeys(TICK_PHASES, 0.0)
+        for k in kids:
+            sums[k[2].rpartition(".")[2]] += k[4] - k[3]
+        assert set(rec["phases"]) == set(TICK_PHASES)
+        for p in TICK_PHASES:
+            if p != "host":
+                assert rec["phases"][p] == pytest.approx(sums[p], abs=1e-8)
+        named = sum(v for p, v in rec["phases"].items() if p != "host")
+        assert rec["phases"]["host"] == pytest.approx(
+            rec["tick_s"] - named, abs=1e-8)
+        assert rec["t_start"] == tick[3] and rec["t_end"] <= tick[4]
+    assert seen == PHASE_SPANS
+    # the engine_tick event carries the same children, measured
+    # (the default event log is the process's: this engine's ticks only)
+    ev = [e for e in eng._ev.as_list() if e.get("kind") == "engine_tick"
+          and e["t_start"] >= ticks[0][3]]
+    by_tick = {t[5]["tick"]: t for t in ticks}
+    assert ev
+    for e in ev:
+        kids = sorted((r for r in ring if r[1] == by_tick[e["tick"]][0]),
+                      key=lambda r: r[3])
+        assert e["spans"] == [[k[2], k[3], k[4]] for k in kids]
+
+
+def test_prefill_span_counts_real_tokens_against_dispatched_rows(params):
+    spans.clear()
+    eng = _engine(params)
+    rid = eng.submit(Request(tokens=[1, 2, 3, 4, 5], max_new_tokens=2))
+    eng.step()
+    (pre,) = _by_name(spans.snapshot(), "tdp:engine.prefill")
+    assert pre[5]["tokens"] == 5
+    assert pre[5]["rows"] == SLOTS * CHUNK == 32
+    assert pre[5]["rids"] == [rid]
+    (dec,) = _by_name(spans.snapshot(), "tdp:engine.decode")
+    assert dec[5]["slots"] == 1 and dec[5]["rids"] == [rid]
+    # a prompt longer than a chunk: its slices' real tokens, chunk by chunk
+    spans.clear()
+    eng.submit(Request(tokens=list(range(1, 12)), max_new_tokens=1))
+    eng.run_until_idle(max_ticks=20)
+    assert [p[5]["tokens"] for p in
+            _by_name(spans.snapshot(), "tdp:engine.prefill")] == [8, 3]
+
+
+def test_first_marks_the_one_compiling_call_of_a_signature(params):
+    spans.clear()
+    eng = _engine(params)
+    for _ in range(2):
+        eng.submit(Request(tokens=[1, 2, 3], max_new_tokens=3))
+        eng.run_until_idle(max_ticks=20)
+        eng.reset_metrics()   # forgets the counted signatures, not the calls
+    ring = spans.snapshot()
+    for name in ("tdp:engine.prefill", "tdp:engine.decode"):
+        calls = _by_name(ring, name)
+        assert len(calls) >= 2
+        assert [bool(c[5].get("first")) for c in calls] == (
+            [True] + [False] * (len(calls) - 1))
+    # the fetch after a first call carries the mark too: two of them
+    fetches = _by_name(ring, "tdp:engine.fetch")
+    assert sum(bool(f[5].get("first")) for f in fetches) == 2
+    firsts = [r for r in ring if r[5].get("first")]
+    assert [r[2].rpartition(".")[2] for r in firsts] == [
+        "prefill", "fetch", "decode", "fetch"]
+    assert eng.serving_summary()["decode_signatures"] == 0   # just reset
+
+
+def test_engine_init_span_holds_the_pool_fill(params):
+    spans.clear()
+    _engine(params)
+    (pool, init) = spans.snapshot()
+    assert (init[2], pool[2]) == ("tdp:engine.init", "tdp:engine.init.pool")
+    assert pool[1] == init[0] and init[1] is None
+    assert init[3] <= pool[3] <= pool[4] <= init[4]
+
+
+def test_fetch_ends_at_the_tokens_and_telemetry_falls_into_host(params):
+    """``Telemetry.end_step`` is called after the fetch span has closed, and
+    its step record still holds the wait for the device."""
+    from torchdistpackage_tpu.obs import Telemetry
+
+    last_closed = []
+
+    class Tel(Telemetry):
+        def end_step(self, *a, **kw):
+            last_closed.append(spans.snapshot()[-1][2])
+            return super().end_step(*a, **kw)
+
+    tel = Tel(run="t", sinks=[], poll_memory=False)
+    spans.clear()
+    eng = _engine(params, telemetry=tel)
+    eng.submit(Request(tokens=[1, 2, 3], max_new_tokens=3))
+    eng.run_until_idle(max_ticks=20)
+    assert last_closed and set(last_closed) == {"tdp:engine.fetch"}
+    steps = [r for r in tel.history if r.get("type") == "step"]
+    assert len(steps) == len(last_closed)
+    # the device span runs from the dispatch's return, so it covers the
+    # engine's own wait: at least the fetch span of that tick
+    fetches = _by_name(spans.snapshot(), "tdp:engine.fetch")[-len(steps):]
+    for rec, f in zip(steps, fetches):
+        assert rec["span_device_s"] >= f[4] - f[3]
+
+
+# ------------------------------------------------------------ kernel names
+
+
+def _kernel_cases():
+    """name -> () -> (function, arguments, lowers for TPU?)"""
+    S = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+
+    def flash(q, k, v):
+        from torchdistpackage_tpu.ops.flash_attention import flash_attention
+
+        return flash_attention(q, k, v, block_q=128, block_k=128).astype(
+            jnp.float32).sum()
+
+    q = S((1, 2, 256, 128), bf)
+    flash_args = (jax.grad(flash, argnums=(0, 1, 2)), (q, q, q), True)
+
+    B, H, hd, bs, mb = 2, 4, 128, 128, 2
+    pool = S((1 + B * mb, H, bs, hd), bf)
+
+    def paged(s_in):
+        from torchdistpackage_tpu.ops.paged_attention import (
+            paged_decode_attention)
+
+        return (lambda q, k, v, t, o: paged_decode_attention(
+            q, k, v, t, o, fetch_width=2, q_pad_to=8),
+                (S((B, H, s_in, hd), bf), pool, pool,
+                 S((B, mb), jnp.int32), S((B,), jnp.int32)), True)
+
+    def carry():
+        from torchdistpackage_tpu.ops.paged_attention import (
+            paged_carry_attention)
+
+        return (lambda q, k, v, t, o: paged_carry_attention(
+            q, k, v, t, o, fetch_width=2, q_pad_to=8),
+                (S((B, H, 16, hd), bf), pool, pool,
+                 S((B, mb), jnp.int32), S((B,), jnp.int32)), True)
+
+    def moe(which):   # interpreter only: neither lowers for TPU (PR 21)
+        from torchdistpackage_tpu.ops import moe_dispatch as M
+        from torchdistpackage_tpu.parallel.moe import (
+            MoEConfig, _top_k_route, init_moe_params)
+
+        T, D, E, k = 24, 16, 4, 2
+        experts = init_moe_params(
+            jax.random.PRNGKey(0),
+            MoEConfig(dim=D, ffn_dim=32, num_experts=E, top_k=k))["experts"]
+        if which == "expert":
+            return M.fused_expert_ffn, (experts, jnp.zeros((E, 8, D))), False
+        route = _top_k_route(jnp.full((T, E), 1.0 / E), k, T)
+        return (lambda ex, t: M.fused_moe_ffn(ex, t, *route, T),
+                (experts, jnp.zeros((T, D))), False)
+
+    return {
+        "flash_fwd": lambda: flash_args,
+        "flash_bwd_dq": lambda: flash_args,
+        "flash_bwd_dkv": lambda: flash_args,
+        "paged_decode": lambda: paged(1),
+        "paged_chunk": lambda: paged(16),
+        "paged_carry": carry,
+        "moe_fused_ffn": lambda: moe("fused"),
+        "moe_expert_ffn": lambda: moe("expert"),
+    }
+
+
+@pytest.mark.parametrize("kernel", [
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
+    "paged_chunk", "paged_carry", "moe_fused_ffn", "moe_expert_ffn"])
+def test_kernel_lowers_under_its_name(monkeypatch, kernel):
+    """XLA names a Mosaic custom call after the name-stack component before
+    ``pallas_call``: that is the kernel's ``name=``, which the device
+    trace then shows (``%flash_fwd.1 = ... custom-call``).  Lowered for TPU
+    from the CPU, but for the MoE pair, which only the interpreter runs."""
+    fn, args, for_tpu = _kernel_cases()[kernel]()
+    if for_tpu:
+        for mod in ("flash_attention", "paged_attention"):
+            monkeypatch.setattr(
+                importlib.import_module(f"torchdistpackage_tpu.ops.{mod}"),
+                "_interpret", lambda: False)
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",) if for_tpu else None).as_text(
+            debug_info=True)
+    assert ("tpu_custom_call" in text) == for_tpu
+    # outside a scan a transformation wraps the name: jvp(flash_fwd)
+    named = set(re.findall(r"(\w+)\)*/pallas_call", text))
+    assert kernel in named
+    assert named <= set(_kernel_cases())   # no kernel under another name

@@ -156,7 +156,9 @@ PR 11 — request-lifecycle tracing + tick accounting; docs/serving.md
                             an engine restart
 ``engine_tick``             one engine tick's host-side accounting:
                             per-phase durations (audit / sched / prefill
-                            / draft / decode / fetch / host), queue
+                            / draft / decode / fetch / host), ``spans``
+                            (the measured ``[name, t0, t1]`` of each
+                            ``tdp:engine.*`` phase span), queue
                             depth, slot occupancy, batch + pool
                             utilization, live hit/accept rates, and the
                             per-rid prefill/decode attribution the

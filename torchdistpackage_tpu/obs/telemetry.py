@@ -285,6 +285,7 @@ class Telemetry:
         self._aot_ok = True
         self._pending_out: Any = None
         self._pending_spans: Dict[str, float] = {}
+        self._dispatch_end = 0.0  # when the wrapped call returned
         self._recompiled = False
         self._last_fetch_end: Optional[float] = None
         self._step_n = 0
@@ -349,7 +350,8 @@ class Telemetry:
                     out = jfn(*args, **kwargs)
                 else:
                     raise
-            self._pending_spans["dispatch"] = time.perf_counter() - t0
+            self._dispatch_end = time.perf_counter()
+            self._pending_spans["dispatch"] = self._dispatch_end - t0
             self._pending_out = out
             return out
 
@@ -449,7 +451,9 @@ class Telemetry:
         **scalars: Any,
     ) -> Dict[str, Any]:
         """Close the step opened by the wrapped call: block on its outputs
-        (device span), fetch the passed scalars (fetch span), build the
+        (device span, from the moment the call returned: a caller that has
+        already fetched them, as the serving engine has, waited for the
+        device there), fetch the passed scalars (fetch span), build the
         record, feed the sinks.  Returns the record with host floats — use
         ``rec["loss"]`` instead of a second ``float(loss)``.
 
@@ -463,6 +467,7 @@ class Telemetry:
 
         t0 = time.perf_counter()
         if self._pending_out is not None:
+            t0 = self._dispatch_end
             try:
                 jax.block_until_ready(self._pending_out)
             except Exception:

@@ -231,6 +231,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, groups=1, window=None):
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -364,6 +365,7 @@ def _bwd(sm_scale, causal, block_q, block_k, groups, window, res, cts):
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, dout, lse, delta)
 
     # GQA: the dkv kernel stays per-Q-HEAD (grid dim 0 = B*Hq, kv blocks
@@ -399,6 +401,7 @@ def _bwd(sm_scale, causal, block_q, block_k, groups, window, res, cts):
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, dout, lse, delta)
     if groups > 1:
         BHkv = BH // groups

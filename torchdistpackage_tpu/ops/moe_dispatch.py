@@ -351,6 +351,7 @@ def _pallas_moe_ffn(
         out_shape=_out_struct((Tp, D), jnp.float32, tokens),
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="moe_fused_ffn",
     )(idx.reshape(-1), *operands)
     return y[:T]
 
@@ -511,6 +512,7 @@ def _pallas_expert_ffn(experts, x):
         out_shape=_out_struct((e_loc, Gp, D), x.dtype, x),
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="moe_expert_ffn",
     )(*operands)
     return out[:, :G]
 

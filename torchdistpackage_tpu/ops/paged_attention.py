@@ -312,6 +312,7 @@ def paged_decode_attention(
         out_shape=_out_struct((B, Hkv, rows, hd), q.dtype, q),
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="paged_decode" if S_in == 1 else "paged_chunk",
     )(tables.astype(jnp.int32), offs, *ordered_ops)
     return out[:, :, :R].reshape(B, H, S_in, hd)
 
@@ -506,6 +507,7 @@ def paged_carry_attention(
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="paged_carry",
     )(tables_local.astype(jnp.int32), offs, *operands)
     return acc, m, l
 

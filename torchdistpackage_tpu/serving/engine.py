@@ -147,6 +147,7 @@ from ..models.generate import _full_logits
 from ..models.gpt import GPTConfig
 from ..obs.aggregate import percentiles
 from ..obs.events import EventLog, default_event_log
+from ..utils.profiling import scope_decorator, span
 from .paged_cache import (
     BlockAllocator,
     chain_block_hashes,
@@ -364,6 +365,7 @@ class ServingEngine:
         on.  A host-only step cannot be combined with a mesh.
     """
 
+    @scope_decorator(name="tdp:engine.init")
     def __init__(
         self,
         params: Any,
@@ -533,7 +535,8 @@ class ServingEngine:
         #: host-only stub — every device touch below goes through it
         self.device_step = device_step
         device_step.bind(self)
-        self.cache = device_step.init_cache()
+        with span("tdp:engine.init.pool"):
+            self.cache = device_step.init_cache()
 
         # host-visible device state, one row per slot
         V = cfg.vocab_size
@@ -563,7 +566,9 @@ class ServingEngine:
         #: estimate_ttft applies (None until a prediction resolved; like
         #: _tick_ewma it is measurement state, NOT reset by reset_metrics)
         self._ttft_bias: Optional[float] = None
-        self._phase: Dict[str, float] = collections.defaultdict(float)
+        #: signatures whose first call (the one that compiles or loads) is
+        #: behind us; like _tick_ewma, NOT reset by reset_metrics
+        self._called_sigs: set = set()
         self._tick_prefill_rids: List[int] = []
         self._tick_decode_rids: List[int] = []
         self._tick_emitted = 0
@@ -1247,6 +1252,19 @@ class ServingEngine:
         return (tokens.shape, str(tokens.dtype), self.num_slots,
                 self.max_blocks)
 
+    def _first_call(self, kind: str, tokens: np.ndarray) -> Dict[str, bool]:
+        """Count the call's signature.  Returns the ``first=True`` attr of
+        the dispatch span and of the fetch after it on the one call of each
+        signature that compiles or loads its program, else nothing:
+        ``_called_sigs`` outlives :meth:`reset_metrics`, which forgets the
+        counted ones."""
+        sig = (kind,) + self._sig(tokens)
+        (self._prefill_sigs if kind == "prefill" else self._decode_sigs).add(sig)
+        if sig in self._called_sigs:
+            return {}
+        self._called_sigs.add(sig)
+        return {"first": True}
+
     def _token_poisoned(self, tok: int) -> bool:
         """An out-of-range sampled token is the host-visible face of a
         poisoned logit row (NaN/garbage logits cannot be told apart from a
@@ -1277,6 +1295,7 @@ class ServingEngine:
         tokens = np.zeros((B, C), np.int32)
         offsets = np.zeros(B, np.int32)
         last_idx = np.zeros(B, np.int32)
+        rids, real = [], 0
         for i, s in enumerate(self._slots):
             if s.state != PREFILL:
                 continue
@@ -1284,29 +1303,30 @@ class ServingEngine:
             tokens[i, :len(sl)] = sl
             offsets[i] = s.off
             last_idx[i] = min(len(s.prompt) - 1 - s.off, C - 1)
-        t_disp = time.perf_counter()
-        out = self._step_fn(
-            self.params, self.cache, tokens, tables, offsets, last_idx,
-            self._samp(), self._keys)
-        if len(out) == 5:  # MoE family: live expert-load stats ride along
-            self.cache, tok, keys, moe_et, moe_dr = out
-            self._absorb_moe_stats(moe_et, moe_dr)
-        else:
-            self.cache, tok, keys = out
-        self._prefill_sigs.add(("prefill",) + self._sig(tokens))
-        t_fetch = time.perf_counter()
-        self._phase["prefill"] += t_fetch - t_disp
-        tok = np.asarray(tok)
-        keys = np.asarray(keys)
-        self._phase["fetch"] += time.perf_counter() - t_fetch
+            rids.append(s.rid)
+            real += len(sl)
+        first = self._first_call("prefill", tokens)
+        # tokens: the real prompt tokens of this tick's slices; rows: what
+        # the compiled call computes whatever the number of slots prefilling
+        with span("tdp:engine.prefill", tokens=real, rows=B * C, rids=rids,
+                  **first):
+            out = self._step_fn(
+                self.params, self.cache, tokens, tables, offsets, last_idx,
+                self._samp(), self._keys)
+            if len(out) == 5:  # MoE family: live expert-load stats ride along
+                self.cache, tok, keys, moe_et, moe_dr = out
+                self._absorb_moe_stats(moe_et, moe_dr)
+            else:
+                self.cache, tok, keys = out
+        with span("tdp:engine.fetch", **first):
+            tok = np.asarray(tok)
+            keys = np.asarray(keys)
         if self.chaos is not None:
             tok = self.chaos.perturb_engine_tokens(self._tick, tok)
         now = time.perf_counter()
-        rids = []
         for i, s in enumerate(self._slots):
             if s.state != PREFILL:
                 continue
-            rids.append(s.rid)
             s.off += C
             if s.off >= len(s.prompt):  # final slice: first token sampled
                 if self._token_poisoned(int(tok[i])):
@@ -1375,23 +1395,22 @@ class ServingEngine:
         last_idx = np.zeros(self.num_slots, np.int32)
         self._tick_decode_rids = [
             s.rid for s in self._slots if s.state == DECODE]
-        t_disp = time.perf_counter()
-        out = self._decode_fn(
-            self.params, self.cache, tokens, tables, offsets, last_idx,
-            self._samp(), self._keys)
-        if len(out) == 5:  # MoE family: live expert-load stats ride along
-            self.cache, tok, keys, moe_et, moe_dr = out
-            self._absorb_moe_stats(moe_et, moe_dr)
-        else:
-            self.cache, tok, keys = out
-        self._decode_sigs.add(("decode",) + self._sig(tokens))
-        t_fetch = time.perf_counter()
-        self._phase["decode"] += t_fetch - t_disp
+        first = self._first_call("decode", tokens)
+        with span("tdp:engine.decode", slots=n_active,
+                  rids=self._tick_decode_rids, **first):
+            out = self._decode_fn(
+                self.params, self.cache, tokens, tables, offsets, last_idx,
+                self._samp(), self._keys)
+            if len(out) == 5:  # MoE family: live expert-load stats ride along
+                self.cache, tok, keys, moe_et, moe_dr = out
+                self._absorb_moe_stats(moe_et, moe_dr)
+            else:
+                self.cache, tok, keys = out
+        with span("tdp:engine.fetch", **first):
+            tok = np.asarray(tok)
+            keys = np.asarray(keys)
         if self.telemetry is not None:
             self.telemetry.end_step(active_slots=n_active)
-        tok = np.asarray(tok)
-        keys = np.asarray(keys)
-        self._phase["fetch"] += time.perf_counter() - t_fetch
         if self.chaos is not None:
             tok = self.chaos.perturb_engine_tokens(self._tick, tok)
         now = time.perf_counter()
@@ -1461,30 +1480,27 @@ class ServingEngine:
         K = self.spec_k
         tokens = np.zeros((self.num_slots, K + 1), np.int32)
         offsets = np.where(mask, self._lengths, 0).astype(np.int32)
-        t_draft = time.perf_counter()
         rids = []
-        for i, s in enumerate(self._slots):
-            if s.state != DECODE:
-                continue
-            rids.append(s.rid)
-            tokens[i, 0] = self._last_tok[i]
-            tokens[i, 1:] = self._draft(s)
-        self._phase["draft"] += time.perf_counter() - t_draft
+        with span("tdp:engine.draft"):
+            for i, s in enumerate(self._slots):
+                if s.state != DECODE:
+                    continue
+                rids.append(s.rid)
+                tokens[i, 0] = self._last_tok[i]
+                tokens[i, 1:] = self._draft(s)
         self._tick_decode_rids = rids
         self._ev.emit("spec_draft", k=K, n_slots=len(rids), rids=rids)
-        t_disp = time.perf_counter()
-        self.cache, verify, accept, keys = self._verify_fn(
-            self.params, self.cache, tokens, tables, offsets, self._samp(),
-            self._keys)
-        self._decode_sigs.add(("decode",) + self._sig(tokens))
-        t_fetch = time.perf_counter()
-        self._phase["decode"] += t_fetch - t_disp
+        first = self._first_call("decode", tokens)
+        with span("tdp:engine.decode", slots=n_active, rids=rids, **first):
+            self.cache, verify, accept, keys = self._verify_fn(
+                self.params, self.cache, tokens, tables, offsets,
+                self._samp(), self._keys)
+        with span("tdp:engine.fetch", **first):
+            verify = np.asarray(verify)
+            accept = np.asarray(accept)
+            keys = np.asarray(keys)
         if self.telemetry is not None:
             self.telemetry.end_step(active_slots=n_active)
-        verify = np.asarray(verify)
-        accept = np.asarray(accept)
-        keys = np.asarray(keys)
-        self._phase["fetch"] += time.perf_counter() - t_fetch
         if self.chaos is not None:
             verify = self.chaos.perturb_engine_tokens(self._tick, verify)
         now = time.perf_counter()
@@ -1754,72 +1770,77 @@ class ServingEngine:
         -> admit (with preemption) -> one prefill slice -> one decode
         step.  Returns what happened (all zeros = idle).
 
-        Every tick is decomposed host-side into the :data:`TICK_PHASES`
-        accounting — audit / sched / prefill / draft / decode / fetch /
-        host — recorded on ``tick_records``, emitted as an
-        ``engine_tick`` timeline event (with per-rid attribution, the
-        raw material of the request-lifecycle trace —
+        The tick is a ``tdp:engine.tick`` span with one child span a phase
+        (``tdp:engine.audit`` / ``sched`` / ``prefill`` / ``draft`` /
+        ``decode`` / ``fetch``; utils/profiling.py: the process-wide ring,
+        and the profiler's clock under a capture).  Their summed durations
+        are the :data:`TICK_PHASES` accounting (``host`` is the remainder)
+        recorded on ``tick_records``, emitted as an ``engine_tick``
+        timeline event (with the measured ``spans`` and per-rid
+        attribution, the raw material of the request-lifecycle trace —
         serving/tracing.py), and exported live through ``metrics_sink``
         under the ``serving_metrics`` schema.  All of it is wall-clock
         bookkeeping around the SAME two compiled calls: zero extra
         device dispatches, ``decode_signatures`` stays 1."""
-        t0 = time.perf_counter()
-        self._tick += 1
-        self._phase = collections.defaultdict(float)
-        self._tick_prefill_rids = []
-        self._tick_decode_rids = []
-        self._tick_emitted = 0
-        if self.chaos is not None:
-            self.chaos.before_engine_tick(self._tick, self)
-        self.stats["audits"] += 1
-        t = time.perf_counter()
-        self.audit(heal=True)
-        self._phase["audit"] += time.perf_counter() - t
-        t = time.perf_counter()
-        expired = self._expire_queue(time.perf_counter())
-        admitted = self._admit()
-        self._phase["sched"] += time.perf_counter() - t
-        prefilled = self._prefill_tick()
-        decoded = self._decode_tick()
-        busy = self.n_busy
-        self._occ_sum += busy / self.num_slots
-        util = float(np.mean([a.utilization() for a in self._allocs]))
-        self._util_sum += util
-        self._occ_ticks += 1
-        if self.snapshot_every and self._tick % self.snapshot_every == 0:
-            self._ev.emit(
-                "slots_snapshot", tick=self._tick, busy=busy,
-                queued=len(self.queue), pool_utilization=round(util, 4))
-        if self.watchdog is not None:
-            self.watchdog.beat(self._tick)
-        t_end = time.perf_counter()
-        if decoded:
-            dt = t_end - t0
-            self._tick_ewma = (
-                dt if self._tick_ewma is None
-                else 0.8 * self._tick_ewma + 0.2 * dt)
-        self._record_tick(t0, t_end, admitted=admitted, expired=expired,
-                          prefilled=prefilled, decoded=decoded, busy=busy,
-                          util=util)
+        with span("tdp:engine.tick", tick=self._tick + 1) as tick:
+            self._tick += 1
+            self._tick_prefill_rids = []
+            self._tick_decode_rids = []
+            self._tick_emitted = 0
+            if self.chaos is not None:
+                self.chaos.before_engine_tick(self._tick, self)
+            self.stats["audits"] += 1
+            with span("tdp:engine.audit"):
+                self.audit(heal=True)
+            with span("tdp:engine.sched"):
+                expired = self._expire_queue(time.perf_counter())
+                admitted = self._admit()
+            prefilled = self._prefill_tick()
+            decoded = self._decode_tick()
+            busy = self.n_busy
+            self._occ_sum += busy / self.num_slots
+            util = float(np.mean([a.utilization() for a in self._allocs]))
+            self._util_sum += util
+            self._occ_ticks += 1
+            if self.snapshot_every and self._tick % self.snapshot_every == 0:
+                self._ev.emit(
+                    "slots_snapshot", tick=self._tick, busy=busy,
+                    queued=len(self.queue), pool_utilization=round(util, 4))
+            if self.watchdog is not None:
+                self.watchdog.beat(self._tick)
+            t_end = time.perf_counter()
+            if decoded:
+                dt = t_end - tick.t0
+                self._tick_ewma = (
+                    dt if self._tick_ewma is None
+                    else 0.8 * self._tick_ewma + 0.2 * dt)
+            self._record_tick(tick, t_end, admitted=admitted,
+                              expired=expired, prefilled=prefilled,
+                              decoded=decoded, busy=busy, util=util)
         return {"admitted": admitted, "prefill_slots": prefilled,
                 "decode_slots": decoded, "busy": busy, "expired": expired}
 
-    def _record_tick(self, t_start: float, t_end: float, *, admitted: int,
+    def _record_tick(self, tick: span, t_end: float, *, admitted: int,
                      expired: int, prefilled: int, decoded: int, busy: int,
                      util: float) -> None:
-        """The tick-level accounting record: phase decomposition (the
-        residual ``host`` phase is everything the named phases did not
-        cover — queue sorts, table rewrites, retirement walks) plus the
-        per-tick gauges.  Appended to ``tick_records`` (bounded), emitted
+        """The tick-level accounting record: the phase decomposition,
+        summed from the tick's child spans (the residual ``host`` phase is
+        everything they did not cover — queue sorts, table rewrites,
+        retirement walks, the telemetry's record), plus the per-tick
+        gauges.  Appended to ``tick_records`` (bounded), emitted
         as an ``engine_tick`` event WHEN THE TICK DID WORK (idle polls
         stay off the timeline), and written to ``metrics_sink`` every
         ``metrics_every`` ticks under :data:`SERVING_METRICS_SCHEMA`."""
         st = self.stats
-        named = sum(self._phase.get(k, 0.0)
-                    for k in TICK_PHASES if k != "host")
-        phases = {k: round(self._phase.get(k, 0.0), 9)
-                  for k in TICK_PHASES if k != "host"}
-        phases["host"] = round(max(0.0, (t_end - t_start) - named), 9)
+        t_start = tick.t0
+        phases = dict.fromkeys(TICK_PHASES, 0.0)
+        for _, _, name, c0, c1, _ in tick.children:
+            phase = name.rpartition(".")[2]
+            if phase in phases:
+                phases[phase] += c1 - c0
+        phases = {k: round(v, 9) for k, v in phases.items()}
+        phases["host"] = round(
+            max(0.0, (t_end - t_start) - sum(phases.values())), 9)
         rec = {
             "tick": self._tick,
             "t_start": t_start,
@@ -1847,7 +1868,8 @@ class ServingEngine:
             self._ev.emit(
                 "engine_tick", spec=bool(self.spec_k),
                 prefill_rids=list(self._tick_prefill_rids),
-                decode_rids=list(self._tick_decode_rids), **rec)
+                decode_rids=list(self._tick_decode_rids),
+                spans=[[c[2], c[3], c[4]] for c in tick.children], **rec)
         if (self.metrics_sink is not None
                 and self._tick % self.metrics_every == 0):
             try:

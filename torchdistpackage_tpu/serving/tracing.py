@@ -27,9 +27,9 @@ dicts; no device call, no new compiled program — the engine's
   arrows (``s``/``f``), so one request's journey across ticks,
   preemptions, and an engine restart renders CONNECTED in
   https://ui.perfetto.dev.  ``engine_tick`` events additionally become
-  per-phase lanes (audit / sched / prefill / draft / decode / fetch /
-  host, laid back-to-back from the tick start — the same reconstruction
-  idiom as obs/trace.py's step spans) and counter tracks (queue depth,
+  per-phase lanes (audit / sched / prefill / draft / decode / fetch,
+  each span where the engine measured it: the event's ``spans``) and
+  counter tracks (queue depth,
   slot occupancy, batch utilization, pool utilization, live hit/accept
   rates).  ``obs.trace.chrome_trace_events`` appends all of it
   automatically when serving events are present, so
@@ -67,12 +67,18 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence
 #: "Serving observability" documents the fields).
 SERVING_METRICS_SCHEMA = "tdp-serving-metrics/v1"
 
-#: Per-tick phases, in execution order (the order the lanes are laid
-#: back-to-back from the tick start): invariant ``audit``, host
-#: ``sched``-uling (expiry + admission + the COW flush), the ``prefill``
-#: chunk dispatch, the host ``draft``-er (speculative only), the
-#: ``decode``/verify dispatch, output ``fetch`` (device→host transfer,
-#: including the telemetry sync), and the residual ``host`` walk.
+#: Per-tick phases, in execution order.  Each but ``host`` is a
+#: ``tdp:engine.<phase>`` span (utils/profiling.py) that the engine opens
+#: inside its ``tdp:engine.tick``; a tick record's ``phases`` are their
+#: summed durations and the ``engine_tick`` event's ``spans`` their
+#: measured starts and ends.  Invariant ``audit``; host ``sched``-uling
+#: (expiry + admission + the COW flush); ``prefill`` and ``decode`` are the
+#: HOST'S DISPATCH of the compiled chunk / decode-or-verify call, which
+#: returns before the device is done; the host ``draft``-er (speculative
+#: only) runs between them; ``fetch`` is the wait for the device, the
+#: device->host transfer of the sampled tokens and nothing else; ``host``
+#: is the remainder of the tick (the walk after the fetch, array building,
+#: the telemetry's record).
 TICK_PHASES = ("audit", "sched", "prefill", "draft", "decode", "fetch",
                "host")
 
@@ -432,10 +438,10 @@ def tick_trace_events(
     t0: Optional[float] = None,
 ) -> List[Dict[str, Any]]:
     """Chrome trace events for the tick accounting: per-phase lanes
-    (``X`` spans laid back-to-back from each tick's start, the same
-    reconstruction as obs/trace.py's step spans) plus counter tracks —
-    queue depth, busy/prefill/decode slots, batch + pool utilization,
-    and the live prefix-hit / spec-accept rates."""
+    (one ``X`` span for each measured ``[name, t0, t1]`` of the event's
+    ``spans``; a record without them, an old file, draws no lane) plus
+    counter tracks — queue depth, busy/prefill/decode slots, batch + pool
+    utilization, and the live prefix-hit / spec-accept rates."""
     ticks = [e for e in events if e.get("kind") == "engine_tick"
              and "t_mono" in e]
     if not ticks:
@@ -448,24 +454,23 @@ def tick_trace_events(
 
     out: List[Dict[str, Any]] = []
     for name, tid in TICK_TIDS.items():
+        if name == "host":  # the remainder: no start or end to draw
+            continue
         out.append({"ph": "M", "name": "thread_name", "pid": process,
                     "tid": tid, "args": {"name": f"tick/{name}"}})
         out.append({"ph": "M", "name": "thread_sort_index", "pid": process,
                     "tid": tid, "args": {"sort_index": tid}})
     for e in ticks:
         start = e.get("t_start", e["t_mono"])
-        phases = e.get("phases") or {}
-        cursor = start
-        for name in TICK_PHASES:
-            dur = float(phases.get(name, 0.0) or 0.0)
-            if dur > 0:
+        for name, s0, s1 in e.get("spans") or ():
+            phase = name.rpartition(".")[2]
+            if phase in TICK_TIDS:
                 out.append({
-                    "ph": "X", "name": name, "cat": "tick",
-                    "pid": process, "tid": TICK_TIDS[name],
-                    "ts": us(cursor), "dur": round(dur * 1e6, 3),
+                    "ph": "X", "name": phase, "cat": "tick",
+                    "pid": process, "tid": TICK_TIDS[phase],
+                    "ts": us(s0), "dur": round((s1 - s0) * 1e6, 3),
                     "args": {"tick": e.get("tick")},
                 })
-            cursor += dur
         ts = us(start)
         out.append({"ph": "C", "name": "serving_queue_depth",
                     "pid": process, "tid": 0, "ts": ts,

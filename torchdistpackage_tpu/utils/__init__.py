@@ -22,10 +22,11 @@ from .logging import (
     master_print,
 )
 from .profiling import (
-    TimedScope,
     prof_start,
     prof_stop,
     scope_decorator,
+    span,
+    spans,
 )
 from .checkpoint import (
     CheckpointManager,
@@ -51,10 +52,11 @@ __all__ = [
     "is_master",
     "master_only",
     "master_print",
-    "TimedScope",
     "prof_start",
     "prof_stop",
     "scope_decorator",
+    "span",
+    "spans",
     "CheckpointManager",
     "GracefulShutdown",
     "auto_resume",

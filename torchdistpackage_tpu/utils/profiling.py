@@ -8,17 +8,24 @@ Analogue of the reference's NVTX toolkit (``dist/utils.py:11-69``):
 - ``nvtx_decorator``                            -> :func:`scope_decorator`
   using ``jax.named_scope`` (names flow into XLA HLO metadata and show up in
   the TPU trace viewer — the XLA-native equivalent of an NVTX range) plus a
-  host-side ``TraceAnnotation`` for the host timeline.
-- ``NVTXContext`` (timing context)              -> :class:`TimedScope`,
-  which additionally blocks on device completion so wall times are real
-  (XLA is async; naive host timing measures dispatch, not execution).
+  host :class:`span`.
+- ``NVTXContext`` (timing context)              -> :class:`span`, the ONE
+  way the package opens a host span: a ``TraceAnnotation`` (so that under a
+  ``jax.profiler`` capture the span lies on the device trace's clock) whose
+  ``perf_counter`` start and end also go to the process-wide ring
+  :data:`spans`, which outlives whatever object opened the span.  It does
+  not wait for the device: a span around a jitted call measures the
+  DISPATCH; put the fetch of the result in a span of its own.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
+import itertools
+import threading
 import time
-from typing import Callable, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
@@ -46,7 +53,7 @@ def scope_decorator(fn: Callable = None, *, name: Optional[str] = None) -> Calla
 
         @functools.wraps(f)
         def wrapper(*args, **kwargs):
-            with jax.named_scope(scope), jax.profiler.TraceAnnotation(scope):
+            with jax.named_scope(scope), span(scope):
                 return f(*args, **kwargs)
 
         return wrapper
@@ -56,39 +63,54 @@ def scope_decorator(fn: Callable = None, *, name: Optional[str] = None) -> Calla
     return deco
 
 
-class TimedScope:
-    """``with TimedScope('fwd') as t: ...`` — named range + real wall time.
+#: One closed span: ``(id, parent_id, name, t0, t1, attrs)``; ``t0``/``t1``
+#: are ``perf_counter`` seconds, ``parent_id`` is None at the top.
+SpanRecord = Tuple[int, Optional[int], str, float, float, Dict[str, Any]]
 
-    Analogue of ``NVTXContext`` (dist/utils.py:46-69).  On exit it
-    ``block_until_ready``-s ``sync_on`` (or nothing, measuring host time only)
-    so ``t.elapsed`` reflects device completion, then optionally prints.
-    """
 
-    def __init__(self, name: str, verbose: bool = False):
+class SpanRing(collections.deque):
+    """The closed spans of this process, newest last; the oldest drop off
+    a full ring.  ``clear()`` empties it."""
+
+    def snapshot(self) -> List[SpanRecord]:
+        return list(self)
+
+
+#: The process-wide ring every :class:`span` closes into.
+spans = SpanRing(maxlen=1 << 17)
+_ids = itertools.count(1)
+_open = threading.local()  # .top: the calling thread's innermost open span
+
+
+class span:
+    """``with span("tdp:engine.sched", tick=7) as sp: ...`` — a named host
+    range.  ``attrs`` are the identifiers and counts of that boundary; more
+    may be put into ``sp.attrs`` until the span closes.  On exit the record
+    goes to :data:`spans` and to the enclosing span's ``children``."""
+
+    __slots__ = ("name", "attrs", "id", "parent", "t0", "t1", "children",
+                 "_annot")
+
+    def __init__(self, name: str, **attrs: Any) -> None:
         self.name = name
-        self.verbose = verbose
-        self.elapsed: Optional[float] = None
-        self._sync_target = None
+        self.attrs = attrs
+        self.children: List[SpanRecord] = []
 
-    def sync_on(self, *arrays) -> None:
-        """Register outputs to block on before stopping the clock."""
-        self._sync_target = arrays
-
-    def __enter__(self) -> "TimedScope":
-        self._scope = jax.named_scope(self.name)
+    def __enter__(self) -> "span":
+        self.id = next(_ids)
+        self.parent = getattr(_open, "top", None)
+        _open.top = self
         self._annot = jax.profiler.TraceAnnotation(self.name)
-        self._scope.__enter__()
         self._annot.__enter__()
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        if self._sync_target is not None:
-            jax.block_until_ready(self._sync_target)
-        self.elapsed = time.perf_counter() - self._t0
+        self.t1 = time.perf_counter()
         self._annot.__exit__(*exc)
-        self._scope.__exit__(*exc)
-        if self.verbose:
-            from .logging import master_print
-
-            master_print(f"[{self.name}] {self.elapsed * 1e3:.3f} ms")
+        parent = _open.top = self.parent
+        rec = (self.id, parent.id if parent is not None else None, self.name,
+               self.t0, self.t1, self.attrs)
+        spans.append(rec)
+        if parent is not None:
+            parent.children.append(rec)
