@@ -116,15 +116,19 @@ def test_cp_prefill_token_parity(refs, fam, cp):
     """CP chunked prefill is bit-identical to the single-replica oracle,
     and the host-side ring ledger agrees with the hop/byte model."""
     ref = refs(fam)
-    s = _assert_parity(ref, _cp_engine(ref, cp), f"{fam} cp={cp}")
+    eng = _cp_engine(ref, cp)
+    eng.prefill_width = 1   # two prompts prefilling in one tick: two calls
+    s = _assert_parity(ref, eng, f"{fam} cp={cp}")
     cfg = ref["cfg"]
     assert s["decode_signatures"] == 1 and s["prefill_signatures"] == 1
     lc = s["long_context"]
     assert lc["cp"] == cp and lc["cp_axis"] == "context"
+    # the ring turns once a compiled CALL and carries that call's rows
+    assert lc["prefill_calls"] > lc["prefill_chunks"] > 0
     assert lc["ring_hops"] == \
-        lc["prefill_chunks"] * ring_hops_per_chunk(cfg.nlayers, cp)
-    assert lc["ring_bytes"] == lc["prefill_chunks"] * ring_chunk_bytes(
-        nlayers=cfg.nlayers, cp=cp, batch=2,
+        lc["prefill_calls"] * ring_hops_per_chunk(cfg.nlayers, cp)
+    assert lc["ring_bytes"] == lc["prefill_calls"] * ring_chunk_bytes(
+        nlayers=cfg.nlayers, cp=cp, batch=eng.prefill_width,
         kv_heads=cfg.block.kv_head_count, head_dim=cfg.block.head_dim,
         chunk=CHUNK, nb_local=16 // cp, block_size=BS, itemsize=4)
 
@@ -233,7 +237,7 @@ def test_cp_ring_hops_priced_per_hop(refs, devices8):
     ref = refs("dense")
     cfg = ref["cfg"]
     eng = _cp_engine(ref, 2)
-    B, C, mb = eng.num_slots, eng.chunk, eng.max_blocks
+    B, C, mb = eng.prefill_width, eng.chunk, eng.max_blocks  # the prefill call
     samp = {"temperature": jnp.zeros((B,), jnp.float32),
             "top_k": jnp.full((B,), cfg.vocab_size, jnp.int32),
             "top_p": jnp.ones((B,), jnp.float32)}

@@ -200,9 +200,9 @@ def test_example_runs_on_cpu_sim(script, tmp_path):
             assert lc["cp"] >= 2 and lc["cp_axis"], lc
             assert lc["prefill_chunks"] > 0, lc
             # every ring hop the engine booked is on the timeline's model:
-            # hops = chunks * 4 * (cp-1) * nlayers, bytes follow the pool
+            # hops = calls * 4 * (cp-1) * nlayers, bytes follow the pool
             assert lc["ring_hops"] > 0 and lc["ring_bytes"] > 0, lc
-            assert lc["ring_hops"] % lc["prefill_chunks"] == 0, lc
+            assert lc["ring_hops"] % lc["prefill_calls"] == 0, lc
             assert {"cp_prefill_chunk", "cp_ring_hop"} <= kinds, kinds
 
     if probe.get("router"):

@@ -34,6 +34,7 @@ from torchdistpackage_tpu.serving import (
     ServingEngine,
     init_paged_kv,
 )
+from torchdistpackage_tpu.utils import spans
 
 # One tiny config per family the acceptance bar names.  nlayers=2 keeps
 # compiles cheap; max_seq=32 keeps block tables narrow.
@@ -402,6 +403,165 @@ def test_paged_write_quant_bit_parity():
         np.asarray(g8[0, :, :6]), np.asarray(want_q[0].transpose(1, 0, 2)))
     np.testing.assert_array_equal(
         np.asarray(gs[0, :, :6]), np.asarray(want_s[0].T))
+
+
+# ------------------------------------------------ compact prefill batches
+
+
+def _full_width_calls(eng):
+    """The prefill call as wide as the decode batch, built from the same
+    ``_step_fn``: tokens ``[num_slots, chunk]``, every slot's own sampling
+    row and key, the tables of slots that are not prefilling masked to the
+    NULL block.  Stands in for ``eng._prefill_calls``: what every row the
+    compact batch leaves out would have computed, nobody read."""
+    def calls(pre):
+        B, C = eng.num_slots, eng.chunk
+        _, tables = eng._masked("prefill")
+        tokens = np.zeros((B, C), np.int32)
+        offsets = np.zeros(B, np.int32)
+        last_idx = np.zeros(B, np.int32)
+        for i in pre:
+            s = eng._slots[i]
+            sl = s.prompt[s.off:s.off + C]
+            tokens[i, :len(sl)] = sl
+            offsets[i] = s.off
+            last_idx[i] = min(len(s.prompt) - 1 - s.off, C - 1)
+        out = eng._step_fn(eng.params, eng.cache, tokens, tables, offsets,
+                           last_idx, eng._samp(), eng._keys)
+        eng.cache = out[0]
+        return np.asarray(out[1]), np.asarray(out[2])
+
+    return calls
+
+
+SLOTS4 = 4
+
+
+@pytest.fixture(scope="module")
+def compact_pairs():
+    """Per family and width: an engine whose prefill calls carry ``width``
+    of its 4 slots, and one whose prefill is the full-width reference call;
+    compiled once a module."""
+    cache = {}
+
+    def get(name, width):
+        if (name, width) not in cache:
+            cfg, params = CFGS[name], _init(name)
+            eng, ref = (ServingEngine(params, cfg, num_slots=SLOTS4,
+                                      block_size=4, chunk=4) for _ in "ab")
+            eng.prefill_width = width
+            ref._prefill_calls = _full_width_calls(ref)
+            cache[name, width] = (cfg, eng, ref)
+        return cache[name, width]
+
+    return get
+
+
+def _prompt(cfg, seed, n):
+    return np.asarray(jax.random.randint(
+        jax.random.PRNGKey(seed), (n,), 0, cfg.vocab_size)).tolist()
+
+
+def _serve_wave(eng, cfg, n_prefilling):
+    """``n_prefilling`` prompts admitted in ONE tick while the other slots
+    decode; returns every request's tokens and the attrs of the wave tick's
+    ``tdp:engine.prefill`` span."""
+    eng.reset_metrics()
+    early = [eng.submit(Request(_prompt(cfg, 40 + i, 3 + i), NEW + 4))
+             for i in range(SLOTS4 - n_prefilling)]
+    for _ in range(2 if early else 0):
+        eng.step()  # the early ones are decoding now
+    wave = [eng.submit(Request(_prompt(cfg, 50 + i, 2 + 2 * i), NEW))
+            for i in range(n_prefilling)]
+    spans.clear()
+    eng.step()
+    pre = [r[5] for r in spans.snapshot() if r[2] == "tdp:engine.prefill"]
+    _drain(eng)  # (the reference call opens no span)
+    return [eng.finished[r]["tokens"] for r in early + wave], pre
+
+
+def _serve_staggered(eng, cfg):
+    """Prompts longer than a chunk (3, 2 and 3 slices) admitted on
+    successive ticks: the prefilling set changes every tick."""
+    eng.reset_metrics()
+    rids = []
+    for i, n in enumerate((9, 6, 11)):
+        rids.append(eng.submit(Request(_prompt(cfg, 60 + i, n), NEW)))
+        eng.step()
+    _drain(eng)
+    return [eng.finished[r]["tokens"] for r in rids], []
+
+
+# width 1: a call never mixes a slot's row with padding; width 2: with 1 or
+# 3 slots prefilling the last call carries one of each (the MoE family's
+# padding rows are routed like any other, so this is its case)
+@pytest.mark.parametrize("family,width",
+                         [("dense", 1), ("dense", 2), ("moe", 2)])
+@pytest.mark.parametrize("scenario", [1, 3, SLOTS4, "staggered"])
+def test_compact_prefill_matches_full_width_call(compact_pairs, family, width,
+                                                 scenario):
+    """The compact prefill batch drops only rows whose output nobody read:
+    greedy tokens equal, token for token, those of the full-width call
+    through the same ``_step_fn``, with 1, 3 and all slots prefilling in a
+    tick and with multi-chunk prompts admitted on different ticks."""
+    cfg, eng, ref = compact_pairs(family, width)
+    serve = (_serve_staggered if scenario == "staggered"
+             else lambda e, c: _serve_wave(e, c, scenario))
+    got, wave_spans = serve(eng, cfg)
+    want, _ = serve(ref, cfg)
+    assert len(got) == len(want) >= 3
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(
+            g, w, err_msg=f"{family} width {width} {scenario}")
+    s = eng.serving_summary()
+    assert s["decode_signatures"] == 1 and s["prefill_signatures"] == 1
+    assert s["prefill_calls"] >= s["prefill_chunks"] > 0
+    assert len(wave_spans) == (scenario != "staggered")
+    for attrs in wave_spans:
+        calls = -(-scenario // width)
+        assert attrs["calls"] == calls
+        assert attrs["rows"] == calls * width * eng.chunk
+        assert attrs["tokens"] == sum(
+            min(eng.chunk, 2 + 2 * i) for i in range(scenario))
+    if family == "moe":
+        assert sum(s["moe"]["expert_tokens"]) > 0  # absorbed once a call
+
+
+def test_compact_prefill_unequal_dp_groups(bundles, devices8):
+    """Under ``dp_axis`` the compact batch is ``[dp * W, chunk]``, group
+    g's rows at ``g*W``: three prompts land two in group 0 and one in group
+    1, so the fuller group sets the number of calls and the other's row is
+    padding in the later ones.  Tokens equal the serial ``generate()``."""
+    b = bundles("gqa")
+    cfg = b["cfg"]
+    tpc.setup_process_groups(
+        [("data", 2), ("tensor", 2)], devices=devices8[:4])
+    mesh = tpc.get_view()
+    sharded = jax.tree.map(
+        lambda a, sp: jax.device_put(a, NamedSharding(mesh, sp)),
+        b["params"], gpt_param_specs(cfg, tp_axis="tensor"))
+    eng = ServingEngine(sharded, cfg, num_slots=4, block_size=4, chunk=4,
+                        mesh=mesh, axis="tensor", dp_axis="data")
+    assert eng.prefill_width == eng.slots_per_group == 2  # never wider
+    eng.prefill_width = W = 1
+    rows = (0, 1, 0)
+    rids = [eng.submit(Request(b["prompts"][r].tolist(), NEW)) for r in rows]
+    spans.clear()
+    eng.step()
+    assert [s.state for s in eng._slots] == ["prefill"] * 3 + ["free"]
+    (pre,) = [r for r in spans.snapshot() if r[2] == "tdp:engine.prefill"]
+    calls = -(-2 // W)  # group 0 holds two of the three
+    assert pre[5]["calls"] == calls
+    assert pre[5]["rows"] == calls * 2 * W * eng.chunk
+    assert pre[5]["tokens"] == 3 * eng.chunk
+    _drain(eng)
+    for rid, r in zip(rids, rows):
+        np.testing.assert_array_equal(
+            eng.finished[rid]["tokens"], b["want"][r],
+            err_msg="compact prefill under dp_axis diverged")
+    s = eng.serving_summary()
+    assert s["decode_signatures"] == 1 and s["prefill_signatures"] == 1
+    assert (s["prefill_chunks"], s["prefill_calls"]) == (2, 2 * calls)
 
 
 # ----------------------------------------------------------------- report

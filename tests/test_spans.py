@@ -175,11 +175,14 @@ def test_tick_children_do_not_overlap_and_phases_are_their_sums(params):
 def test_prefill_span_counts_real_tokens_against_dispatched_rows(params):
     spans.clear()
     eng = _engine(params)
+    eng.prefill_width = W = 2   # of the 4 slots: calls of [2, CHUNK] rows
     rid = eng.submit(Request(tokens=[1, 2, 3, 4, 5], max_new_tokens=2))
     eng.step()
     (pre,) = _by_name(spans.snapshot(), "tdp:engine.prefill")
     assert pre[5]["tokens"] == 5
-    assert pre[5]["rows"] == SLOTS * CHUNK == 32
+    # one slot prefills: one call of the compact width, not SLOTS * CHUNK rows
+    assert pre[5]["calls"] == 1
+    assert pre[5]["rows"] == pre[5]["calls"] * W * CHUNK == 16
     assert pre[5]["rids"] == [rid]
     (dec,) = _by_name(spans.snapshot(), "tdp:engine.decode")
     assert dec[5]["slots"] == 1 and dec[5]["rids"] == [rid]
@@ -189,6 +192,22 @@ def test_prefill_span_counts_real_tokens_against_dispatched_rows(params):
     eng.run_until_idle(max_ticks=20)
     assert [p[5]["tokens"] for p in
             _by_name(spans.snapshot(), "tdp:engine.prefill")] == [8, 3]
+    # more slots prefilling than one call carries: ceil(n / W) calls under
+    # ONE span and one fetch, the real tokens summed over them
+    spans.clear()
+    rids = [eng.submit(Request(tokens=[1] * (3 + i), max_new_tokens=1))
+            for i in range(W + 1)]
+    eng.step()
+    (pre,) = _by_name(spans.snapshot(), "tdp:engine.prefill")
+    assert pre[5]["calls"] == 2
+    assert len(_by_name(spans.snapshot(), "tdp:engine.fetch")) == 1
+    assert pre[5]["rows"] == 2 * W * CHUNK
+    assert pre[5]["tokens"] == sum(3 + i for i in range(W + 1))
+    assert pre[5]["rids"] == rids
+    s = eng.serving_summary()
+    assert s["prefill_signatures"] == 1
+    # ticks that prefilled (1 + 2 + 1) against compiled calls (1 + 2 + 2)
+    assert (s["prefill_chunks"], s["prefill_calls"]) == (4, 5)
 
 
 def test_first_marks_the_one_compiling_call_of_a_signature(params):

@@ -17,9 +17,13 @@ batching).  This engine is that scheduler, built TPU-first:
   depends on which requests are in flight, so there is no per-request
   retrace (``serving_summary()['decode_signatures']`` is the evidence).
 - **Chunked prefill.**  Prompts enter through the same paged forward in
-  ``chunk``-token slices, one slice per tick, batched across every
-  prefilling slot — a long prompt never stalls in-flight decodes for more
-  than one chunk's latency.  The final slice samples the first token
+  ``chunk``-token slices, one slice per tick for every prefilling slot —
+  a long prompt never stalls in-flight decodes for more than one chunk's
+  latency.  The compiled call carries only the slots that ARE prefilling
+  (a compact ``[dp * prefill_width, chunk]`` batch, ``ceil(n / W)`` calls
+  of that one signature when a tick has more; ``_prefill_batches``), so
+  an admission costs its own rows, not ``num_slots`` of them.  The final
+  slice samples the first token
   (per-slot ``last_idx`` picks the true last prompt row out of the padded
   chunk), which is also when TTFT stops ticking.
 - **Per-slot sampling.**  Temperature / top-k / top-p and the PRNG key are
@@ -165,6 +169,17 @@ FREE, PREFILL, DECODE = "free", "prefill", "decode"
 
 #: Drain-payload schema tag (ServingEngine.drain / .resume).
 DRAIN_SCHEMA = "tdp-engine-drain/v1"
+
+#: Slots (a dp group) that one compiled prefill call carries, at most (an
+#: engine with fewer slots a group carries them all): a tick with n slots
+#: prefilling makes ceil(n / W) calls of this ONE signature.  Every call
+#: pays a fixed price (one pass over the weights, and a copy of the KV pool
+#: that the step does not donate) and every row beyond the prefilling
+#: slots' is computed for nobody.  8: on a v5e a full wave of 64 admissions
+#: then costs about what one 64-slot call did (1.1 s against 0.95 s) and
+#: the usual lone admission a sixth of it (134 against 868 ms); PERF.md
+#: section 6, PR 25, has what 1, 2, 4 and 6 measured.
+PREFILL_WIDTH = 8
 
 
 @dataclasses.dataclass
@@ -511,6 +526,8 @@ class ServingEngine:
             raise ValueError(
                 f"num_slots {num_slots} not divisible by dp {self.dp}")
         self.slots_per_group = num_slots // self.dp
+        #: slots of a dp group in one compiled prefill call
+        self.prefill_width = min(PREFILL_WIDTH, self.slots_per_group)
         if num_blocks is None:
             num_blocks = 1 + self.slots_per_group * self.max_blocks
             if self.cp > 1:  # pool shards evenly over the context axis
@@ -610,9 +627,13 @@ class ServingEngine:
         return functools.partial(paged_forward, attn_impl=self.attn_impl)
 
     def _build_step(self) -> Callable:
-        """ONE python step serves both phases: S_in=1 calls are the decode
-        step, S_in=chunk calls the prefill-chunk step — two signatures of
-        the same program, compiled once each."""
+        """ONE python step serves both phases: ``[num_slots, 1]`` calls are
+        the decode step, ``[dp * prefill_width, chunk]`` calls the
+        prefill-chunk step (:meth:`_prefill_batches`: only slots that are
+        prefilling) — two signatures of the same program, compiled once
+        each.  The row count comes from ``tokens.shape[0]`` and the pool
+        is reached through ``tables`` alone, so nothing here is
+        ``num_slots`` wide."""
         cfg, axis = self.cfg, self.axis
         moe = bool(cfg.moe_experts)
         if self.cp_axis is not None:
@@ -1284,49 +1305,126 @@ class ServingEngine:
             "engine_recovered", fault="invalid_token", slot=i, rid=rid,
             action="requeued", tick=self._tick)
 
-    def _prefill_tick(self) -> int:
-        """One ``chunk``-token slice for EVERY prefilling slot, batched in
-        one compiled call.  Slots whose slice covers the last prompt row
-        sample their first token (TTFT) and move to DECODE."""
-        mask, tables = self._masked(PREFILL)
-        if not mask.any():
-            return 0
-        B, C = self.num_slots, self.chunk
-        tokens = np.zeros((B, C), np.int32)
-        offsets = np.zeros(B, np.int32)
-        last_idx = np.zeros(B, np.int32)
-        rids, real = [], 0
-        for i, s in enumerate(self._slots):
-            if s.state != PREFILL:
-                continue
-            sl = s.prompt[s.off:s.off + C]
-            tokens[i, :len(sl)] = sl
-            offsets[i] = s.off
-            last_idx[i] = min(len(s.prompt) - 1 - s.off, C - 1)
-            rids.append(s.rid)
-            real += len(sl)
-        first = self._first_call("prefill", tokens)
+    def _prefill_batches(self, pre: List[int]) -> List[Tuple[Any, ...]]:
+        """The prefilling slots ``pre`` packed into compact batches of
+        ``prefill_width`` slots a dp group: ``[dp * W, chunk]`` rows,
+        group g's at ``g*W..(g+1)*W`` (a slot's table indexes its own
+        group's pool shard), as many batches as the fullest group needs.  A
+        row with no slot is padding: NULL table, token 0, greedy, zero key,
+        so it writes the NULL block only and nobody reads its output.
+        Each batch is ``(slot_of, step_args)``: ``slot_of[r]`` is the slot
+        that compact row r carries, -1 for padding."""
+        W, C, G = self.prefill_width, self.chunk, self.slots_per_group
+        groups = [[i for i in pre if i // G == g] for g in range(self.dp)]
+        batches = []
+        for c in range(max(-(-len(g) // W) for g in groups)):
+            slot_of = np.full(self.dp * W, -1)
+            for g, members in enumerate(groups):
+                part = members[c * W:(c + 1) * W]
+                slot_of[g * W:g * W + len(part)] = part
+            live = slot_of >= 0
+            slots = slot_of[live]
+
+            def rows(src: np.ndarray, fill: Any = 0) -> np.ndarray:
+                out = np.full(live.shape + src.shape[1:], fill, src.dtype)
+                out[live] = src[slots]
+                return out
+
+            tokens = np.zeros((len(live), C), np.int32)
+            offsets = np.zeros(len(live), np.int32)
+            last_idx = np.zeros(len(live), np.int32)
+            for r, i in zip(np.flatnonzero(live), slots):
+                s = self._slots[i]
+                sl = s.prompt[s.off:s.off + C]
+                tokens[r, :len(sl)] = sl
+                offsets[r] = s.off
+                last_idx[r] = min(len(s.prompt) - 1 - s.off, C - 1)
+            samp = {"temperature": rows(self._temps),
+                    "top_k": rows(self._top_k, self.cfg.vocab_size),
+                    "top_p": rows(self._top_p, 1.0)}
+            batches.append((slot_of, (
+                tokens, rows(self._tables), offsets, last_idx, samp,
+                rows(self._keys))))
+        return batches
+
+    def _prefill_calls(self, pre: List[int]) -> Tuple[np.ndarray, np.ndarray]:
+        """One ``chunk``-token slice for every slot of ``pre`` through the
+        compiled step: ``ceil(n / W)`` calls of the ONE compact signature
+        (:meth:`_prefill_batches`), then the one fetch.  Returns the
+        sampled tokens and the advanced keys by SLOT index (``[num_slots]``;
+        rows of slots not in ``pre`` are zero)."""
+        C = self.chunk
+        batches = self._prefill_batches(pre)
+        real = sum(min(C, len(self._slots[i].prompt) - self._slots[i].off)
+                   for i in pre)
+        rids = [self._slots[i].rid for i in pre]
+        first = self._first_call("prefill", batches[0][1][0])
+        outs = []
         # tokens: the real prompt tokens of this tick's slices; rows: what
-        # the compiled call computes whatever the number of slots prefilling
-        with span("tdp:engine.prefill", tokens=real, rows=B * C, rids=rids,
-                  **first):
-            out = self._step_fn(
-                self.params, self.cache, tokens, tables, offsets, last_idx,
-                self._samp(), self._keys)
-            if len(out) == 5:  # MoE family: live expert-load stats ride along
-                self.cache, tok, keys, moe_et, moe_dr = out
-                self._absorb_moe_stats(moe_et, moe_dr)
-            else:
-                self.cache, tok, keys = out
+        # the compiled calls compute, padding included
+        with span("tdp:engine.prefill", tokens=real, calls=len(batches),
+                  rows=sum(args[0].size for _, args in batches),
+                  rids=rids, **first):
+            for _, args in batches:
+                if outs:
+                    # one call in flight: the step does not donate the
+                    # pool, so a call queued behind a running one holds it
+                    # a third time (+1.6 GB at 64 x 768 on a v5e)
+                    jax.block_until_ready(outs[-1][0])
+                out = self._step_fn(self.params, self.cache, *args)
+                self.cache = out[0]
+                outs.append(out[1:])
+        tok = np.zeros(self.num_slots, np.int32)
+        keys = np.zeros_like(self._keys)
         with span("tdp:engine.fetch", **first):
-            tok = np.asarray(tok)
-            keys = np.asarray(keys)
+            for (slot_of, _), out in zip(batches, outs):
+                live = slot_of >= 0
+                tok[slot_of[live]] = np.asarray(out[0])[live]
+                keys[slot_of[live]] = np.asarray(out[1])[live]
+                if len(out) == 4:  # MoE family: expert-load stats ride along
+                    self._absorb_moe_stats(out[2], out[3])
+        self.stats["prefill_calls"] += len(batches)
+        self._tick_prefill_rids = rids
+        self._ev.emit("prefill_chunk", rids=rids, chunk=C, n_slots=len(rids),
+                      calls=len(batches))
+        if self.cp > 1:
+            # modeled ring accounting (host math, ops/ring_paged.py): each
+            # compiled call issued 4*(cp-1) unrolled ppermutes per layer
+            # — the comm-ledger test prices the same count from HLO
+            from ..ops.ring_paged import ring_chunk_bytes, ring_hops_per_chunk
+
+            hops = len(batches) * ring_hops_per_chunk(self.cfg.nlayers, self.cp)
+            bts = len(batches) * ring_chunk_bytes(
+                nlayers=self.cfg.nlayers, cp=self.cp,
+                batch=len(batches[0][0]),
+                kv_heads=self.cfg.block.kv_head_count,
+                head_dim=self.cfg.block.head_dim, chunk=C,
+                nb_local=self.num_blocks // self.cp,
+                block_size=self.block_size,
+                itemsize=jnp.dtype(self.cfg.dtype).itemsize)
+            self.stats["cp_ring_hops"] += hops
+            self.stats["cp_ring_bytes"] += bts
+            self._ev.emit("cp_prefill_chunk", rids=rids, chunk=C,
+                          cp=self.cp, sub_chunk=C // self.cp)
+            self._ev.emit("cp_ring_hop", tick=self._tick, hops=hops,
+                          bytes=bts)
+        return tok, keys
+
+    def _prefill_tick(self) -> int:
+        """One ``chunk``-token slice for EVERY prefilling slot
+        (:meth:`_prefill_calls`: only those slots' rows are computed).
+        Slots whose slice covers the last prompt row sample their first
+        token (TTFT) and move to DECODE."""
+        pre = [i for i, s in enumerate(self._slots) if s.state == PREFILL]
+        if not pre:
+            return 0
+        C = self.chunk
+        tok, keys = self._prefill_calls(pre)
         if self.chaos is not None:
             tok = self.chaos.perturb_engine_tokens(self._tick, tok)
         now = time.perf_counter()
-        for i, s in enumerate(self._slots):
-            if s.state != PREFILL:
-                continue
+        for i in pre:
+            s = self._slots[i]
             s.off += C
             if s.off >= len(s.prompt):  # final slice: first token sampled
                 if self._token_poisoned(int(tok[i])):
@@ -1354,30 +1452,7 @@ class ServingEngine:
                 self._tick_emitted += 1
                 self._maybe_retire(i, int(tok[i]), now)
         self.stats["prefill_chunks"] += 1
-        self._tick_prefill_rids = rids
-        self._ev.emit("prefill_chunk", rids=rids, chunk=C,
-                      n_slots=len(rids))
-        if self.cp > 1:
-            # modeled ring accounting (host math, ops/ring_paged.py): the
-            # compiled chunk issued 4*(cp-1) unrolled ppermutes per layer
-            # — the comm-ledger test prices the same count from HLO
-            from ..ops.ring_paged import ring_chunk_bytes, ring_hops_per_chunk
-
-            hops = ring_hops_per_chunk(self.cfg.nlayers, self.cp)
-            bts = ring_chunk_bytes(
-                nlayers=self.cfg.nlayers, cp=self.cp, batch=self.num_slots,
-                kv_heads=self.cfg.block.kv_head_count,
-                head_dim=self.cfg.block.head_dim, chunk=C,
-                nb_local=self.num_blocks // self.cp,
-                block_size=self.block_size,
-                itemsize=jnp.dtype(self.cfg.dtype).itemsize)
-            self.stats["cp_ring_hops"] += hops
-            self.stats["cp_ring_bytes"] += bts
-            self._ev.emit("cp_prefill_chunk", rids=rids, chunk=C,
-                          cp=self.cp, sub_chunk=C // self.cp)
-            self._ev.emit("cp_ring_hop", tick=self._tick, hops=hops,
-                          bytes=bts)
-        return len(rids)
+        return len(pre)
 
     def _decode_tick(self) -> int:
         if self.hold_decode:
@@ -2346,7 +2421,8 @@ class ServingEngine:
         """Zero the serving metrics (the bench's warmup/measure split);
         compiled steps, pool, and queue state are untouched."""
         self.stats = {"decode_steps": 0, "prefill_chunks": 0,
-                      "decode_slot_steps": 0, "generated_tokens": 0,
+                      "prefill_calls": 0, "decode_slot_steps": 0,
+                      "generated_tokens": 0,
                       "shed": 0, "expired": 0, "cancelled": 0,
                       "preempted": 0, "resumed": 0, "faults_detected": 0,
                       "faults_healed": 0, "audits": 0,
@@ -2596,12 +2672,14 @@ class ServingEngine:
                 "max_ctx": self.max_ctx,
                 "chunk": self.chunk,
                 "prefill_chunks": st["prefill_chunks"],
+                "prefill_calls": st["prefill_calls"],
                 "ring_hops": st["cp_ring_hops"],
                 "ring_bytes": st["cp_ring_bytes"],
             }} if self.cp_axis is not None else {}),
             **({"moe": moe} if moe is not None else {}),
             "decode_steps": st["decode_steps"],
             "prefill_chunks": st["prefill_chunks"],
+            "prefill_calls": st["prefill_calls"],
             "decode_batch_mean": (
                 st["decode_slot_steps"] / st["decode_steps"]
                 if st["decode_steps"] else 0.0),
