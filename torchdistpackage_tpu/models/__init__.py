@@ -42,6 +42,13 @@ from .gpt_moe import (
     moe_stage_pattern,
     stack_moe_stage_params,
 )
+from .hybrid import (
+    HybridConfig,
+    hybrid_paged_forward,
+    init_hybrid_params,
+    init_state,
+    mamba2_mixer,
+)
 from .vit import (
     ViTConfig,
     init_vit_params,

@@ -87,8 +87,29 @@ class MoEConfig:
     dispatch: str = "auto"
     # Expert FFN activation: 'gelu' | 'swiglu' (stacked [E, 2, D, F]
     # gate/up — the Mixtral-style expert; structural dispatch on w1.ndim,
-    # mirroring the dense MLP's convention in tensor_parallel/layers.py).
+    # mirroring the dense MLP's convention in tensor_parallel/layers.py) |
+    # 'relu2' (non-gated squared ReLU, no biases: serving path only).
     act: str = "gelu"
+    # --- the sigmoid-router / latent-expert family (serving path only,
+    # :func:`moe_serve_forward`; docs/serving.md "State models"):
+    # 'softmax' (Mixtral: probabilities over all experts, top-k kept and
+    # renormalized) | 'sigmoid' (one score an expert in float32; the top-k
+    # of score + the router's ``bias`` leaf is chosen, the bias does not
+    # enter the weights; weights = score / sum of the chosen scores x
+    # ``routed_scale``)
+    score: str = "softmax"
+    routed_scale: float = 1.0
+    # experts work in a latent of this width: ``latent.down`` [D, latent]
+    # before the dispatch, ``latent.up`` [latent, D] after the combine
+    latent_dim: Optional[int] = None
+    # a shared expert at full width (0 = none), same activation, every token
+    shared_ffn: int = 0
+    # ``(first, count)``: the experts THIS device holds.  The router keeps
+    # its ``num_experts`` outputs and its top-k; the stacked expert leaves
+    # are ``[count, ...]``; assignments to experts not held are left out
+    # and the partial result goes on (one chip's share of an EP layer, run
+    # without its exchange).  None = all of them.
+    held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         if self.router not in ("topk", "expert_choice"):
@@ -100,8 +121,20 @@ class MoEConfig:
                 "dispatch='pallas' consumes a _top_k_route decision; the "
                 "expert_choice router has no (gate_idx, slot, keep) form — "
                 "use dispatch='dense'/'sorted'/'auto' with it")
-        if self.act not in ("gelu", "swiglu"):
+        if self.act not in ("gelu", "swiglu", "relu2"):
             raise ValueError(f"unknown MoE act {self.act!r}")
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown MoE router score {self.score!r}")
+        if self.held is not None:
+            first, count = self.held
+            if not (0 <= first and count >= 1
+                    and first + count <= self.num_experts):
+                raise ValueError(
+                    f"held experts {self.held} outside 0..{self.num_experts}")
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return (0, self.num_experts) if self.held is None else self.held
 
 
 # ------------------------------------------------------------------ dispatch
@@ -343,6 +376,12 @@ def moe_forward(
     """
     B, S, D = x.shape
     T = B * S
+    if (cfg.score != "softmax" or cfg.latent_dim or cfg.shared_ffn
+            or cfg.held is not None or cfg.act == "relu2"):
+        raise NotImplementedError(
+            "the sigmoid-router / latent / held-range expert layer has a "
+            "serving path only (moe_serve_forward); its training-side "
+            "router and aux loss are ROADMAP queue 2 A1")
     E = cfg.num_experts
     tokens = x.reshape(T, D)
 
@@ -495,12 +534,68 @@ def moe_forward(
     return out + (metrics,) if return_metrics else out
 
 
+def _serve_route(router: Dict[str, jnp.ndarray], tokens: jnp.ndarray,
+                 cfg: MoEConfig):
+    """tokens [T, D] -> (probs [T, E], gate_vals [T, k], gate_idx [T, k]).
+    ``score='softmax'`` is the Mixtral decision (kept operation for
+    operation); ``'sigmoid'`` scores in float32, chooses by score + bias and
+    weighs by the chosen scores alone."""
+    k = cfg.top_k
+    if cfg.score == "softmax":
+        probs = jax.nn.softmax(
+            (tokens @ router["w"]).astype(jnp.float32), axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [T, k]
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+        return probs, gate_vals, gate_idx
+    scores = jax.nn.sigmoid(jnp.dot(
+        tokens, router["w"], preferred_element_type=jnp.float32))
+    _, gate_idx = jax.lax.top_k(
+        scores + router["bias"].astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, gate_idx, axis=-1)
+    gate_vals = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return scores, gate_vals * cfg.routed_scale, gate_idx
+
+
+def _relu2(x: jnp.ndarray) -> jnp.ndarray:
+    return jnp.square(jax.nn.relu(x))
+
+
+#: A call of at most this many tokens (a decode call: one a slot) runs the
+#: relu2 experts as ONE batched matmul over every held expert at the exact
+#: no-drop capacity C = T (a token takes an expert at most once), not as
+#: ``ragged_dot`` groups.  At decode the layer is bound by the experts'
+#: weights, and the batched form reads them once at 89% of the HBM peak
+#: (1.9 ms for 128 experts of [1024, 2688], both GEMMs) where the grouped
+#: GEMM took 4.2-5.1 ms AND as long as the experts its rows touch, which
+#: moved a run's rate by 2.4% between seeds (PERF.md, PR 26).  Its padded
+#: compute grows as held x T rows: past this size the grouped GEMM wins.
+_BATCHED_EXPERTS_MAX_TOKENS = 128
+
+
+def _batched_relu2_experts(ex, rows, sorted_expert, group_sizes, T: int):
+    """``rows`` [R, d] sorted by expert (``sorted_expert`` [R]; values past
+    the last group are no expert's) -> the experts' outputs, row for row
+    ([R, d]; zero for a row of no expert).  Every expert gets ``T`` slots;
+    row r sits in slot ``r - start of its group``."""
+    n, d = ex["w1"].shape[0], rows.shape[-1]
+    starts = jnp.cumsum(group_sizes) - group_sizes
+    held = sorted_expert < n
+    pos = jnp.arange(rows.shape[0]) - starts[jnp.minimum(sorted_expert, n - 1)]
+    slot = jnp.where(held, sorted_expert * T + pos, n * T)   # n * T: nowhere
+    xe = jnp.zeros((n * T, d), rows.dtype).at[slot].set(rows, mode="drop")
+    h = _relu2(jnp.einsum("ecd,edf->ecf", xe.reshape(n, T, d), ex["w1"]))
+    out = jnp.einsum("ecf,efd->ecd", h, ex["w2"]).reshape(n * T, d)
+    return out.at[slot].get(mode="fill", fill_value=0)
+
+
 def moe_serve_forward(
     params: Dict[str, PyTree],
     x: jnp.ndarray,
     cfg: MoEConfig,
     dispatch: Optional[str] = None,
     return_metrics: bool = False,
+    valid: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Serving-time MoE FFN: EXACT no-drop routing with ragged grouped
     matmuls — zero capacity padding (VERDICT r4 weak #5: training-style
@@ -533,12 +628,29 @@ def moe_serve_forward(
     never materializes).  ``return_metrics=True`` appends the per-expert
     routed-token counts ({'expert_tokens', 'dropped_token_rate'} — rate
     identically 0 here, both paths are no-drop) for the engine's live
-    ``moe`` load signal."""
+    ``moe`` load signal.
+
+    The sigmoid-router / latent family (``MoEConfig.score`` /
+    ``latent_dim`` / ``shared_ffn`` / ``held`` / ``act='relu2'``) rides the
+    same ragged path: the router scores all ``num_experts``, the rows go
+    down to the latent before the sort, the ``held`` experts' groups run,
+    rows assigned to an expert that is not held sort behind every group
+    and count for nothing, the combine goes up from the latent, and the
+    shared expert adds its full-width part.  Its metrics count the held
+    experts only and add ``rows_routed`` / ``rows_held`` /
+    ``experts_touched`` and ``gate_idx`` ([B, S, k]: the experts each
+    position chose, of all ``num_experts``); ``valid`` [B, S] names the
+    real positions: a padding row's choices are held by no expert here, so
+    they sort behind every group with the absent experts' and cost the
+    grouped matmul nothing (a compact prefill call is mostly padding, all of
+    it the same token: its rows fell on the same few experts, a different
+    few with every seed's weights)."""
     if cfg.router != "topk":
         raise NotImplementedError(
             f"moe_serve_forward supports router='topk' (got {cfg.router!r})")
     B, S, D = x.shape
     T, E, k = B * S, cfg.num_experts, cfg.top_k
+    first, n_held = cfg.held_range
     tokens = x.reshape(T, D)
 
     disp = cfg.dispatch if dispatch is None else dispatch
@@ -547,23 +659,47 @@ def moe_serve_forward(
 
         disp = resolve_moe_dispatch(disp)
 
-    probs = jax.nn.softmax(
-        (tokens @ params["router"]["w"]).astype(jnp.float32), axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [T, k]
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    probs, gate_vals, gate_idx = _serve_route(params["router"], tokens, cfg)
+    # the expert of each choice as this device numbers the ones it holds;
+    # ``n_held`` = not held (sorts last, belongs to no group)
+    local_idx = gate_idx
+    if cfg.held is not None:
+        here = (gate_idx >= first) & (gate_idx < first + n_held)
+        if valid is not None:
+            here &= valid.reshape(T, 1)
+        local_idx = jnp.where(here, gate_idx - first, n_held)
 
     def _with_metrics(y: jnp.ndarray):
         if not return_metrics:
             return y
+        if cfg.held is None and valid is None:
+            counts = jnp.bincount(gate_idx.reshape(-1), length=E)
+        else:
+            w = (jnp.ones((T,), jnp.int32) if valid is None
+                 else valid.reshape(T).astype(jnp.int32))
+            counts = jnp.bincount(
+                local_idx.reshape(-1), weights=jnp.repeat(w, k),
+                length=n_held + 1)[:n_held]
         metrics = {
-            "expert_tokens": jnp.bincount(
-                gate_idx.reshape(-1), length=E).astype(jnp.float32),
+            "expert_tokens": counts.astype(jnp.float32),
             "dropped_token_rate": jnp.zeros((), jnp.float32),
         }
+        if cfg.held is not None:
+            metrics["rows_routed"] = (
+                jnp.float32(T * k) if valid is None
+                else jnp.sum(valid).astype(jnp.float32) * k)
+            metrics["rows_held"] = jnp.sum(counts).astype(jnp.float32)
+            metrics["experts_touched"] = jnp.sum(counts > 0).astype(
+                jnp.float32)
+            metrics["gate_idx"] = gate_idx.reshape(B, S, k)
         return y, metrics
 
     if disp == "pallas":
+        if cfg.score != "softmax" or cfg.held is not None or (
+                cfg.latent_dim or cfg.shared_ffn or cfg.act == "relu2"):
+            raise NotImplementedError(
+                "the fused dispatch kernel computes the Mixtral-shaped "
+                "layer only")
         from ..ops.moe_dispatch import fused_moe_ffn
 
         # C = T is the static no-drop bound (a token holds at most one
@@ -574,28 +710,54 @@ def moe_serve_forward(
         y = fused_moe_ffn(params["experts"], tokens, gv, gi, slot, keep, T)
         return _with_metrics(y.reshape(B, S, D).astype(x.dtype))
 
-    flat_expert = gate_idx.reshape(-1)  # [T*k] token-major
+    src = tokens
+    if cfg.latent_dim:
+        src = tokens @ params["latent"]["down"]
+    flat_expert = local_idx.reshape(-1)  # [T*k] token-major
     order = jnp.argsort(flat_expert, stable=True)
     sorted_tok = (order // k).astype(jnp.int32)  # token of each sorted row
     sorted_expert = flat_expert[order]
-    rows = tokens[sorted_tok]  # [T*k, D] gather, expert-grouped
-    group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
+    rows = src[sorted_tok]  # [T*k, D] gather, expert-grouped
+    # with a held range there is one more bin, for the rows of no expert
+    # here; it is counted and cut off, so the groups end before those rows
+    group_sizes = jnp.bincount(
+        flat_expert, length=n_held + (cfg.held is not None)
+    )[:n_held].astype(jnp.int32)
 
     ex = params["experts"]
     if ex["w1"].ndim == 4:  # swiglu: [E, 2, D, F] stacked gate/up
         F = ex["w1"].shape[-1]
-        w1 = ex["w1"].transpose(0, 2, 1, 3).reshape(E, D, 2 * F)
+        w1 = ex["w1"].transpose(0, 2, 1, 3).reshape(-1, D, 2 * F)
         gu = jax.lax.ragged_dot(rows, w1, group_sizes)
-        gu = gu + ex["b1"].reshape(E, 2 * F)[sorted_expert]
+        gu = gu + ex["b1"].reshape(-1, 2 * F)[sorted_expert]
         h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
+    elif cfg.act == "relu2":  # no gate, no biases
+        h = None if T <= _BATCHED_EXPERTS_MAX_TOKENS else _relu2(
+            jax.lax.ragged_dot(rows, ex["w1"], group_sizes))
     else:
         h = jax.lax.ragged_dot(rows, ex["w1"], group_sizes)
         h = jax.nn.gelu(h + ex["b1"][sorted_expert])
-    out = jax.lax.ragged_dot(h, ex["w2"], group_sizes)
-    out = out + ex["b2"][sorted_expert]
+    if h is None:  # a small call: every held expert in one batched matmul
+        out = _batched_relu2_experts(ex, rows, sorted_expert, group_sizes, T)
+    else:
+        out = jax.lax.ragged_dot(h, ex["w2"], group_sizes)
+    if "b2" in ex:
+        out = out + ex["b2"][sorted_expert]
 
     g = gate_vals.reshape(-1)[order].astype(out.dtype)
-    y = jnp.zeros((T, D), out.dtype).at[sorted_tok].add(g[:, None] * out)
+    if cfg.held is not None:
+        # rows behind the last group are no expert's: whatever the grouped
+        # matmul left there counts for nothing
+        kept = sorted_expert < n_held
+        out = jnp.where(kept[:, None], out, 0)
+        g = jnp.where(kept, g, 0)
+    y = jnp.zeros((T, out.shape[-1]), out.dtype).at[sorted_tok].add(
+        g[:, None] * out)
+    if cfg.latent_dim:
+        y = y @ params["latent"]["up"]
+    if cfg.shared_ffn:
+        sh = params["shared"]
+        y = y + _relu2(tokens @ sh["w1"]) @ sh["w2"]
     return _with_metrics(y.reshape(B, S, D).astype(x.dtype))
 
 

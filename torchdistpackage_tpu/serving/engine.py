@@ -286,7 +286,7 @@ class _SlotState:
 
     __slots__ = ("state", "rid", "req", "blocks", "prompt", "off",
                  "generated", "t_submit", "t_admit", "t_last", "ttft_s",
-                 "tpot_s", "orig_prompt_len", "pre_gen")
+                 "tpot_s", "orig_prompt_len", "pre_gen", "routing")
 
     def __init__(self) -> None:
         self.reset()
@@ -304,6 +304,8 @@ class _SlotState:
         self.tpot_s: List[float] = []
         self.orig_prompt_len = 0
         self.pre_gen = 0
+        #: ``record_routing``: one [positions, E-layers, k] piece a call
+        self.routing: List[np.ndarray] = []
 
 
 class ServingEngine:
@@ -378,6 +380,26 @@ class ServingEngine:
         audit, and event timeline, zero compilation — what
         ``tools/trace_replay.py`` and the compile-free policy tests run
         on.  A host-only step cannot be combined with a mesh.
+    record_routing: a state model with expert layers only: every call also
+        returns the experts each position chose, and a finished request
+        carries them (``finished[rid]['routing']``: int16 [fed positions,
+        expert layers, top_k], the last token is never fed).  A top-k
+        choice is discontinuous, so a reference in another precision can
+        only be held to the program's logits along the program's own
+        choices; this is what lets it follow them.
+    run_ahead: a state model only: the decode call of a tick is dispatched
+        BEFORE the call of the tick before it is fetched.  A slot whose
+        newest token is still on the device is fed it (and its sampling
+        key) from there, so the host's walk over the slots, its tick
+        record, the caller's loop, audit, admission and the building of
+        the next call's arrays run while the device computes and not
+        between its calls.  Every sequence's tokens are the ones the
+        unpipelined engine gives; what the host sees (``finished``, the
+        tick records' ``emitted_tokens``) lags one decode call, so a slot
+        freed by a retirement is filled one tick later.  A slot whose
+        in-flight token is its last by count sits the next call out; one
+        that ends on ``eos_id``, is cancelled, preempted or requeued with a
+        token in flight has that token dropped when it arrives.
     """
 
     @scope_decorator(name="tdp:engine.init")
@@ -411,6 +433,8 @@ class ServingEngine:
         metrics_every: int = 1,
         tick_history: int = 4096,
         device_step: Optional[Any] = None,
+        record_routing: bool = False,
+        run_ahead: bool = False,
     ) -> None:
         if (axis is not None or dp_axis is not None) and mesh is None:
             raise ValueError("axis/dp_axis need a mesh")
@@ -422,6 +446,39 @@ class ServingEngine:
                 "serving: pass cp_axis= for sequence-sharded (ring paged) "
                 "prefill over the block pool, or decode a CP-trained "
                 "checkpoint with attn_impl='flash', context_axis=None")
+        #: a model some of whose layers keep a recurrent state per sequence
+        #: instead of keys and values (models/hybrid.py); docs/serving.md
+        #: "State models"
+        self.state_model = bool(getattr(cfg, "state_layers", 0))
+        if self.state_model:
+            for on, what in ((prefix_cache, "prefix_cache"),
+                             (spec_k, "spec_k"), (cp_axis, "cp_axis"),
+                             (mesh, "a mesh (tp/dp/ep)")):
+                if on:
+                    raise NotImplementedError(
+                        f"{what} with a state model is not supported: a "
+                        f"recurrent state cannot be shared by prefix, "
+                        f"rolled back after a rejected draft or split over "
+                        f"devices without per-position SNAPSHOTS of it, "
+                        f"which the engine does not keep yet (ROADMAP "
+                        f"queue 2 A4)")
+            if record_routing and not cfg.moe_experts:
+                raise ValueError("record_routing: the model has no "
+                                 "expert layers")
+            q = cfg.ssm_chunk
+            if chunk > q and chunk % q:
+                raise ValueError(
+                    f"chunk ({chunk}) must be at most the model's "
+                    f"recurrence chunk ({q}) or a multiple of it")
+        elif record_routing or run_ahead:
+            raise NotImplementedError(
+                "record_routing and run_ahead are written for the state "
+                "model's step only")
+        self.record_routing = bool(record_routing)
+        self.run_ahead = bool(run_ahead)
+        #: run_ahead: the decode call whose outputs are still on the device
+        #: (:meth:`_absorb_decode` books them one tick later)
+        self._flight: Optional[Dict[str, Any]] = None
         if cp_axis is not None:
             if mesh is None:
                 raise ValueError("cp_axis needs a mesh")
@@ -554,6 +611,19 @@ class ServingEngine:
         device_step.bind(self)
         with span("tdp:engine.init.pool"):
             self.cache = device_step.init_cache()
+        #: state models: the recurrent state, one row a slot, beside the
+        #: pool (``models.hybrid.init_state``); the compiled step is handed
+        #: it as a donated argument and the engine keeps what comes back
+        self.state = None
+        self.state_bytes = 0
+        if self.state_model:
+            with span("tdp:engine.init.state"):
+                self.state = device_step.init_state()
+            self.state_bytes = int(cfg.state_bytes(num_slots))
+        #: run_ahead's first decode call: no call before it to take from
+        self._no_flight = {"out": (jnp.zeros(num_slots, jnp.int32),
+                                   jnp.zeros((num_slots, 2), jnp.uint32))
+                           } if self.run_ahead else None
 
         # host-visible device state, one row per slot
         V = cfg.vocab_size
@@ -589,6 +659,7 @@ class ServingEngine:
         self._tick_prefill_rids: List[int] = []
         self._tick_decode_rids: List[int] = []
         self._tick_emitted = 0
+        self._tick_moe = [0.0, 0.0, 0.0]
         self._pending_cow: List[Tuple[int, int, int]] = []  # slot, src, dst
         wrap = (telemetry is not None
                 and getattr(device_step, "wrap_steps", True))
@@ -638,6 +709,8 @@ class ServingEngine:
         moe = bool(cfg.moe_experts)
         if self.cp_axis is not None:
             return self._build_cp_step()
+        if self.state_model:
+            return self._build_state_step()
         fwd = self._fwd(moe_stats=moe)
 
         def step(params, cache, tokens, tables, offsets, last_idx, samp, keys):
@@ -673,6 +746,74 @@ class ServingEngine:
         if self.mesh is None:
             return jax.jit(step)
         return self._mesh_step(step)
+
+    def _build_state_step(self) -> Callable:
+        """:meth:`_build_step` for a state model: the same two signatures,
+        with the recurrent ``state`` as a DONATED argument after the pool
+        (each Mamba layer's array is updated where it lies: it is never
+        held twice, as the pool is) and two more row vectors at the end:
+        ``rows`` (None: row b is slot b, the decode call; else the slot
+        whose state each compact prefill row carries) and ``n_valid`` (the
+        real positions of each row: padding advances no state).  The
+        expert layers' counters always ride along, as the MoE family's
+        do, plus ``[rows routed, rows on held experts, experts touched]``
+        and, with ``record_routing``, every position's chosen experts.
+        ``prev`` (``run_ahead``'s decode call): ``(tok, keys, take)``, the
+        call before's sampled tokens and advanced keys as they lie on the
+        device, and the rows that take theirs from there."""
+        from .paged_cache import paged_forward_hybrid
+
+        cfg, attn_impl = self.cfg, self.attn_impl
+        record = self.record_routing
+        held = cfg.moe.held_range[1] if cfg.moe_experts else 1
+
+        def step(params, cache, state, tokens, tables, offsets, last_idx,
+                 samp, keys, rows, n_valid, prev=None):
+            if prev is not None:
+                take = prev[2][:, None] > 0
+                tokens = jnp.where(take, prev[0][:, None], tokens)
+                keys = jnp.where(take, prev[1], keys)
+            cache, state, logits, m = paged_forward_hybrid(
+                params, tokens, cfg, cache, state, tables, offsets, n_valid,
+                rows=rows, last_idx=last_idx, attn_impl=attn_impl)
+            keys, sub = _split_keys(keys)
+            tok = _slot_sample(logits, sub, samp["temperature"],
+                               samp["top_k"], samp["top_p"])
+            if m is None:
+                m = {"expert_tokens": jnp.zeros((held,), jnp.float32),
+                     "dropped_token_rate": jnp.zeros((), jnp.float32)}
+            share = jnp.stack([m.get(k, jnp.zeros((), jnp.float32)) for k in
+                               ("rows_routed", "rows_held",
+                                "experts_touched")])
+            out = (cache, state, tok, keys, m["expert_tokens"][None, :],
+                   m["dropped_token_rate"][None], share)
+            if record:
+                out += (m["routing"].astype(jnp.int16),)
+            return out
+
+        return jax.jit(step, donate_argnums=(2,))
+
+    def _dispatch(self, fn: Callable, args: Tuple[Any, ...]) -> Tuple[Any, ...]:
+        """One call of a compiled step.  Keeps the pool (and a state
+        model's state) that it returns, and hands back the rest: ``(tok,
+        keys)`` and, where the model has expert layers, their counters."""
+        if self.state is None:
+            out = fn(self.params, self.cache, *args)
+        else:
+            out = fn(self.params, self.cache, self.state, *args)
+            self.state, out = out[1], out[:1] + out[2:]
+        self.cache = out[0]
+        return out[1:]
+
+    def _needs_snapshots(self, what: str) -> None:
+        """A state model's requests cannot leave the engine mid-flight: the
+        recurrent state would have to travel with them."""
+        if self.state_model:
+            raise NotImplementedError(
+                f"{what} with a state model is not supported: the "
+                f"request's recurrent state would have to be snapshotted "
+                f"and carried, which the engine does not do yet (ROADMAP "
+                f"queue 2 A4)")
 
     def _build_cp_step(self) -> Callable:
         """The ring-paged step (docs/long_context.md "CP prefill
@@ -1333,18 +1474,25 @@ class ServingEngine:
             tokens = np.zeros((len(live), C), np.int32)
             offsets = np.zeros(len(live), np.int32)
             last_idx = np.zeros(len(live), np.int32)
+            n_valid = np.zeros(len(live), np.int32)
             for r, i in zip(np.flatnonzero(live), slots):
                 s = self._slots[i]
                 sl = s.prompt[s.off:s.off + C]
                 tokens[r, :len(sl)] = sl
                 offsets[r] = s.off
                 last_idx[r] = min(len(s.prompt) - 1 - s.off, C - 1)
+                n_valid[r] = len(sl)
             samp = {"temperature": rows(self._temps),
                     "top_k": rows(self._top_k, self.cfg.vocab_size),
                     "top_p": rows(self._top_p, 1.0)}
-            batches.append((slot_of, (
-                tokens, rows(self._tables), offsets, last_idx, samp,
-                rows(self._keys))))
+            args = (tokens, rows(self._tables), offsets, last_idx, samp,
+                    rows(self._keys))
+            if self.state_model:
+                # the slot whose state each row carries; a padding row
+                # names none (num_slots: read clipped, written nowhere)
+                args += (np.where(live, slot_of, self.num_slots).astype(
+                    np.int32), n_valid)
+            batches.append((slot_of, args))
         return batches
 
     def _prefill_calls(self, pre: List[int]) -> Tuple[np.ndarray, np.ndarray]:
@@ -1361,28 +1509,33 @@ class ServingEngine:
         first = self._first_call("prefill", batches[0][1][0])
         outs = []
         # tokens: the real prompt tokens of this tick's slices; rows: what
-        # the compiled calls compute, padding included
+        # the compiled calls compute, padding included; state_slots (a
+        # state model): the slots whose state the calls gather and scatter
+        state_attr = {"state_slots": len(pre)} if self.state_model else {}
         with span("tdp:engine.prefill", tokens=real, calls=len(batches),
                   rows=sum(args[0].size for _, args in batches),
-                  rids=rids, **first):
+                  rids=rids, **state_attr, **first):
             for _, args in batches:
                 if outs:
                     # one call in flight: the step does not donate the
                     # pool, so a call queued behind a running one holds it
                     # a third time (+1.6 GB at 64 x 768 on a v5e)
                     jax.block_until_ready(outs[-1][0])
-                out = self._step_fn(self.params, self.cache, *args)
-                self.cache = out[0]
-                outs.append(out[1:])
+                outs.append(self._dispatch(self._step_fn, args))
         tok = np.zeros(self.num_slots, np.int32)
         keys = np.zeros_like(self._keys)
         with span("tdp:engine.fetch", **first):
-            for (slot_of, _), out in zip(batches, outs):
+            for (slot_of, args), out in zip(batches, outs):
                 live = slot_of >= 0
                 tok[slot_of[live]] = np.asarray(out[0])[live]
                 keys[slot_of[live]] = np.asarray(out[1])[live]
-                if len(out) == 4:  # MoE family: expert-load stats ride along
-                    self._absorb_moe_stats(out[2], out[3])
+                if len(out) > 2:  # expert layers: load stats ride along
+                    self._absorb_moe_stats(*out[2:5])
+                if len(out) > 5:  # record_routing: the real positions' own
+                    routing, n_valid = np.asarray(out[5]), args[-1]
+                    for r in np.flatnonzero(live):
+                        self._slots[slot_of[r]].routing.append(
+                            routing[r, :n_valid[r]])
         self.stats["prefill_calls"] += len(batches)
         self._tick_prefill_rids = rids
         self._ev.emit("prefill_chunk", rids=rids, chunk=C, n_slots=len(rids),
@@ -1462,36 +1615,77 @@ class ServingEngine:
         if self.spec_k:
             return self._spec_decode_tick()
         mask, tables = self._masked(DECODE)
+        flight, ahead = self._flight, np.zeros(self.num_slots, bool)
+        if flight is not None:
+            # run_ahead: the slots whose newest token is still on the device
+            for i, who in flight["slots"]:
+                s = self._slots[i]
+                if s.state != DECODE or (s.rid, s.t_admit) != who:
+                    continue
+                if len(s.generated) + 1 >= s.req.max_new_tokens:
+                    mask[i], tables[i] = False, 0  # that token is its last
+                else:
+                    ahead[i] = True
         n_active = int(mask.sum())
         if n_active == 0:
+            self._absorb_decode(flight)
             return 0
-        tokens = np.where(mask, self._last_tok, 0).astype(np.int32)[:, None]
-        offsets = np.where(mask, self._lengths, 0).astype(np.int32)
+        tokens = np.where(mask & ~ahead, self._last_tok,
+                          0).astype(np.int32)[:, None]
+        offsets = np.where(mask, self._lengths + ahead, 0).astype(np.int32)
         last_idx = np.zeros(self.num_slots, np.int32)
-        self._tick_decode_rids = [
-            s.rid for s in self._slots if s.state == DECODE]
+        slots = [(int(i), (self._slots[i].rid, self._slots[i].t_admit))
+                 for i in np.flatnonzero(mask)]
+        self._tick_decode_rids = [who[0] for _, who in slots]
         first = self._first_call("decode", tokens)
+        args = (tokens, tables, offsets, last_idx, self._samp(), self._keys)
+        if self.state_model:
+            # row b is slot b: the state is updated where it lies, and a
+            # masked slot (0 real positions) keeps its own
+            args += (None, mask.astype(np.int32))
+        if self.run_ahead:
+            args += ((flight or self._no_flight)["out"][:2]
+                     + (ahead.astype(np.int32),),)
         with span("tdp:engine.decode", slots=n_active,
                   rids=self._tick_decode_rids, **first):
-            out = self._decode_fn(
-                self.params, self.cache, tokens, tables, offsets, last_idx,
-                self._samp(), self._keys)
-            if len(out) == 5:  # MoE family: live expert-load stats ride along
-                self.cache, tok, keys, moe_et, moe_dr = out
-                self._absorb_moe_stats(moe_et, moe_dr)
-            else:
-                self.cache, tok, keys = out
-        with span("tdp:engine.fetch", **first):
-            tok = np.asarray(tok)
-            keys = np.asarray(keys)
+            out = self._dispatch(self._decode_fn, args)
+        self.stats["decode_steps"] += 1
+        self.stats["decode_slot_steps"] += n_active
+        call = {"out": out, "slots": slots, "first": first}
+        if self.run_ahead:
+            # the call before this one: the device has it done, or nearly
+            self._absorb_decode(flight)
+            self._flight = call
+        else:
+            self._absorb_decode(call)
+        return n_active
+
+    def _absorb_decode(self, call: Optional[Dict[str, Any]]) -> None:
+        """Fetch what one decode call returned and book it: every slot's
+        token, key and chosen experts, its retirement.  With ``run_ahead``
+        this is the call of the tick before, and a slot that was retired,
+        preempted or requeued meanwhile has its token dropped."""
+        if call is None:
+            return
+        self._flight = None
+        out = call["out"]
+        with span("tdp:engine.fetch", **call["first"]):
+            tok = np.asarray(out[0])
+            keys = np.asarray(out[1])
+            if len(out) > 2:  # expert layers: live load stats ride along
+                self._absorb_moe_stats(*out[2:5], decode=True)
+            routing = np.asarray(out[5]) if len(out) > 5 else None
         if self.telemetry is not None:
-            self.telemetry.end_step(active_slots=n_active)
+            self.telemetry.end_step(active_slots=len(call["slots"]))
         if self.chaos is not None:
             tok = self.chaos.perturb_engine_tokens(self._tick, tok)
         now = time.perf_counter()
-        for i, s in enumerate(self._slots):
-            if s.state != DECODE:
+        for i, who in call["slots"]:
+            s = self._slots[i]
+            if s.state != DECODE or (s.rid, s.t_admit) != who:
                 continue
+            if routing is not None:  # record_routing
+                s.routing.append(routing[i])
             if self._token_poisoned(int(tok[i])):
                 self._poisoned_token_recover(i, int(tok[i]))
                 continue
@@ -1503,9 +1697,6 @@ class ServingEngine:
             s.tpot_s.append(now - s.t_last)
             s.t_last = now
             self._maybe_retire(i, int(tok[i]), now)
-        self.stats["decode_steps"] += 1
-        self.stats["decode_slot_steps"] += n_active
-        return n_active
 
     # ------------------------------------------------------ speculative decode
 
@@ -1664,6 +1855,8 @@ class ServingEngine:
             "t_submit": s.t_submit,
             "t_done": now,
         }
+        if self.record_routing:
+            self.finished[s.rid]["routing"] = np.concatenate(s.routing)
         self._inject.pop(s.rid, None)
         self._ttft_pred.pop(s.rid, None)
         if completed:
@@ -1862,6 +2055,7 @@ class ServingEngine:
             self._tick_prefill_rids = []
             self._tick_decode_rids = []
             self._tick_emitted = 0
+            self._tick_moe = [0.0, 0.0, 0.0]
             if self.chaos is not None:
                 self.chaos.before_engine_tick(self._tick, self)
             self.stats["audits"] += 1
@@ -1873,6 +2067,8 @@ class ServingEngine:
             prefilled = self._prefill_tick()
             decoded = self._decode_tick()
             busy = self.n_busy
+            if not busy:
+                self._flight = None  # run_ahead: nobody is left to take it
             self._occ_sum += busy / self.num_slots
             util = float(np.mean([a.utilization() for a in self._allocs]))
             self._util_sum += util
@@ -1938,6 +2134,9 @@ class ServingEngine:
                 st["spec_accepted"] / st["spec_drafted"], 4)
             if st["spec_drafted"] else 0.0,
         }
+        if self.state_model:
+            rec.update(zip(("moe_rows_routed", "moe_rows_held",
+                            "experts_touched"), self._tick_moe))
         self.tick_records.append(rec)
         if admitted or expired or prefilled or decoded or busy or self.queue:
             self._ev.emit(
@@ -2015,6 +2214,7 @@ class ServingEngine:
         verify-before-restore idiom — :meth:`resume` refuses bytes that
         rotted on disk).  Returns the payload either way; a restarted
         engine replays it with :meth:`resume`."""
+        self._needs_snapshots("drain")
         self._draining = True
         descs: List[Dict[str, Any]] = []
         n_inflight = 0
@@ -2090,6 +2290,7 @@ class ServingEngine:
         continues exactly where the drained engine stopped (temp-0:
         exact-trajectory; sampled: same key stream).  Returns the new
         rids, in descriptor order."""
+        self._needs_snapshots("resume")
         if isinstance(source, str):
             source = self._load_drain(source)
         if not isinstance(source, dict) or source.get("schema") != DRAIN_SCHEMA:
@@ -2172,6 +2373,7 @@ class ServingEngine:
         request now lives only in the descriptor, which the router must
         either import somewhere or resume (never both: the
         block-conservation audit spans both allocators)."""
+        self._needs_snapshots("KV migration (export_slot)")
         for i, s in enumerate(self._slots):
             if s.state == DECODE and s.rid == rid:
                 break
@@ -2228,6 +2430,7 @@ class ServingEngine:
         ``[n_shared:n_live]`` (``migrate_blocks``) and install the
         returned cache BEFORE this engine's next step.  ``None`` = no
         capacity (free slot or blocks), nothing partially admitted."""
+        self._needs_snapshots("KV migration (import_slot)")
         emitted = [int(t) for t in desc.get("emitted") or []]
         if not emitted:
             raise ValueError(
@@ -2432,7 +2635,13 @@ class ServingEngine:
                       "spec_drafted": 0, "spec_accepted": 0,
                       "migrated_in": 0, "migrated_out": 0,
                       "imports_aborted": 0,
-                      "cp_ring_hops": 0, "cp_ring_bytes": 0}
+                      "cp_ring_hops": 0, "cp_ring_bytes": 0,
+                      # a held range of experts (one chip's share): rows
+                      # routed, the rows among them that fell on held
+                      # experts, and the held experts the DECODE calls
+                      # touched (summed over the expert layers and calls)
+                      "moe_rows_routed": 0.0, "moe_rows_held": 0.0,
+                      "experts_touched": 0.0}
         self._decode_sigs: set = set()
         self._prefill_sigs: set = set()
         self._cow_sigs: set = set()
@@ -2466,10 +2675,22 @@ class ServingEngine:
         for a in self._allocs:
             a.peak_in_use = a.in_use
 
-    def _absorb_moe_stats(self, et, dr) -> None:
+    def _absorb_moe_stats(self, et, dr, share=None,
+                          decode: bool = False) -> None:
         """Fold one step's expert-load stats into the accumulators.
         ``et``: [groups, E] per-dp-group routed-token counts (groups = 1
-        without a mesh), ``dr``: [groups] drop rates."""
+        without a mesh), ``dr``: [groups] drop rates.  ``share`` (a held
+        range of experts): ``[rows routed, rows on held experts, held
+        experts touched]`` of the call, into ``stats`` and the tick."""
+        if share is not None:
+            routed, held, touched = (float(v) for v in np.asarray(share))
+            self.stats["moe_rows_routed"] += routed
+            self.stats["moe_rows_held"] += held
+            self._tick_moe[0] += routed
+            self._tick_moe[1] += held
+            if decode:
+                self.stats["experts_touched"] += touched
+                self._tick_moe[2] += touched
         et = np.asarray(et, np.float64).sum(axis=0)
         if self._moe_expert_tokens is None:
             self._moe_expert_tokens = et
@@ -2604,7 +2825,8 @@ class ServingEngine:
             moe = moe_load_stats(
                 self._moe_expert_tokens
                 if self._moe_expert_tokens is not None
-                else [0.0] * self.cfg.moe_experts,
+                else [0.0] * (self.cfg.moe.held_range[1] if self.state_model
+                              else self.cfg.moe_experts),
                 dropped_rate=dropped,
             )
             moe["dispatch"] = (self.moe_dispatch if self.moe_dispatch

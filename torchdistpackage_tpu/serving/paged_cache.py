@@ -92,13 +92,20 @@ def init_paged_kv(
         raise ValueError(
             f"num_blocks must be >= 2 (block 0 is the reserved NULL block), "
             f"got {num_blocks}")
-    shape = (cfg.nlayers, num_blocks, hkv, block_size, cfg.block.head_dim)
+    shape = (_kv_layers(cfg), num_blocks, hkv, block_size, cfg.block.head_dim)
     if quantized:
         def entry():
             return (jnp.zeros(shape, jnp.int8),
                     jnp.ones(shape[:-1], jnp.float32))
         return {"k": entry(), "v": entry()}
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def _kv_layers(cfg) -> int:
+    """The pool's depth: the layers that keep keys and values.  A hybrid
+    family (models/hybrid.py) says how many of its layers do; every other
+    family's layers all do."""
+    return getattr(cfg, "kv_layers", cfg.nlayers)
 
 
 def block_size_of(cache: Dict[str, Any]) -> int:
@@ -129,7 +136,7 @@ def expected_pool_bytes(
     ``cfg.dtype`` (int8 + f32 per-vector scale when ``quantized``).  The
     independent half of the pool-accounting cross-check."""
     hkv = cfg.block.kv_head_count // axis_size
-    entries = cfg.nlayers * num_blocks * hkv * block_size
+    entries = _kv_layers(cfg) * num_blocks * hkv * block_size
     hd = cfg.block.head_dim
     if quantized:
         per_kv = entries * hd * 1 + entries * 4  # int8 q + f32 scale
@@ -511,6 +518,57 @@ def paged_forward_moe(
     if moe_stats:
         return cache, logits, metrics
     return cache, logits
+
+
+def paged_forward_hybrid(
+    params: Dict[str, PyTree],
+    tokens: jnp.ndarray,
+    cfg,
+    cache: Dict[str, Any],
+    state: Dict[str, Any],
+    tables: jnp.ndarray,
+    offset: jnp.ndarray,
+    n_valid: jnp.ndarray,
+    rows: Optional[jnp.ndarray] = None,
+    last_idx=None,
+    attn_impl: str = "gather",
+):
+    """:func:`paged_forward` for the hybrid family (models/hybrid.py): the
+    attention layers write and attend through the block tables, the Mamba
+    layers read and write the recurrent ``state`` (``init_state``: one
+    ``[num_slots, ...]`` array a Mamba layer).  ``n_valid`` [B]: how many of
+    each row's positions are real; the rest advance no state.
+
+    ``rows`` None: row b of ``tokens`` IS slot b (the decode call), the
+    state is updated where it lies.  Otherwise ``rows`` [B] int32 names the
+    slot whose state each row carries (the compact prefill call): those
+    slots' state is gathered, advanced and scattered back; a row that
+    names no slot (``rows[b] >= num_slots``, padding) reads some slot's
+    state, advances nothing (its ``n_valid`` is 0) and writes nowhere.  A
+    row at position 0 has no history: it starts from the ZERO state
+    whatever its slot held, which is how admission, preemption and a fault
+    requeue restart a sequence (recompute, as for KV) with no reset call.
+
+    Returns ``(cache, state, logits [B, V], moe_metrics)``."""
+    from ..models.hybrid import hybrid_paged_forward
+
+    offset = jnp.asarray(offset, jnp.int32)
+    ops = _paged_cache_ops(tables, attn_impl)
+    mine = state
+    if rows is not None:
+        def own(a):
+            a = a.at[rows].get(mode="clip")
+            fresh = (offset == 0).reshape((-1,) + (1,) * (a.ndim - 1))
+            return jnp.where(fresh, jnp.zeros((), a.dtype), a)
+
+        mine = jax.tree.map(own, state)
+    cache, mine, logits, metrics = hybrid_paged_forward(
+        params, tokens, cfg, cache, mine, n_valid, ops, offset,
+        last_idx=last_idx)
+    if rows is not None:
+        mine = jax.tree.map(
+            lambda a, new: a.at[rows].set(new, mode="drop"), state, mine)
+    return cache, mine, logits, metrics
 
 
 def copy_blocks(cache: Dict[str, Any], src: jnp.ndarray,

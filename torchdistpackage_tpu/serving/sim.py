@@ -78,6 +78,13 @@ class DeviceStep:
     def init_cache(self) -> Any:
         raise NotImplementedError
 
+    def init_state(self) -> Any:
+        """A state model's recurrent state, one row a slot (engines of
+        every other model never ask)."""
+        raise NotImplementedError(
+            f"{type(self).__name__} keeps no recurrent state: a state "
+            f"model runs on the compiled step only")
+
     def step_fn(self) -> Callable:
         """``(params, cache, tokens[B,S], tables, offsets, last_idx,
         samp, keys) -> (cache, tok[B], keys)`` — the shared
@@ -118,6 +125,11 @@ class CompiledDeviceStep(DeviceStep):
                 cache, eng._cache_specs(cache))
         return cache
 
+    def init_state(self) -> Any:
+        from ..models.hybrid import init_state
+
+        return init_state(self.engine.cfg, self.engine.num_slots)
+
     def step_fn(self) -> Callable:
         return self.engine._build_step()
 
@@ -130,7 +142,15 @@ class CompiledDeviceStep(DeviceStep):
     def prng_key(self, seed: int) -> np.ndarray:
         import jax
 
-        return np.asarray(jax.random.PRNGKey(seed), np.uint32)
+        # on the host's own device where there is one: a key is two words,
+        # and on the accelerator it queues behind a call in flight, so an
+        # admission would wait for the device (engine ``run_ahead``)
+        try:
+            host = jax.local_devices(backend="cpu")[0]
+        except RuntimeError:
+            return np.asarray(jax.random.PRNGKey(seed), np.uint32)
+        with jax.default_device(host):
+            return np.asarray(jax.random.PRNGKey(seed), np.uint32)
 
 
 class LatencyModel:
