@@ -193,6 +193,63 @@ def test_kernel_int8_fused_dequant():
                                    atol=2e-6)
 
 
+#: (groups, S_in, window, int8): dense / GQA, decode / K+1 verify / chunk,
+#: causal / sliding window, fp pool / int8 pair
+STACKED_CASES = {
+    "dense-decode": (1, 1, None, False),
+    "gqa-verify": (2, 3, None, False),
+    "gqa-window-chunk": (2, 8, 6, False),
+    "dense-window-decode": (1, 1, 6, False),
+    "int8-decode": (2, 1, None, True),
+    "int8-verify-window": (2, 3, 6, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STACKED_CASES))
+def test_kernel_stacked_pool_matches_per_layer(case):
+    """The whole pool ``[L, nb, Hkv, bs, hd]`` and a layer index (a python
+    int, and a traced scalar as the layer scan hands it over) give BITWISE
+    what the per-layer call gives on ``pool[layer]``, for every layer: the
+    index map's leading coordinate is all that changed.  And the gather
+    oracle reaches the same layer through ``[layer, tables]``."""
+    groups, s_in, window, int8 = STACKED_CASES[case]
+    L, B, hkv, bs, hd, mb = 3, 2, 2, 4, 8, 5
+    nb = 1 + B * mb
+    rs = np.random.RandomState(11)
+    if int8:
+        def side():
+            return (jnp.asarray(rs.randint(-127, 128, (L, nb, hkv, bs, hd)),
+                                jnp.int8),
+                    jnp.asarray(rs.uniform(1e-3, 2e-2, (L, nb, hkv, bs)),
+                                jnp.float32))
+    else:
+        def side():
+            return jnp.asarray(rs.standard_normal((L, nb, hkv, bs, hd)),
+                               jnp.float32)
+    kp, vp = side(), side()
+    tables = jnp.asarray(rs.permutation(np.arange(1, nb)).reshape(B, mb),
+                         jnp.int32)
+    offs = jnp.asarray([9, 12 - s_in], jnp.int32)
+    q = jnp.asarray(rs.standard_normal((B, hkv * groups, s_in, hd)),
+                    jnp.float32)
+    one = lambda c, i: jax.tree.map(lambda a: a[i], c)
+    traced = jax.jit(lambda q, kp, vp, li: paged_decode_attention(
+        q, kp, vp, tables, offs, layer=li, window=window))
+    for li in range(L):
+        want = paged_decode_attention(q, one(kp, li), one(vp, li), tables,
+                                      offs, window=window)
+        got = paged_decode_attention(q, kp, vp, tables, offs, layer=li,
+                                     window=window)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(
+            np.asarray(traced(q, kp, vp, jnp.int32(li))), np.asarray(want))
+        np.testing.assert_array_equal(
+            np.asarray(paged_attention(q, kp, vp, offs, tables=tables,
+                                       window=window, layer=li)),
+            np.asarray(paged_attention(q, one(kp, li), one(vp, li), offs,
+                                       tables=tables, window=window)))
+
+
 def test_resolve_attn_impl():
     """'auto' resolves per backend (gather on CPU — the interpreter kernel
     is a correctness story, not a speed story); junk is rejected."""

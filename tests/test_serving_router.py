@@ -36,6 +36,7 @@ from torchdistpackage_tpu.obs.events import EventLog, set_default_event_log
 from torchdistpackage_tpu.obs.report import _validate_router
 from torchdistpackage_tpu.resilience import ChaosMonkey, Fault
 from torchdistpackage_tpu.serving import (
+    ChunkedWireTransport,
     Request,
     Router,
     ServingEngine,
@@ -326,6 +327,46 @@ def test_prefill_decode_handoff_bit_parity(fleet, event_log):
         assert [h["replica"] for h in by_rid[rid]["hops"]] == [0, 1]
         assert by_rid[rid]["outcome"] == "retired"
         assert by_rid[rid]["migrations"][0]["bytes"] > 0
+
+
+@pytest.mark.parametrize("wire", ["loopback", "chunked_wire", "bounced"])
+def test_exported_pool_is_good_until_the_source_steps(fleet, event_log,
+                                                      monkeypatch, wire):
+    """``export_slot`` hands out the source engine's OWN pool buffer, which
+    the source's next device call donates.  So: export from A, import and
+    deliver into B through the transport, THEN step A (the handle dies),
+    then B: B's tokens equal the unmigrated run's.  ``bounced``: the
+    destination refuses the import, the request goes back into A through
+    the same-replica lane copy (source pool == destination pool), and
+    still ends bit-equal after its second, real handoff."""
+    a, b = _pair(fleet)
+    p = fleet["prompts"]
+    router = Router(
+        [a, b], roles=["prefill", "decode"],
+        transport=ChunkedWireTransport() if wire == "chunked_wire" else None)
+    moved = router.submit(Request(p[0].tolist(), NEW))
+    while not a.decode_slots():
+        a.step()
+    (src_rid, _slot), = a.decode_slots()
+    exported = a.cache["k"]  # what export_slot will hand out
+    if wire == "bounced":
+        monkeypatch.setattr(b, "import_slot", lambda desc: None)
+    assert router._handoff(0, src_rid)
+    monkeypatch.undo()
+    assert not exported.is_deleted()  # a handoff makes no call on A's pool
+    assert (a.n_busy, b.n_busy) == ((1, 0) if wire == "bounced" else (0, 1))
+    assert router.audit()["ok"]
+
+    other = router.submit(Request(p[1].tolist(), NEW))  # lands on A
+    a.step()  # A's next device call: the exported handle is consumed
+    if wire != "bounced":  # (a bounce already replaced A's pool by a copy)
+        assert exported.is_deleted()
+    _run_audited(router)
+    for rid, row in ((moved, 0), (other, 1)):
+        np.testing.assert_array_equal(
+            router.finished[rid]["tokens"], fleet["want"][row],
+            err_msg=f"{wire}: the migrated KV was not what A had written")
+        assert router.finished[rid]["replica"] == 1
 
 
 def test_warm_handoff_ships_only_the_tail(fleet, event_log):

@@ -312,7 +312,9 @@ def hybrid_paged_forward(
 ):
     """``tokens`` [B, S] through the stack.  ``cache``: the block pool of
     the attention layers (``{'k','v': [kv_layers, ...]}``), reached through
-    ``cache_ops``; ``state``: :func:`init_state`'s arrays with one row a
+    ``cache_ops(layer)`` (the pool's ``(write, attend)`` pair for one of
+    its layers; the pool itself is threaded whole through the attention
+    layers); ``state``: :func:`init_state`'s arrays with one row a
     row of ``tokens``; ``n_valid`` [B]: the real positions of each row.
     Returns ``(cache, state, logits [B, V], moe_metrics)``: the logits of
     row ``last_idx`` (default: the last), and the expert layers' counters
@@ -324,8 +326,8 @@ def hybrid_paged_forward(
     S = tokens.shape[1]
     valid = jnp.arange(S)[None, :] < n_valid[:, None]
     h = jnp.take(params["tok_emb"], tokens, axis=0)
-    layer = lambda c, i: jax.tree.map(lambda a: a[i], c)  # tuple-safe (int8)
-    ks, vs, ssm, conv, mets = [], [], [], [], []
+    ck, cv, kv_layer = cache["k"], cache["v"], 0
+    ssm, conv, mets = [], [], []
     mcfg = cfg.moe if cfg.moe_experts else None
     for kind, lp in zip(cfg.pattern, params["layers"]):
         x = rms_norm(h, lp["norm"], cfg.norm_eps)
@@ -336,20 +338,15 @@ def hybrid_paged_forward(
             ssm.append(s_m)
             conv.append(c_m)
         elif kind == "*":
-            a = len(ks)
             y, ck, cv = attention_mixer(
-                lp, x, cfg, layer(cache["k"], a), layer(cache["v"], a),
-                offset, cache_ops)
-            ks.append(ck)
-            vs.append(cv)
+                lp, x, cfg, ck, cv, offset, cache_ops(kv_layer))
+            kv_layer += 1
         else:
             y, met = moe_serve_forward(
                 lp, x, mcfg, return_metrics=True, valid=valid)
             mets.append(met)
         h = h + y
-    if ks:
-        stack = lambda cs: jax.tree.map(lambda *xs: jnp.stack(xs), *cs)
-        cache = {"k": stack(ks), "v": stack(vs)}
+    cache = {"k": ck, "v": cv}
     state = {"ssm": tuple(ssm), "conv": tuple(conv)}
     metrics = None
     if mets:

@@ -126,6 +126,15 @@ def _abstract_signature(args: Tuple[Any, ...]) -> Tuple:
     return (str(treedef), tuple(sig))
 
 
+def _any_deleted(tree: Any) -> bool:
+    """True when a jax array in ``tree`` was consumed by a call that
+    donated it."""
+    import jax
+
+    return any(isinstance(leaf, jax.Array) and leaf.is_deleted()
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
 def _host_numerics(stats: Dict[str, Any]) -> Dict[str, Any]:
     """Fetch a (possibly nested) dict of device scalars to host floats —
     one device_get for the whole tree, so the numerics stats cost a
@@ -307,10 +316,13 @@ class Telemetry:
 
         The first call per abstract signature is AOT-lowered and compiled,
         capturing compile time + XLA cost analysis; subsequent calls go to
-        the compiled executable (no double compile).  If the AOT executable
+        the compiled executable (no double compile; it keeps the jitted
+        step's donations).  If the AOT executable
         rejects a call (sharding/donation edge the signature key can't
         see), the wrapper permanently falls back to the original callable —
-        telemetry must never change what the loop computes.
+        telemetry must never change what the loop computes.  A call that
+        failed AFTER it consumed a donated argument is not made again: the
+        error is the caller's to see.
 
         The executable cache is scoped PER WRAPPED CALLABLE: two different
         step fns wrapped by the same Telemetry (e.g. the 1F1B and ZB arms
@@ -342,14 +354,16 @@ class Telemetry:
             try:
                 out = target(*args, **kwargs)
             except Exception:
-                if target is not jfn:
-                    # AOT path rejected the call: fall back for good
-                    self._aot_ok = False
-                    for e in self._compiled.values():
-                        e["compiled"] = None
-                    out = jfn(*args, **kwargs)
-                else:
+                if target is jfn or _any_deleted((args, kwargs)):
+                    # nothing to fall back to, or with: the failed call
+                    # already consumed an argument it donates (the serving
+                    # step's KV pool, a train step's state)
                     raise
+                # AOT path rejected the call: fall back for good
+                self._aot_ok = False
+                for e in self._compiled.values():
+                    e["compiled"] = None
+                out = jfn(*args, **kwargs)
             self._dispatch_end = time.perf_counter()
             self._pending_spans["dispatch"] = self._dispatch_end - t0
             self._pending_out = out

@@ -122,10 +122,11 @@ def _compiler_params():
 
 
 def _kernel(
-    tab_ref, off_ref, q_ref, *refs,
+    tab_ref, off_ref, lay_ref, q_ref, *refs,
     S_in, bs, window, sm_scale, quantized, fetch_width, rows,
 ):
-    """Grid ``(slot b, kv-head h, kv-step j)``; ``refs`` carries the
+    """Grid ``(slot b, kv-head h, kv-step j)``; ``lay_ref`` (the layer of
+    the stacked pool) is read by the index maps alone; ``refs`` carries the
     ``fetch_width`` per-step KV blocks ((k, v) dense or (k8, ks, v8, vs)
     quantized, sub-block-major), then the output ref and the (acc, m, l)
     online-softmax VMEM scratch carried across j steps."""
@@ -155,16 +156,16 @@ def _kernel(
         @pl.when(blk < hi)
         def _compute(i=i, blk=blk):
             if quantized:
-                k8 = kv_refs[4 * i][0, 0]
-                ks = kv_refs[4 * i + 1][0, 0]  # [1, bs]
-                v8 = kv_refs[4 * i + 2][0, 0]
-                vs = kv_refs[4 * i + 3][0, 0]  # [1, bs]
+                k8 = kv_refs[4 * i][0, 0, 0]
+                ks = kv_refs[4 * i + 1][0, 0, 0]  # [1, bs]
+                v8 = kv_refs[4 * i + 2][0, 0, 0]
+                vs = kv_refs[4 * i + 3][0, 0, 0]  # [1, bs]
                 kblk = k8.astype(jnp.float32)
                 s = jnp.dot(q.astype(jnp.float32), kblk.T,
                             preferred_element_type=jnp.float32)
                 s = s * ks
             else:
-                kblk = kv_refs[2 * i][0, 0]
+                kblk = kv_refs[2 * i][0, 0, 0]
                 s = jnp.dot(q, kblk.T,
                             preferred_element_type=jnp.float32)
             s = s * sm_scale
@@ -186,7 +187,7 @@ def _kernel(
                 upd = jnp.dot(pv, v8.astype(jnp.float32),
                               preferred_element_type=jnp.float32)
             else:
-                vblk = kv_refs[2 * i + 1][0, 0]
+                vblk = kv_refs[2 * i + 1][0, 0, 0]
                 upd = jnp.dot(p.astype(vblk.dtype), vblk,
                               preferred_element_type=jnp.float32)
             acc_ref[...] = acc_ref[...] * corr + upd
@@ -206,6 +207,7 @@ def paged_decode_attention(
     tables: jnp.ndarray,
     offsets,
     *,
+    layer=None,
     window: Optional[int] = None,
     sm_scale: Optional[float] = None,
     fetch_width: Optional[int] = None,
@@ -214,19 +216,24 @@ def paged_decode_attention(
     """Attention of ``q`` [B, H, S_in, hd] against each slot's paged
     context, walking the block table in-kernel.
 
-    ``k_pool``/``v_pool``: one layer's pool ``[num_blocks, Hkv, bs, hd]``
-    (or its int8 ``(q8 [..., hd], scale [...])`` pair).  ``tables``
-    [B, max_blocks] int32 block tables; ``offsets`` scalar or [B] — slot
-    b's rows sit at positions ``offsets[b] + arange(S_in)`` and attend
-    keys at ``kpos <= qpos`` (``window`` additionally bounds below).
-    Returns [B, H, S_in, hd] in ``q.dtype`` — drop-in for the gather
-    path's ``_cached_attention`` output (float-tolerance equal; the
-    engine goldens assert token bit parity).
+    ``k_pool``/``v_pool``: the WHOLE pool ``[L, num_blocks, Hkv, bs, hd]``
+    (or its int8 ``(q8 [..., hd], scale [...])`` pair) and ``layer`` (an
+    int, traced or not) naming the layer to read: the layer is one more
+    scalar-prefetch operand and the index map's leading coordinate, so no
+    ``pool[layer]`` is ever materialised.  ``layer=None``: the pools are
+    ONE layer's ``[num_blocks, Hkv, bs, hd]``, taken as the one-layer
+    stack.  ``tables`` [B, max_blocks] int32 block tables; ``offsets``
+    scalar or [B] — slot b's rows sit at positions ``offsets[b] +
+    arange(S_in)`` and attend keys at ``kpos <= qpos`` (``window``
+    additionally bounds below).  Returns [B, H, S_in, hd] in ``q.dtype``
+    — drop-in for the gather path's ``_cached_attention`` output
+    (float-tolerance equal; the engine goldens assert token bit parity).
     """
     B, H, S_in, hd = q.shape
+    k_pool, v_pool, lay = _stacked(k_pool, v_pool, layer)
     quantized = isinstance(k_pool, tuple)
     k_arr = k_pool[0] if quantized else k_pool
-    nb, Hkv, bs, _hd = k_arr.shape
+    _L, nb, Hkv, bs, _hd = k_arr.shape
     groups, rem = divmod(H, Hkv)
     if rem:
         raise ValueError(
@@ -250,35 +257,33 @@ def paged_decode_attention(
     if rows != R:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - R), (0, 0)))
 
-    def qidx(b, h, j, tab, off):
+    def qidx(b, h, j, tab, off, lay):
         return (b, h, 0, 0)
 
-    def kvidx(b, h, j, tab, off, i=0):
+    def kvidx(b, h, j, tab, off, lay, i=0, own_layer=False):
         # clamp dead steps onto the last live block: consecutive grid
         # steps then revisit the same index and Mosaic skips the re-fetch
         # — attention HBM traffic scales with the slot's ACTUAL length
         hi1 = (off[b] + S_in + bs - 1) // bs - 1
         blk = jnp.minimum(jnp.minimum(j * fw + i, hi1), mb - 1)
-        return (tab[b, blk], h, 0, 0)
+        return (0 if own_layer else lay[0], tab[b, blk], h, 0, 0)
 
     in_specs = [pl.BlockSpec((1, 1, rows, hd), qidx)]
     operands = [qr]
     for pool in (k_pool, v_pool):
+        scales = _scale_rows(pool[1], lay) if quantized else None
         for i in range(fw):
             if quantized:
-                p8, ps = pool
                 in_specs.append(pl.BlockSpec(
-                    (1, 1, bs, hd), functools.partial(kvidx, i=i)))
-                operands.append(p8)
-                # scales ride as [nb, Hkv, 1, bs]: a (1, bs) block over a
-                # (1, bs) minor pair is legal on TPU, (1, bs) over
-                # (Hkv, bs) is not
+                    (1, 1, 1, bs, hd), functools.partial(kvidx, i=i)))
+                operands.append(pool[0])
                 in_specs.append(pl.BlockSpec(
-                    (1, 1, 1, bs), functools.partial(kvidx, i=i)))
-                operands.append(ps[:, :, None, :])
+                    (1, 1, 1, 1, bs),
+                    functools.partial(kvidx, i=i, own_layer=True)))
+                operands.append(scales)
             else:
                 in_specs.append(pl.BlockSpec(
-                    (1, 1, bs, hd), functools.partial(kvidx, i=i)))
+                    (1, 1, 1, bs, hd), functools.partial(kvidx, i=i)))
                 operands.append(pool)
     # interleave per sub-block: kernel expects (k, v) / (k8, ks, v8, vs)
     # pairs sub-block-major — reorder the flat k-then-v lists
@@ -293,7 +298,7 @@ def paged_decode_attention(
         ordered_specs.extend(v_specs[per * i:per * (i + 1)])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Hkv, -(-mb // fw)),
         in_specs=ordered_specs,
         out_specs=pl.BlockSpec((1, 1, rows, hd), qidx),
@@ -313,15 +318,37 @@ def paged_decode_attention(
         compiler_params=_compiler_params(),
         interpret=_interpret(),
         name="paged_decode" if S_in == 1 else "paged_chunk",
-    )(tables.astype(jnp.int32), offs, *ordered_ops)
+    )(tables.astype(jnp.int32), offs, lay, *ordered_ops)
     return out[:, :, :R].reshape(B, H, S_in, hd)
+
+
+def _stacked(k_pool: Any, v_pool: Any, layer) -> Tuple[Any, Any, jnp.ndarray]:
+    """The pools as the kernels take them: stacked ``[L, nb, Hkv, bs, hd]``
+    beside the layer as an int32 ``[1]`` scalar-prefetch operand.  One
+    layer's pool (``layer`` None) is the one-layer stack: a reshape."""
+    if layer is None:
+        lift = lambda c: jax.tree.map(lambda a: a[None], c)
+        return lift(k_pool), lift(v_pool), jnp.zeros((1,), jnp.int32)
+    return k_pool, v_pool, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _scale_rows(scale: jnp.ndarray, lay: jnp.ndarray) -> jnp.ndarray:
+    """An int8 pool's scales ``[L, nb, Hkv, bs]`` as the kernel reads them,
+    ``[1, nb, Hkv, 1, bs]`` of layer ``lay``: a (1, bs) block over a
+    (1, bs) minor pair is legal on TPU, (1, bs) over (Hkv, bs) is not.
+    That view is a relayout (a real copy) of what it covers, so it covers
+    ONE layer's scales (1/32 of the layer's int8 bytes), which the index
+    map then reaches at layer 0; the int8 values themselves are read
+    where they lie."""
+    one = jax.lax.dynamic_index_in_dim(scale, lay[0], 0, keepdims=True)
+    return one[:, :, :, None, :]
 
 
 # ------------------------------------------------ CP ring carry entry point
 
 
 def _cp_kernel(
-    tab_ref, off_ref, q_ref, *refs,
+    tab_ref, off_ref, lay_ref, q_ref, *refs,
     S_in, bs, window, sm_scale, fetch_width, rows, nb, has_carry,
 ):
     """Ring-hop variant of :func:`_kernel` for context-parallel prefill
@@ -365,7 +392,7 @@ def _cp_kernel(
         def _compute(i=i, blk=blk):
             raw = tab_ref[b, blk]  # re-based id; out of [0, nb) = remote
             owned = (raw >= 0) & (raw < nb)
-            kblk = kv_refs[2 * i][0, 0]
+            kblk = kv_refs[2 * i][0, 0, 0]
             s = jnp.dot(q, kblk.T, preferred_element_type=jnp.float32)
             s = s * sm_scale
             kpos = blk * bs + jax.lax.broadcasted_iota(
@@ -381,7 +408,7 @@ def _cp_kernel(
             corr = jnp.exp(m - m_new)
             l_ref[...] = jnp.broadcast_to(
                 l * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-            vblk = kv_refs[2 * i + 1][0, 0]
+            vblk = kv_refs[2 * i + 1][0, 0, 0]
             upd = jnp.dot(p.astype(vblk.dtype), vblk,
                           preferred_element_type=jnp.float32)
             acc_ref[...] = acc_ref[...] * corr + upd
@@ -401,6 +428,7 @@ def paged_carry_attention(
     tables_local: jnp.ndarray,
     offsets,
     *,
+    layer=None,
     carry: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]] = None,
     window: Optional[int] = None,
     sm_scale: Optional[float] = None,
@@ -408,8 +436,10 @@ def paged_carry_attention(
     q_pad_to: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One ring hop of CP paged prefill: accumulate ``q`` [B, H, S_in,
-    hd] against ONE rank's pool slice ``[nb, Hkv, bs, hd]`` reached
-    through ``tables_local`` (= global tables minus that rank's slice
+    hd] against layer ``layer`` of ONE rank's pool slice ``[L, nb, Hkv,
+    bs, hd]`` (``layer=None``: one layer's ``[nb, Hkv, bs, hd]``, as in
+    :func:`paged_decode_attention`) reached through
+    ``tables_local`` (= global tables minus that rank's slice
     base; out-of-slice entries are masked in-kernel), returning the
     UN-normalized online-softmax carry ``(acc [B, Hkv, rows, hd] f32,
     m [B, Hkv, rows, 128] f32, l [B, Hkv, rows, 128] f32)``.
@@ -428,7 +458,8 @@ def paged_carry_attention(
         raise NotImplementedError(
             "paged_carry_attention does not support int8 pools")
     B, H, S_in, hd = q.shape
-    nb, Hkv, bs, _hd = k_pool.shape
+    k_pool, v_pool, lay = _stacked(k_pool, v_pool, layer)
+    _L, nb, Hkv, bs, _hd = k_pool.shape
     groups, rem = divmod(H, Hkv)
     if rem:
         raise ValueError(
@@ -451,17 +482,17 @@ def paged_carry_attention(
     if rows != R:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - R), (0, 0)))
 
-    def qidx(b, h, j, tab, off):
+    def qidx(b, h, j, tab, off, lay):
         return (b, h, 0, 0)
 
-    def kvidx(b, h, j, tab, off, i=0):
+    def kvidx(b, h, j, tab, off, lay, i=0):
         # same dead-step clamp as the decode kernel, plus a clamp of the
         # re-based table entry into the slice (remote blocks fetch SOME
         # valid block; the in-kernel ownership test masks the scores)
         hi1 = (off[b] + S_in + bs - 1) // bs - 1
         blk = jnp.minimum(jnp.minimum(j * fw + i, hi1), mb - 1)
         idx = jnp.clip(tab[b, blk], 0, nb - 1)
-        return (idx, h, 0, 0)
+        return (lay[0], idx, h, 0, 0)
 
     has_carry = carry is not None
     in_specs = [pl.BlockSpec((1, 1, rows, hd), qidx)]
@@ -472,14 +503,14 @@ def paged_carry_attention(
             operands.append(c)
     for i in range(fw):
         in_specs.append(pl.BlockSpec(
-            (1, 1, bs, hd), functools.partial(kvidx, i=i)))
+            (1, 1, 1, bs, hd), functools.partial(kvidx, i=i)))
         operands.append(k_pool)
         in_specs.append(pl.BlockSpec(
-            (1, 1, bs, hd), functools.partial(kvidx, i=i)))
+            (1, 1, 1, bs, hd), functools.partial(kvidx, i=i)))
         operands.append(v_pool)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Hkv, -(-mb // fw)),
         in_specs=in_specs,
         out_specs=[
@@ -508,7 +539,7 @@ def paged_carry_attention(
         compiler_params=_compiler_params(),
         interpret=_interpret(),
         name="paged_carry",
-    )(tables_local.astype(jnp.int32), offs, *operands)
+    )(tables_local.astype(jnp.int32), offs, lay, *operands)
     return acc, m, l
 
 

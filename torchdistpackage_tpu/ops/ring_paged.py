@@ -68,9 +68,10 @@ def _ring_perm(cp: int):
     return [(i, (i + 1) % cp) for i in range(cp)]
 
 
-def _scatter_local(c, val, pos, tables, rank_base, nb_local: int):
+def _scatter_local(c, val, pos, tables, rank_base, nb_local: int, layer):
     """Scatter ``val`` [B, Hkv, S, hd] at absolute positions ``pos``
-    [B, S] into the LOCAL pool slice ``c`` [nb_local, Hkv, bs, hd]:
+    [B, S] into layer ``layer`` of the LOCAL pool slice ``c``
+    [L, nb_local, Hkv, bs, hd] (returned whole, written where it lies):
     global block ids resolve through ``tables`` and re-base by
     ``rank_base``; rows landing outside this rank's slice get the
     sentinel index ``nb_local`` — NOT -1, which ``.at[...]`` would wrap
@@ -81,7 +82,7 @@ def _scatter_local(c, val, pos, tables, rank_base, nb_local: int):
     ``paged_write`` (NULL entries re-base to rank 0's local NULL; on
     other ranks they drop — never read either way)."""
     B, Hkv, S, hd = val.shape
-    bs = c.shape[2]
+    bs = c.shape[3]
     mb = tables.shape[1]
     blk = jnp.take_along_axis(
         tables, jnp.clip(pos // bs, 0, mb - 1), axis=1).reshape(-1)
@@ -89,12 +90,17 @@ def _scatter_local(c, val, pos, tables, rank_base, nb_local: int):
     loc = blk - rank_base
     loc = jnp.where((loc >= 0) & (loc < nb_local), loc, nb_local)
     vals = val.transpose(0, 2, 1, 3).reshape(B * S, Hkv, hd)
-    return c.at[loc, :, idx].set(vals.astype(c.dtype), mode="drop")
+    # one index row a head, so that the update's window is ``hd`` alone: a
+    # ``[Hkv, hd]`` window straddles ``bs`` and XLA:TPU then wants the pool
+    # in another layout than the kernel reads it in (``paged_write``)
+    return c.at[layer, loc[:, None], jnp.arange(Hkv)[None, :],
+                idx[:, None]].set(vals.astype(c.dtype), mode="drop")
 
 
 def ring_paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray,
-                     cp_axis: str, prefill: bool = False):
-    """CP analogue of ``paged_write`` for a pool slice sharded over
+                     layer: int, cp_axis: str, prefill: bool = False):
+    """CP analogue of ``paged_write`` for layer ``layer`` of a pool slice
+    ``[L, nb_local, Hkv, bs, hd]`` sharded over
     ``cp_axis``: ``val`` [B, Hkv, S, hd] holds THIS rank's fresh rows —
     its sub-chunk (rows at ``offset + rank*S .. +S``) when ``prefill``,
     or the replicated decode row (identical on every rank) otherwise.
@@ -109,29 +115,34 @@ def ring_paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray,
     cp = axis_size(cp_axis)
     r = jax.lax.axis_index(cp_axis)
     B, Hkv, S, hd = val.shape
-    nb_local = c.shape[0]
+    nb_local = c.shape[1]
     base = r * nb_local
     if not prefill or cp == 1:
         pos = jnp.asarray(offset)[:, None] + jnp.arange(S)[None, :]
-        return _scatter_local(c, val, pos, tables, base, nb_local)
+        return _scatter_local(c, val, pos, tables, base, nb_local, layer)
     perm = _ring_perm(cp)
     cur = val
     for hop in range(cp):  # python-unrolled: one HLO permute per hop
         src = jnp.mod(r - hop, cp)
         pos = (jnp.asarray(offset)[:, None] + src * S
                + jnp.arange(S)[None, :])
-        c = _scatter_local(c, cur, pos, tables, base, nb_local)
+        c = _scatter_local(c, cur, pos, tables, base, nb_local, layer)
         if hop < cp - 1:
             cur = jax.lax.ppermute(cur, cp_axis, perm)
     return c
 
 
-def _gather_slice(pool, tbl_local):
-    """Pool slice [nb_local, Hkv, bs, hd] -> dense per-slot view
-    [B, Hkv, mb*bs, hd] through RE-BASED tables; out-of-slice ids
+def _gather_slice(pool, tbl_local, layer):
+    """Layer ``layer`` of a pool slice [L, nb_local, Hkv, bs, hd] -> dense
+    per-slot view [B, Hkv, mb*bs, hd] through RE-BASED tables (one gather:
+    the layer is not sliced out first); out-of-slice ids
     (negative or >= nb_local) gather zeros (``mode='fill'``) and are
     masked out of the scores by the caller."""
-    g = jnp.take(pool, tbl_local, axis=0, mode="fill", fill_value=0)
+    nb_local = pool.shape[1]
+    # the sentinel, as in _scatter_local: .at[...] would wrap a negative id
+    ids = jnp.where((tbl_local >= 0) & (tbl_local < nb_local), tbl_local,
+                    nb_local)
+    g = pool.at[layer, ids].get(mode="fill", fill_value=0)
     B, mb, Hkv, bs, hd = g.shape
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, mb * bs, hd)
 
@@ -178,13 +189,18 @@ def ring_paged_attend(
     offset,
     *,
     tables: jnp.ndarray,
+    layer: int,
     cp_axis: str,
     window: Optional[int] = None,
     impl: str = "gather",
     sm_scale: Optional[float] = None,
     prefill: bool = False,
 ) -> jnp.ndarray:
-    """Attention of this rank's rows against the cp-sharded pool.
+    """Attention of this rank's rows against layer ``layer`` of the
+    cp-sharded pool ``ck`` / ``cv`` [L, nb_local, Hkv, bs, hd].  The rank's
+    own slice is read where it lies, at ``layer``; what the ring rotates
+    is that one layer (``[1, nb_local, ...]``: the payload of a hop is a
+    copy by nature), read at layer 0.
 
     Prefill (``prefill=True`` — a trace-time flag, not inferred from the
     q length: at ``chunk == cp`` a sub-chunk is one row too): ``q``
@@ -206,9 +222,7 @@ def ring_paged_attend(
     cp = axis_size(cp_axis)
     r = jax.lax.axis_index(cp_axis)
     B, H, S_in, hd = q.shape
-    Hkv = ck.shape[1]
-    nb_local = ck.shape[0]
-    bs = ck.shape[2]
+    _L, nb_local, Hkv, bs, _hd = ck.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
     decode = (not prefill) and cp > 1
@@ -222,17 +236,16 @@ def ring_paged_attend(
         offs_q = jnp.asarray(offset, jnp.int32) + (
             r * S_in if prefill else 0)
         carry = None
-        kk, vv = ck, cv
+        kk, vv, at = ck, cv, layer
         perm = _ring_perm(cp)
         hops = 1 if decode else cp
         for hop in range(hops):
             src = jnp.mod(r - hop, cp)
             carry = paged_carry_attention(
-                q, kk, vv, tables - src * nb_local, offs_q,
+                q, kk, vv, tables - src * nb_local, offs_q, layer=at,
                 carry=carry, window=window, sm_scale=sm_scale)
             if hop < hops - 1:
-                kk = jax.lax.ppermute(kk, cp_axis, perm)
-                vv = jax.lax.ppermute(vv, cp_axis, perm)
+                kk, vv, at = _rotate(kk, vv, at, cp_axis, perm)
         if decode:
             carry = _psum_combine_kernel_carry(carry, cp_axis)
         return finalize_paged_carry(carry, B, H, S_in, hd, q.dtype)
@@ -242,20 +255,19 @@ def ring_paged_attend(
     carry = (jnp.full(shape + (1,), NEG_INF, jnp.float32),
              jnp.zeros(shape + (1,), jnp.float32),
              jnp.zeros(shape + (hd,), jnp.float32))
-    kk, vv = ck, cv
+    kk, vv, at = ck, cv, layer
     perm = _ring_perm(cp)
     hops = 1 if decode else cp
     for hop in range(hops):  # python-unrolled: every hop priced in HLO
         src = jnp.mod(r - hop, cp)
         base = src * nb_local
         valid = _valid_positions(tables, base, nb_local, bs)
-        view_k = _gather_slice(kk, tables - base)
-        view_v = _gather_slice(vv, tables - base)
+        view_k = _gather_slice(kk, tables - base, at)
+        view_v = _gather_slice(vv, tables - base, at)
         carry = _partial_update(q, view_k, view_v, valid, qpos, carry,
                                 sm_scale, window)
         if hop < hops - 1:
-            kk = jax.lax.ppermute(kk, cp_axis, perm)
-            vv = jax.lax.ppermute(vv, cp_axis, perm)
+            kk, vv, at = _rotate(kk, vv, at, cp_axis, perm)
     m, l, acc = carry
     if decode:
         m_g = jax.lax.pmax(m, cp_axis)
@@ -264,6 +276,15 @@ def ring_paged_attend(
         acc = jax.lax.psum(acc * w, cp_axis)
     out = acc / l
     return out.reshape(B, H, S_in, hd).astype(q.dtype)
+
+
+def _rotate(kk, vv, at: int, cp_axis: str, perm):
+    """One hop of the attend ring: layer ``at`` of ``kk`` / ``vv`` moves to
+    the next rank.  Returns the payloads as one-layer stacks and the layer
+    to read them at (0), so a hop's payload is read as the pool is."""
+    one = lambda a: jax.lax.ppermute(
+        jax.lax.slice_in_dim(a, at, at + 1, axis=0), cp_axis, perm)
+    return one(kk), one(vv), 0
 
 
 def _psum_combine_kernel_carry(carry, cp_axis: str):

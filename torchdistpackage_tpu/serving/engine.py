@@ -173,12 +173,14 @@ DRAIN_SCHEMA = "tdp-engine-drain/v1"
 #: Slots (a dp group) that one compiled prefill call carries, at most (an
 #: engine with fewer slots a group carries them all): a tick with n slots
 #: prefilling makes ceil(n / W) calls of this ONE signature.  Every call
-#: pays a fixed price (one pass over the weights, and a copy of the KV pool
-#: that the step does not donate) and every row beyond the prefilling
-#: slots' is computed for nobody.  8: on a v5e a full wave of 64 admissions
-#: then costs about what one 64-slot call did (1.1 s against 0.95 s) and
-#: the usual lone admission a sixth of it (134 against 868 ms); PERF.md
-#: section 6, PR 25, has what 1, 2, 4 and 6 measured.
+#: pays a fixed price (one pass over the weights; until PR 27 also a copy
+#: of the KV pool, which the step now donates and updates where it lies)
+#: and every row beyond the prefilling slots' is computed for nobody.  8
+#: was chosen while the copy was in that price: on a v5e a full wave of 64
+#: admissions then cost about what one 64-slot call did (1.1 s against
+#: 0.95 s) and the usual lone admission a sixth of it (134 against
+#: 868 ms); PERF.md section 6, PR 25, has what 1, 2, 4 and 6 measured, and
+#: section 7 lists measuring them again as the next issue's first contact.
 PREFILL_WIDTH = 8
 
 
@@ -575,8 +577,9 @@ class ServingEngine:
         self.max_ctx = int(max_ctx if max_ctx is not None else cfg.max_seq)
         # spec slack: a verify step writes up to spec_k positions past the
         # committed length, so the table must cover max_ctx + spec_k
-        # positions or the clamp in _scatter_positions would fold an
-        # overshoot write back onto a REAL block
+        # positions: a write past the table's width goes to the NULL block
+        # (paged_cache._write_blocks), and the later drafts of the same
+        # call would attend to keys that were never stored
         self.max_blocks = -(-(self.max_ctx + self.spec_k) // block_size)
         self.dp = int(mesh.shape[dp_axis]) if (mesh is not None and dp_axis) else 1
         if num_slots % self.dp:
@@ -612,8 +615,9 @@ class ServingEngine:
         with span("tdp:engine.init.pool"):
             self.cache = device_step.init_cache()
         #: state models: the recurrent state, one row a slot, beside the
-        #: pool (``models.hybrid.init_state``); the compiled step is handed
-        #: it as a donated argument and the engine keeps what comes back
+        #: pool (``models.hybrid.init_state``); like the pool, the compiled
+        #: step is handed it as a donated argument and the engine keeps
+        #: what comes back
         self.state = None
         self.state_bytes = 0
         if self.state_model:
@@ -704,7 +708,16 @@ class ServingEngine:
         prefilling) — two signatures of the same program, compiled once
         each.  The row count comes from ``tokens.shape[0]`` and the pool
         is reached through ``tables`` alone, so nothing here is
-        ``num_slots`` wide."""
+        ``num_slots`` wide.
+
+        The pool is a DONATED argument of every program that takes it
+        (this one, the state, mesh, ring, verify and copy-on-write
+        programs): the forward carries it whole through the layers and
+        writes a call's rows at ``[layer, block, :, row]``, so the buffer
+        that goes in is the buffer that comes out, held once and never
+        copied.  The array handed in is dead after the call: the engine
+        keeps what comes back (:meth:`_dispatch`), and so must anyone who
+        calls a step by hand."""
         cfg, axis = self.cfg, self.axis
         moe = bool(cfg.moe_experts)
         if self.cp_axis is not None:
@@ -744,14 +757,15 @@ class ServingEngine:
             return cache, tok, keys
 
         if self.mesh is None:
-            return jax.jit(step)
+            return jax.jit(step, donate_argnums=(1,))
         return self._mesh_step(step)
 
     def _build_state_step(self) -> Callable:
         """:meth:`_build_step` for a state model: the same two signatures,
-        with the recurrent ``state`` as a DONATED argument after the pool
-        (each Mamba layer's array is updated where it lies: it is never
-        held twice, as the pool is) and two more row vectors at the end:
+        with the recurrent ``state`` as a second DONATED argument after the
+        pool (each Mamba layer's array is updated where it lies and never
+        held twice, exactly as the pool) and two more row vectors at the
+        end:
         ``rows`` (None: row b is slot b, the decode call; else the slot
         whose state each compact prefill row carries) and ``n_valid`` (the
         real positions of each row: padding advances no state).  The
@@ -791,11 +805,12 @@ class ServingEngine:
                 out += (m["routing"].astype(jnp.int16),)
             return out
 
-        return jax.jit(step, donate_argnums=(2,))
+        return jax.jit(step, donate_argnums=(1, 2))
 
     def _dispatch(self, fn: Callable, args: Tuple[Any, ...]) -> Tuple[Any, ...]:
-        """One call of a compiled step.  Keeps the pool (and a state
-        model's state) that it returns, and hands back the rest: ``(tok,
+        """One call of a compiled step.  The call consumes the pool (and a
+        state model's state): both are donated, so what is kept here is
+        the only live handle to either.  Hands back the rest: ``(tok,
         keys)`` and, where the model has expert layers, their counters."""
         if self.state is None:
             out = fn(self.params, self.cache, *args)
@@ -866,7 +881,8 @@ class ServingEngine:
             # [dp, E] / [dp] globally; the host sums / means the groups
             out_specs = out_specs + (row, row)
         return jax.jit(shard_map(
-            step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs))
+            step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs),
+            donate_argnums=(1,))
 
     def _build_verify_step(self) -> Callable:
         """The speculative verify program — ONE compiled step at a STATIC
@@ -939,7 +955,7 @@ class ServingEngine:
             return cache, ver, acc, carry
 
         if self.mesh is None:
-            return jax.jit(step)
+            return jax.jit(step, donate_argnums=(1,))
         from jax.sharding import PartitionSpec as P
 
         from jax import shard_map
@@ -955,7 +971,8 @@ class ServingEngine:
         )
         out_specs = (self._cache_specs(self.cache), row, row, row)
         return jax.jit(shard_map(
-            step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs))
+            step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs),
+            donate_argnums=(1,))
 
     def _build_cow(self) -> Callable:
         """The copy-on-write program: one fixed-signature block copy
@@ -966,7 +983,7 @@ class ServingEngine:
             return copy_blocks(cache, src, dst)
 
         if self.mesh is None:
-            return jax.jit(cow)
+            return jax.jit(cow, donate_argnums=(0,))
         from jax.sharding import PartitionSpec as P
 
         from jax import shard_map
@@ -975,7 +992,7 @@ class ServingEngine:
         cache_specs = self._cache_specs(self.cache)
         return jax.jit(shard_map(
             cow, mesh=self.mesh, in_specs=(cache_specs, row, row),
-            out_specs=cache_specs))
+            out_specs=cache_specs), donate_argnums=(0,))
 
     def param_specs_cached(self):
         if getattr(self, "_param_specs", None) is None:
@@ -1517,9 +1534,13 @@ class ServingEngine:
                   rids=rids, **state_attr, **first):
             for _, args in batches:
                 if outs:
-                    # one call in flight: the step does not donate the
-                    # pool, so a call queued behind a running one holds it
-                    # a third time (+1.6 GB at 64 x 768 on a v5e)
+                    # one call in flight.  The wait dates from a step that
+                    # did not donate the pool: a call queued behind a
+                    # running one then held it a third time (+1.6 GB at
+                    # 64 x 768 on a v5e).  The pool is donated now (PR 27)
+                    # and a queued call holds nothing more, so the reason
+                    # is gone; deleting the wait is the next PR's to
+                    # measure (PERF.md section 7)
                     jax.block_until_ready(outs[-1][0])
                 outs.append(self._dispatch(self._step_fn, args))
         tok = np.zeros(self.num_slots, np.int32)
@@ -2366,9 +2387,17 @@ class ServingEngine:
         block list, its committed length, and ``n_live`` (blocks holding
         real KV — positions ``0..length-1``; trailing table blocks are
         only budget).  Returns ``(desc, cache)`` where ``cache`` is the
-        engine's CURRENT pool value: jax arrays are immutable, so the
-        snapshot stays valid as a ``migrate_blocks`` source even after
-        this engine frees and reuses the blocks.  The slot is released
+        engine's CURRENT pool, the very buffer and not a copy: it is valid
+        as a ``migrate_blocks`` source UNTIL THIS ENGINE'S NEXT DEVICE CALL
+        (a step, a verify step, a copy-on-write), which donates the pool
+        and leaves this handle deleted; reading it then raises.  Until
+        then the exported blocks hold the request's KV even though the
+        allocator has them back, since only a device call writes the
+        pool.  So the copy out of it (``Router._handoff``: begin, fetch
+        and deliver of every transport, and the bounce back into this
+        engine) has to be dispatched before this engine steps again, and
+        a caller that wants the bytes for longer copies them out first
+        (``ChunkedWireTransport.fetch`` does).  The slot is released
         immediately (blocks freed refcount-aware, rows cleared) — the
         request now lives only in the descriptor, which the router must
         either import somewhere or resume (never both: the
@@ -2395,7 +2424,7 @@ class ServingEngine:
             "ttft_s": s.ttft_s,
             "tpot_s": [float(t) for t in s.tpot_s],
         })
-        cache = self.cache  # immutable pool snapshot: the copy source
+        cache = self.cache  # the copy source, until the next device call
         alloc = self._allocs[i // self.slots_per_group]
         self._release_blocks(alloc, s.blocks)
         self._clear_slot_rows(i)

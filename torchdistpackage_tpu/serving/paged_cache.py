@@ -147,56 +147,104 @@ def expected_pool_bytes(
     return 2 * per_kv  # k and v
 
 
-def _scatter_positions(tables: jnp.ndarray, pos: jnp.ndarray, block_size: int):
-    """Map absolute per-slot positions [B, S] -> (block ids [B*S], in-block
-    offsets [B*S]) through the block tables.  Positions past a table's
-    width clamp to its last entry — unallocated entries are NULL_BLOCK, so
-    overshoot (padded prefill tails) lands in the write-off block."""
+def _write_blocks(tables: jnp.ndarray, offset: jnp.ndarray, S_in: int,
+                  block_size: int):
+    """The pool blocks that rows ``offset[b] + arange(S_in)`` fall in, as
+    :func:`paged_write` rewrites them: ``(blk [B, n], src [B, n, bs],
+    valid [B, n, bs])``.  Slot b's rows span at most ``n`` consecutive
+    table columns from ``offset[b] // bs``; row r of its j-th block is
+    position ``(offset[b] // bs + j) * bs + r``, which the call's row
+    ``src`` supplies where ``valid`` and which keeps what it holds
+    elsewhere.  A column past the table's width (a padded tail's
+    overshoot), or one that none of the call's rows falls in, is sent to
+    the NULL block; an unallocated column already names it."""
     max_blocks = tables.shape[1]
+    n = (S_in + block_size - 2) // block_size + 1
+    col = (offset // block_size)[:, None] + jnp.arange(n)[None, :]  # [B, n]
+    src = (col * block_size - offset[:, None])[..., None] + jnp.arange(
+        block_size)                                               # [B, n, bs]
+    valid = (src >= 0) & (src < S_in)
+    live = (col < max_blocks) & valid.any(-1)
     blk = jnp.take_along_axis(
-        tables, jnp.clip(pos // block_size, 0, max_blocks - 1), axis=1)
-    return blk.reshape(-1), (pos % block_size).reshape(-1)
+        tables, jnp.clip(col, 0, max_blocks - 1), axis=1)
+    return jnp.where(live, blk, NULL_BLOCK), jnp.clip(src, 0, S_in - 1), valid
 
 
-def paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray):
-    """Scatter ``val`` [B, Hkv, S_in, hd] into the per-layer pool ``c``
-    ([num_blocks, Hkv, bs, hd] or its quantized pair) at per-slot positions
-    ``offset[b] + arange(S_in)`` via the block tables.  Live slots own
-    disjoint blocks, so the scatter has no racing duplicates (only the
-    NULL block absorbs colliding writes, and it is never read)."""
+def paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray,
+                layer=None):
+    """Write ``val`` [B, Hkv, S_in, hd] into layer ``layer`` of the pool
+    ``c`` ([L, num_blocks, Hkv, bs, hd] or its quantized pair) at per-slot
+    positions ``offset[b] + arange(S_in)`` via the block tables, and return
+    the WHOLE pool.  ``layer=None``: ``c`` is one layer's ``[num_blocks,
+    Hkv, bs, hd]``.
+
+    The write is by whole blocks (:func:`_write_blocks`): the blocks the
+    rows fall in are read at ``[layer, blk]``, the call's rows laid over
+    them, and the blocks scattered back.  A scatter whose update is a
+    whole ``[Hkv, bs, hd]`` block is contiguous in the pool AS IT LIES, so
+    on a loop carry (or a donated argument) XLA updates the buffer in
+    place.  The row form ``c.at[layer, blk, :, idx]`` is not: its
+    ``[Hkv, hd]`` window straddles ``bs``, XLA:TPU gives such a scatter a
+    pool with ``bs`` and ``Hkv`` swapped, and the paged kernel wants the
+    pool as it lies, so every layer paid two copies of the pool between
+    the two layouts (PERF.md section 6, PR 27).  Live slots own disjoint
+    blocks, so the scatter has no racing duplicates (only the NULL block
+    absorbs colliding writes, and it is never read)."""
+    if layer is None:
+        whole = paged_write(jax.tree.map(lambda a: a[None], c), val, offset,
+                            tables=tables, layer=0)
+        return jax.tree.map(lambda a: a[0], whole)
     B, Hkv, S_in, hd = val.shape
-    bs = (c[0] if isinstance(c, tuple) else c).shape[2]
-    pos = jnp.asarray(offset)[:, None] + jnp.arange(S_in)[None, :]  # [B, S]
-    blk, idx = _scatter_positions(tables, pos, bs)
-    vals = val.transpose(0, 2, 1, 3).reshape(B * S_in, Hkv, hd)
+    bs = (c[0] if isinstance(c, tuple) else c).shape[3]
+    blk, src, valid = _write_blocks(
+        tables, jnp.asarray(offset, jnp.int32), S_in, bs)
+    n = blk.shape[1]
+
+    def put(pool, rows):
+        """``rows`` [B, Hkv, S_in, ...] over the blocks ``blk`` of ``pool``
+        [L, nb, Hkv, bs, ...]."""
+        tail = rows.shape[3:]
+        pick = src.reshape((B, 1, n * bs) + (1,) * len(tail))
+        new = jnp.take_along_axis(rows, pick, axis=2).reshape(
+            (B, Hkv, n, bs) + tail).swapaxes(1, 2)  # [B, n, Hkv, bs, ...]
+        keep = valid.reshape((B, n, 1, bs) + (1,) * len(tail))
+        new = jnp.where(keep, new.astype(pool.dtype), pool[layer, blk])
+        return pool.at[layer, blk.reshape(-1)].set(
+            new.reshape((B * n,) + new.shape[2:]))
+
     if isinstance(c, tuple):
-        q8, scale = c
-        vq, vs = _kv_quant(vals)  # per-vector: identical to contiguous path
-        return (q8.at[blk, :, idx].set(vq), scale.at[blk, :, idx].set(vs))
-    return c.at[blk, :, idx].set(vals.astype(c.dtype))
+        vq, vs = _kv_quant(val)  # per-vector: identical to contiguous path
+        return (put(c[0], vq), put(c[1], vs))
+    return put(c, val)
 
 
-def gather_kv(c, tables: jnp.ndarray):
-    """Per-layer pool -> dense per-slot view [B, Hkv, max_blocks*bs, hd]
-    (or its quantized pair) through the block tables.  Gathered index ==
-    slot-relative position, so the result drops straight into
-    ``_cached_attention`` in place of the contiguous buffer."""
+def gather_kv(c, tables: jnp.ndarray, layer=None):
+    """Layer ``layer`` of the pool (``None``: ``c`` is one layer's) ->
+    dense per-slot view [B, Hkv, max_blocks*bs, hd] (or its quantized
+    pair) through the block tables: ONE gather at ``[layer, tables]``, the
+    layer is never sliced out first.  Gathered index == slot-relative
+    position, so the result drops straight into ``_cached_attention`` in
+    place of the contiguous buffer."""
+    at = (lambda a: a[tables]) if layer is None else (
+        lambda a: a[layer, tables])
     if isinstance(c, tuple):
         q8, scale = c
-        g = q8[tables]  # [B, nb, Hkv, bs, hd]
+        g = at(q8)  # [B, nb, Hkv, bs, hd]
         B, nb, Hkv, bs, hd = g.shape
-        gs = scale[tables].transpose(0, 2, 1, 3).reshape(B, Hkv, nb * bs)
+        gs = at(scale).transpose(0, 2, 1, 3).reshape(B, Hkv, nb * bs)
         return (g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, nb * bs, hd), gs)
-    g = c[tables]
+    g = at(c)
     B, nb, Hkv, bs, hd = g.shape
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, nb * bs, hd)
 
 
 def paged_attention(
     q: jnp.ndarray, ck, cv, offset, *, tables: jnp.ndarray, window=None,
-    impl: str = "gather",
+    impl: str = "gather", layer=None,
 ) -> jnp.ndarray:
-    """Attention of q [B, H, S_in, hd] against each slot's paged context.
+    """Attention of q [B, H, S_in, hd] against each slot's paged context
+    in layer ``layer`` of the pools ``ck`` / ``cv`` (``None``: they are one
+    layer's).
 
     ``impl='gather'`` (the parity oracle and CPU fallback): gather the
     slot's blocks into a dense ``[B, Hkv, max_blocks*bs, hd]`` view, then
@@ -210,19 +258,22 @@ def paged_attention(
         from ..ops.paged_attention import paged_decode_attention
 
         return paged_decode_attention(q, ck, cv, tables, offset,
-                                      window=window)
+                                      layer=layer, window=window)
     return _cached_attention(
-        q, gather_kv(ck, tables), gather_kv(cv, tables), offset,
-        window=window)
+        q, gather_kv(ck, tables, layer), gather_kv(cv, tables, layer),
+        offset, window=window)
 
 
-def _paged_cache_ops(tables: jnp.ndarray, attn_impl: str = "gather"):
-    """The ``cache_ops`` pair ``cached_block_forward`` needs to run on the
-    block pool instead of the contiguous buffer."""
+def _paged_cache_ops(tables: jnp.ndarray, attn_impl: str, layer):
+    """The ``cache_ops`` pair ``cached_block_forward`` needs to run one
+    layer on the block pool instead of the contiguous buffer: the cache it
+    threads through is the WHOLE pool, and ``layer`` (a python int in an
+    unrolled loop, the scan's counter otherwise) is where both ops reach
+    into it.  The one way a layer reaches the pool."""
     def attend(q, ck, cv, offset, window=None):
         return paged_attention(q, ck, cv, offset, tables=tables,
-                               window=window, impl=attn_impl)
-    return functools.partial(paged_write, tables=tables), attend
+                               window=window, impl=attn_impl, layer=layer)
+    return functools.partial(paged_write, tables=tables, layer=layer), attend
 
 
 def _batched_rope(bcfg, positions: jnp.ndarray):
@@ -266,9 +317,11 @@ def paged_forward(
     through the cached stack, writing k/v into each slot's blocks and
     attending through its table.  Returns the updated pool and the logits
     [B, V_local] read at per-slot row ``last_idx`` (default: the last row
-    — the decode case).  The layer dim rides the same ``lax.scan`` as the
-    contiguous path; chunked prefill is just S_in=chunk at a running
-    offset — one implementation, both phases, either layout.
+    — the decode case).  The layers ride a ``lax.scan`` over the stacked
+    block params and a layer counter, with the pool as a carry that every
+    layer updates at ``[layer, ...]`` (docs/serving.md "The pool's life");
+    chunked prefill is just S_in=chunk at a running offset — one
+    implementation, both phases, either layout.
 
     ``all_logits=True`` returns the per-position logits [B, S_in,
     V_local] instead — the multi-position evaluation the speculative
@@ -284,17 +337,19 @@ def paged_forward(
     positions = offset[:, None] + jnp.arange(S_in)[None, :]
     h = _embed_at(params, tokens, positions, axis)
     rope = _batched_rope(bcfg, positions)
-    ops = _paged_cache_ops(tables, attn_impl)
 
-    def body(hc, xs):
-        lp, ck, cv = xs
-        y, ck, cv = cached_block_forward(
+    def body(carry, xs):
+        # the pool is a CARRY, whole: as the scan's xs / ys every layer
+        # would slice its share out of the stack and write it back
+        hc, ck, cv = carry
+        lp, li = xs
+        return cached_block_forward(
             lp, hc, bcfg, ck, cv, offset, axis=axis, rope=rope,
-            cache_ops=ops)
-        return y, (ck, cv)
+            cache_ops=_paged_cache_ops(tables, attn_impl, li)), None
 
-    h, (ck, cv) = jax.lax.scan(
-        body, h, (params["blocks"], cache["k"], cache["v"]))
+    (h, ck, cv), _ = jax.lax.scan(
+        body, (h, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(cfg.nlayers)))
     if all_logits:
         return {"k": ck, "v": cv}, gpt_head(params, h, axis, False,
                                             eps=cfg.norm_eps)
@@ -304,9 +359,10 @@ def paged_forward(
 
 
 def _cp_paged_cache_ops(tables: jnp.ndarray, cp_axis: str, attn_impl: str,
-                        prefill: bool):
-    """``cache_ops`` pair running ``cached_block_forward`` on a pool whose
-    block dim is sharded over ``cp_axis`` (ops/ring_paged.py): the write
+                        prefill: bool, layer: int):
+    """``cache_ops`` pair running layer ``layer`` of
+    ``cached_block_forward`` on a pool whose block dim is sharded over
+    ``cp_axis`` (ops/ring_paged.py), the pool threaded whole: the write
     ring completes the chunk's pool write BEFORE attend runs (the pair is
     called write-then-attend), so the attend ring only ever rotates pool
     slices.  ``prefill`` is the trace-time phase flag (S_in of the FULL
@@ -315,12 +371,12 @@ def _cp_paged_cache_ops(tables: jnp.ndarray, cp_axis: str, attn_impl: str,
     from ..ops.ring_paged import ring_paged_attend, ring_paged_write
 
     def write(c, val, offset):
-        return ring_paged_write(c, val, offset, tables=tables,
+        return ring_paged_write(c, val, offset, tables=tables, layer=layer,
                                 cp_axis=cp_axis, prefill=prefill)
 
     def attend(q, ck, cv, offset, window=None):
         return ring_paged_attend(q, ck, cv, offset, tables=tables,
-                                 cp_axis=cp_axis, window=window,
+                                 layer=layer, cp_axis=cp_axis, window=window,
                                  impl=attn_impl, prefill=prefill)
     return write, attend
 
@@ -379,18 +435,14 @@ def cp_paged_forward(
         positions = offset[:, None] + r * sub + jnp.arange(sub)[None, :]
     h = _embed_at(params, my_tokens, positions, axis)
     rope = _batched_rope(bcfg, positions)
-    ops = _cp_paged_cache_ops(tables, cp_axis, attn_impl,
-                              prefill=not decode)
-
-    cks, cvs = [], []
+    ck, cv = cache["k"], cache["v"]
     for li in range(cfg.nlayers):  # unrolled: one HLO permute per hop
         lp = jax.tree_util.tree_map(lambda a: a[li], params["blocks"])
         h, ck, cv = cached_block_forward(
-            lp, h, bcfg, cache["k"][li], cache["v"][li], offset, axis=axis,
-            rope=rope, cache_ops=ops)
-        cks.append(ck)
-        cvs.append(cv)
-    new_cache = {"k": jnp.stack(cks), "v": jnp.stack(cvs)}
+            lp, h, bcfg, ck, cv, offset, axis=axis, rope=rope,
+            cache_ops=_cp_paged_cache_ops(
+                tables, cp_axis, attn_impl, prefill=not decode, layer=li))
+    new_cache = {"k": ck, "v": cv}
 
     if decode or cp == 1:
         # decode h is replicated over cp (psum-combined attends on
@@ -464,7 +516,6 @@ def paged_forward_moe(
     positions = offset[:, None] + jnp.arange(S_in)[None, :]
     h = _embed_at(params, tokens, positions, axis)
     rope = _batched_rope(bcfg, positions)
-    ops = _paged_cache_ops(tables, attn_impl)
 
     collected = []  # per-MoE-layer metrics dicts (moe_stats)
     if ep_axis is None:
@@ -489,18 +540,14 @@ def paged_forward_moe(
             z, _aux = out
             return z
 
-    ks, vs = [], []
-    layer = lambda c, i: jax.tree.map(lambda a: a[i], c)  # tuple-safe (int8)
+    ck, cv = cache["k"], cache["v"]
     for i, bp in enumerate(params["blocks"]):
         h, ck, cv = cached_block_forward(
-            bp, h, bcfg, layer(cache["k"], i), layer(cache["v"], i), offset,
+            bp, h, bcfg, ck, cv, offset,
             axis=axis, rope=rope, ffn=moe_ffn if "moe" in bp else None,
-            cache_ops=ops,
+            cache_ops=_paged_cache_ops(tables, attn_impl, i),
         )
-        ks.append(ck)
-        vs.append(cv)
-    stack = lambda cs: jax.tree.map(lambda *xs: jnp.stack(xs), *cs)
-    cache = {"k": stack(ks), "v": stack(vs)}
+    cache = {"k": ck, "v": cv}
     metrics = None
     if moe_stats:
         # sum routed-token counts over the MoE layers, mean the drop rate
@@ -553,7 +600,7 @@ def paged_forward_hybrid(
     from ..models.hybrid import hybrid_paged_forward
 
     offset = jnp.asarray(offset, jnp.int32)
-    ops = _paged_cache_ops(tables, attn_impl)
+    ops = functools.partial(_paged_cache_ops, tables, attn_impl)
     mine = state
     if rows is not None:
         def own(a):
@@ -600,8 +647,9 @@ def migrate_blocks(
     (src, dst) pool pair whatever a migration needs moved; unused lanes
     are padded ``NULL -> NULL`` (the write-off block is never read, so
     colliding pad writes are harmless).  Returns the updated dst cache;
-    the src cache is read-only (jax arrays are immutable, so a snapshot
-    taken before the source engine reuses the blocks stays valid).
+    the src cache is only read (it is the source engine's own buffer: run
+    this before that engine's next device call, which donates it,
+    ``ServingEngine.export_slot``).
 
     ``compress=True`` models the int8 WIRE format of a DCN-crossing
     transfer on an fp pool: the payload is quantized per position-vector

@@ -34,7 +34,8 @@ engine, built entirely from primitives the engines already prove:
   completion (first token sampled — TTFT stops ticking there), then the
   router hands the request to a ``'decode'`` replica by migrating the
   paged KV blocks themselves: :meth:`~.engine.ServingEngine.export_slot`
-  (descriptor + immutable pool snapshot) →
+  (descriptor + the source's pool, good until the source's next device
+  call: ``_handoff`` copies out of it before any replica steps again) →
   :meth:`~.engine.ServingEngine.import_slot` (decode-phase admission, no
   prefill) → :func:`~.paged_cache.migrate_blocks` (the ``copy_blocks``
   NULL-padded-lane idiom generalized across pools, ONE fixed-signature
@@ -573,6 +574,10 @@ class Router:
                 outcome="deferred", chosen=None, need_blocks=need,
                 candidates=candidates)
             return False
+        # src_cache is p's own buffer, donated by p's next device call: every
+        # read of it below (begin / fetch / deliver, the bounce's lane copy)
+        # is dispatched before this routine returns, and nothing in here
+        # steps p
         desc, src_cache = p.export_slot(rid)
         # the in-flight window opens: until the import lands, the request
         # exists ONLY in `desc` — audit() counts this record as its one
