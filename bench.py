@@ -110,13 +110,9 @@ LONG_CANDIDATES = [
 # dispatch).  Measured 2026-07-31 (docs/BENCH_AB.md): b8 sorted 66,636
 # tok/s (MFU 0.358 activated) wins; sorted beats dense 10.2% at the
 # identical b2 config.  Dense at b>=4 is untestable (the [T, E, C]
-# one-hots alone exceed HBM).  PR 18 adds the fused Pallas dispatch
-# ('pallas': gather -> expert FFN -> weighted scatter in one kernel, no
-# [E, C, D] slot view in HBM — ops/moe_dispatch.py) as a paired arm
-# against the sorted incumbent; it has no on-chip number.
+# one-hots alone exceed HBM).
 MOE_CANDIDATES = [
     (8, "flash", None, "sorted"),
-    (8, "flash", None, "pallas"),
     (16, "flash", None, "sorted"),
     (2, "flash", None, "sorted"),
     (2, "flash", None, "dense"),

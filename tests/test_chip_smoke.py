@@ -120,9 +120,9 @@ def test_paged_lowers_for_tpu_at_smoke_shapes(monkeypatch, s_in, quantized):
 
 def test_tpu_autos_pick_paths_that_lower(monkeypatch):
     """What each ``auto`` resolves to on a TPU: the paged kernel (covered
-    above, both pools) and the sorted MoE dispatch, which is plain jnp — the
-    fused MoE kernel does not lower for TPU and ``auto`` must not pick it."""
-    from torchdistpackage_tpu.ops import resolve_attn_impl, resolve_moe_dispatch
+    above, both pools) and the sorted MoE dispatch, which is plain jnp."""
+    from torchdistpackage_tpu.ops import resolve_attn_impl
+    from torchdistpackage_tpu.parallel.moe import resolve_moe_dispatch
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert resolve_attn_impl("auto") == "pallas"

@@ -110,6 +110,88 @@ def test_parse_hlo_async_start_counted_once():
     assert recs[0]["bytes"] == 32
 
 
+# jax 0.9 prints operands BY NAME ('all-reduce(%x)'): an operand's type is
+# the result type of the instruction it names.  Each case: HLO text, then
+# the (op, bytes, async) the parser must read from it.
+_PARAMS = (
+    "%p = f32[16,16]{1,0} parameter(0)\n"
+    "%c = bf16[16,16]{1,0} convert(%p)\n"
+    "%g = f32[64,16]{1,0} parameter(1)\n"
+)
+_GROUP4 = "channel_id=1, replica_groups={{0,1,2,3}}"
+
+
+def _compiled_int8_ppermute():
+    """The line the installed JAX itself prints for a four-device int8
+    ``ppermute``: the next JAX that changes the text fails HERE."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("x",))
+    f = jax.jit(shard_map(
+        lambda a: jax.lax.ppermute(
+            a, "x", [(i, (i + 1) % 4) for i in range(4)]),
+        mesh=mesh, in_specs=P("x"), out_specs=P("x")))
+    return f.lower(jnp.ones((8, 128), jnp.int8)).compile().as_text()
+
+
+_BY_NAME_CASES = {
+    "all-reduce": (
+        _PARAMS + "%ar = f32[16,16]{1,0} all-reduce(%p), " + _GROUP4
+        + ", to_apply=%add",
+        [("all-reduce", 16 * 16 * 4, False)]),
+    "all-gather": (  # the operand is the local shard: x group size
+        _PARAMS + "%ag = f32[64,16]{1,0} all-gather(%p), " + _GROUP4
+        + ", dimensions={0}",
+        [("all-gather", 16 * 16 * 4 * 4, False)]),
+    "reduce-scatter": (
+        _PARAMS + "%rs = f32[16,16]{1,0} reduce-scatter(%g), " + _GROUP4
+        + ", dimensions={0}, to_apply=%add",
+        [("reduce-scatter", 64 * 16 * 4, False)]),
+    "all-to-all": (  # variadic: the payload is the sum of the operands
+        _PARAMS + "%s0 = f32[1,16,16]{2,1,0} bitcast(%p)\n"
+        "%a2a = (f32[1,16,16]{2,1,0}, f32[1,16,16]{2,1,0}) "
+        "all-to-all(%s0, %s0), channel_id=1, replica_groups={{0,1}}",
+        [("all-to-all", 2 * 16 * 16 * 4, False)]),
+    "collective-permute": (
+        _compiled_int8_ppermute,
+        [("collective-permute", 2 * 128, False)]),
+    "start-done": (  # the -start's result is a tuple; its operand is not
+        _PARAMS + "%ags = (f32[16,16]{1,0}, f32[64,16]{1,0}) "
+        "all-gather-start(%p), " + _GROUP4 + ", dimensions={0}\n"
+        "%agd = f32[64,16]{1,0} all-gather-done(%ags)",
+        [("all-gather", 16 * 16 * 4 * 4, True)]),
+    "tuple-result": (  # XLA's combiner: two dtypes in one all-reduce
+        _PARAMS + "%ar = (f32[16,16]{1,0}, bf16[16,16]{1,0}) "
+        "all-reduce(%p, %c), " + _GROUP4 + ", to_apply=%add",
+        [("all-reduce", 16 * 16 * 4 + 16 * 16 * 2, False)]),
+    "tpu-layout": (  # a TPU layout carries parentheses of its own
+        "%p = s8[2,128]{1,0:T(8,128)(4,1)} parameter(0)\n"
+        "%cp = s8[2,128]{1,0:T(8,128)(4,1)} collective-permute(%p), "
+        "channel_id=1, source_target_pairs={{0,1},{1,0}}",
+        [("collective-permute", 2 * 128, False)]),
+    "no-definition": (  # nothing defines %nowhere: an error, never 0 bytes
+        "%ar = f32[16,16]{1,0} all-reduce(%nowhere), " + _GROUP4,
+        ValueError),
+}
+
+
+@pytest.mark.parametrize("case", list(_BY_NAME_CASES))
+def test_parse_hlo_operands_by_name(case, devices8):
+    hlo, want = _BY_NAME_CASES[case]
+    if callable(hlo):
+        hlo = hlo()
+    if want is ValueError:
+        with pytest.raises(ValueError, match=r"all-reduce\(%nowhere\)"):
+            parse_hlo_collectives(hlo)
+        return
+    recs = parse_hlo_collectives(hlo)
+    assert [(r["op"], r["bytes"], r["async"]) for r in recs] == want
+    if case == "start-done":
+        assert recs[0]["sched_distance"] == 0
+    if case == "collective-permute":
+        assert sorted(recs[0]["pairs"]) == [(0, 1), (1, 2), (2, 3), (3, 0)]
+
+
 def test_parse_hlo_overlap_window_records_collectives_inside():
     """TP-under-PP overlap evidence (PR 14): collectives issued between an
     async op's -start and -done land in its ``overlapped_idx``, and
@@ -194,15 +276,21 @@ def test_ledger_tp_dp_step_dp_bytes_match_params(devices8):
     params = jnp.ones((D, D), jnp.float32)
 
     def body(p, x):
-        y = x @ p
-        y = jax.lax.psum(y, "tensor")          # tp activation collective
-        loss = (y ** 2).mean()
-        g = jax.grad(lambda p_: ((x @ p_) ** 2).mean())(p)
+        # a per-device copy of p, as DataParallel takes its grads: the grad
+        # of an UNVARYING p is already summed over the mesh by autodiff
+        # (jax 0.9's typed shard_map), and the explicit sync below would be
+        # a second all-reduce of the tree
+        pv = jax.lax.pcast(p, ("data", "tensor"), to="varying")
+        y = jax.lax.psum(x @ pv, "tensor")     # tp activation collective
+        loss = (y ** 2).mean()[None]           # one per data shard
+        g = jax.grad(lambda p_: ((x @ p_) ** 2).mean())(
+            jax.lax.pcast(p, "data", to="varying"))
         g = jax.lax.psum(g, "data")            # dp grad sync
         return loss, g
 
     f = jax.jit(shard_map(
-        body, mesh=mesh, in_specs=(P(), P("data")), out_specs=(P(), P())))
+        body, mesh=mesh, in_specs=(P(), P("data")),
+        out_specs=(P("data"), P())))
     compiled = f.lower(params, jnp.ones((8, D), jnp.float32)).compile()
     ledger = ledger_from_compiled(compiled, mesh=mesh)
     assert ledger is not None and ledger["n_collectives"] >= 2
@@ -466,7 +554,12 @@ def test_telemetry_runreport_comm_section(devices8, tmp_path):
     D = 16
 
     def body(p, x):
-        g = jax.grad(lambda p_: ((x @ p_) ** 2).mean())(p)
+        # the grad of a per-device copy, as DataParallel takes it: the grad
+        # of an UNVARYING p is already summed over "data" by autodiff (jax
+        # 0.9's typed shard_map) and the sync below would be a second
+        # all-reduce of the tree — which the ledger reads truthfully
+        g = jax.grad(lambda p_: ((x @ p_) ** 2).mean())(
+            jax.lax.pcast(p, "data", to="varying"))
         return jax.lax.psum(g, "data").mean()
 
     f = jax.jit(shard_map(

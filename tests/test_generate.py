@@ -122,8 +122,8 @@ MOE_CFGS = {
 @pytest.mark.parametrize("name", [
     # both params drive the SAME no-drop decode dispatch, which by PR-20
     # is fast-tier-covered end to end elsewhere: token bit parity by
-    # test_moe_dispatch.py::test_engine_token_bit_parity and the
-    # dispatch math by test_fused_matches_sorted_and_dense_fwd_and_grad
+    # test_serving.py::test_moe_engine_token_bit_parity and the
+    # dispatch math by test_moe.py::test_sorted_dispatch_matches_dense
     # — so BOTH teacher-forced goldens ride the slow tier now (tier-1
     # budget, PR-13 payback idiom)
     pytest.param("moe", marks=pytest.mark.slow),

@@ -187,7 +187,7 @@ def test_the_mixtral_shaped_layer_keeps_its_results_bit_for_bit(act, dtype):
     params = init_moe_params(jax.random.PRNGKey(3), cfg)
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 7, 32)).astype(dtype)
     new, met = jax.jit(lambda p, x: moe_serve_forward(
-        p, x, cfg, dispatch="gather", return_metrics=True))(params, x)
+        p, x, cfg, return_metrics=True))(params, x)
     old = jax.jit(lambda p, x: _old_serve_forward(p, x, cfg))(params, x)
     np.testing.assert_array_equal(np.asarray(new, np.float32),
                                   np.asarray(old, np.float32))

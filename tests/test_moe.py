@@ -133,42 +133,40 @@ def test_gpt_moe_gqa_specs_match_params(devices8):
     assert np.isfinite(float(loss))
 
 
-# PR-18 tier-1 payback: the fast-tier holder for this claim is
-# test_moe_dispatch.py::test_fused_matches_sorted_and_dense_fwd_and_grad
-# (pallas vs sorted vs dense, fwd+grads, drops included) — this full
-# router x capacity matrix (expert_choice included) stays slow-tier.
-@pytest.mark.slow
-def test_sorted_dispatch_matches_dense():
+# 'dense' is the oracle the sorted dispatch is held to.  The point that
+# DROPS (priority + dumpster row) holds the claim in the fast tier since the
+# fused kernel and its test went (PR 28); the rest of the router x capacity
+# matrix stays slow-tier.
+@pytest.mark.parametrize("router,cf", [
+    ("topk", 0.6),    # drops: priority/dumpster path exercised
+    pytest.param("topk", 4.0, marks=pytest.mark.slow),    # no drops
+    pytest.param("expert_choice", 1.0, marks=pytest.mark.slow),
+])
+def test_sorted_dispatch_matches_dense(router, cf):
     """The index-based (gather/scatter-add) dispatch must reproduce the
     dense [T,E,C] einsum path — same routing decision, same outputs and
     GRADS, for both routers, including a capacity that actually drops."""
     import dataclasses
 
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, CFG.dim))
+    dense_cfg = dataclasses.replace(
+        CFG, router=router, capacity_factor=cf, dispatch="dense")
+    sort_cfg = dataclasses.replace(dense_cfg, dispatch="sorted")
+    params = init_moe_params(jax.random.PRNGKey(0), dense_cfg)
 
-    for router, cf in [
-        ("topk", 4.0),    # no drops
-        ("topk", 0.6),    # drops: priority/dumpster path exercised
-        ("expert_choice", 1.0),
-    ]:
-        dense_cfg = dataclasses.replace(
-            CFG, router=router, capacity_factor=cf, dispatch="dense")
-        sort_cfg = dataclasses.replace(dense_cfg, dispatch="sorted")
-        params = init_moe_params(jax.random.PRNGKey(0), dense_cfg)
+    def loss(p, cfg):
+        y, aux = moe_forward(p, x, cfg)
+        return jnp.mean(y * y) + aux
 
-        def loss(p, cfg):
-            y, aux = moe_forward(p, x, cfg)
-            return jnp.mean(y * y) + aux
-
-        ls, gs = jax.value_and_grad(functools.partial(loss, cfg=sort_cfg))(params)
-        ld, gd = jax.value_and_grad(functools.partial(loss, cfg=dense_cfg))(params)
-        np.testing.assert_allclose(float(ls), float(ld), rtol=1e-6)
-        jax.tree.map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
-            ),
-            gs, gd,
-        )
+    ls, gs = jax.value_and_grad(functools.partial(loss, cfg=sort_cfg))(params)
+    ld, gd = jax.value_and_grad(functools.partial(loss, cfg=dense_cfg))(params)
+    np.testing.assert_allclose(float(ls), float(ld), rtol=1e-6)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
+        ),
+        gs, gd,
+    )
 
 
 def test_dispatch_auto_threshold():
@@ -185,9 +183,48 @@ def test_dispatch_auto_threshold():
     assert not _use_sorted("dense", T=big_T, E=E, capacity=8)
 
 
-# PR-18 tier-1 payback: fast-tier EP coverage now lives in
-# test_moe_dispatch.py::test_fused_ep_matches_sorted (pallas vs sorted
-# fwd+grads on a 2x2 mesh) plus test_moe_ep_matches_serial below; this
+@pytest.mark.parametrize("bad", ["pallas", "cuda"])
+def test_dispatch_values_validated(bad):
+    """The dispatch values are the ones that exist: the fused Pallas
+    dispatch went in PR 28 (it never lowered for the TPU), and every layer
+    that takes the option names the list when it refuses."""
+    import dataclasses
+
+    from torchdistpackage_tpu.models import GPTConfig
+    from torchdistpackage_tpu.parallel.moe import (
+        MOE_DISPATCHES, resolve_moe_dispatch)
+
+    assert MOE_DISPATCHES == ("dense", "sorted", "auto")
+    for make in (lambda: dataclasses.replace(CFG, dispatch=bad),
+                 lambda: resolve_moe_dispatch(bad),
+                 lambda: GPTConfig(vocab_size=64, dim=32, nheads=4,
+                                   nlayers=2, max_seq=32, moe_experts=4,
+                                   moe_dispatch=bad)):
+        with pytest.raises(ValueError, match="'dense', 'sorted', 'auto'"):
+            make()
+
+
+def test_resolve_moe_dispatch_records_auto():
+    """'auto' resolves per backend (the size rule on the CPU; 'sorted' on a
+    TPU is test_chip_smoke's assertion) and records the choice on the
+    event timeline; explicit values pass through."""
+    from torchdistpackage_tpu.obs.events import EventLog, set_default_event_log
+    from torchdistpackage_tpu.parallel.moe import resolve_moe_dispatch
+
+    log = EventLog()
+    set_default_event_log(log)
+    try:
+        assert resolve_moe_dispatch("auto") == "auto"
+        assert resolve_moe_dispatch(None) == "auto"
+        sel = log.of_kind("moe_dispatch_selected")
+        assert len(sel) == 2 and sel[-1]["chosen"] == "auto"
+    finally:
+        set_default_event_log(None)
+    for ok in ("dense", "sorted"):
+        assert resolve_moe_dispatch(ok) == ok
+
+
+# Fast-tier EP coverage is test_moe_ep_matches_serial below; this
 # EP=4-vs-serial-chunks golden stays slow-tier.
 @pytest.mark.slow
 def test_sorted_dispatch_under_ep_matches_serial(devices8):

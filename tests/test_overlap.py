@@ -219,7 +219,9 @@ def test_ring_ag_matmul_matches_fused(devices8):
     def ring(xs, w):
         return ring_ag_matmul(xs, lambda c: c @ w, "tensor")
 
-    specs = dict(in_specs=(P(None, "tensor"), P()), out_specs=P())
+    # every shard holds the whole product, but a gathered value is TYPED
+    # varying (jax 0.9): the shards' copies come back stacked
+    specs = dict(in_specs=(P(None, "tensor"), P()), out_specs=P("tensor"))
 
     def out_and_grad(f):
         # ONE compiled program per variant: fwd output rides as aux of the
@@ -297,10 +299,14 @@ def test_collective_matmul_transformer_parity(devices8):
         # one compiled program per config: forward output rides as aux of
         # the grad pass (tier-1 compile budget)
         def f(p, xx):
-            out = transformer_forward(p, xx, c, axis="tensor", sp=True)
-            return (out ** 2).mean(), out
+            # the output stays sequence-sharded and out_specs reassembles
+            # it: a gathered value is typed varying, never P()
+            out = transformer_forward(p, xx, c, axis="tensor", sp=True,
+                                      gather_output=False)
+            return jax.lax.pmean((out ** 2).mean(), "tensor"), out
 
-        sm = shard_map(f, mesh=mesh, in_specs=(specs, P()), out_specs=(P(), P()))
+        sm = shard_map(f, mesh=mesh, in_specs=(specs, P()),
+                       out_specs=(P(), P(None, "tensor")))
         (_, out), g = jax.jit(
             jax.value_and_grad(lambda p: sm(p, x), has_aux=True))(params)
         return out, g
@@ -323,9 +329,11 @@ def test_collective_matmul_gqa_swiglu_rope_parity(devices8):
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 16, 64))
 
     def run(c):
-        f = lambda p, xx: transformer_forward(p, xx, c, axis="tensor", sp=True)
+        f = lambda p, xx: transformer_forward(
+            p, xx, c, axis="tensor", sp=True, gather_output=False)
         return jax.jit(shard_map(
-            f, mesh=mesh, in_specs=(specs, P()), out_specs=P()))(params, x)
+            f, mesh=mesh, in_specs=(specs, P()),
+            out_specs=P(None, "tensor")))(params, x)
 
     np.testing.assert_allclose(
         np.asarray(run(cfg)), np.asarray(run(cfg_cm)), atol=2e-4)
@@ -342,9 +350,10 @@ def test_collective_matmul_ledger_shows_ring(devices8):
     x = jnp.ones((2, 16, 32))
 
     def compiled_for(c):
-        f = lambda p, xx: transformer_forward(p, xx, c, axis="tensor", sp=True)
+        f = lambda p, xx: transformer_forward(
+            p, xx, c, axis="tensor", sp=True, gather_output=False)
         return jax.jit(shard_map(
-            f, mesh=mesh, in_specs=(specs, P()), out_specs=P())
+            f, mesh=mesh, in_specs=(specs, P()), out_specs=P(None, "tensor"))
         ).lower(params, x).compile()
 
     cm_cfg = dataclasses.replace(cfg, collective_matmul=True, cm_min_bytes=0)

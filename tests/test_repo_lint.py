@@ -342,25 +342,21 @@ def test_autoplan_event_kinds_registered_and_emitted():
 def test_moe_event_kinds_registered_and_emitted():
     """The MoE dispatch kinds (PR 18) are in the registry AND emitted
     where the dispatch layer lives — ``moe_dispatch_selected`` is the
-    trace-time record of which path ``dispatch='auto'`` resolved to (the
-    Pallas kernel on TPU, XLA gather/scatter elsewhere), emitted from
-    ops/moe_dispatch.py's resolver; ``expert_overflow`` is the host-side
+    trace-time record of which path ``dispatch='auto'`` resolved to
+    (``'sorted'`` on a TPU, the size rule elsewhere), emitted from
+    ``resolve_moe_dispatch``; ``expert_overflow`` is the host-side
     capacity alarm (dropped-token rate over threshold) emitted from
-    parallel/moe.py's ``check_expert_overflow``; a kind that stopped
-    being emitted would silently blind the serving summary's expert-load
-    audit."""
+    ``check_expert_overflow``; both live in parallel/moe.py.  A kind that
+    stopped being emitted would silently blind the serving summary's
+    expert-load audit."""
     from torchdistpackage_tpu.obs.events import EVENT_KINDS
 
     moe_kinds = {"moe_dispatch_selected", "expert_overflow"}
     assert moe_kinds <= EVENT_KINDS
-    dispatch_kinds = {
-        k for _, k in _emit_call_kinds(PKG / "ops" / "moe_dispatch.py")}
-    assert "moe_dispatch_selected" in dispatch_kinds, (
-        "moe_dispatch_selected never emitted from ops/moe_dispatch.py")
     moe_layer_kinds = {
         k for _, k in _emit_call_kinds(PKG / "parallel" / "moe.py")}
-    assert "expert_overflow" in moe_layer_kinds, (
-        "expert_overflow never emitted from parallel/moe.py")
+    assert moe_kinds <= moe_layer_kinds, (
+        f"never emitted from parallel/moe.py: {moe_kinds - moe_layer_kinds}")
 
 
 def test_zb_event_kinds_registered_and_emitted():

@@ -180,7 +180,10 @@ def test_manifestless_template_drift_reraises(tmp_path, events):
     d = str(tmp_path / "run")
     with CheckpointManager(d, max_to_keep=4) as mgr:
         mgr.save(0, _payload(params, opt), wait=True)
-        with pytest.raises(Exception, match="[Kk]ey mismatch"):
+        # orbax's own words for a template that is not the tree on disk
+        # ("Key mismatch" before 0.11, "tree structures do not match" now)
+        with pytest.raises(
+                ValueError, match="[Kk]ey mismatch|structures do not match"):
             auto_resume(mgr, {"params": {"w": jnp.zeros((5,))}})
         assert mgr.latest_step() == 0
     assert events.of_kind("ckpt_quarantine") == []
@@ -464,11 +467,15 @@ def test_stall_trips_watchdog_hang_suspected(tmp_path, events):
             chaos=ChaosMonkey([Fault("stall", step=3, duration_s=0.5)]))
         res = loop.run(params, opt)
     assert res.verdict == "clean"  # a stall is latency, not divergence
-    assert res.summary["hang_suspected"] == 1
+    # the injected stall is flagged exactly once, at its step.  A loaded
+    # host may also take longer than the 0.15 s timeout over some other
+    # step (the suite runs six workers): that is the host's, so only the
+    # bookkeeping is asserted of it: counted, and resolved by the next beat
     sus = events.of_kind("hang_suspected")
-    assert len(sus) == 1 and sus[0]["last_step"] == 3
+    assert [e["last_step"] for e in sus].count(3) == 1
+    assert res.summary["hang_suspected"] == len(sus)
     assert [e["fault"] for e in events.of_kind("fault_injected")] == ["stall"]
-    assert len(events.of_kind("hang_resolved")) == 1
+    assert len(events.of_kind("hang_resolved")) == len(sus)
 
 
 # ============================================================== watchdog

@@ -147,8 +147,8 @@ def test_init_paged_kv_guards():
     "family",
     # moe demoted to slow (PR-19 budget payback): the staggered
     # admission regime is family-independent and held fast-tier by the
-    # dense/gqa/sliding rows; the moe expert-dispatch math keeps its own
-    # fast-tier holder in test_moe_dispatch.py::test_engine_token_bit_parity
+    # dense/gqa/sliding rows; the moe family keeps its own fast-tier
+    # holder in test_moe_engine_token_bit_parity below
     [pytest.param("moe", marks=pytest.mark.slow)]
     + [f for f in FAMILIES if f != "moe"])
 def test_paged_parity_staggered(bundles, family):
@@ -180,6 +180,53 @@ def test_paged_parity_staggered(bundles, family):
     assert s["ttft_s"] and s["tpot_s"]
 
 
+def test_moe_engine_token_bit_parity(bundles):
+    """Tier-1's holder of the MoE family in the engine (the [moe] rows
+    around it are slow-tier): the ragged no-drop serving path emits tokens
+    BIT-equal to contiguous ``generate()`` under staggered admission, one
+    decode signature."""
+    b = bundles("moe")
+    eng = b["eng"]
+    eng.reset_metrics()
+    r0 = eng.submit(Request(b["prompts"][0].tolist(), NEW))
+    eng.step()
+    eng.step()
+    r1 = eng.submit(Request(b["prompts"][1].tolist(), NEW))
+    _drain(eng)
+    for rid, row in ((r0, 0), (r1, 1)):
+        np.testing.assert_array_equal(
+            eng.finished[rid]["tokens"], b["want"][row],
+            err_msg="moe: engine diverged from generate()")
+    s = eng.serving_summary()
+    assert s["decode_signatures"] == 1
+    assert s["requests"]["completed"] == 2
+
+
+def test_moe_engine_summary_block(bundles):
+    """serving_summary()['moe'] is the live expert-load block the
+    router's load index consumes: real per-expert routed-token counts,
+    normalized entropy, no drops at cf=E/top_k — and it validates."""
+    from torchdistpackage_tpu.obs.report import _validate_serving
+
+    b = bundles("moe")
+    eng, cfg = b["eng"], b["cfg"]
+    eng.reset_metrics()
+    for p in b["prompts"]:
+        eng.submit(Request(p.tolist(), NEW))
+    _drain(eng)
+    s = eng.serving_summary()
+    assert _validate_serving(s) == []
+    moe = s["moe"]
+    assert "dispatch" not in moe  # one serving path: nothing to name
+    assert moe["num_experts"] == cfg.moe_experts
+    assert len(moe["expert_tokens"]) == cfg.moe_experts
+    assert sum(moe["expert_tokens"]) > 0  # stats actually flowed
+    assert moe["imbalance"] >= 0.0
+    assert 0.0 <= moe["load_entropy"] <= 1.0
+    assert moe["dropped_token_rate"] == 0.0  # cf = E/top_k: no drops
+    assert eng.moe_imbalance() == pytest.approx(moe["imbalance"])
+
+
 @pytest.mark.parametrize(
     "family",
     # dense demoted to slow (PR-12 budget payback): the mesh/table
@@ -188,8 +235,8 @@ def test_paged_parity_staggered(bundles, family):
     # above, and the pallas-vs-gather engine pair in
     # test_paged_attention.py re-proves the dense-attention math per PR.
     # moe joins it (PR-19 payback): the mesh/table plumbing is held by
-    # the fast gqa/sliding rows; moe expert sharding under tensor-
-    # parallel decode keeps its fast holder in test_moe_dispatch.py
+    # the fast gqa/sliding rows; the moe family's single-device engine
+    # parity is test_moe_engine_token_bit_parity below
     [pytest.param(f, marks=pytest.mark.slow) for f in ("dense", "moe")]
     + [f for f in FAMILIES if f not in ("dense", "moe")])
 def test_tp_dp_paged_parity(bundles, family, devices8):
@@ -317,6 +364,10 @@ def test_submit_guards(bundles):
         Request([], 1)
     with pytest.raises(ValueError, match="need a mesh"):
         ServingEngine(b["params"], b["cfg"], axis="tensor")
+    # the serving MoE path is one path: the option went with the fused
+    # kernel (PR 28)
+    with pytest.raises(TypeError, match="moe_dispatch"):
+        ServingEngine(b["params"], b["cfg"], moe_dispatch="gather")
     import dataclasses
     cp = dataclasses.replace(b["cfg"], attn_impl="ring")
     # training-side ring/Ulysses still refuses — serving-side CP is the

@@ -42,6 +42,7 @@ from torchdistpackage_tpu.obs.events import EventLog, set_default_event_log
 from torchdistpackage_tpu.obs.report import _validate_serving
 from torchdistpackage_tpu.resilience import ChaosMonkey, Fault
 from torchdistpackage_tpu.serving import BlockAllocator, Request, ServingEngine
+from torchdistpackage_tpu.serving.engine import FREE
 from torchdistpackage_tpu.serving.paged_cache import chain_block_hashes
 
 CFG = GPTConfig(vocab_size=64, dim=32, nheads=4, nlayers=2, max_seq=32)
@@ -371,9 +372,18 @@ def test_preempt_on_shared_blocks_never_frees_coowner(fp, event_log):
     eng.reset_metrics()
     c1 = eng.submit(Request(prompt.tolist(), NEW))
     c2 = eng.submit(Request(prompt.tolist(), NEW))
-    for _ in range(3):
-        eng.step()
+    # ONE step: the whole prompt is a cache hit, so prefill is one chunk,
+    # and spec_k=2 retires a NEW-token request on the second tick.  Cancel
+    # while both provably hold slots on the same blocks, so the decrement
+    # path is what runs.
+    eng.step()
+    live = {s.rid for s in eng._slots if s.state != FREE}
+    assert {c1, c2} <= live, "the sharers must be in flight at the cancel"
+    assert any(v > 1 for v in eng._allocs[0]._ref.values())
     assert eng.cancel(c1) is True
+    assert eng.finished[c1]["reason"] == "cancelled"
+    rep = eng.audit(heal=False)
+    assert rep["ok"], rep["violations"]
     _run_audited(eng)
     np.testing.assert_array_equal(eng.finished[c2]["tokens"], want)
     assert _kinds_count(event_log, "request_cancelled") == 1

@@ -272,7 +272,7 @@ def test_fetch_ends_at_the_tokens_and_telemetry_falls_into_host(params):
 
 
 def _kernel_cases():
-    """name -> () -> (function, arguments, lowers for TPU?)"""
+    """name -> () -> (function, arguments)"""
     S = jax.ShapeDtypeStruct
     bf = jnp.bfloat16
 
@@ -283,7 +283,7 @@ def _kernel_cases():
             jnp.float32).sum()
 
     q = S((1, 2, 256, 128), bf)
-    flash_args = (jax.grad(flash, argnums=(0, 1, 2)), (q, q, q), True)
+    flash_args = (jax.grad(flash, argnums=(0, 1, 2)), (q, q, q))
 
     B, H, hd, bs, mb = 2, 4, 128, 128, 2
     pool = S((1 + B * mb, H, bs, hd), bf)
@@ -295,7 +295,7 @@ def _kernel_cases():
         return (lambda q, k, v, t, o: paged_decode_attention(
             q, k, v, t, o, fetch_width=2, q_pad_to=8),
                 (S((B, H, s_in, hd), bf), pool, pool,
-                 S((B, mb), jnp.int32), S((B,), jnp.int32)), True)
+                 S((B, mb), jnp.int32), S((B,), jnp.int32)))
 
     def carry():
         from torchdistpackage_tpu.ops.paged_attention import (
@@ -304,22 +304,7 @@ def _kernel_cases():
         return (lambda q, k, v, t, o: paged_carry_attention(
             q, k, v, t, o, fetch_width=2, q_pad_to=8),
                 (S((B, H, 16, hd), bf), pool, pool,
-                 S((B, mb), jnp.int32), S((B,), jnp.int32)), True)
-
-    def moe(which):   # interpreter only: neither lowers for TPU (PR 21)
-        from torchdistpackage_tpu.ops import moe_dispatch as M
-        from torchdistpackage_tpu.parallel.moe import (
-            MoEConfig, _top_k_route, init_moe_params)
-
-        T, D, E, k = 24, 16, 4, 2
-        experts = init_moe_params(
-            jax.random.PRNGKey(0),
-            MoEConfig(dim=D, ffn_dim=32, num_experts=E, top_k=k))["experts"]
-        if which == "expert":
-            return M.fused_expert_ffn, (experts, jnp.zeros((E, 8, D))), False
-        route = _top_k_route(jnp.full((T, E), 1.0 / E), k, T)
-        return (lambda ex, t: M.fused_moe_ffn(ex, t, *route, T),
-                (experts, jnp.zeros((T, D))), False)
+                 S((B, mb), jnp.int32), S((B,), jnp.int32)))
 
     return {
         "flash_fwd": lambda: flash_args,
@@ -328,29 +313,25 @@ def _kernel_cases():
         "paged_decode": lambda: paged(1),
         "paged_chunk": lambda: paged(16),
         "paged_carry": carry,
-        "moe_fused_ffn": lambda: moe("fused"),
-        "moe_expert_ffn": lambda: moe("expert"),
     }
 
 
 @pytest.mark.parametrize("kernel", [
     "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
-    "paged_chunk", "paged_carry", "moe_fused_ffn", "moe_expert_ffn"])
+    "paged_chunk", "paged_carry"])
 def test_kernel_lowers_under_its_name(monkeypatch, kernel):
     """XLA names a Mosaic custom call after the name-stack component before
     ``pallas_call``: that is the kernel's ``name=``, which the device
     trace then shows (``%flash_fwd.1 = ... custom-call``).  Lowered for TPU
-    from the CPU, but for the MoE pair, which only the interpreter runs."""
-    fn, args, for_tpu = _kernel_cases()[kernel]()
-    if for_tpu:
-        for mod in ("flash_attention", "paged_attention"):
-            monkeypatch.setattr(
-                importlib.import_module(f"torchdistpackage_tpu.ops.{mod}"),
-                "_interpret", lambda: False)
+    from the CPU: every kernel the package holds lowers for the chip."""
+    fn, args = _kernel_cases()[kernel]()
+    for mod in ("flash_attention", "paged_attention"):
+        monkeypatch.setattr(
+            importlib.import_module(f"torchdistpackage_tpu.ops.{mod}"),
+            "_interpret", lambda: False)
     text = jax.jit(fn).trace(*args).lower(
-        lowering_platforms=("tpu",) if for_tpu else None).as_text(
-            debug_info=True)
-    assert ("tpu_custom_call" in text) == for_tpu
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "tpu_custom_call" in text
     # outside a scan a transformation wraps the name: jvp(flash_fwd)
     named = set(re.findall(r"(\w+)\)*/pallas_call", text))
     assert kernel in named

@@ -111,9 +111,12 @@ class GPTConfig:
     # EC remains available through moe_forward(causal=False) for
     # encoder/non-AR models built from the same MoE layer.
     moe_router: str = "topk"
-    moe_dispatch: str = "auto"  # 'dense' | 'sorted' | 'pallas' | 'auto' (see MoEConfig)
+    moe_dispatch: str = "auto"  # 'dense' | 'sorted' | 'auto' (see MoEConfig)
 
     def __post_init__(self):
+        from ..parallel.moe import check_moe_dispatch
+
+        check_moe_dispatch(self.moe_dispatch)
         if self.context_axis is not None and self.attn_impl not in ("ring", "ulysses"):
             raise ValueError(
                 f"context_axis={self.context_axis!r} requires attn_impl "

@@ -108,7 +108,7 @@ AXIS_DIM: Dict[str, str] = {
     "context": "cp",
 }
 
-_DTYPE_BITS = {
+DTYPE_BITS = {
     "pred": 8, "s2": 2, "u2": 2, "s4": 4, "u4": 4,
     "s8": 8, "u8": 8, "f8e4m3fn": 8, "f8e5m2": 8, "f8e4m3b11fnuz": 8,
     "f8e4m3fnuz": 8, "f8e5m2fnuz": 8, "f8e3m4": 8, "f8e4m3": 8,
@@ -118,33 +118,26 @@ _DTYPE_BITS = {
     "c128": 128,
 }
 
+# ------------------------------------------------- the one reader of HLO text
+#
+# Everything in the repo that reads types out of HLO text (this ledger and
+# obs.numerics' dtype ledger) goes through the four names below, so a JAX
+# that prints HLO differently is met in one place.
+
+# An array type: 'f32[2,16]'.  A tuple type is several of them in parentheses.
 _SHAPE_RE = re.compile(r"\b([a-z]\w*)\[([0-9,]*)\]")
 
-# Defining line of a collective instruction:
-#   %all-reduce.1 = f32[2,16]{1,0} all-reduce(f32[2,16]{1,0} %x), ...
-# Lazy prefix = the result type (possibly a tuple); the op name must be
-# followed by '(' so references like 'get-tuple-element(... %all-to-all.2)'
-# don't match.
-_INSTR_RE = re.compile(
+# A defining line:
+#   %all-reduce.1 = f32[2,16]{1,0} all-reduce(%x), channel_id=1, ...
+# Lazy 'res' = the result type, which may be a tuple and on a TPU carries
+# parentheses in its layout ('{1,0:T(8,128)}'); the op is the first word
+# followed by '(' that stands after whitespace, so a reference such as
+# 'get-tuple-element(%all-to-all.2)' is an operand, never a definition.
+_DEF_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%(?P<name>[^\s=]+)\s+=\s+(?P<res>.+?)\s+"
-    r"(?P<op>" + "|".join(COLLECTIVE_OPS) + r")(?P<start>-start)?"
-    r"\((?P<rest>.*)$"
-)
-
-# The matching async completion:
-#   %all-gather-done.1 = f32[...] all-gather-done(... %all-gather-start.1)
-# The first %token in the operand list names the -start instruction.
-_DONE_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%[^\s=]+\s+=\s+.+?\s+"
-    r"(?:" + "|".join(COLLECTIVE_OPS) + r")-done"
-    r"\((?P<rest>.*)$"
+    r"(?P<op>[\w-]+)\((?P<rest>.*)$"
 )
 _OPERAND_NAME_RE = re.compile(r"%([^\s,)]+)")
-
-# Any defining instruction line — the unit the scheduling distance is
-# counted in (instructions between a collective's -start and its -done:
-# how much independent work XLA's scheduler placed under the transfer).
-_ANY_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%[^\s=]+\s+=\s")
 
 _REPLICA_GROUPS_RE = re.compile(
     r"replica_groups=(\{\{[0-9,{} ]*\}\}|\{\}|\[[0-9,]+\]<=\[[0-9,]+\](?:T\([0-9,]+\))?)"
@@ -154,32 +147,80 @@ _CHANNEL_RE = re.compile(r"channel_id=(\d+)")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 
 
-def _shape_bits(dtype: str, dims: str) -> int:
-    bits = _DTYPE_BITS.get(dtype)
-    if bits is None:
-        return 0
-    n = 1
-    for d in dims.split(","):
-        if d:
-            n *= int(d)
-    return n * bits
+def hlo_instructions(
+    hlo_text: str,
+) -> Tuple[List["re.Match[str]"], Dict[str, str]]:
+    """Every defining line of an HLO module, in order, as a match with the
+    groups ``name``, ``res`` (result type), ``op`` and ``rest`` (the text
+    after the op's opening parenthesis: operands, then attributes); and the
+    module's ``name -> res`` table, which :func:`operand_shapes` resolves
+    a named operand in."""
+    instrs = [m for m in map(_DEF_RE.match, hlo_text.splitlines()) if m]
+    return instrs, {m.group("name"): m.group("res") for m in instrs}
 
 
-def _operand_bytes(args: str) -> int:
-    """Sum the bytes of the operand shapes in an argument list, stopping at
-    the instruction's closing paren (operands of these collectives are
-    arrays, so the first unmatched ')' ends the list)."""
-    depth = 0
-    end = len(args)
-    for i, c in enumerate(args):
-        if c == "(":
+def type_shapes(type_text: str) -> List[Tuple[str, str]]:
+    """``(dtype, dims)`` of every array in a type: one for ``f32[2,16]{1,0}``,
+    one per element for a tuple (the ``-start`` forms, variadic results)."""
+    return _SHAPE_RE.findall(type_text)
+
+
+def shape_elems(dims: str) -> int:
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+def operand_shapes(
+    rest: str, result_types: Dict[str, str]
+) -> List[Tuple[str, str]]:
+    """``(dtype, dims)`` of every array an instruction takes as an operand.
+
+    ``rest`` is a defining line's text after the op's ``(``; the first
+    unmatched ``)`` ends the operand list.  An operand's type is the shape
+    printed beside it where the text has one (``f32[2,16]{1,0} %x``: older
+    JAX) and otherwise the result type of the instruction it names
+    (``%x``: jax 0.9), looked up in ``result_types`` (the table of
+    :func:`hlo_instructions`).  An operand that is neither raises
+    ``ValueError``: a size nobody can read must not become 0."""
+    operands: List[str] = []
+    depth, start, end = 0, 0, len(rest)
+    for i, c in enumerate(rest):
+        if c in "([{":
             depth += 1
-        elif c == ")":
+        elif c in ")]}":
             if depth == 0:
                 end = i
                 break
             depth -= 1
-    bits = sum(_shape_bits(d, s) for d, s in _SHAPE_RE.findall(args[:end]))
+        elif c == "," and depth == 0:
+            operands.append(rest[start:i])
+            start = i + 1
+    operands.append(rest[start:end])
+    shapes: List[Tuple[str, str]] = []
+    for operand in operands:
+        if not operand.strip():
+            continue
+        found = type_shapes(operand)
+        if not found:
+            nm = _OPERAND_NAME_RE.search(operand)
+            found = type_shapes(result_types.get(nm.group(1), "")) if nm else []
+            if not found:
+                raise ValueError(
+                    f"HLO operand {operand.strip()!r} has no shape beside it "
+                    "and names no instruction of this module")
+        shapes += found
+    return shapes
+
+
+def _operand_bytes(m: "re.Match[str]", result_types: Dict[str, str]) -> int:
+    """Bytes of a collective's operands (arrays, so whole bytes each pass
+    through; sub-byte dtypes are summed in bits first)."""
+    try:
+        shapes = operand_shapes(m.group("rest"), result_types)
+        bits = sum(DTYPE_BITS[dt] * shape_elems(dims) for dt, dims in shapes)
+    except (KeyError, ValueError) as e:
+        raise ValueError(
+            f"comm ledger cannot size the operands of: {m.string.strip()}"
+        ) from e
     return bits // 8
 
 
@@ -243,28 +284,24 @@ def parse_hlo_collectives(hlo_text: str) -> List[Dict[str, Any]]:
     out: List[Dict[str, Any]] = []
     starts: Dict[str, Dict[str, Any]] = {}
     open_starts: List[Dict[str, Any]] = []
-    instr_idx = 0
-    for line in hlo_text.splitlines():
-        is_instr = _ANY_INSTR_RE.match(line) is not None
-        if is_instr:
-            instr_idx += 1
-        m = _INSTR_RE.match(line)
-        if m is None:
-            if not is_instr:
-                continue
-            dm = _DONE_RE.match(line)
-            if dm is None:
-                continue
-            onm = _OPERAND_NAME_RE.search(dm.group("rest"))
+    instrs, result_types = hlo_instructions(hlo_text)
+    # instr_idx counts defining lines: the unit of the scheduling distance
+    for instr_idx, m in enumerate(instrs, 1):
+        line = m.string
+        op = m.group("op").removesuffix("-start")
+        if op.endswith("-done") and op[:-len("-done")] in COLLECTIVE_OPS:
+            # the first %name in the operand list is the -start instruction
+            onm = _OPERAND_NAME_RE.search(m.group("rest"))
             rec = starts.get(onm.group(1)) if onm else None
             if rec is not None:
                 rec["sched_distance"] = max(0, instr_idx - rec["_idx"] - 1)
                 if rec in open_starts:
                     open_starts.remove(rec)
             continue
-        op = m.group("op")
-        rest = m.group("rest")
-        operand_bytes = _operand_bytes(rest)
+        if op not in COLLECTIVE_OPS:
+            continue
+        is_start = op != m.group("op")
+        operand_bytes = _operand_bytes(m, result_types)
         gm = _REPLICA_GROUPS_RE.search(line)
         groups = _expand_replica_groups(gm.group(1)) if gm else []
         pairs: List[Tuple[int, int]] = []
@@ -289,7 +326,7 @@ def parse_hlo_collectives(hlo_text: str) -> List[Dict[str, Any]]:
             "pairs": pairs,
             "channel_id": int(cm.group(1)) if cm else None,
             "op_name": nm.group(1) if nm else None,
-            "async": bool(m.group("start")),
+            "async": is_start,
             "sched_distance": None,
             "overlapped_idx": None,
             "_idx": instr_idx,

@@ -44,6 +44,14 @@ from __future__ import annotations
 import re
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
+from .comm_ledger import (
+    DTYPE_BITS,
+    hlo_instructions,
+    operand_shapes,
+    shape_elems,
+    type_shapes,
+)
+
 NUMERICS_SCHEMA = "tdp-numerics/v1"
 DTYPE_LEDGER_SCHEMA = "tdp-dtype-ledger/v1"
 
@@ -282,25 +290,7 @@ def check_alerts(
 
 # ----------------------------------------------------------- dtype ledger
 
-# A defining HLO instruction: result type(s), op name, open paren.  The
-# result may be a tuple '(f32[2]{0}, s8[4]{0})' — every shape inside is
-# counted.  Same shape token grammar as comm_ledger.
-_SHAPE_RE = re.compile(r"\b([a-z]\w*)\[([0-9,]*)\]")
-_DEF_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%[^\s=]+\s+=\s+(?P<res>\(?[^(]*?\)?)\s+"
-    r"(?P<op>[\w-]+)\((?P<rest>.*)$"
-)
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
-
-_DTYPE_BITS = {
-    "pred": 8, "s2": 2, "u2": 2, "s4": 4, "u4": 4,
-    "s8": 8, "u8": 8, "f8e4m3fn": 8, "f8e5m2": 8, "f8e4m3b11fnuz": 8,
-    "f8e4m3fnuz": 8, "f8e5m2fnuz": 8, "f8e3m4": 8, "f8e4m3": 8,
-    "s16": 16, "u16": 16, "f16": 16, "bf16": 16,
-    "s32": 32, "u32": 32, "f32": 32,
-    "s64": 64, "u64": 64, "f64": 64, "c64": 64,
-    "c128": 128,
-}
 
 # Result buffers of these ops alias/bookkeep rather than compute — they
 # would double-count the producing instruction's bytes.
@@ -308,14 +298,6 @@ _NO_ALLOC_OPS = frozenset({
     "parameter", "get-tuple-element", "tuple", "bitcast", "constant",
     "after-all", "partition-id", "replica-id",
 })
-
-
-def _shape_elems(dims: str) -> int:
-    n = 1
-    for d in dims.split(","):
-        if d:
-            n *= int(d)
-    return n
 
 
 def dtype_ledger_from_hlo(
@@ -343,38 +325,31 @@ def dtype_ledger_from_hlo(
     def bucket(dt: str) -> Dict[str, float]:
         return per.setdefault(dt, {"bytes": 0, "ops": 0, "flops": 0})
 
-    for line in hlo_text.splitlines():
-        m = _DEF_RE.match(line)
-        if m is None:
-            continue
+    instrs, result_types = hlo_instructions(hlo_text)
+    for m in instrs:
         op = m.group("op")
         if op in _NO_ALLOC_OPS:
             continue
-        shapes = _SHAPE_RE.findall(m.group("res"))
-        if not shapes:
-            continue
+        # a tuple result '(f32[2]{0}, s8[4]{0})': every array is counted
+        shapes = type_shapes(m.group("res"))
         for i, (dt, dims) in enumerate(shapes):
-            bits = _DTYPE_BITS.get(dt)
+            bits = DTYPE_BITS.get(dt)
             if bits is None:
                 continue
             b = bucket(dt)
-            b["bytes"] += _shape_elems(dims) * bits // 8
+            b["bytes"] += shape_elems(dims) * bits // 8
             if i == 0:
                 b["ops"] += 1
-        if op == "dot":
-            rest = m.group("rest")
-            operands = _SHAPE_RE.findall(rest)
-            cm = _CONTRACT_RE.search(line)
-            if operands and cm is not None:
-                lhs_dt, lhs_dims = operands[0]
-                lhs_shape = [int(d) for d in lhs_dims.split(",") if d]
-                k = 1
-                for idx in cm.group(1).split(","):
-                    if idx and int(idx) < len(lhs_shape):
-                        k *= lhs_shape[int(idx)]
-                out_elems = sum(
-                    _shape_elems(dims) for _, dims in shapes)
-                bucket(lhs_dt)["flops"] += 2 * out_elems * k
+        cm = _CONTRACT_RE.search(m.string) if op == "dot" else None
+        if cm is not None:
+            lhs_dt, lhs_dims = operand_shapes(m.group("rest"), result_types)[0]
+            lhs_shape = [int(d) for d in lhs_dims.split(",") if d]
+            k = 1
+            for idx in cm.group(1).split(","):
+                if idx and int(idx) < len(lhs_shape):
+                    k *= lhs_shape[int(idx)]
+            out_elems = sum(shape_elems(dims) for _, dims in shapes)
+            bucket(lhs_dt)["flops"] += 2 * out_elems * k
     total_bytes = sum(b["bytes"] for b in per.values())
     total_flops = sum(b["flops"] for b in per.values())
     ledger: Dict[str, Any] = {

@@ -467,10 +467,6 @@ def _validate_serving(srv: Any) -> List[str]:
             if not isinstance(et, list) or (
                     isinstance(ne, int) and len(et) != ne):
                 errs.append("serving.moe.expert_tokens missing/wrong length")
-            if moe.get("dispatch") not in (
-                    "gather", "pallas", "dense", "sorted", "auto"):
-                errs.append(
-                    f"serving.moe.dispatch {moe.get('dispatch')!r} unknown")
     # ring-paged-prefill fields (PR 20) — present for cp_axis engines
     lc = srv.get("long_context")
     if lc is not None:

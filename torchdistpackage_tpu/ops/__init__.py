@@ -1,12 +1,4 @@
 from .flash_attention import flash_attention, flash_attention_with_lse, mha_reference
-from .moe_dispatch import (
-    fused_expert_ffn,
-    fused_moe_ffn,
-    modeled_slot_view_bytes,
-    moe_ffn_oracle,
-    quantize_moe_experts,
-    resolve_moe_dispatch,
-)
 from .paged_attention import (
     default_paged_params,
     modeled_attend_temp_bytes,

@@ -421,8 +421,6 @@ class FSDP:
                 )
 
                 def core(p_shard, opt_state, batch):
-                    p_shard = pvary_params(p_shard, (ax,))
-
                     def gathered_loss(ps, b):
                         if gather == "leaf":
                             ps = gather_params(
@@ -430,8 +428,11 @@ class FSDP:
                                 compress_min_size=compress_min_size)
                         return loss_fn(ps, b)
 
+                    # the grad is taken of a per-device copy; the update
+                    # below goes onto the shards as they came in, so a
+                    # leaf that is replicated stays typed replicated
                     loss, grads = jax.value_and_grad(gathered_loss)(
-                        p_shard, batch)
+                        pvary_params(p_shard, (ax,)), batch)
                     n = axis_size(ax)
                     # gathered leaves: the transpose already reduce-
                     # scattered (SUM over the axis) -> /n for the mean;

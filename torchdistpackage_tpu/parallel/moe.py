@@ -45,6 +45,18 @@ from ..dist.topology import EXPERT_AXIS, MOE_DATA_AXIS
 PyTree = Any
 
 
+# How moe_forward materializes dispatch and combine (MoEConfig.dispatch).
+MOE_DISPATCHES = ("dense", "sorted", "auto")
+
+
+def check_moe_dispatch(dispatch: str) -> str:
+    if dispatch not in MOE_DISPATCHES:
+        raise ValueError(
+            f"unknown MoE dispatch {dispatch!r}: it is one of "
+            f"{MOE_DISPATCHES}")
+    return dispatch
+
+
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     dim: int
@@ -72,18 +84,9 @@ class MoEConfig:
     #              kept (token, choice) writes its token row into flat slot
     #              e*C + c, dropped choices write to a discarded dumpster
     #              row; combine gathers the slot outputs back per token.
-    #   'pallas' — fused kernel (ops/moe_dispatch.py): the _top_k_route
-    #              decision rides scalar prefetch as [E, C] slot maps and
-    #              gather -> expert FFN -> weighted scatter-add run inside
-    #              one Pallas grid — neither materialization above ever
-    #              exists in HBM.  topk router only; under ep_axis the
-    #              all_to_all exchange keeps the 'sorted' layout (it IS
-    #              the wire payload) and only the expert FFN fuses.
     #   'auto'   — 'sorted' on the TPU backend (the path with a chip
     #              record); elsewhere 'sorted' when the dense tensors would
-    #              exceed _DENSE_DISPATCH_MAX elements (all three paths are
-    #              exercised by CI — the kernel in Pallas interpreter mode;
-    #              it does not lower for TPU, ops/moe_dispatch.py).
+    #              exceed _DENSE_DISPATCH_MAX elements.
     dispatch: str = "auto"
     # Expert FFN activation: 'gelu' | 'swiglu' (stacked [E, 2, D, F]
     # gate/up — the Mixtral-style expert; structural dispatch on w1.ndim,
@@ -114,13 +117,7 @@ class MoEConfig:
     def __post_init__(self):
         if self.router not in ("topk", "expert_choice"):
             raise ValueError(f"unknown MoE router {self.router!r}")
-        if self.dispatch not in ("dense", "sorted", "auto", "pallas"):
-            raise ValueError(f"unknown MoE dispatch {self.dispatch!r}")
-        if self.dispatch == "pallas" and self.router != "topk":
-            raise ValueError(
-                "dispatch='pallas' consumes a _top_k_route decision; the "
-                "expert_choice router has no (gate_idx, slot, keep) form — "
-                "use dispatch='dense'/'sorted'/'auto' with it")
+        check_moe_dispatch(self.dispatch)
         if self.act not in ("gelu", "swiglu", "relu2"):
             raise ValueError(f"unknown MoE act {self.act!r}")
         if self.score not in ("softmax", "sigmoid"):
@@ -147,11 +144,25 @@ class MoEConfig:
 _DENSE_DISPATCH_MAX = 1 << 24
 
 
+def resolve_moe_dispatch(dispatch: Optional[str]) -> str:
+    """``'auto'``/None -> ``'sorted'`` on TPU (the one dispatch with a chip
+    record: it beat ``'dense'`` there), ``'auto'`` (the size-based
+    dense/sorted selection of :func:`_use_sorted`) elsewhere.  Explicit
+    values pass through validated.  The choice is recorded on the event
+    timeline (``moe_dispatch_selected``)."""
+    if dispatch in (None, "auto"):
+        chosen = "sorted" if jax.default_backend() == "tpu" else "auto"
+        from ..obs.events import emit_event
+
+        emit_event("moe_dispatch_selected", requested="auto", chosen=chosen,
+                   backend=jax.default_backend())
+        return chosen
+    return check_moe_dispatch(dispatch)
+
+
 def _use_sorted(dispatch: str, T: int, E: int, capacity: int) -> bool:
-    """``dispatch``: cfg.dispatch after ``resolve_moe_dispatch``."""
-    if dispatch in ("auto", "pallas"):
-        # 'pallas' reaches here only where the kernel doesn't apply (the
-        # EP exchange layout)
+    """``dispatch``: cfg.dispatch after :func:`resolve_moe_dispatch`."""
+    if dispatch == "auto":
         return T * E * capacity > _DENSE_DISPATCH_MAX
     return dispatch == "sorted"
 
@@ -388,12 +399,9 @@ def moe_forward(
     probs = jax.nn.softmax(
         (tokens @ params["router"]["w"]).astype(jnp.float32), axis=-1
     )  # [T, E] in fp32 for routing stability
-    from ..ops.moe_dispatch import resolve_moe_dispatch
-
     # 'auto' -> the backend's choice, recorded as a ``moe_dispatch_selected``
-    # event at trace time; MoEConfig refuses 'pallas' off the topk router
+    # event at trace time
     dispatch = resolve_moe_dispatch(cfg.dispatch)
-    pallas = dispatch == "pallas"
     if cfg.router == "expert_choice":
         if causal:
             raise ValueError(
@@ -454,21 +462,7 @@ def moe_forward(
         metrics = (
             _router_metrics(probs, keep, cfg.top_k) if return_metrics else None
         )
-        if pallas and ep_axis is None:
-            # fused path: the routing decision goes straight into the
-            # kernel as slot maps — no expert_in materialization at all
-            from ..ops.moe_dispatch import fused_moe_ffn
-
-            y = fused_moe_ffn(
-                params["experts"], tokens, gate_vals, gate_idx, slot, keep,
-                capacity,
-            )
-            out = (y.reshape(B, S, D).astype(x.dtype), aux.astype(jnp.float32))
-            return out + (metrics,) if return_metrics else out
-        # under EP the exchange needs a materialized [E, C, D] layout (it
-        # IS the all_to_all payload): keep the sorted dispatch and fuse
-        # only the expert FFN leg (fused_expert_ffn below)
-        if pallas or _use_sorted(dispatch, T, E, capacity):
+        if _use_sorted(dispatch, T, E, capacity):
             kept = jnp.sum(keep, axis=-1)  # [T, k] 1 iff the choice fit
             # flat destination slot e*C + c; dropped choices go to a
             # dumpster row (index E*C) that is sliced off / zeroed
@@ -506,13 +500,8 @@ def moe_forward(
             def combine_out(expert_out: jnp.ndarray) -> jnp.ndarray:
                 return jnp.einsum("tec,ecd->td", combine, expert_out)
 
-    ffn = _expert_ffn
-    if pallas:
-        from ..ops.moe_dispatch import fused_expert_ffn
-
-        ffn = fused_expert_ffn
     if ep_axis is None:
-        expert_out = ffn(params["experts"], expert_in)  # [E, C, D]
+        expert_out = _expert_ffn(params["experts"], expert_in)  # [E, C, D]
     else:
         ep = axis_size(ep_axis)
         if E % ep != 0:
@@ -523,7 +512,7 @@ def moe_forward(
         recv = jax.lax.all_to_all(send, ep_axis, split_axis=0, concat_axis=0)
         # my local experts now see ep*C slots (C from every EP peer)
         grouped = recv.transpose(1, 0, 2, 3).reshape(e_loc, ep * capacity, D)
-        out = ffn(params["experts"], grouped)
+        out = _expert_ffn(params["experts"], grouped)
         back = out.reshape(e_loc, ep, capacity, D).transpose(1, 0, 2, 3)
         expert_out = jax.lax.all_to_all(
             back, ep_axis, split_axis=0, concat_axis=0
@@ -593,7 +582,6 @@ def moe_serve_forward(
     params: Dict[str, PyTree],
     x: jnp.ndarray,
     cfg: MoEConfig,
-    dispatch: Optional[str] = None,
     return_metrics: bool = False,
     valid: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
@@ -619,15 +607,11 @@ def moe_serve_forward(
     goes through :func:`moe_forward`'s exchange path instead
     (models/generate.forward_cached_moe wires both).
 
-    ``dispatch`` overrides ``cfg.dispatch`` for the serving A/B:
-    ``'gather'`` pins THIS ragged path (the serving parity oracle —
-    decode_bench's gather arm), ``'pallas'`` runs the fused kernel at the
-    no-drop capacity bound ``C = T`` (statically safe; the kernel's
-    all-zero capacity tiles skip their gather and matmuls, so the
-    ``E/top_k`` padded-compute tax that bound implies for the jnp paths
-    never materializes).  ``return_metrics=True`` appends the per-expert
+    ``cfg.dispatch`` chooses between :func:`moe_forward`'s capacity
+    materializations and has no say here: there is no capacity to
+    materialize.  ``return_metrics=True`` appends the per-expert
     routed-token counts ({'expert_tokens', 'dropped_token_rate'} — rate
-    identically 0 here, both paths are no-drop) for the engine's live
+    identically 0 here, the path is no-drop) for the engine's live
     ``moe`` load signal.
 
     The sigmoid-router / latent family (``MoEConfig.score`` /
@@ -652,12 +636,6 @@ def moe_serve_forward(
     T, E, k = B * S, cfg.num_experts, cfg.top_k
     first, n_held = cfg.held_range
     tokens = x.reshape(T, D)
-
-    disp = cfg.dispatch if dispatch is None else dispatch
-    if disp != "gather":
-        from ..ops.moe_dispatch import resolve_moe_dispatch
-
-        disp = resolve_moe_dispatch(disp)
 
     probs, gate_vals, gate_idx = _serve_route(params["router"], tokens, cfg)
     # the expert of each choice as this device numbers the ones it holds;
@@ -693,22 +671,6 @@ def moe_serve_forward(
                 jnp.float32)
             metrics["gate_idx"] = gate_idx.reshape(B, S, k)
         return y, metrics
-
-    if disp == "pallas":
-        if cfg.score != "softmax" or cfg.held is not None or (
-                cfg.latent_dim or cfg.shared_ffn or cfg.act == "relu2"):
-            raise NotImplementedError(
-                "the fused dispatch kernel computes the Mixtral-shaped "
-                "layer only")
-        from ..ops.moe_dispatch import fused_moe_ffn
-
-        # C = T is the static no-drop bound (a token holds at most one
-        # slot per expert), so keep == the full choice one-hot and this
-        # branch routes EXACTLY the same (token, expert) set as the
-        # ragged path below
-        gv, gi, slot, keep = _top_k_route(probs, k, T)
-        y = fused_moe_ffn(params["experts"], tokens, gv, gi, slot, keep, T)
-        return _with_metrics(y.reshape(B, S, D).astype(x.dtype))
 
     src = tokens
     if cfg.latent_dim:

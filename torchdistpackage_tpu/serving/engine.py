@@ -151,6 +151,7 @@ from ..models.generate import _full_logits
 from ..models.gpt import GPTConfig
 from ..obs.aggregate import percentiles
 from ..obs.events import EventLog, default_event_log
+from ..ops import paged_attention as paged_attention_ops
 from ..utils.profiling import scope_decorator, span
 from .paged_cache import (
     BlockAllocator,
@@ -355,15 +356,6 @@ class ServingEngine:
         gather on CPU (the interpreter-mode kernel is correct but slow —
         tests opt in explicitly).  Recorded in
         ``serving_summary()['attn_impl']``.
-    moe_dispatch: MoE dispatch for the expert-FFN layers of a MoE family
-        (ignored otherwise): ``'gather'`` pins the ragged grouped-GEMM
-        serving oracle, ``'pallas'`` the fused dispatch kernel
-        (ops/moe_dispatch.py; Pallas interpreter only — it does not lower
-        for TPU), ``None`` defers to ``cfg.moe_dispatch`` (whose ``'auto'``
-        means the ragged path here).  Recorded in
-        ``serving_summary()['moe']['dispatch']``; both arms feed the same
-        live expert-load stats (the summary's ``moe`` subsection and the
-        Router's imbalance-weighted load index).
     metrics_sink: any obs exporter sink (``write(record)`` — e.g.
         :class:`~..obs.exporters.PrometheusTextfileSink` or ``JsonlSink``);
         every ``metrics_every``-th tick writes a ``serving_metrics``
@@ -430,7 +422,6 @@ class ServingEngine:
         prefix_cache: bool = False,
         spec_k: int = 0,
         attn_impl: str = "auto",
-        moe_dispatch: Optional[str] = None,
         metrics_sink: Optional[Any] = None,
         metrics_every: int = 1,
         tick_history: int = 4096,
@@ -553,19 +544,6 @@ class ServingEngine:
         #: default; interpreter-mode pallas on CPU is correct but slow).
         #: docs/serving.md "Paged attention kernel".
         self.attn_impl = resolve_attn_impl(attn_impl)
-        #: 'gather' (ragged grouped-GEMM oracle) or 'pallas' (fused
-        #: dispatch kernel); None defers to cfg.moe_dispatch.  MoE
-        #: families only — serving_summary()['moe']['dispatch'].
-        self.moe_dispatch = moe_dispatch
-        if moe_dispatch is not None:
-            if not cfg.moe_experts:
-                raise ValueError(
-                    "moe_dispatch is set but the model has no MoE layers "
-                    "(cfg.moe_experts == 0)")
-            if moe_dispatch not in ("gather", "pallas"):
-                raise ValueError(
-                    "engine moe_dispatch must be 'gather' or 'pallas', got "
-                    f"{moe_dispatch!r}")
         if metrics_every < 1:
             raise ValueError(f"metrics_every must be >= 1, got {metrics_every}")
         self.metrics_sink = metrics_sink
@@ -697,7 +675,6 @@ class ServingEngine:
         if self.cfg.moe_experts:
             return functools.partial(paged_forward_moe, ep_axis=self.ep_axis,
                                      attn_impl=self.attn_impl,
-                                     moe_dispatch=self.moe_dispatch,
                                      moe_stats=moe_stats)
         return functools.partial(paged_forward, attn_impl=self.attn_impl)
 
@@ -880,8 +857,17 @@ class ServingEngine:
             # [1, E] expert counts / [1] drop rate per dp group -> stacked
             # [dp, E] / [dp] globally; the host sums / means the groups
             out_specs = out_specs + (row, row)
+        # The Pallas INTERPRETER evaluates a kernel's index maps as plain
+        # jaxprs, and shard_map's type check refuses to index a
+        # scalar-prefetch operand that varies over a mesh axis (tables,
+        # offsets) by a grid position that does not.  Mosaic has no such
+        # check, so the check goes exactly when the kernels are
+        # interpreted; every other engine program keeps it.
+        interpreted = (self.attn_impl == "pallas"
+                       and paged_attention_ops._interpret())
         return jax.jit(shard_map(
-            step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs),
+            step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=not interpreted),
             donate_argnums=(1,))
 
     def _build_verify_step(self) -> Callable:
@@ -2841,8 +2827,7 @@ class ServingEngine:
                               for k, v in phases_mean.items()},
         }
         # --- live expert-load (MoE families): moe_load_stats over the
-        # accumulated per-expert routed-token counts, plus the dispatch
-        # implementation the compiled programs traced.  The overflow
+        # accumulated per-expert routed-token counts.  The overflow
         # tripwire fires here, where the stats are concrete.
         moe = None
         if self.cfg.moe_experts:
@@ -2858,8 +2843,6 @@ class ServingEngine:
                               else self.cfg.moe_experts),
                 dropped_rate=dropped,
             )
-            moe["dispatch"] = (self.moe_dispatch if self.moe_dispatch
-                               is not None else self.cfg.moe_dispatch)
             check_expert_overflow(moe, where="serving_summary")
         return {
             "requests": {"completed": completed, "queued": len(self.queue),

@@ -473,7 +473,6 @@ def paged_forward_moe(
     ep_axis: Optional[str] = None,
     all_logits: bool = False,
     attn_impl: str = "gather",
-    moe_dispatch: Optional[str] = None,
     moe_stats: bool = False,
 ) -> Tuple[Dict[str, Any], jnp.ndarray]:
     """:func:`paged_forward` for the MoE family (heterogeneous block list,
@@ -485,12 +484,9 @@ def paged_forward_moe(
     ``attn_impl`` as in :func:`paged_forward` (the MoE families ride the
     same kernel — attention is family-independent).
 
-    ``moe_dispatch`` overrides the model's ``cfg.moe_dispatch`` for the
-    serving A/B ('gather' pins the ragged oracle, 'pallas' the fused
-    kernel — :func:`~..parallel.moe.moe_serve_forward`).  ``moe_stats=True``
-    returns ``(cache, logits, moe_metrics)`` where ``moe_metrics`` sums
-    per-expert routed-token counts over the MoE layers — the engine's live
-    expert-load signal.
+    ``moe_stats=True`` returns ``(cache, logits, moe_metrics)`` where
+    ``moe_metrics`` sums per-expert routed-token counts over the MoE layers
+    — the engine's live expert-load signal.
     """
     import dataclasses as _dc
 
@@ -504,13 +500,6 @@ def paged_forward_moe(
         capacity_factor=max(mcfg.capacity_factor,
                             mcfg.num_experts / mcfg.top_k),
     )
-    if moe_dispatch is not None and ep_axis is not None:
-        # the EP exchange has no ragged analogue: its 'gather' arm is the
-        # sorted index materialization (same jnp gather/scatter family)
-        mcfg = _dc.replace(
-            mcfg,
-            dispatch="sorted" if moe_dispatch == "gather" else moe_dispatch,
-        )
     S_in = tokens.shape[1]
     offset = jnp.asarray(offset, jnp.int32)
     positions = offset[:, None] + jnp.arange(S_in)[None, :]
@@ -521,8 +510,7 @@ def paged_forward_moe(
     if ep_axis is None:
         def moe_ffn(p, hh):
             out = moe_serve_forward(
-                p["moe"], hh, mcfg, dispatch=moe_dispatch,
-                return_metrics=moe_stats)
+                p["moe"], hh, mcfg, return_metrics=moe_stats)
             if moe_stats:
                 z, met = out
                 collected.append(met)

@@ -45,16 +45,6 @@ through both attention implementations — paired
 bit-parity ASSERTED between the arms, and the ``serve-paged-ab`` line
 carrying ``paged_pallas_tok_s`` (a ``bench_trend`` aux column).
 
-``--serve --moe-dispatch {gather,pallas}`` adds the MoE expert-dispatch
-A/B (docs/moe.md "Fused dispatch"): the same f32 requests through a
-GPT-MoE engine with the ragged gather oracle vs the fused Pallas
-dispatch kernel (ops/moe_dispatch.py) — paired
-``serve-moe-{gather,pallas}`` lines at equal ``config_hash``, token
-bit-parity ASSERTED between the arms, expert-load stats
-(imbalance/entropy/drop rate) on every line, and the ``serve-moe-ab``
-roll-up carrying ``moe_pallas_tok_s`` / ``expert_imbalance``
-(``bench_trend`` aux columns).
-
 ``--serve --shared-prefix`` and ``--serve --spec K`` add the fast-path
 A/Bs (docs/serving.md "Prefix cache" / "Speculative decoding"): the
 prefix arm replays shared-system-prompt traffic with the prefix cache
@@ -994,117 +984,6 @@ def bench_serve_long_context(jax, jnp, cfg, params, tel, *, cp, contexts,
     return summary_n
 
 
-def bench_serve_moe(jax, jnp, cfg, tel, *, moe_dispatch, n_requests,
-                    num_slots, block_size, chunk, seed, smoke):
-    """The MoE expert-dispatch A/B (docs/moe.md "Fused dispatch"): the
-    same requests through a GPT-MoE engine with ``moe_dispatch='gather'``
-    (the ragged parity oracle — argsorted dispatch, materialized slot
-    view) and ``moe_dispatch='pallas'`` (ops/moe_dispatch.py: gather ->
-    expert FFN -> weighted scatter fused in one kernel, no [E, C, D]
-    slot view in HBM) — paired ``serve-moe-{gather,pallas}`` JSON lines
-    at equal ``config_hash``, token BIT-parity asserted between the
-    arms.  Both arms run f32 — the dtype the parity claim is exact at
-    (same convention as :func:`bench_serve_paged`).  Each line carries
-    the engine's accumulated expert-load stats (``serving_summary()``'s
-    validated ``moe`` subsection); the ``serve-moe-ab`` roll-up carries
-    the speedup plus ``moe_pallas_tok_s`` / ``expert_imbalance`` for the
-    bench_trend aux trail.  ``moe_dispatch`` picks which arm's summary
-    lands in the RUNREPORT serving section.
-
-    On the CPU sim the pallas arm runs the INTERPRETER — wall-clock
-    there proves the path runs; the kernel's win is a real-chip number."""
-    import dataclasses
-    import hashlib
-
-    import numpy as np
-
-    from ..models import init_gpt_moe_params
-    from ..serving import Request, ServingEngine
-    from ..utils.logging import master_print
-
-    mcfg = dataclasses.replace(
-        cfg, dtype=jnp.float32, moe_experts=4 if smoke else 8,
-        moe_top_k=2, moe_every=2, moe_capacity_factor=2.0)
-    params = jax.device_put(
-        init_gpt_moe_params(jax.random.PRNGKey(0), mcfg))
-
-    rng = np.random.RandomState(seed + 7)
-    p_lens = [4, 8] if smoke else [16, 32, 64]
-    n_lens = [6, 10] if smoke else [8, 16, 32]
-    reqs = [Request(rng.randint(0, mcfg.vocab_size,
-                                size=int(rng.choice(p_lens))).tolist(),
-                    int(rng.choice(n_lens)))
-            for _ in range(n_requests)]
-    cfg_hash = hashlib.sha1(
-        f"serve-moe|d{mcfg.dim}|L{mcfg.nlayers}|E{mcfg.moe_experts}"
-        f"|n{n_requests}|s{num_slots}|bs{block_size}|c{chunk}|seed{seed}"
-        .encode()).hexdigest()[:12]
-
-    results = {}
-    for arm in ("gather", "pallas"):
-        eng = ServingEngine(
-            params, mcfg, num_slots=num_slots, block_size=block_size,
-            chunk=chunk, max_ctx=max(p_lens) + max(n_lens),
-            moe_dispatch=arm)
-        eng.submit(Request(reqs[0].tokens, 2))  # warm the compiled steps
-        eng.run_until_idle()
-        eng.reset_metrics()
-        wall, summary = _closed_loop(eng, [Request(r.tokens, r.max_new_tokens)
-                                           for r in reqs])
-        tok_s = summary["generated_tokens"] / wall if wall > 0 else 0.0
-        moe = summary.get("moe") or {}
-        line = {
-            "metric": f"serve-moe-{arm}",
-            "value": round(tok_s, 1),
-            "moe_dispatch": arm,
-            "dtype": "float32",
-            "num_experts": mcfg.moe_experts,
-            "expert_imbalance": round(float(moe.get("imbalance", 0.0)), 4),
-            "expert_load_entropy": round(
-                float(moe.get("load_entropy", 0.0)), 4),
-            "dropped_token_rate": round(
-                float(moe.get("dropped_token_rate", 0.0)), 4),
-            "n_requests": n_requests, "num_slots": num_slots,
-            "decode_steps": summary["decode_steps"],
-            "decode_signatures": summary["decode_signatures"],
-            "prefill_signatures": summary["prefill_signatures"],
-            "config_hash": cfg_hash,
-            **_mem_cols(),
-        }
-        master_print(json.dumps(line), flush=True)
-        results[arm] = (eng, summary, tok_s)
-    # token bit-parity between the arms: at capacity = T the fused kernel
-    # keeps the same (token, expert) set as the ragged oracle, and both
-    # run f32 — greedy argmax absorbs accumulation-order noise
-    g_eng, p_eng = results["gather"][0], results["pallas"][0]
-    g_out = [t for _, t in sorted(
-        (f["rid"], tuple(int(x) for x in f["tokens"]))
-        for f in g_eng.finished.values())]
-    p_out = [t for _, t in sorted(
-        (f["rid"], tuple(int(x) for x in f["tokens"]))
-        for f in p_eng.finished.values())]
-    assert g_out == p_out, (
-        "pallas MoE dispatch arm diverged from the gather oracle")
-    moe_chosen = results[moe_dispatch][1].get("moe") or {}
-    master_print(json.dumps({
-        "metric": "serve-moe-ab",
-        # value = pallas/gather speedup (the trended series); the pallas
-        # arm's absolute tokens/s rides the aux trail AND its own line
-        "value": round(results["pallas"][2] / results["gather"][2], 3)
-        if results["gather"][2] > 0 else 0.0,
-        "moe_pallas_tok_s": round(results["pallas"][2], 1),
-        "moe_gather_tok_s": round(results["gather"][2], 1),
-        "expert_imbalance": round(
-            float(moe_chosen.get("imbalance", 0.0)), 4),
-        "bit_parity": True,
-        "interpret_mode": jax.default_backend() == "cpu",
-        "config_hash": cfg_hash,
-    }), flush=True)
-    chosen = results[moe_dispatch][1]
-    tel.record_serving(chosen)
-    return chosen
-
-
 def _parse_args(argv=None):
     ap = argparse.ArgumentParser(
         prog="python -m torchdistpackage_tpu.tools.decode_bench",
@@ -1163,16 +1042,6 @@ def _parse_args(argv=None):
                          "and the serve-longctx-ab rollup")
     ap.add_argument("--cp", type=int, default=2, metavar="N",
                     help="--long-context ring width (default 2)")
-    ap.add_argument("--moe-dispatch", choices=("gather", "pallas"),
-                    default=None,
-                    help="with --serve: add the MoE expert-dispatch A/B "
-                         "on a GPT-MoE engine — BOTH arms always run "
-                         "paired at equal config_hash "
-                         "(serve-moe-{gather,pallas} lines, token "
-                         "bit-parity asserted, expert-load stats on "
-                         "every line); the chosen value picks which "
-                         "arm's summary lands in the RUNREPORT serving "
-                         "section")
     ap.add_argument("--serve-requests", type=int, default=None,
                     metavar="N", help="requests in the --serve schedule "
                     "(default: 8 smoke / 24 full)")
@@ -1290,12 +1159,6 @@ def main(argv=None):
                 contexts=[96, 160] if smoke else [8192, 32768, 131072],
                 block_size=args.block_size, chunk=args.chunk,
                 seed=args.seed, smoke=smoke)
-        if args.moe_dispatch:
-            bench_serve_moe(
-                jax, jnp, cfg, tel, moe_dispatch=args.moe_dispatch,
-                n_requests=args.serve_requests or (8 if smoke else 24),
-                num_slots=args.slots, block_size=args.block_size,
-                chunk=args.chunk, seed=args.seed, smoke=smoke)
         if args.router:
             if args.router < 2:
                 master_print("decode_bench: --router needs R >= 2",
@@ -1316,11 +1179,10 @@ def main(argv=None):
             master_print(phase_table(tel.events.as_list()),
                          file=sys.stderr)
     elif (args.overload or args.shared_prefix or args.spec
-          or args.attn_impl or args.router or args.moe_dispatch
-          or args.long_context):
+          or args.attn_impl or args.router or args.long_context):
         master_print(
             "decode_bench: --overload/--shared-prefix/--spec/--attn-impl/"
-            "--router/--moe-dispatch/--long-context need --serve",
+            "--router/--long-context need --serve",
             file=sys.stderr)
         return 2
     for B, ctx in cells:
