@@ -32,6 +32,8 @@ import pytest
 
 from torchdistpackage_tpu.models import generate, init_gpt_params, llama_config
 from torchdistpackage_tpu.ops.paged_attention import (
+    _heads_per_step,
+    fetched_block,
     modeled_attend_temp_bytes,
     paged_decode_attention,
     resolve_attn_impl,
@@ -125,6 +127,17 @@ def _rand_pool(nb, hkv, bs, hd, seed):
     return kp, vp
 
 
+def _pools_for(int8, shape, rs):
+    """(k, v) pools of ``shape`` [..., nb, hkv, bs, hd]: fp, or int8 pairs."""
+    def side():
+        if int8:
+            return (jnp.asarray(rs.randint(-127, 128, shape), jnp.int8),
+                    jnp.asarray(rs.uniform(1e-3, 2e-2, shape[:-1]),
+                                jnp.float32))
+        return jnp.asarray(rs.standard_normal(shape), jnp.float32)
+    return side(), side()
+
+
 def test_kernel_matches_gather_oracle():
     """Dense + GQA x {decode, K+1 verify} x {causal, sliding window} x
     fetch widths 1/2/4, vector offsets — all within float tolerance of the
@@ -216,17 +229,7 @@ def test_kernel_stacked_pool_matches_per_layer(case):
     L, B, hkv, bs, hd, mb = 3, 2, 2, 4, 8, 5
     nb = 1 + B * mb
     rs = np.random.RandomState(11)
-    if int8:
-        def side():
-            return (jnp.asarray(rs.randint(-127, 128, (L, nb, hkv, bs, hd)),
-                                jnp.int8),
-                    jnp.asarray(rs.uniform(1e-3, 2e-2, (L, nb, hkv, bs)),
-                                jnp.float32))
-    else:
-        def side():
-            return jnp.asarray(rs.standard_normal((L, nb, hkv, bs, hd)),
-                               jnp.float32)
-    kp, vp = side(), side()
+    kp, vp = _pools_for(int8, (L, nb, hkv, bs, hd), rs)
     tables = jnp.asarray(rs.permutation(np.arange(1, nb)).reshape(B, mb),
                          jnp.int32)
     offs = jnp.asarray([9, 12 - s_in], jnp.int32)
@@ -248,6 +251,128 @@ def test_kernel_stacked_pool_matches_per_layer(case):
                                        window=window, layer=li)),
             np.asarray(paged_attention(q, one(kp, li), one(vp, li), offs,
                                        tables=tables, window=window)))
+
+
+#: (q heads, KV heads) of the two serving cells at toy size: Mistral's 32 / 8
+#: and Nemotron's 32 / 2.  Few rows a head, so a grid step carries all of a
+#: slot's KV heads.
+DECODE_GEOMETRIES = {"gqa8-4": (8, 4), "gqa8-2": (8, 2)}
+
+
+@pytest.mark.parametrize("s_in", (1, 3))
+@pytest.mark.parametrize("fw", (1, 2, 3, 4, 6))
+@pytest.mark.parametrize("geom", sorted(DECODE_GEOMETRIES))
+def test_decode_shape_matches_gather_oracle(geom, fw, s_in):
+    """The decode shape (``hb`` > 1 KV heads a grid step) at the cell's table
+    width, every fetch width that divides or covers it, slots whose live
+    blocks number 1, exactly ``fw``, ``fw`` + 1 and ``mb``: decode and the
+    K+1 verify rows, with and without the window, the int8 pool, and the
+    stacked pool under a traced layer, all within float tolerance of the
+    gather oracle."""
+    H, hkv = DECODE_GEOMETRIES[geom]
+    bs, hd, mb, L = 4, 8, 6, 2
+    lives = (1, fw, min(fw + 1, mb), mb)
+    B, nb = len(lives), 1 + len(lives) * mb
+    rows = -(-(H // hkv) * s_in // 8) * 8
+    assert _heads_per_step(hkv, rows, fw, bs * hd * 4) == hkv
+    rs = np.random.RandomState(fw * 10 + s_in)
+    tables = jnp.asarray(rs.permutation(np.arange(1, nb)).reshape(B, mb),
+                         jnp.int32)
+    # the rows' last position is the last but one of the slot's last block
+    offs = jnp.asarray([n * bs - s_in - 1 for n in lives], jnp.int32)
+    q = jnp.asarray(rs.standard_normal((B, H, s_in, hd)), jnp.float32)
+    traced = jax.jit(lambda q, kp, vp, li, window: paged_decode_attention(
+        q, kp, vp, tables, offs, layer=li, window=window, fetch_width=fw),
+        static_argnums=4)
+    # (int8 pool, window, traced layer of the stacked pool)
+    for int8, window, layer in ((False, None, None), (False, 6, 1),
+                                (True, 6 if s_in == 1 else None, None)):
+        shape = (nb, hkv, bs, hd) if layer is None else (L, nb, hkv, bs, hd)
+        kp, vp = _pools_for(int8, shape, rs)
+        want = paged_attention(q, kp, vp, offs, tables=tables, window=window,
+                               layer=layer)
+        if layer is None:
+            got = paged_decode_attention(q, kp, vp, tables, offs,
+                                         window=window, fetch_width=fw)
+        else:
+            got = traced(q, kp, vp, jnp.int32(layer), window)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=2e-6,
+            err_msg=f"int8={int8} window={window} layer={layer}")
+
+
+def _pallas_grid(s_in, fw, *, B=2, H=8, hkv=4, bs=4, hd=8, mb=6):
+    """(grid, block shapes of every operand and the output) of the call's
+    ``pallas_call``, read from its jaxpr."""
+    S = jax.ShapeDtypeStruct
+    pool = S((1 + B * mb, hkv, bs, hd), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, t, o: paged_decode_attention(
+        q, k, v, t, o, fetch_width=fw))(
+            S((B, H, s_in, hd), jnp.float32), pool, pool,
+            S((B, mb), jnp.int32), S((B,), jnp.int32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    gm = call.params["grid_mapping"]
+    blocks = [tuple(d.block_size for d in bm.block_shape)
+              for bm in gm.block_mappings]
+    return call.params["name"], gm.grid, blocks
+
+
+@pytest.mark.parametrize("fw", (1, 2, 4))
+def test_chunk_keeps_one_head_a_step(fw):
+    """``S_in`` = a chunk yields ``hb`` = 1 and ``paged_chunk``'s grid, block
+    shapes and operand count as they were before a step carried several
+    heads (``(slot, kv-head, kv-step)``, a ``(1, 1, 1, bs, hd)`` block a
+    sub-block and side); the decode shape at the same geometry takes all
+    four heads in one step."""
+    B, H, hkv, bs, hd, mb, chunk = 2, 8, 4, 4, 8, 6, 64
+    rows = (H // hkv) * chunk
+    name, grid, blocks = _pallas_grid(chunk, fw)
+    assert name == "paged_chunk"
+    assert grid == (B, hkv, -(-mb // fw))
+    assert blocks == ([(1, 1, rows, hd)] + 2 * fw * [(1, 1, 1, bs, hd)]
+                      + [(1, 1, rows, hd)])
+    name, grid, blocks = _pallas_grid(1, fw)
+    assert name == "paged_decode"
+    assert grid == (B, 1, -(-mb // fw))
+    assert blocks == ([(1, hkv, 8, hd)] + 2 * fw * [(1, 1, hkv, bs, hd)]
+                      + [(1, hkv, 8, hd)])
+
+
+@pytest.mark.parametrize("fw", (1, 2, 3, 4, 6))
+def test_fetch_rule_asks_for_live_blocks_only(fw):
+    """The mechanism's counter, without a chip: walk the grid in its order
+    and issue a copy wherever an operand's index (``fetched_block``: the
+    index map's own rule) differs from the one it holds, as the pipeline
+    does.  Every (slot, head group) then fetches exactly its live blocks,
+    once each (K and V share the map), and the constant block is fetched
+    only by an operand that was live in the slot before and is never live
+    in this one."""
+    bs, mb = 4, 6
+    lives = np.asarray([1, 3, 6, 2, 2, 5, 6, 1])
+    B = len(lives)
+    tables = 1 + np.random.RandomState(fw).permutation(B * mb).reshape(B, mb)
+    for s_in, groups in ((1, 1), (3, 2), (1, 4)):
+        offs = lives * bs - s_in - 1
+        held, got, const = [None] * fw, {}, []
+        for b in range(B):
+            for h in range(groups):
+                for j in range(-(-mb // fw)):
+                    for i in range(fw):
+                        idx = tuple(int(x) for x in fetched_block(
+                            tables, offs, b, h, j, i, S_in=s_in, bs=bs, fw=fw))
+                        if idx == held[i]:
+                            continue
+                        held[i] = idx
+                        if idx == (0, 0):
+                            const.append((b, i))
+                        else:
+                            assert idx[1] == h
+                            got.setdefault((b, h), []).append(idx[0])
+        for b in range(B):
+            for h in range(groups):
+                assert sorted(got[b, h]) == sorted(tables[b, :lives[b]])
+        assert const == [(b, i) for b in range(B) for i in range(fw)
+                         if i >= lives[b] and (b == 0 or i < lives[b - 1])]
 
 
 def test_resolve_attn_impl():

@@ -285,8 +285,10 @@ def _kernel_cases():
     q = S((1, 2, 256, 128), bf)
     flash_args = (jax.grad(flash, argnums=(0, 1, 2)), (q, q, q))
 
-    B, H, hd, bs, mb = 2, 4, 128, 128, 2
-    pool = S((1 + B * mb, H, bs, hd), bf)
+    # GQA 8 / 4: the decode rows take all four KV heads in one grid step,
+    # the chunk's 2 x 64 rows a head take one (ops/paged_attention.py)
+    B, H, Hkv, hd, bs, mb = 2, 8, 4, 128, 128, 2
+    pool = S((1 + B * mb, Hkv, bs, hd), bf)
 
     def paged(s_in):
         from torchdistpackage_tpu.ops.paged_attention import (
@@ -311,7 +313,7 @@ def _kernel_cases():
         "flash_bwd_dq": lambda: flash_args,
         "flash_bwd_dkv": lambda: flash_args,
         "paged_decode": lambda: paged(1),
-        "paged_chunk": lambda: paged(16),
+        "paged_chunk": lambda: paged(64),
         "paged_carry": carry,
     }
 

@@ -6,16 +6,27 @@ materializes every slot's blocks into a contiguous ``[B, Hkv,
 max_blocks*bs, hd]`` view before the dense ``_cached_attention`` — O(max
 context) HBM read AND written per decode tick, whatever the slot's actual
 length, plus an f32 upcast temp of the same size on the int8 pool.  This
-kernel removes that round trip: the grid runs ``(slot, kv_head,
-kv-block-step)`` and each program DMAs ONE pool block into VMEM through a
-scalar-prefetched block table (``PrefetchScalarGridSpec`` — the table IS
-the index map), runs online-softmax flash accumulation against it with
-per-row position masking, and stops issuing fresh fetches past the slot's
-live length (the index map clamps dead steps onto the last live block, so
-Mosaic's block-revisit elision skips the re-fetch).  Per-tick attention
-HBM traffic scales with the tokens a slot actually holds, VMEM per
-program is O(block) — which is what opens 32k+ serving contexts
-(docs/long_context.md) on the same pool.
+kernel removes that round trip: the grid runs ``(slot, kv-head group,
+kv-step)`` and each program DMAs ``fetch_width`` pool blocks into VMEM
+through a scalar-prefetched block table (``PrefetchScalarGridSpec`` — the
+table IS the index map), runs online-softmax flash accumulation against
+them with per-row position masking, and issues no fetch past the slot's
+live length: a sub-block operand whose step lies past the last live block
+asks for the block it already holds (:func:`fetched_block`), and the
+pipeline skips a copy whose index did not change.  (Clamping every dead
+operand onto the slot's LAST live block would spare the copy at
+``fetch_width`` 1 only: wider, each operand moves to that block once
+more.)  Per-tick attention HBM traffic is the live blocks',
+whole, and nothing else; VMEM per program is O(block) — which is what opens
+32k+ serving contexts (docs/long_context.md) on the same pool.
+
+How many KV heads a program carries follows from the shape
+(:func:`_heads_per_step`).  The pool lies ``[L, nb, Hkv, bs, hd]``, so the
+``Hkv`` heads of one block are contiguous: a decode or verify call, with a
+handful of query rows a head, takes them all in ONE copy a block and one
+grid step a slot and kv-step, where a head a step would pay a step's fixed
+price and its nine small copies for two ``[8, 128] x [128, 128]`` products;
+a prefill chunk's hundreds of rows a head keep one head a step.
 
 One entry point covers every serving shape:
 
@@ -70,16 +81,29 @@ NEG_INF = -1e30  # finite "minus infinity": avoids (-inf) - (-inf) NaNs
 
 _LANES = 128  # m/l scratch keeps a full lane dim for layout friendliness
 
+#: A grid step carries several KV heads only while all its query rows fit
+#: one 128-row tile (decode: 8 heads x 8 rows; a chunk's 1,024 rows: 1 head).
+_ROWS_PER_STEP = 128
+
+#: VMEM the double-buffered K + V blocks of one grid step may take (bytes):
+#: ``2 x 2 x fetch_width x heads x block`` stays under it.
+_KV_VMEM_BUDGET = 8 << 20
+
 #: Kernel parameters by device_kind substring.  ``fetch_width`` = pool
 #: blocks streamed per grid step; ``q_pad_to`` = q-row padding multiple (the
-#: K+1 verify shape's G*(K+1) rows are rarely tile-aligned).  The v5e row
-#: compiles and matches the gather oracle on the chip but was never TUNED
-#: there: tools/flash_tune.py ``--paged`` has not run on a v5e, so read it as
-#: "works", not "fastest".  The cpu row is the Pallas interpreter's.  A chip
-#: with no row is an error.
+#: K+1 verify shape's G*(K+1) rows are rarely tile-aligned).  The v5e row is
+#: MEASURED there (PR 29, ``tools/flash_tune.py --paged --shape
+#: mistral7b.decode``: 64 slots x GQA 32 / 8 x block 128, six table columns,
+#: a bf16 pool; ms a call, decode / verify / chunk): (6, 8) 0.203 / 0.216 /
+#: 0.622, (6, 16) 0.208 / 0.217 / 0.621, (1, 8) 0.266 / 0.280 / 0.665,
+#: (2, 8) 0.278 / 0.292 / 0.643, (3, 8) 0.283 / 0.298 / 0.636, (4, 8) 0.336
+#: / 0.350 / 0.670.  6 covers the table: one grid step a slot, and no
+#: operand that moves at a second kv-step.  PERF.md §6 has the 16-layer
+#: loop's table.  The cpu row is the Pallas interpreter's.  A chip with no
+#: row is an error.
 _PAGED_PARAMS = (
-    ("v5 lite", {"fetch_width": 4, "q_pad_to": 8}),
-    ("v5e", {"fetch_width": 4, "q_pad_to": 8}),
+    ("v5 lite", {"fetch_width": 6, "q_pad_to": 8}),
+    ("v5e", {"fetch_width": 6, "q_pad_to": 8}),
     ("cpu", {"fetch_width": 1, "q_pad_to": 8}),
 )
 
@@ -99,6 +123,18 @@ def default_paged_params() -> dict:
     """``{fetch_width, q_pad_to}`` for the attached chip, from
     :data:`_PAGED_PARAMS`."""
     return paged_params_for(jax.devices()[0].device_kind)
+
+
+def _step_params(mb: int, fetch_width: Optional[int],
+                 q_pad_to: Optional[int]) -> Tuple[int, int]:
+    """``(fetch_width, q_pad_to)`` of a call over ``mb`` table columns: the
+    caller's, else the attached chip's row (one row serves the decode, the
+    verify and the chunk rows: PR 29's measurement)."""
+    params = default_paged_params()
+    fw = int(fetch_width if fetch_width is not None else
+             params["fetch_width"])
+    pad_to = int(q_pad_to if q_pad_to is not None else params["q_pad_to"])
+    return max(1, min(fw, mb)), pad_to
 
 
 def resolve_attn_impl(impl: Optional[str]) -> str:
@@ -121,15 +157,70 @@ def _compiler_params():
     )
 
 
+def _heads_per_step(Hkv: int, rows: int, fw: int, block_bytes: int) -> int:
+    """KV heads one grid step carries, from the shape: as many as divide
+    ``Hkv`` while the step's query rows stay within one 128-row tile
+    (:data:`_ROWS_PER_STEP`) and its double-buffered K + V blocks within
+    :data:`_KV_VMEM_BUDGET`.  A chunk's hundreds of rows a head give 1: the
+    grid ``(slot, kv-head, kv-step)`` of one head a step."""
+    hb = 1
+    for cand in range(2, Hkv + 1):
+        if Hkv % cand == 0 and cand * rows <= _ROWS_PER_STEP and (
+                2 * 2 * fw * cand * block_bytes <= _KV_VMEM_BUDGET):
+            hb = cand
+    return hb
+
+
+def fetched_block(tab, off, b, h, j, i, *, S_in: int, bs: int, fw: int):
+    """``(pool block, head group)`` that sub-block operand ``i`` asks for at
+    grid step ``(b, h, j)``: the index map's rule, as a pure function of the
+    table and the offsets (refs, traced or numpy arrays alike).
+
+    Live (``j*fw + i`` within the slot's live blocks): the table's entry.
+    Dead: what the operand already HOLDS, so that the pipeline, which skips
+    a copy whose block index did not change, fetches nothing: its own last
+    live block of this slot, or, if it was never live here, head group 0 of
+    pool block 0 (any valid block: the compute of a dead sub-block is
+    skipped), which stays the same index from head to head and slot to
+    slot."""
+    mb = tab.shape[-1]
+    hi1 = jnp.minimum((off[b] + S_in + bs - 1) // bs, mb) - 1
+    blk = j * fw + i
+    own_last = i + fw * (jnp.maximum(hi1 - i, 0) // fw)
+    col = jnp.where(blk <= hi1, blk, own_last)
+    live_here = i <= hi1
+    return (jnp.where(live_here, tab[b, jnp.minimum(col, mb - 1)], 0),
+            jnp.where(live_here, h, 0))
+
+
+def _accumulate(s, keep, pv, acc_ref, m_ref, l_ref):
+    """One KV block's online-softmax step on the (acc, m, l) scratch:
+    ``s`` the scaled f32 scores ``[..., rows, bs]`` (a leading head axis or
+    none), ``keep`` their mask, ``pv(p)`` the f32 ``[..., rows, hd]`` product
+    of the block's probabilities with its values."""
+    s = jnp.where(keep, s, NEG_INF)
+    m = m_ref[..., :1]
+    l = l_ref[..., :1]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l_ref[...] = jnp.broadcast_to(
+        l * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+    acc_ref[...] = acc_ref[...] * corr + pv(p)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+
 def _kernel(
     tab_ref, off_ref, lay_ref, q_ref, *refs,
-    S_in, bs, window, sm_scale, quantized, fetch_width, rows,
+    S_in, bs, window, sm_scale, quantized, fetch_width, rows, hb,
 ):
-    """Grid ``(slot b, kv-head h, kv-step j)``; ``lay_ref`` (the layer of
-    the stacked pool) is read by the index maps alone; ``refs`` carries the
-    ``fetch_width`` per-step KV blocks ((k, v) dense or (k8, ks, v8, vs)
-    quantized, sub-block-major), then the output ref and the (acc, m, l)
-    online-softmax VMEM scratch carried across j steps."""
+    """Grid ``(slot b, kv-head group h, kv-step j)``, ``hb`` KV heads a
+    group, the batch axis of every product in here; ``lay_ref`` (the layer
+    of the stacked pool) is read by the index maps alone; ``refs`` carries
+    the ``fetch_width`` per-step KV blocks of ``hb`` heads each ((k, v)
+    dense or (k8, ks, v8, vs) quantized, sub-block-major), then the output
+    ref and the (acc, m, l) online-softmax VMEM scratch carried across j
+    steps."""
     per = 4 if quantized else 2
     kv_refs = refs[:fetch_width * per]
     o_ref = refs[fetch_width * per]
@@ -145,10 +236,16 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]  # [rows, hd]
+    q = q_ref[0]  # [hb, rows, hd]
     # row r covers query position off + (r % S_in) (group-major rows);
     # padded rows past the real R mask everything and are sliced off
-    qpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) % S_in
+    qpos = off + jax.lax.broadcasted_iota(
+        jnp.int32, (hb, rows, bs), 1) % S_in
+    # scores [hb, rows, bs] = q . k over hd; update [hb, rows, hd] = p . v
+    qk = functools.partial(jnp.einsum, "hrd,hkd->hrk",
+                           preferred_element_type=jnp.float32)
+    pv = functools.partial(jnp.einsum, "hrk,hkd->hrd",
+                           preferred_element_type=jnp.float32)
 
     for i in range(fetch_width):
         blk = j * fetch_width + i  # absolute pool-block step
@@ -156,48 +253,29 @@ def _kernel(
         @pl.when(blk < hi)
         def _compute(i=i, blk=blk):
             if quantized:
-                k8 = kv_refs[4 * i][0, 0, 0]
-                ks = kv_refs[4 * i + 1][0, 0, 0]  # [1, bs]
-                v8 = kv_refs[4 * i + 2][0, 0, 0]
-                vs = kv_refs[4 * i + 3][0, 0, 0]  # [1, bs]
-                kblk = k8.astype(jnp.float32)
-                s = jnp.dot(q.astype(jnp.float32), kblk.T,
-                            preferred_element_type=jnp.float32)
-                s = s * ks
+                k8 = kv_refs[4 * i][0, 0]
+                ks = kv_refs[4 * i + 1][0, 0]  # [hb, 1, bs]
+                v8 = kv_refs[4 * i + 2][0, 0]
+                vs = kv_refs[4 * i + 3][0, 0]  # [hb, 1, bs]
+                s = qk(q.astype(jnp.float32), k8.astype(jnp.float32)) * ks
+                upd = lambda p: pv(p * vs, v8.astype(jnp.float32))
             else:
-                kblk = kv_refs[2 * i][0, 0, 0]
-                s = jnp.dot(q, kblk.T,
-                            preferred_element_type=jnp.float32)
-            s = s * sm_scale
+                kblk = kv_refs[2 * i][0, 0]  # [hb, bs, hd]
+                vblk = kv_refs[2 * i + 1][0, 0]
+                s = qk(q, kblk)
+                upd = lambda p: pv(p.astype(vblk.dtype), vblk)
             kpos = blk * bs + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, bs), 1)
+                jnp.int32, (hb, rows, bs), 2)
             keep = kpos <= qpos
             if window is not None:  # Mistral: key in (qpos - window, qpos]
                 keep = keep & (kpos > qpos - window)
-            s = jnp.where(keep, s, NEG_INF)
-            m = m_ref[:, :1]
-            l = l_ref[:, :1]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)
-            l_ref[...] = jnp.broadcast_to(
-                l * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-            if quantized:
-                pv = p * vs
-                upd = jnp.dot(pv, v8.astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
-            else:
-                vblk = kv_refs[2 * i + 1][0, 0, 0]
-                upd = jnp.dot(p.astype(vblk.dtype), vblk,
-                              preferred_element_type=jnp.float32)
-            acc_ref[...] = acc_ref[...] * corr + upd
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            _accumulate(s * sm_scale, keep, upd, acc_ref, m_ref, l_ref)
 
     @pl.when(j == (hi - 1) // fetch_width)
     def _write():
         # l > 0 for every real row (a query always attends its own
         # position); padded rows divide garbage that is sliced away
-        o_ref[0, 0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[..., :1]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -241,18 +319,14 @@ def paged_decode_attention(
     mb = tables.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
-    params = default_paged_params()
-    fw = int(fetch_width if fetch_width is not None else
-             params["fetch_width"])
-    fw = max(1, min(fw, mb))
-    pad_to = int(q_pad_to if q_pad_to is not None else params["q_pad_to"])
-
     offs = jnp.asarray(offsets, jnp.int32)
     if offs.ndim == 0:
         offs = jnp.broadcast_to(offs, (B,))
     # group-major rows: row r = g*S_in + s covers position off + s
     R = groups * S_in
+    fw, pad_to = _step_params(mb, fetch_width, q_pad_to)
     rows = -(-R // pad_to) * pad_to
+    hb = _heads_per_step(Hkv, rows, fw, bs * hd * k_arr.dtype.itemsize)
     qr = q.reshape(B, Hkv, R, hd)
     if rows != R:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - R), (0, 0)))
@@ -261,56 +335,40 @@ def paged_decode_attention(
         return (b, h, 0, 0)
 
     def kvidx(b, h, j, tab, off, lay, i=0, own_layer=False):
-        # clamp dead steps onto the last live block: consecutive grid
-        # steps then revisit the same index and Mosaic skips the re-fetch
-        # — attention HBM traffic scales with the slot's ACTUAL length
-        hi1 = (off[b] + S_in + bs - 1) // bs - 1
-        blk = jnp.minimum(jnp.minimum(j * fw + i, hi1), mb - 1)
-        return (0 if own_layer else lay[0], tab[b, blk], h, 0, 0)
+        blk, hg = fetched_block(tab, off, b, h, j, i, S_in=S_in, bs=bs, fw=fw)
+        return (0 if own_layer else lay[0], blk, hg, 0, 0)
 
-    in_specs = [pl.BlockSpec((1, 1, rows, hd), qidx)]
+    # per sub-block (k, v) / (k8, ks, v8, vs), sub-block-major, each block
+    # the hb heads of one pool block: one contiguous copy
+    in_specs = [pl.BlockSpec((1, hb, rows, hd), qidx)]
     operands = [qr]
-    for pool in (k_pool, v_pool):
-        scales = _scale_rows(pool[1], lay) if quantized else None
-        for i in range(fw):
+    scales = [_scale_rows(pool[1], lay) if quantized else None
+              for pool in (k_pool, v_pool)]
+    for i in range(fw):
+        for pool, scale in zip((k_pool, v_pool), scales):
+            in_specs.append(pl.BlockSpec(
+                (1, 1, hb, bs, hd), functools.partial(kvidx, i=i)))
+            operands.append(pool[0] if quantized else pool)
             if quantized:
                 in_specs.append(pl.BlockSpec(
-                    (1, 1, 1, bs, hd), functools.partial(kvidx, i=i)))
-                operands.append(pool[0])
-                in_specs.append(pl.BlockSpec(
-                    (1, 1, 1, 1, bs),
+                    (1, 1, hb, 1, bs),
                     functools.partial(kvidx, i=i, own_layer=True)))
-                operands.append(scales)
-            else:
-                in_specs.append(pl.BlockSpec(
-                    (1, 1, 1, bs, hd), functools.partial(kvidx, i=i)))
-                operands.append(pool)
-    # interleave per sub-block: kernel expects (k, v) / (k8, ks, v8, vs)
-    # pairs sub-block-major — reorder the flat k-then-v lists
-    per = 2 if quantized else 1
-    k_ops, v_ops = operands[1:1 + fw * per], operands[1 + fw * per:]
-    k_specs, v_specs = in_specs[1:1 + fw * per], in_specs[1 + fw * per:]
-    ordered_ops, ordered_specs = [operands[0]], [in_specs[0]]
-    for i in range(fw):
-        ordered_ops.extend(k_ops[per * i:per * (i + 1)])
-        ordered_ops.extend(v_ops[per * i:per * (i + 1)])
-        ordered_specs.extend(k_specs[per * i:per * (i + 1)])
-        ordered_specs.extend(v_specs[per * i:per * (i + 1)])
+                operands.append(scale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, Hkv, -(-mb // fw)),
-        in_specs=ordered_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, hd), qidx),
+        grid=(B, Hkv // hb, -(-mb // fw)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, hb, rows, hd), qidx),
         scratch_shapes=[
-            pltpu.VMEM((rows, hd), jnp.float32),     # acc
-            pltpu.VMEM((rows, _LANES), jnp.float32),  # m
-            pltpu.VMEM((rows, _LANES), jnp.float32),  # l
+            pltpu.VMEM((hb, rows, hd), jnp.float32),      # acc
+            pltpu.VMEM((hb, rows, _LANES), jnp.float32),  # m
+            pltpu.VMEM((hb, rows, _LANES), jnp.float32),  # l
         ],
     )
     kernel = functools.partial(
         _kernel, S_in=S_in, bs=bs, window=window, sm_scale=float(sm_scale),
-        quantized=quantized, fetch_width=fw, rows=rows)
+        quantized=quantized, fetch_width=fw, rows=rows, hb=hb)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -318,7 +376,7 @@ def paged_decode_attention(
         compiler_params=_compiler_params(),
         interpret=_interpret(),
         name="paged_decode" if S_in == 1 else "paged_chunk",
-    )(tables.astype(jnp.int32), offs, lay, *ordered_ops)
+    )(tables.astype(jnp.int32), offs, lay, *operands)
     return out[:, :, :R].reshape(B, H, S_in, hd)
 
 
@@ -393,26 +451,18 @@ def _cp_kernel(
             raw = tab_ref[b, blk]  # re-based id; out of [0, nb) = remote
             owned = (raw >= 0) & (raw < nb)
             kblk = kv_refs[2 * i][0, 0, 0]
+            vblk = kv_refs[2 * i + 1][0, 0, 0]
             s = jnp.dot(q, kblk.T, preferred_element_type=jnp.float32)
-            s = s * sm_scale
             kpos = blk * bs + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, bs), 1)
             keep = (kpos <= qpos) & owned
             if window is not None:
                 keep = keep & (kpos > qpos - window)
-            s = jnp.where(keep, s, NEG_INF)
-            m = m_ref[:, :1]
-            l = l_ref[:, :1]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)
-            l_ref[...] = jnp.broadcast_to(
-                l * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-            vblk = kv_refs[2 * i + 1][0, 0, 0]
-            upd = jnp.dot(p.astype(vblk.dtype), vblk,
-                          preferred_element_type=jnp.float32)
-            acc_ref[...] = acc_ref[...] * corr + upd
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            _accumulate(
+                s * sm_scale, keep,
+                lambda p: jnp.dot(p.astype(vblk.dtype), vblk,
+                                  preferred_element_type=jnp.float32),
+                acc_ref, m_ref, l_ref)
 
     @pl.when(j == (hi - 1) // fetch_width)
     def _write():
@@ -467,16 +517,11 @@ def paged_carry_attention(
     mb = tables_local.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
-    params = default_paged_params()
-    fw = int(fetch_width if fetch_width is not None else
-             params["fetch_width"])
-    fw = max(1, min(fw, mb))
-    pad_to = int(q_pad_to if q_pad_to is not None else params["q_pad_to"])
-
     offs = jnp.asarray(offsets, jnp.int32)
     if offs.ndim == 0:
         offs = jnp.broadcast_to(offs, (B,))
     R = groups * S_in
+    fw, pad_to = _step_params(mb, fetch_width, q_pad_to)
     rows = -(-R // pad_to) * pad_to
     qr = q.reshape(B, Hkv, R, hd)
     if rows != R:
@@ -486,13 +531,11 @@ def paged_carry_attention(
         return (b, h, 0, 0)
 
     def kvidx(b, h, j, tab, off, lay, i=0):
-        # same dead-step clamp as the decode kernel, plus a clamp of the
-        # re-based table entry into the slice (remote blocks fetch SOME
-        # valid block; the in-kernel ownership test masks the scores)
-        hi1 = (off[b] + S_in + bs - 1) // bs - 1
-        blk = jnp.minimum(jnp.minimum(j * fw + i, hi1), mb - 1)
-        idx = jnp.clip(tab[b, blk], 0, nb - 1)
-        return (lay[0], idx, h, 0, 0)
+        # the decode kernel's fetch rule, plus a clamp of the re-based
+        # table entry into the slice (remote blocks fetch SOME valid
+        # block; the in-kernel ownership test masks the scores)
+        blk, hg = fetched_block(tab, off, b, h, j, i, S_in=S_in, bs=bs, fw=fw)
+        return (lay[0], jnp.clip(blk, 0, nb - 1), hg, 0, 0)
 
     has_carry = carry is not None
     in_specs = [pl.BlockSpec((1, 1, rows, hd), qidx)]
@@ -569,14 +612,19 @@ def modeled_attend_temp_bytes(
     ``gather``: the dense per-slot view ``[B, Hkv, max_blocks*bs, hd]``
     materialized for k AND v (the int8 pool additionally upcasts both to
     f32 in the einsum, so ``itemsize=4`` models that case too) — O(max
-    context) whatever the slot holds.  ``pallas``: q/out rows plus
-    ``fetch_width`` double-buffered KV blocks per program — O(block),
+    context) whatever the slot holds.  ``pallas``: what one program holds
+    in VMEM (the q/out rows of its ``hb`` KV heads plus ``fetch_width``
+    double-buffered K and V blocks of ``hb`` heads each, ``hb`` from
+    :func:`_heads_per_step`) times the programs of one kv-step — O(block),
     independent of context."""
     if impl == "gather":
         return 2 * batch * kv_heads * max_blocks * block_size * head_dim * itemsize
     if impl == "pallas":
-        fw = int(fetch_width or paged_params_for("cpu")["fetch_width"])
+        fw = int(fetch_width or default_paged_params()["fetch_width"])
         rows = groups * s_in
-        blocks = 2 * 2 * fw * block_size * head_dim * itemsize  # k+v, 2-buf
-        return batch * kv_heads * (2 * rows * head_dim * itemsize + blocks)
+        block = block_size * head_dim * itemsize
+        hb = _heads_per_step(kv_heads, rows, fw, block)
+        # one program: hb heads' q and out rows, fw K + V blocks, 2-buffered
+        program = hb * (2 * rows * head_dim * itemsize + 2 * 2 * fw * block)
+        return batch * (kv_heads // hb) * program
     raise ValueError(f"impl must be 'gather' or 'pallas', got {impl!r}")

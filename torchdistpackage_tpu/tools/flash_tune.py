@@ -19,10 +19,15 @@ or CLI: ``python -m torchdistpackage_tpu.tools.flash_tune --seq 2048``.
 streamed per grid step — how wide the in-kernel table walk fetches
 relative to the pool ``block_size``) and ``q_pad_to`` (the q-row padding
 multiple; the speculative K+1 verify shape lands at awkward row counts),
-timed at BOTH serving shapes — ``S_in=1`` ordinary decode and ``S_in=K+1``
-spec verify — so one (fetch_width, q_pad_to) row serves both compiled
-engine programs.  ``_PAGED_PARAMS`` in ops/paged_attention.py is the
-consumer of a measured row.
+timed at BOTH decode-program shapes — ``S_in=1`` ordinary decode and
+``S_in=K+1`` spec verify — so one (fetch_width, q_pad_to) row serves both,
+and, where the shape names a prefill chunk, at its shape too, whose
+time the report prints beside (``chunk_rel``: the same row serves
+``paged_chunk``, and must not cost it).  ``hb`` is the KV heads a grid step
+of the decode shape carries, which the kernel computes from the shape.
+``--shape mistral7b.decode`` is the benchmark cell's geometry.
+``_PAGED_PARAMS`` in ops/paged_attention.py is the consumer of a measured
+row.
 
 Timing chains the iterations through a data dependency and fetches a scalar
 at the end, so the clock stops after the device has finished.
@@ -137,47 +142,62 @@ def tune_flash_blocks(
 # ------------------------------------------------- paged-attention tuner
 
 #: (fetch_width, q_pad_to) candidates for the paged decode kernel;
-#: fetch_width is clamped to the table width per shape.
+#: fetch_width is clamped to the table width per shape.  1, 2, 3 and 6
+#: divide or cover the benchmark cell's six table columns.
 PAGED_CANDIDATES: Tuple[Tuple[int, int], ...] = (
     (1, 8),
     (2, 8),
+    (3, 8),
     (4, 8),
+    (6, 8),
     (8, 8),
-    (1, 16),
-    (4, 16),
+    (3, 16),
+    (6, 16),
 )
+
+#: Named geometries for ``--shape``: ``tune_paged_params`` arguments.
+#: ``mistral7b.decode``: the benchmark cell's engine (64 slots, GQA 32 / 8
+#: x 128, block 128, ``max_ctx`` 768, chunk 256 at 8 slots a prefill call,
+#: a bf16 pool of 385 blocks), slots 10-70% full (mean ~300 tokens).
+PAGED_SHAPES = {
+    "mistral7b.decode": dict(
+        num_slots=64, kv_heads=8, groups=4, head_dim=128, block_size=128,
+        max_blocks=6, spec_k=2, chunk=256, chunk_slots=8, fill=(0.1, 0.7),
+        dtype="bfloat16"),
+}
 
 
 def _time_paged_config(
-    q_shapes, k_pool, v_pool, tables, offsets, fetch_width, q_pad_to,
-    steps: int, warmup: int, seed: int,
+    q_shape, k_pool, v_pool, tables, offsets, fetch_width, q_pad_to,
+    steps: int, warmup: int, seed: int, calls: int = 16,
 ) -> float:
-    """Seconds per decode step for one (fetch_width, q_pad_to), SUMMED
-    over the serving q shapes (S_in=1 decode + S_in=K+1 verify) — the
-    engine compiles both, so the winning row must serve both."""
+    """Seconds per call of the kernel at one q shape for one
+    (fetch_width, q_pad_to).  One dispatch runs ``calls`` calls chained
+    through q (a decode call takes a fraction of a millisecond, less than
+    the host needs to launch it)."""
     from ..ops.paged_attention import paged_decode_attention
 
-    total = 0.0
-    for shape in q_shapes:
-        q = jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
+    q = 0.1 * jax.random.normal(jax.random.PRNGKey(seed), q_shape,
+                                k_pool.dtype)
 
-        step = jax.jit(lambda qq: paged_decode_attention(
-            qq, k_pool, v_pool, tables, offsets,
-            fetch_width=fetch_width, q_pad_to=q_pad_to))
+    @jax.jit
+    def step(qq, kp, vp):
+        def body(qq, _):
+            return qq + paged_decode_attention(
+                qq, kp, vp, tables, offsets,
+                fetch_width=fetch_width, q_pad_to=q_pad_to), None
+        return jax.lax.scan(body, qq, None, length=calls)[0]
 
-        def chain(qq, n):
-            for _ in range(n):
-                out = step(qq)
-                qq = qq + 0 * out
-            return qq
+    def run(n):
+        out = q
+        for _ in range(n):
+            out = step(q, k_pool, v_pool)
+        float(jnp.sum(out[0, 0, 0].astype(jnp.float32)))
 
-        q1 = chain(q, warmup)
-        float(jnp.sum(q1[0, 0, 0].astype(jnp.float32)))
-        t0 = time.perf_counter()
-        q2 = chain(q, steps)
-        float(jnp.sum(q2[0, 0, 0].astype(jnp.float32)))
-        total += (time.perf_counter() - t0) / steps
-    return total
+    run(max(1, warmup))
+    t0 = time.perf_counter()
+    run(steps)
+    return (time.perf_counter() - t0) / (steps * calls)
 
 
 def tune_paged_params(
@@ -188,6 +208,10 @@ def tune_paged_params(
     block_size: int = 64,
     max_blocks: int = 64,
     spec_k: int = 2,
+    chunk: int = 0,
+    chunk_slots: int = 8,
+    fill: Tuple[float, float] = (0.25, 1.0),
+    dtype="float32",
     candidates: Sequence[Tuple[int, int]] = PAGED_CANDIDATES,
     steps: int = 10,
     warmup: int = 2,
@@ -195,53 +219,74 @@ def tune_paged_params(
 ) -> Tuple[dict, List[dict]]:
     """Measure every (fetch_width, q_pad_to) candidate at a serving shape:
     a ``[max_blocks*num_slots + 1, kv_heads, block_size, head_dim]`` pool
-    with per-slot tables at mixed live lengths, q at S_in=1 (decode) AND
-    S_in=spec_k+1 (the verify program).  Returns ``(best, report)`` with
-    ``report`` rows ``{"fetch_width", "q_pad_to", "ms", "rel"}`` sorted
-    fastest-first."""
+    with per-slot tables at mixed live lengths (``fill``: the share of the
+    max context a slot holds, drawn uniformly between the two), q at
+    S_in=1 (decode) AND S_in=spec_k+1 (the verify program); with ``chunk``,
+    also ``chunk_slots`` slots' prefill chunk.  Returns ``(best, report)``
+    with ``report`` rows ``{"fetch_width", "q_pad_to", "hb", "ms",
+    "decode_ms", "verify_ms"[, "chunk_ms", "chunk_rel"], "rel"}`` sorted
+    fastest-first by ``ms`` = decode + verify; ``chunk_rel`` is the chunk's
+    time over the fastest chunk's."""
     import numpy as np
 
+    from ..ops.paged_attention import _heads_per_step
+
+    dtype = jnp.dtype(dtype)
     nb = max_blocks * num_slots + 1
     kp = jax.random.normal(
         jax.random.PRNGKey(seed + 1),
-        (nb, kv_heads, block_size, head_dim), jnp.float32)
+        (nb, kv_heads, block_size, head_dim), dtype)
     vp = jax.random.normal(
         jax.random.PRNGKey(seed + 2),
-        (nb, kv_heads, block_size, head_dim), jnp.float32)
+        (nb, kv_heads, block_size, head_dim), dtype)
     rng = np.random.RandomState(seed)
     tables = jnp.asarray(
         rng.permutation(np.arange(1, nb))[:num_slots * max_blocks]
         .reshape(num_slots, max_blocks), jnp.int32)
-    # mixed live depths: slots between 25% and 100% of max context
+    max_ctx = max_blocks * block_size
     offsets = jnp.asarray(
-        rng.randint(max_blocks * block_size // 4,
-                    max_blocks * block_size - spec_k - 1,
+        rng.randint(int(max_ctx * fill[0]),
+                    min(int(max_ctx * fill[1]), max_ctx - spec_k - 1),
                     size=num_slots), jnp.int32)
     H = kv_heads * groups
-    q_shapes = [(num_slots, H, 1, head_dim),
-                (num_slots, H, spec_k + 1, head_dim)]
+    # shape name -> (slots, S_in, offsets)
+    shapes = {"decode": (num_slots, 1, offsets),
+              "verify": (num_slots, spec_k + 1, offsets)}
+    if chunk:
+        shapes["chunk"] = (
+            chunk_slots, chunk,
+            jnp.minimum(offsets[:chunk_slots] // chunk * chunk,
+                        max_ctx - chunk))
 
     rows = []
     for fw, pad in candidates:
         if fw > max_blocks:
             continue
+        row = {"fetch_width": fw, "q_pad_to": pad,
+               "hb": _heads_per_step(
+                   kv_heads, -(-groups // pad) * pad, fw,
+                   block_size * head_dim * dtype.itemsize)}
         try:
-            dt = _time_paged_config(
-                q_shapes, kp, vp, tables, offsets, fw, pad, steps, warmup,
-                seed)
+            for name, (slots, s_in, offs) in shapes.items():
+                row[f"{name}_ms"] = 1e3 * _time_paged_config(
+                    (slots, H, s_in, head_dim), kp, vp, tables[:slots],
+                    offs, fw, pad, steps, warmup, seed)
+            row["ms"] = row["decode_ms"] + row["verify_ms"]
         except Exception as e:  # one bad config must not kill the sweep
-            rows.append({"fetch_width": fw, "q_pad_to": pad,
-                         "ms": None, "error": repr(e)[:200]})
-            continue
-        rows.append({"fetch_width": fw, "q_pad_to": pad, "ms": dt * 1e3})
+            row.update(ms=None, error=repr(e)[:200])
+        rows.append(row)
     ok = [r for r in rows if r.get("ms") is not None]
     if not ok:
         raise RuntimeError(f"no paged config succeeded: {rows}")
     ok.sort(key=lambda r: r["ms"])
     best_ms = ok[0]["ms"]
+    best_chunk = min(r.get("chunk_ms", 0.0) for r in ok)
     for r in ok:
         r["rel"] = round(r["ms"] / best_ms, 3)
-        r["ms"] = round(r["ms"], 3)
+        if chunk:
+            r["chunk_rel"] = round(r["chunk_ms"] / best_chunk, 3)
+        for k in [k for k in r if k == "ms" or k.endswith("_ms")]:
+            r[k] = round(r[k], 3)
     report = ok + [r for r in rows if r.get("ms") is None]
     best = {"fetch_width": ok[0]["fetch_width"],
             "q_pad_to": ok[0]["q_pad_to"]}
@@ -273,23 +318,23 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                     help="--paged: table width (max_ctx / block_size)")
     ap.add_argument("--spec-k", type=int, default=2,
                     help="--paged: verify draft width (S_in = K+1 shape)")
+    ap.add_argument("--shape", choices=sorted(PAGED_SHAPES),
+                    help="--paged: a named geometry (with its prefill chunk "
+                         "and pool dtype), in place of the options above")
     args = ap.parse_args(argv)
     from ..utils.logging import master_print
 
     if args.paged:
-        best, report = tune_paged_params(
+        shape = dict(PAGED_SHAPES[args.shape]) if args.shape else dict(
             num_slots=args.slots, kv_heads=args.kv_heads,
             head_dim=args.head_dim, block_size=args.block_size,
-            max_blocks=args.max_blocks, spec_k=args.spec_k,
-            steps=args.steps)
+            max_blocks=args.max_blocks, spec_k=args.spec_k)
+        best, report = tune_paged_params(steps=args.steps, **shape)
         master_print(json.dumps({
             "kernel": "paged_attention",
             "backend": jax.default_backend(),
             "chip": jax.devices()[0].device_kind,
-            "shape": {"num_slots": args.slots, "kv_heads": args.kv_heads,
-                      "head_dim": args.head_dim,
-                      "block_size": args.block_size,
-                      "max_blocks": args.max_blocks, "spec_k": args.spec_k},
+            "shape": shape,
             "best": best,
             "report": report,
         }, indent=1))
