@@ -126,7 +126,10 @@ def _setup_mesh(cfg: SmokeConfig):
 
 def kernels_phase(cfg: SmokeConfig) -> Dict[str, Any]:
     """Flash fwd+bwd against ``mha_reference``, paged decode and paged chunk
-    against the gather path, at the per-chip shapes the other phases run.
+    against the gather path, at the per-chip shapes the other phases run;
+    the latent kernel's decode and chunk calls (``H`` absorbed queries a
+    slot over one shared row of ``4.5 x head_dim`` a position, the first
+    ``4 x`` its value: 576 / 512 at a head of 128) against theirs.
     The reference side computes in float32 at ``highest`` matmul precision.
     Tolerance: max abs error <= 2^-6 of the reference's largest magnitude —
     four bf16 ulps, what bf16 inputs and probabilities cost; a wrong mask or
@@ -135,6 +138,8 @@ def kernels_phase(cfg: SmokeConfig) -> Dict[str, Any]:
     import jax.numpy as jnp
 
     from torchdistpackage_tpu.ops import flash_attention, mha_reference
+    from torchdistpackage_tpu.ops.mla_attention import (
+        mla_gather_attention, mla_paged_attention)
     from torchdistpackage_tpu.serving.paged_cache import paged_attention
 
     m = cfg.model
@@ -197,6 +202,22 @@ def kernels_phase(cfg: SmokeConfig) -> Dict[str, Any]:
                 q.astype(f32), kc.astype(f32), vc.astype(f32), o, tables=t,
                 impl="gather"))(qp, k_pool, v_pool, offs, tables)
         out[f"paged_{name}"] = rel_err(got, ref)
+
+        # latent: the same tables and offsets over a pool of shared rows
+        dc, W = 4 * hd, 4 * hd + hd // 2
+        lat = jax.random.normal(
+            jax.random.PRNGKey(cfg.seed + 3), (nb, 1, W, cfg.block_size),
+            f32).astype(dt)
+        ql = jax.random.normal(
+            jax.random.PRNGKey(cfg.seed + 4), (Bs, H, s_in, W), f32).astype(dt)
+        kw = dict(latent=dc, sm_scale=W ** -0.5)
+        got = jax.jit(lambda q, p, o, t: mla_paged_attention(
+            q, p, t, o, **kw))(ql, lat, offs, tables)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda q, p, o, t: mla_gather_attention(
+                q.astype(f32), p.astype(f32), t, o, **kw))(
+                    ql, lat, offs, tables)
+        out[f"mla_{name}"] = rel_err(got, ref)
 
     tol = 2.0 ** -6
     bad = {n: e for n, e in out.items() if not e <= tol}

@@ -52,7 +52,8 @@ def test_phases_at_toy_size_on_the_sim(devices8):
 
     kernels = cs.kernels_phase(cfg)
     assert set(kernels) >= {"flash_o", "flash_dq", "flash_dk", "flash_dv",
-                            "paged_decode", "paged_chunk"}
+                            "paged_decode", "paged_chunk", "mla_decode",
+                            "mla_chunk"}
 
     serve = cs.serve_phase(cfg, compiles)
     assert serve["attn_impl"] == "gather"  # what 'auto' means off the TPU

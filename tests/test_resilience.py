@@ -470,12 +470,15 @@ def test_stall_trips_watchdog_hang_suspected(tmp_path, events):
     # the injected stall is flagged exactly once, at its step.  A loaded
     # host may also take longer than the 0.15 s timeout over some other
     # step (the suite runs six workers): that is the host's, so only the
-    # bookkeeping is asserted of it: counted, and resolved by the next beat
+    # bookkeeping is asserted of it: counted, and resolved by the next beat.
+    # The last step (4: the forced save and the wait for it) has no next
+    # beat, so an episode that opens there stays open when the loop ends
     sus = events.of_kind("hang_suspected")
     assert [e["last_step"] for e in sus].count(3) == 1
     assert res.summary["hang_suspected"] == len(sus)
     assert [e["fault"] for e in events.of_kind("fault_injected")] == ["stall"]
-    assert len(events.of_kind("hang_resolved")) == len(sus)
+    beaten = [e for e in sus if e["last_step"] < 4]
+    assert len(beaten) <= len(events.of_kind("hang_resolved")) <= len(sus)
 
 
 # ============================================================== watchdog

@@ -308,6 +308,15 @@ def _kernel_cases():
                 (S((B, H, 16, hd), bf), pool, pool,
                  S((B, mb), jnp.int32), S((B,), jnp.int32)))
 
+    def latent(s_in):
+        from torchdistpackage_tpu.ops.mla_attention import mla_paged_attention
+
+        # 8 heads over one cached row of 128 + 64 a position
+        return (lambda q, p, t, o: mla_paged_attention(
+            q, p, t, o, latent=128, sm_scale=0.1, fetch_width=2),
+                (S((B, H, s_in, 192), bf), S((1 + B * mb, 1, 192, bs), bf),
+                 S((B, mb), jnp.int32), S((B,), jnp.int32)))
+
     return {
         "flash_fwd": lambda: flash_args,
         "flash_bwd_dq": lambda: flash_args,
@@ -315,19 +324,21 @@ def _kernel_cases():
         "paged_decode": lambda: paged(1),
         "paged_chunk": lambda: paged(64),
         "paged_carry": carry,
+        "mla_decode": lambda: latent(1),
+        "mla_chunk": lambda: latent(64),
     }
 
 
 @pytest.mark.parametrize("kernel", [
     "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
-    "paged_chunk", "paged_carry"])
+    "paged_chunk", "paged_carry", "mla_decode", "mla_chunk"])
 def test_kernel_lowers_under_its_name(monkeypatch, kernel):
     """XLA names a Mosaic custom call after the name-stack component before
     ``pallas_call``: that is the kernel's ``name=``, which the device
     trace then shows (``%flash_fwd.1 = ... custom-call``).  Lowered for TPU
     from the CPU: every kernel the package holds lowers for the chip."""
     fn, args = _kernel_cases()[kernel]()
-    for mod in ("flash_attention", "paged_attention"):
+    for mod in ("flash_attention", "paged_attention", "mla_attention"):
         monkeypatch.setattr(
             importlib.import_module(f"torchdistpackage_tpu.ops.{mod}"),
             "_interpret", lambda: False)

@@ -7,12 +7,18 @@ layer is ONE mixer and not attention + MLP (the ``nemotron_h`` shape):
 - ``*``  grouped-query attention with NO positional encoding (the Mamba
   layers carry order), through the same cache ops (block pool, paged
   kernel) as every other family;
-- ``E``  the latent expert layer (:func:`~..parallel.moe.moe_serve_forward`
-  with ``score='sigmoid'``, a latent width, a shared expert and a held
-  range of experts).
+- ``E``  the expert layer (:func:`~..parallel.moe.moe_serve_forward` with
+  ``score='sigmoid'``, a shared expert and a held range of experts;
+  ``moe_act`` 'relu2' experts in a latent width, or gated 'swiglu' ones);
+- ``L``  latent attention (:func:`latent_attention_mixer`): every head's
+  keys and values are up-projections of ONE cached row a position, the
+  normed latent and a rotated key shared by all heads, and attention runs
+  in that latent (the absorbed form), over a block pool of its own shape;
+- ``D``  a dense gated MLP (SwiGLU, gate and up side by side).
 
-Every layer is ``x <- x + mixer(RMSNorm(x))``; a final RMSNorm, then an
-untied head.  No biases except the convolution's.  ``params["layers"]`` is a
+Every layer is ``x <- x + mixer(RMSNorm(x))``, so a pre-norm block of
+attention + FFN is two layers here (``"LD"``, ``"LE"``); a final RMSNorm,
+then an untied head.  No biases except the convolution's.  ``params["layers"]`` is a
 list of per-layer dicts (as ``gpt_moe.py`` lists its blocks), each
 ``{"norm": ..., <the mixer's leaves>}``; the kind of layer ``i`` is
 ``cfg.pattern[i]``.
@@ -33,9 +39,13 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..parallel.moe import MoEConfig, moe_serve_forward
+from ..parallel.moe import MoEConfig, _unbiased_act, moe_serve_forward
 from ..parallel.tensor_parallel import TransformerConfig, dense
-from ..parallel.tensor_parallel.layers import rms_norm
+from ..parallel.tensor_parallel.layers import (
+    apply_rope,
+    rms_norm,
+    rope_cache,
+)
 
 PyTree = Any
 F32 = jnp.float32
@@ -46,17 +56,18 @@ _HI = jax.lax.Precision.HIGHEST
 class HybridConfig:
     vocab_size: int
     dim: int
-    #: one character a layer: 'M' Mamba-2 | '*' attention | 'E' latent MoE
+    #: one character a layer: 'M' Mamba-2 | '*' attention | 'E' experts |
+    #: 'L' latent attention | 'D' dense gated MLP
     pattern: str
     max_seq: int
     # attention: nheads x head_dim == dim (the engine's pool derives it so)
     nheads: int
     kv_heads: int
-    # Mamba-2: d_inner = mamba_heads x mamba_head_dim
-    mamba_heads: int
-    mamba_head_dim: int
-    ssm_state: int
-    ssm_groups: int
+    # Mamba-2 ('M'): d_inner = mamba_heads x mamba_head_dim
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
     conv_kernel: int = 4
     #: prefill computes the recurrence in chunks of this many positions
     ssm_chunk: int = 128
@@ -69,6 +80,19 @@ class HybridConfig:
     moe_ffn: int = 0
     moe_shared_ffn: int = 0
     moe_routed_scale: float = 1.0
+    #: the experts' (and the shared expert's) activation: 'relu2' | 'swiglu'
+    moe_act: str = "relu2"
+    # latent attention ('L'): a query head is ``mla_nope + mla_rope`` wide,
+    # a value head ``mla_v``; a position caches ``mla_latent + mla_rope``
+    mla_latent: int = 0
+    mla_nope: int = 0
+    mla_rope: int = 0
+    mla_v: int = 0
+    rope_theta: float = 10000.0
+    #: a rope-scaling dict as ``rope_cache`` takes it (yarn), or None
+    rope_scaling: Optional[Dict[str, Any]] = None
+    #: the dense gated MLP's width ('D')
+    dense_ffn: int = 0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     #: the recurrent state's precision (the convolution's rows keep ``dtype``)
@@ -80,12 +104,24 @@ class HybridConfig:
     moe_dispatch = "auto"
 
     def __post_init__(self):
-        bad = set(self.pattern) - set("M*E")
+        bad = set(self.pattern) - set("M*ELD")
         if bad or not self.pattern:
             raise ValueError(
-                f"pattern {self.pattern!r}: one of 'M', '*', 'E' a layer")
+                f"pattern {self.pattern!r}: one of 'M', '*', 'E', 'L', 'D' "
+                f"a layer")
+        if "*" in self.pattern and "L" in self.pattern:
+            raise ValueError("one kind of block pool a model: '*' or 'L'")
+        if "L" in self.pattern and not (
+                self.mla_latent and self.mla_nope and self.mla_rope
+                and self.mla_v):
+            raise ValueError("an 'L' layer needs the four mla_* widths")
+        if "D" in self.pattern and not self.dense_ffn:
+            raise ValueError("a 'D' layer needs dense_ffn")
         if self.nheads * (self.dim // self.nheads) != self.dim:
             raise ValueError("dim must divide by nheads")
+        if "M" in self.pattern and not (
+                self.mamba_heads and self.mamba_head_dim and self.ssm_state):
+            raise ValueError("an 'M' layer needs the Mamba-2 sizes")
         if self.mamba_heads % self.ssm_groups:
             raise ValueError("mamba_heads must divide by ssm_groups")
         if "E" in self.pattern and not self.moe_experts:
@@ -98,8 +134,28 @@ class HybridConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers that keep keys and values (the block pool's depth)."""
-        return self.pattern.count("*")
+        """Layers that keep keys and values, or the latent they are made
+        from (the block pool's depth)."""
+        return self.pattern.count("*") + self.pattern.count("L")
+
+    @property
+    def latent_width(self) -> int:
+        """What one position caches in an 'L' layer (0: a K/V pool)."""
+        return (self.mla_latent + self.mla_rope) if "L" in self.pattern else 0
+
+    @property
+    def mla_scale(self) -> float:
+        """The softmax scale of latent attention: the query head's width,
+        times yarn's ``mscale`` squared where the rope is stretched (the
+        cos/sin tables themselves stay unscaled when ``mscale ==
+        mscale_all_dim``, which ``rope_cache`` works out)."""
+        scale = (self.mla_nope + self.mla_rope) ** -0.5
+        rs = self.rope_scaling or {}
+        if rs.get("mscale_all_dim") and float(rs.get("factor", 1.0)) > 1.0:
+            m = 0.1 * float(rs["mscale_all_dim"]) * math.log(
+                float(rs["factor"])) + 1.0
+            scale *= m * m
+        return scale
 
     @property
     def state_layers(self) -> int:
@@ -127,7 +183,7 @@ class HybridConfig:
     def moe(self) -> MoEConfig:
         return MoEConfig(
             dim=self.dim, ffn_dim=self.moe_ffn, num_experts=self.moe_experts,
-            top_k=self.moe_top_k, dtype=self.dtype, act="relu2",
+            top_k=self.moe_top_k, dtype=self.dtype, act=self.moe_act,
             score="sigmoid", routed_scale=self.moe_routed_scale,
             latent_dim=self.moe_latent, shared_ffn=self.moe_shared_ffn,
             held=self.moe_held, dispatch=self.moe_dispatch)
@@ -296,6 +352,42 @@ def attention_mixer(p, x, cfg: HybridConfig, ck, cv, offset, cache_ops):
     return dense(out, p["wo"]), ck, cv
 
 
+def latent_attention_mixer(p, x, cfg: HybridConfig, pool, offset, cache_ops):
+    """Latent attention in the absorbed form: x [B, S, D] (normed) -> (y,
+    pool).  A position caches ONE row, ``[RMSNorm(c) | rope(k_rope)]``
+    (``mla_latent + mla_rope`` wide), which is every head's key AND, in its
+    first ``mla_latent`` columns, every head's value: head h's query goes
+    into the latent through ``wuk[h]`` (its key up-projection, transposed),
+    the heads attend to the shared rows, and what comes out of the latent
+    goes through ``wuv[h]`` to the head's value width.  The keys and values
+    of the published form, ``wuk[h] c`` and ``wuv[h] c``, are never made.
+    Each query head is normed (a learned RMSNorm over its whole width)
+    before its rope part is rotated; ``k_rope`` is not normed.
+    ``cache_ops``: ``(write, attend)`` of ``serving/paged_cache.py`` for the
+    latent pool."""
+    B, S, _ = x.shape
+    H, dn, dr, dc = cfg.nheads, cfg.mla_nope, cfg.mla_rope, cfg.mla_latent
+    write, attend = cache_ops
+    pos = offset[:, None] + jnp.arange(S)[None, :]
+    cos, sin = rope_cache(pos.reshape(-1), dr, cfg.rope_theta,
+                          scaling=cfg.rope_scaling)
+    rope = (cos.reshape(B, 1, S, dr // 2), sin.reshape(B, 1, S, dr // 2))
+
+    q = dense(x, p["wq"]).reshape(B, S, H, dn + dr).transpose(0, 2, 1, 3)
+    q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+    q_lat = jnp.einsum("bhsn,hnc->bhsc", q[..., :dn], p["wuk"])
+    q = jnp.concatenate(
+        [q_lat, apply_rope(q[..., dn:], cache=rope)], axis=-1)
+    kva = dense(x, p["wkva"])                              # [B, S, dc + dr]
+    row = jnp.concatenate(
+        [rms_norm(kva[..., :dc], p["kv_norm"], cfg.norm_eps),
+         apply_rope(kva[:, None, :, dc:], cache=rope)[:, 0]], axis=-1)
+    pool = write(pool, row, offset)
+    o_lat = attend(q, pool, offset)                        # [B, H, S, dc]
+    o = jnp.einsum("bhsc,hcv->bshv", o_lat, p["wuv"])
+    return dense(o.reshape(B, S, H * cfg.mla_v), p["wo"]), pool
+
+
 # ------------------------------------------------------------------ forward
 
 
@@ -311,7 +403,8 @@ def hybrid_paged_forward(
     last_idx=None,
 ):
     """``tokens`` [B, S] through the stack.  ``cache``: the block pool of
-    the attention layers (``{'k','v': [kv_layers, ...]}``), reached through
+    the attention layers (``{'k','v': [kv_layers, ...]}``, or ``{'kv':
+    ...}`` where they are latent), reached through
     ``cache_ops(layer)`` (the pool's ``(write, attend)`` pair for one of
     its layers; the pool itself is threaded whole through the attention
     layers); ``state``: :func:`init_state`'s arrays with one row a
@@ -326,7 +419,7 @@ def hybrid_paged_forward(
     S = tokens.shape[1]
     valid = jnp.arange(S)[None, :] < n_valid[:, None]
     h = jnp.take(params["tok_emb"], tokens, axis=0)
-    ck, cv, kv_layer = cache["k"], cache["v"], 0
+    cache, kv_layer = dict(cache), 0
     ssm, conv, mets = [], [], []
     mcfg = cfg.moe if cfg.moe_experts else None
     for kind, lp in zip(cfg.pattern, params["layers"]):
@@ -338,15 +431,21 @@ def hybrid_paged_forward(
             ssm.append(s_m)
             conv.append(c_m)
         elif kind == "*":
-            y, ck, cv = attention_mixer(
-                lp, x, cfg, ck, cv, offset, cache_ops(kv_layer))
+            y, cache["k"], cache["v"] = attention_mixer(
+                lp, x, cfg, cache["k"], cache["v"], offset,
+                cache_ops(kv_layer))
             kv_layer += 1
+        elif kind == "L":
+            y, cache["kv"] = latent_attention_mixer(
+                lp, x, cfg, cache["kv"], offset, cache_ops(kv_layer))
+            kv_layer += 1
+        elif kind == "D":
+            y = dense(_unbiased_act(dense(x, lp["w1"]), "swiglu"), lp["w2"])
         else:
             y, met = moe_serve_forward(
                 lp, x, mcfg, return_metrics=True, valid=valid)
             mets.append(met)
         h = h + y
-    cache = {"k": ck, "v": cv}
     state = {"ssm": tuple(ssm), "conv": tuple(conv)}
     metrics = None
     if mets:
@@ -397,20 +496,37 @@ def init_hybrid_params(key, cfg: HybridConfig) -> Dict[str, PyTree]:
             lp = {"wq": normal(ks[0], (D, D), D),
                   "wkv": normal(ks[1], (2, D, dkv), D),
                   "wo": normal(ks[2], (D, D), D)}
+        elif kind == "L":
+            H, dn, dr, dc, dv = (cfg.nheads, cfg.mla_nope, cfg.mla_rope,
+                                 cfg.mla_latent, cfg.mla_v)
+            lp = {"wq": normal(ks[0], (D, H * (dn + dr)), D),
+                  "q_norm": {"scale": jnp.ones((dn + dr,), dt)},
+                  "wkva": normal(ks[1], (D, dc + dr), D),
+                  "kv_norm": {"scale": jnp.ones((dc,), dt)},
+                  "wuk": normal(ks[2], (H, dn, dc), dc),
+                  "wuv": normal(ks[3], (H, dc, dv), dc),
+                  "wo": normal(ks[4], (H * dv, D), H * dv)}
+        elif kind == "D":
+            lp = {"w1": normal(ks[0], (D, 2 * cfg.dense_ffn), D),
+                  "w2": normal(ks[1], (cfg.dense_ffn, D), cfg.dense_ffn)}
         else:
             m = cfg.moe
             _, held = m.held_range
             lat = m.latent_dim or D
+            # a gated expert's w1 is gate and up side by side
+            wide = 2 if m.act == "swiglu" else 1
             lp = {"router": {"w": normal(ks[0], (D, m.num_experts), D),
                              "bias": jnp.zeros((m.num_experts,), F32)},
-                  "experts": {"w1": normal(ks[1], (held, lat, m.ffn_dim), lat),
+                  "experts": {"w1": normal(
+                      ks[1], (held, lat, wide * m.ffn_dim), lat),
                               "w2": normal(ks[2], (held, m.ffn_dim, lat),
                                            m.ffn_dim)}}
             if m.latent_dim:
                 lp["latent"] = {"down": normal(ks[3], (D, lat), D),
                                 "up": normal(ks[4], (lat, D), lat)}
             if m.shared_ffn:
-                lp["shared"] = {"w1": normal(ks[5], (D, m.shared_ffn), D),
+                lp["shared"] = {"w1": normal(
+                    ks[5], (D, wide * m.shared_ffn), D),
                                 "w2": normal(ks[6], (m.shared_ffn, D),
                                              m.shared_ffn)}
         layers.append({"norm": norm(), **lp})

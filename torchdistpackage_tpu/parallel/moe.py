@@ -90,7 +90,9 @@ class MoEConfig:
     dispatch: str = "auto"
     # Expert FFN activation: 'gelu' | 'swiglu' (stacked [E, 2, D, F]
     # gate/up — the Mixtral-style expert; structural dispatch on w1.ndim,
-    # mirroring the dense MLP's convention in tensor_parallel/layers.py) |
+    # mirroring the dense MLP's convention in tensor_parallel/layers.py;
+    # a 3-dim ``w1`` [E, D, 2F] under 'swiglu' is the gated expert WITHOUT
+    # biases, gate and up side by side in one matmul: serving path only) |
     # 'relu2' (non-gated squared ReLU, no biases: serving path only).
     act: str = "gelu"
     # --- the sigmoid-router / latent-expert family (serving path only,
@@ -546,12 +548,18 @@ def _serve_route(router: Dict[str, jnp.ndarray], tokens: jnp.ndarray,
     return scores, gate_vals * cfg.routed_scale, gate_idx
 
 
-def _relu2(x: jnp.ndarray) -> jnp.ndarray:
-    return jnp.square(jax.nn.relu(x))
+def _unbiased_act(h: jnp.ndarray, act: str) -> jnp.ndarray:
+    """What an expert WITHOUT biases does between its two matmuls: 'relu2'
+    squares the positive part; 'swiglu' takes ``h`` as gate and up side by
+    side (``w1`` [..., D, 2F]) and gives silu(gate) * up."""
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    F = h.shape[-1] // 2
+    return jax.nn.silu(h[..., :F]) * h[..., F:]
 
 
 #: A call of at most this many tokens (a decode call: one a slot) runs the
-#: relu2 experts as ONE batched matmul over every held expert at the exact
+#: unbiased experts as ONE batched matmul over every held expert at the exact
 #: no-drop capacity C = T (a token takes an expert at most once), not as
 #: ``ragged_dot`` groups.  At decode the layer is bound by the experts'
 #: weights, and the batched form reads them once at 89% of the HBM peak
@@ -562,7 +570,7 @@ def _relu2(x: jnp.ndarray) -> jnp.ndarray:
 _BATCHED_EXPERTS_MAX_TOKENS = 128
 
 
-def _batched_relu2_experts(ex, rows, sorted_expert, group_sizes, T: int):
+def _batched_experts(ex, rows, sorted_expert, group_sizes, T: int, act: str):
     """``rows`` [R, d] sorted by expert (``sorted_expert`` [R]; values past
     the last group are no expert's) -> the experts' outputs, row for row
     ([R, d]; zero for a row of no expert).  Every expert gets ``T`` slots;
@@ -573,7 +581,8 @@ def _batched_relu2_experts(ex, rows, sorted_expert, group_sizes, T: int):
     pos = jnp.arange(rows.shape[0]) - starts[jnp.minimum(sorted_expert, n - 1)]
     slot = jnp.where(held, sorted_expert * T + pos, n * T)   # n * T: nowhere
     xe = jnp.zeros((n * T, d), rows.dtype).at[slot].set(rows, mode="drop")
-    h = _relu2(jnp.einsum("ecd,edf->ecf", xe.reshape(n, T, d), ex["w1"]))
+    h = _unbiased_act(
+        jnp.einsum("ecd,edf->ecf", xe.reshape(n, T, d), ex["w1"]), act)
     out = jnp.einsum("ecf,efd->ecd", h, ex["w2"]).reshape(n * T, d)
     return out.at[slot].get(mode="fill", fill_value=0)
 
@@ -615,7 +624,8 @@ def moe_serve_forward(
     ``moe`` load signal.
 
     The sigmoid-router / latent family (``MoEConfig.score`` /
-    ``latent_dim`` / ``shared_ffn`` / ``held`` / ``act='relu2'``) rides the
+    ``latent_dim`` / ``shared_ffn`` / ``held`` / ``act='relu2'`` or the
+    gated ``'swiglu'`` with a 3-dim ``w1`` [E, D, 2F]; no biases) rides the
     same ragged path: the router scores all ``num_experts``, the rows go
     down to the latent before the sort, the ``held`` experts' groups run,
     rows assigned to an expert that is not held sort behind every group
@@ -693,14 +703,15 @@ def moe_serve_forward(
         gu = jax.lax.ragged_dot(rows, w1, group_sizes)
         gu = gu + ex["b1"].reshape(-1, 2 * F)[sorted_expert]
         h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
-    elif cfg.act == "relu2":  # no gate, no biases
-        h = None if T <= _BATCHED_EXPERTS_MAX_TOKENS else _relu2(
-            jax.lax.ragged_dot(rows, ex["w1"], group_sizes))
+    elif cfg.act in ("relu2", "swiglu"):  # no biases; swiglu: [E, D, 2F]
+        h = None if T <= _BATCHED_EXPERTS_MAX_TOKENS else _unbiased_act(
+            jax.lax.ragged_dot(rows, ex["w1"], group_sizes), cfg.act)
     else:
         h = jax.lax.ragged_dot(rows, ex["w1"], group_sizes)
         h = jax.nn.gelu(h + ex["b1"][sorted_expert])
     if h is None:  # a small call: every held expert in one batched matmul
-        out = _batched_relu2_experts(ex, rows, sorted_expert, group_sizes, T)
+        out = _batched_experts(ex, rows, sorted_expert, group_sizes, T,
+                               cfg.act)
     else:
         out = jax.lax.ragged_dot(h, ex["w2"], group_sizes)
     if "b2" in ex:
@@ -719,7 +730,7 @@ def moe_serve_forward(
         y = y @ params["latent"]["up"]
     if cfg.shared_ffn:
         sh = params["shared"]
-        y = y + _relu2(tokens @ sh["w1"]) @ sh["w2"]
+        y = y + _unbiased_act(tokens @ sh["w1"], cfg.act) @ sh["w2"]
     return _with_metrics(y.reshape(B, S, D).astype(x.dtype))
 
 

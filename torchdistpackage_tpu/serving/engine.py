@@ -439,12 +439,14 @@ class ServingEngine:
                 "serving: pass cp_axis= for sequence-sharded (ring paged) "
                 "prefill over the block pool, or decode a CP-trained "
                 "checkpoint with attn_impl='flash', context_axis=None")
-        #: a model some of whose layers keep a recurrent state per sequence
-        #: instead of keys and values (models/hybrid.py); docs/serving.md
-        #: "State models"
-        self.state_model = bool(getattr(cfg, "state_layers", 0))
+        #: the hybrid family (models/hybrid.py), some of whose layers may keep
+        #: a recurrent state per sequence instead of keys and values: its
+        #: step carries that state beside the pool (none at all where the
+        #: pattern has no such layer); docs/serving.md "State models"
+        self.state_model = hasattr(cfg, "state_layers")
         if self.state_model:
-            for on, what in ((prefix_cache, "prefix_cache"),
+            for on, what in ((prefix_cache and cfg.state_layers,
+                              "prefix_cache"),
                              (spec_k, "spec_k"), (cp_axis, "cp_axis"),
                              (mesh, "a mesh (tp/dp/ep)")):
                 if on:
@@ -453,13 +455,14 @@ class ServingEngine:
                         f"recurrent state cannot be shared by prefix, "
                         f"rolled back after a rejected draft or split over "
                         f"devices without per-position SNAPSHOTS of it, "
-                        f"which the engine does not keep yet (ROADMAP "
+                        f"which the engine does not keep yet, and the "
+                        f"family's step has no verify or mesh form (ROADMAP "
                         f"queue 2 A4)")
             if record_routing and not cfg.moe_experts:
                 raise ValueError("record_routing: the model has no "
                                  "expert layers")
             q = cfg.ssm_chunk
-            if chunk > q and chunk % q:
+            if cfg.state_layers and chunk > q and chunk % q:
                 raise ValueError(
                     f"chunk ({chunk}) must be at most the model's "
                     f"recurrence chunk ({q}) or a multiple of it")
@@ -590,8 +593,9 @@ class ServingEngine:
         #: host-only stub — every device touch below goes through it
         self.device_step = device_step
         device_step.bind(self)
-        with span("tdp:engine.init.pool"):
+        with span("tdp:engine.init.pool") as sp:
             self.cache = device_step.init_cache()
+            sp.attrs["bytes"] = pool_bytes(self.cache)
         #: state models: the recurrent state, one row a slot, beside the
         #: pool (``models.hybrid.init_state``); like the pool, the compiled
         #: step is handed it as a donated argument and the engine keeps
@@ -1654,7 +1658,8 @@ class ServingEngine:
             args += ((flight or self._no_flight)["out"][:2]
                      + (ahead.astype(np.int32),),)
         with span("tdp:engine.decode", slots=n_active,
-                  rids=self._tick_decode_rids, **first):
+                  rids=self._tick_decode_rids,
+                  live_tokens=int(offsets.sum()) + n_active, **first):
             out = self._dispatch(self._decode_fn, args)
         self.stats["decode_steps"] += 1
         self.stats["decode_slot_steps"] += n_active

@@ -18,6 +18,12 @@ compiles ONCE):
   ``(q8, scale)`` pairs via the same ``_kv_quant`` per-vector symmetric
   scheme as the contiguous cache (scale ``[L, num_blocks, Hkv,
   block_size]`` f32), halving KV HBM at long context.
+  A latent-attention model (``models/hybrid.py``, kind ``L``) caches one
+  row a position, shared by all heads, and its pool is ONE leaf,
+  ``{'kv': [L, num_blocks, 1, W, block_size]}``: no per-head axis to speak
+  of, no separate V, each block the transpose of its rows
+  (ops/mla_attention.py says why).  Everything below that names blocks
+  (tables, allocator, copy-on-write, migration) is the same for both.
 - **Block tables**: ``[num_slots, max_blocks]`` int32 per-slot rows.  Block
   ``i`` of a slot's table covers its positions ``[i*bs, (i+1)*bs)``, so the
   table IS the page table and position arithmetic is two integer ops.
@@ -81,17 +87,29 @@ def init_paged_kv(
     ``axis_size`` divides the KV heads for TP (build the global array and
     shard dim 2 over the tensor axis, or call inside shard_map).
     ``quantized=True``: int8 ``(q8, scale)`` pairs per entry, the same
-    per-position-vector symmetric scheme as the contiguous cache."""
+    per-position-vector symmetric scheme as the contiguous cache.
+
+    A model whose attention is latent (``cfg.latent_width``) gets the
+    one-leaf pool ``{'kv': [L, num_blocks, 1, latent_width, block_size]}``
+    instead: nothing to divide over a tensor axis, no int8 form yet."""
+    if num_blocks < 2:
+        raise ValueError(
+            f"num_blocks must be >= 2 (block 0 is the reserved NULL block), "
+            f"got {num_blocks}")
+    width = _latent_width(cfg)
+    if width:
+        if quantized or axis_size != 1:
+            raise NotImplementedError(
+                "a latent pool has no int8 form and no tensor-parallel "
+                "split yet (ROADMAP queue 2)")
+        return {"kv": jnp.zeros(
+            (_kv_layers(cfg), num_blocks, 1, width, block_size), cfg.dtype)}
     hkv, rem = divmod(cfg.block.kv_head_count, axis_size)
     if rem or hkv == 0:
         raise ValueError(
             f"kv_heads {cfg.block.kv_head_count} not divisible by tp "
             f"{axis_size} (whole KV heads per shard)"
         )
-    if num_blocks < 2:
-        raise ValueError(
-            f"num_blocks must be >= 2 (block 0 is the reserved NULL block), "
-            f"got {num_blocks}")
     shape = (_kv_layers(cfg), num_blocks, hkv, block_size, cfg.block.head_dim)
     if quantized:
         def entry():
@@ -108,10 +126,24 @@ def _kv_layers(cfg) -> int:
     return getattr(cfg, "kv_layers", cfg.nlayers)
 
 
+def _latent_width(cfg) -> int:
+    """What one position caches where attention is latent; 0 = keys and
+    values a head."""
+    return getattr(cfg, "latent_width", 0)
+
+
 def block_size_of(cache: Dict[str, Any]) -> int:
-    """The pool's block size, tuple-safe (quantized pools store pairs)."""
+    """The pool's block size, tuple-safe (quantized pools store pairs); a
+    latent pool's blocks lie transposed, positions last."""
+    if "kv" in cache:
+        return cache["kv"].shape[4]
     k = cache["k"]
     return (k[0] if isinstance(k, tuple) else k).shape[3]
+
+
+def is_quantized(cache: Dict[str, Any]) -> bool:
+    """Whether the pool's leaves are int8 ``(q8, scale)`` pairs."""
+    return any(isinstance(leaf, tuple) for leaf in cache.values())
 
 
 def pool_bytes(cache: Dict[str, Any]) -> int:
@@ -134,7 +166,13 @@ def expected_pool_bytes(
     """What :func:`init_paged_kv` SHOULD allocate, from shape math alone:
     ``2 * L * num_blocks * Hkv/axis_size * block_size * hd`` entries in
     ``cfg.dtype`` (int8 + f32 per-vector scale when ``quantized``).  The
-    independent half of the pool-accounting cross-check."""
+    independent half of the pool-accounting cross-check.  A latent pool
+    is ONE leaf of ``L * num_blocks * block_size * latent_width``."""
+    if _latent_width(cfg):
+        import jax.numpy as jnp
+
+        return (_kv_layers(cfg) * num_blocks * block_size
+                * _latent_width(cfg) * jnp.dtype(cfg.dtype).itemsize)
     hkv = cfg.block.kv_head_count // axis_size
     entries = _kv_layers(cfg) * num_blocks * hkv * block_size
     hd = cfg.block.head_dim
@@ -274,6 +312,44 @@ def _paged_cache_ops(tables: jnp.ndarray, attn_impl: str, layer):
         return paged_attention(q, ck, cv, offset, tables=tables,
                                window=window, impl=attn_impl, layer=layer)
     return functools.partial(paged_write, tables=tables, layer=layer), attend
+
+
+def latent_write(pool: jnp.ndarray, rows: jnp.ndarray, offset, *,
+                 tables: jnp.ndarray, layer) -> jnp.ndarray:
+    """:func:`paged_write` for a latent pool ``[L, nb, 1, W, bs]``: ``rows``
+    [B, S_in, W] go to positions ``offset[b] + arange(S_in)`` of layer
+    ``layer``, and the WHOLE pool comes back.  The same write by whole
+    blocks (:func:`_write_blocks`), the block laid transposed: a block is
+    read at ``[layer, blk]``, the call's rows become its columns, and it is
+    scattered back whole, contiguous in the pool as it lies."""
+    B, S_in, W = rows.shape
+    bs = pool.shape[4]
+    blk, src, valid = _write_blocks(
+        tables, jnp.asarray(offset, jnp.int32), S_in, bs)
+    n = blk.shape[1]
+    new = jnp.take_along_axis(
+        rows, src.reshape(B, n * bs, 1), axis=1).reshape(B, n, bs, W)
+    new = jnp.where(valid[:, :, None, :],
+                    new.swapaxes(2, 3).astype(pool.dtype),
+                    pool[layer, blk, 0])                  # [B, n, W, bs]
+    return pool.at[layer, blk.reshape(-1), 0].set(new.reshape(B * n, W, bs))
+
+
+def _latent_cache_ops(tables: jnp.ndarray, attn_impl: str, cfg, layer):
+    """:func:`_paged_cache_ops` for a latent layer: ``write(pool, rows
+    [B, S_in, W], offset)`` and ``attend(q [B, H, S_in, W], pool, offset)
+    -> [B, H, S_in, latent]`` (ops/mla_attention.py: the kernel, or its
+    gathered oracle)."""
+    from ..ops import mla_attention as M
+
+    attend = (M.mla_paged_attention if attn_impl == "pallas"
+              else M.mla_gather_attention)
+
+    def attend_layer(q, pool, offset):
+        return attend(q, pool, tables, offset, latent=cfg.mla_latent,
+                      sm_scale=cfg.mla_scale, layer=layer)
+    return functools.partial(latent_write, tables=tables,
+                             layer=layer), attend_layer
 
 
 def _batched_rope(bcfg, positions: jnp.ndarray):
@@ -588,7 +664,9 @@ def paged_forward_hybrid(
     from ..models.hybrid import hybrid_paged_forward
 
     offset = jnp.asarray(offset, jnp.int32)
-    ops = functools.partial(_paged_cache_ops, tables, attn_impl)
+    ops = (functools.partial(_latent_cache_ops, tables, attn_impl, cfg)
+           if _latent_width(cfg)
+           else functools.partial(_paged_cache_ops, tables, attn_impl))
     mine = state
     if rows is not None:
         def own(a):
@@ -649,7 +727,7 @@ def migrate_blocks(
     migration either way)."""
     # a quantized pool's leaves are (q8, scale) pairs — already the wire
     # format; its f32 scale sideband must never be re-quantized
-    compress = compress and not isinstance(dst_cache["k"], tuple)
+    compress = compress and not is_quantized(dst_cache)
 
     def cp(s_leaf, d_leaf):
         payload = s_leaf[:, src_ids]

@@ -60,6 +60,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..obs.events import default_event_log
+from .paged_cache import is_quantized
 from ..resilience.ckpt_guard import with_retries
 
 
@@ -223,7 +224,7 @@ class ChunkedWireTransport(MigrationTransport):
         self._seq += 1
         self.stats["sends"] += 1
         # int8 stub / kv_quant tuple pools are already at wire precision
-        compress = bool(compress) and not isinstance(src_cache["k"], tuple)
+        compress = bool(compress) and not is_quantized(src_cache)
         return {"src_cache": src_cache, "src": src, "dst": dst,
                 "compress": compress, "seq": seq, "rid": desc.get("orig_rid"),
                 "staged": {}, "manifest": {}}
