@@ -335,8 +335,11 @@ def test_a_preempted_request_restarts_from_a_zero_state(toy, run_ahead):
 
 def _serve(toy, run_ahead, temperature=0.0, eos=None, cancel_at=None):
     """The ``served`` fixture's seven requests on three slots, sampled at
-    ``temperature`` from a seed each; ``eos``: {request index: eos_id}."""
+    ``temperature`` (one for all, or {request index: its own}, the others
+    greedy) from a seed each; ``eos``: {request index: eos_id}."""
     _, cfg, params = toy
+    temps = temperature if isinstance(temperature, dict) else {
+        i: temperature for i in range(7)}
     rng = np.random.RandomState(0)
     with jax.default_matmul_precision("highest"):
         eng = ServingEngine(params, cfg, num_slots=3, block_size=8, chunk=8,
@@ -345,7 +348,7 @@ def _serve(toy, run_ahead, temperature=0.0, eos=None, cancel_at=None):
         for i, n in enumerate((8, 13, 16, 5, 21, 24, 9)):
             eng.submit(Request(tokens=rng.randint(0, 211, n).tolist(),
                                max_new_tokens=4 + 3 * (i % 3),
-                               temperature=temperature, seed=100 + i,
+                               temperature=temps.get(i, 0.0), seed=100 + i,
                                eos_id=(eos or {}).get(i)))
         if cancel_at is not None:
             for _ in range(cancel_at):
@@ -385,6 +388,39 @@ def test_run_ahead_serves_the_unpipelined_engines_tokens(toy, temperature):
         assert got._flight is None and got.n_busy == 0
     # fewer host waits: the calls are the same, a retirement shows a tick late
     assert got.stats["decode_steps"] <= want.stats["decode_steps"] + 7
+
+
+@pytest.mark.parametrize("run_ahead", [False, True],
+                         ids=["in_step", "run_ahead"])
+def test_one_sampling_request_among_greedy_ones(toy, run_ahead):
+    """The state step's sampler chooses by its rows as the dense step's
+    does: with request 1 alone sampling, every greedy request ends with the
+    all-greedy run's tokens and request 1 with those it draws when every
+    request samples (the same seed, neighbours that asked for something
+    else), and ``sampled_rows`` on the spans reads 0 on the calls that took
+    the greedy branch.  ``run_ahead`` carries the keys on the device."""
+    from torchdistpackage_tpu.utils.profiling import spans
+
+    def serve(temps):
+        before = len(spans)  # not cleared: the ring is `served`'s too
+        eng = _serve(toy, run_ahead, temps)
+        counts = [r[5]["sampled_rows"] for r in spans.snapshot()[before:]
+                  if r[2] in ("tdp:engine.prefill", "tdp:engine.decode")]
+        return eng.finished, counts
+
+    greedy, counts = serve(0.0)
+    assert set(counts) == {0}
+    every, counts = serve(0.9)
+    assert 0 not in counts and max(counts) == 3
+    mixed, counts = serve({1: 0.9})
+    assert set(counts) == {0, 1}  # both branches ran in this engine
+    for rid in range(7):
+        want = every if rid == 1 else greedy
+        np.testing.assert_array_equal(mixed[rid]["tokens"],
+                                      want[rid]["tokens"])
+        np.testing.assert_array_equal(mixed[rid]["routing"],
+                                      want[rid]["routing"])
+    assert not np.array_equal(every[1]["tokens"], greedy[1]["tokens"])
 
 
 def test_run_ahead_drops_the_token_in_flight_of_a_cancelled_request(toy):

@@ -27,6 +27,7 @@ from torchdistpackage_tpu.models import (
     llama_config,
 )
 from torchdistpackage_tpu.obs.events import EventLog, set_default_event_log
+from torchdistpackage_tpu.serving.engine import _filtered_logits, _slot_sample
 from torchdistpackage_tpu.serving import (
     BlockAllocator,
     NULL_BLOCK,
@@ -349,6 +350,155 @@ def test_per_slot_sampling_isolated_and_reproducible(bundles):
     np.testing.assert_array_equal(sampled_a, sampled_b)  # seed replays
     assert not np.array_equal(sampled_a, sampled_c)  # seed matters
     assert np.all(sampled_a[PROMPT:] < b["cfg"].vocab_size)
+
+
+# ----------------------------- the sampler's cost follows what its rows ask
+
+
+def _straight_sample(logits, keys, temperature, top_k, top_p):
+    """`_slot_sample` as it was before its ``cond``: every row through the
+    filter chain and the draw, the greedy rows' results thrown away."""
+    x = logits.astype(jnp.float32)
+    greedy = jnp.argmax(x, axis=-1).astype(jnp.int32)
+    xs = _filtered_logits(x, temperature, top_k, top_p)
+    sampled = jax.vmap(jax.random.categorical)(keys, xs).astype(jnp.int32)
+    return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+def _sampler_inputs(temps):
+    n, V = len(temps), 64
+    logits = (4.0 * jax.random.normal(jax.random.PRNGKey(3), (n, V))).astype(
+        jnp.bfloat16)
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(n))
+    return (logits, keys, jnp.asarray(temps, jnp.float32),
+            jnp.asarray([V, 16, V, 5][:n], jnp.int32),
+            jnp.asarray([1.0, 0.9, 0.5, 1.0][:n], jnp.float32))
+
+
+@pytest.mark.parametrize("temps", [(0.0, 0.0, 0.0, 0.0), (0.0, 0.8, 0.0, 0.0),
+                                   (0.7, 0.0, 1.3, 0.0), (0.9, 0.9, 0.9, 0.9)],
+                         ids=["all_greedy", "one_sampling", "half", "all"])
+def test_slot_sample_returns_the_straight_line_samplers_tokens(temps):
+    """Whatever the mix of rows: the tokens of the body that filtered and
+    drew for every row.  A greedy row is the f32 argmax, always."""
+    args = _sampler_inputs(temps)
+    got = np.asarray(_slot_sample(*args))
+    np.testing.assert_array_equal(got, np.asarray(_straight_sample(*args)))
+    greedy = np.argmax(np.asarray(args[0].astype(jnp.float32)), axis=-1)
+    rows = np.asarray(temps) <= 0.0
+    np.testing.assert_array_equal(got[rows], greedy[rows])
+    assert got.dtype == np.int32
+
+
+def _prims(jaxpr, skip=()):
+    """Every primitive of ``jaxpr`` and of what its equations carry
+    (pjit bodies, branches), bar the equations named in ``skip``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in skip:
+            continue
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _prims(sub, skip)
+
+
+def test_slot_sample_sorts_only_inside_its_cond():
+    """The sort (and the draw) are one branch of the function's one
+    ``cond``; the other branch and everything outside it hold neither, so
+    an all-greedy call executes no sort."""
+    jaxpr = jax.make_jaxpr(_slot_sample)(*_sampler_inputs((0.0,) * 4)).jaxpr
+    outside = list(_prims(jaxpr, skip=("cond",)))
+    assert "argmax" in outside and "sort" not in outside
+    assert not any("random" in p or "threefry" in p for p in outside)
+    (cond,) = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    greedy, draw = (list(_prims(b.jaxpr)) for b in cond.params["branches"])
+    assert "sort" in draw and "sort" not in greedy  # index 0: the false branch
+    assert not greedy, greedy  # hands the argmax through, computes nothing
+    # the [B, V] operand of the cond is the logits as the model left them
+    # (bf16 here), not an f32 copy: see the comment in `_slot_sample`
+    wide = [v.aval.dtype for v in cond.invars if v.aval.shape == (4, 64)]
+    assert wide == [jnp.bfloat16], wide
+
+
+def _sampled_rows(kind):
+    return [r[5]["sampled_rows"] for r in spans.snapshot()
+            if r[2] == "tdp:engine." + kind]
+
+
+SAMPLING = dict(temperature=1.0, top_k=16, top_p=0.9)
+
+
+def test_one_sampling_row_among_greedy_rows(bundles):
+    """A batch with ONE sampling row: its tokens are those it draws when
+    served alone from the same seed, its greedy neighbour's are the
+    all-greedy run's, and ``sampled_rows`` on the spans says which calls
+    took the sampler's drawing branch: 0 on the all-greedy run's."""
+    b = bundles("dense")
+    eng = b["eng"]
+
+    def serve(*reqs):
+        eng.reset_metrics()
+        spans.clear()
+        rids = [eng.submit(r) for r in reqs]
+        _drain(eng)
+        counts = _sampled_rows("prefill"), _sampled_rows("decode")
+        return [eng.finished[r]["tokens"] for r in rids], counts
+
+    p0, p1 = (p.tolist() for p in b["prompts"])
+    (g, s), (pre, dec) = serve(Request(p0, NEW + 3),
+                               Request(p1, NEW, seed=7, **SAMPLING))
+    # the greedy request outlives the sampling one: the last calls are
+    # all-greedy again
+    assert pre == [1, 1] and dec[:NEW - 1] == [1] * (NEW - 1)
+    assert dec[NEW - 1:] == [0] * 3
+    (alone,), (pre, dec) = serve(Request(p1, NEW, seed=7, **SAMPLING))
+    assert pre == [1, 1] and dec == [1] * (NEW - 1)
+    np.testing.assert_array_equal(s, alone)
+    (g0, g1), (pre, dec) = serve(Request(p0, NEW + 3), Request(p1, NEW))
+    assert pre == [0, 0] and set(dec) == {0} and len(dec) == NEW + 2
+    np.testing.assert_array_equal(g, g0)
+    np.testing.assert_array_equal(g0[:PROMPT + NEW], b["want"][0])
+    np.testing.assert_array_equal(g1, b["want"][1])
+    assert not np.array_equal(s, g1)  # the draw was a draw
+
+
+def test_a_late_samplers_draws_do_not_depend_on_the_ticks_before(bundles):
+    """The key stream: a request that starts sampling after N all-greedy
+    ticks draws what it draws when its neighbour sampled throughout, and
+    what it draws alone.  And a greedy slot's key advances on every call
+    it is in, though no call of its run drew from it."""
+    b = bundles("dense")
+    eng = b["eng"]
+    p0, p1 = (p.tolist() for p in b["prompts"])
+    N = 4
+
+    def late(**neighbour):
+        eng.reset_metrics()
+        spans.clear()
+        eng.submit(Request(p0, 20, seed=3, **neighbour))
+        for _ in range(N):
+            eng.step()
+        before = _sampled_rows("prefill") + _sampled_rows("decode")
+        neighbour_at_n = eng._keys[0].copy(), len(eng._slots[0].generated)
+        rid = eng.submit(Request(p1, NEW, seed=7, **SAMPLING))
+        _drain(eng)
+        return eng.finished[rid]["tokens"], before, neighbour_at_n
+
+    after_greedy, before, (key, emitted) = late()
+    assert before == [0] * len(before) and len(before) >= N
+    # one split for every token the slot emitted (the last prefill slice's,
+    # then a decode call's each), all of them on the greedy branch
+    want = jax.random.PRNGKey(3)
+    for _ in range(emitted):
+        want = jax.random.split(want, 2)[0]
+    assert emitted >= N - 1
+    np.testing.assert_array_equal(key, np.asarray(want))
+    after_sampling, before, _ = late(**SAMPLING)
+    assert before == [1] * len(before)
+    np.testing.assert_array_equal(after_greedy, after_sampling)
+    eng.reset_metrics()
+    rid = eng.submit(Request(p1, NEW, seed=7, **SAMPLING))
+    _drain(eng)
+    np.testing.assert_array_equal(after_greedy, eng.finished[rid]["tokens"])
 
 
 def test_submit_guards(bundles):

@@ -273,12 +273,32 @@ def _slot_sample(
     ITS OWN temperature -> top-k -> top-p filter chain
     (:func:`_filtered_logits`) and draws from its own key;
     ``temperature <= 0`` rows take the plain f32 argmax — bitwise the
-    ``generate()`` greedy choice."""
-    x = logits.astype(jnp.float32)
-    greedy = jnp.argmax(x, axis=-1).astype(jnp.int32)
-    xs = _filtered_logits(x, temperature, top_k, top_p)
-    sampled = jax.vmap(jax.random.categorical)(keys, xs).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+    ``generate()`` greedy choice.
+
+    What a call costs follows what its rows ask for.  The argmax is always
+    computed; the filter chain (one descending sort of all ``[B, V]``
+    logits: 9 ms of a 30 ms decode tick at ``[128, 65536]`` on a v5e) and
+    the draw sit behind one ``lax.cond`` on ``any(temperature > 0)``, so a
+    call whose rows are all greedy runs neither.  ONE sampling row brings
+    the whole ``[B, V]`` chain back for that call: a ``cond`` chooses for
+    the call, not for the row.  The tokens are the same either way, and
+    the keys are split by the caller on every call, outside the ``cond``,
+    so a row's draws do not depend on what its neighbours asked for."""
+    greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+
+    def draw() -> jnp.ndarray:
+        # widened HERE, from the logits in the model's dtype: handed an f32
+        # copy made outside, XLA's TPU compiler fuses the widening into the
+        # head's GEMM, the bf16 rounding goes, and the ARGMAX above breaks
+        # its near-ties differently (59 of 61 requests of sarvam105b.reason
+        # ended on other tokens: my chip run, PR 32)
+        xs = _filtered_logits(logits.astype(jnp.float32), temperature,
+                              top_k, top_p)
+        sampled = jax.vmap(jax.random.categorical)(keys, xs)
+        return jnp.where(temperature <= 0.0, greedy,
+                         sampled.astype(jnp.int32))
+
+    return jax.lax.cond(jnp.any(temperature > 0.0), draw, lambda: greedy)
 
 
 class _SlotState:
@@ -1517,10 +1537,13 @@ class ServingEngine:
         outs = []
         # tokens: the real prompt tokens of this tick's slices; rows: what
         # the compiled calls compute, padding included; state_slots (a
-        # state model): the slots whose state the calls gather and scatter
+        # state model): the slots whose state the calls gather and scatter;
+        # sampled_rows: the slots that asked for temperature > 0 (0: every
+        # call took _slot_sample's greedy branch, no sort and no draw)
         state_attr = {"state_slots": len(pre)} if self.state_model else {}
         with span("tdp:engine.prefill", tokens=real, calls=len(batches),
                   rows=sum(args[0].size for _, args in batches),
+                  sampled_rows=np.count_nonzero(self._temps[pre] > 0),
                   rids=rids, **state_attr, **first):
             for _, args in batches:
                 if outs:
@@ -1659,7 +1682,8 @@ class ServingEngine:
                      + (ahead.astype(np.int32),),)
         with span("tdp:engine.decode", slots=n_active,
                   rids=self._tick_decode_rids,
-                  live_tokens=int(offsets.sum()) + n_active, **first):
+                  live_tokens=int(offsets.sum()) + n_active,
+                  sampled_rows=np.count_nonzero(self._temps > 0), **first):
             out = self._dispatch(self._decode_fn, args)
         self.stats["decode_steps"] += 1
         self.stats["decode_slot_steps"] += n_active
