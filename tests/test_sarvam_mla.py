@@ -276,7 +276,7 @@ def test_engine_state_is_empty_and_the_spans_carry_the_new_attrs(toy, served):
     summ = served.serving_summary()
     assert summ["prefill_signatures"] == summ["decode_signatures"] == 1
     assert served.state_model and served.state_bytes == 0
-    assert served.state == {"ssm": (), "conv": ()}
+    assert served.state == {"ssm": (), "conv": (), "tail": ()}
     assert served.cache["kv"].shape == (3, served.num_blocks, 1, 40, 8)
     kv = summ["memory"]["kv_pool"] if "memory" in summ else None
     st = served.stats

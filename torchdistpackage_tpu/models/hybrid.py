@@ -14,20 +14,33 @@ layer is ONE mixer and not attention + MLP (the ``nemotron_h`` shape):
   keys and values are up-projections of ONE cached row a position, the
   normed latent and a rotated key shared by all heads, and attention runs
   in that latent (the absorbed form), over a block pool of its own shape;
-- ``D``  a dense gated MLP (SwiGLU, gate and up side by side).
+- ``D``  a dense gated MLP (SwiGLU, gate and up side by side);
+- ``C``  compressed convolutional attention (:func:`cca_mixer`): queries
+  and keys are projected DOWN into ``nheads + kv_heads`` heads of
+  ``head_dim``, mixed by two short causal convolutions over the sequence
+  before they are normed, rotated (the leading ``cca_rope`` dims of a head)
+  and cached; half the value heads are the position BEFORE's.  The first
+  layer that keeps both: keys and values in the block pool (the ``*``
+  layers' pool and kernels), and a tail a sequence (the rows the next
+  position's convolutions and shifted value need).
 
 Every layer is ``x <- x + mixer(RMSNorm(x))``, so a pre-norm block of
-attention + FFN is two layers here (``"LD"``, ``"LE"``); a final RMSNorm,
-then an untied head.  No biases except the convolution's.  ``params["layers"]`` is a
-list of per-layer dicts (as ``gpt_moe.py`` lists its blocks), each
-``{"norm": ..., <the mixer's leaves>}``; the kind of layer ``i`` is
-``cfg.pattern[i]``.
+attention + FFN is two layers here (``"LD"``, ``"LE"``, ``"CE"``); a layer
+with a ``res`` leaf mixes by four learned vectors instead, ``x <- a_h x +
+b_h + a_y y + b_y``.  A final RMSNorm, then the head: ``params["head"]``
+[D, V], or the embedding table itself where the tree has no such leaf (a
+tied head).  The biases are the convolutions', the network router's
+(``moe_score='mlp'``) and the ``res`` leaves'; no projection has one.
+``params["layers"]`` is a list of per-layer dicts (as ``gpt_moe.py`` lists
+its blocks), each ``{"norm": ..., <the mixer's leaves>}``; the kind of
+layer ``i`` is ``cfg.pattern[i]``.
 
 This is the SERVING path (:func:`hybrid_paged_forward`, driven by
-``ServingEngine``): the engine keeps the recurrent state beside its paged
-KV pool, one array a Mamba layer with one row a slot.  Training this family
-(a chunked scan with a backward, the router's auxiliary loss) is ROADMAP
-queue 2 A1.
+``ServingEngine``): the engine keeps what a layer carries from position to
+position beside its paged KV pool (:func:`init_state`), one array a layer
+that has any with one row a slot.  Training this family (a chunked scan
+with a backward, the router's auxiliary loss, a backward through the
+convolved keys) is ROADMAP queue 2 A1.
 """
 
 from __future__ import annotations
@@ -57,12 +70,14 @@ class HybridConfig:
     vocab_size: int
     dim: int
     #: one character a layer: 'M' Mamba-2 | '*' attention | 'E' experts |
-    #: 'L' latent attention | 'D' dense gated MLP
+    #: 'L' latent attention | 'D' dense gated MLP | 'C' convolved attention
     pattern: str
     max_seq: int
-    # attention: nheads x head_dim == dim (the engine's pool derives it so)
     nheads: int
     kv_heads: int
+    #: a head's width ('*', 'C'), which the pool takes from :attr:`block`;
+    #: 0: the heads tile the model's width, ``dim / nheads``
+    head_dim: int = 0
     # Mamba-2 ('M'): d_inner = mamba_heads x mamba_head_dim
     mamba_heads: int = 0
     mamba_head_dim: int = 0
@@ -82,6 +97,16 @@ class HybridConfig:
     moe_routed_scale: float = 1.0
     #: the experts' (and the shared expert's) activation: 'relu2' | 'swiglu'
     moe_act: str = "relu2"
+    #: the router: 'sigmoid' | 'mlp' (a network with a stream of its own
+    #: from expert layer to expert layer, ``parallel.moe._mlp_route``,
+    #: ``moe_router_hidden`` wide)
+    moe_score: str = "sigmoid"
+    moe_router_hidden: int = 0
+    # convolved attention ('C'): the two convolutions' kernel sizes over
+    # the sequence, and how many leading dims of a head rope turns
+    cca_time0: int = 2
+    cca_time1: int = 2
+    cca_rope: int = 0
     # latent attention ('L'): a query head is ``mla_nope + mla_rope`` wide,
     # a value head ``mla_v``; a position caches ``mla_latent + mla_rope``
     mla_latent: int = 0
@@ -104,21 +129,32 @@ class HybridConfig:
     moe_dispatch = "auto"
 
     def __post_init__(self):
-        bad = set(self.pattern) - set("M*ELD")
+        bad = set(self.pattern) - set("M*ELDC")
         if bad or not self.pattern:
             raise ValueError(
-                f"pattern {self.pattern!r}: one of 'M', '*', 'E', 'L', 'D' "
-                f"a layer")
-        if "*" in self.pattern and "L" in self.pattern:
-            raise ValueError("one kind of block pool a model: '*' or 'L'")
+                f"pattern {self.pattern!r}: one of 'M', '*', 'E', 'L', 'D', "
+                f"'C' a layer")
+        if "L" in self.pattern and set("*C") & set(self.pattern):
+            raise ValueError(
+                "one kind of block pool a model: '*' / 'C' or 'L'")
+        if not self.head_dim:
+            if self.dim % self.nheads:
+                raise ValueError("dim does not divide by nheads: say head_dim")
+            object.__setattr__(self, "head_dim", self.dim // self.nheads)
+        if "C" in self.pattern and (
+                self.kv_heads % 2 or self.nheads % self.kv_heads
+                or min(self.cca_time0, self.cca_time1) < 1
+                or self.cca_rope % 2 or self.cca_rope > self.head_dim):
+            raise ValueError(
+                "a 'C' layer needs an even number of KV heads (half of "
+                "them shifted) that divides nheads, kernels of at least "
+                "1, and an even cca_rope within a head")
         if "L" in self.pattern and not (
                 self.mla_latent and self.mla_nope and self.mla_rope
                 and self.mla_v):
             raise ValueError("an 'L' layer needs the four mla_* widths")
         if "D" in self.pattern and not self.dense_ffn:
             raise ValueError("a 'D' layer needs dense_ffn")
-        if self.nheads * (self.dim // self.nheads) != self.dim:
-            raise ValueError("dim must divide by nheads")
         if "M" in self.pattern and not (
                 self.mamba_heads and self.mamba_head_dim and self.ssm_state):
             raise ValueError("an 'M' layer needs the Mamba-2 sizes")
@@ -126,6 +162,8 @@ class HybridConfig:
             raise ValueError("mamba_heads must divide by ssm_groups")
         if "E" in self.pattern and not self.moe_experts:
             raise ValueError("an 'E' layer needs moe_experts")
+        if self.moe_score == "mlp" and not self.moe_router_hidden:
+            raise ValueError("the 'mlp' router needs moe_router_hidden")
 
     # ---- layer counts: the engine sizes its pool and its state by these
     @property
@@ -136,7 +174,7 @@ class HybridConfig:
     def kv_layers(self) -> int:
         """Layers that keep keys and values, or the latent they are made
         from (the block pool's depth)."""
-        return self.pattern.count("*") + self.pattern.count("L")
+        return sum(self.pattern.count(k) for k in "*LC")
 
     @property
     def latent_width(self) -> int:
@@ -158,9 +196,30 @@ class HybridConfig:
         return scale
 
     @property
-    def state_layers(self) -> int:
+    def ssm_layers(self) -> int:
         """Layers that keep a recurrent state instead."""
         return self.pattern.count("M")
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that carry something from one position to the next
+        beside the pool (:meth:`state_shapes`): a request's cached blocks
+        alone do not say where such a layer stands."""
+        return self.pattern.count("M") + self.pattern.count("C")
+
+    @property
+    def cca_channels(self) -> int:
+        """The convolutions' channels: every query and key head."""
+        return (self.nheads + self.kv_heads) * self.head_dim
+
+    @property
+    def cca_tail(self) -> int:
+        """What a 'C' layer keeps of a sequence: the ``time0 + time1 - 2``
+        rows of ``[q~ ; k~]`` before the next position (as projected, NOT
+        convolved: zero rows are then the start of a sequence in every
+        layer) and the last position's shifted value heads."""
+        return ((self.cca_time0 + self.cca_time1 - 2) * self.cca_channels
+                + self.kv_heads // 2 * self.head_dim)
 
     @property
     def d_inner(self) -> int:
@@ -173,46 +232,54 @@ class HybridConfig:
     @property
     def block(self) -> TransformerConfig:
         """The attention layers' shape, as the pool and the paged ops read
-        it (head counts and head size; nothing positional)."""
+        it (head counts and head size; nothing positional: a 'C' layer
+        rotates its own keys before the write).  Its ``dim`` is the
+        attention's width, ``nheads x head_dim``, not the model's."""
         return TransformerConfig(
-            dim=self.dim, nheads=self.nheads, nlayers=max(self.kv_layers, 1),
-            kv_heads=self.kv_heads, dtype=self.dtype, norm="rms",
-            norm_eps=self.norm_eps, rope=False)
+            dim=self.nheads * self.head_dim, nheads=self.nheads,
+            nlayers=max(self.kv_layers, 1), kv_heads=self.kv_heads,
+            dtype=self.dtype, norm="rms", norm_eps=self.norm_eps, rope=False)
 
     @property
     def moe(self) -> MoEConfig:
         return MoEConfig(
             dim=self.dim, ffn_dim=self.moe_ffn, num_experts=self.moe_experts,
             top_k=self.moe_top_k, dtype=self.dtype, act=self.moe_act,
-            score="sigmoid", routed_scale=self.moe_routed_scale,
+            score=self.moe_score, routed_scale=self.moe_routed_scale,
             latent_dim=self.moe_latent, shared_ffn=self.moe_shared_ffn,
-            held=self.moe_held, dispatch=self.moe_dispatch)
+            held=self.moe_held, dispatch=self.moe_dispatch,
+            norm_eps=self.norm_eps)
 
-    def state_shapes(self, rows: int) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
-        """One Mamba layer's state for ``rows`` sequences: name ->
-        (shape, dtype)."""
+    def state_shapes(
+        self, rows: int,
+    ) -> Dict[str, Tuple[Tuple[int, ...], Any, int]]:
+        """What the layers keep of ``rows`` sequences beside the pool: name
+        -> (one layer's shape, dtype, how many layers keep one).  A Mamba
+        layer: its recurrent state and its convolution's rows; a 'C' layer:
+        its tail (:attr:`cca_tail`), flat, so that a row is whole lanes."""
+        m, c = self.pattern.count("M"), self.pattern.count("C")
         return {
             "ssm": ((rows, self.mamba_heads, self.mamba_head_dim,
-                     self.ssm_state), self.state_dtype),
+                     self.ssm_state), self.state_dtype, m),
             "conv": ((rows, self.conv_kernel - 1, self.conv_channels),
-                     self.dtype),
+                     self.dtype, m),
+            "tail": ((rows, self.cca_tail), self.dtype, c),
         }
 
     def state_bytes(self, rows: int) -> int:
-        per = sum(math.prod(shape) * jnp.dtype(dt).itemsize
-                  for shape, dt in self.state_shapes(rows).values())
-        return per * self.state_layers
+        return sum(math.prod(shape) * jnp.dtype(dt).itemsize * layers
+                   for shape, dt, layers in self.state_shapes(rows).values())
 
 
 def init_state(cfg: HybridConfig, rows: int) -> Dict[str, Tuple[jnp.ndarray, ...]]:
-    """Zeroed recurrent state for ``rows`` sequences: ``{'ssm': (one
-    [rows, H, P, N] array a Mamba layer), 'conv': (one [rows, K-1, C] a
-    layer)}``.  One array a layer and not a stacked ``[L, ...]`` one: a
-    step that is handed them as donated buffers then updates each in place
-    (a stacked array would be rebuilt by a concatenate, and held twice)."""
-    return {name: tuple(jnp.zeros(shape, dt)
-                        for _ in range(cfg.state_layers))
-            for name, (shape, dt) in cfg.state_shapes(rows).items()}
+    """Zeroed state for ``rows`` sequences: ``{'ssm': (one [rows, H, P, N]
+    array a Mamba layer), 'conv': (one [rows, K-1, C] a Mamba layer),
+    'tail': (one [rows, cca_tail] a 'C' layer)}``.  One array a layer and
+    not a stacked ``[L, ...]`` one: a step that is handed them as donated
+    buffers then updates each in place (a stacked array would be rebuilt by
+    a concatenate, and held twice)."""
+    return {name: tuple(jnp.zeros(shape, dt) for _ in range(layers))
+            for name, (shape, dt, layers) in cfg.state_shapes(rows).items()}
 
 
 # ------------------------------------------------------------------ Mamba-2
@@ -339,7 +406,7 @@ def attention_mixer(p, x, cfg: HybridConfig, ck, cv, offset, cache_ops):
     cv).  ``cache_ops`` is the ``(write, attend)`` pair of
     ``serving/paged_cache.py``, as ``cached_block_forward`` takes it."""
     B, S, _ = x.shape
-    hd = cfg.block.head_dim
+    hd = cfg.head_dim
     write, attend = cache_ops
     q = dense(x, p["wq"]).reshape(B, S, -1, hd).transpose(0, 2, 1, 3)
     kv = dense(x, p["wkv"], "bsd,tdh->tbsh")
@@ -350,6 +417,99 @@ def attention_mixer(p, x, cfg: HybridConfig, ck, cv, offset, cache_ops):
     out = attend(q, ck, cv, offset, window=None)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, q.shape[1] * hd)
     return dense(out, p["wo"]), ck, cv
+
+
+def cca_mixer(p, x, cfg: HybridConfig, ck, cv, tail, offset, n_valid,
+              cache_ops):
+    """Compressed convolutional attention (arXiv:2510.04476) on the block
+    pool: x [B, S, D] (normed) -> (y, ck, cv, tail).
+
+    ``z = x W_z`` is ``[q~ ; k~]``, ``nheads + kv_heads`` heads of
+    ``head_dim``: attention runs in this latent.  Two causal convolutions
+    over the sequence mix it: ``c0`` depthwise (``conv0_w`` [K0, C]), then
+    ``c1`` grouped, one group a head (``conv1_w`` [K1, heads, hd, hd]), the
+    PAIR padded once on the left by ``K0 + K1 - 2`` zero rows of ``z`` (so
+    ``c0`` before the first position is its bias, not zero).  The q-k mean
+    ``m = (q~ + k~) / 2`` (a query head with its key head) is added back,
+    ``q = c1_q + m``, ``k = c1_k + mean over its query heads of m``; each
+    head is scaled to length ``sqrt(head_dim)``, the key times ``k_temp``
+    a KV head; rope turns the leading ``cca_rope`` dims.  The first half of
+    the value heads are this position's ``x W_v``, the second half the
+    position BEFORE's (zero before the first).
+
+    ``tail`` [B, cca_tail] is what each row's sequence left behind BEFORE
+    this call: the ``K0 + K1 - 2`` rows of ``z`` before its first position,
+    then the last position's shifted value heads; ``n_valid`` [B] says how
+    many of the S positions are real.  The tail that comes back ends at the
+    last REAL one (``n_valid == 0``: bit for bit what came in), so prefill
+    in chunks and decode give the numbers of one unchunked call.
+    ``cache_ops``: the K/V pool's ``(write, attend)`` pair, as
+    :func:`attention_mixer` takes it."""
+    B, S, _ = x.shape
+    H, Hkv, hd, C = cfg.nheads, cfg.kv_heads, cfg.head_dim, cfg.cca_channels
+    R, K0, K1 = H // Hkv, cfg.cca_time0, cfg.cca_time1
+    T, shifted = K0 + K1 - 2, Hkv // 2 * hd
+    write, attend = cache_ops
+
+    z = dense(x, p["wz"])                                  # [B, S, C]
+    cat = jnp.concatenate(
+        [tail[:, :T * C].reshape(B, T, C).astype(z.dtype), z], axis=1)
+    # c0 at the K1 - 1 positions before the call's first, then at its own
+    w0, n0 = p["conv0_w"].astype(F32), S + K1 - 1
+    c0 = p["conv0_b"].astype(F32) + sum(
+        cat[:, j:j + n0].astype(F32) * w0[j] for j in range(K0))
+    # float32 operands: the MXU's default pass rounds them to bfloat16 and
+    # accumulates in float32, and the CPU has no bf16 x bf16 -> f32 product
+    c0 = c0.reshape(B, n0, H + Hkv, hd)
+    c1 = p["conv1_b"].astype(F32).reshape(H + Hkv, hd) + sum(
+        jnp.einsum("bsgd,gde->bsge", c0[:, j:j + S],
+                   p["conv1_w"][j].astype(F32)) for j in range(K1))
+
+    zf = z.astype(F32)
+    m = 0.5 * (zf[..., :H * hd].reshape(B, S, Hkv, R, hd)
+               + zf[..., H * hd:].reshape(B, S, Hkv, 1, hd))
+    q = c1[:, :, :H] + m.reshape(B, S, H, hd)
+    k = c1[:, :, H:] + jnp.mean(m, axis=3)
+
+    def unit(a):   # length sqrt(hd): unit mean square
+        return a * jax.lax.rsqrt(
+            jnp.mean(a * a, axis=-1, keepdims=True) + 1e-12)
+
+    k = unit(k) * p["k_temp"].astype(F32)[:, None]
+    pos = offset[:, None] + jnp.arange(S)[None, :]
+    cos, sin = rope_cache(pos.reshape(-1), cfg.cca_rope, cfg.rope_theta,
+                          scaling=cfg.rope_scaling)
+    rope = (cos.reshape(B, 1, S, -1), sin.reshape(B, 1, S, -1))
+
+    def turned(a):   # [B, S, heads, hd] -> [B, heads, S, hd], rope applied
+        a = a.transpose(0, 2, 1, 3)
+        return jnp.concatenate(
+            [apply_rope(a[..., :cfg.cca_rope], cache=rope),
+             a[..., cfg.cca_rope:]], axis=-1).astype(x.dtype)
+
+    q, k = turned(unit(q)), turned(k)
+
+    v = dense(x, p["wv"])                                  # [B, S, Hkv * hd]
+    vcat = jnp.concatenate(
+        [tail[:, None, T * C:].astype(v.dtype), v[..., Hkv * hd - shifted:]],
+        axis=1)                                            # [B, 1 + S, shifted]
+    v = jnp.concatenate([v[..., :Hkv * hd - shifted], vcat[:, :S]], axis=-1)
+    v = v.reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)
+
+    # the tail after this call: what lies before position n_valid
+    def ends_at(c, n, rows):
+        return jax.lax.dynamic_slice_in_dim(c, n, rows, axis=0).reshape(-1)
+
+    tail = jnp.concatenate(
+        [jax.vmap(lambda c, n: ends_at(c, n, T))(cat, n_valid),
+         jax.vmap(lambda c, n: ends_at(c, n, 1))(vcat, n_valid)],
+        axis=-1).astype(tail.dtype)
+
+    ck = write(ck, k, offset)
+    cv = write(cv, v, offset)
+    out = attend(q, ck, cv, offset, window=None)
+    out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
+    return dense(out, p["wo"]), ck, cv, tail
 
 
 def latent_attention_mixer(p, x, cfg: HybridConfig, pool, offset, cache_ops):
@@ -409,6 +569,8 @@ def hybrid_paged_forward(
     its layers; the pool itself is threaded whole through the attention
     layers); ``state``: :func:`init_state`'s arrays with one row a
     row of ``tokens``; ``n_valid`` [B]: the real positions of each row.
+    The network router's stream (``moe_score='mlp'``) is a second carry
+    through the loop, from one expert layer to the next.
     Returns ``(cache, state, logits [B, V], moe_metrics)``: the logits of
     row ``last_idx`` (default: the last), and the expert layers' counters
     summed over the layers, with ``routing`` [B, S, E-layers, k]: the
@@ -420,8 +582,9 @@ def hybrid_paged_forward(
     valid = jnp.arange(S)[None, :] < n_valid[:, None]
     h = jnp.take(params["tok_emb"], tokens, axis=0)
     cache, kv_layer = dict(cache), 0
-    ssm, conv, mets = [], [], []
+    ssm, conv, tails, mets = [], [], [], []
     mcfg = cfg.moe if cfg.moe_experts else None
+    depth = None
     for kind, lp in zip(cfg.pattern, params["layers"]):
         x = rms_norm(h, lp["norm"], cfg.norm_eps)
         if kind == "M":
@@ -435,6 +598,13 @@ def hybrid_paged_forward(
                 lp, x, cfg, cache["k"], cache["v"], offset,
                 cache_ops(kv_layer))
             kv_layer += 1
+        elif kind == "C":
+            y, cache["k"], cache["v"], tail = cca_mixer(
+                lp, x, cfg, cache["k"], cache["v"],
+                state["tail"][len(tails)], offset, n_valid,
+                cache_ops(kv_layer))
+            tails.append(tail)
+            kv_layer += 1
         elif kind == "L":
             y, cache["kv"] = latent_attention_mixer(
                 lp, x, cfg, cache["kv"], offset, cache_ops(kv_layer))
@@ -442,26 +612,40 @@ def hybrid_paged_forward(
         elif kind == "D":
             y = dense(_unbiased_act(dense(x, lp["w1"]), "swiglu"), lp["w2"])
         else:
-            y, met = moe_serve_forward(
-                lp, x, mcfg, return_metrics=True, valid=valid)
+            y, met, *stream = moe_serve_forward(
+                lp, x, mcfg, return_metrics=True, valid=valid, depth=depth)
             mets.append(met)
-        h = h + y
-    state = {"ssm": tuple(ssm), "conv": tuple(conv)}
+            depth = stream[0] if stream else None
+        if "res" in lp:
+            a_h, b_h, a_y, b_y = (lp["res"][k].astype(F32) for k in (
+                "a_h", "b_h", "a_y", "b_y"))
+            h = (a_h * h.astype(F32) + b_h + a_y * y.astype(F32)
+                 + b_y).astype(h.dtype)
+        else:
+            h = h + y
+    state = {"ssm": tuple(ssm), "conv": tuple(conv), "tail": tuple(tails)}
     metrics = None
     if mets:
         routing = jnp.stack([m.pop("gate_idx") for m in mets], axis=2)
         metrics = {k: sum(m[k] for m in mets) for k in mets[0]}
         metrics["routing"] = routing
     h = rms_norm(_select_row(h, last_idx), params["ln_f"], cfg.norm_eps)
-    return cache, state, dense(h, params["head"])[:, 0, :], metrics
+    if "head" in params:
+        logits = dense(h, params["head"])
+    else:   # tied: the table as it lies, contracted over its rows' width
+        logits = jnp.einsum("bsd,vd->bsv", h, params["tok_emb"])
+    return cache, state, logits[:, 0, :], metrics
 
 
 # --------------------------------------------------------------------- init
 
 
-def init_hybrid_params(key, cfg: HybridConfig) -> Dict[str, PyTree]:
+def init_hybrid_params(key, cfg: HybridConfig, scaled_residual: bool = False,
+                       tied_head: bool = False) -> Dict[str, PyTree]:
     """Seeded parameters in the layout :func:`hybrid_paged_forward` reads
-    (tests and examples; a checkpoint converter is not written yet)."""
+    (tests and examples; a checkpoint converter is not written yet).
+    ``scaled_residual``: every layer gets the four ``res`` vectors (at the
+    identity: a 1, b 0); ``tied_head``: no ``head`` leaf."""
     dt, D = cfg.dtype, cfg.dim
 
     def normal(k, shape, fan_in):
@@ -473,8 +657,8 @@ def init_hybrid_params(key, cfg: HybridConfig) -> Dict[str, PyTree]:
 
     layers: List[Dict[str, Any]] = []
     keys = jax.random.split(key, len(cfg.pattern) + 2)
-    hd = cfg.block.head_dim
-    for kind, k in zip(cfg.pattern, keys):
+    hd, first_expert_layer = cfg.head_dim, cfg.pattern.find("E")
+    for i, (kind, k) in enumerate(zip(cfg.pattern, keys)):
         ks = jax.random.split(k, 8)
         if kind == "M":
             di, C, H = cfg.d_inner, cfg.conv_channels, cfg.mamba_heads
@@ -492,10 +676,21 @@ def init_hybrid_params(key, cfg: HybridConfig) -> Dict[str, PyTree]:
                 "out_proj": normal(ks[4], (di, D), di),
             }
         elif kind == "*":
-            dkv = cfg.kv_heads * hd
-            lp = {"wq": normal(ks[0], (D, D), D),
+            dq, dkv = cfg.nheads * hd, cfg.kv_heads * hd
+            lp = {"wq": normal(ks[0], (D, dq), D),
                   "wkv": normal(ks[1], (2, D, dkv), D),
-                  "wo": normal(ks[2], (D, D), D)}
+                  "wo": normal(ks[2], (dq, D), dq)}
+        elif kind == "C":
+            G, C = cfg.nheads + cfg.kv_heads, cfg.cca_channels
+            lp = {"wz": normal(ks[0], (D, C), D),
+                  "wv": normal(ks[1], (D, cfg.kv_heads * hd), D),
+                  "conv0_w": normal(ks[2], (cfg.cca_time0, C), cfg.cca_time0),
+                  "conv0_b": jnp.zeros((C,), dt),
+                  "conv1_w": normal(ks[3], (cfg.cca_time1, G, hd, hd),
+                                    cfg.cca_time1 * hd),
+                  "conv1_b": jnp.zeros((C,), dt),
+                  "k_temp": jnp.ones((cfg.kv_heads,), dt),
+                  "wo": normal(ks[4], (cfg.nheads * hd, D), cfg.nheads * hd)}
         elif kind == "L":
             H, dn, dr, dc, dv = (cfg.nheads, cfg.mla_nope, cfg.mla_rope,
                                  cfg.mla_latent, cfg.mla_v)
@@ -515,8 +710,23 @@ def init_hybrid_params(key, cfg: HybridConfig) -> Dict[str, PyTree]:
             lat = m.latent_dim or D
             # a gated expert's w1 is gate and up side by side
             wide = 2 if m.act == "swiglu" else 1
-            lp = {"router": {"w": normal(ks[0], (D, m.num_experts), D),
-                             "bias": jnp.zeros((m.num_experts,), F32)},
+            if m.score != "mlp":
+                router = {"w": normal(ks[0], (D, m.num_experts), D),
+                          "bias": jnp.zeros((m.num_experts,), F32)}
+            else:
+                R = cfg.moe_router_hidden
+                kr = jax.random.split(ks[0], 4)
+                router = {
+                    "down": {"w": normal(kr[0], (D, R), D),
+                             "b": jnp.zeros((R,), dt)},
+                    "norm": {"scale": jnp.ones((R,), dt)},
+                    "w1": normal(kr[1], (R, R), R), "b1": jnp.zeros((R,), dt),
+                    "w2": normal(kr[2], (R, R), R), "b2": jnp.zeros((R,), dt),
+                    "w3": normal(kr[3], (R, m.num_experts), R),
+                    "bias": jnp.zeros((m.num_experts,), F32)}
+                if i != first_expert_layer:   # the first has no layer before
+                    router["gamma"] = jnp.ones((R,), dt)
+            lp = {"router": router,
                   "experts": {"w1": normal(
                       ks[1], (held, lat, wide * m.ffn_dim), lat),
                               "w2": normal(ks[2], (held, m.ffn_dim, lat),
@@ -529,11 +739,16 @@ def init_hybrid_params(key, cfg: HybridConfig) -> Dict[str, PyTree]:
                     ks[5], (D, wide * m.shared_ffn), D),
                                 "w2": normal(ks[6], (m.shared_ffn, D),
                                              m.shared_ffn)}
+        if scaled_residual:
+            lp["res"] = {"a_h": jnp.ones((D,), dt), "b_h": jnp.zeros((D,), dt),
+                         "a_y": jnp.ones((D,), dt), "b_y": jnp.zeros((D,), dt)}
         layers.append({"norm": norm(), **lp})
-    return {
+    out = {
         "tok_emb": (jax.random.normal(keys[-2], (cfg.vocab_size, D), F32)
                     * 0.02).astype(dt),
         "layers": layers,
         "ln_f": norm(),
-        "head": normal(keys[-1], (D, cfg.vocab_size), D),
     }
+    if not tied_head:
+        out["head"] = normal(keys[-1], (D, cfg.vocab_size), D)
+    return out

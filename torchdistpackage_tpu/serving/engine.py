@@ -460,9 +460,10 @@ class ServingEngine:
                 "prefill over the block pool, or decode a CP-trained "
                 "checkpoint with attn_impl='flash', context_axis=None")
         #: the hybrid family (models/hybrid.py), some of whose layers may keep
-        #: a recurrent state per sequence instead of keys and values: its
-        #: step carries that state beside the pool (none at all where the
-        #: pattern has no such layer); docs/serving.md "State models"
+        #: a recurrent state per sequence instead of keys and values, or a
+        #: tail of the rows before a position BESIDE its keys and values:
+        #: its step carries that state beside the pool (none at all where
+        #: the pattern has no such layer); docs/serving.md "State models"
         self.state_model = hasattr(cfg, "state_layers")
         if self.state_model:
             for on, what in ((prefix_cache and cfg.state_layers,
@@ -472,17 +473,18 @@ class ServingEngine:
                 if on:
                     raise NotImplementedError(
                         f"{what} with a state model is not supported: a "
-                        f"recurrent state cannot be shared by prefix, "
-                        f"rolled back after a rejected draft or split over "
-                        f"devices without per-position SNAPSHOTS of it, "
-                        f"which the engine does not keep yet, and the "
-                        f"family's step has no verify or mesh form (ROADMAP "
-                        f"queue 2 A4)")
+                        f"recurrent state, or the tail an attention layer "
+                        f"keeps of the rows before a position, cannot be "
+                        f"shared by prefix, rolled back after a rejected "
+                        f"draft or split over devices without per-position "
+                        f"SNAPSHOTS of it, which the engine does not keep "
+                        f"yet, and the family's step has no verify or mesh "
+                        f"form (ROADMAP queue 2 A4)")
             if record_routing and not cfg.moe_experts:
                 raise ValueError("record_routing: the model has no "
                                  "expert layers")
             q = cfg.ssm_chunk
-            if cfg.state_layers and chunk > q and chunk % q:
+            if cfg.ssm_layers and chunk > q and chunk % q:
                 raise ValueError(
                     f"chunk ({chunk}) must be at most the model's "
                     f"recurrence chunk ({q}) or a multiple of it")
@@ -623,9 +625,9 @@ class ServingEngine:
         self.state = None
         self.state_bytes = 0
         if self.state_model:
-            with span("tdp:engine.init.state"):
-                self.state = device_step.init_state()
             self.state_bytes = int(cfg.state_bytes(num_slots))
+            with span("tdp:engine.init.state", bytes=self.state_bytes):
+                self.state = device_step.init_state()
         #: run_ahead's first decode call: no call before it to take from
         self._no_flight = {"out": (jnp.zeros(num_slots, jnp.int32),
                                    jnp.zeros((num_slots, 2), jnp.uint32))
@@ -763,9 +765,10 @@ class ServingEngine:
 
     def _build_state_step(self) -> Callable:
         """:meth:`_build_step` for a state model: the same two signatures,
-        with the recurrent ``state`` as a second DONATED argument after the
-        pool (each Mamba layer's array is updated where it lies and never
-        held twice, exactly as the pool) and two more row vectors at the
+        with the per-sequence ``state`` as a second DONATED argument after
+        the pool (each layer's array, a Mamba layer's state or a convolved
+        attention layer's tail, is updated where it lies and never held
+        twice, exactly as the pool) and two more row vectors at the
         end:
         ``rows`` (None: row b is slot b, the decode call; else the slot
         whose state each compact prefill row carries) and ``n_valid`` (the
@@ -827,9 +830,9 @@ class ServingEngine:
         if self.state_model:
             raise NotImplementedError(
                 f"{what} with a state model is not supported: the "
-                f"request's recurrent state would have to be snapshotted "
-                f"and carried, which the engine does not do yet (ROADMAP "
-                f"queue 2 A4)")
+                f"request's recurrent state (or its attention layers' "
+                f"tails) would have to be snapshotted and carried, which "
+                f"the engine does not do yet (ROADMAP queue 2 A4)")
 
     def _build_cp_step(self) -> Callable:
         """The ring-paged step (docs/long_context.md "CP prefill

@@ -647,8 +647,10 @@ def paged_forward_hybrid(
     """:func:`paged_forward` for the hybrid family (models/hybrid.py): the
     attention layers write and attend through the block tables, the Mamba
     layers read and write the recurrent ``state`` (``init_state``: one
-    ``[num_slots, ...]`` array a Mamba layer).  ``n_valid`` [B]: how many of
-    each row's positions are real; the rest advance no state.
+    ``[num_slots, ...]`` array a Mamba layer), and a convolved attention
+    layer does both: keys and values through the tables, and its tail (the
+    rows before a position) in ``state`` beside them.  ``n_valid`` [B]: how
+    many of each row's positions are real; the rest advance no state.
 
     ``rows`` None: row b of ``tokens`` IS slot b (the decode call), the
     state is updated where it lies.  Otherwise ``rows`` [B] int32 names the
