@@ -6,6 +6,7 @@ The load-bearing claims, each against the gather path as parity oracle:
   gather-then-dense oracle to float tolerance across every serving shape
   — dense and GQA head grouping, sliding window, scalar AND [B]-vector
   offsets, S_in=1 decode and the K+1 spec-verify shape, fetch widths 1/2/4
+  (the key tile's blocks where the kernel walks a slot's live blocks itself)
   — and the fused int8 dequant path matches the gather-quant oracle.
 - **Engine token bit-parity**: an ``attn_impl='pallas'`` engine (running
   the interpreter-mode kernel on CPU) emits tokens BIT-equal to the
@@ -32,7 +33,7 @@ import pytest
 
 from torchdistpackage_tpu.models import generate, init_gpt_params, llama_config
 from torchdistpackage_tpu.ops.paged_attention import (
-    _heads_per_step,
+    decode_walk,
     fetched_block,
     modeled_attend_temp_bytes,
     paged_decode_attention,
@@ -89,8 +90,11 @@ def bundle():
                "want": want, "tel": {}, "eng": {}, "tokens": {},
                "gather_calls": {}}
         # narrow tables (max_ctx=16 at block_size=8 -> 3-wide) keep the
-        # interpreter's unrolled grid small: compile cost, not coverage
-        ekw = dict(num_slots=2, block_size=8, chunk=4, max_ctx=16)
+        # interpreter's work small: compile cost, not coverage.  Three slots
+        # for two requests: the decode walk's two tile halves, ``[2, Hkv,
+        # mb * bs, hd]`` where a tile covers the table, must not LOOK like
+        # two slots' gathered view ``[B, Hkv, mb * bs, hd]``
+        ekw = dict(num_slots=3, block_size=8, chunk=4, max_ctx=16)
         # pallas arm runs spec_k=2 so its decode program IS the K+1
         # verify shape; the gather oracle runs the ordinary S_in=1 decode
         # (both gather programs' gathered view looks the same)
@@ -253,34 +257,45 @@ def test_kernel_stacked_pool_matches_per_layer(case):
                                        tables=tables, window=window)))
 
 
-#: (q heads, KV heads) of the two serving cells at toy size: Mistral's 32 / 8
-#: and Nemotron's 32 / 2.  Few rows a head, so a grid step carries all of a
-#: slot's KV heads.
-DECODE_GEOMETRIES = {"gqa8-4": (8, 4), "gqa8-2": (8, 2)}
+#: (q heads, KV heads, table columns) of the serving cells at toy size:
+#: Mistral's 32 / 8 and Nemotron's 32 / 2 over six columns, ZAYA1's 8 / 2 over
+#: twenty.  Few rows a head, so a program carries all of a slot's KV heads and
+#: walks the slot's live blocks itself, a key tile of ``T`` blocks at a time.
+DECODE_GEOMETRIES = {"gqa8-4": (8, 4, 6), "gqa8-2": (8, 2, 6),
+                     "gqa8-2x20": (8, 2, 20)}
+
+
+def _decode_case(geom, T, s_in, rs, bs=4, hd=8):
+    """Slots whose live blocks number 1, ``T``, ``T`` + 1 and all of the
+    table, their rows' last position the last but one of the last block."""
+    H, hkv, mb = DECODE_GEOMETRIES[geom]
+    lives = (1, T, min(T + 1, mb), mb)
+    B, nb = len(lives), 1 + len(lives) * mb
+    tables = jnp.asarray(rs.permutation(np.arange(1, nb)).reshape(B, mb),
+                         jnp.int32)
+    offs = jnp.asarray([n * bs - s_in - 1 for n in lives], jnp.int32)
+    q = jnp.asarray(rs.standard_normal((B, H, s_in, hd)), jnp.float32)
+    return lives, nb, tables, offs, q
 
 
 @pytest.mark.parametrize("s_in", (1, 3))
 @pytest.mark.parametrize("fw", (1, 2, 3, 4, 6))
 @pytest.mark.parametrize("geom", sorted(DECODE_GEOMETRIES))
 def test_decode_shape_matches_gather_oracle(geom, fw, s_in):
-    """The decode shape (``hb`` > 1 KV heads a grid step) at the cell's table
-    width, every fetch width that divides or covers it, slots whose live
-    blocks number 1, exactly ``fw``, ``fw`` + 1 and ``mb``: decode and the
-    K+1 verify rows, with and without the window, the int8 pool, and the
-    stacked pool under a traced layer, all within float tolerance of the
-    gather oracle."""
-    H, hkv = DECODE_GEOMETRIES[geom]
-    bs, hd, mb, L = 4, 8, 6, 2
-    lives = (1, fw, min(fw + 1, mb), mb)
-    B, nb = len(lives), 1 + len(lives) * mb
+    """The decode shape (the in-kernel walk, ``hb`` > 1 KV heads a program)
+    at the cells' table widths, every tile width ``T`` = ``fw`` that divides,
+    covers or straddles them, slots whose live blocks number 1, exactly
+    ``T``, ``T`` + 1 and ``mb``: decode and the K+1 verify rows, with and
+    without the window, the int8 pool (which keeps the grid's walk,
+    ``fetch_width`` its blocks a step), and the stacked pool under a traced
+    layer, all within float tolerance of the gather oracle."""
+    H, hkv, mb = DECODE_GEOMETRIES[geom]
+    bs, hd, L = 4, 8, 2
     rows = -(-(H // hkv) * s_in // 8) * 8
-    assert _heads_per_step(hkv, rows, fw, bs * hd * 4) == hkv
+    assert decode_walk(hkv, rows, mb, fw, bs, bs * hd * 4) == (hkv, mb)
+    assert decode_walk(hkv, rows, mb, fw, bs, bs * hd, True) == (hkv, 0)
     rs = np.random.RandomState(fw * 10 + s_in)
-    tables = jnp.asarray(rs.permutation(np.arange(1, nb)).reshape(B, mb),
-                         jnp.int32)
-    # the rows' last position is the last but one of the slot's last block
-    offs = jnp.asarray([n * bs - s_in - 1 for n in lives], jnp.int32)
-    q = jnp.asarray(rs.standard_normal((B, H, s_in, hd)), jnp.float32)
+    _lives, nb, tables, offs, q = _decode_case(geom, fw, s_in, rs)
     traced = jax.jit(lambda q, kp, vp, li, window: paged_decode_attention(
         q, kp, vp, tables, offs, layer=li, window=window, fetch_width=fw),
         static_argnums=4)
@@ -301,6 +316,69 @@ def test_decode_shape_matches_gather_oracle(geom, fw, s_in):
             err_msg=f"int8={int8} window={window} layer={layer}")
 
 
+@pytest.mark.parametrize("s_in", (1, 3))
+@pytest.mark.parametrize("T", (None, 1, 3, 8))
+def test_decode_walk_reads_nothing_a_slot_does_not_own(T, s_in):
+    """NaN in every pool block that no live column of the call's tables
+    names (the NULL block behind the dead columns among them) and in the
+    rows of each slot's last block behind its last position: the output is
+    BITWISE the clean pool's.  A dead block inside a live key tile is never
+    copied, its stale keys are masked and its values zeroed, so nothing of
+    it reaches the output, not even as 0 x NaN."""
+    geom, bs, hd, L = "gqa8-2x20", 4, 8, 2
+    rs = np.random.RandomState(s_in)
+    lives, nb, tables, offs, q = _decode_case(geom, T or 5, s_in, rs, bs, hd)
+    hkv, mb = DECODE_GEOMETRIES[geom][1:]
+    kp, vp = (np.array(a) for a in _pools_for(False, (L, nb, hkv, bs, hd), rs))
+    dirty = [kp.copy(), vp.copy()]
+    owned = np.zeros(nb, bool)
+    tab = np.asarray(tables)
+    for b, n in enumerate(lives):
+        owned[tab[b, :n]] = True
+        for pool in dirty:  # the last block's rows nobody wrote yet
+            pool[:, tab[b, n - 1], :, (int(offs[b]) + s_in) % bs:] = np.nan
+    tables = jnp.asarray(np.where(
+        np.arange(mb)[None] < np.asarray(lives)[:, None], tab, 0), jnp.int32)
+    for pool in dirty:
+        pool[:, ~owned] = np.nan
+    run = lambda k, v: np.asarray(paged_decode_attention(
+        q, jnp.asarray(k), jnp.asarray(v), tables, offs, layer=1,
+        fetch_width=T))
+    want = run(kp, vp)
+    assert np.isfinite(want).all()
+    np.testing.assert_array_equal(run(*dirty), want)
+
+
+#: (KV heads, query heads, S_in, table columns, block size, head dim,
+#: itemsize, int8) -> (hb, T): every shape the cells and the smoke test hand
+#: the kernel, at their real sizes.
+WALK_SHAPES = {
+    "mistral7b.decode": ((8, 32, 1, 6, 128, 128, 2, False), (8, 6)),
+    "mistral7b.decode-chunk": ((8, 32, 256, 6, 128, 128, 2, False), (1, 0)),
+    "nemotron3s.decode": ((2, 32, 1, 6, 128, 128, 2, False), (2, 6)),
+    "nemotron3s.decode-chunk": ((2, 32, 128, 6, 128, 128, 2, False), (1, 0)),
+    "zaya1.reason": ((2, 8, 1, 20, 128, 128, 2, False), (2, 10)),
+    "zaya1.reason-chunk": ((2, 8, 256, 20, 128, 128, 2, False), (1, 0)),
+    "zaya1.reason-verify": ((2, 8, 3, 21, 128, 128, 2, False), (2, 10)),
+    "chip_smoke": ((16, 16, 1, 16, 128, 128, 2, False), (16, 4)),
+    "32k-engine": ((2, 4, 1, 64, 512, 8, 4, False), (2, 2)),
+    "32k-mistral": ((8, 32, 1, 256, 128, 128, 2, False), (8, 8)),
+    "int8-decode": ((8, 32, 1, 6, 128, 128, 1, True), (8, 0)),
+    "one-column": ((8, 32, 1, 1, 128, 128, 2, False), (8, 1)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WALK_SHAPES))
+def test_walk_follows_the_shape(name):
+    """``decode_walk`` is the one place that says how a call walks: ``hb``
+    KV heads a program and a key tile of ``T`` blocks for the small shapes,
+    the grid's walk (``T`` = 0) for a chunk's rows and an int8 pool."""
+    (hkv, H, s_in, mb, bs, hd, itemsize, int8), want = WALK_SHAPES[name]
+    rows = -(-(H // hkv) * s_in // 8) * 8
+    assert decode_walk(hkv, rows, mb, min(6, mb), bs, bs * hd * itemsize,
+                       int8) == want
+
+
 def _pallas_grid(s_in, fw, *, B=2, H=8, hkv=4, bs=4, hd=8, mb=6):
     """(grid, block shapes of every operand and the output) of the call's
     ``pallas_call``, read from its jaxpr."""
@@ -310,7 +388,15 @@ def _pallas_grid(s_in, fw, *, B=2, H=8, hkv=4, bs=4, hd=8, mb=6):
         q, k, v, t, o, fetch_width=fw))(
             S((B, H, s_in, hd), jnp.float32), pool, pool,
             S((B, mb), jnp.int32), S((B,), jnp.int32))
-    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+
+    def calls(jaxpr):  # the decode walk's call sits inside its own jit
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from calls(sub)
+
+    (call,) = calls(jaxpr.jaxpr)
     gm = call.params["grid_mapping"]
     blocks = [tuple(d.block_size for d in bm.block_shape)
               for bm in gm.block_mappings]
@@ -320,12 +406,13 @@ def _pallas_grid(s_in, fw, *, B=2, H=8, hkv=4, bs=4, hd=8, mb=6):
 @pytest.mark.parametrize("fw", (1, 2, 4))
 def test_chunk_keeps_one_head_a_step(fw):
     """``S_in`` = a chunk yields ``hb`` = 1 and ``paged_chunk``'s grid, block
-    shapes and operand count as they were before a step carried several
+    shapes and operand count as they were before a program carried several
     heads (``(slot, kv-head, kv-step)``, a ``(1, 1, 1, bs, hd)`` block a
     sub-block and side); the decode shape at the same geometry takes all
-    four heads in one step."""
-    B, H, hkv, bs, hd, mb, chunk = 2, 8, 4, 4, 8, 6, 64
-    rows = (H // hkv) * chunk
+    four heads in one program a slot, its pools handed over whole (they
+    stay in HBM and the kernel copies what is live)."""
+    B, H, hkv, bs, hd, mb, chunk = 2, 8, 4, 4, 8, 6, 128
+    rows = (H // hkv) * chunk  # 256: past the one 128-row tile of a program
     name, grid, blocks = _pallas_grid(chunk, fw)
     assert name == "paged_chunk"
     assert grid == (B, hkv, -(-mb // fw))
@@ -333,8 +420,8 @@ def test_chunk_keeps_one_head_a_step(fw):
                       + [(1, 1, rows, hd)])
     name, grid, blocks = _pallas_grid(1, fw)
     assert name == "paged_decode"
-    assert grid == (B, 1, -(-mb // fw))
-    assert blocks == ([(1, hkv, 8, hd)] + 2 * fw * [(1, 1, hkv, bs, hd)]
+    assert grid == (B, 1)
+    assert blocks == ([(1, hkv, 8, hd)] + 2 * [(1, 1 + B * mb, hkv, bs, hd)]
                       + [(1, hkv, 8, hd)])
 
 

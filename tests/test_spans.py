@@ -241,6 +241,31 @@ def test_engine_init_span_holds_the_pool_fill(params):
     assert init[3] <= pool[3] <= pool[4] <= init[4]
 
 
+@pytest.mark.parametrize("kw, rows", [
+    ({}, 8), ({"spec_k": 2}, 8), ({"kv_quant": True}, 8)])
+def test_pool_span_says_how_the_kernel_walks(params, kw, rows):
+    """``kv_heads_per_step`` and ``kv_tile_blocks`` on ``tdp:engine.init.pool``
+    are the shape function's result for the engine's decode call (the K+1
+    verify rows with ``spec_k``; 0 blocks a tile for an int8 pool, which
+    keeps the grid's walk); the gather path runs no kernel and says
+    nothing."""
+    from torchdistpackage_tpu.ops.paged_attention import decode_walk
+
+    spans.clear()
+    eng = _engine(params, attn_impl="pallas", **kw)
+    attrs = _by_name(spans.snapshot(), "tdp:engine.init.pool")[0][5]
+    blk, int8 = CFG.block, bool(kw.get("kv_quant"))
+    hb, T = decode_walk(
+        blk.kv_head_count, rows, eng.max_blocks, 1, 8,
+        8 * blk.head_dim * (1 if int8 else 4), int8)
+    assert (attrs["kv_heads_per_step"], attrs["kv_tile_blocks"]) == (hb, T)
+    assert hb == blk.kv_head_count and (T == 0) == int8
+    spans.clear()
+    _engine(params)
+    attrs = _by_name(spans.snapshot(), "tdp:engine.init.pool")[0][5]
+    assert "bytes" in attrs and "kv_tile_blocks" not in attrs
+
+
 def test_fetch_ends_at_the_tokens_and_telemetry_falls_into_host(params):
     """``Telemetry.end_step`` is called after the fetch span has closed, and
     its step record still holds the wait for the device."""
@@ -285,8 +310,9 @@ def _kernel_cases():
     q = S((1, 2, 256, 128), bf)
     flash_args = (jax.grad(flash, argnums=(0, 1, 2)), (q, q, q))
 
-    # GQA 8 / 4: the decode rows take all four KV heads in one grid step,
-    # the chunk's 2 x 64 rows a head take one (ops/paged_attention.py)
+    # GQA 8 / 4: the decode rows take all four KV heads in one program that
+    # walks the slot's live blocks itself, the chunk's 2 x 128 rows a head
+    # take one head a grid step (ops/paged_attention.py)
     B, H, Hkv, hd, bs, mb = 2, 8, 4, 128, 128, 2
     pool = S((1 + B * mb, Hkv, bs, hd), bf)
 
@@ -322,7 +348,7 @@ def _kernel_cases():
         "flash_bwd_dq": lambda: flash_args,
         "flash_bwd_dkv": lambda: flash_args,
         "paged_decode": lambda: paged(1),
-        "paged_chunk": lambda: paged(64),
+        "paged_chunk": lambda: paged(128),
         "paged_carry": carry,
         "mla_decode": lambda: latent(1),
         "mla_chunk": lambda: latent(64),

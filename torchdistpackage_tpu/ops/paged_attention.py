@@ -6,27 +6,29 @@ materializes every slot's blocks into a contiguous ``[B, Hkv,
 max_blocks*bs, hd]`` view before the dense ``_cached_attention`` — O(max
 context) HBM read AND written per decode tick, whatever the slot's actual
 length, plus an f32 upcast temp of the same size on the int8 pool.  This
-kernel removes that round trip: the grid runs ``(slot, kv-head group,
-kv-step)`` and each program DMAs ``fetch_width`` pool blocks into VMEM
-through a scalar-prefetched block table (``PrefetchScalarGridSpec`` — the
-table IS the index map), runs online-softmax flash accumulation against
-them with per-row position masking, and issues no fetch past the slot's
-live length: a sub-block operand whose step lies past the last live block
-asks for the block it already holds (:func:`fetched_block`), and the
-pipeline skips a copy whose index did not change.  (Clamping every dead
-operand onto the slot's LAST live block would spare the copy at
-``fetch_width`` 1 only: wider, each operand moves to that block once
-more.)  Per-tick attention HBM traffic is the live blocks',
-whole, and nothing else; VMEM per program is O(block) — which is what opens
-32k+ serving contexts (docs/long_context.md) on the same pool.
+kernel removes that round trip.  The block table, the per-slot offsets and
+the layer of the stacked pool are scalar-prefetched, and the table is
+walked in one of two ways, chosen from the call's shape alone
+(:func:`decode_walk`).  Either way a program runs online-softmax flash
+accumulation with per-row position masking, per-tick attention HBM traffic
+is the live blocks', whole, and nothing else, and VMEM per program is
+O(block): what opens 32k+ serving contexts (docs/long_context.md).
 
-How many KV heads a program carries follows from the shape
-(:func:`_heads_per_step`).  The pool lies ``[L, nb, Hkv, bs, hd]``, so the
-``Hkv`` heads of one block are contiguous: a decode or verify call, with a
-handful of query rows a head, takes them all in ONE copy a block and one
-grid step a slot and kv-step, where a head a step would pay a step's fixed
-price and its nine small copies for two ``[8, 128] x [128, 128]`` products;
-a prefill chunk's hundreds of rows a head keep one head a step.
+The SMALL shape, a handful of query rows a head (decode, the K+1 verify
+rows: all of a program's ``hb x rows`` within one 128-row tile), walks the
+table IN the kernel (:func:`_walk_kernel`): grid ``(slot, kv-head group)``,
+the pools left in HBM, and a loop over the slot's LIVE blocks in key tiles
+of ``T`` blocks, tile ``t + 1`` copied into one half of a VMEM buffer while
+tile ``t`` is computed from the other: one DMA a live block and side, one
+softmax step a tile, and nothing evaluated, fetched or waited for on behalf
+of a dead table column.  The pool lies ``[L, nb, Hkv, bs, hd]``, the heads
+of one block contiguous, so a copy carries all ``hb`` heads of a program.
+
+A prefill chunk's hundreds of rows a head, and an int8 pool, walk the GRID
+(:func:`_kernel`): ``(slot, kv-head, kv-step)``, ``fetch_width`` blocks a
+step through ``BlockSpec`` operands whose index map is the table; a
+sub-block past the last live block asks for the block it already holds
+(:func:`fetched_block`) and the pipeline skips the copy.
 
 One entry point covers every serving shape:
 
@@ -56,12 +58,10 @@ On CPU the kernel runs in Pallas interpreter mode automatically (same
 ``_interpret`` switch as ops/flash_attention.py), so every test exercises
 the identical code path the TPU compiles.
 
-Tuning: ``fetch_width`` (pool blocks streamed per grid step — each is an
-independent BlockSpec input, so Mosaic pipelines the DMAs) and
-``q_pad_to`` (pad the in-kernel q rows to a tile-friendly multiple; the
+Tuning: ``T`` and ``hb`` follow from the shape; ``fetch_width`` (the grid
+walk's blocks a step) and ``q_pad_to`` (the q rows' padding multiple: the
 K+1 verify shape lands at awkward row counts like G*(K+1)) come from the
-per-chip table :data:`_PAGED_PARAMS` (tools/flash_tune.py ``--paged``
-measures candidates for a row).
+per-chip table :data:`_PAGED_PARAMS` (tools/flash_tune.py ``--paged``).
 """
 
 from __future__ import annotations
@@ -81,26 +81,26 @@ NEG_INF = -1e30  # finite "minus infinity": avoids (-inf) - (-inf) NaNs
 
 _LANES = 128  # m/l scratch keeps a full lane dim for layout friendliness
 
-#: A grid step carries several KV heads only while all its query rows fit
-#: one 128-row tile (decode: 8 heads x 8 rows; a chunk's 1,024 rows: 1 head).
+#: A program carries several KV heads only while all its query rows fit one
+#: 128-row tile (decode: 8 heads x 8 rows; a chunk's 1,024 rows: 1 head).
 _ROWS_PER_STEP = 128
 
-#: VMEM the double-buffered K + V blocks of one grid step may take (bytes):
-#: ``2 x 2 x fetch_width x heads x block`` stays under it.
+#: VMEM the K + V blocks a program holds twice over may take (bytes): ``2 x 2
+#: x blocks x heads x block`` (a key tile's, or ``fetch_width``) stays under it.
 _KV_VMEM_BUDGET = 8 << 20
 
-#: Kernel parameters by device_kind substring.  ``fetch_width`` = pool
-#: blocks streamed per grid step; ``q_pad_to`` = q-row padding multiple (the
-#: K+1 verify shape's G*(K+1) rows are rarely tile-aligned).  The v5e row is
-#: MEASURED there (PR 29, ``tools/flash_tune.py --paged --shape
-#: mistral7b.decode``: 64 slots x GQA 32 / 8 x block 128, six table columns,
-#: a bf16 pool; ms a call, decode / verify / chunk): (6, 8) 0.203 / 0.216 /
-#: 0.622, (6, 16) 0.208 / 0.217 / 0.621, (1, 8) 0.266 / 0.280 / 0.665,
-#: (2, 8) 0.278 / 0.292 / 0.643, (3, 8) 0.283 / 0.298 / 0.636, (4, 8) 0.336
-#: / 0.350 / 0.670.  6 covers the table: one grid step a slot, and no
-#: operand that moves at a second kv-step.  PERF.md §6 has the 16-layer
-#: loop's table.  The cpu row is the Pallas interpreter's.  A chip with no
-#: row is an error.
+#: Kernel parameters by device_kind substring.  ``fetch_width`` = pool blocks
+#: the GRID's walk streams a step (chunk rows, int8 pools); ``q_pad_to`` = the
+#: q rows' padding multiple (G*(K+1) verify rows are rarely tile-aligned).  The
+#: v5e row is MEASURED there (PR 29, ``tools/flash_tune.py --paged --shape
+#: mistral7b.decode``; ms a call, decode / verify / chunk): (6, 8) 0.203 /
+#: 0.216 / 0.622, (6, 16) 0.208 / 0.217 / 0.621, (1, 8) 0.266 / 0.280 / 0.665.
+#: The decode walk's key tile ``T`` follows from the shape (``decode_walk``).
+#: MEASURED for it (PR 34, ``--shape zaya1.reason``: 64 slots x 8 / 2 heads x
+#: 20 columns; ms a call, decode / verify): ``T`` 1 0.366 / 0.376, 2 0.236 /
+#: 0.239, 4 0.175 / 0.181, 6 0.159 / 0.164, 10 0.152 / 0.156, 20 0.151 / 0.156;
+#: a 20-layer pass, the cell's fill / one live block / full tables: ``T`` 10 2.75
+#: / 1.45 / 4.63, the grid's walk 11.05 / 6.57 / 16.59.  cpu: the interpreter's.
 _PAGED_PARAMS = (
     ("v5 lite", {"fetch_width": 6, "q_pad_to": 8}),
     ("v5e", {"fetch_width": 6, "q_pad_to": 8}),
@@ -210,6 +210,156 @@ def _accumulate(s, keep, pv, acc_ref, m_ref, l_ref):
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
 
+#: Keys one key tile of the decode walk holds at most (MEASURED at blocks of
+#: 128, see :data:`_PAGED_PARAMS`: ten blocks): a wider tile saves no more
+#: softmax steps than its dead tail's products cost, and its float32 scores
+#: ``[hb, rows, keys]`` grow with it.
+_KV_TILE_KEYS = 1280
+
+
+def decode_walk(Hkv: int, rows: int, mb: int, fw: int, bs: int,
+                block_bytes: int, quantized: bool = False) -> Tuple[int, int]:
+    """``(hb, T)`` of a call, from its shape alone: the KV heads a program
+    carries (:func:`_heads_per_step`) and the pool blocks of one key tile.
+    ``T`` > 0 names the in-kernel walk over live blocks
+    (:func:`_walk_kernel`): the SMALL shape, a handful of query rows a head
+    (decode, the K+1 verify rows), where all ``hb x rows`` query rows fit
+    one 128-row tile (:data:`_ROWS_PER_STEP`); ``T`` is as many of the
+    table's columns, up to :data:`_KV_TILE_KEYS` keys, as keep the two
+    halves of the K and V tile buffers within :data:`_KV_VMEM_BUDGET`
+    (``block_bytes``: one head's ``bs`` rows).  ``T`` = 0:
+    a prefill chunk's hundreds of rows a head, and an int8 pool, keep the
+    grid's walk over table columns (:func:`_kernel`)."""
+    if quantized or rows > _ROWS_PER_STEP:
+        return _heads_per_step(Hkv, rows, fw, block_bytes), 0
+    hb = _heads_per_step(Hkv, rows, 1, block_bytes)
+    fit = _KV_VMEM_BUDGET // (2 * 2 * hb * block_bytes)
+    return hb, max(1, min(_KV_TILE_KEYS // bs, mb, fit))
+
+
+def call_walk(R: int, Hkv: int, mb: int, bs: int, block_bytes: int,
+              quantized: bool = False, fetch_width: Optional[int] = None,
+              q_pad_to: Optional[int] = None) -> Tuple[int, int, int, int]:
+    """``(rows, fw, hb, T)`` of a call of ``R`` query rows a KV head over
+    ``mb`` table columns on the attached chip: the padded rows and the
+    grid walk's fetch width from the chip's row (:func:`_step_params`),
+    ``hb`` and ``T`` from :func:`decode_walk`.  A caller's own
+    ``fetch_width`` (the tuner's, the tests') is the blocks fetched at a
+    time in either walk: the key tile's too.  What the wrapper runs, what
+    :func:`modeled_attend_temp_bytes` counts, what the tuner prints and what
+    the engine writes on its ``tdp:engine.init.pool`` span."""
+    fw, pad_to = _step_params(mb, fetch_width, q_pad_to)
+    rows = -(-R // pad_to) * pad_to
+    hb, T = decode_walk(Hkv, rows, mb, fw, bs, block_bytes, quantized)
+    return rows, fw, hb, fw if T and fetch_width is not None else T
+
+
+def _walk_kernel(
+    tab_ref, off_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
+    kbuf, vbuf, sem, par_ref, acc_ref, m_ref, l_ref,
+    *, S_in, bs, mb, window, sm_scale, rows, hb, T,
+):
+    """The decode shape's walk.  Grid ``(slot b, kv-head group h)``, run in
+    order; the pools stay in HBM and program ``(b, h)`` loops over the
+    slot's LIVE blocks in key tiles of ``T``: tile ``t`` lies in one half of
+    ``kbuf`` / ``vbuf`` ``[2, hb, T x bs, hd]`` while the copies of tile
+    ``t + 1`` (after the last, of the NEXT program's first tile) fill the
+    other, one DMA a live block and side, none for a dead one.  ``par_ref``
+    (SMEM) carries from program to program which half the first tile is in.
+    A tile is ONE online-softmax step: one batched score product over its
+    ``T x bs`` keys, one max / exp / rescale, one value product.  A dead
+    block inside a live tile holds whatever the buffer held: its keys lie
+    behind every query position, so the mask takes them out of the scores,
+    and as values the rows behind the call's last position are zeroed (so
+    are the rows of the slot's own last block that nobody wrote yet):
+    nothing reaches the output even as 0 x NaN."""
+    b, h = pl.program_id(0), pl.program_id(1)
+    nh = pl.num_programs(1)
+    lay = lay_ref[0]
+
+    def live_blocks(b):
+        return jnp.minimum((off_ref[b] + S_in + bs - 1) // bs, mb)
+
+    def tile_copies(b, h, t, half, act):
+        """Start or wait for (``act``) the copies of slot ``b``'s tile ``t``
+        of head group ``h`` into buffer half ``half``: a loop over the
+        tile's live blocks.  (``T`` unrolled copies behind a test each ran
+        no faster and cost ``T`` times the trace:
+        :func:`paged_decode_attention` on what a trace costs.)"""
+        def block(i, carry):
+            src = (lay, tab_ref[b, t * T + i], pl.ds(h * hb, hb))
+            dst = (half, slice(None), pl.ds(pl.multiple_of(i * bs, bs), bs))
+            for pool, buf, side in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                act(pltpu.make_async_copy(
+                    pool.at[src], buf.at[dst], sem.at[half, side]))
+            return carry
+
+        jax.lax.fori_loop(
+            0, jnp.clip(live_blocks(b) - t * T, 0, T), block, None)
+
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
+
+    @pl.when((b == 0) & (h == 0))
+    def _first():
+        par_ref[0] = 0
+        tile_copies(b, h, 0, 0, start)
+
+    off = off_ref[b]
+    last = off + S_in  # positions written so far, this call's rows included
+    tiles = (live_blocks(b) + T - 1) // T
+    par0 = par_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[0]  # [hb, rows, hd]
+    # row r covers query position off + (r % S_in) (group-major rows);
+    # padded rows past the real R mask everything and are sliced off
+    qpos = off + jax.lax.broadcasted_iota(
+        jnp.int32, (hb, rows, T * bs), 1) % S_in
+
+    def tile(t, carry):
+        half = (par0 + t) % 2
+
+        @pl.when(t + 1 < tiles)
+        def _next_tile():
+            tile_copies(b, h, t + 1, 1 - half, start)
+
+        @pl.when((t + 1 == tiles) & ((b + 1 < pl.num_programs(0))
+                                     | (h + 1 < nh)))
+        def _next_program():
+            wrap = h + 1 == nh
+            tile_copies(jnp.where(wrap, b + 1, b), jnp.where(wrap, 0, h + 1),
+                        0, 1 - half, start)
+
+        tile_copies(b, h, t, half, wait)
+        k = kbuf[half]  # [hb, T*bs, hd]
+        v = vbuf[half]
+        s = jnp.einsum("hrd,hkd->hrk", q, k,
+                       preferred_element_type=jnp.float32)
+        kpos = t * (T * bs) + jax.lax.broadcasted_iota(
+            jnp.int32, (hb, rows, T * bs), 2)
+        keep = kpos <= qpos
+        if window is not None:  # Mistral: key in (qpos - window, qpos]
+            keep = keep & (kpos > qpos - window)
+        written = t * (T * bs) + jax.lax.broadcasted_iota(
+            jnp.int32, v.shape, 1) < last
+        v = jnp.where(written, v, 0)
+        _accumulate(
+            s * sm_scale, keep,
+            lambda p: jnp.einsum("hrk,hkd->hrd", p.astype(v.dtype), v,
+                                 preferred_element_type=jnp.float32),
+            acc_ref, m_ref, l_ref)
+        return carry
+
+    jax.lax.fori_loop(0, tiles, tile, None)
+    par_ref[0] = (par0 + tiles) % 2
+    # l > 0 for every real row (a query always attends its own position);
+    # padded rows divide garbage that is sliced away
+    o_ref[0] = (acc_ref[...] / l_ref[..., :1]).astype(o_ref.dtype)
+
+
 def _kernel(
     tab_ref, off_ref, lay_ref, q_ref, *refs,
     S_in, bs, window, sm_scale, quantized, fetch_width, rows, hb,
@@ -278,6 +428,8 @@ def _kernel(
         o_ref[0] = (acc_ref[...] / l_ref[..., :1]).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "window", "sm_scale", "fetch_width", "q_pad_to"))
 def paged_decode_attention(
     q: jnp.ndarray,
     k_pool: Any,
@@ -306,6 +458,13 @@ def paged_decode_attention(
     additionally bounds below).  Returns [B, H, S_in, hd] in ``q.dtype``
     — drop-in for the gather path's ``_cached_attention`` output
     (float-tolerance equal; the engine goldens assert token bit parity).
+
+    Jitted, the layer an operand: a program whose python-unrolled layers
+    call it with one shape traces and lowers the kernel ONCE.  Beside a TPU
+    every static index of a kernel body becomes a device constant as it is
+    traced: a decode walk with its ~80 copies unrolled took 1.4 s to trace
+    there (0.17 s on a CPU), twenty layers' index maps 20 s to lower, all
+    inside ``setup_s``.
     """
     B, H, S_in, hd = q.shape
     k_pool, v_pool, lay = _stacked(k_pool, v_pool, layer)
@@ -324,12 +483,18 @@ def paged_decode_attention(
         offs = jnp.broadcast_to(offs, (B,))
     # group-major rows: row r = g*S_in + s covers position off + s
     R = groups * S_in
-    fw, pad_to = _step_params(mb, fetch_width, q_pad_to)
-    rows = -(-R // pad_to) * pad_to
-    hb = _heads_per_step(Hkv, rows, fw, bs * hd * k_arr.dtype.itemsize)
+    rows, fw, hb, T = call_walk(
+        R, Hkv, mb, bs, bs * hd * k_arr.dtype.itemsize, quantized,
+        fetch_width, q_pad_to)
     qr = q.reshape(B, Hkv, R, hd)
     if rows != R:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - R), (0, 0)))
+    name = "paged_decode" if S_in == 1 else "paged_chunk"
+    scalars = (tables.astype(jnp.int32), offs, lay)
+    if T:
+        return _walk_call(qr, k_pool, v_pool, scalars, S_in=S_in,
+                          window=window, sm_scale=float(sm_scale), hb=hb,
+                          T=T, name=name)[:, :, :R].reshape(B, H, S_in, hd)
 
     def qidx(b, h, j, tab, off, lay):
         return (b, h, 0, 0)
@@ -375,9 +540,53 @@ def paged_decode_attention(
         out_shape=_out_struct((B, Hkv, rows, hd), q.dtype, q),
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-        name="paged_decode" if S_in == 1 else "paged_chunk",
-    )(tables.astype(jnp.int32), offs, lay, *operands)
+        name=name,
+    )(*scalars, *operands)
     return out[:, :, :R].reshape(B, H, S_in, hd)
+
+
+def _walk_call(qr, k_pool, v_pool, scalars, *, S_in, window, sm_scale, hb, T,
+               name):
+    """The ``pallas_call`` of :func:`_walk_kernel` over padded group-major
+    rows ``qr`` [B, Hkv, rows, hd] and the stacked pools."""
+    B, Hkv, rows, hd = qr.shape
+    bs = k_pool.shape[3]
+    mb = scalars[0].shape[-1]
+
+    def qidx(b, h, tab, off, lay):
+        return (b, h, 0, 0)
+
+    tile = pltpu.VMEM((2, hb, T * bs, hd), k_pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, Hkv // hb),
+        in_specs=[pl.BlockSpec((1, hb, rows, hd), qidx),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, hb, rows, hd), qidx),
+        scratch_shapes=[
+            tile, tile,                                   # K, V tiles x 2
+            pltpu.SemaphoreType.DMA((2, 2)),              # [half, K | V]
+            pltpu.SMEM((1,), jnp.int32),                  # first tile's half
+            pltpu.VMEM((hb, rows, hd), jnp.float32),      # acc
+            pltpu.VMEM((hb, rows, _LANES), jnp.float32),  # m
+            pltpu.VMEM((hb, rows, _LANES), jnp.float32),  # l
+        ],
+    )
+    kernel = functools.partial(
+        _walk_kernel, S_in=S_in, bs=bs, mb=mb, window=window,
+        sm_scale=sm_scale, rows=rows, hb=hb, T=T)
+    # programs run in order: each starts the next one's first copies
+    params = None if _interpret() else pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=_out_struct((B, Hkv, rows, hd), qr.dtype, qr),
+        compiler_params=params,
+        interpret=_interpret(),
+        name=name,
+    )(*scalars, qr, k_pool, v_pool)
 
 
 def _stacked(k_pool: Any, v_pool: Any, layer) -> Tuple[Any, Any, jnp.ndarray]:
@@ -613,18 +822,21 @@ def modeled_attend_temp_bytes(
     materialized for k AND v (the int8 pool additionally upcasts both to
     f32 in the einsum, so ``itemsize=4`` models that case too) — O(max
     context) whatever the slot holds.  ``pallas``: what one program holds
-    in VMEM (the q/out rows of its ``hb`` KV heads plus ``fetch_width``
-    double-buffered K and V blocks of ``hb`` heads each, ``hb`` from
-    :func:`_heads_per_step`) times the programs of one kv-step — O(block),
-    independent of context."""
+    in VMEM (the q/out rows of its ``hb`` KV heads plus the K and V blocks
+    it keeps twice over: both halves of a key tile of ``T`` blocks where the
+    shape takes the in-kernel walk, ``fetch_width`` double-buffered blocks
+    a side where it walks the grid; all from :func:`call_walk`) times the
+    programs of one step: O(block), independent of context."""
     if impl == "gather":
         return 2 * batch * kv_heads * max_blocks * block_size * head_dim * itemsize
     if impl == "pallas":
-        fw = int(fetch_width or default_paged_params()["fetch_width"])
         rows = groups * s_in
         block = block_size * head_dim * itemsize
-        hb = _heads_per_step(kv_heads, rows, fw, block)
-        # one program: hb heads' q and out rows, fw K + V blocks, 2-buffered
-        program = hb * (2 * rows * head_dim * itemsize + 2 * 2 * fw * block)
+        _padded, fw, hb, T = call_walk(
+            rows, kv_heads, max_blocks, block_size, block,
+            fetch_width=fetch_width)
+        # one program: hb heads' q and out rows, T or fw K + V blocks, twice
+        program = hb * (2 * rows * head_dim * itemsize
+                        + 2 * 2 * (T or fw) * block)
         return batch * (kv_heads // hb) * program
     raise ValueError(f"impl must be 'gather' or 'pallas', got {impl!r}")

@@ -617,7 +617,7 @@ class ServingEngine:
         device_step.bind(self)
         with span("tdp:engine.init.pool") as sp:
             self.cache = device_step.init_cache()
-            sp.attrs["bytes"] = pool_bytes(self.cache)
+            sp.attrs.update(bytes=pool_bytes(self.cache), **self._walk_attrs())
         #: state models: the recurrent state, one row a slot, beside the
         #: pool (``models.hybrid.init_state``); like the pool, the compiled
         #: step is handed it as a donated argument and the engine keeps
@@ -810,6 +810,25 @@ class ServingEngine:
             return out
 
         return jax.jit(step, donate_argnums=(1, 2))
+
+    def _walk_attrs(self) -> Dict[str, int]:
+        """How the paged kernel walks a decode (or verify) call's tables,
+        which follows from the shape alone
+        (``ops.paged_attention.call_walk``): the KV heads a program carries
+        and the pool blocks of one key tile (0: the grid walks the table's
+        columns).  Nothing for the gather path and for a latent pool."""
+        k = self.cache.get("k")
+        if self.attn_impl != "pallas" or k is None:
+            return {}
+        arr = k[0] if self.kv_quant else k
+        tp = int(self.mesh.shape[self.axis]) if (
+            self.mesh is not None and self.axis) else 1
+        blk = self.cfg.block
+        _rows, _fw, hb, T = paged_attention_ops.call_walk(
+            blk.nheads // blk.kv_head_count * (self.spec_k + 1),
+            arr.shape[2] // tp, self.max_blocks, arr.shape[3],
+            arr.shape[3] * arr.shape[4] * arr.dtype.itemsize, self.kv_quant)
+        return {"kv_heads_per_step": hb, "kv_tile_blocks": T}
 
     def _dispatch(self, fn: Callable, args: Tuple[Any, ...]) -> Tuple[Any, ...]:
         """One call of a compiled step.  The call consumes the pool (and a

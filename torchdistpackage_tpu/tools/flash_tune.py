@@ -16,18 +16,19 @@ or CLI: ``python -m torchdistpackage_tpu.tools.flash_tune --seq 2048``.
 
 ``--paged`` tunes the paged decode-attention kernel instead
 (ops/paged_attention.py): the candidates are ``fetch_width`` (pool blocks
-streamed per grid step — how wide the in-kernel table walk fetches
-relative to the pool ``block_size``) and ``q_pad_to`` (the q-row padding
-multiple; the speculative K+1 verify shape lands at awkward row counts),
-timed at BOTH decode-program shapes — ``S_in=1`` ordinary decode and
-``S_in=K+1`` spec verify — so one (fetch_width, q_pad_to) row serves both,
-and, where the shape names a prefill chunk, at its shape too, whose
-time the report prints beside (``chunk_rel``: the same row serves
-``paged_chunk``, and must not cost it).  ``hb`` is the KV heads a grid step
-of the decode shape carries, which the kernel computes from the shape.
-``--shape mistral7b.decode`` is the benchmark cell's geometry.
-``_PAGED_PARAMS`` in ops/paged_attention.py is the consumer of a measured
-row.
+fetched at a time: the blocks of one KEY TILE where the kernel walks a
+slot's live blocks itself, decode and verify; the blocks a grid step
+streams where the grid walks the table's columns, a prefill chunk) and
+``q_pad_to`` (the q-row padding multiple; the speculative K+1 verify shape
+lands at awkward row counts), timed at BOTH decode-program shapes —
+``S_in=1`` ordinary decode and ``S_in=K+1`` spec verify — and, where the
+shape names a prefill chunk, at its shape too, whose time the report prints
+beside (``chunk_rel``).  ``hb`` and ``T`` are the KV heads a program of the
+decode shape carries and the key tile the kernel would choose on its own,
+which it computes from the shape (``decode_walk``).  ``--shape
+mistral7b.decode`` and ``--shape zaya1.reason`` are the benchmark cells'
+geometries.  ``_KV_TILE_KEYS`` and ``_PAGED_PARAMS`` in
+ops/paged_attention.py are the consumers of a measured row.
 
 Timing chains the iterations through a data dependency and fetches a scalar
 at the end, so the clock stops after the device has finished.
@@ -143,15 +144,19 @@ def tune_flash_blocks(
 
 #: (fetch_width, q_pad_to) candidates for the paged decode kernel;
 #: fetch_width is clamped to the table width per shape.  1, 2, 3 and 6
-#: divide or cover the benchmark cell's six table columns.
+#: divide or cover ``mistral7b.decode``'s six table columns, 4, 5, 10 and
+#: 20 ``zaya1.reason``'s twenty.
 PAGED_CANDIDATES: Tuple[Tuple[int, int], ...] = (
     (1, 8),
     (2, 8),
     (3, 8),
     (4, 8),
+    (5, 8),
     (6, 8),
     (8, 8),
-    (3, 16),
+    (10, 8),
+    (20, 8),
+    (4, 16),
     (6, 16),
 )
 
@@ -159,10 +164,16 @@ PAGED_CANDIDATES: Tuple[Tuple[int, int], ...] = (
 #: ``mistral7b.decode``: the benchmark cell's engine (64 slots, GQA 32 / 8
 #: x 128, block 128, ``max_ctx`` 768, chunk 256 at 8 slots a prefill call,
 #: a bf16 pool of 385 blocks), slots 10-70% full (mean ~300 tokens).
+#: ``zaya1.reason``: 64 slots, 8 / 2 heads x 128, block 128, ``max_ctx``
+#: 2,560 (20 columns), a bf16 pool of 1,281 blocks, contexts 256-2,560.
 PAGED_SHAPES = {
     "mistral7b.decode": dict(
         num_slots=64, kv_heads=8, groups=4, head_dim=128, block_size=128,
         max_blocks=6, spec_k=2, chunk=256, chunk_slots=8, fill=(0.1, 0.7),
+        dtype="bfloat16"),
+    "zaya1.reason": dict(
+        num_slots=64, kv_heads=2, groups=4, head_dim=128, block_size=128,
+        max_blocks=20, spec_k=2, chunk=256, chunk_slots=8, fill=(0.1, 1.0),
         dtype="bfloat16"),
 }
 
@@ -223,13 +234,13 @@ def tune_paged_params(
     max context a slot holds, drawn uniformly between the two), q at
     S_in=1 (decode) AND S_in=spec_k+1 (the verify program); with ``chunk``,
     also ``chunk_slots`` slots' prefill chunk.  Returns ``(best, report)``
-    with ``report`` rows ``{"fetch_width", "q_pad_to", "hb", "ms",
+    with ``report`` rows ``{"fetch_width", "q_pad_to", "hb", "T", "ms",
     "decode_ms", "verify_ms"[, "chunk_ms", "chunk_rel"], "rel"}`` sorted
     fastest-first by ``ms`` = decode + verify; ``chunk_rel`` is the chunk's
     time over the fastest chunk's."""
     import numpy as np
 
-    from ..ops.paged_attention import _heads_per_step
+    from ..ops.paged_attention import call_walk
 
     dtype = jnp.dtype(dtype)
     nb = max_blocks * num_slots + 1
@@ -262,10 +273,10 @@ def tune_paged_params(
     for fw, pad in candidates:
         if fw > max_blocks:
             continue
-        row = {"fetch_width": fw, "q_pad_to": pad,
-               "hb": _heads_per_step(
-                   kv_heads, -(-groups // pad) * pad, fw,
-                   block_size * head_dim * dtype.itemsize)}
+        _rows, _fw, hb, T = call_walk(
+            groups, kv_heads, max_blocks, block_size,
+            block_size * head_dim * dtype.itemsize, q_pad_to=pad)
+        row = {"fetch_width": fw, "q_pad_to": pad, "hb": hb, "T": T}
         try:
             for name, (slots, s_in, offs) in shapes.items():
                 row[f"{name}_ms"] = 1e3 * _time_paged_config(
