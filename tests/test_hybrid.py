@@ -423,6 +423,34 @@ def test_one_sampling_request_among_greedy_ones(toy, run_ahead):
     assert not np.array_equal(every[1]["tokens"], greedy[1]["tokens"])
 
 
+def test_call_ties_a_run_ahead_fetch_to_the_dispatch_of_the_tick_before(toy):
+    """With ``run_ahead`` a tick's fetch waits for the decode call that the
+    tick BEFORE dispatched: the ``call`` attr says which, so that a reader
+    of the spans need not guess (benchmarks/layer_metrics/idle_by_phase.py)."""
+    from torchdistpackage_tpu.utils import spans
+
+    spans.clear()
+    eng = _serve(toy, True)
+    ring = spans.snapshot()
+    ticks = {r[0]: r[5]["tick"] for r in ring if r[2] == "tdp:engine.tick"}
+    made = {r[5]["call"]: (r[2], ticks[r[1]]) for r in ring
+            if r[2] in ("tdp:engine.prefill", "tdp:engine.decode")}
+    # a running count: every device call of the engine once (a prefill
+    # span names its last)
+    assert max(made) == (eng.stats["prefill_calls"]
+                         + eng.stats["decode_steps"])
+    behind = 0
+    for r in ring:
+        if r[2] != "tdp:engine.fetch":
+            continue
+        kind, tick = made[r[5]["call"]]
+        assert tick <= ticks[r[1]]
+        if kind == "tdp:engine.prefill":
+            assert tick == ticks[r[1]]   # a prefill call is fetched at once
+        behind += tick < ticks[r[1]]
+    assert behind >= 5   # decode calls: fetched a tick (or an idle poll) on
+
+
 def test_run_ahead_drops_the_token_in_flight_of_a_cancelled_request(toy):
     got = _serve(toy, True, cancel_at=5)
     want = _serve(toy, False, cancel_at=5)

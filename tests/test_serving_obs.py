@@ -238,6 +238,23 @@ def test_phase_table_renders():
     for name in TICK_PHASES:
         assert any(ln.strip().startswith(name) for ln in table.splitlines())
     assert phase_table([]) == "tick phase breakdown: no engine_tick records"
+    # under the phases, the time lost to stalls: the stream's two pauses
+    # between ticks (4.0 -> 5.0 s preempted, 6.0 -> 7.1 s restarting)
+    assert table.splitlines()[-2:] == [
+        "  stalls: 2 slow ticks of 8 lost 2.100 s",
+        "    in fetch (the device) 0.000 s, anywhere else (the host) 2.100 s"]
+    # tick 3's fetch takes 0.6 s longer
+    slow = [dict(e) for e in _synthetic_stream()]
+    ticks = [e for e in slow if e.get("kind") == "engine_tick"]
+    ticks[2]["spans"] = [[n, a, b + (0.6 if n.endswith(".fetch") else 0.0)]
+                         for n, a, b in ticks[2]["spans"]]
+    ticks[2]["tick_s"] += 0.6
+    for e in ticks[3:]:
+        e["t_start"] += 0.6
+        e["spans"] = [[n, a + 0.6, b + 0.6] for n, a, b in e["spans"]]
+    assert phase_table(slow).splitlines()[-2:] == [
+        "  stalls: 3 slow ticks of 8 lost 2.700 s",
+        "    in fetch (the device) 0.600 s, anywhere else (the host) 2.100 s"]
 
 
 # ----------------------------------------------- serving.slo validation

@@ -89,6 +89,7 @@ from .tracing import (
     fleet_trace_events,
     lifecycle_phases,
     phase_table,
+    stalls,
     request_trace_events,
     serving_metrics_record,
     serving_trace_events,
@@ -143,6 +144,7 @@ __all__ = [
     "assemble_request_timelines",
     "lifecycle_phases",
     "phase_table",
+    "stalls",
     "request_trace_events",
     "serving_metrics_record",
     "serving_trace_events",

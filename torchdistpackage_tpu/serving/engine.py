@@ -1448,6 +1448,13 @@ class ServingEngine:
 
     # -------------------------------------------------------------------- ticks
 
+    #: device calls dispatched so far, an engine's own count from 0: the
+    #: ``call`` attr that ties a dispatch span to the ``tdp:engine.fetch``
+    #: that waits for it.  (Set here and not in ``__init__``: the line
+    #: numbers above the step builders are part of every compiled kernel's
+    #: cache key, PERF.md section 6, PR 34.)
+    _call = 0
+
     def _masked(self, state: str) -> np.ndarray:
         """Table rows for slots NOT in ``state`` zeroed (NULL block) so a
         phase's step can never touch another phase's cache blocks."""
@@ -1551,22 +1558,30 @@ class ServingEngine:
         sampled tokens and the advanced keys by SLOT index (``[num_slots]``;
         rows of slots not in ``pre`` are zero)."""
         C = self.chunk
-        batches = self._prefill_batches(pre)
-        real = sum(min(C, len(self._slots[i].prompt) - self._slots[i].off)
-                   for i in pre)
-        rids = [self._slots[i].rid for i in pre]
-        first = self._first_call("prefill", batches[0][1][0])
+        with span("tdp:engine.build"):
+            batches = self._prefill_batches(pre)
+            first = self._first_call("prefill", batches[0][1][0])
+            self._call += len(batches)
+            # tokens: the real prompt tokens of this tick's slices; rows:
+            # what the compiled calls compute, padding included; call: the
+            # LAST of the `calls` device calls this span dispatches, by the
+            # engine's running count (the fetch that waits for them says
+            # the same); state_slots (a state model): the slots whose state
+            # the calls gather and scatter; sampled_rows: the slots that
+            # asked for temperature > 0 (0: every call took _slot_sample's
+            # greedy branch, no sort and no draw)
+            attrs = dict(
+                tokens=sum(
+                    min(C, len(self._slots[i].prompt) - self._slots[i].off)
+                    for i in pre),
+                calls=len(batches), call=self._call,
+                rows=sum(args[0].size for _, args in batches),
+                sampled_rows=np.count_nonzero(self._temps[pre] > 0),
+                rids=self._tick_prefill_rids, **first)
+            if self.state_model:
+                attrs["state_slots"] = len(pre)
         outs = []
-        # tokens: the real prompt tokens of this tick's slices; rows: what
-        # the compiled calls compute, padding included; state_slots (a
-        # state model): the slots whose state the calls gather and scatter;
-        # sampled_rows: the slots that asked for temperature > 0 (0: every
-        # call took _slot_sample's greedy branch, no sort and no draw)
-        state_attr = {"state_slots": len(pre)} if self.state_model else {}
-        with span("tdp:engine.prefill", tokens=real, calls=len(batches),
-                  rows=sum(args[0].size for _, args in batches),
-                  sampled_rows=np.count_nonzero(self._temps[pre] > 0),
-                  rids=rids, **state_attr, **first):
+        with span("tdp:engine.prefill", **attrs):
             for _, args in batches:
                 if outs:
                     # one call in flight.  The wait dates from a step that
@@ -1580,7 +1595,7 @@ class ServingEngine:
                 outs.append(self._dispatch(self._step_fn, args))
         tok = np.zeros(self.num_slots, np.int32)
         keys = np.zeros_like(self._keys)
-        with span("tdp:engine.fetch", **first):
+        with span("tdp:engine.fetch", call=self._call, **first):
             for (slot_of, args), out in zip(batches, outs):
                 live = slot_of >= 0
                 tok[slot_of[live]] = np.asarray(out[0])[live]
@@ -1593,19 +1608,24 @@ class ServingEngine:
                         self._slots[slot_of[r]].routing.append(
                             routing[r, :n_valid[r]])
         self.stats["prefill_calls"] += len(batches)
-        self._tick_prefill_rids = rids
+        return tok, keys
+
+    def _book_prefill(self, n_calls: int) -> None:
+        """The events of a tick's ``n_calls`` prefill calls, once their
+        tokens are on the host."""
+        C, rids = self.chunk, self._tick_prefill_rids
         self._ev.emit("prefill_chunk", rids=rids, chunk=C, n_slots=len(rids),
-                      calls=len(batches))
+                      calls=n_calls)
         if self.cp > 1:
             # modeled ring accounting (host math, ops/ring_paged.py): each
             # compiled call issued 4*(cp-1) unrolled ppermutes per layer
             # — the comm-ledger test prices the same count from HLO
             from ..ops.ring_paged import ring_chunk_bytes, ring_hops_per_chunk
 
-            hops = len(batches) * ring_hops_per_chunk(self.cfg.nlayers, self.cp)
-            bts = len(batches) * ring_chunk_bytes(
+            hops = n_calls * ring_hops_per_chunk(self.cfg.nlayers, self.cp)
+            bts = n_calls * ring_chunk_bytes(
                 nlayers=self.cfg.nlayers, cp=self.cp,
-                batch=len(batches[0][0]),
+                batch=self.dp * self.prefill_width,
                 kv_heads=self.cfg.block.kv_head_count,
                 head_dim=self.cfg.block.head_dim, chunk=C,
                 nb_local=self.num_blocks // self.cp,
@@ -1617,7 +1637,6 @@ class ServingEngine:
                           cp=self.cp, sub_chunk=C // self.cp)
             self._ev.emit("cp_ring_hop", tick=self._tick, hops=hops,
                           bytes=bts)
-        return tok, keys
 
     def _prefill_tick(self) -> int:
         """One ``chunk``-token slice for EVERY prefilling slot
@@ -1628,7 +1647,21 @@ class ServingEngine:
         if not pre:
             return 0
         C = self.chunk
+        self._tick_prefill_rids = [self._slots[i].rid for i in pre]
+        # how many calls it took, by the engine's own count (a test stands
+        # another function of the same two results in for _prefill_calls)
+        calls_before = self.stats["prefill_calls"]
         tok, keys = self._prefill_calls(pre)
+        with span("tdp:engine.absorb"):
+            self._book_prefill(self.stats["prefill_calls"] - calls_before)
+            self._walk_prefilled(pre, tok, keys)
+        self.stats["prefill_chunks"] += 1
+        return len(pre)
+
+    def _walk_prefilled(self, pre: List[int], tok: np.ndarray,
+                        keys: np.ndarray) -> None:
+        """Book one fetched prefill slice a slot of ``pre``."""
+        C = self.chunk
         if self.chaos is not None:
             tok = self.chaos.perturb_engine_tokens(self._tick, tok)
         now = time.perf_counter()
@@ -1660,8 +1693,6 @@ class ServingEngine:
                 s.generated.append(int(tok[i]))
                 self._tick_emitted += 1
                 self._maybe_retire(i, int(tok[i]), now)
-        self.stats["prefill_chunks"] += 1
-        return len(pre)
 
     def _decode_tick(self) -> int:
         if self.hold_decode:
@@ -1670,8 +1701,40 @@ class ServingEngine:
             return 0
         if self.spec_k:
             return self._spec_decode_tick()
+        flight = self._flight
+        with span("tdp:engine.build"):
+            built = self._build_decode(flight)
+        if built is None:
+            self._absorb_decode(flight)
+            return 0
+        args, slots, attrs = built
+        with span("tdp:engine.decode", **attrs):
+            out = self._dispatch(self._decode_fn, args)
+        self.stats["decode_steps"] += 1
+        self.stats["decode_slot_steps"] += len(slots)
+        # what the fetch span says of the call it waits for
+        call = {"out": out, "slots": slots,
+                "waits_for": {k: attrs[k] for k in ("call", "first")
+                              if k in attrs}}
+        if self.run_ahead:
+            # the call before this one: the device has it done, or nearly
+            self._absorb_decode(flight)
+            self._flight = call
+        else:
+            self._absorb_decode(call)
+        return len(slots)
+
+    def _build_decode(self, flight: Optional[Dict[str, Any]]) -> Optional[
+            Tuple[Tuple[Any, ...], List[Tuple[int, Tuple[int, float]]],
+                  Dict[str, Any]]]:
+        """The decode call's host side: ``(the compiled call's arguments,
+        the decoding slots as (slot, (rid, t_admit)), the dispatch span's
+        attrs)``; None when no slot decodes this tick.  ``call`` among the
+        attrs is this device call by the engine's running count: the
+        ``tdp:engine.fetch`` that waits for it (with ``run_ahead`` in the
+        NEXT tick) carries the same."""
         mask, tables = self._masked(DECODE)
-        flight, ahead = self._flight, np.zeros(self.num_slots, bool)
+        ahead = np.zeros(self.num_slots, bool)
         if flight is not None:
             # run_ahead: the slots whose newest token is still on the device
             for i, who in flight["slots"]:
@@ -1684,8 +1747,7 @@ class ServingEngine:
                     ahead[i] = True
         n_active = int(mask.sum())
         if n_active == 0:
-            self._absorb_decode(flight)
-            return 0
+            return None
         tokens = np.where(mask & ~ahead, self._last_tok,
                           0).astype(np.int32)[:, None]
         offsets = np.where(mask, self._lengths + ahead, 0).astype(np.int32)
@@ -1693,7 +1755,6 @@ class ServingEngine:
         slots = [(int(i), (self._slots[i].rid, self._slots[i].t_admit))
                  for i in np.flatnonzero(mask)]
         self._tick_decode_rids = [who[0] for _, who in slots]
-        first = self._first_call("decode", tokens)
         args = (tokens, tables, offsets, last_idx, self._samp(), self._keys)
         if self.state_model:
             # row b is slot b: the state is updated where it lies, and a
@@ -1702,21 +1763,12 @@ class ServingEngine:
         if self.run_ahead:
             args += ((flight or self._no_flight)["out"][:2]
                      + (ahead.astype(np.int32),),)
-        with span("tdp:engine.decode", slots=n_active,
-                  rids=self._tick_decode_rids,
-                  live_tokens=int(offsets.sum()) + n_active,
-                  sampled_rows=np.count_nonzero(self._temps > 0), **first):
-            out = self._dispatch(self._decode_fn, args)
-        self.stats["decode_steps"] += 1
-        self.stats["decode_slot_steps"] += n_active
-        call = {"out": out, "slots": slots, "first": first}
-        if self.run_ahead:
-            # the call before this one: the device has it done, or nearly
-            self._absorb_decode(flight)
-            self._flight = call
-        else:
-            self._absorb_decode(call)
-        return n_active
+        self._call += 1
+        attrs = dict(slots=n_active, rids=self._tick_decode_rids,
+                     live_tokens=int(offsets.sum()) + n_active,
+                     sampled_rows=np.count_nonzero(self._temps > 0),
+                     call=self._call, **self._first_call("decode", tokens))
+        return args, slots, attrs
 
     def _absorb_decode(self, call: Optional[Dict[str, Any]]) -> None:
         """Fetch what one decode call returned and book it: every slot's
@@ -1727,34 +1779,35 @@ class ServingEngine:
             return
         self._flight = None
         out = call["out"]
-        with span("tdp:engine.fetch", **call["first"]):
+        with span("tdp:engine.fetch", **call["waits_for"]):
             tok = np.asarray(out[0])
             keys = np.asarray(out[1])
             if len(out) > 2:  # expert layers: live load stats ride along
                 self._absorb_moe_stats(*out[2:5], decode=True)
             routing = np.asarray(out[5]) if len(out) > 5 else None
-        if self.telemetry is not None:
-            self.telemetry.end_step(active_slots=len(call["slots"]))
-        if self.chaos is not None:
-            tok = self.chaos.perturb_engine_tokens(self._tick, tok)
-        now = time.perf_counter()
-        for i, who in call["slots"]:
-            s = self._slots[i]
-            if s.state != DECODE or (s.rid, s.t_admit) != who:
-                continue
-            if routing is not None:  # record_routing
-                s.routing.append(routing[i])
-            if self._token_poisoned(int(tok[i])):
-                self._poisoned_token_recover(i, int(tok[i]))
-                continue
-            self._keys[i] = keys[i]
-            self._lengths[i] += 1
-            self._last_tok[i] = tok[i]
-            s.generated.append(int(tok[i]))
-            self._tick_emitted += 1
-            s.tpot_s.append(now - s.t_last)
-            s.t_last = now
-            self._maybe_retire(i, int(tok[i]), now)
+        with span("tdp:engine.absorb"):
+            if self.telemetry is not None:
+                self.telemetry.end_step(active_slots=len(call["slots"]))
+            if self.chaos is not None:
+                tok = self.chaos.perturb_engine_tokens(self._tick, tok)
+            now = time.perf_counter()
+            for i, who in call["slots"]:
+                s = self._slots[i]
+                if s.state != DECODE or (s.rid, s.t_admit) != who:
+                    continue
+                if routing is not None:  # record_routing
+                    s.routing.append(routing[i])
+                if self._token_poisoned(int(tok[i])):
+                    self._poisoned_token_recover(i, int(tok[i]))
+                    continue
+                self._keys[i] = keys[i]
+                self._lengths[i] += 1
+                self._last_tok[i] = tok[i]
+                s.generated.append(int(tok[i]))
+                self._tick_emitted += 1
+                s.tpot_s.append(now - s.t_last)
+                s.t_last = now
+                self._maybe_retire(i, int(tok[i]), now)
 
     # ------------------------------------------------------ speculative decode
 
@@ -1797,13 +1850,14 @@ class ServingEngine:
         ``speculative_generate`` argument).  Emits 1..k+1 tokens per slot
         per tick at one decode-signature — the decode latency floor
         broken without touching the compile-once contract."""
-        mask, tables = self._masked(DECODE)
-        n_active = int(mask.sum())
+        K = self.spec_k
+        with span("tdp:engine.build"):
+            mask, tables = self._masked(DECODE)
+            n_active = int(mask.sum())
+            tokens = np.zeros((self.num_slots, K + 1), np.int32)
+            offsets = np.where(mask, self._lengths, 0).astype(np.int32)
         if n_active == 0:
             return 0
-        K = self.spec_k
-        tokens = np.zeros((self.num_slots, K + 1), np.int32)
-        offsets = np.where(mask, self._lengths, 0).astype(np.int32)
         rids = []
         with span("tdp:engine.draft"):
             for i, s in enumerate(self._slots):
@@ -1812,17 +1866,32 @@ class ServingEngine:
                 rids.append(s.rid)
                 tokens[i, 0] = self._last_tok[i]
                 tokens[i, 1:] = self._draft(s)
-        self._tick_decode_rids = rids
-        self._ev.emit("spec_draft", k=K, n_slots=len(rids), rids=rids)
-        first = self._first_call("decode", tokens)
-        with span("tdp:engine.decode", slots=n_active, rids=rids, **first):
+        with span("tdp:engine.build"):  # the draft is a phase of its own
+            self._tick_decode_rids = rids
+            self._ev.emit("spec_draft", k=K, n_slots=len(rids), rids=rids)
+            self._call += 1
+            waits_for = {"call": self._call,
+                         **self._first_call("decode", tokens)}
+            samp = self._samp()
+        with span("tdp:engine.decode", slots=n_active, rids=rids,
+                  **waits_for):
             self.cache, verify, accept, keys = self._verify_fn(
-                self.params, self.cache, tokens, tables, offsets,
-                self._samp(), self._keys)
-        with span("tdp:engine.fetch", **first):
+                self.params, self.cache, tokens, tables, offsets, samp,
+                self._keys)
+        with span("tdp:engine.fetch", **waits_for):
             verify = np.asarray(verify)
             accept = np.asarray(accept)
             keys = np.asarray(keys)
+        with span("tdp:engine.absorb"):
+            self._walk_verified(tokens, verify, accept, keys, n_active)
+        return n_active
+
+    def _walk_verified(self, tokens: np.ndarray, verify: np.ndarray,
+                       accept: np.ndarray, keys: np.ndarray,
+                       n_active: int) -> None:
+        """Book one fetched verify call: every decoding slot's accepted
+        draft prefix and the model's own token behind it."""
+        K, rids = self.spec_k, self._tick_decode_rids
         if self.telemetry is not None:
             self.telemetry.end_step(active_slots=n_active)
         if self.chaos is not None:
@@ -1875,7 +1944,6 @@ class ServingEngine:
                       emitted=emitted_total, accepted=accepted_total)
         self.stats["decode_steps"] += 1
         self.stats["decode_slot_steps"] += n_active
-        return n_active
 
     # --------------------------------------------------------------- retirement
 
@@ -2100,14 +2168,17 @@ class ServingEngine:
         (``tdp:engine.audit`` / ``sched`` / ``prefill`` / ``draft`` /
         ``decode`` / ``fetch``; utils/profiling.py: the process-wide ring,
         and the profiler's clock under a capture).  Their summed durations
-        are the :data:`TICK_PHASES` accounting (``host`` is the remainder)
-        recorded on ``tick_records``, emitted as an ``engine_tick``
-        timeline event (with the measured ``spans`` and per-rid
-        attribution, the raw material of the request-lifecycle trace —
-        serving/tracing.py), and exported live through ``metrics_sink``
-        under the ``serving_metrics`` schema.  All of it is wall-clock
-        bookkeeping around the SAME two compiled calls: zero extra
-        device dispatches, ``decode_signatures`` stays 1."""
+        are the :data:`TICK_PHASES` accounting (``host`` is the remainder);
+        three more children divide that remainder in the ring and are no
+        phases (``tdp:engine.build`` before a dispatch, ``absorb`` from a
+        fetch's end to the end of the slot walk, ``record`` behind the
+        decode phase).  The accounting is recorded on ``tick_records``,
+        emitted as an ``engine_tick`` timeline event (with the measured
+        ``spans`` and per-rid attribution, the raw material of the
+        request-lifecycle trace — serving/tracing.py), and exported live
+        through ``metrics_sink`` under the ``serving_metrics`` schema.  All
+        of it is wall-clock bookkeeping around the SAME two compiled calls:
+        zero extra device dispatches, ``decode_signatures`` stays 1."""
         with span("tdp:engine.tick", tick=self._tick + 1) as tick:
             self._tick += 1
             self._tick_prefill_rids = []
@@ -2124,28 +2195,32 @@ class ServingEngine:
                 admitted = self._admit()
             prefilled = self._prefill_tick()
             decoded = self._decode_tick()
-            busy = self.n_busy
-            if not busy:
-                self._flight = None  # run_ahead: nobody is left to take it
-            self._occ_sum += busy / self.num_slots
-            util = float(np.mean([a.utilization() for a in self._allocs]))
-            self._util_sum += util
-            self._occ_ticks += 1
-            if self.snapshot_every and self._tick % self.snapshot_every == 0:
-                self._ev.emit(
-                    "slots_snapshot", tick=self._tick, busy=busy,
-                    queued=len(self.queue), pool_utilization=round(util, 4))
-            if self.watchdog is not None:
-                self.watchdog.beat(self._tick)
-            t_end = time.perf_counter()
-            if decoded:
-                dt = t_end - tick.t0
-                self._tick_ewma = (
-                    dt if self._tick_ewma is None
-                    else 0.8 * self._tick_ewma + 0.2 * dt)
-            self._record_tick(tick, t_end, admitted=admitted,
-                              expired=expired, prefilled=prefilled,
-                              decoded=decoded, busy=busy, util=util)
+            with span("tdp:engine.record"):
+                busy = self.n_busy
+                if not busy:
+                    self._flight = None  # run_ahead: nobody left to take it
+                self._occ_sum += busy / self.num_slots
+                util = float(np.mean(
+                    [a.utilization() for a in self._allocs]))
+                self._util_sum += util
+                self._occ_ticks += 1
+                if (self.snapshot_every
+                        and self._tick % self.snapshot_every == 0):
+                    self._ev.emit(
+                        "slots_snapshot", tick=self._tick, busy=busy,
+                        queued=len(self.queue),
+                        pool_utilization=round(util, 4))
+                if self.watchdog is not None:
+                    self.watchdog.beat(self._tick)
+                t_end = time.perf_counter()
+                if decoded:
+                    dt = t_end - tick.t0
+                    self._tick_ewma = (
+                        dt if self._tick_ewma is None
+                        else 0.8 * self._tick_ewma + 0.2 * dt)
+                self._record_tick(tick, t_end, admitted=admitted,
+                                  expired=expired, prefilled=prefilled,
+                                  decoded=decoded, busy=busy, util=util)
         return {"admitted": admitted, "prefill_slots": prefilled,
                 "decode_slots": decoded, "busy": busy, "expired": expired}
 
@@ -2154,9 +2229,10 @@ class ServingEngine:
                      util: float) -> None:
         """The tick-level accounting record: the phase decomposition,
         summed from the tick's child spans (the residual ``host`` phase is
-        everything they did not cover — queue sorts, table rewrites,
-        retirement walks, the telemetry's record), plus the per-tick
-        gauges.  Appended to ``tick_records`` (bounded), emitted
+        everything the six phase spans did not cover — queue sorts, table
+        rewrites, retirement walks, the telemetry's record; the ``build``,
+        ``absorb`` and ``record`` children time it piece by piece and are
+        no phases), plus the per-tick gauges.  Appended to ``tick_records`` (bounded), emitted
         as an ``engine_tick`` event WHEN THE TICK DID WORK (idle polls
         stay off the timeline), and written to ``metrics_sink`` every
         ``metrics_every`` ticks under :data:`SERVING_METRICS_SCHEMA`."""

@@ -56,12 +56,16 @@ dicts; no device call, no new compiled program — the engine's
   watch while the engine runs).
 - **Operator table** (:func:`phase_table`) — the per-tick phase
   breakdown as text, printed by ``decode_bench --serve --trace`` next
-  to the latency tables.
+  to the latency tables, with the time lost to stalls under it.
+- **Stalls by phase** (:func:`stalls`) — which ticks (or gaps between two
+  ticks) took far longer than their kind does, how much time that lost,
+  and in which child span of the tick the excess lies.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, List, Optional, Sequence
+import statistics
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 #: Schema tag on every ``metrics_sink`` record (docs/serving.md
 #: "Serving observability" documents the fields).
@@ -78,7 +82,9 @@ SERVING_METRICS_SCHEMA = "tdp-serving-metrics/v1"
 #: only) runs between them; ``fetch`` is the wait for the device, the
 #: device->host transfer of the sampled tokens and nothing else; ``host``
 #: is the remainder of the tick (the walk after the fetch, array building,
-#: the telemetry's record).
+#: the telemetry's record).  The engine's ``tdp:engine.build`` / ``absorb``
+#: / ``record`` spans time that remainder piece by piece in the ring; they
+#: are NOT phases, and ``host`` stays "the tick outside the six above".
 TICK_PHASES = ("audit", "sched", "prefill", "draft", "decode", "fetch",
                "host")
 
@@ -789,14 +795,82 @@ def fleet_trace_events(
     return out
 
 
+# ------------------------------------------------------------------ stalls
+
+#: a tick's child span that DISPATCHES a compiled prefill call (a tick with
+#: one is of another kind, and length, than a decode-only tick), and the
+#: one that waits for the device
+PREFILL_SPAN, FETCH_SPAN = "tdp:engine.prefill", "tdp:engine.fetch"
+#: where :func:`stalls` credits time that no child span of the tick covers,
+#: and the time between the tick before's end and this tick's start
+UNCOVERED, BETWEEN_TICKS = "(uncovered)", "(between ticks)"
+#: a tick is slow when it takes more than this many times its kind's median
+#: AND this many seconds more than it
+STALL_FACTOR, STALL_FLOOR_S = 2.0, 0.020
+
+
+def stalls(ticks: Sequence[Tuple[float, float]],
+           children: Sequence[Sequence[Tuple[str, float, float]]],
+           ) -> Dict[str, Any]:
+    """Time lost to stalls over a run of consecutive ticks, by the phase
+    that held it.  ``ticks[i]`` is ``(start, end)`` of one
+    ``tdp:engine.tick`` and ``children[i]`` its child spans as ``(name,
+    start, end)`` (the ring's records, or an ``engine_tick`` event's
+    ``t_start`` / ``t_end`` / ``spans``), in time order, one clock.
+
+    A tick is taken WITH the gap before it (the caller's loop between two
+    ticks; none before the first) and is of one of two kinds: with a
+    prefill call (:data:`PREFILL_SPAN` among its children) or without.  It
+    is slow when gap + tick take more than :data:`STALL_FACTOR` times the
+    median of its kind and :data:`STALL_FLOOR_S` more than it; it then lost
+    its time less that median, and the whole loss is credited to the ONE
+    part (a child span's name, its durations summed; :data:`UNCOVERED`;
+    :data:`BETWEEN_TICKS`) that exceeds its own median of that kind by
+    most.  Returns ``{"lost_s", "slow", "by_part": {part: seconds lost},
+    "ticks"}``; a wait for the device is ``by_part[FETCH_SPAN]``."""
+    parts: List[Dict[str, float]] = []
+    kinds: List[bool] = []
+    for i, ((t0, t1), kids) in enumerate(zip(ticks, children)):
+        covered: Dict[str, float] = {}
+        for name, c0, c1 in kids:
+            covered[name] = covered.get(name, 0.0) + (c1 - c0)
+        parts.append({
+            BETWEEN_TICKS: max(0.0, t0 - ticks[i - 1][1]) if i else 0.0,
+            **covered,
+            UNCOVERED: max(0.0, (t1 - t0) - sum(covered.values()))})
+        kinds.append(any(k[0] == PREFILL_SPAN for k in kids))
+    by_part: Dict[str, float] = {}
+    slow = 0
+    for kind in (False, True):
+        mine = [p for p, k in zip(parts, kinds) if k == kind]
+        if not mine:
+            continue
+        median = statistics.median(sum(p.values()) for p in mine)
+        part_median = {
+            name: statistics.median(p.get(name, 0.0) for p in mine)
+            for name in {n for p in mine for n in p}}
+        for p in mine:
+            took = sum(p.values())
+            if (took <= STALL_FACTOR * median
+                    or took <= median + STALL_FLOOR_S):
+                continue
+            slow += 1
+            worst = max(p, key=lambda n: p[n] - part_median[n])
+            by_part[worst] = by_part.get(worst, 0.0) + (took - median)
+    return {"lost_s": sum(by_part.values()), "slow": slow,
+            "by_part": by_part, "ticks": len(parts)}
+
+
 # ---------------------------------------------------------- operator table
 
 
 def phase_table(events: Iterable[Dict[str, Any]]) -> str:
     """Text table of the per-tick phase breakdown over ``engine_tick``
     records — totals, mean ms, and share of accounted tick time per
-    phase.  ``decode_bench --serve --trace`` prints it next to the
-    latency tables."""
+    phase — and under it the time :func:`stalls` finds lost in slow
+    ticks: in the wait for the device (``fetch``) and anywhere else.
+    ``decode_bench --serve --trace`` prints it next to the latency
+    tables."""
     ticks = [e for e in events if e.get("kind") == "engine_tick"]
     if not ticks:
         return "tick phase breakdown: no engine_tick records"
@@ -818,4 +892,13 @@ def phase_table(events: Iterable[Dict[str, Any]]) -> str:
             f"  {name:<9} {totals[name] * 1e3:>10.2f} "
             f"{(totals[name] / n * 1e3 if n else 0.0):>9.3f} "
             f"{n:>6} {totals[name] / accounted:>6.1%}")
+    timed = [e for e in ticks if "t_start" in e and "tick_s" in e]
+    lost = stalls([(e["t_start"], e["t_start"] + e["tick_s"]) for e in timed],
+                  [e.get("spans") or () for e in timed])
+    in_fetch = lost["by_part"].get(FETCH_SPAN, 0.0)
+    lines += [
+        f"  stalls: {lost['slow']} slow ticks of {lost['ticks']} lost "
+        f"{lost['lost_s']:.3f} s",
+        f"    in fetch (the device) {in_fetch:.3f} s, anywhere else "
+        f"(the host) {lost['lost_s'] - in_fetch:.3f} s"]
     return "\n".join(lines)
