@@ -9,6 +9,9 @@ sampling, compile-once) rides the same tiny per-family bundles so the
 whole file costs a handful of compiled programs, not one per test.
 """
 
+import dataclasses
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +30,8 @@ from torchdistpackage_tpu.models import (
     llama_config,
 )
 from torchdistpackage_tpu.obs.events import EventLog, set_default_event_log
-from torchdistpackage_tpu.serving.engine import _filtered_logits, _slot_sample
+from torchdistpackage_tpu.serving.engine import (
+    PREFILL_WIDTH, _filtered_logits, _slot_sample)
 from torchdistpackage_tpu.serving import (
     BlockAllocator,
     NULL_BLOCK,
@@ -763,6 +767,168 @@ def test_compact_prefill_unequal_dp_groups(bundles, devices8):
     s = eng.serving_summary()
     assert s["decode_signatures"] == 1 and s["prefill_signatures"] == 1
     assert (s["prefill_chunks"], s["prefill_calls"]) == (2, 2 * calls)
+
+
+# -------------------------------------- the default width, family by family
+
+SLOTS8 = 8
+
+
+def _hybrid_toy(mod):
+    """A hybrid family at toy width in float32, from the TOY configuration
+    of the family's own test file: (the program's config, weights)."""
+    s = mod.family.shape(mod.TOY, 64)
+    cfg = dataclasses.replace(mod.family.program_config(mod.TOY, 64),
+                              dtype=jnp.float32)
+    return cfg, jax.tree.map(lambda a: a.astype(jnp.float32),
+                             mod.make_weights(s, 7))
+
+
+#: every family the engine serves: dense GQA, routed experts, and the state
+#: model's three shapes (recurrent state + held experts, latent attention +
+#: held gated experts, convolved attention with a tail a slot)
+SERVED = {"gqa": None, "moe": None, "state": "test_hybrid",
+          "latent": "test_sarvam_mla", "cca": "test_zaya"}
+
+
+@pytest.fixture(scope="module")
+def width_pairs():
+    """Per served family: an engine of 8 slots whose prefill calls have the
+    DEFAULT width, and the full-width reference, one call that carries all
+    8 slots; compiled once a module."""
+    cache = {}
+
+    def get(name):
+        if name not in cache:
+            if SERVED[name] is None:
+                cfg, params, kw = CFGS[name], _init(name), dict(
+                    block_size=4, chunk=4)
+            else:
+                cfg, params = _hybrid_toy(importlib.import_module(SERVED[name]))
+                kw = dict(block_size=8, chunk=8, max_ctx=64,
+                          attn_impl="gather")
+            eng, ref = (ServingEngine(params, cfg, num_slots=SLOTS8, **kw)
+                        for _ in "ab")
+            assert eng.prefill_width == PREFILL_WIDTH < SLOTS8
+            ref.prefill_width = SLOTS8
+            cache[name] = (cfg, eng, ref)
+        return cache[name]
+
+    return get
+
+
+def _wave_len(i, C):
+    """Prompt i of a first wave: 2 tokens up to just under two chunks."""
+    return 2 + (i * (2 * C - 3)) // 7
+
+
+def _serve_first_wave(eng, cfg):
+    """A first wave: as many prompts as slots, of one and of two chunks,
+    admitted in ONE tick.  Steps until nothing prefills, checks that every
+    slot then decodes over a clean pool, and returns every request's tokens
+    and each prefilling tick's ``tdp:engine.prefill`` attrs."""
+    eng.reset_metrics()
+    C = eng.chunk
+    rids = [eng.submit(Request(_prompt(cfg, 70 + i, _wave_len(i, C)), NEW + 3))
+            for i in range(SLOTS8)]
+    spans.clear()
+    eng.step()
+    eng.step()
+    assert [s.state for s in eng._slots] == ["decode"] * SLOTS8
+    assert eng.audit(heal=False)["ok"]
+    pre = [r[5] for r in spans.snapshot() if r[2] == "tdp:engine.prefill"]
+    _drain(eng)
+    assert eng.audit(heal=False)["ok"]
+    return [eng.finished[r]["tokens"] for r in rids], pre
+
+
+def _serve_one_by_one(eng, cfg):
+    """The steady case: prompts of one to three chunks admitted on
+    successive ticks, so a call carries one or two slots and padding."""
+    eng.reset_metrics()
+    C, rids = eng.chunk, []
+    for i, n in enumerate((2 * C + 1, C, C + 3, 3, 3 * C - 1)):
+        rids.append(eng.submit(Request(_prompt(cfg, 80 + i, n), NEW)))
+        eng.step()
+    _drain(eng)
+    return [eng.finished[r]["tokens"] for r in rids], []
+
+
+@pytest.mark.parametrize("family", list(SERVED))
+@pytest.mark.parametrize("scenario", ["first_wave", "one_by_one"])
+def test_default_width_matches_full_width_call(width_pairs, family, scenario):
+    """At ``PREFILL_WIDTH`` every served family's greedy tokens equal, token
+    for token, those of an engine whose one prefill call carries every
+    slot: a first wave of ``num_slots`` prompts (``ceil(n / W)`` calls a
+    tick, then every slot decoding, the pool's audit clean) and prompts
+    admitted one at a time."""
+    cfg, eng, ref = width_pairs(family)
+    serve = _serve_first_wave if scenario == "first_wave" else _serve_one_by_one
+    got, wave = serve(eng, cfg)
+    want, full = serve(ref, cfg)
+    assert len(got) == len(want) >= 5
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w, err_msg=f"{family} {scenario}")
+    s = eng.serving_summary()
+    assert s["decode_signatures"] == 1 and s["prefill_signatures"] == 1
+    if scenario == "first_wave":
+        W, C = eng.prefill_width, eng.chunk
+        two_chunks = sum(_wave_len(i, C) > C for i in range(SLOTS8))
+        assert [a["calls"] for a in wave] == [
+            -(-SLOTS8 // W), -(-two_chunks // W)]
+        assert [a["rows"] for a in wave] == [a["calls"] * W * C for a in wave]
+        assert [a["calls"] for a in full] == [1, 1]
+        assert s["prefill_calls"] == sum(a["calls"] for a in wave)
+
+
+def test_a_ticks_prefill_calls_are_dispatched_back_to_back(width_pairs,
+                                                           monkeypatch):
+    """A tick with n > W prefilling slots makes ``ceil(n / W)`` dispatches
+    of the one signature and the host asks for no result (no
+    ``block_until_ready``, no read of a call's output) until the last is
+    dispatched: the pool is donated and chained call to call, so a queued
+    call holds its small inputs only."""
+    cfg, eng, _ = width_pairs("gqa")
+    eng.reset_metrics()
+    order = []
+
+    class Out:
+        """A call's output that notes when the host reads it."""
+
+        def __init__(self, value, call):
+            self.value, self.call = value, call
+
+        def __array__(self, *a, **kw):
+            order.append(("read", self.call))
+            return np.asarray(self.value)
+
+    real = eng._dispatch
+
+    def dispatch(fn, args):
+        out = real(fn, args)
+        if args[0].shape[1] == 1:  # the decode call
+            return out
+        call = sum(1 for kind, _ in order if kind == "call")
+        order.append(("call", call))
+        return tuple(Out(o, call) for o in out)
+
+    monkeypatch.setattr(eng, "_dispatch", dispatch)
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: order.append(("wait", None)) or x)
+    n = SLOTS8 - 1
+    rids = [eng.submit(Request(_prompt(cfg, 90 + i, 2 + i % 3), NEW))
+            for i in range(n)]
+    eng.step()
+    calls = -(-n // eng.prefill_width)
+    assert calls >= 2
+    assert order[:calls] == [("call", c) for c in range(calls)]
+    assert {kind for kind, _ in order[calls:]} == {"read"}
+    assert {c for _, c in order[calls:]} == set(range(calls))
+    monkeypatch.undo()
+    _drain(eng)
+    assert all(eng.finished[r]["new_tokens"] == NEW for r in rids)
+    assert eng.serving_summary()["prefill_signatures"] == 1
 
 
 # ----------------------------------------------------------------- report

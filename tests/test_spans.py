@@ -20,6 +20,7 @@ import pytest
 
 from torchdistpackage_tpu.models import GPTConfig, init_gpt_params
 from torchdistpackage_tpu.serving import Request, ServingEngine
+from torchdistpackage_tpu.serving.engine import PREFILL_WIDTH
 from torchdistpackage_tpu.serving.tracing import TICK_PHASES
 from torchdistpackage_tpu.utils import span, spans
 
@@ -339,6 +340,35 @@ def test_prefill_span_counts_real_tokens_against_dispatched_rows(params):
     assert s["prefill_signatures"] == 1
     # ticks that prefilled (1 + 2 + 1) against compiled calls (1 + 2 + 2)
     assert (s["prefill_chunks"], s["prefill_calls"]) == (4, 5)
+
+
+def test_a_wave_at_the_default_width_is_one_span_of_calls_and_one_fetch(params):
+    """An engine as it is constructed: a first wave of more prompts than
+    ``PREFILL_WIDTH`` is ``ceil(n / W)`` calls of the one signature under
+    ONE dispatch span, and one fetch behind them that names the last."""
+    W = PREFILL_WIDTH
+    n = 2 * W + 1
+    eng = ServingEngine(params, CFG, num_slots=n, block_size=8, chunk=CHUNK,
+                        max_ctx=64)
+    assert eng.prefill_width == W
+    spans.clear()
+    rids = [eng.submit(Request(tokens=[1] * (2 + i % 5), max_new_tokens=4))
+            for i in range(n)]
+    eng.step()
+    ring = spans.snapshot()
+    (pre,) = _by_name(ring, "tdp:engine.prefill")
+    fetches = _by_name(ring, "tdp:engine.fetch")
+    assert (pre[5]["calls"], pre[5]["call"]) == (3, 3)
+    assert pre[5]["rows"] == 3 * W * CHUNK and pre[5]["rids"] == rids
+    assert pre[5]["tokens"] == sum(2 + i % 5 for i in range(n))
+    # the wave's one fetch opens after the last dispatch returned
+    assert fetches[0][5]["call"] == 3 and fetches[0][3] >= pre[4]
+    assert [f[5]["call"] for f in fetches] == [3, 4]   # then the decode call's
+    assert [s.state for s in eng._slots] == ["decode"] * n
+    assert eng.audit(heal=False)["ok"]
+    s = eng.serving_summary()
+    assert s["prefill_signatures"] == s["decode_signatures"] == 1
+    assert (s["prefill_chunks"], s["prefill_calls"]) == (1, 3)
 
 
 def test_first_marks_the_one_compiling_call_of_a_signature(params):

@@ -21,7 +21,8 @@ batching).  This engine is that scheduler, built TPU-first:
   a long prompt never stalls in-flight decodes for more than one chunk's
   latency.  The compiled call carries only the slots that ARE prefilling
   (a compact ``[dp * prefill_width, chunk]`` batch, ``ceil(n / W)`` calls
-  of that one signature when a tick has more; ``_prefill_batches``), so
+  of that one signature queued back to back when a tick has more;
+  ``_prefill_batches``, ``PREFILL_WIDTH``), so
   an admission costs its own rows, not ``num_slots`` of them.  The final
   slice samples the first token
   (per-slot ``last_idx`` picks the true last prompt row out of the padded
@@ -173,16 +174,23 @@ DRAIN_SCHEMA = "tdp-engine-drain/v1"
 
 #: Slots (a dp group) that one compiled prefill call carries, at most (an
 #: engine with fewer slots a group carries them all): a tick with n slots
-#: prefilling makes ceil(n / W) calls of this ONE signature.  Every call
-#: pays a fixed price (one pass over the weights; until PR 27 also a copy
-#: of the KV pool, which the step now donates and updates where it lies)
-#: and every row beyond the prefilling slots' is computed for nobody.  8
-#: was chosen while the copy was in that price: on a v5e a full wave of 64
-#: admissions then cost about what one 64-slot call did (1.1 s against
-#: 0.95 s) and the usual lone admission a sixth of it (134 against
-#: 868 ms); PERF.md section 6, PR 25, has what 1, 2, 4 and 6 measured, and
-#: section 7 lists measuring them again as the next issue's first contact.
-PREFILL_WIDTH = 8
+#: prefilling makes ceil(n / W) calls of this ONE signature, queued back to
+#: back.  A call costs a fixed part F (one pass over the weights, an expert
+#: layer's grouped GEMMs at their price a group, a launch and a fetch) and
+#: p a slot, and a steady tick of a full engine admits one or two prompts:
+#: every row beyond theirs is computed for nobody.  On a v5e one steady
+#: call at W = 8 / 4 / 2 / 1 reads 96 / 49 / 27 / 17 ms (a dense 7B at half
+#: depth, chunk 256: F ~2, p ~12), 66 / 50 / 43 / 20 (a state model with
+#: 128 held experts, chunk 128: F ~34) and 80 / 51 / 38 / 33, 42 / 34 /
+#: 28 / 20 (two expert models, chunk 256: F ~22).  What a narrower call
+#: costs is the first wave of a full engine, once: ceil(num_slots / W)
+#: calls a tick while every first prompt prefills.  4 is the narrowest of
+#: 1, 2, 4 at which that wave added under 5% to the set-up of every cell
+#: of the benchmark (+1.5 to +3.2%; 2 read +8.8% and +9.8% in the two
+#: cells whose F is most of the call).  8 was PR 25's, chosen while a call
+#: also copied the KV pool (~30 of its ~43 ms fixed; PR 27 took the copy
+#: away).  PERF.md section 6, PR 37, has every reading.
+PREFILL_WIDTH = 4
 
 
 @dataclasses.dataclass
@@ -1580,19 +1588,11 @@ class ServingEngine:
                 rids=self._tick_prefill_rids, **first)
             if self.state_model:
                 attrs["state_slots"] = len(pre)
-        outs = []
         with span("tdp:engine.prefill", **attrs):
-            for _, args in batches:
-                if outs:
-                    # one call in flight.  The wait dates from a step that
-                    # did not donate the pool: a call queued behind a
-                    # running one then held it a third time (+1.6 GB at
-                    # 64 x 768 on a v5e).  The pool is donated now (PR 27)
-                    # and a queued call holds nothing more, so the reason
-                    # is gone; deleting the wait is the next PR's to
-                    # measure (PERF.md section 7)
-                    jax.block_until_ready(outs[-1][0])
-                outs.append(self._dispatch(self._step_fn, args))
+            # back to back: the pool is donated and chained call to call,
+            # so a queued call holds its small inputs only
+            outs = [self._dispatch(self._step_fn, args)
+                    for _, args in batches]
         tok = np.zeros(self.num_slots, np.int32)
         keys = np.zeros_like(self._keys)
         with span("tdp:engine.fetch", call=self._call, **first):
