@@ -22,7 +22,14 @@ layer is ONE mixer and not attention + MLP (the ``nemotron_h`` shape):
   and cached; half the value heads are the position BEFORE's.  The first
   layer that keeps both: keys and values in the block pool (the ``*``
   layers' pool and kernels), and a tail a sequence (the rows the next
-  position's convolutions and shifted value need).
+  position's convolutions and shifted value need);
+- ``S``  indexed (sparse) attention (:func:`indexed_attention_mixer`):
+  rotated GQA with a learned norm a head, behind an INDEXER that scores
+  every cached position with a second, small key (one ``idx_dim`` row a
+  position, in a pool leaf of its own beside K and V), keeps the
+  ``idx_topk`` best and lets all query heads attend to those alone.  The
+  first layer here with positions of the ordinary kind: rope over the whole
+  head, by three position axes (``mrope_section``) that are equal for text.
 
 Every layer is ``x <- x + mixer(RMSNorm(x))``, so a pre-norm block of
 attention + FFN is two layers here (``"LD"``, ``"LE"``, ``"CE"``); a layer
@@ -51,11 +58,13 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..parallel.moe import MoEConfig, _unbiased_act, moe_serve_forward
 from ..parallel.tensor_parallel import TransformerConfig, dense
 from ..parallel.tensor_parallel.layers import (
     apply_rope,
+    layer_norm,
     rms_norm,
     rope_cache,
 )
@@ -71,11 +80,12 @@ class HybridConfig:
     dim: int
     #: one character a layer: 'M' Mamba-2 | '*' attention | 'E' experts |
     #: 'L' latent attention | 'D' dense gated MLP | 'C' convolved attention
+    #: | 'S' indexed attention
     pattern: str
     max_seq: int
     nheads: int
     kv_heads: int
-    #: a head's width ('*', 'C'), which the pool takes from :attr:`block`;
+    #: a head's width ('*', 'C', 'S'), which the pool takes from :attr:`block`;
     #: 0: the heads tile the model's width, ``dim / nheads``
     head_dim: int = 0
     # Mamba-2 ('M'): d_inner = mamba_heads x mamba_head_dim
@@ -97,7 +107,8 @@ class HybridConfig:
     moe_routed_scale: float = 1.0
     #: the experts' (and the shared expert's) activation: 'relu2' | 'swiglu'
     moe_act: str = "relu2"
-    #: the router: 'sigmoid' | 'mlp' (a network with a stream of its own
+    #: the router: 'sigmoid' | 'softmax' (probabilities over all experts,
+    #: the top k renormalised) | 'mlp' (a network with a stream of its own
     #: from expert layer to expert layer, ``parallel.moe._mlp_route``,
     #: ``moe_router_hidden`` wide)
     moe_score: str = "sigmoid"
@@ -113,6 +124,16 @@ class HybridConfig:
     mla_nope: int = 0
     mla_rope: int = 0
     mla_v: int = 0
+    # indexed attention ('S'): the indexer's query heads and their width
+    # (ONE key of that width a position), how many positions a query keeps,
+    # how many leading dims of an indexer head rope turns, and how a
+    # head's ``head_dim / 2`` frequency pairs are dealt to the three
+    # position axes (temporal, height, width; None: plain rope)
+    idx_heads: int = 0
+    idx_dim: int = 0
+    idx_topk: int = 0
+    idx_rope: int = 0
+    mrope_section: Optional[Tuple[int, ...]] = None
     rope_theta: float = 10000.0
     #: a rope-scaling dict as ``rope_cache`` takes it (yarn), or None
     rope_scaling: Optional[Dict[str, Any]] = None
@@ -129,14 +150,15 @@ class HybridConfig:
     moe_dispatch = "auto"
 
     def __post_init__(self):
-        bad = set(self.pattern) - set("M*ELDC")
+        bad = set(self.pattern) - set("M*ELDCS")
         if bad or not self.pattern:
             raise ValueError(
                 f"pattern {self.pattern!r}: one of 'M', '*', 'E', 'L', 'D', "
-                f"'C' a layer")
-        if "L" in self.pattern and set("*C") & set(self.pattern):
+                f"'C', 'S' a layer")
+        if sum(bool(set(kinds) & set(self.pattern))
+               for kinds in ("*C", "L", "S")) > 1:
             raise ValueError(
-                "one kind of block pool a model: '*' / 'C' or 'L'")
+                "one kind of block pool a model: '*' / 'C', 'L' or 'S'")
         if not self.head_dim:
             if self.dim % self.nheads:
                 raise ValueError("dim does not divide by nheads: say head_dim")
@@ -149,6 +171,20 @@ class HybridConfig:
                 "a 'C' layer needs an even number of KV heads (half of "
                 "them shifted) that divides nheads, kernels of at least "
                 "1, and an even cca_rope within a head")
+        if "S" in self.pattern:
+            if not (self.idx_heads and self.idx_dim and self.idx_topk > 0
+                    and self.kv_heads and self.nheads % self.kv_heads == 0):
+                raise ValueError(
+                    "an 'S' layer needs idx_heads, idx_dim, idx_topk and "
+                    "KV heads that divide nheads")
+            if self.idx_rope % 2 or self.idx_rope > self.idx_dim:
+                raise ValueError("an even idx_rope within an indexer head")
+            if self.mrope_section is not None and (
+                    len(self.mrope_section) != 3
+                    or sum(self.mrope_section) != self.head_dim // 2):
+                raise ValueError(
+                    f"mrope_section {self.mrope_section} must deal a head's "
+                    f"{self.head_dim // 2} frequency pairs to three axes")
         if "L" in self.pattern and not (
                 self.mla_latent and self.mla_nope and self.mla_rope
                 and self.mla_v):
@@ -174,12 +210,18 @@ class HybridConfig:
     def kv_layers(self) -> int:
         """Layers that keep keys and values, or the latent they are made
         from (the block pool's depth)."""
-        return sum(self.pattern.count(k) for k in "*LC")
+        return sum(self.pattern.count(k) for k in "*LCS")
 
     @property
     def latent_width(self) -> int:
         """What one position caches in an 'L' layer (0: a K/V pool)."""
         return (self.mla_latent + self.mla_rope) if "L" in self.pattern else 0
+
+    @property
+    def index_width(self) -> int:
+        """What one position caches for the indexer of an 'S' layer beside
+        its keys and values (0: no such leaf in the pool)."""
+        return self.idx_dim if "S" in self.pattern else 0
 
     @property
     def mla_scale(self) -> float:
@@ -512,6 +554,95 @@ def cca_mixer(p, x, cfg: HybridConfig, ck, cv, tail, offset, n_valid,
     return dense(out, p["wo"]), ck, cv, tail
 
 
+def mrope_cache(positions: jnp.ndarray, head_dim: int, theta: float,
+                section: Optional[Tuple[int, ...]]):
+    """(cos, sin) [B, 1, S, head_dim / 2] for :func:`apply_rope` from THREE
+    position rows ``positions`` [3, B, S] (temporal, height, width): of a
+    head's frequency pairs (half-split, ``(i, i + head_dim / 2)``) the first
+    ``section[0]`` turn by the temporal position, the next ``section[1]`` by
+    the height, the last ``section[2]`` by the width.  A text token has the
+    three equal, and this is then :func:`rope_cache` of that position, bit
+    for bit.  ``section`` None: the temporal row alone."""
+    half = head_dim // 2
+    inv_freq = theta ** (-jnp.arange(0, half, dtype=F32) / half)
+    axis_of = np.repeat(np.arange(3), (half, 0, 0) if section is None
+                        else section)
+    pos = jnp.take(positions.astype(F32), axis_of, axis=0)    # [half, B, S]
+    ang = jnp.moveaxis(pos, 0, -1) * inv_freq                 # [B, S, half]
+    return jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
+
+
+def indexed_attention_mixer(p, x, cfg: HybridConfig, cache, offset,
+                            cache_ops, positions=None):
+    """Indexed (sparse) attention on the block pool: x [B, S, D] (normed)
+    -> (y, cache, selection), ``cache`` the pool's three leaves ``{'k', 'v',
+    'idx'}``, ``selection`` [B, S, words] int16 the positions each row kept,
+    as bits (``ops.dsa_attention.selection_words``).
+
+    Attention is grouped-query attention with a learned RMSNorm over each
+    query head and each key head before the rotation (:func:`mrope_cache`).
+    The INDEXER decides which cached positions a query reads: ``qI = x
+    W_qI`` (``idx_heads`` heads of ``idx_dim``), ONE key a position ``kI =
+    LayerNorm(x W_kI)`` (cached in the pool's ``idx`` leaf), both rotated on
+    their leading ``idx_rope`` dims by the temporal position, head weights
+    ``w = (x W_w) idx_heads^-0.5 idx_dim^-0.5`` in float32, and the score of
+    position s for the query at t (s <= t) is ``sum_j w_j relu(qI_j .
+    kI_s)``.  The ``min(idx_topk, t + 1)`` positions of largest score (equal
+    scores: the lower position first) are the ones ALL query heads of t
+    attend to; the rest are never read as keys or values.  This call's own
+    rows are written first (keys, values and indexer keys alike), so a
+    position may select itself and a chunk's rows select among the chunk's.
+
+    ``positions`` [3, B, S]: the three position rows; None: a text token's,
+    ``offset[b] + arange(S)`` three times.  ``cache_ops``: ``(write,
+    write_idx, attend)`` of ``serving/paged_cache._indexed_cache_ops``."""
+    B, S, _ = x.shape
+    H, Hkv, hd = cfg.nheads, cfg.kv_heads, cfg.head_dim
+    J, di, dr = cfg.idx_heads, cfg.idx_dim, cfg.idx_rope
+    write, write_idx, attend = cache_ops
+    if positions is None:
+        positions = jnp.broadcast_to(
+            offset[:, None] + jnp.arange(S)[None, :], (3, B, S))
+    rope = mrope_cache(positions, hd, cfg.rope_theta, cfg.mrope_section)
+
+    q = rms_norm(dense(x, p["wq"]).reshape(B, S, H, hd), p["q_norm"],
+                 cfg.norm_eps)
+    kv = dense(x, p["wkv"], "bsd,tdh->tbsh")
+    k = rms_norm(kv[0].reshape(B, S, Hkv, hd), p["k_norm"], cfg.norm_eps)
+    q = apply_rope(q.transpose(0, 2, 1, 3), cache=rope)
+    k = apply_rope(k.transpose(0, 2, 1, 3), cache=rope)
+    v = kv[1].reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)
+
+    # the indexer's query and key stay in float32 from the projection
+    # through the norm and the rotation and are rounded ONCE, as they are
+    # cached and multiplied: a score's rounding error decides which
+    # positions sit on which side of the ``idx_topk``-th, and every
+    # intermediate rounding adds to it
+    irope = mrope_cache(positions, dr, cfg.rope_theta, None)
+
+    def turned(a):   # [B, heads, S, di] float32: rope on the leading ``dr``
+        return jnp.concatenate(
+            [apply_rope(a[..., :dr], cache=irope), a[..., dr:]],
+            axis=-1).astype(x.dtype)
+
+    def proj(w):
+        return jnp.einsum("bsd,dn->bsn", x, w, preferred_element_type=F32)
+
+    qi = turned(proj(p["wq_idx"]).reshape(B, S, J, di).transpose(
+        0, 2, 1, 3))                                       # [B, J, S, di]
+    ki = turned(layer_norm(proj(p["wk_idx"]), p["k_idx_norm"],
+                           cfg.norm_eps)[:, None])[:, 0]   # [B, S, di]
+    w = proj(p["w_idx"]) * (J ** -0.5 * di ** -0.5)
+
+    cache = dict(cache)
+    cache["k"] = write(cache["k"], k, offset)
+    cache["v"] = write(cache["v"], v, offset)
+    cache["idx"] = write_idx(cache["idx"], ki, offset)
+    out, kept = attend(q, cache["k"], cache["v"], cache["idx"], qi, w, offset)
+    out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
+    return dense(out, p["wo"]), cache, kept
+
+
 def latent_attention_mixer(p, x, cfg: HybridConfig, pool, offset, cache_ops):
     """Latent attention in the absorbed form: x [B, S, D] (normed) -> (y,
     pool).  A position caches ONE row, ``[RMSNorm(c) | rope(k_rope)]``
@@ -561,28 +692,34 @@ def hybrid_paged_forward(
     cache_ops,
     offset: jnp.ndarray,
     last_idx=None,
+    positions=None,
 ):
     """``tokens`` [B, S] through the stack.  ``cache``: the block pool of
-    the attention layers (``{'k','v': [kv_layers, ...]}``, or ``{'kv':
-    ...}`` where they are latent), reached through
+    the attention layers (``{'k','v': [kv_layers, ...]}``, with an ``'idx'``
+    leaf beside them where attention is indexed, or ``{'kv': ...}`` where
+    it is latent), reached through
     ``cache_ops(layer)`` (the pool's ``(write, attend)`` pair for one of
     its layers; the pool itself is threaded whole through the attention
     layers); ``state``: :func:`init_state`'s arrays with one row a
     row of ``tokens``; ``n_valid`` [B]: the real positions of each row.
     The network router's stream (``moe_score='mlp'``) is a second carry
-    through the loop, from one expert layer to the next.
+    through the loop, from one expert layer to the next.  ``positions``
+    [3, B, S]: an 'S' layer's three position rows (None: text positions,
+    ``offset[b] + arange(S)``; the engine serves token ids and passes none).
     Returns ``(cache, state, logits [B, V], moe_metrics)``: the logits of
     row ``last_idx`` (default: the last), and the expert layers' counters
     summed over the layers, with ``routing`` [B, S, E-layers, k]: the
     experts every position chose in every expert layer (None without an
-    'E' layer)."""
+    'E' layer), and where attention is indexed ``selection`` [B, S,
+    S-layers, words] int16: the positions every row kept in every 'S' layer,
+    as bits (``ops.dsa_attention.selection_words``)."""
     from ..serving.paged_cache import _select_row
 
     S = tokens.shape[1]
     valid = jnp.arange(S)[None, :] < n_valid[:, None]
     h = jnp.take(params["tok_emb"], tokens, axis=0)
     cache, kv_layer = dict(cache), 0
-    ssm, conv, tails, mets = [], [], [], []
+    ssm, conv, tails, mets, kept = [], [], [], [], []
     mcfg = cfg.moe if cfg.moe_experts else None
     depth = None
     for kind, lp in zip(cfg.pattern, params["layers"]):
@@ -604,6 +741,12 @@ def hybrid_paged_forward(
                 state["tail"][len(tails)], offset, n_valid,
                 cache_ops(kv_layer))
             tails.append(tail)
+            kv_layer += 1
+        elif kind == "S":
+            y, cache, rows_kept = indexed_attention_mixer(
+                lp, x, cfg, cache, offset, cache_ops(kv_layer),
+                positions=positions)
+            kept.append(rows_kept)
             kv_layer += 1
         elif kind == "L":
             y, cache["kv"] = latent_attention_mixer(
@@ -629,6 +772,8 @@ def hybrid_paged_forward(
         routing = jnp.stack([m.pop("gate_idx") for m in mets], axis=2)
         metrics = {k: sum(m[k] for m in mets) for k in mets[0]}
         metrics["routing"] = routing
+        if kept:
+            metrics["selection"] = jnp.stack(kept, axis=2)
     h = rms_norm(_select_row(h, last_idx), params["ln_f"], cfg.norm_eps)
     if "head" in params:
         logits = dense(h, params["head"])
@@ -680,6 +825,19 @@ def init_hybrid_params(key, cfg: HybridConfig, scaled_residual: bool = False,
             lp = {"wq": normal(ks[0], (D, dq), D),
                   "wkv": normal(ks[1], (2, D, dkv), D),
                   "wo": normal(ks[2], (dq, D), dq)}
+        elif kind == "S":
+            dq, dkv = cfg.nheads * hd, cfg.kv_heads * hd
+            J, di = cfg.idx_heads, cfg.idx_dim
+            lp = {"wq": normal(ks[0], (D, dq), D),
+                  "wkv": normal(ks[1], (2, D, dkv), D),
+                  "q_norm": {"scale": jnp.ones((hd,), dt)},
+                  "k_norm": {"scale": jnp.ones((hd,), dt)},
+                  "wo": normal(ks[2], (dq, D), dq),
+                  "wq_idx": normal(ks[3], (D, J * di), D),
+                  "wk_idx": normal(ks[4], (D, di), D),
+                  "k_idx_norm": {"scale": jnp.ones((di,), dt),
+                                 "bias": jnp.zeros((di,), dt)},
+                  "w_idx": normal(ks[5], (D, J), D)}
         elif kind == "C":
             G, C = cfg.nheads + cfg.kv_heads, cfg.cca_channels
             lp = {"wz": normal(ks[0], (D, C), D),

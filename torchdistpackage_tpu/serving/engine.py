@@ -159,6 +159,7 @@ from .paged_cache import (
     chain_block_hashes,
     copy_blocks,
     expected_pool_bytes,
+    index_bytes,
     init_paged_kv,
     paged_forward,
     paged_forward_moe,
@@ -408,7 +409,16 @@ class ServingEngine:
         expert layers, top_k], the last token is never fed).  A top-k
         choice is discontinuous, so a reference in another precision can
         only be held to the program's logits along the program's own
-        choices; this is what lets it follow them.
+        choices; this is what lets it follow them.  Where attention is
+        INDEXED the positions a row keeps are such a choice too: a fed
+        position's record is then flat, its ``expert layers x top_k``
+        experts and behind them, for each indexed layer, ``ceil(max_ctx /
+        16)`` words of the kept positions as bits
+        (``ops.dsa_attention.selection_words``; 14 KB a position at 8
+        layers x 14,336, fetched for the live rows only), and
+        ``finished[rid]['routing']`` is the LIST of the calls' pieces
+        ``[positions, width]`` as they were fetched: putting 145 MB a
+        request together is the reader's, not a serving tick's.
     run_ahead: a state model only: the decode call of a tick is dispatched
         BEFORE the call of the tick before it is fetched.  A slot whose
         newest token is still on the device is fed it (and its sampling
@@ -478,16 +488,25 @@ class ServingEngine:
                               "prefix_cache"),
                              (spec_k, "spec_k"), (cp_axis, "cp_axis"),
                              (mesh, "a mesh (tp/dp/ep)")):
-                if on:
+                if not on:
+                    continue
+                if not cfg.state_layers:
                     raise NotImplementedError(
-                        f"{what} with a state model is not supported: a "
-                        f"recurrent state, or the tail an attention layer "
-                        f"keeps of the rows before a position, cannot be "
-                        f"shared by prefix, rolled back after a rejected "
-                        f"draft or split over devices without per-position "
-                        f"SNAPSHOTS of it, which the engine does not keep "
-                        f"yet, and the family's step has no verify or mesh "
-                        f"form (ROADMAP queue 2 A4)")
+                        f"{what} with the hybrid family is not supported: "
+                        f"its step has no verify or mesh form (a model of "
+                        f"attention layers alone keeps nothing outside its "
+                        f"blocks, so nothing else stands in the way; where "
+                        f"attention is indexed, the verify rows would each "
+                        f"select for themselves: ROADMAP queue 2 A4)")
+                raise NotImplementedError(
+                    f"{what} with a state model is not supported: a "
+                    f"recurrent state, or the tail an attention layer "
+                    f"keeps of the rows before a position, cannot be "
+                    f"shared by prefix, rolled back after a rejected "
+                    f"draft or split over devices without per-position "
+                    f"SNAPSHOTS of it, which the engine does not keep "
+                    f"yet, and the family's step has no verify or mesh "
+                    f"form (ROADMAP queue 2 A4)")
             if record_routing and not cfg.moe_experts:
                 raise ValueError("record_routing: the model has no "
                                  "expert layers")
@@ -626,6 +645,8 @@ class ServingEngine:
         with span("tdp:engine.init.pool") as sp:
             self.cache = device_step.init_cache()
             sp.attrs.update(bytes=pool_bytes(self.cache), **self._walk_attrs())
+            if "idx" in self.cache:  # of which the indexer's keys
+                sp.attrs.update(index_bytes=index_bytes(self.cache))
         #: state models: the recurrent state, one row a slot, beside the
         #: pool (``models.hybrid.init_state``); like the pool, the compiled
         #: step is handed it as a donated argument and the engine keeps
@@ -676,6 +697,12 @@ class ServingEngine:
         self._tick_decode_rids: List[int] = []
         self._tick_emitted = 0
         self._tick_moe = [0.0, 0.0, 0.0]
+        #: indexed attention (``cfg.index_width``): how many positions a
+        #: query keeps at most (0: attention is not indexed), and this
+        #: tick's (query, position) pairs scored and selected
+        self._idx_topk = (int(cfg.idx_topk)
+                          if getattr(cfg, "index_width", 0) else 0)
+        self._tick_dsa = [0, 0]
         self._pending_cow: List[Tuple[int, int, int]] = []  # slot, src, dst
         wrap = (telemetry is not None
                 and getattr(device_step, "wrap_steps", True))
@@ -814,7 +841,16 @@ class ServingEngine:
             out = (cache, state, tok, keys, m["expert_tokens"][None, :],
                    m["dropped_token_rate"][None], share)
             if record:
-                out += (m["routing"].astype(jnp.int16),)
+                chose = m["routing"].astype(jnp.int16)
+                if "selection" in m:
+                    # behind a position's experts, the positions it kept;
+                    # a compact prefill row is a leaf of its own, so that
+                    # the host fetches the live rows alone
+                    chose = jnp.concatenate(
+                        [a.reshape(*chose.shape[:2], -1)
+                         for a in (chose, m["selection"])], axis=-1)
+                    chose = chose if rows is None else tuple(chose)
+                out += (chose,)
             return out
 
         return jax.jit(step, donate_argnums=(1, 2))
@@ -854,12 +890,20 @@ class ServingEngine:
     def _needs_snapshots(self, what: str) -> None:
         """A state model's requests cannot leave the engine mid-flight: the
         recurrent state would have to travel with them."""
-        if self.state_model:
+        if not self.state_model:
+            return
+        if not self.cfg.state_layers:
             raise NotImplementedError(
-                f"{what} with a state model is not supported: the "
-                f"request's recurrent state (or its attention layers' "
-                f"tails) would have to be snapshotted and carried, which "
-                f"the engine does not do yet (ROADMAP queue 2 A4)")
+                f"{what} with the hybrid family is not supported: a model "
+                f"of attention layers alone keeps nothing outside its "
+                f"blocks (K, V and an indexer's keys travel with them), but "
+                f"the family's engine path has no export, import or drain "
+                f"form yet (ROADMAP queue 2 A4)")
+        raise NotImplementedError(
+            f"{what} with a state model is not supported: the "
+            f"request's recurrent state (or its attention layers' "
+            f"tails) would have to be snapshotted and carried, which "
+            f"the engine does not do yet (ROADMAP queue 2 A4)")
 
     def _build_cp_step(self) -> Callable:
         """The ring-paged step (docs/long_context.md "CP prefill
@@ -1588,6 +1632,10 @@ class ServingEngine:
                 rids=self._tick_prefill_rids, **first)
             if self.state_model:
                 attrs["state_slots"] = len(pre)
+            if self._idx_topk:
+                attrs.update(self._indexed_attrs(
+                    np.concatenate([args[2] for _, args in batches]),
+                    np.concatenate([args[-1] for _, args in batches])))
         with span("tdp:engine.prefill", **attrs):
             # back to back: the pool is donated and chained call to call,
             # so a queued call holds its small inputs only
@@ -1603,10 +1651,13 @@ class ServingEngine:
                 if len(out) > 2:  # expert layers: load stats ride along
                     self._absorb_moe_stats(*out[2:5])
                 if len(out) > 5:  # record_routing: the real positions' own
-                    routing, n_valid = np.asarray(out[5]), args[-1]
+                    n_valid, by_row = args[-1], isinstance(out[5], tuple)
+                    routing = None if by_row else np.asarray(out[5])
                     for r in np.flatnonzero(live):
+                        piece = (np.asarray(out[5][r]) if by_row
+                                 else routing[r])
                         self._slots[slot_of[r]].routing.append(
-                            routing[r, :n_valid[r]])
+                            piece[:n_valid[r]])
         self.stats["prefill_calls"] += len(batches)
         return tok, keys
 
@@ -1768,7 +1819,23 @@ class ServingEngine:
                      live_tokens=int(offsets.sum()) + n_active,
                      sampled_rows=np.count_nonzero(self._temps > 0),
                      call=self._call, **self._first_call("decode", tokens))
+        if self._idx_topk:
+            attrs.update(self._indexed_attrs(offsets, mask))
         return args, slots, attrs
+
+    def _indexed_attrs(self, offsets: np.ndarray,
+                       n_valid: np.ndarray) -> Dict[str, int]:
+        """What a call's indexed attention layers each do, from its rows'
+        offsets and real positions: ``indexed_positions`` (the (query,
+        cached position) pairs the indexer scores) and
+        ``selected_positions`` (those attention then reads: ``min(topk,
+        context)`` a query); booked on the tick as well."""
+        from ..ops.dsa_attention import position_counts
+
+        pair = position_counts(offsets, n_valid, self._idx_topk)
+        self._tick_dsa[0] += pair[0]
+        self._tick_dsa[1] += pair[1]
+        return {"indexed_positions": pair[0], "selected_positions": pair[1]}
 
     def _absorb_decode(self, call: Optional[Dict[str, Any]]) -> None:
         """Fetch what one decode call returned and book it: every slot's
@@ -1982,7 +2049,9 @@ class ServingEngine:
             "t_done": now,
         }
         if self.record_routing:
-            self.finished[s.rid]["routing"] = np.concatenate(s.routing)
+            # indexed attention (flat pieces, 14 KB a position): as fetched
+            self.finished[s.rid]["routing"] = (
+                s.routing if self._idx_topk else np.concatenate(s.routing))
         self._inject.pop(s.rid, None)
         self._ttft_pred.pop(s.rid, None)
         if completed:
@@ -2185,6 +2254,7 @@ class ServingEngine:
             self._tick_decode_rids = []
             self._tick_emitted = 0
             self._tick_moe = [0.0, 0.0, 0.0]
+            self._tick_dsa = [0, 0]
             if self.chaos is not None:
                 self.chaos.before_engine_tick(self._tick, self)
             self.stats["audits"] += 1
@@ -2271,6 +2341,9 @@ class ServingEngine:
         if self.state_model:
             rec.update(zip(("moe_rows_routed", "moe_rows_held",
                             "experts_touched"), self._tick_moe))
+        if self._idx_topk:
+            rec.update(zip(("indexed_positions", "selected_positions"),
+                           self._tick_dsa))
         self.tick_records.append(rec)
         if admitted or expired or prefilled or decoded or busy or self.queue:
             self._ev.emit(
@@ -3016,6 +3089,8 @@ class ServingEngine:
                 # device buffer actually held vs what the shape math says
                 # init_paged_kv should have allocated
                 "pool_bytes": pool_bytes(self.cache),
+                # of which the indexer's keys (indexed attention; else 0)
+                "index_bytes": index_bytes(self.cache),
                 "pool_bytes_expected": expected_pool_bytes(
                     self.cfg, self.dp * self.num_blocks, self.block_size,
                     quantized=self.kv_quant),

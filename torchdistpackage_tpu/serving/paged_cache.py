@@ -22,8 +22,12 @@ compiles ONCE):
   row a position, shared by all heads, and its pool is ONE leaf,
   ``{'kv': [L, num_blocks, 1, W, block_size]}``: no per-head axis to speak
   of, no separate V, each block the transpose of its rows
-  (ops/mla_attention.py says why).  Everything below that names blocks
-  (tables, allocator, copy-on-write, migration) is the same for both.
+  (ops/mla_attention.py says why).  A model whose attention is INDEXED
+  (kind ``S``) keeps K and V as every GQA model does and, beside them, the
+  indexer's one small key a position in a THIRD leaf, ``{'k', 'v', 'idx':
+  [L, num_blocks, 1, idx_dim, block_size]}``, laid as the latent pool's
+  blocks are and written by the same op.  Everything below that names blocks
+  (tables, allocator, copy-on-write, migration) is the same for all three.
 - **Block tables**: ``[num_slots, max_blocks]`` int32 per-slot rows.  Block
   ``i`` of a slot's table covers its positions ``[i*bs, (i+1)*bs)``, so the
   table IS the page table and position arithmetic is two integer ops.
@@ -111,6 +115,15 @@ def init_paged_kv(
             f"{axis_size} (whole KV heads per shard)"
         )
     shape = (_kv_layers(cfg), num_blocks, hkv, block_size, cfg.block.head_dim)
+    if _index_width(cfg):
+        if quantized or axis_size != 1:
+            raise NotImplementedError(
+                "an indexed pool has no int8 form and no tensor-parallel "
+                "split yet (ROADMAP queue 2)")
+        return {"k": jnp.zeros(shape, cfg.dtype),
+                "v": jnp.zeros(shape, cfg.dtype),
+                "idx": jnp.zeros((shape[0], num_blocks, 1, _index_width(cfg),
+                                  block_size), cfg.dtype)}
     if quantized:
         def entry():
             return (jnp.zeros(shape, jnp.int8),
@@ -130,6 +143,17 @@ def _latent_width(cfg) -> int:
     """What one position caches where attention is latent; 0 = keys and
     values a head."""
     return getattr(cfg, "latent_width", 0)
+
+
+def _index_width(cfg) -> int:
+    """What one position caches for the indexer where attention is indexed
+    (the pool's ``idx`` leaf); 0 = no such leaf."""
+    return getattr(cfg, "index_width", 0)
+
+
+def index_bytes(cache: Dict[str, Any]) -> int:
+    """Bytes of the pool's indexer-key leaf (0 where there is none)."""
+    return pool_bytes({"idx": cache["idx"]}) if "idx" in cache else 0
 
 
 def block_size_of(cache: Dict[str, Any]) -> int:
@@ -167,10 +191,9 @@ def expected_pool_bytes(
     ``2 * L * num_blocks * Hkv/axis_size * block_size * hd`` entries in
     ``cfg.dtype`` (int8 + f32 per-vector scale when ``quantized``).  The
     independent half of the pool-accounting cross-check.  A latent pool
-    is ONE leaf of ``L * num_blocks * block_size * latent_width``."""
+    is ONE leaf of ``L * num_blocks * block_size * latent_width``; an
+    indexed pool adds ``L * num_blocks * block_size * index_width``."""
     if _latent_width(cfg):
-        import jax.numpy as jnp
-
         return (_kv_layers(cfg) * num_blocks * block_size
                 * _latent_width(cfg) * jnp.dtype(cfg.dtype).itemsize)
     hkv = cfg.block.kv_head_count // axis_size
@@ -179,10 +202,10 @@ def expected_pool_bytes(
     if quantized:
         per_kv = entries * hd * 1 + entries * 4  # int8 q + f32 scale
     else:
-        import jax.numpy as jnp
-
         per_kv = entries * hd * jnp.dtype(cfg.dtype).itemsize
-    return 2 * per_kv  # k and v
+    # k and v, and the indexer's key a position where attention is indexed
+    return 2 * per_kv + (_kv_layers(cfg) * num_blocks * block_size
+                         * _index_width(cfg) * jnp.dtype(cfg.dtype).itemsize)
 
 
 def _write_blocks(tables: jnp.ndarray, offset: jnp.ndarray, S_in: int,
@@ -350,6 +373,26 @@ def _latent_cache_ops(tables: jnp.ndarray, attn_impl: str, cfg, layer):
                       sm_scale=cfg.mla_scale, layer=layer)
     return functools.partial(latent_write, tables=tables,
                              layer=layer), attend_layer
+
+
+def _indexed_cache_ops(tables: jnp.ndarray, attn_impl: str, cfg, layer):
+    """:func:`_paged_cache_ops` for an indexed layer (models/hybrid.py kind
+    ``S``): ``(write, write_idx, attend)``.  ``write`` is the K/V pool's,
+    ``write_idx(pool, rows [B, S_in, idx_dim], offset)`` the latent pool's
+    op on the ``idx`` leaf, and ``attend(q, ck, cv, cidx, qi [B, J, S_in,
+    idx_dim], w [B, S_in, J], offset)`` scores every cached position, keeps
+    each query's ``idx_topk`` best and attends to those alone
+    (ops/dsa_attention.py: three kernels, or their gathered oracle); it
+    gives the output and the kept positions as bits."""
+    from ..ops import dsa_attention as D
+
+    def attend(q, ck, cv, cidx, qi, w, offset):
+        return D.indexed_attention(
+            q, ck, cv, cidx, qi, w, tables, offset, topk=cfg.idx_topk,
+            layer=layer, impl=attn_impl)
+    return (functools.partial(paged_write, tables=tables, layer=layer),
+            functools.partial(latent_write, tables=tables, layer=layer),
+            attend)
 
 
 def _batched_rope(bcfg, positions: jnp.ndarray):
@@ -666,9 +709,12 @@ def paged_forward_hybrid(
     from ..models.hybrid import hybrid_paged_forward
 
     offset = jnp.asarray(offset, jnp.int32)
-    ops = (functools.partial(_latent_cache_ops, tables, attn_impl, cfg)
-           if _latent_width(cfg)
-           else functools.partial(_paged_cache_ops, tables, attn_impl))
+    if _latent_width(cfg):
+        ops = functools.partial(_latent_cache_ops, tables, attn_impl, cfg)
+    elif _index_width(cfg):
+        ops = functools.partial(_indexed_cache_ops, tables, attn_impl, cfg)
+    else:
+        ops = functools.partial(_paged_cache_ops, tables, attn_impl)
     mine = state
     if rows is not None:
         def own(a):
