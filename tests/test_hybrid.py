@@ -5,6 +5,7 @@ against the plain reference's full forward (benchmarks/reference/
 nemotron_h.py, which imports nothing of the program)."""
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -519,19 +520,252 @@ def test_dense_and_moe_engines_still_equal_their_goldens():
         np.testing.assert_array_equal(eng.finished[rid]["tokens"], want)
 
 
+def _force_grouped(monkeypatch):
+    """Every unbiased expert layer from here on runs its ``ragged_dot``
+    pair, whatever its rows (the counter still says what it WOULD take)."""
+    from torchdistpackage_tpu.parallel import moe as M
+
+    monkeypatch.setattr(
+        M, "_batched_experts", lambda ex, rows, se, gs, C, act, fetch=False:
+        jnp.where((se < ex["w1"].shape[0])[:, None],
+                  M._grouped_experts(ex, rows, gs, act), 0))
+
+
 def test_a_small_call_batches_the_experts_and_equals_the_grouped_gemm(
         toy, monkeypatch):
     """A decode-sized call runs the held experts as one batched matmul at
-    capacity C = T; a larger one as ``ragged_dot`` groups.  Same layer."""
-    from torchdistpackage_tpu.parallel import moe as M
-
+    capacity C = T; the ``ragged_dot`` groups give the same layer."""
     _, cfg, params = toy
     p = params["layers"][1]
     x = jax.random.normal(jax.random.PRNGKey(6), (5, 1, cfg.dim), F32)
     with jax.default_matmul_precision("highest"):
         batched, mb = moe_serve_forward(p, x, cfg.moe, return_metrics=True)
-        monkeypatch.setattr(M, "_BATCHED_EXPERTS_MAX_TOKENS", 0)
+        _force_grouped(monkeypatch)
         grouped, mg = moe_serve_forward(p, x, cfg.moe, return_metrics=True)
     np.testing.assert_allclose(batched, grouped, rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(mb["gate_idx"], mg["gate_idx"])
     assert float(jnp.abs(batched).max()) > 0
+
+
+#: an unbiased held-expert layer by its family: the config, and what the
+#: router and the layer carry beside the stacked experts
+_FORM_LAYERS = {
+    "relu2_latent_shared": MoEConfig(
+        dim=32, ffn_dim=24, num_experts=16, top_k=6, act="relu2",
+        score="sigmoid", routed_scale=2.5, latent_dim=16, shared_ffn=40,
+        held=(4, 4)),
+    "swiglu": MoEConfig(dim=32, ffn_dim=24, num_experts=8, top_k=2,
+                        act="swiglu", score="sigmoid", held=(2, 4)),
+    "mlp_top1": MoEConfig(dim=32, ffn_dim=24, num_experts=4, top_k=1,
+                          act="swiglu", score="mlp", held=(0, 4)),
+}
+
+
+def _form_layer(family):
+    """(config, float32 weights) with a bias that sends EVERY row to the
+    first held expert too: that expert's group is the call's real rows."""
+    cfg = _FORM_LAYERS[family]
+    keys = iter(jax.random.split(jax.random.PRNGKey(40), 16))
+    rnd = lambda *shape: jax.random.normal(next(keys), shape, F32) / np.sqrt(
+        shape[-2] if len(shape) > 1 else 1.0)
+    D, E, n = cfg.dim, cfg.num_experts, cfg.held[1]
+    d = cfg.latent_dim or D
+    gate = 2 if cfg.act == "swiglu" else 1
+    bias = jnp.zeros((E,), F32).at[cfg.held[0]].set(10.0)
+    if cfg.score == "mlp":
+        R = 8
+        router = {"down": {"w": rnd(D, R), "b": rnd(R)}, "gamma": rnd(R),
+                  "norm": {"scale": jnp.ones((R,), F32)}, "w1": rnd(R, R),
+                  "b1": rnd(R),
+                  "w2": rnd(R, R), "b2": rnd(R), "w3": rnd(R, E),
+                  "bias": bias}
+    else:
+        router = {"w": rnd(D, E), "bias": bias}
+    p = {"router": router,
+         "experts": {"w1": rnd(n, d, gate * cfg.ffn_dim),
+                     "w2": rnd(n, cfg.ffn_dim, d)}}
+    if cfg.latent_dim:
+        p["latent"] = {"down": rnd(D, d), "up": rnd(d, D)}
+    if cfg.shared_ffn:
+        p["shared"] = {"w1": rnd(D, gate * cfg.shared_ffn),
+                       "w2": rnd(cfg.shared_ffn, D)}
+    return cfg, p
+
+
+@pytest.mark.parametrize("call,batched", [
+    ("wide_call_every_group_fits", 1.0), ("wide_call_one_group_crowded", 0.0),
+    ("small_call", 1.0)])
+@pytest.mark.parametrize("family", list(_FORM_LAYERS))
+def test_the_experts_form_follows_the_largest_group_and_changes_nothing(
+        family, call, batched, monkeypatch):
+    """Past ``C`` rows a call asks its largest group: the batched form where
+    every held expert got at most ``C`` REAL rows, the ``ragged_dot`` pair
+    otherwise; a call of at most ``C`` rows never asks.  The output, the
+    choices and every counter are the forced-``ragged_dot`` layer's, and
+    ``layers_batched`` says which form ran."""
+    from torchdistpackage_tpu.parallel import moe as M
+
+    C = 8
+    monkeypatch.setattr(M, "_BATCHED_EXPERTS_MAX_ROWS", C)
+    cfg, p = _form_layer(family)
+    first = cfg.held[0]
+    # 3 rows of 8 positions; the padding is one token over and over, as a
+    # compact prefill call's is
+    B, S, real = {"wide_call_every_group_fits": (3, 8, 6),
+                  "wide_call_one_group_crowded": (3, 8, 12),
+                  "small_call": (1, 6, 6)}[call]
+    x = jax.random.normal(jax.random.PRNGKey(41), (B, S, cfg.dim), F32)
+    valid = (jnp.arange(B * S) < real).reshape(B, S)
+    x = jnp.where(valid[..., None], x, x[0, 0])
+    depth = (jax.random.normal(jax.random.PRNGKey(42), (B, S, 8), F32)
+             if cfg.score == "mlp" else None)
+
+    def layer():
+        with jax.default_matmul_precision("highest"):
+            return moe_serve_forward(p, x, cfg, return_metrics=True,
+                                     valid=valid, depth=depth)
+
+    y, m, *stream = layer()
+    _force_grouped(monkeypatch)
+    y_g, m_g, *stream_g = layer()
+    np.testing.assert_allclose(y, y_g, rtol=1e-5, atol=1e-6)
+    assert m.keys() == m_g.keys() >= {
+        "expert_tokens", "rows_routed", "rows_held", "experts_touched",
+        "gate_idx", "layers_batched"}
+    for k in m:
+        np.testing.assert_array_equal(m[k], m_g[k], err_msg=k)
+    for a, b in zip(stream, stream_g):
+        np.testing.assert_array_equal(a, b)
+    assert float(m["layers_batched"]) == batched
+    # the first held expert's group is the REAL rows: the padding rows
+    # chose it as well, more often than it has slots, and fill none
+    assert float(m["expert_tokens"][0]) == real
+    assert np.asarray(m["gate_idx"] == first).any(-1).all()
+    if call == "wide_call_every_group_fits":
+        assert B * S - real > C
+    assert float(m["rows_routed"]) == real * cfg.top_k
+    assert float(jnp.abs(y).max()) > 0
+
+
+@pytest.mark.parametrize("prompts,batched_share", [
+    ((13,), "all"), ((8, 8, 8), "some")], ids=["one_live_row", "a_wave"])
+def test_a_prefill_call_with_few_real_rows_batches_its_experts(
+        toy, prompts, batched_share, monkeypatch):
+    """The toy's compact prefill call is 3 x 8 rows, past ``C`` = 8.  With
+    one live row no held expert gets more than 8 rows: every expert layer
+    of every prefill call runs batched, and says so.  A wave's call crowds
+    some expert of some layer and that layer falls back.  Either way the
+    tokens are the forced-``ragged_dot`` engine's, from the same two
+    programs."""
+    from torchdistpackage_tpu.parallel import moe as M
+
+    _, cfg, params = toy
+    monkeypatch.setattr(M, "_BATCHED_EXPERTS_MAX_ROWS", 8)
+
+    def serve():
+        rng = np.random.RandomState(3)
+        with jax.default_matmul_precision("highest"):
+            eng = ServingEngine(params, cfg, num_slots=3, block_size=8,
+                                chunk=8, max_ctx=32, attn_impl="gather")
+            for n in prompts:
+                eng.submit(Request(tokens=rng.randint(0, 211, n).tolist(),
+                                   max_new_tokens=5))
+            eng.run_until_idle()
+        return eng
+
+    got = serve()
+    st, layers = got.stats, cfg.pattern.count("E")
+    calls = st["prefill_calls"]
+    assert calls == (2 if batched_share == "all" else 1)
+    assert st["prefill_moe_layers_run"] == calls * layers
+    if batched_share == "all":
+        assert st["prefill_moe_layers_batched"] == calls * layers
+    else:
+        assert 0 <= st["prefill_moe_layers_batched"] < calls * layers
+    # a decode call (3 rows) never asks: all of its layers, every call
+    decode = {k: st[f"moe_{k}"] - st[f"prefill_moe_{k}"]
+              for k in ("layers_run", "layers_batched")}
+    assert decode["layers_run"] == decode["layers_batched"] \
+        == st["decode_steps"] * layers > 0
+    for k in ("moe_layers_run", "prefill_moe_layers_batched"):
+        assert sum(t[k] for t in got.tick_records) == st[k]
+    assert got._step_fn._cache_size() == 2      # one prefill, one decode
+    summ = got.serving_summary()
+    assert summ["prefill_signatures"] == summ["decode_signatures"] == 1
+    _force_grouped(monkeypatch)
+    want = serve()
+    assert want.finished.keys() == got.finished.keys()
+    for rid, w in want.finished.items():
+        np.testing.assert_array_equal(got.finished[rid]["tokens"],
+                                      w["tokens"])
+    assert want.stats["prefill_moe_layers_batched"] \
+        == st["prefill_moe_layers_batched"]     # the counter is the rule's
+
+
+def _conditionals(text):
+    """The ``stablehlo.case`` operations of a lowered program, each as the
+    list of its branches' lines (by the printer's indentation)."""
+    lines, out = text.splitlines(), []
+    for i, line in enumerate(lines):
+        if '"stablehlo.case"' not in line:
+            continue
+        pad = line[:len(line) - len(line.lstrip())]
+        branches = [[]]
+        for inner in lines[i + 1:]:
+            if inner.startswith(pad + "}) :"):
+                break
+            if inner == pad + "}, {":
+                branches.append([])
+            else:
+                branches[-1].append(inner)
+        out.append(["\n".join(b) for b in branches])
+    return out
+
+
+def test_the_prefill_program_holds_one_conditional_an_expert_layer_and_the_decode_program_none(
+        toy, monkeypatch):
+    """What "the decode call is untouched" means, without a chip: lowered
+    for the TPU, the prefill program has ONE conditional an expert layer,
+    the ``ragged_dot`` pair in one branch and the batched ``dot_general``
+    pair in the other, and no ``ragged_dot`` outside them; the decode
+    program has no ``ragged_dot`` and no conditional that holds a matmul
+    (the sampler's, which both programs have, holds none)."""
+    from torchdistpackage_tpu.parallel import moe as M
+
+    _, cfg, params = toy
+    monkeypatch.setattr(M, "_BATCHED_EXPERTS_MAX_ROWS", 8)
+    eng = ServingEngine(params, cfg, num_slots=3, block_size=8, chunk=8,
+                        max_ctx=32, attn_impl="gather")
+    called = {}
+    dispatch = eng._dispatch
+
+    def keep(fn, args):
+        called.setdefault(args[0].shape, jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            (eng.params, eng.cache, eng.state, *args)))
+        return dispatch(fn, args)
+
+    monkeypatch.setattr(eng, "_dispatch", keep)
+    eng.submit(Request(tokens=list(range(1, 10)), max_new_tokens=3))
+    eng.run_until_idle()
+    assert set(called) == {(3, 8), (3, 1)}
+    text = {shape: eng._step_fn.trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+        for shape, args in called.items()}
+    ragged = '"chlo.ragged_dot"'
+    # the batched form's matmuls carry the expert as a batching dimension
+    batched = re.compile(r"stablehlo\.dot_general .*batching_dims")
+
+    prefill = [b for b in _conditionals(text[3, 8])
+               if any("dot_general" in part or ragged in part for part in b)]
+    assert len(prefill) == cfg.pattern.count("E") == 3
+    for branches in prefill:
+        assert len(branches) == 2
+        assert sorted(part.count(ragged) for part in branches) == [0, 2]
+        for part in branches:
+            assert len(batched.findall(part)) == (0 if ragged in part else 2)
+    assert text[3, 8].count(ragged) == 2 * 3
+    assert ragged not in text[3, 1]
+    assert not [b for b in _conditionals(text[3, 1])
+                if any("dot_general" in part for part in b)]
+    assert len(batched.findall(text[3, 1])) >= 2 * 3

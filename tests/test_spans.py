@@ -525,10 +525,13 @@ def test_kernel_lowers_under_its_name(monkeypatch, kernel):
     trace then shows (``%flash_fwd.1 = ... custom-call``).  Lowered for TPU
     from the CPU: every kernel the package holds lowers for the chip."""
     fn, args = _kernel_cases()[kernel]()
-    for mod in ("flash_attention", "paged_attention", "mla_attention"):
-        monkeypatch.setattr(
-            importlib.import_module(f"torchdistpackage_tpu.ops.{mod}"),
-            "_interpret", lambda: False)
+    # every module imported BEFORE any is patched: two of them take their
+    # `_interpret` from flash_attention as they are imported, and one first
+    # imported under the patch would keep it for the rest of the process
+    mods = [importlib.import_module(f"torchdistpackage_tpu.ops.{mod}")
+            for mod in ("flash_attention", "paged_attention", "mla_attention")]
+    for mod in mods:
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
     text = jax.jit(fn).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert "tpu_custom_call" in text

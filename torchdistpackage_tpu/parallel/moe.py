@@ -594,33 +594,55 @@ def _unbiased_act(h: jnp.ndarray, act: str) -> jnp.ndarray:
     return jax.nn.silu(h[..., :F]) * h[..., F:]
 
 
-#: A call of at most this many tokens (a decode call: one a slot) runs the
-#: unbiased experts as ONE batched matmul over every held expert at the exact
-#: no-drop capacity C = T (a token takes an expert at most once), not as
-#: ``ragged_dot`` groups.  At decode the layer is bound by the experts'
-#: weights, and the batched form reads them once at 89% of the HBM peak
-#: (1.9 ms for 128 experts of [1024, 2688], both GEMMs) where the grouped
-#: GEMM took 4.2-5.1 ms AND as long as the experts its rows touch, which
-#: moved a run's rate by 2.4% between seeds (PERF.md, PR 26).  Its padded
-#: compute grows as held x T rows: past this size the grouped GEMM wins.
-_BATCHED_EXPERTS_MAX_TOKENS = 128
+#: The most rows ONE held expert may get for the unbiased experts to run as
+#: ONE batched matmul over every held expert (each expert ``C`` slots, the
+#: exact no-drop capacity of the call) and not as ``ragged_dot`` groups: the
+#: capacity ``C`` of :func:`_batched_experts`.  The batched form reads every
+#: held expert's weights once (89% of the HBM peak: 1.9 ms for 128 experts of
+#: [1024, 2688], both GEMMs, where the grouped GEMM took 4.2-5.1 ms AND as
+#: long as the experts its rows touch, which moved a run's rate by 2.4%
+#: between seeds: PERF.md, PR 26) and does ``C`` flop a byte of weight.  The
+#: v5e's ridge is 197e12 / 819e9 = 240 flop a byte, so up to 128 the form
+#: stays bound by that one pass for ANY expert shape; at 256 it no longer
+#: does.  A call of at most this many tokens (a decode call: one a slot)
+#: cannot give an expert more (a token takes an expert at most once); a
+#: wider call (a prefill call, mostly padding) asks its largest group.
+_BATCHED_EXPERTS_MAX_ROWS = 128
 
 
-def _batched_experts(ex, rows, sorted_expert, group_sizes, T: int, act: str):
+def _batched_experts(ex, rows, sorted_expert, group_sizes, C: int, act: str,
+                     fetch: bool = False):
     """``rows`` [R, d] sorted by expert (``sorted_expert`` [R]; values past
     the last group are no expert's) -> the experts' outputs, row for row
-    ([R, d]; zero for a row of no expert).  Every expert gets ``T`` slots;
-    row r sits in slot ``r - start of its group``."""
-    n, d = ex["w1"].shape[0], rows.shape[-1]
+    ([R, d]; zero for a row of no expert).  Every expert gets ``C`` slots
+    (no group may be larger); row r sits in slot ``r - start of its
+    group``.  A small call PUTS each of its few rows into its slot; a wide
+    one, most of whose rows are padding's or absent experts', has each slot
+    ``fetch`` its row (a gather of ``n * C`` rows, not a scatter of ``R``:
+    0.2-1.1 ms a layer less on a v5e at every wide shape measured, PERF.md
+    section 6, PR 40)."""
+    n, (R, d) = ex["w1"].shape[0], rows.shape
     starts = jnp.cumsum(group_sizes) - group_sizes
-    held = sorted_expert < n
-    pos = jnp.arange(rows.shape[0]) - starts[jnp.minimum(sorted_expert, n - 1)]
-    slot = jnp.where(held, sorted_expert * T + pos, n * T)   # n * T: nowhere
-    xe = jnp.zeros((n * T, d), rows.dtype).at[slot].set(rows, mode="drop")
-    h = _unbiased_act(
-        jnp.einsum("ecd,edf->ecf", xe.reshape(n, T, d), ex["w1"]), act)
-    out = jnp.einsum("ecf,efd->ecd", h, ex["w2"]).reshape(n * T, d)
+    pos = jnp.arange(R) - starts[jnp.minimum(sorted_expert, n - 1)]
+    slot = jnp.where(sorted_expert < n, sorted_expert * C + pos,
+                     n * C)                                   # n * C: nowhere
+    if fetch:
+        live = jnp.arange(C) < group_sizes[:, None]           # [n, C]
+        at = jnp.where(live, starts[:, None] + jnp.arange(C), 0)
+        xe = jnp.where(live[..., None], rows[at], 0)
+    else:
+        xe = jnp.zeros((n * C, d), rows.dtype).at[slot].set(
+            rows, mode="drop").reshape(n, C, d)
+    h = _unbiased_act(jnp.einsum("ecd,edf->ecf", xe, ex["w1"]), act)
+    out = jnp.einsum("ecf,efd->ecd", h, ex["w2"]).reshape(n * C, d)
     return out.at[slot].get(mode="fill", fill_value=0)
+
+
+def _grouped_experts(ex, rows, group_sizes, act: str):
+    """The same experts as ``ragged_dot`` groups: time follows the experts
+    touched, whatever their rows; rows past the last group are undefined."""
+    h = _unbiased_act(jax.lax.ragged_dot(rows, ex["w1"], group_sizes), act)
+    return jax.lax.ragged_dot(h, ex["w2"], group_sizes)
 
 
 def moe_serve_forward(
@@ -675,7 +697,13 @@ def moe_serve_forward(
     they sort behind every group with the absent experts' and cost the
     grouped matmul nothing (a compact prefill call is mostly padding, all of
     it the same token: its rows fell on the same few experts, a different
-    few with every seed's weights).
+    few with every seed's weights).  These unbiased experts run as ONE
+    batched matmul over every held expert wherever no held expert got more
+    than ``_BATCHED_EXPERTS_MAX_ROWS`` rows, which a call of at most that
+    many tokens cannot and a wider call asks of its group sizes on the
+    device (``lax.cond``; the ``ragged_dot`` pair otherwise: the same
+    operands, the same rows out); the metrics say which under
+    ``layers_batched`` (1.0 / 0.0).
 
     ``score='mlp'`` (:func:`_mlp_route`) is the same layer behind a router
     that is a network: ``depth`` [B, S, R] is the router's stream as the
@@ -733,6 +761,8 @@ def moe_serve_forward(
             metrics["experts_touched"] = jnp.sum(counts > 0).astype(
                 jnp.float32)
             metrics["gate_idx"] = gate_idx.reshape(B, S, k)
+        if batched is not None:
+            metrics["layers_batched"] = batched
         return metrics
 
     src = tokens
@@ -750,22 +780,32 @@ def moe_serve_forward(
     )[:n_held].astype(jnp.int32)
 
     ex = params["experts"]
+    batched = None   # the unbiased path alone: 1.0 where it ran batched
     if ex["w1"].ndim == 4:  # swiglu: [E, 2, D, F] stacked gate/up
         F = ex["w1"].shape[-1]
         w1 = ex["w1"].transpose(0, 2, 1, 3).reshape(-1, D, 2 * F)
         gu = jax.lax.ragged_dot(rows, w1, group_sizes)
         gu = gu + ex["b1"].reshape(-1, 2 * F)[sorted_expert]
         h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
+        out = jax.lax.ragged_dot(h, ex["w2"], group_sizes)
     elif cfg.act in ("relu2", "swiglu"):  # no biases; swiglu: [E, D, 2F]
-        h = None if T <= _BATCHED_EXPERTS_MAX_TOKENS else _unbiased_act(
-            jax.lax.ragged_dot(rows, ex["w1"], group_sizes), cfg.act)
+        C = _BATCHED_EXPERTS_MAX_ROWS
+        if T <= C:  # a small call: no expert can get more than T rows
+            batched = jnp.ones((), jnp.float32)
+            out = _batched_experts(ex, rows, sorted_expert, group_sizes, T,
+                                   cfg.act)
+        else:  # by the largest group, which the padding rows are not in
+            fits = jnp.max(group_sizes) <= C
+            batched = fits.astype(jnp.float32)
+            out = jax.lax.cond(
+                fits,
+                lambda r: _batched_experts(ex, r, sorted_expert, group_sizes,
+                                           C, cfg.act, fetch=True),
+                lambda r: _grouped_experts(ex, r, group_sizes, cfg.act),
+                rows)
     else:
         h = jax.lax.ragged_dot(rows, ex["w1"], group_sizes)
         h = jax.nn.gelu(h + ex["b1"][sorted_expert])
-    if h is None:  # a small call: every held expert in one batched matmul
-        out = _batched_experts(ex, rows, sorted_expert, group_sizes, T,
-                               cfg.act)
-    else:
         out = jax.lax.ragged_dot(h, ex["w2"], group_sizes)
     if "b2" in ex:
         out = out + ex["b2"][sorted_expert]

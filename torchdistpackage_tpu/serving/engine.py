@@ -177,7 +177,7 @@ DRAIN_SCHEMA = "tdp-engine-drain/v1"
 #: engine with fewer slots a group carries them all): a tick with n slots
 #: prefilling makes ceil(n / W) calls of this ONE signature, queued back to
 #: back.  A call costs a fixed part F (one pass over the weights, an expert
-#: layer's grouped GEMMs at their price a group, a launch and a fetch) and
+#: layer's experts at their form's price, a launch and a fetch) and
 #: p a slot, and a steady tick of a full engine admits one or two prompts:
 #: every row beyond theirs is computed for nobody.  On a v5e one steady
 #: call at W = 8 / 4 / 2 / 1 reads 96 / 49 / 27 / 17 ms (a dense 7B at half
@@ -190,8 +190,24 @@ DRAIN_SCHEMA = "tdp-engine-drain/v1"
 #: of the benchmark (+1.5 to +3.2%; 2 read +8.8% and +9.8% in the two
 #: cells whose F is most of the call).  8 was PR 25's, chosen while a call
 #: also copied the KV pool (~30 of its ~43 ms fixed; PR 27 took the copy
-#: away).  PERF.md section 6, PR 37, has every reading.
+#: away).  PERF.md section 6, PR 37, has every reading.  Since PR 40 the
+#: experts of such a call run batched wherever no held expert got more
+#: than 128 real rows (parallel/moe.py), which took ~21 and ~12 ms of F
+#: out of the first two expert models' calls (W = 4 reads 29 and 39 ms
+#: now); the width was not chosen again.
 PREFILL_WIDTH = 4
+
+
+#: What a state model's calls report of their expert layers, in ``stats``
+#: and on every tick record: rows routed, the rows among them that fell on
+#: held experts (a held range is one chip's share), the held experts the
+#: DECODE calls touched, the expert layers executed and those among them
+#: whose experts ran as one batched matmul (``moe_serve_forward`` chooses by
+#: the largest group), the last two again for the PREFILL calls alone, where
+#: the choice is open.  All summed over the expert layers and the calls.
+_MOE_CALL_STATS = ("moe_rows_routed", "moe_rows_held", "experts_touched",
+                   "moe_layers_run", "moe_layers_batched",
+                   "prefill_moe_layers_run", "prefill_moe_layers_batched")
 
 
 @dataclasses.dataclass
@@ -696,7 +712,7 @@ class ServingEngine:
         self._tick_prefill_rids: List[int] = []
         self._tick_decode_rids: List[int] = []
         self._tick_emitted = 0
-        self._tick_moe = [0.0, 0.0, 0.0]
+        self._tick_moe = dict.fromkeys(_MOE_CALL_STATS, 0.0)
         #: indexed attention (``cfg.index_width``): how many positions a
         #: query keeps at most (0: attention is not indexed), and this
         #: tick's (query, position) pairs scored and selected
@@ -809,8 +825,10 @@ class ServingEngine:
         whose state each compact prefill row carries) and ``n_valid`` (the
         real positions of each row: padding advances no state).  The
         expert layers' counters always ride along, as the MoE family's
-        do, plus ``[rows routed, rows on held experts, experts touched]``
-        and, with ``record_routing``, every position's chosen experts.
+        do, plus ``[rows routed, rows on held experts, experts touched,
+        expert layers that ran batched]`` (one vector: a transfer costs by
+        the array) and, with ``record_routing``, every position's chosen
+        experts.
         ``prev`` (``run_ahead``'s decode call): ``(tok, keys, take)``, the
         call before's sampled tokens and advanced keys as they lie on the
         device, and the rows that take theirs from there."""
@@ -837,7 +855,7 @@ class ServingEngine:
                      "dropped_token_rate": jnp.zeros((), jnp.float32)}
             share = jnp.stack([m.get(k, jnp.zeros((), jnp.float32)) for k in
                                ("rows_routed", "rows_held",
-                                "experts_touched")])
+                                "experts_touched", "layers_batched")])
             out = (cache, state, tok, keys, m["expert_tokens"][None, :],
                    m["dropped_token_rate"][None], share)
             if record:
@@ -2253,7 +2271,7 @@ class ServingEngine:
             self._tick_prefill_rids = []
             self._tick_decode_rids = []
             self._tick_emitted = 0
-            self._tick_moe = [0.0, 0.0, 0.0]
+            self._tick_moe = dict.fromkeys(_MOE_CALL_STATS, 0.0)
             self._tick_dsa = [0, 0]
             if self.chaos is not None:
                 self.chaos.before_engine_tick(self._tick, self)
@@ -2339,8 +2357,7 @@ class ServingEngine:
             if st["spec_drafted"] else 0.0,
         }
         if self.state_model:
-            rec.update(zip(("moe_rows_routed", "moe_rows_held",
-                            "experts_touched"), self._tick_moe))
+            rec.update(self._tick_moe)
         if self._idx_topk:
             rec.update(zip(("indexed_positions", "selected_positions"),
                            self._tick_dsa))
@@ -2851,12 +2868,7 @@ class ServingEngine:
                       "migrated_in": 0, "migrated_out": 0,
                       "imports_aborted": 0,
                       "cp_ring_hops": 0, "cp_ring_bytes": 0,
-                      # a held range of experts (one chip's share): rows
-                      # routed, the rows among them that fell on held
-                      # experts, and the held experts the DECODE calls
-                      # touched (summed over the expert layers and calls)
-                      "moe_rows_routed": 0.0, "moe_rows_held": 0.0,
-                      "experts_touched": 0.0}
+                      **dict.fromkeys(_MOE_CALL_STATS, 0.0)}
         self._decode_sigs: set = set()
         self._prefill_sigs: set = set()
         self._cow_sigs: set = set()
@@ -2896,16 +2908,22 @@ class ServingEngine:
         ``et``: [groups, E] per-dp-group routed-token counts (groups = 1
         without a mesh), ``dr``: [groups] drop rates.  ``share`` (a held
         range of experts): ``[rows routed, rows on held experts, held
-        experts touched]`` of the call, into ``stats`` and the tick."""
+        experts touched, expert layers that ran batched]`` of the call,
+        into ``stats`` and the tick."""
         if share is not None:
-            routed, held, touched = (float(v) for v in np.asarray(share))
-            self.stats["moe_rows_routed"] += routed
-            self.stats["moe_rows_held"] += held
-            self._tick_moe[0] += routed
-            self._tick_moe[1] += held
+            routed, held, touched, batched = (
+                float(v) for v in np.asarray(share))
+            layers = float(self.cfg.pattern.count("E"))
+            booked = {"moe_rows_routed": routed, "moe_rows_held": held,
+                      "moe_layers_run": layers, "moe_layers_batched": batched}
             if decode:
-                self.stats["experts_touched"] += touched
-                self._tick_moe[2] += touched
+                booked["experts_touched"] = touched
+            else:
+                booked["prefill_moe_layers_run"] = layers
+                booked["prefill_moe_layers_batched"] = batched
+            for k, v in booked.items():
+                self.stats[k] += v
+                self._tick_moe[k] += v
         et = np.asarray(et, np.float64).sum(axis=0)
         if self._moe_expert_tokens is None:
             self._moe_expert_tokens = et
