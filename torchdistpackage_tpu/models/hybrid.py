@@ -23,6 +23,17 @@ layer is ONE mixer and not attention + MLP (the ``nemotron_h`` shape):
   layer that keeps both: keys and values in the block pool (the ``*``
   layers' pool and kernels), and a tail a sequence (the rows the next
   position's convolutions and shifted value need);
+- ``W``  WINDOWED grouped-query attention, rotated (rope over the whole
+  head, half-split pairs): a query at ``t`` reads the keys in ``(t -
+  window, t]`` and nothing before them.  It stands beside ``*`` layers in
+  one model (three window layers to one global layer, say), and the two
+  kinds keep different amounts of cache: the model has TWO block pools of
+  one block shape, the ``*`` layers' holding every position of a sequence
+  and the ``W`` layers' the last ``window`` (+ a call's rows) alone
+  (serving/paged_cache.py, docs/serving.md "Two pools").  Both kinds take
+  two optional sets of leaves: ``wg`` (an output gate, ``y = (o *
+  sigmoid(x W_g)) W_o``) and ``q_norm`` / ``k_norm`` (a learned RMSNorm
+  over each query head and each key head, before the rotation);
 - ``S``  indexed (sparse) attention (:func:`indexed_attention_mixer`):
   rotated GQA with a learned norm a head, behind an INDEXER that scores
   every cached position with a second, small key (one ``idx_dim`` row a
@@ -31,10 +42,13 @@ layer is ONE mixer and not attention + MLP (the ``nemotron_h`` shape):
   first layer here with positions of the ordinary kind: rope over the whole
   head, by three position axes (``mrope_section``) that are equal for text.
 
-Every layer is ``x <- x + mixer(RMSNorm(x))``, so a pre-norm block of
+A layer is ``x <- x + mixer(RMSNorm(x))``, so a pre-norm block of
 attention + FFN is two layers here (``"LD"``, ``"LE"``, ``"CE"``); a layer
-with a ``res`` leaf mixes by four learned vectors instead, ``x <- a_h x +
-b_h + a_y y + b_y``.  A final RMSNorm, then the head: ``params["head"]``
+with a ``post_norm`` leaf norms what its mixer gives as well, ``x <- x +
+RMSNorm_post(mixer(RMSNorm(x)))`` (a block of four norms is two such
+layers), and one with a ``res`` leaf mixes by four learned vectors
+instead, ``x <- a_h x + b_h + a_y y + b_y``.  The embedding is scaled by
+``embed_scale`` where a model says so.  A final RMSNorm, then the head: ``params["head"]``
 [D, V], or the embedding table itself where the tree has no such leaf (a
 tied head).  The biases are the convolutions', the network router's
 (``moe_score='mlp'``) and the ``res`` leaves'; no projection has one.
@@ -80,7 +94,7 @@ class HybridConfig:
     dim: int
     #: one character a layer: 'M' Mamba-2 | '*' attention | 'E' experts |
     #: 'L' latent attention | 'D' dense gated MLP | 'C' convolved attention
-    #: | 'S' indexed attention
+    #: | 'S' indexed attention | 'W' windowed, rotated attention
     pattern: str
     max_seq: int
     nheads: int
@@ -134,6 +148,10 @@ class HybridConfig:
     idx_topk: int = 0
     idx_rope: int = 0
     mrope_section: Optional[Tuple[int, ...]] = None
+    #: windowed attention ('W'): the keys a query reads, itself included
+    window: int = 0
+    #: what the embedding's rows are multiplied by (1: as they lie)
+    embed_scale: float = 1.0
     rope_theta: float = 10000.0
     #: a rope-scaling dict as ``rope_cache`` takes it (yarn), or None
     rope_scaling: Optional[Dict[str, Any]] = None
@@ -150,15 +168,24 @@ class HybridConfig:
     moe_dispatch = "auto"
 
     def __post_init__(self):
-        bad = set(self.pattern) - set("M*ELDCS")
+        bad = set(self.pattern) - set("M*ELDCSW")
         if bad or not self.pattern:
             raise ValueError(
                 f"pattern {self.pattern!r}: one of 'M', '*', 'E', 'L', 'D', "
-                f"'C', 'S' a layer")
+                f"'C', 'S', 'W' a layer")
         if sum(bool(set(kinds) & set(self.pattern))
-               for kinds in ("*C", "L", "S")) > 1:
+               for kinds in ("*CW", "L", "S")) > 1:
             raise ValueError(
-                "one kind of block pool a model: '*' / 'C', 'L' or 'S'")
+                "one kind of block pool a model: '*' / 'C' (a 'W' layer's "
+                "pool, of their block shape, may stand beside it), 'L' or "
+                "'S'")
+        if "W" in self.pattern and not (
+                self.window > 0 and "*" in self.pattern):
+            raise ValueError(
+                "a 'W' layer needs window > 0 and a '*' layer beside it: "
+                "the window layers' pool stands beside the pool of the "
+                "layers that keep every position (a model of window layers "
+                "alone is the GPTConfig family's sliding_window)")
         if not self.head_dim:
             if self.dim % self.nheads:
                 raise ValueError("dim does not divide by nheads: say head_dim")
@@ -208,9 +235,15 @@ class HybridConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers that keep keys and values, or the latent they are made
-        from (the block pool's depth)."""
+        """Layers that keep keys and values of EVERY position, or the
+        latent they are made from (the block pool's depth)."""
         return sum(self.pattern.count(k) for k in "*LCS")
+
+    @property
+    def window_layers(self) -> int:
+        """Layers that keep the last :attr:`window` positions' keys and
+        values alone (the window pool's depth; 0: no such pool)."""
+        return self.pattern.count("W")
 
     @property
     def latent_width(self) -> int:
@@ -443,21 +476,40 @@ def mamba2_mixer(
 # ---------------------------------------------------------------- attention
 
 
-def attention_mixer(p, x, cfg: HybridConfig, ck, cv, offset, cache_ops):
-    """Position-free GQA on the block pool: x [B, S, D] (normed) -> (y, ck,
-    cv).  ``cache_ops`` is the ``(write, attend)`` pair of
-    ``serving/paged_cache.py``, as ``cached_block_forward`` takes it."""
+def attention_mixer(p, x, cfg: HybridConfig, ck, cv, offset, cache_ops,
+                    window: Optional[int] = None):
+    """GQA on a block pool: x [B, S, D] (normed) -> (y, ck, cv).  ``window``
+    None: a ``*`` layer, position-free, every key ``<= t``; else a ``W``
+    layer: queries and keys rotated over the whole head, keys in ``(t -
+    window, t]``.  Leaves ``q_norm`` / ``k_norm``: a learned RMSNorm over
+    each query and key head (before the rotation); ``wg``: an output gate,
+    ``y = (o * sigmoid(x W_g)) W_o``.  ``cache_ops`` is the ``(write,
+    attend)`` pair of ``serving/paged_cache.py`` for the layer's own pool,
+    as ``cached_block_forward`` takes it."""
     B, S, _ = x.shape
     hd = cfg.head_dim
     write, attend = cache_ops
-    q = dense(x, p["wq"]).reshape(B, S, -1, hd).transpose(0, 2, 1, 3)
+    q = dense(x, p["wq"]).reshape(B, S, -1, hd)
     kv = dense(x, p["wkv"], "bsd,tdh->tbsh")
-    k = kv[0].reshape(B, S, -1, hd).transpose(0, 2, 1, 3)
+    k = kv[0].reshape(B, S, -1, hd)
+    if "q_norm" in p:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
     v = kv[1].reshape(B, S, -1, hd).transpose(0, 2, 1, 3)
+    if window is not None:
+        pos = offset[:, None] + jnp.arange(S)[None, :]
+        cos, sin = rope_cache(pos.reshape(-1), hd, cfg.rope_theta,
+                              scaling=cfg.rope_scaling)
+        rope = (cos.reshape(B, 1, S, -1), sin.reshape(B, 1, S, -1))
+        q, k = apply_rope(q, cache=rope), apply_rope(k, cache=rope)
     ck = write(ck, k, offset)
     cv = write(cv, v, offset)
-    out = attend(q, ck, cv, offset, window=None)
+    out = attend(q, ck, cv, offset, window=window)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, q.shape[1] * hd)
+    if "wg" in p:
+        out = out * jax.nn.sigmoid(dense(x, p["wg"]).astype(F32)).astype(
+            out.dtype)
     return dense(out, p["wo"]), ck, cv
 
 
@@ -693,6 +745,7 @@ def hybrid_paged_forward(
     offset: jnp.ndarray,
     last_idx=None,
     positions=None,
+    window_ops=None,
 ):
     """``tokens`` [B, S] through the stack.  ``cache``: the block pool of
     the attention layers (``{'k','v': [kv_layers, ...]}``, with an ``'idx'``
@@ -700,7 +753,10 @@ def hybrid_paged_forward(
     it is latent), reached through
     ``cache_ops(layer)`` (the pool's ``(write, attend)`` pair for one of
     its layers; the pool itself is threaded whole through the attention
-    layers); ``state``: :func:`init_state`'s arrays with one row a
+    layers).  A model with 'W' layers has a second pool under
+    ``cache['win']`` (``{'k','v': [window_layers, ...]}``), reached through
+    ``window_ops(layer)``, that pool's pair over its own table; a layer of
+    either pool is named by its index WITHIN its kind.  ``state``: :func:`init_state`'s arrays with one row a
     row of ``tokens``; ``n_valid`` [B]: the real positions of each row.
     The network router's stream (``moe_score='mlp'``) is a second carry
     through the loop, from one expert layer to the next.  ``positions``
@@ -718,7 +774,11 @@ def hybrid_paged_forward(
     S = tokens.shape[1]
     valid = jnp.arange(S)[None, :] < n_valid[:, None]
     h = jnp.take(params["tok_emb"], tokens, axis=0)
+    if cfg.embed_scale != 1.0:
+        h = (h.astype(F32) * cfg.embed_scale).astype(h.dtype)
     cache, kv_layer = dict(cache), 0
+    win = dict(cache["win"]) if "win" in cache else None
+    w_layer = 0
     ssm, conv, tails, mets, kept = [], [], [], [], []
     mcfg = cfg.moe if cfg.moe_experts else None
     depth = None
@@ -735,6 +795,11 @@ def hybrid_paged_forward(
                 lp, x, cfg, cache["k"], cache["v"], offset,
                 cache_ops(kv_layer))
             kv_layer += 1
+        elif kind == "W":
+            y, win["k"], win["v"] = attention_mixer(
+                lp, x, cfg, win["k"], win["v"], offset,
+                window_ops(w_layer), window=cfg.window)
+            w_layer += 1
         elif kind == "C":
             y, cache["k"], cache["v"], tail = cca_mixer(
                 lp, x, cfg, cache["k"], cache["v"],
@@ -759,6 +824,8 @@ def hybrid_paged_forward(
                 lp, x, mcfg, return_metrics=True, valid=valid, depth=depth)
             mets.append(met)
             depth = stream[0] if stream else None
+        if "post_norm" in lp:
+            y = rms_norm(y, lp["post_norm"], cfg.norm_eps)
         if "res" in lp:
             a_h, b_h, a_y, b_y = (lp["res"][k].astype(F32) for k in (
                 "a_h", "b_h", "a_y", "b_y"))
@@ -767,6 +834,8 @@ def hybrid_paged_forward(
         else:
             h = h + y
     state = {"ssm": tuple(ssm), "conv": tuple(conv), "tail": tuple(tails)}
+    if win is not None:
+        cache["win"] = win
     metrics = None
     if mets:
         routing = jnp.stack([m.pop("gate_idx") for m in mets], axis=2)
@@ -790,7 +859,8 @@ def init_hybrid_params(key, cfg: HybridConfig, scaled_residual: bool = False,
     """Seeded parameters in the layout :func:`hybrid_paged_forward` reads
     (tests and examples; a checkpoint converter is not written yet).
     ``scaled_residual``: every layer gets the four ``res`` vectors (at the
-    identity: a 1, b 0); ``tied_head``: no ``head`` leaf."""
+    identity: a 1, b 0); ``tied_head``: no ``head`` leaf.  The optional
+    leaves (``wg``, the head norms, ``post_norm``) are a weight file's."""
     dt, D = cfg.dtype, cfg.dim
 
     def normal(k, shape, fan_in):
@@ -820,7 +890,7 @@ def init_hybrid_params(key, cfg: HybridConfig, scaled_residual: bool = False,
                 "gate_norm": {"scale": jnp.ones((di,), dt)},
                 "out_proj": normal(ks[4], (di, D), di),
             }
-        elif kind == "*":
+        elif kind in "*W":
             dq, dkv = cfg.nheads * hd, cfg.kv_heads * hd
             lp = {"wq": normal(ks[0], (D, dq), D),
                   "wkv": normal(ks[1], (2, D, dkv), D),

@@ -85,6 +85,22 @@ _LANES = 128  # m/l scratch keeps a full lane dim for layout friendliness
 #: 128-row tile (decode: 8 heads x 8 rows; a chunk's 1,024 rows: 1 head).
 _ROWS_PER_STEP = 128
 
+#: Query rows a KV head that the GRID's walk keeps in ONE program, and the
+#: most a program carries where a head's rows pass that.  A program's q and
+#: out blocks (twice over), its float32 (acc, m, l) scratch and the scores of
+#: a KV block all grow with its rows, against a v5e's 16 MB of scoped VMEM.
+#: MEASURED there at rows of 128 (PR 41): 4,096 rows (a chunk of 512 under 8
+#: query heads a KV head) ask for 17.4 MB and compile nowhere; 2,048 is the
+#: edge: 16 query heads x a chunk of 128 over a walk from column 0 have
+#: compiled as one program since PR 26, while 2,048 rows of a walk that
+#: starts at its window asked for 16.27 MB standing alone and fitted inside
+#: an engine's program.  So a call of up to ``_PROGRAM_ROWS`` is the one
+#: program it always was, whatever its window; past it a KV head's query
+#: heads are dealt to programs of at most ``_CHUNK_ROWS`` (4 heads x 256:
+#: well inside), each fetching the head's blocks again (:func:`head_split`).
+_PROGRAM_ROWS = 2048
+_CHUNK_ROWS = 1024
+
 #: VMEM the K + V blocks a program holds twice over may take (bytes): ``2 x 2
 #: x blocks x heads x block`` (a key tile's, or ``fetch_width``) stays under it.
 _KV_VMEM_BUDGET = 8 << 20
@@ -171,10 +187,56 @@ def _heads_per_step(Hkv: int, rows: int, fw: int, block_bytes: int) -> int:
     return hb
 
 
-def fetched_block(tab, off, b, h, j, i, *, S_in: int, bs: int, fw: int):
+def window_binds(window: Optional[int], mb: int, bs: int) -> bool:
+    """Whether a window can lie short of what a table of ``mb`` columns
+    holds.  Where it can, both walks START at the window
+    (:func:`first_column`) and the call carries a kernel name of its own
+    (``swa_decode`` / ``swa_chunk``); where it cannot (no window, or one as
+    wide as the table: a mask that never takes a key out), the walk is the
+    one from column 0, operation for operation."""
+    return window is not None and window < mb * bs
+
+
+def first_column(off, window: int, bs: int):
+    """The table column that holds the first key inside the window of a
+    call's FIRST row, at position ``off``: keys in ``(off - window, off]``.
+    Every column before it lies wholly behind every row's window (a later
+    row's starts later), so neither walk fetches it or multiplies by it;
+    inside it the mask does the rest."""
+    return jnp.maximum(off - (window - 1), 0) // bs
+
+
+def window_columns(window: int, S_in: int, bs: int) -> int:
+    """The most table columns that ``S_in`` rows' windows reach over, at
+    the worst alignment: positions ``[off - window + 1, off + S_in)``."""
+    return (window + S_in - 3) // bs + 2
+
+
+def head_split(groups: int, S_in: int) -> int:
+    """The programs that a KV head's ``groups x S_in`` query rows are dealt
+    to: 1 up to :data:`_PROGRAM_ROWS`; past it the fewest whole query heads'
+    worth that brings a program to :data:`_CHUNK_ROWS` rows or under."""
+    if groups * S_in <= _PROGRAM_ROWS:
+        return 1
+    return next((d for d in range(2, groups + 1) if groups % d == 0
+                 and groups * S_in // d <= _CHUNK_ROWS), groups)
+
+
+def walked_columns(window: Optional[int], mb: int, S_in: int, bs: int) -> int:
+    """The table columns a call's walk reaches over: all ``mb``, or a
+    window's (:func:`window_columns`) where it binds."""
+    if not window_binds(window, mb, bs):
+        return mb
+    return min(mb, window_columns(window, S_in, bs))
+
+
+def fetched_block(tab, off, b, h, j, i, *, S_in: int, bs: int, fw: int,
+                  window: Optional[int] = None):
     """``(pool block, head group)`` that sub-block operand ``i`` asks for at
     grid step ``(b, h, j)``: the index map's rule, as a pure function of the
-    table and the offsets (refs, traced or numpy arrays alike).
+    table and the offsets (refs, traced or numpy arrays alike).  ``window``
+    (one that binds, :func:`window_binds`): step 0 stands at the slot's
+    :func:`first_column`, not at column 0.
 
     Live (``j*fw + i`` within the slot's live blocks): the table's entry.
     Dead: what the operand already HOLDS, so that the pipeline, which skips
@@ -185,6 +247,8 @@ def fetched_block(tab, off, b, h, j, i, *, S_in: int, bs: int, fw: int):
     slot."""
     mb = tab.shape[-1]
     hi1 = jnp.minimum((off[b] + S_in + bs - 1) // bs, mb) - 1
+    if window is not None:   # the operand's columns count from the window
+        i = i + first_column(off[b], window, bs)
     blk = j * fw + i
     own_last = i + fw * (jnp.maximum(hi1 - i, 0) // fw)
     col = jnp.where(blk <= hi1, blk, own_last)
@@ -257,7 +321,7 @@ def call_walk(R: int, Hkv: int, mb: int, bs: int, block_bytes: int,
 def _walk_kernel(
     tab_ref, off_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
     kbuf, vbuf, sem, par_ref, acc_ref, m_ref, l_ref,
-    *, S_in, bs, mb, window, sm_scale, rows, hb, T,
+    *, S_in, bs, mb, window, sm_scale, rows, hb, T, bound,
 ):
     """The decode shape's walk.  Grid ``(slot b, kv-head group h)``, run in
     order; the pools stay in HBM and program ``(b, h)`` loops over the
@@ -272,13 +336,25 @@ def _walk_kernel(
     behind every query position, so the mask takes them out of the scores,
     and as values the rows behind the call's last position are zeroed (so
     are the rows of the slot's own last block that nobody wrote yet):
-    nothing reaches the output even as 0 x NaN."""
+    nothing reaches the output even as 0 x NaN.
+
+    ``bound`` (static; :func:`window_binds`): the walk's block 0 is the
+    slot's :func:`first_column` and not the table's column 0, so a block
+    that lies wholly behind the window is not fetched, waited for or
+    multiplied by, whatever its table column names.  Without it the body is
+    the one from column 0, operation for operation."""
     b, h = pl.program_id(0), pl.program_id(1)
     nh = pl.num_programs(1)
     lay = lay_ref[0]
 
+    def column(b, j):
+        """The table column of the j-th block of slot ``b``'s walk."""
+        return j + first_column(off_ref[b], window, bs) if bound else j
+
     def live_blocks(b):
-        return jnp.minimum((off_ref[b] + S_in + bs - 1) // bs, mb)
+        """The blocks of slot ``b``'s walk."""
+        live = jnp.minimum((off_ref[b] + S_in + bs - 1) // bs, mb)
+        return live - column(b, 0) if bound else live
 
     def tile_copies(b, h, t, half, act):
         """Start or wait for (``act``) the copies of slot ``b``'s tile ``t``
@@ -287,7 +363,7 @@ def _walk_kernel(
         no faster and cost ``T`` times the trace:
         :func:`paged_decode_attention` on what a trace costs.)"""
         def block(i, carry):
-            src = (lay, tab_ref[b, t * T + i], pl.ds(h * hb, hb))
+            src = (lay, tab_ref[b, column(b, t * T + i)], pl.ds(h * hb, hb))
             dst = (half, slice(None), pl.ds(pl.multiple_of(i * bs, bs), bs))
             for pool, buf, side in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
                 act(pltpu.make_async_copy(
@@ -338,12 +414,14 @@ def _walk_kernel(
         v = vbuf[half]
         s = jnp.einsum("hrd,hkd->hrk", q, k,
                        preferred_element_type=jnp.float32)
-        kpos = t * (T * bs) + jax.lax.broadcasted_iota(
+        # the tile's first key position
+        pos0 = column(b, t * T) * bs if bound else t * (T * bs)
+        kpos = pos0 + jax.lax.broadcasted_iota(
             jnp.int32, (hb, rows, T * bs), 2)
         keep = kpos <= qpos
         if window is not None:  # Mistral: key in (qpos - window, qpos]
             keep = keep & (kpos > qpos - window)
-        written = t * (T * bs) + jax.lax.broadcasted_iota(
+        written = pos0 + jax.lax.broadcasted_iota(
             jnp.int32, v.shape, 1) < last
         v = jnp.where(written, v, 0)
         _accumulate(
@@ -362,7 +440,7 @@ def _walk_kernel(
 
 def _kernel(
     tab_ref, off_ref, lay_ref, q_ref, *refs,
-    S_in, bs, window, sm_scale, quantized, fetch_width, rows, hb,
+    S_in, bs, window, sm_scale, quantized, fetch_width, rows, hb, bound,
 ):
     """Grid ``(slot b, kv-head group h, kv-step j)``, ``hb`` KV heads a
     group, the batch axis of every product in here; ``lay_ref`` (the layer
@@ -370,7 +448,9 @@ def _kernel(
     the ``fetch_width`` per-step KV blocks of ``hb`` heads each ((k, v)
     dense or (k8, ks, v8, vs) quantized, sub-block-major), then the output
     ref and the (acc, m, l) online-softmax VMEM scratch carried across j
-    steps."""
+    steps.  ``bound`` (static; :func:`window_binds`): step 0 stands at the
+    slot's :func:`first_column`, as the index map's (:func:`fetched_block`),
+    and the grid is only as long as a window's columns."""
     per = 4 if quantized else 2
     kv_refs = refs[:fetch_width * per]
     o_ref = refs[fetch_width * per]
@@ -379,6 +459,7 @@ def _kernel(
     j = pl.program_id(2)
     off = off_ref[b]
     hi = (off + S_in + bs - 1) // bs  # live KV blocks for this slot
+    col0 = first_column(off, window, bs) if bound else None
 
     @pl.when(j == 0)
     def _init():
@@ -399,6 +480,8 @@ def _kernel(
 
     for i in range(fetch_width):
         blk = j * fetch_width + i  # absolute pool-block step
+        if bound:
+            blk = blk + col0
 
         @pl.when(blk < hi)
         def _compute(i=i, blk=blk):
@@ -421,7 +504,7 @@ def _kernel(
                 keep = keep & (kpos > qpos - window)
             _accumulate(s * sm_scale, keep, upd, acc_ref, m_ref, l_ref)
 
-    @pl.when(j == (hi - 1) // fetch_width)
+    @pl.when(j == ((hi - 1 - col0) if bound else (hi - 1)) // fetch_width)
     def _write():
         # l > 0 for every real row (a query always attends its own
         # position); padded rows divide garbage that is sliced away
@@ -483,24 +566,36 @@ def paged_decode_attention(
         offs = jnp.broadcast_to(offs, (B,))
     # group-major rows: row r = g*S_in + s covers position off + s
     R = groups * S_in
+    # a window that can lie short of the table: both walks start at it and
+    # reach over its columns alone, under a kernel name of their own
+    bound = window_binds(window, mb, bs)
+    cols = walked_columns(window, mb, S_in, bs)
+    # more rows a KV head than one program takes: its query heads go to
+    # ``split`` programs, each fetching the head's blocks
+    split = head_split(groups, S_in)
+    R //= split
     rows, fw, hb, T = call_walk(
-        R, Hkv, mb, bs, bs * hd * k_arr.dtype.itemsize, quantized,
+        R, Hkv, cols, bs, bs * hd * k_arr.dtype.itemsize, quantized,
         fetch_width, q_pad_to)
-    qr = q.reshape(B, Hkv, R, hd)
+    qr = q.reshape(B, Hkv * split, R, hd)
     if rows != R:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - R), (0, 0)))
-    name = "paged_decode" if S_in == 1 else "paged_chunk"
+    name = ("swa_" if bound else "paged_") + (
+        "decode" if S_in == 1 else "chunk")
     scalars = (tables.astype(jnp.int32), offs, lay)
     if T:
         return _walk_call(qr, k_pool, v_pool, scalars, S_in=S_in,
                           window=window, sm_scale=float(sm_scale), hb=hb,
-                          T=T, name=name)[:, :, :R].reshape(B, H, S_in, hd)
+                          T=T, name=name, bound=bound,
+                          )[:, :, :R].reshape(B, H, S_in, hd)
 
     def qidx(b, h, j, tab, off, lay):
         return (b, h, 0, 0)
 
     def kvidx(b, h, j, tab, off, lay, i=0, own_layer=False):
-        blk, hg = fetched_block(tab, off, b, h, j, i, S_in=S_in, bs=bs, fw=fw)
+        blk, hg = fetched_block(tab, off, b, h if split == 1 else h // split,
+                                j, i, S_in=S_in, bs=bs, fw=fw,
+                                window=window if bound else None)
         return (0 if own_layer else lay[0], blk, hg, 0, 0)
 
     # per sub-block (k, v) / (k8, ks, v8, vs), sub-block-major, each block
@@ -522,7 +617,7 @@ def paged_decode_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, Hkv // hb, -(-mb // fw)),
+        grid=(B, Hkv * split // hb, -(-cols // fw)),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hb, rows, hd), qidx),
         scratch_shapes=[
@@ -533,11 +628,11 @@ def paged_decode_attention(
     )
     kernel = functools.partial(
         _kernel, S_in=S_in, bs=bs, window=window, sm_scale=float(sm_scale),
-        quantized=quantized, fetch_width=fw, rows=rows, hb=hb)
+        quantized=quantized, fetch_width=fw, rows=rows, hb=hb, bound=bound)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=_out_struct((B, Hkv, rows, hd), q.dtype, q),
+        out_shape=_out_struct((B, Hkv * split, rows, hd), q.dtype, q),
         compiler_params=_compiler_params(),
         interpret=_interpret(),
         name=name,
@@ -546,7 +641,7 @@ def paged_decode_attention(
 
 
 def _walk_call(qr, k_pool, v_pool, scalars, *, S_in, window, sm_scale, hb, T,
-               name):
+               name, bound):
     """The ``pallas_call`` of :func:`_walk_kernel` over padded group-major
     rows ``qr`` [B, Hkv, rows, hd] and the stacked pools."""
     B, Hkv, rows, hd = qr.shape
@@ -575,7 +670,7 @@ def _walk_call(qr, k_pool, v_pool, scalars, *, S_in, window, sm_scale, hb, T,
     )
     kernel = functools.partial(
         _walk_kernel, S_in=S_in, bs=bs, mb=mb, window=window,
-        sm_scale=sm_scale, rows=rows, hb=hb, T=T)
+        sm_scale=sm_scale, rows=rows, hb=hb, T=T, bound=bound)
     # programs run in order: each starts the next one's first copies
     params = None if _interpret() else pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"))
@@ -813,6 +908,7 @@ def modeled_attend_temp_bytes(
     impl: str, *, batch: int, kv_heads: int, max_blocks: int,
     block_size: int, head_dim: int, s_in: int = 1, groups: int = 1,
     itemsize: int = 4, fetch_width: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> int:
     """Modeled per-layer attention working-set bytes for one decode step —
     the MemoryModel-style no-compile estimate the 32k serving test (and a
@@ -825,18 +921,22 @@ def modeled_attend_temp_bytes(
     in VMEM (the q/out rows of its ``hb`` KV heads plus the K and V blocks
     it keeps twice over: both halves of a key tile of ``T`` blocks where the
     shape takes the in-kernel walk, ``fetch_width`` double-buffered blocks
-    a side where it walks the grid; all from :func:`call_walk`) times the
+    a side where it walks the grid; all from :func:`call_walk` over the
+    columns the walk reaches, :func:`walked_columns`, and the rows of one of
+    a head's :func:`head_split` programs, as the wrapper asks) times the
     programs of one step: O(block), independent of context."""
     if impl == "gather":
         return 2 * batch * kv_heads * max_blocks * block_size * head_dim * itemsize
     if impl == "pallas":
-        rows = groups * s_in
+        split = head_split(groups, s_in)
+        rows = groups * s_in // split
         block = block_size * head_dim * itemsize
         _padded, fw, hb, T = call_walk(
-            rows, kv_heads, max_blocks, block_size, block,
-            fetch_width=fetch_width)
+            rows, kv_heads, walked_columns(window, max_blocks, s_in,
+                                           block_size),
+            block_size, block, fetch_width=fetch_width)
         # one program: hb heads' q and out rows, T or fw K + V blocks, twice
         program = hb * (2 * rows * head_dim * itemsize
                         + 2 * 2 * (T or fw) * block)
-        return batch * (kv_heads // hb) * program
+        return batch * (kv_heads * split // hb) * program
     raise ValueError(f"impl must be 'gather' or 'pallas', got {impl!r}")

@@ -164,6 +164,8 @@ from .paged_cache import (
     paged_forward,
     paged_forward_moe,
     pool_bytes,
+    window_bytes,
+    window_reach,
 )
 from .tracing import TICK_PHASES, serving_metrics_record
 
@@ -332,7 +334,7 @@ class _SlotState:
     ``orig_prompt_len``/``pre_gen`` account for resumed requests whose
     admitted prompt includes an already-emitted prefix (drain/resume)."""
 
-    __slots__ = ("state", "rid", "req", "blocks", "prompt", "off",
+    __slots__ = ("state", "rid", "req", "blocks", "wblocks", "prompt", "off",
                  "generated", "t_submit", "t_admit", "t_last", "ttft_s",
                  "tpot_s", "orig_prompt_len", "pre_gen", "routing")
 
@@ -344,6 +346,10 @@ class _SlotState:
         self.rid = -1
         self.req: Optional[Request] = None
         self.blocks: List[int] = []
+        #: the blocks it holds of the window layers' pool (a model with
+        #: window layers; which column names which changes as they are
+        #: handed on, the set does not)
+        self.wblocks: List[int] = []
         self.prompt: Optional[np.ndarray] = None
         self.off = 0
         self.generated: List[int] = []
@@ -500,6 +506,30 @@ class ServingEngine:
         #: the pattern has no such layer); docs/serving.md "State models"
         self.state_model = hasattr(cfg, "state_layers")
         if self.state_model:
+            # a window pool keeps a sequence's last window alone: a block
+            # that fell behind it is handed on to a column ahead and
+            # overwritten (docs/serving.md "Two pools")
+            for on, what, why in (
+                    (prefix_cache, "prefix_cache",
+                     "a handed-on block is no prefix block: the window "
+                     "layers' keys of a shared prefix are overwritten as "
+                     "its first owner goes on, so a later prompt would map "
+                     "the other layers' blocks and find no window ones"),
+                    (spec_k, "spec_k",
+                     "a rejected draft's rows may already have overwritten "
+                     "a handed-on block that the accepted length still "
+                     "reads"),
+                    (cp_axis, "cp_axis",
+                     "the ring rotates ONE pool's slices through one table"),
+                    (mesh, "a mesh (tp/dp/ep)",
+                     "the window pool has no sharded form and one "
+                     "allocator"),
+                    (kv_quant, "kv_quant",
+                     "the window pool has no int8 form")):
+                if on and getattr(cfg, "window_layers", 0):
+                    raise NotImplementedError(
+                        f"{what} with a window pool is not supported: "
+                        f"{why} (ROADMAP queue 2 A2)")
             for on, what in ((prefix_cache and cfg.state_layers,
                               "prefix_cache"),
                              (spec_k, "spec_k"), (cp_axis, "cp_axis"),
@@ -645,6 +675,19 @@ class ServingEngine:
                 f"sharded over cp_axis")
         self.num_blocks = num_blocks  # per dp group
         self._allocs = [BlockAllocator(num_blocks) for _ in range(self.dp)]
+        #: a model with window layers (models/hybrid.py kind 'W'): the
+        #: SECOND pool, its own allocator and table.  A slot holds at most
+        #: ``window_reach`` of its blocks at a time (the columns from the
+        #: first key inside the window of a call's first row to the call's
+        #: last row), so the pool is every slot's reach and the NULL block,
+        #: whatever ``num_blocks`` says of the pool that keeps everything
+        self.window = int(getattr(cfg, "window_layers", 0) and cfg.window)
+        self.window_reach = (window_reach(self.window, chunk, block_size)
+                             if self.window else 0)
+        self.window_blocks = (1 + num_slots * self.window_reach
+                              if self.window else 0)
+        self._walloc = (BlockAllocator(self.window_blocks)
+                        if self.window else None)
         self._param_specs = param_specs
 
         from .sim import CompiledDeviceStep
@@ -663,6 +706,9 @@ class ServingEngine:
             sp.attrs.update(bytes=pool_bytes(self.cache), **self._walk_attrs())
             if "idx" in self.cache:  # of which the indexer's keys
                 sp.attrs.update(index_bytes=index_bytes(self.cache))
+            if self.window:  # of which the window layers' pool
+                sp.attrs.update(window_bytes=window_bytes(self.cache),
+                                window_blocks=self.window_blocks)
         #: state models: the recurrent state, one row a slot, beside the
         #: pool (``models.hybrid.init_state``); like the pool, the compiled
         #: step is handed it as a donated argument and the engine keeps
@@ -681,6 +727,10 @@ class ServingEngine:
         # host-visible device state, one row per slot
         V = cfg.vocab_size
         self._tables = np.zeros((num_slots, self.max_blocks), np.int32)
+        #: the window pool's table: absolute columns as the other's, a
+        #: column behind the window NULL once its block was handed on
+        self._wtables = (np.zeros_like(self._tables) if self.window
+                         else None)
         self._lengths = np.zeros(num_slots, np.int32)
         self._last_tok = np.zeros(num_slots, np.int32)
         self._temps = np.zeros(num_slots, np.float32)
@@ -719,6 +769,9 @@ class ServingEngine:
         self._idx_topk = (int(cfg.idx_topk)
                           if getattr(cfg, "index_width", 0) else 0)
         self._tick_dsa = [0, 0]
+        #: a window pool: this tick's [positions the window layers hold for
+        #: the calls' slots, blocks handed on]
+        self._tick_window = [0, 0]
         self._pending_cow: List[Tuple[int, int, int]] = []  # slot, src, dst
         wrap = (telemetry is not None
                 and getattr(device_step, "wrap_steps", True))
@@ -885,11 +938,16 @@ class ServingEngine:
         arr = k[0] if self.kv_quant else k
         tp = int(self.mesh.shape[self.axis]) if (
             self.mesh is not None and self.axis) else 1
-        blk = self.cfg.block
-        _rows, _fw, hb, T = paged_attention_ops.call_walk(
-            blk.nheads // blk.kv_head_count * (self.spec_k + 1),
-            arr.shape[2] // tp, self.max_blocks, arr.shape[3],
-            arr.shape[3] * arr.shape[4] * arr.dtype.itemsize, self.kv_quant)
+        blk, ops = self.cfg.block, paged_attention_ops
+        groups, s_in = blk.nheads // blk.kv_head_count, self.spec_k + 1
+        # as the wrapper asks: one of a head's programs, the columns walked
+        _rows, _fw, hb, T = ops.call_walk(
+            groups * s_in // ops.head_split(groups, s_in),
+            arr.shape[2] // tp,
+            ops.walked_columns(getattr(blk, "sliding_window", None),
+                               self.max_blocks, s_in, arr.shape[3]),
+            arr.shape[3], arr.shape[3] * arr.shape[4] * arr.dtype.itemsize,
+            self.kv_quant)
         return {"kv_heads_per_step": hb, "kv_tile_blocks": T}
 
     def _dispatch(self, fn: Callable, args: Tuple[Any, ...]) -> Tuple[Any, ...]:
@@ -910,6 +968,12 @@ class ServingEngine:
         recurrent state would have to travel with them."""
         if not self.state_model:
             return
+        if self.window:
+            raise NotImplementedError(
+                f"{what} with a window pool is not supported: a request "
+                f"would travel with two tables, and the window pool's "
+                f"blocks stand at columns that depend on where its last "
+                f"call stood (ROADMAP queue 2 A2)")
         if not self.cfg.state_layers:
             raise NotImplementedError(
                 f"{what} with the hybrid family is not supported: a model "
@@ -1114,6 +1178,12 @@ class ServingEngine:
         # them (mirrors speculative_generate's overshoot slack)
         return -(-(len(req.tokens) + req.max_new_tokens + self.spec_k)
                  // self.block_size)
+
+    def _window_blocks_needed(self, req: Request) -> int:
+        """What a request reserves of the window pool: its whole length's
+        blocks, or the reach of a window (``window_reach``) where it is
+        longer (the rest is handed on, :meth:`_hand_on`)."""
+        return min(self._blocks_needed(req), self.window_reach)
 
     def _prefix_hashes(self, tokens) -> List[Any]:
         return (chain_block_hashes(tokens, self.block_size)
@@ -1343,6 +1413,8 @@ class ServingEngine:
         rid, req, t_submit = s.rid, s.req, s.t_submit
         alloc = self._allocs[i // self.slots_per_group]
         self._release_blocks(alloc, s.blocks)
+        if self.window:
+            self._release_blocks(self._walloc, s.wblocks)
         self._clear_slot_rows(i)
         s.reset()
         # the admission-time TTFT prediction's premise (the queue as it
@@ -1370,6 +1442,8 @@ class ServingEngine:
 
     def _clear_slot_rows(self, i: int) -> None:
         self._tables[i] = 0
+        if self.window:
+            self._wtables[i] = 0
         self._lengths[i] = 0
         self._last_tok[i] = 0
         self._temps[i] = 0.0
@@ -1415,6 +1489,10 @@ class ServingEngine:
                 # unpin — the copy is scheduled before the next device
                 # call, and the cache threading orders it before any write
                 alloc.free([cow_src])
+            if self.window:
+                # every slot's reach is in the pool: a free slot finds it
+                self._slots[i].wblocks = self._walloc.alloc(
+                    self._window_blocks_needed(req))
             return i, hit, cow_src, fresh
         return None
 
@@ -1456,6 +1534,9 @@ class ServingEngine:
             s.orig_prompt_len, s.pre_gen = len(req.tokens), 0
             self._tables[slot_idx] = 0
             self._tables[slot_idx, :need] = blocks
+            if self.window:
+                self._wtables[slot_idx] = 0
+                self._wtables[slot_idx, :len(s.wblocks)] = s.wblocks
             self._lengths[slot_idx] = 0
             if evicted:
                 self.stats["cache_evictions"] += len(evicted)
@@ -1654,6 +1735,10 @@ class ServingEngine:
                 attrs.update(self._indexed_attrs(
                     np.concatenate([args[2] for _, args in batches]),
                     np.concatenate([args[-1] for _, args in batches])))
+        if self.window:
+            with span("tdp:engine.handon"):
+                batches = [(slot_of, self._hand_on(args, slot_of, attrs))
+                           for slot_of, args in batches]
         with span("tdp:engine.prefill", **attrs):
             # back to back: the pool is donated and chained call to call,
             # so a queued call holds its small inputs only
@@ -1777,6 +1862,11 @@ class ServingEngine:
             self._absorb_decode(flight)
             return 0
         args, slots, attrs = built
+        if self.window:
+            with span("tdp:engine.handon"):
+                args = self._hand_on(
+                    args, np.where(args[7] > 0, np.arange(self.num_slots),
+                                   -1), attrs)
         with span("tdp:engine.decode", **attrs):
             out = self._dispatch(self._decode_fn, args)
         self.stats["decode_steps"] += 1
@@ -1855,6 +1945,69 @@ class ServingEngine:
         self._tick_dsa[1] += pair[1]
         return {"indexed_positions": pair[0], "selected_positions": pair[1]}
 
+    def _hand_on(self, args: Tuple[Any, ...], slot_of: np.ndarray,
+                 attrs: Dict[str, Any]) -> Tuple[Any, ...]:
+        """A call's window table, made just before its dispatch.  Row r of
+        the call carries slot ``slot_of[r]`` (-1: none) from position
+        ``offsets[r]`` for ``n_valid[r]`` real rows, and will write the
+        table columns those fall in.  Where such a column names no block
+        yet, it is HANDED one that lies wholly behind the window of the
+        call's first row (a later row's window, and every later call's,
+        starts later still): the host's table alone changes, old column
+        NULL, new column the same block id; no device copy, no allocator
+        traffic.  The table keeps absolute columns, so the kernel needs no
+        ring arithmetic, and a call in flight keeps the table it was handed.
+        A slot's blocks are the columns from its lowest live one on, so one
+        look at the call's first and last column says whether any is short.
+
+        Returns ``args`` with ``(tables, window tables)`` in the table's
+        place; adds to the dispatch span's ``attrs`` (and the tick)
+        ``window_positions``, the positions a window layer holds for the
+        call's slots at its last row, beside ``live_tokens``, those the
+        other layers hold (a decode span counts its own), and
+        ``blocks_handed_on``; a prefill span also gets ``window_pairs`` and
+        ``live_pairs``, the (row, key) pairs its real rows attend in a
+        window layer (``min(window, position + 1)`` a row) and in a global
+        one (``position + 1``): a decode row's are the positions held."""
+        W, bs = self.window, self.block_size
+        offsets, n_valid = args[2], args[7]
+        live = np.flatnonzero((slot_of >= 0) & (n_valid > 0))
+        slots, off, n = slot_of[live], offsets[live], n_valid[live]
+        first = off // bs
+        last = np.minimum((off + n - 1) // bs, self.max_blocks - 1)
+        handed = 0
+        for r in np.flatnonzero((self._wtables[slots, first] == 0)
+                                | (self._wtables[slots, last] == 0)):
+            row = self._wtables[slots[r]]
+            want = [c for c in range(first[r], last[r] + 1) if not row[c]]
+            behind = max(off[r] - W + 1, 0) // bs
+            give = np.flatnonzero(row[:behind])[:len(want)]
+            if len(give) < len(want):
+                raise RuntimeError(
+                    f"slot {slots[r]}: {len(want)} window blocks short at "
+                    f"position {off[r]} and {len(give)} behind the window")
+            row[want] = row[give]
+            row[give] = 0
+            handed += len(want)
+        wtables = np.zeros((len(slot_of), self.max_blocks), np.int32)
+        wtables[live] = self._wtables[slots]
+        held = int(np.minimum(off + n, W).sum())
+        attrs["window_positions"] = attrs.get("window_positions", 0) + held
+        attrs["blocks_handed_on"] = attrs.get("blocks_handed_on", 0) + handed
+        if "rows" in attrs:   # a prefill span: summed over the tick's calls
+            end = (off + n).astype(np.int64)
+            tri = lambda x: x * (x + 1) // 2   # sum of p + 1 over p < x
+            inside = lambda x: tri(np.minimum(x, W)) + np.maximum(x - W, 0) * W
+            for key, add in (("live_tokens", end.sum()),
+                             ("live_pairs", (tri(end) - tri(end - n)).sum()),
+                             ("window_pairs",
+                              (inside(end) - inside(end - n)).sum())):
+                attrs[key] = attrs.get(key, 0) + int(add)
+        self._tick_window[0] += held
+        self._tick_window[1] += handed
+        self.stats["blocks_handed_on"] += handed
+        return args[:1] + ((args[1], wtables),) + args[2:]
+
     def _absorb_decode(self, call: Optional[Dict[str, Any]]) -> None:
         """Fetch what one decode call returned and book it: every slot's
         token, key and chosen experts, its retirement.  With ``run_ahead``
@@ -1864,11 +2017,16 @@ class ServingEngine:
             return
         self._flight = None
         out = call["out"]
-        with span("tdp:engine.fetch", **call["waits_for"]):
+        with span("tdp:engine.fetch", **call["waits_for"]) as sp:
             tok = np.asarray(out[0])
             keys = np.asarray(out[1])
             if len(out) > 2:  # expert layers: live load stats ride along
                 self._absorb_moe_stats(*out[2:5], decode=True)
+            if len(out) > 4:
+                # a held range: the held experts THIS call touched, on the
+                # span that names the call (its dispatch span closed before
+                # it ran)
+                sp.attrs["experts_touched"] = float(np.asarray(out[4])[2])
             routing = np.asarray(out[5]) if len(out) > 5 else None
         with span("tdp:engine.absorb"):
             if self.telemetry is not None:
@@ -2102,6 +2260,8 @@ class ServingEngine:
                 "request_cancelled", rid=s.rid, slot=i, where="slot",
                 emitted_tokens=new_tokens, blocks_freed=len(s.blocks))
         self._allocs[i // self.slots_per_group].free(s.blocks)
+        if self.window:
+            self._walloc.free(s.wblocks)
         self._clear_slot_rows(i)
         s.reset()
 
@@ -2161,6 +2321,11 @@ class ServingEngine:
           under sharing — refcount-0 cached blocks are accounted, not
           leaked).
 
+        A model with window layers is audited twice, once a pool: the
+        window pool's violations carry ``pool: "window"``, and a slot's row
+        of ITS table must name exactly the slot's window blocks, at
+        whichever columns (they move as they are handed on).
+
         ``heal=True`` (the engine's in-``step()`` mode) repairs what it
         finds — poisoned slots are retired + requeued for replay, orphaned
         blocks reclaimed, stale rows zeroed — bracketed by
@@ -2170,56 +2335,71 @@ class ServingEngine:
         """
         violations: List[Dict[str, Any]] = []
         poisoned: List[int] = []
-        stale_rows: List[int] = []
-        orphans: Dict[int, List[int]] = {}
-        for g, alloc in enumerate(self._allocs):
-            lo, hi = g * self.slots_per_group, (g + 1) * self.slots_per_group
-            owned_lists = []
-            for i in range(lo, hi):
-                s = self._slots[i]
-                row = self._tables[i]
-                if s.state == FREE:
-                    if row.any():
-                        violations.append(
-                            {"kind": "stale_table_row", "slot": i})
-                        stale_rows.append(i)
-                    continue
-                owned_lists.append(s.blocks)
-                want = np.zeros(self.max_blocks, np.int32)
-                want[:len(s.blocks)] = s.blocks
-                if not np.array_equal(row, want):
-                    violations.append({
-                        "kind": "table_mismatch", "slot": i, "rid": s.rid,
-                        "row": row.tolist(), "owned": list(s.blocks)})
-                    poisoned.append(i)
-            rep = alloc.audit(owned_lists)
-            for b in rep["shared"]:
-                # refcount-weighted ownership violated: more (or fewer)
-                # slots reference the block than its refcount records
-                refs = [i for i in range(lo, hi)
-                        if b in self._slots[i].blocks]
-                violations.append({
-                    "kind": "shared_block", "block": int(b),
-                    "group": g, "slots": refs})
-                for i in refs:
-                    if i not in poisoned:
-                        poisoned.append(i)
-            if rep["orphaned"]:
-                violations.append({
-                    "kind": "orphaned_blocks", "group": g,
-                    "blocks": rep["orphaned"]})
-                orphans[g] = rep["orphaned"]
-            for b in rep["unknown"]:
-                violations.append({
-                    "kind": "unowned_block", "group": g, "block": int(b)})
+        stale_rows: List[Tuple[np.ndarray, int]] = []
+        orphans: List[Tuple[BlockAllocator, List[int]]] = []
+        pools = [({}, self._allocs, self._tables, "blocks")]
+        if self.window:
+            pools.append(({"pool": "window"}, [self._walloc], self._wtables,
+                          "wblocks"))
+        for pool, allocs, tables, owned in pools:
+            for g, alloc in enumerate(allocs):
+                # the window pool is one group: a mesh refuses it
+                n = self.num_slots // len(allocs)
+                lo, hi = g * n, (g + 1) * n
+                owned_lists = []
                 for i in range(lo, hi):
-                    if b in self._slots[i].blocks and i not in poisoned:
+                    s = self._slots[i]
+                    row, mine = tables[i], getattr(s, owned)
+                    if s.state == FREE:
+                        if row.any():
+                            violations.append(
+                                {"kind": "stale_table_row", "slot": i, **pool})
+                            stale_rows.append((tables, i))
+                        continue
+                    owned_lists.append(mine)
+                    if pool:   # handed on: at whichever columns
+                        ok = np.sort(row[row != 0]).tolist() == sorted(mine)
+                    else:
+                        want = np.zeros(self.max_blocks, np.int32)
+                        want[:len(mine)] = mine
+                        ok = np.array_equal(row, want)
+                    if not ok:
+                        violations.append({
+                            "kind": "table_mismatch", "slot": i,
+                            "rid": s.rid, "row": row.tolist(),
+                            "owned": list(mine), **pool})
                         poisoned.append(i)
-            if not rep["conserved"]:
-                violations.append({
-                    "kind": "conservation", "group": g,
-                    "in_use": rep["in_use"], "n_free": rep["n_free"],
-                    "n_usable": alloc.n_usable})
+                rep = alloc.audit(owned_lists)
+                for b in rep["shared"]:
+                    # refcount-weighted ownership violated: more (or
+                    # fewer) slots reference the block than its refcount
+                    # records
+                    refs = [i for i in range(lo, hi)
+                            if b in getattr(self._slots[i], owned)]
+                    violations.append({
+                        "kind": "shared_block", "block": int(b),
+                        "group": g, "slots": refs, **pool})
+                    for i in refs:
+                        if i not in poisoned:
+                            poisoned.append(i)
+                if rep["orphaned"]:
+                    violations.append({
+                        "kind": "orphaned_blocks", "group": g,
+                        "blocks": rep["orphaned"], **pool})
+                    orphans.append((alloc, rep["orphaned"]))
+                for b in rep["unknown"]:
+                    violations.append({
+                        "kind": "unowned_block", "group": g,
+                        "block": int(b), **pool})
+                    for i in range(lo, hi):
+                        if (b in getattr(self._slots[i], owned)
+                                and i not in poisoned):
+                            poisoned.append(i)
+                if not rep["conserved"]:
+                    violations.append({
+                        "kind": "conservation", "group": g,
+                        "in_use": rep["in_use"], "n_free": rep["n_free"],
+                        "n_usable": alloc.n_usable, **pool})
         if violations and heal:
             self.stats["faults_detected"] += len(violations)
             self._ev.emit(
@@ -2228,11 +2408,11 @@ class ServingEngine:
                 kinds=sorted({v["kind"] for v in violations}),
                 slots=sorted(poisoned))
             requeued = [self._requeue_slot(i) for i in sorted(poisoned)]
-            for i in stale_rows:
-                self._tables[i] = 0
+            for tables, i in stale_rows:
+                tables[i] = 0
             reclaimed = 0
-            for g, blocks in orphans.items():
-                reclaimed += len(self._allocs[g].reclaim(blocks))
+            for alloc, blocks in orphans:
+                reclaimed += len(alloc.reclaim(blocks))
             self.stats["faults_healed"] += len(violations)
             self._ev.emit(
                 "engine_recovered", fault="invariant_audit",
@@ -2273,6 +2453,7 @@ class ServingEngine:
             self._tick_emitted = 0
             self._tick_moe = dict.fromkeys(_MOE_CALL_STATS, 0.0)
             self._tick_dsa = [0, 0]
+            self._tick_window = [0, 0]
             if self.chaos is not None:
                 self.chaos.before_engine_tick(self._tick, self)
             self.stats["audits"] += 1
@@ -2291,6 +2472,8 @@ class ServingEngine:
                 util = float(np.mean(
                     [a.utilization() for a in self._allocs]))
                 self._util_sum += util
+                if self.window:
+                    self._wutil_sum += self._walloc.utilization()
                 self._occ_ticks += 1
                 if (self.snapshot_every
                         and self._tick % self.snapshot_every == 0):
@@ -2361,6 +2544,9 @@ class ServingEngine:
         if self._idx_topk:
             rec.update(zip(("indexed_positions", "selected_positions"),
                            self._tick_dsa))
+        if self.window:
+            rec.update(zip(("window_positions", "blocks_handed_on"),
+                           self._tick_window))
         self.tick_records.append(rec)
         if admitted or expired or prefilled or decoded or busy or self.queue:
             self._ev.emit(
@@ -2868,6 +3054,7 @@ class ServingEngine:
                       "migrated_in": 0, "migrated_out": 0,
                       "imports_aborted": 0,
                       "cp_ring_hops": 0, "cp_ring_bytes": 0,
+                      "blocks_handed_on": 0,
                       **dict.fromkeys(_MOE_CALL_STATS, 0.0)}
         self._decode_sigs: set = set()
         self._prefill_sigs: set = set()
@@ -2885,7 +3072,7 @@ class ServingEngine:
         self._calib_n = 0
         self._slo_by_prio: Dict[int, Dict[str, int]] = {}
         self._tick = 0
-        self._occ_sum = self._util_sum = 0.0
+        self._occ_sum = self._util_sum = self._wutil_sum = 0.0
         self._occ_ticks = 0
         self._t_first = float("inf")
         self._t_last_done = 0.0
@@ -2899,7 +3086,7 @@ class ServingEngine:
         self._moe_expert_tokens: Optional[np.ndarray] = None
         self._moe_dropped_sum = 0.0
         self._moe_steps = 0
-        for a in self._allocs:
+        for a in self._allocs + [self._walloc] * bool(self.window):
             a.peak_in_use = a.in_use
 
     def _absorb_moe_stats(self, et, dr, share=None,
@@ -3111,7 +3298,22 @@ class ServingEngine:
                 "index_bytes": index_bytes(self.cache),
                 "pool_bytes_expected": expected_pool_bytes(
                     self.cfg, self.dp * self.num_blocks, self.block_size,
-                    quantized=self.kv_quant),
+                    quantized=self.kv_quant,
+                    window_blocks=self.window_blocks),
+                # a model with window layers: of which their pool, with its
+                # own blocks and utilisation (the figures above count the
+                # pool that keeps every position)
+                **({"window": {
+                    "window": self.window,
+                    "num_blocks": self.window_blocks,
+                    "blocks_per_slot": self.window_reach,
+                    "pool_bytes": window_bytes(self.cache),
+                    "mean_utilization": (self._wutil_sum / self._occ_ticks
+                                         if self._occ_ticks else 0.0),
+                    "peak_utilization": (self._walloc.peak_in_use
+                                         / self._walloc.n_usable),
+                    "blocks_handed_on": st["blocks_handed_on"],
+                }} if self.window else {}),
             },
             # which attention implementation the compiled programs traced
             # (docs/serving.md "Paged attention kernel"): 'pallas' walks

@@ -28,6 +28,13 @@ compiles ONCE):
   [L, num_blocks, 1, idx_dim, block_size]}``, laid as the latent pool's
   blocks are and written by the same op.  Everything below that names blocks
   (tables, allocator, copy-on-write, migration) is the same for all three.
+  A model with WINDOW layers beside global ones (kind ``W``) keeps two
+  different amounts of cache and so has a SECOND pool of the same block
+  shape under ``'win'``, ``{'k','v': [window_layers, window_blocks, Hkv,
+  block_size, hd]}``, with block ids, a NULL block, an allocator and a
+  table of its own: a sequence holds at most :func:`window_reach` of its
+  blocks, and the engine hands a block that fell behind the window on to a
+  column ahead (docs/serving.md "Two pools").
 - **Block tables**: ``[num_slots, max_blocks]`` int32 per-slot rows.  Block
   ``i`` of a slot's table covers its positions ``[i*bs, (i+1)*bs)``, so the
   table IS the page table and position arithmetic is two integer ops.
@@ -84,7 +91,7 @@ NULL_BLOCK = 0
 
 def init_paged_kv(
     cfg: GPTConfig, num_blocks: int, block_size: int, axis_size: int = 1,
-    quantized: bool = False,
+    quantized: bool = False, window_blocks: int = 0,
 ) -> Dict[str, Any]:
     """Zeroed block pool ``{'k','v': [L, num_blocks, Hkv_local, block_size,
     hd]}`` in ``cfg.dtype`` — the paged analogue of ``init_kv_cache``.
@@ -95,7 +102,21 @@ def init_paged_kv(
 
     A model whose attention is latent (``cfg.latent_width``) gets the
     one-leaf pool ``{'kv': [L, num_blocks, 1, latent_width, block_size]}``
-    instead: nothing to divide over a tensor axis, no int8 form yet."""
+    instead: nothing to divide over a tensor axis, no int8 form yet.
+
+    A model with window layers (``cfg.window_layers``) gets a SECOND pool of
+    the same block shape under ``'win'``: ``{'k','v': [window_layers,
+    window_blocks, Hkv, block_size, hd]}``, with block ids, a NULL block and
+    a table of its own."""
+    if _window_layers(cfg):
+        if quantized or axis_size != 1:
+            raise NotImplementedError(
+                "a window pool has no int8 form and no tensor-parallel "
+                "split yet (ROADMAP queue 2)")
+        if window_blocks < 2:
+            raise ValueError(
+                f"window_blocks must be >= 2 (block 0 is the window pool's "
+                f"NULL block), got {window_blocks}")
     if num_blocks < 2:
         raise ValueError(
             f"num_blocks must be >= 2 (block 0 is the reserved NULL block), "
@@ -129,7 +150,38 @@ def init_paged_kv(
             return (jnp.zeros(shape, jnp.int8),
                     jnp.ones(shape[:-1], jnp.float32))
         return {"k": entry(), "v": entry()}
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    pool = {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    if _window_layers(cfg):
+        wshape = (_window_layers(cfg), window_blocks) + shape[2:]
+        pool["win"] = {"k": jnp.zeros(wshape, cfg.dtype),
+                       "v": jnp.zeros(wshape, cfg.dtype)}
+    return pool
+
+
+def _window_layers(cfg) -> int:
+    """The window pool's depth (models/hybrid.py kind ``W``); 0 = the model
+    has one pool."""
+    return getattr(cfg, "window_layers", 0)
+
+
+def window_bytes(cache: Dict[str, Any]) -> int:
+    """Bytes of the window layers' pool (0 where there is none)."""
+    return pool_bytes(cache["win"]) if "win" in cache else 0
+
+
+def window_reach(window: int, chunk: int, block_size: int) -> int:
+    """The most blocks of the window pool that one sequence needs at a
+    time: the table columns from the first key inside the window of a
+    call's FIRST row to the call's last row, over every call the engine
+    makes.  A prefill chunk at offset ``o``, a multiple of ``chunk``, reads
+    keys from ``o - window + 1`` to ``o + chunk - 1``: ``(window + chunk) /
+    block_size`` columns where both are whole blocks, which is asked of
+    them; a decode row reaches over ``window / block_size + 1``."""
+    if window % block_size or chunk % block_size:
+        raise ValueError(
+            f"a window pool wants window ({window}) and chunk ({chunk}) in "
+            f"whole blocks of {block_size}: a slot's reach is their sum")
+    return (window + chunk) // block_size
 
 
 def _kv_layers(cfg) -> int:
@@ -185,14 +237,16 @@ def pool_bytes(cache: Dict[str, Any]) -> int:
 
 def expected_pool_bytes(
     cfg: GPTConfig, num_blocks: int, block_size: int, axis_size: int = 1,
-    quantized: bool = False,
+    quantized: bool = False, window_blocks: int = 0,
 ) -> int:
     """What :func:`init_paged_kv` SHOULD allocate, from shape math alone:
     ``2 * L * num_blocks * Hkv/axis_size * block_size * hd`` entries in
     ``cfg.dtype`` (int8 + f32 per-vector scale when ``quantized``).  The
     independent half of the pool-accounting cross-check.  A latent pool
     is ONE leaf of ``L * num_blocks * block_size * latent_width``; an
-    indexed pool adds ``L * num_blocks * block_size * index_width``."""
+    indexed pool adds ``L * num_blocks * block_size * index_width``; a
+    window pool ``2 * window_layers * window_blocks * Hkv * block_size *
+    hd``."""
     if _latent_width(cfg):
         return (_kv_layers(cfg) * num_blocks * block_size
                 * _latent_width(cfg) * jnp.dtype(cfg.dtype).itemsize)
@@ -203,9 +257,12 @@ def expected_pool_bytes(
         per_kv = entries * hd * 1 + entries * 4  # int8 q + f32 scale
     else:
         per_kv = entries * hd * jnp.dtype(cfg.dtype).itemsize
-    # k and v, and the indexer's key a position where attention is indexed
-    return 2 * per_kv + (_kv_layers(cfg) * num_blocks * block_size
-                         * _index_width(cfg) * jnp.dtype(cfg.dtype).itemsize)
+    # k and v, the indexer's key a position where attention is indexed, and
+    # the window layers' k and v
+    return (2 * per_kv
+            + (_kv_layers(cfg) * num_blocks * block_size * _index_width(cfg)
+               + 2 * _window_layers(cfg) * window_blocks * hkv * block_size
+               * hd) * jnp.dtype(cfg.dtype).itemsize)
 
 
 def _write_blocks(tables: jnp.ndarray, offset: jnp.ndarray, S_in: int,
@@ -705,10 +762,20 @@ def paged_forward_hybrid(
     whatever its slot held, which is how admission, preemption and a fault
     requeue restart a sequence (recompute, as for KV) with no reset call.
 
+    A model with window layers: ``tables`` is the PAIR ``(tables, window
+    tables)``, one table a pool; the window layers write and attend through
+    the second, whose columns are absolute as the first's are (the engine
+    hands a block that fell behind the window on to a column ahead:
+    docs/serving.md "Two pools").
+
     Returns ``(cache, state, logits [B, V], moe_metrics)``."""
     from ..models.hybrid import hybrid_paged_forward
 
     offset = jnp.asarray(offset, jnp.int32)
+    window_ops = None
+    if _window_layers(cfg):
+        tables, wtables = tables
+        window_ops = functools.partial(_paged_cache_ops, wtables, attn_impl)
     if _latent_width(cfg):
         ops = functools.partial(_latent_cache_ops, tables, attn_impl, cfg)
     elif _index_width(cfg):
@@ -725,7 +792,7 @@ def paged_forward_hybrid(
         mine = jax.tree.map(own, state)
     cache, mine, logits, metrics = hybrid_paged_forward(
         params, tokens, cfg, cache, mine, n_valid, ops, offset,
-        last_idx=last_idx)
+        last_idx=last_idx, window_ops=window_ops)
     if rows is not None:
         mine = jax.tree.map(
             lambda a, new: a.at[rows].set(new, mode="drop"), state, mine)
@@ -736,14 +803,19 @@ def copy_blocks(cache: Dict[str, Any], src: jnp.ndarray,
                 dst: jnp.ndarray) -> Dict[str, Any]:
     """Copy block contents ``src[i] -> dst[i]`` along the pool's block dim
     (dim 1 of every leaf, quantized pairs included) — the device half of
-    copy-on-write.  ``src``/``dst`` are fixed-width int32 vectors so the
+    copy-on-write.  The ids name blocks of the pool that keeps every
+    position; a window pool (``cache['win']``, ids of its own) is handed
+    back as it came.  ``src``/``dst`` are fixed-width int32 vectors so the
     copy is ONE compiled program whatever blocks an admission wave needs
     copied; unused lanes are padded ``NULL -> NULL`` (the write-off
     block's contents are never read, so colliding pad writes are
     harmless)."""
     def cp(leaf):
         return leaf.at[:, dst].set(leaf[:, src])
-    return jax.tree.map(cp, cache)
+    out = jax.tree.map(cp, {k: v for k, v in cache.items() if k != "win"})
+    if "win" in cache:
+        out["win"] = cache["win"]
+    return out
 
 
 def migrate_blocks(

@@ -116,7 +116,8 @@ class CompiledDeviceStep(DeviceStep):
 
         eng = self.engine
         cache = init_paged_kv(eng.cfg, eng.dp * eng.num_blocks,
-                              eng.block_size, quantized=eng.kv_quant)
+                              eng.block_size, quantized=eng.kv_quant,
+                              window_blocks=eng.window_blocks)
         if eng.mesh is not None:
             from jax.sharding import NamedSharding
 

@@ -83,8 +83,14 @@ SERVING_METRICS_SCHEMA = "tdp-serving-metrics/v1"
 #: device->host transfer of the sampled tokens and nothing else; ``host``
 #: is the remainder of the tick (the walk after the fetch, array building,
 #: the telemetry's record).  The engine's ``tdp:engine.build`` / ``absorb``
-#: / ``record`` spans time that remainder piece by piece in the ring; they
-#: are NOT phases, and ``host`` stays "the tick outside the six above".
+#: / ``record`` spans time that remainder piece by piece in the ring, and
+#: ``tdp:engine.handon`` (a model with a window pool: the window table's
+#: blocks handed on, before each dispatch) one more piece of it; they are NOT
+#: phases, and ``host`` stays "the tick outside the six above".  A window
+#: pool's tick records carry ``window_positions`` and ``blocks_handed_on``
+#: as its dispatch spans do (docs/serving.md "Two pools"; a prefill span also
+#: ``window_pairs`` and ``live_pairs``), and the ``tdp:engine.fetch`` of a
+#: decode call with expert layers says that call's ``experts_touched``.
 TICK_PHASES = ("audit", "sched", "prefill", "draft", "decode", "fetch",
                "host")
 
