@@ -130,9 +130,10 @@ def _pools(B, mb, bs, hkv, hd, L=2):
 
 @pytest.mark.parametrize("s_in,groups,fw", [
     (1, 2, None), (1, 1, 2), (3, 2, None), (24, 2, 2), (24, 2, 3), (40, 1, 4),
-    (72, 4, 3)],
+    (72, 4, 3), (72, 2, None), (72, 2, 6), (72, 4, None)],
     ids=["decode", "decode-tile2", "verify", "chunk-fw2", "chunk-fw3",
-         "chunk-wide", "chunk-split"])
+         "chunk-wide", "chunk-split", "chunk-own-tile", "chunk-tile6",
+         "chunk-split-own-tile"])
 def test_both_walks_start_at_the_window(s_in, groups, fw, monkeypatch):
     """Offsets before, at and far past the window, decode and chunk rows:
     the kernel against the gathered oracle, with every table column that
@@ -141,8 +142,8 @@ def test_both_walks_start_at_the_window(s_in, groups, fw, monkeypatch):
     or a value.  The windowed call carries its own kernel name."""
     bs, mb, hkv, hd, window = 8, 12, 2, 16, 20
     if groups == 4:   # 288 rows a KV head: two programs of 144 share its K, V
-        monkeypatch.setattr(PA, "_PROGRAM_ROWS", 287)
-        monkeypatch.setattr(PA, "_CHUNK_ROWS", 150)
+        monkeypatch.setattr(PA, "_PROGRAM_ROWS", 150)
+        assert PA.head_split(groups, s_in) == 2
     offs = np.asarray([0, 5, 19, 20, 37, 50, 96 - s_in], np.int32)
     offs = np.minimum(offs, mb * bs - s_in)
     B = len(offs)
@@ -207,25 +208,31 @@ def test_kernel_names_and_grid_follow_the_window():
     assert PA.window_columns(2048, 1, 128) == 17
 
 
-@pytest.mark.parametrize("groups,s_in,want", [
-    (16, 128, 1), (4, 256, 1), (8, 1, 1), (8, 512, 4), (4, 1024, 4),
-    (3, 1000, 3)],
-    ids=["nemotron3s", "mistral7b", "decode", "trinity", "halves-too-wide",
-         "odd-heads"])
+@pytest.mark.parametrize("groups,s_in,want,tile", [
+    (16, 128, 1, 6), (4, 256, 1, 7), (8, 1, 1, None), (8, 512, 1, 7),
+    (4, 1024, 1, 7), (8, 1024, 2, 7), (8, 2048, 4, 8), (3, 2000, 3, 8)],
+    ids=["nemotron3s", "mistral7b", "decode", "trinity", "four-long-heads",
+         "four-heads-a-program", "two-heads-a-program", "odd-heads"])
 def test_a_heads_rows_stay_one_program_up_to_what_always_compiled(
-        groups, s_in, want):
-    """Up to 2,048 rows a KV head a call is the one program it was before
-    there was a split (the accepted cells' shapes); past it, programs of at
-    most 1,024 rows in whole query heads.  The estimator counts the same."""
+        groups, s_in, want, tile):
+    """Up to 4,096 rows a KV head a call is one program (every cell's
+    shape); past it, the fewest programs of whole query heads that come
+    under 4,096 rows.  The estimator counts the same, the float32 scores of
+    a key tile among it (``tile``: what the shape takes of a window's 18-32
+    columns, in equal steps of at most 1,024 keys)."""
     assert PA.head_split(groups, s_in) == want
-    assert groups * s_in // want <= (2048 if want == 1 else 1024)
+    rows = groups * s_in // want
+    assert rows <= 4096 and (want == 1 or groups * s_in // (want - 1) > 4096
+                             or groups % (want - 1))
     geo = dict(batch=2, kv_heads=2, max_blocks=32, block_size=128,
-               head_dim=128, itemsize=2, fetch_width=6)
-    if groups * s_in > 128:   # the grid's walk: six blocks a side, twice
-        assert PA.modeled_attend_temp_bytes(
-            "pallas", s_in=s_in, groups=groups, window=2048, **geo) == (
-            2 * 2 * want * (2 * groups * s_in // want * 128 * 2
-                            + 2 * 2 * 6 * 128 * 128 * 2))
+               head_dim=128, itemsize=2)
+    if groups * s_in > 128:   # the grid's walk: a tile's blocks a side, twice
+        for fw in (6, None):  # a caller's tile, the shape's
+            assert PA.modeled_attend_temp_bytes(
+                "pallas", s_in=s_in, groups=groups, window=2048,
+                fetch_width=fw, **geo) == 2 * 2 * want * (
+                    2 * rows * 128 * 2 + 2 * 2 * (fw or tile) * 128 * 128 * 2
+                    + 4 * rows * (fw or tile) * 128)
 
 
 def test_the_index_map_asks_for_no_block_behind_the_window():
@@ -354,7 +361,15 @@ def test_a_moved_chunk_boundary_and_the_kernel_path_change_nothing(
     wide = _serve(toy, chunk=32)
     assert (served.window_reach, wide.window_reach) == (3, 6)
     assert wide.window_blocks == 1 + 3 * 6
+    from torchdistpackage_tpu.utils.profiling import spans
+    spans.clear()
     kernel = _serve(toy, attn_impl="pallas", requests=REQUESTS[:3])
+    (walk,) = [r[5] for r in spans.snapshot()
+               if r[2] == "tdp:engine.init.pool"]
+    # a chunk of 8 under the toy's heads: one tile over the table's columns
+    # in a global layer, over a window's reach in a window layer
+    assert walk["chunk_tile_keys"] == kernel.max_blocks * 8
+    assert walk["window_chunk_tile_keys"] == PA.window_columns(16, 8, 8) * 8
     for rid, f in served.finished.items():
         np.testing.assert_array_equal(wide.finished[rid]["tokens"],
                                       f["tokens"])

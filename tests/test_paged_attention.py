@@ -33,6 +33,8 @@ import pytest
 
 from torchdistpackage_tpu.models import generate, init_gpt_params, llama_config
 from torchdistpackage_tpu.ops.paged_attention import (
+    call_walk,
+    chunk_tile,
     decode_walk,
     fetched_block,
     modeled_attend_temp_bytes,
@@ -377,6 +379,168 @@ def test_walk_follows_the_shape(name):
     rows = -(-(H // hkv) * s_in // 8) * 8
     assert decode_walk(hkv, rows, mb, min(6, mb), bs, bs * hd * itemsize,
                        int8) == want
+
+
+#: (window, int8, tile blocks): a prefill chunk's rows (2 query heads a KV
+#: head x 72 = 144 rows a program: the grid's walk) over a table of 12
+#: columns.  A window that binds (the walk starts at it), one as wide as the
+#: table (a mask that takes nothing out), none; the tile the kernel takes on
+#: its own (None: all 12 columns, one step) and tiles that divide, straddle
+#: and cover the table; the int8 pool's scale rows side by side.
+CHUNK_TILES = {
+    "none-own": (None, False, None), "none-2": (None, False, 2),
+    "none-5": (None, False, 5), "none-12": (None, False, 12),
+    "binds-own": (20, False, None), "binds-3": (20, False, 3),
+    "binds-5": (20, False, 5), "wide-4": (96, False, 4),
+    "wide-own": (96, False, None), "int8-own": (None, True, None),
+    "int8-3": (None, True, 3), "int8-binds-4": (20, True, 4),
+}
+
+
+def _chunk_case(rs, s_in=72, groups=2, hkv=2, bs=8, hd=16, mb=12):
+    """Slots whose chunk ends in the table's first block, mid-table and at
+    its end: live blocks from 1 short of a tile to all of the table."""
+    offs = np.asarray([0, 3, 17, 24], np.int32)
+    offs = np.minimum(offs, mb * bs - s_in)
+    B, nb = len(offs), 1 + len(offs) * mb
+    tables = rs.permutation(np.arange(1, nb)).reshape(B, mb).astype(np.int32)
+    q = jnp.asarray(rs.standard_normal((B, hkv * groups, s_in, hd)),
+                    jnp.float32)
+    return offs, nb, tables, q
+
+
+@pytest.mark.parametrize("case", sorted(CHUNK_TILES))
+def test_chunk_tile_matches_gather_oracle(case):
+    """The grid's walk with a step's blocks as ONE key tile against the
+    gathered oracle: every slot's last tile holds dead sub-blocks (its chunk
+    ends before the tile does), a window's walk starts mid-table."""
+    window, int8, fw = CHUNK_TILES[case]
+    rs = np.random.RandomState(len(case))
+    hkv, bs, hd, L = 2, 8, 16, 2
+    offs, nb, tables, q = _chunk_case(rs)
+    kp, vp = _pools_for(int8, (L, nb, hkv, bs, hd), rs)
+    want = paged_attention(q, kp, vp, jnp.asarray(offs), tables=tables,
+                           window=window, layer=1)
+    got = paged_decode_attention(q, kp, vp, jnp.asarray(tables),
+                                 jnp.asarray(offs), layer=1, window=window,
+                                 fetch_width=fw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("window", (None, 20))
+@pytest.mark.parametrize("fw", (None, 3, 5, 8))
+def test_chunk_tile_reads_nothing_a_slot_does_not_own(fw, window):
+    """The twin of the decode walk's test for the grid's walk: NaN in every
+    pool block that no live column of the call's tables names (the NULL
+    block that a never-live operand holds among them, and every block behind
+    a window) and in the rows of each slot's last block behind its last
+    position: the output is finite and BITWISE the clean pool's.  A dead
+    sub-block of a live tile is masked out of the scores and zeroed as
+    values, so nothing of it reaches the output, not even as 0 x NaN."""
+    rs = np.random.RandomState(7)
+    hkv, bs, hd, L, s_in, mb = 2, 8, 16, 2, 72, 12
+    offs, nb, tab, q = _chunk_case(rs)
+    kp, vp = (np.array(a) for a in _pools_for(False, (L, nb, hkv, bs, hd), rs))
+    dirty = [kp.copy(), vp.copy()]
+    owned = np.zeros(nb, bool)
+    tables = np.zeros_like(tab)
+    for b, off in enumerate(offs):
+        lo = max(off - window + 1, 0) // bs if window else 0
+        hi1 = (off + s_in - 1) // bs
+        tables[b, lo:hi1 + 1] = tab[b, lo:hi1 + 1]
+        owned[tab[b, lo:hi1 + 1]] = True
+        for pool in dirty:  # the last block's rows nobody wrote yet
+            pool[:, tab[b, hi1], :, (off + s_in - 1) % bs + 1:] = np.nan
+    for pool in dirty:
+        pool[:, ~owned] = np.nan
+    run = lambda k, v: np.asarray(paged_decode_attention(
+        q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables),
+        jnp.asarray(offs), layer=1, window=window, fetch_width=fw))
+    want = run(kp, vp)
+    assert np.isfinite(want).all()
+    np.testing.assert_array_equal(run(*dirty), want)
+    oracle = paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                             jnp.asarray(offs), tables=tab, window=window,
+                             layer=1)
+    np.testing.assert_allclose(want, np.asarray(oracle), rtol=2e-5, atol=2e-6)
+
+
+#: (hb x rows, walked columns, block size) -> blocks of one key tile, at the
+#: cells' real sizes: as many columns as 1,024 keys and 24 MB of scores take,
+#: in equal steps.
+CHUNK_TILE_SHAPES = {
+    "mistral7b.decode": ((1024, 6, 128), 6),
+    "zaya1.reason": ((1024, 20, 128), 7),
+    "nemotron3s.decode": ((2048, 6, 128), 6),
+    "trinitymini.mixedlen-window": ((4096, 21, 128), 7),
+    "trinitymini.mixedlen-global": ((4096, 112, 128), 8),
+    "int8-decode": ((64, 6, 128), 6),
+    "1024-rows-21-columns": ((1024, 21, 128), 7),
+    "8192-rows": ((8192, 21, 128), 4),
+    "a-block-of-1024-keys": ((2048, 6, 1024), 1),
+    "toy": ((144, 7, 8), 7),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CHUNK_TILE_SHAPES))
+def test_chunk_tile_follows_the_shape(name):
+    """``chunk_tile`` says from the shape alone how many blocks the grid's
+    walk makes one key tile; ``call_walk`` hands it on as the chunk's ``fw``
+    and leaves a caller's own ``fetch_width`` and the decode shape alone."""
+    (rows, cols, bs), want = CHUNK_TILE_SHAPES[name]
+    assert chunk_tile(1, rows, cols, bs) == want
+    got = call_walk(rows, 2, cols, bs, bs * 128 * 2)
+    assert got == ((rows, want, 1, 0) if rows > 128 else
+                   (rows, got[1], 2, min(cols, 1280 // bs)))
+    assert call_walk(rows, 2, cols, bs, bs * 128 * 2, fetch_width=2)[1] == min(
+        2, cols)
+
+
+def _exp_shapes(s_in, fw, *, groups, bs, mb, hkv=2, hd=128, window=None):
+    """Shapes of every ``exp`` over a plane of scores (more than one key) in
+    the body of the chunk's kernel, from the call's jaxpr alone."""
+    S = jax.ShapeDtypeStruct
+    pool = S((1 + mb, hkv, bs, hd), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, t, o: paged_decode_attention(
+        q, k, v, t, o, fetch_width=fw, window=window))(
+            S((1, hkv * groups, s_in, hd), jnp.bfloat16), pool, pool,
+            S((1, mb), jnp.int32), S((1,), jnp.int32))
+    found = []
+
+    def walk(j, inside):
+        for e in j.eqns:
+            if inside and e.primitive.name == "exp" and (
+                    e.outvars[0].aval.shape[-1] > 1):
+                found.append(tuple(e.outvars[0].aval.shape))
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub, inside or e.primitive.name == "pallas_call")
+
+    walk(jaxpr.jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("groups,s_in,bs,mb,window,want", [
+    (4, 256, 128, 6, None, (1, 1024, 768)),
+    (16, 128, 128, 6, None, (1, 2048, 768)),
+    (4, 256, 128, 20, None, (1, 1024, 896)),
+    (8, 512, 128, 112, 2048, (1, 4096, 896)),
+    (8, 512, 128, 112, None, (1, 4096, 1024)),
+    (16, 128, 1024, 6, None, (1, 2048, 1024))],
+    ids=["mistral7b", "nemotron3s", "zaya1", "trinity-window",
+         "trinity-global", "a-block-a-step"])
+def test_a_chunk_step_is_one_softmax_step(groups, s_in, bs, mb, window, want):
+    """A grid step of the chunk's kernel holds ONE ``exp`` over ``[rows,
+    tile keys]`` at the cells' shapes (one mask, one max, one rescale of the
+    accumulator for all the blocks it fetched), and where the shape fits
+    nothing wider than a block, the step a block it always had."""
+    assert _exp_shapes(s_in, None, groups=groups, bs=bs, mb=mb,
+                       window=window) == [want]
+    if bs == 1024:
+        assert want[-1] == bs
+    # a caller's own width is one tile too
+    assert _exp_shapes(s_in, 3, groups=groups, bs=bs, mb=mb,
+                       window=window) == [want[:2] + (3 * bs,)]
 
 
 def _pallas_grid(s_in, fw, *, B=2, H=8, hkv=4, bs=4, hd=8, mb=6):

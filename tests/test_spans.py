@@ -421,10 +421,39 @@ def test_pool_span_says_how_the_kernel_walks(params, kw, rows):
         8 * blk.head_dim * (1 if int8 else 4), int8)
     assert (attrs["kv_heads_per_step"], attrs["kv_tile_blocks"]) == (hb, T)
     assert hb == blk.kv_head_count and (T == 0) == int8
+    # the chunk's call: 8 rows a head, one tile over the table's columns
+    assert (attrs["chunk_rows"], attrs["chunk_tile_keys"],
+            attrs["chunk_programs"]) == (8, eng.max_blocks * 8, 1)
     spans.clear()
     _engine(params)
     attrs = _by_name(spans.snapshot(), "tdp:engine.init.pool")[0][5]
     assert "bytes" in attrs and "kv_tile_blocks" not in attrs
+
+
+@pytest.mark.parametrize("kw, want", [
+    ({}, (136, 256, 4)), ({"kv_quant": True}, (136, 256, 4)),
+    ({"chunk": 64}, (64, 256, 2))],
+    ids=["grid-walk", "int8", "in-kernel-walk"])
+def test_pool_span_says_the_chunks_tile(params, kw, want):
+    """``chunk_rows``, ``chunk_tile_keys`` and ``chunk_programs`` are
+    ``call_walk``'s result for the engine's prefill call: past 128 rows a
+    head the grid walks, one KV head a program, all of the table's 32
+    columns ONE key tile a step; up to 128 rows the in-kernel walk's tile
+    and as many heads a program as keep its rows within 128."""
+    from torchdistpackage_tpu.ops.paged_attention import call_walk
+
+    spans.clear()
+    eng = ServingEngine(params, CFG, num_slots=2, block_size=8, max_ctx=256,
+                        attn_impl="pallas", **{"chunk": 136, **kw})
+    attrs = _by_name(spans.snapshot(), "tdp:engine.init.pool")[0][5]
+    assert (attrs["chunk_rows"], attrs["chunk_tile_keys"],
+            attrs["chunk_programs"]) == want
+    blk, int8 = CFG.block, bool(kw.get("kv_quant"))
+    rows, fw, hb, T = call_walk(
+        eng.chunk, blk.kv_head_count, eng.max_blocks, 8,
+        8 * blk.head_dim * (1 if int8 else 4), int8)
+    assert want == (rows, (T or fw) * 8, blk.kv_head_count // hb)
+    assert "window_chunk_tile_keys" not in attrs
 
 
 def test_fetch_ends_at_the_tokens_and_telemetry_falls_into_host(params):

@@ -25,10 +25,10 @@ of a dead table column.  The pool lies ``[L, nb, Hkv, bs, hd]``, the heads
 of one block contiguous, so a copy carries all ``hb`` heads of a program.
 
 A prefill chunk's hundreds of rows a head, and an int8 pool, walk the GRID
-(:func:`_kernel`): ``(slot, kv-head, kv-step)``, ``fetch_width`` blocks a
-step through ``BlockSpec`` operands whose index map is the table; a
-sub-block past the last live block asks for the block it already holds
-(:func:`fetched_block`) and the pipeline skips the copy.
+(:func:`_kernel`): ``(slot, kv-head, kv-step)``, a step's blocks ONE key tile
+and one softmax step (:func:`chunk_tile`), fetched through ``BlockSpec``
+operands whose index map is the table; a sub-block past the last live block
+asks for the block it already holds (:func:`fetched_block`): no copy.
 
 One entry point covers every serving shape:
 
@@ -58,9 +58,9 @@ On CPU the kernel runs in Pallas interpreter mode automatically (same
 ``_interpret`` switch as ops/flash_attention.py), so every test exercises
 the identical code path the TPU compiles.
 
-Tuning: ``T`` and ``hb`` follow from the shape; ``fetch_width`` (the grid
-walk's blocks a step) and ``q_pad_to`` (the q rows' padding multiple: the
-K+1 verify shape lands at awkward row counts like G*(K+1)) come from the
+Tuning: both walks' key tiles, ``hb`` and a program's rows follow from the
+shape (:func:`call_walk`); ``q_pad_to`` (the q rows' padding multiple: the
+K+1 verify shape lands at awkward row counts like G*(K+1)) comes from the
 per-chip table :data:`_PAGED_PARAMS` (tools/flash_tune.py ``--paged``).
 """
 
@@ -85,38 +85,39 @@ _LANES = 128  # m/l scratch keeps a full lane dim for layout friendliness
 #: 128-row tile (decode: 8 heads x 8 rows; a chunk's 1,024 rows: 1 head).
 _ROWS_PER_STEP = 128
 
-#: Query rows a KV head that the GRID's walk keeps in ONE program, and the
-#: most a program carries where a head's rows pass that.  A program's q and
-#: out blocks (twice over), its float32 (acc, m, l) scratch and the scores of
-#: a KV block all grow with its rows, against a v5e's 16 MB of scoped VMEM.
-#: MEASURED there at rows of 128 (PR 41): 4,096 rows (a chunk of 512 under 8
-#: query heads a KV head) ask for 17.4 MB and compile nowhere; 2,048 is the
-#: edge: 16 query heads x a chunk of 128 over a walk from column 0 have
-#: compiled as one program since PR 26, while 2,048 rows of a walk that
-#: starts at its window asked for 16.27 MB standing alone and fitted inside
-#: an engine's program.  So a call of up to ``_PROGRAM_ROWS`` is the one
-#: program it always was, whatever its window; past it a KV head's query
-#: heads are dealt to programs of at most ``_CHUNK_ROWS`` (4 heads x 256:
-#: well inside), each fetching the head's blocks again (:func:`head_split`).
-_PROGRAM_ROWS = 2048
-_CHUNK_ROWS = 1024
+#: What a program of the GRID's walk may hold, against the scoped VMEM its call
+#: asks for (:data:`_GRID_VMEM_LIMIT`; a v5e has 128 MB, a call 16 MB unless
+#: it asks).  Two parts grow: with a program's ROWS its q and out blocks
+#: (twice over) and the float32 (acc, m, l) scratch, 2.5 KB a row of 128 bf16
+#: lanes; with ROWS x KEYS the float32 scores and the probabilities of one
+#: key tile, ~5.5 B a score.  :data:`_PROGRAM_ROWS` bounds the first: a KV
+#: head's query rows up to it are one program, past it they are dealt in
+#: whole query heads to the fewest programs that come under it
+#: (:func:`head_split`; each fetches the head's blocks again).
+#: :data:`_TILE_SCORE_BYTES` bounds the second (:func:`chunk_tile`).
+#: MEASURED (PR 42, compiled alone for a described v5e, MB asked): 1,024 rows
+#: x 768 keys 7.5, 2,048 x 768 14.66, 2,048 x 1,280 19.59, 4,096 x 896 33.59;
+#: the step a block of PR 41 asked for 16.27 at 2,048 rows, 17.4 at 4,096.
+_PROGRAM_ROWS = 4096
+_TILE_SCORE_BYTES = 24 << 20
+_GRID_VMEM_LIMIT = 64 << 20
 
 #: VMEM the K + V blocks a program holds twice over may take (bytes): ``2 x 2
 #: x blocks x heads x block`` (a key tile's, or ``fetch_width``) stays under it.
 _KV_VMEM_BUDGET = 8 << 20
 
-#: Kernel parameters by device_kind substring.  ``fetch_width`` = pool blocks
-#: the GRID's walk streams a step (chunk rows, int8 pools); ``q_pad_to`` = the
-#: q rows' padding multiple (G*(K+1) verify rows are rarely tile-aligned).  The
-#: v5e row is MEASURED there (PR 29, ``tools/flash_tune.py --paged --shape
-#: mistral7b.decode``; ms a call, decode / verify / chunk): (6, 8) 0.203 /
-#: 0.216 / 0.622, (6, 16) 0.208 / 0.217 / 0.621, (1, 8) 0.266 / 0.280 / 0.665.
-#: The decode walk's key tile ``T`` follows from the shape (``decode_walk``).
-#: MEASURED for it (PR 34, ``--shape zaya1.reason``: 64 slots x 8 / 2 heads x
-#: 20 columns; ms a call, decode / verify): ``T`` 1 0.366 / 0.376, 2 0.236 /
-#: 0.239, 4 0.175 / 0.181, 6 0.159 / 0.164, 10 0.152 / 0.156, 20 0.151 / 0.156;
-#: a 20-layer pass, the cell's fill / one live block / full tables: ``T`` 10 2.75
-#: / 1.45 / 4.63, the grid's walk 11.05 / 6.57 / 16.59.  cpu: the interpreter's.
+#: Kernel parameters by device_kind substring.  ``q_pad_to`` = the q rows'
+#: padding multiple (G*(K+1) verify rows are rarely tile-aligned); ``fetch_width``
+#: = pool blocks a step of the ring hop (:func:`_cp_kernel`): both walks here take
+#: their key tile from the shape.  The v5e row is MEASURED there (PR 29, ``tools/
+#: flash_tune.py --paged --shape mistral7b.decode``).  The decode walk's ``T`` (PR
+#: 34, ``--shape zaya1.reason``; ms a call, decode): 1 0.366, 2 0.236, 4 0.175, 6
+#: 0.159, 10 0.152, 20 0.151.  The GRID's tile (PR 42, a chunk's call alone, ms; a
+#: step a block -> tiles of n blocks): mistral7b.decode's 8 x 256 rows over 6
+#: columns 0.780 -> 1 0.798, 3 0.559, 6 0.432; zaya1.reason's 4 x 256 over 20 0.278
+#: -> 3 0.157, 5 0.116, 6 0.127, 7 0.114, 10 0.115; nemotron3s.decode's 2,048 rows
+#: over 6 0.173 -> 1 0.158, 2 0.133, 3 0.114, 6 0.091; trinitymini.mixedlen's 4 x
+#: 4,096 rows: PERF.md section 6, PR 42 (rows a program x tile).  cpu: interpreter.
 _PAGED_PARAMS = (
     ("v5 lite", {"fetch_width": 6, "q_pad_to": 8}),
     ("v5e", {"fetch_width": 6, "q_pad_to": 8}),
@@ -165,11 +166,12 @@ def resolve_attn_impl(impl: Optional[str]) -> str:
     return impl
 
 
-def _compiler_params():
+def _compiler_params(vmem_limit_bytes: Optional[int] = None):
     if _interpret():
         return None
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_limit_bytes,
     )
 
 
@@ -215,11 +217,9 @@ def window_columns(window: int, S_in: int, bs: int) -> int:
 def head_split(groups: int, S_in: int) -> int:
     """The programs that a KV head's ``groups x S_in`` query rows are dealt
     to: 1 up to :data:`_PROGRAM_ROWS`; past it the fewest whole query heads'
-    worth that brings a program to :data:`_CHUNK_ROWS` rows or under."""
-    if groups * S_in <= _PROGRAM_ROWS:
-        return 1
-    return next((d for d in range(2, groups + 1) if groups % d == 0
-                 and groups * S_in // d <= _CHUNK_ROWS), groups)
+    worth that brings a program's rows under it."""
+    return next((d for d in range(1, groups + 1) if groups % d == 0
+                 and groups * S_in // d <= _PROGRAM_ROWS), groups)
 
 
 def walked_columns(window: Optional[int], mb: int, S_in: int, bs: int) -> int:
@@ -280,6 +280,28 @@ def _accumulate(s, keep, pv, acc_ref, m_ref, l_ref):
 #: ``[hb, rows, keys]`` grow with it.
 _KV_TILE_KEYS = 1280
 
+#: Keys one key tile of the GRID's walk holds at most (MEASURED at a chunk's
+#: 1,024-4,096 rows a program, PR 42: the gain over a step a block is had by
+#: six blocks of 128, seven to ten read the same at 1,024 rows, ten read 19%
+#: WORSE than eight at 2,048).
+_CHUNK_TILE_KEYS = 1024
+
+
+def chunk_tile(hb: int, rows: int, cols: int, bs: int) -> int:
+    """Pool blocks of one key tile of the GRID's walk, which is what a grid
+    step fetches, from the shape alone: the walk's ``cols`` columns in the
+    fewest steps whose tile stays within :data:`_CHUNK_TILE_KEYS` keys and
+    whose float32 scores and probabilities ``[hb x rows, keys]`` (~5.5 B a
+    score) within :data:`_TILE_SCORE_BYTES`, the steps then made EQUAL: 21
+    columns under a cap of eight blocks are three tiles of 7, not 8 + 8 + 5
+    (a dead sub-block of a live tile is multiplied and masked like a live
+    one).  A shape that fits nothing wider than a block keeps a step a
+    block."""
+    cap = max(1, min(_CHUNK_TILE_KEYS,
+                     2 * _TILE_SCORE_BYTES // (11 * hb * rows)) // bs)
+    steps = -(-cols // cap)
+    return -(-cols // steps)
+
 
 def decode_walk(Hkv: int, rows: int, mb: int, fw: int, bs: int,
                 block_bytes: int, quantized: bool = False) -> Tuple[int, int]:
@@ -293,7 +315,7 @@ def decode_walk(Hkv: int, rows: int, mb: int, fw: int, bs: int,
     halves of the K and V tile buffers within :data:`_KV_VMEM_BUDGET`
     (``block_bytes``: one head's ``bs`` rows).  ``T`` = 0:
     a prefill chunk's hundreds of rows a head, and an int8 pool, keep the
-    grid's walk over table columns (:func:`_kernel`)."""
+    grid's walk over table columns (:func:`_kernel`), ``fw`` blocks a step."""
     if quantized or rows > _ROWS_PER_STEP:
         return _heads_per_step(Hkv, rows, fw, block_bytes), 0
     hb = _heads_per_step(Hkv, rows, 1, block_bytes)
@@ -304,18 +326,40 @@ def decode_walk(Hkv: int, rows: int, mb: int, fw: int, bs: int,
 def call_walk(R: int, Hkv: int, mb: int, bs: int, block_bytes: int,
               quantized: bool = False, fetch_width: Optional[int] = None,
               q_pad_to: Optional[int] = None) -> Tuple[int, int, int, int]:
-    """``(rows, fw, hb, T)`` of a call of ``R`` query rows a KV head over
-    ``mb`` table columns on the attached chip: the padded rows and the
-    grid walk's fetch width from the chip's row (:func:`_step_params`),
-    ``hb`` and ``T`` from :func:`decode_walk`.  A caller's own
-    ``fetch_width`` (the tuner's, the tests') is the blocks fetched at a
-    time in either walk: the key tile's too.  What the wrapper runs, what
+    """``(rows, fw, hb, T)`` of a call of ``R`` query rows a KV head (one of
+    its :func:`head_split` programs') over ``mb`` walked columns on the
+    attached chip: the rows padded to the chip's multiple
+    (:func:`_step_params`), ``hb`` and ``T`` from :func:`decode_walk`, and,
+    where the grid walks (``T`` = 0), ``fw`` = the blocks of its one key tile
+    a step (:func:`chunk_tile`).  A caller's own ``fetch_width`` (the
+    tuner's, the tests') is the blocks fetched at a time in either walk: the
+    key tile's in both.  What the wrapper runs, what
     :func:`modeled_attend_temp_bytes` counts, what the tuner prints and what
     the engine writes on its ``tdp:engine.init.pool`` span."""
     fw, pad_to = _step_params(mb, fetch_width, q_pad_to)
     rows = -(-R // pad_to) * pad_to
-    hb, T = decode_walk(Hkv, rows, mb, fw, bs, block_bytes, quantized)
-    return rows, fw, hb, fw if T and fetch_width is not None else T
+    if fetch_width is not None:
+        hb, T = decode_walk(Hkv, rows, mb, fw, bs, block_bytes, quantized)
+        return rows, fw, hb, fw if T else 0
+    # hb's K + V blocks: at the widest tile a grid step takes
+    hb, T = decode_walk(Hkv, rows, mb, min(mb, max(1, _CHUNK_TILE_KEYS // bs)),
+                        bs, block_bytes, quantized)
+    return rows, fw if T else chunk_tile(hb, rows, mb, bs), hb, T
+
+
+def shape_walk(groups: int, S_in: int, Hkv: int, mb: int, bs: int,
+               block_bytes: int, window: Optional[int] = None,
+               quantized: bool = False, fetch_width: Optional[int] = None,
+               q_pad_to: Optional[int] = None) -> Tuple[int, ...]:
+    """``(split, cols, rows, fw, hb, T)`` of a call of ``groups x S_in``
+    query rows a KV head over a table of ``mb`` columns, as the wrapper asks
+    :func:`call_walk`: the rows of ONE of the head's :func:`head_split`
+    programs over the columns the walk reaches (:func:`walked_columns`)."""
+    split = head_split(groups, S_in)
+    cols = walked_columns(window, mb, S_in, bs)
+    return (split, cols) + call_walk(
+        groups * S_in // split, Hkv, cols, bs, block_bytes, quantized,
+        fetch_width, q_pad_to)
 
 
 def _walk_kernel(
@@ -448,18 +492,31 @@ def _kernel(
     the ``fetch_width`` per-step KV blocks of ``hb`` heads each ((k, v)
     dense or (k8, ks, v8, vs) quantized, sub-block-major), then the output
     ref and the (acc, m, l) online-softmax VMEM scratch carried across j
-    steps.  ``bound`` (static; :func:`window_binds`): step 0 stands at the
-    slot's :func:`first_column`, as the index map's (:func:`fetched_block`),
-    and the grid is only as long as a window's columns."""
+    steps.  A step's blocks side by side are ONE key tile and one
+    online-softmax step: one score product over its ``fetch_width x bs``
+    keys, one mask, one max / exp / rescale, one value product (a step a
+    block spent more on rescaling the accumulator than on its two
+    products).  A dead sub-block of a live tile holds a block fetched
+    earlier (:func:`fetched_block`): its key positions lie past every query
+    position, so the mask takes them out of the scores, and as values the
+    rows behind the call's last position are zeroed (so are the rows of the
+    slot's own last block that nobody wrote yet): nothing reaches the output
+    even as 0 x NaN.  A step whose first block is dead is skipped whole.
+    ``bound`` (static; :func:`window_binds`): step 0 stands at the slot's
+    :func:`first_column`, as the index map's, and the grid is only as long
+    as a window's columns."""
     per = 4 if quantized else 2
-    kv_refs = refs[:fetch_width * per]
-    o_ref = refs[fetch_width * per]
-    acc_ref, m_ref, l_ref = refs[fetch_width * per + 1:]
+    fw = fetch_width
+    kv_refs = refs[:fw * per]
+    o_ref = refs[fw * per]
+    acc_ref, m_ref, l_ref = refs[fw * per + 1:]
     b = pl.program_id(0)
     j = pl.program_id(2)
     off = off_ref[b]
-    hi = (off + S_in + bs - 1) // bs  # live KV blocks for this slot
+    last = off + S_in  # positions written so far, this call's rows included
+    hi = (last + bs - 1) // bs  # live KV blocks for this slot
     col0 = first_column(off, window, bs) if bound else None
+    blk0 = j * fw + col0 if bound else j * fw  # the tile's first column
 
     @pl.when(j == 0)
     def _init():
@@ -467,44 +524,46 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]  # [hb, rows, hd]
-    # row r covers query position off + (r % S_in) (group-major rows);
-    # padded rows past the real R mask everything and are sliced off
-    qpos = off + jax.lax.broadcasted_iota(
-        jnp.int32, (hb, rows, bs), 1) % S_in
-    # scores [hb, rows, bs] = q . k over hd; update [hb, rows, hd] = p . v
-    qk = functools.partial(jnp.einsum, "hrd,hkd->hrk",
-                           preferred_element_type=jnp.float32)
-    pv = functools.partial(jnp.einsum, "hrk,hkd->hrd",
-                           preferred_element_type=jnp.float32)
+    @pl.when(blk0 < hi)
+    def _compute():
+        def side(n, axis, written=False):
+            """Operand ``n`` of the step's blocks side by side along
+            ``axis``, their key axis; ``written``: zero behind ``last``."""
+            x = jnp.concatenate(
+                [kv_refs[per * i + n][0, 0] for i in range(fw)], axis=axis)
+            if not written:
+                return x
+            pos = blk0 * bs + jax.lax.broadcasted_iota(
+                jnp.int32, x.shape, axis)
+            return jnp.where(pos < last, x, jnp.zeros_like(x))
 
-    for i in range(fetch_width):
-        blk = j * fetch_width + i  # absolute pool-block step
-        if bound:
-            blk = blk + col0
+        # scores [hb, rows, K] = q . k over hd; update [hb, rows, hd] = p . v
+        qk = functools.partial(jnp.einsum, "hrd,hkd->hrk",
+                               preferred_element_type=jnp.float32)
+        pv = functools.partial(jnp.einsum, "hrk,hkd->hrd",
+                               preferred_element_type=jnp.float32)
+        q = q_ref[0]  # [hb, rows, hd]
+        if quantized:  # scale rows [hb, 1, K]; an int8 value is never NaN
+            k8, ks = side(0, 1), side(1, 2)
+            v8, vs = side(2, 1), side(3, 2, written=True)
+            s = qk(q.astype(jnp.float32), k8.astype(jnp.float32)) * ks
+            upd = lambda p: pv(p * vs, v8.astype(jnp.float32))
+        else:
+            k, v = side(0, 1), side(1, 1, written=True)  # [hb, K, hd]
+            s = qk(q, k)
+            upd = lambda p: pv(p.astype(v.dtype), v)
+        # row r covers query position off + (r % S_in) (group-major rows);
+        # padded rows past the real R mask everything and are sliced off
+        qpos = off + jax.lax.broadcasted_iota(
+            jnp.int32, (hb, rows, 1), 1) % S_in
+        kpos = blk0 * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (hb, 1, fw * bs), 2)
+        keep = kpos <= qpos
+        if window is not None:  # Mistral: key in (qpos - window, qpos]
+            keep = keep & (kpos > qpos - window)
+        _accumulate(s * sm_scale, keep, upd, acc_ref, m_ref, l_ref)
 
-        @pl.when(blk < hi)
-        def _compute(i=i, blk=blk):
-            if quantized:
-                k8 = kv_refs[4 * i][0, 0]
-                ks = kv_refs[4 * i + 1][0, 0]  # [hb, 1, bs]
-                v8 = kv_refs[4 * i + 2][0, 0]
-                vs = kv_refs[4 * i + 3][0, 0]  # [hb, 1, bs]
-                s = qk(q.astype(jnp.float32), k8.astype(jnp.float32)) * ks
-                upd = lambda p: pv(p * vs, v8.astype(jnp.float32))
-            else:
-                kblk = kv_refs[2 * i][0, 0]  # [hb, bs, hd]
-                vblk = kv_refs[2 * i + 1][0, 0]
-                s = qk(q, kblk)
-                upd = lambda p: pv(p.astype(vblk.dtype), vblk)
-            kpos = blk * bs + jax.lax.broadcasted_iota(
-                jnp.int32, (hb, rows, bs), 2)
-            keep = kpos <= qpos
-            if window is not None:  # Mistral: key in (qpos - window, qpos]
-                keep = keep & (kpos > qpos - window)
-            _accumulate(s * sm_scale, keep, upd, acc_ref, m_ref, l_ref)
-
-    @pl.when(j == ((hi - 1 - col0) if bound else (hi - 1)) // fetch_width)
+    @pl.when(j == ((hi - 1 - col0) if bound else (hi - 1)) // fw)
     def _write():
         # l > 0 for every real row (a query always attends its own
         # position); padded rows divide garbage that is sliced away
@@ -567,16 +626,14 @@ def paged_decode_attention(
     # group-major rows: row r = g*S_in + s covers position off + s
     R = groups * S_in
     # a window that can lie short of the table: both walks start at it and
-    # reach over its columns alone, under a kernel name of their own
+    # reach over its columns alone, under a kernel name of their own; more
+    # rows a KV head than one program takes: its query heads go to ``split``
+    # programs, each fetching the head's blocks
     bound = window_binds(window, mb, bs)
-    cols = walked_columns(window, mb, S_in, bs)
-    # more rows a KV head than one program takes: its query heads go to
-    # ``split`` programs, each fetching the head's blocks
-    split = head_split(groups, S_in)
+    split, cols, rows, fw, hb, T = shape_walk(
+        groups, S_in, Hkv, mb, bs, bs * hd * k_arr.dtype.itemsize, window,
+        quantized, fetch_width, q_pad_to)
     R //= split
-    rows, fw, hb, T = call_walk(
-        R, Hkv, cols, bs, bs * hd * k_arr.dtype.itemsize, quantized,
-        fetch_width, q_pad_to)
     qr = q.reshape(B, Hkv * split, R, hd)
     if rows != R:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - R), (0, 0)))
@@ -633,7 +690,7 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=_out_struct((B, Hkv * split, rows, hd), q.dtype, q),
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(_GRID_VMEM_LIMIT),
         interpret=_interpret(),
         name=name,
     )(*scalars, *operands)
@@ -918,25 +975,24 @@ def modeled_attend_temp_bytes(
     materialized for k AND v (the int8 pool additionally upcasts both to
     f32 in the einsum, so ``itemsize=4`` models that case too) — O(max
     context) whatever the slot holds.  ``pallas``: what one program holds
-    in VMEM (the q/out rows of its ``hb`` KV heads plus the K and V blocks
-    it keeps twice over: both halves of a key tile of ``T`` blocks where the
-    shape takes the in-kernel walk, ``fetch_width`` double-buffered blocks
-    a side where it walks the grid; all from :func:`call_walk` over the
-    columns the walk reaches, :func:`walked_columns`, and the rows of one of
-    a head's :func:`head_split` programs, as the wrapper asks) times the
-    programs of one step: O(block), independent of context."""
+    in VMEM (the q/out rows of its ``hb`` KV heads, the K and V blocks it
+    keeps twice over: both halves of a key tile of ``T`` blocks where the
+    shape takes the in-kernel walk, the ``fw`` double-buffered blocks of a
+    grid step's tile where it walks the grid, and that tile's float32
+    scores ``[hb x rows, keys]``; all from :func:`shape_walk`, as the
+    wrapper asks) times the programs of one step: O(block), independent of
+    context."""
     if impl == "gather":
         return 2 * batch * kv_heads * max_blocks * block_size * head_dim * itemsize
     if impl == "pallas":
-        split = head_split(groups, s_in)
-        rows = groups * s_in // split
         block = block_size * head_dim * itemsize
-        _padded, fw, hb, T = call_walk(
-            rows, kv_heads, walked_columns(window, max_blocks, s_in,
-                                           block_size),
-            block_size, block, fetch_width=fetch_width)
-        # one program: hb heads' q and out rows, T or fw K + V blocks, twice
-        program = hb * (2 * rows * head_dim * itemsize
-                        + 2 * 2 * (T or fw) * block)
+        split, _cols, rows, fw, hb, T = shape_walk(
+            groups, s_in, kv_heads, max_blocks, block_size, block, window,
+            fetch_width=fetch_width)
+        tile = T or fw
+        # one program: hb heads' q and out rows, the tile's K + V blocks
+        # twice, its float32 scores
+        program = hb * (2 * rows * head_dim * itemsize + 2 * 2 * tile * block
+                        + 4 * rows * tile * block_size)
         return batch * (kv_heads * split // hb) * program
     raise ValueError(f"impl must be 'gather' or 'pallas', got {impl!r}")

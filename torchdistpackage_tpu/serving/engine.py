@@ -927,11 +927,14 @@ class ServingEngine:
         return jax.jit(step, donate_argnums=(1, 2))
 
     def _walk_attrs(self) -> Dict[str, int]:
-        """How the paged kernel walks a decode (or verify) call's tables,
-        which follows from the shape alone
-        (``ops.paged_attention.call_walk``): the KV heads a program carries
-        and the pool blocks of one key tile (0: the grid walks the table's
-        columns).  Nothing for the gather path and for a latent pool."""
+        """How the paged kernel walks this engine's calls, which follows
+        from their shapes alone (``ops.paged_attention.call_walk``).  A
+        decode (or verify) call: the KV heads a program carries and the pool
+        blocks of one key tile (0: the grid walks the table's columns).  A
+        prefill chunk's call: the query rows of one program, the keys of its
+        one key tile a grid step (one online-softmax step), and the programs
+        a slot's call is dealt to.  Nothing for the gather path and for a
+        latent pool."""
         k = self.cache.get("k")
         if self.attn_impl != "pallas" or k is None:
             return {}
@@ -939,16 +942,24 @@ class ServingEngine:
         tp = int(self.mesh.shape[self.axis]) if (
             self.mesh is not None and self.axis) else 1
         blk, ops = self.cfg.block, paged_attention_ops
-        groups, s_in = blk.nheads // blk.kv_head_count, self.spec_k + 1
-        # as the wrapper asks: one of a head's programs, the columns walked
-        _rows, _fw, hb, T = ops.call_walk(
-            groups * s_in // ops.head_split(groups, s_in),
-            arr.shape[2] // tp,
-            ops.walked_columns(getattr(blk, "sliding_window", None),
-                               self.max_blocks, s_in, arr.shape[3]),
-            arr.shape[3], arr.shape[3] * arr.shape[4] * arr.dtype.itemsize,
-            self.kv_quant)
-        return {"kv_heads_per_step": hb, "kv_tile_blocks": T}
+        groups, hkv = blk.nheads // blk.kv_head_count, arr.shape[2] // tp
+        bs = arr.shape[3]
+
+        def walk(s_in, window):  # as the wrapper asks
+            return ops.shape_walk(
+                groups, s_in, hkv, self.max_blocks, bs,
+                bs * arr.shape[4] * arr.dtype.itemsize, window, self.kv_quant)
+
+        window = getattr(blk, "sliding_window", None)
+        *_, hb, T = walk(self.spec_k + 1, window)
+        split, _cols, rows, fw, chb, cT = walk(self.chunk, window)
+        attrs = {"kv_heads_per_step": hb, "kv_tile_blocks": T,
+                 "chunk_rows": rows, "chunk_tile_keys": (cT or fw) * bs,
+                 "chunk_programs": hkv * split // chb}
+        if self.window:  # the window layers' chunk walks the window's columns
+            *_, fw, _hb, cT = walk(self.chunk, self.window)
+            attrs["window_chunk_tile_keys"] = (cT or fw) * bs
+        return attrs
 
     def _dispatch(self, fn: Callable, args: Tuple[Any, ...]) -> Tuple[Any, ...]:
         """One call of a compiled step.  The call consumes the pool (and a

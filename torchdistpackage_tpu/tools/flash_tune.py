@@ -25,10 +25,15 @@ lands at awkward row counts), timed at BOTH decode-program shapes —
 shape names a prefill chunk, at its shape too, whose time the report prints
 beside (``chunk_rel``).  ``hb`` and ``T`` are the KV heads a program of the
 decode shape carries and the key tile the kernel would choose on its own,
-which it computes from the shape (``decode_walk``).  ``--shape
-mistral7b.decode`` and ``--shape zaya1.reason`` are the benchmark cells'
-geometries.  ``_KV_TILE_KEYS`` and ``_PAGED_PARAMS`` in
-ops/paged_attention.py are the consumers of a measured row.
+which it computes from the shape (``decode_walk``); ``chunk_rows``,
+``chunk_tile_keys`` and ``chunk_programs`` say the same of the chunk's call
+(``chunk_tile``: a candidate's ``fetch_width`` there is the blocks of the
+ONE key tile a grid step makes of what it fetched).  ``--shape
+mistral7b.decode``, ``--shape zaya1.reason`` and ``--shape
+trinitymini.mixedlen`` (with and without its window) are the benchmark
+cells' geometries.  ``_KV_TILE_KEYS``, ``_TILE_SCORE_BYTES``,
+``_PROGRAM_ROWS`` and ``_PAGED_PARAMS`` in ops/paged_attention.py are the
+consumers of a measured row.
 
 Timing chains the iterations through a data dependency and fetches a scalar
 at the end, so the clock stops after the device has finished.
@@ -145,14 +150,16 @@ def tune_flash_blocks(
 #: (fetch_width, q_pad_to) candidates for the paged decode kernel;
 #: fetch_width is clamped to the table width per shape.  1, 2, 3 and 6
 #: divide or cover ``mistral7b.decode``'s six table columns, 4, 5, 10 and
-#: 20 ``zaya1.reason``'s twenty.
-PAGED_CANDIDATES: Tuple[Tuple[int, int], ...] = (
+#: 20 ``zaya1.reason``'s twenty, 7 the 21 of ``trinitymini.mixedlen``'s window.
+PAGED_CANDIDATES: Tuple[Tuple[Optional[int], int], ...] = (
+    (None, 8),  # what the kernel takes from the shape on its own
     (1, 8),
     (2, 8),
     (3, 8),
     (4, 8),
     (5, 8),
     (6, 8),
+    (7, 8),
     (8, 8),
     (10, 8),
     (20, 8),
@@ -166,6 +173,10 @@ PAGED_CANDIDATES: Tuple[Tuple[int, int], ...] = (
 #: a bf16 pool of 385 blocks), slots 10-70% full (mean ~300 tokens).
 #: ``zaya1.reason``: 64 slots, 8 / 2 heads x 128, block 128, ``max_ctx``
 #: 2,560 (20 columns), a bf16 pool of 1,281 blocks, contexts 256-2,560.
+#: ``trinitymini.mixedlen``: 32 slots, GQA 32 / 4 x 128, block 128,
+#: ``max_ctx`` 14,336 (112 columns), chunk 512 at 4 slots a prefill call,
+#: contexts 1k-12k; its global layers' walk, and ``-window`` its window
+#: layers' (2,048: a chunk reaches over 21 columns wherever it stands).
 PAGED_SHAPES = {
     "mistral7b.decode": dict(
         num_slots=64, kv_heads=8, groups=4, head_dim=128, block_size=128,
@@ -175,12 +186,19 @@ PAGED_SHAPES = {
         num_slots=64, kv_heads=2, groups=4, head_dim=128, block_size=128,
         max_blocks=20, spec_k=2, chunk=256, chunk_slots=8, fill=(0.1, 1.0),
         dtype="bfloat16"),
+    "trinitymini.mixedlen": dict(
+        num_slots=32, kv_heads=4, groups=8, head_dim=128, block_size=128,
+        max_blocks=112, spec_k=0, chunk=512, chunk_slots=4, fill=(0.07, 0.85),
+        dtype="bfloat16"),
 }
+PAGED_SHAPES["trinitymini.mixedlen-window"] = dict(
+    PAGED_SHAPES["trinitymini.mixedlen"], window=2048)
 
 
 def _time_paged_config(
     q_shape, k_pool, v_pool, tables, offsets, fetch_width, q_pad_to,
     steps: int, warmup: int, seed: int, calls: int = 16,
+    window: Optional[int] = None,
 ) -> float:
     """Seconds per call of the kernel at one q shape for one
     (fetch_width, q_pad_to).  One dispatch runs ``calls`` calls chained
@@ -195,7 +213,7 @@ def _time_paged_config(
     def step(qq, kp, vp):
         def body(qq, _):
             return qq + paged_decode_attention(
-                qq, kp, vp, tables, offsets,
+                qq, kp, vp, tables, offsets, window=window,
                 fetch_width=fetch_width, q_pad_to=q_pad_to), None
         return jax.lax.scan(body, qq, None, length=calls)[0]
 
@@ -223,6 +241,7 @@ def tune_paged_params(
     chunk_slots: int = 8,
     fill: Tuple[float, float] = (0.25, 1.0),
     dtype="float32",
+    window: Optional[int] = None,
     candidates: Sequence[Tuple[int, int]] = PAGED_CANDIDATES,
     steps: int = 10,
     warmup: int = 2,
@@ -233,14 +252,16 @@ def tune_paged_params(
     with per-slot tables at mixed live lengths (``fill``: the share of the
     max context a slot holds, drawn uniformly between the two), q at
     S_in=1 (decode) AND S_in=spec_k+1 (the verify program); with ``chunk``,
-    also ``chunk_slots`` slots' prefill chunk.  Returns ``(best, report)``
-    with ``report`` rows ``{"fetch_width", "q_pad_to", "hb", "T", "ms",
-    "decode_ms", "verify_ms"[, "chunk_ms", "chunk_rel"], "rel"}`` sorted
-    fastest-first by ``ms`` = decode + verify; ``chunk_rel`` is the chunk's
-    time over the fastest chunk's."""
+    also ``chunk_slots`` slots' prefill chunk; ``window``: a sliding window
+    on every call.  Returns ``(best, report)`` with ``report`` rows
+    ``{"fetch_width", "q_pad_to", "hb", "T", "ms", "decode_ms",
+    "verify_ms"[, "chunk_ms", "chunk_rel", "chunk_rows", "chunk_tile_keys",
+    "chunk_programs"], "rel"}`` sorted fastest-first by ``ms`` = decode +
+    verify; ``chunk_rel`` is the chunk's time over the fastest chunk's, and
+    the row whose ``fetch_width`` is None is the kernel left to itself."""
     import numpy as np
 
-    from ..ops.paged_attention import call_walk
+    from ..ops.paged_attention import shape_walk
 
     dtype = jnp.dtype(dtype)
     nb = max_blocks * num_slots + 1
@@ -270,18 +291,26 @@ def tune_paged_params(
                         max_ctx - chunk))
 
     rows = []
+    block_bytes = block_size * head_dim * dtype.itemsize
     for fw, pad in candidates:
-        if fw > max_blocks:
+        if fw is not None and fw > max_blocks:
             continue
-        _rows, _fw, hb, T = call_walk(
-            groups, kv_heads, max_blocks, block_size,
-            block_size * head_dim * dtype.itemsize, q_pad_to=pad)
+        *_, hb, T = shape_walk(
+            groups, 1, kv_heads, max_blocks, block_size, block_bytes, window,
+            q_pad_to=pad)
         row = {"fetch_width": fw, "q_pad_to": pad, "hb": hb, "T": T}
+        if chunk:  # the chunk's call as the wrapper asks for it
+            split, _cols, c_rows, c_fw, c_hb, c_T = shape_walk(
+                groups, chunk, kv_heads, max_blocks, block_size, block_bytes,
+                window, fetch_width=fw, q_pad_to=pad)
+            row.update(chunk_rows=c_rows,
+                       chunk_tile_keys=(c_T or c_fw) * block_size,
+                       chunk_programs=kv_heads * split // c_hb)
         try:
             for name, (slots, s_in, offs) in shapes.items():
                 row[f"{name}_ms"] = 1e3 * _time_paged_config(
                     (slots, H, s_in, head_dim), kp, vp, tables[:slots],
-                    offs, fw, pad, steps, warmup, seed)
+                    offs, fw, pad, steps, warmup, seed, window=window)
             row["ms"] = row["decode_ms"] + row["verify_ms"]
         except Exception as e:  # one bad config must not kill the sweep
             row.update(ms=None, error=repr(e)[:200])
@@ -299,8 +328,9 @@ def tune_paged_params(
         for k in [k for k in r if k == "ms" or k.endswith("_ms")]:
             r[k] = round(r[k], 3)
     report = ok + [r for r in rows if r.get("ms") is None]
-    best = {"fetch_width": ok[0]["fetch_width"],
-            "q_pad_to": ok[0]["q_pad_to"]}
+    named = [r for r in ok if r["fetch_width"] is not None]
+    best = {"fetch_width": named[0]["fetch_width"],
+            "q_pad_to": named[0]["q_pad_to"]}
     return best, report
 
 
