@@ -447,7 +447,7 @@ def test_call_ties_a_run_ahead_fetch_to_the_dispatch_of_the_tick_before(toy):
         kind, tick = made[r[5]["call"]]
         assert tick <= ticks[r[1]]
         if kind == "tdp:engine.prefill":
-            assert tick == ticks[r[1]]   # a prefill call is fetched at once
+            assert tick == ticks[r[1]]   # a prefill call is fetched in its tick
         behind += tick < ticks[r[1]]
     assert behind >= 5   # decode calls: fetched a tick (or an idle poll) on
 

@@ -634,7 +634,8 @@ def _full_width_calls(eng):
         out = eng._step_fn(eng.params, eng.cache, tokens, tables, offsets,
                            last_idx, eng._samp(), eng._keys)
         eng.cache = out[0]
-        return np.asarray(out[1]), np.asarray(out[2])
+        # what _prefill_calls hands back: the calls' fetch
+        return lambda: (np.asarray(out[1]), np.asarray(out[2]), {})
 
     return calls
 

@@ -528,11 +528,13 @@ def test_lifecycle_trace_preempt_drain_resume(fp, event_log, tmp_path):
     a = eng.submit(Request(pa.tolist(), NEW))
     v = eng.submit(Request(pv.tolist(), NEW))
 
-    def _decoding(rid):
-        return any(s.rid == rid and s.state == "decode" and s.generated
-                   for s in eng._slots)
+    def _decoding(rid, tokens=1):
+        return any(s.rid == rid and s.state == "decode"
+                   and len(s.generated) >= tokens for s in eng._slots)
 
-    while not (_decoding(a) and _decoding(v)):
+    # past their first verify call: a prompt's last slice leaves the slot in
+    # DECODE with ONE token, and its first decode step is the next tick's
+    while not (_decoding(a, 2) and _decoding(v, 2)):
         eng.step()
         assert eng._tick < 100
     # v (most recently admitted at equal priority) is the preemption

@@ -16,17 +16,17 @@ batching).  This engine is that scheduler, built TPU-first:
   code between ticks only rewrites small int32 tables.  No shape ever
   depends on which requests are in flight, so there is no per-request
   retrace (``serving_summary()['decode_signatures']`` is the evidence).
-- **Chunked prefill.**  Prompts enter through the same paged forward in
-  ``chunk``-token slices, one slice per tick for every prefilling slot —
-  a long prompt never stalls in-flight decodes for more than one chunk's
-  latency.  The compiled call carries only the slots that ARE prefilling
-  (a compact ``[dp * prefill_width, chunk]`` batch, ``ceil(n / W)`` calls
-  of that one signature queued back to back when a tick has more;
-  ``_prefill_batches``, ``PREFILL_WIDTH``), so
-  an admission costs its own rows, not ``num_slots`` of them.  The final
-  slice samples the first token
-  (per-slot ``last_idx`` picks the true last prompt row out of the padded
-  chunk), which is also when TTFT stops ticking.
+- **Chunked prefill, and the tick's order.**  Prompts enter through the
+  same paged forward in ``chunk``-token slices, one a tick for every
+  prefilling slot, in compact ``[dp * prefill_width, chunk]`` calls
+  (``ceil(n / W)`` of the ONE signature back to back: ``_prefill_batches``,
+  ``PREFILL_WIDTH``), so an admission costs its own rows.  A tick dispatches
+  ALL of its calls before it fetches any: the prefill calls, the decode call
+  behind them, then the fetches, so the device runs both while results
+  travel and the host walks.  A prompt's last slice samples the first token
+  (``last_idx``; TTFT stops there); that slot's first DECODE step is the NEXT
+  tick's call, this tick's having been built before the fetch: its second
+  token comes one tick later, once (``late_joins``; :meth:`step`).
 - **Per-slot sampling.**  Temperature / top-k / top-p and the PRNG key are
   ``[num_slots]`` arrays, so every request keeps its own sampling policy
   and stream inside one compiled sampler (temperature 0 = greedy, exactly
@@ -1617,6 +1617,17 @@ class ServingEngine:
     #: cache key, PERF.md section 6, PR 34.)
     _call = 0
 
+    def _still(self, slots: List[Tuple[int, Tuple[int, float]]], state: str):
+        """Of a dispatched call's ``slots`` (``(slot, (rid, t_admit))`` as
+        it was built), those that are still their request's and in
+        ``state`` when its results arrive, as ``(slot, its state)``: a slot
+        retired, cancelled, preempted or requeued meanwhile, and whoever
+        was admitted into it since, gets nothing of the call's."""
+        for i, who in slots:
+            s = self._slots[i]
+            if s.state == state and (s.rid, s.t_admit) == who:
+                yield i, s
+
     def _masked(self, state: str) -> np.ndarray:
         """Table rows for slots NOT in ``state`` zeroed (NULL block) so a
         phase's step can never touch another phase's cache blocks."""
@@ -1713,17 +1724,24 @@ class ServingEngine:
             batches.append((slot_of, args))
         return batches
 
-    def _prefill_calls(self, pre: List[int]) -> Tuple[np.ndarray, np.ndarray]:
+    def _prefill_calls(self, pre: List[int]) -> Callable[[], Tuple[
+            np.ndarray, np.ndarray, Dict[int, np.ndarray]]]:
         """One ``chunk``-token slice for every slot of ``pre`` through the
         compiled step: ``ceil(n / W)`` calls of the ONE compact signature
-        (:meth:`_prefill_batches`), then the one fetch.  Returns the
-        sampled tokens and the advanced keys by SLOT index (``[num_slots]``;
-        rows of slots not in ``pre`` are zero)."""
+        (:meth:`_prefill_batches`), dispatched and NOT waited for.  Returns
+        their fetch: called once, behind whatever else the tick dispatches,
+        it waits for the calls and gives the sampled tokens and the
+        advanced keys by SLOT index (``[num_slots]``; rows of slots not in
+        ``pre`` are zero) and, with ``record_routing``, each slot's piece
+        of the record."""
         C = self.chunk
         with span("tdp:engine.build"):
             batches = self._prefill_batches(pre)
             first = self._first_call("prefill", batches[0][1][0])
             self._call += len(batches)
+            # what the fetch says of the calls it waits for: the decode
+            # call's build moves the engine's count on before it opens
+            waits_for = {"call": self._call, **first}
             # tokens: the real prompt tokens of this tick's slices; rows:
             # what the compiled calls compute, padding included; call: the
             # LAST of the `calls` device calls this span dispatches, by the
@@ -1736,10 +1754,10 @@ class ServingEngine:
                 tokens=sum(
                     min(C, len(self._slots[i].prompt) - self._slots[i].off)
                     for i in pre),
-                calls=len(batches), call=self._call,
+                calls=len(batches),
                 rows=sum(args[0].size for _, args in batches),
                 sampled_rows=np.count_nonzero(self._temps[pre] > 0),
-                rids=self._tick_prefill_rids, **first)
+                rids=self._tick_prefill_rids, **waits_for)
             if self.state_model:
                 attrs["state_slots"] = len(pre)
             if self._idx_topk:
@@ -1755,25 +1773,29 @@ class ServingEngine:
             # so a queued call holds its small inputs only
             outs = [self._dispatch(self._step_fn, args)
                     for _, args in batches]
-        tok = np.zeros(self.num_slots, np.int32)
-        keys = np.zeros_like(self._keys)
-        with span("tdp:engine.fetch", call=self._call, **first):
-            for (slot_of, args), out in zip(batches, outs):
-                live = slot_of >= 0
-                tok[slot_of[live]] = np.asarray(out[0])[live]
-                keys[slot_of[live]] = np.asarray(out[1])[live]
-                if len(out) > 2:  # expert layers: load stats ride along
-                    self._absorb_moe_stats(*out[2:5])
-                if len(out) > 5:  # record_routing: the real positions' own
-                    n_valid, by_row = args[-1], isinstance(out[5], tuple)
-                    routing = None if by_row else np.asarray(out[5])
-                    for r in np.flatnonzero(live):
-                        piece = (np.asarray(out[5][r]) if by_row
-                                 else routing[r])
-                        self._slots[slot_of[r]].routing.append(
-                            piece[:n_valid[r]])
         self.stats["prefill_calls"] += len(batches)
-        return tok, keys
+
+        def fetch():
+            tok = np.zeros(self.num_slots, np.int32)
+            keys = np.zeros_like(self._keys)
+            routing: Dict[int, np.ndarray] = {}
+            with span("tdp:engine.fetch", **waits_for):
+                for (slot_of, args), out in zip(batches, outs):
+                    live = slot_of >= 0
+                    tok[slot_of[live]] = np.asarray(out[0])[live]
+                    keys[slot_of[live]] = np.asarray(out[1])[live]
+                    if len(out) > 2:  # expert layers: load stats ride along
+                        self._absorb_moe_stats(*out[2:5])
+                    if len(out) > 5:  # record_routing: the real positions' own
+                        n_valid, by_row = args[-1], isinstance(out[5], tuple)
+                        whole = None if by_row else np.asarray(out[5])
+                        for r in np.flatnonzero(live):
+                            piece = (np.asarray(out[5][r]) if by_row
+                                     else whole[r])
+                            routing[slot_of[r]] = piece[:n_valid[r]]
+            return tok, keys, routing
+
+        return fetch
 
     def _book_prefill(self, n_calls: int) -> None:
         """The events of a tick's ``n_calls`` prefill calls, once their
@@ -1803,35 +1825,51 @@ class ServingEngine:
             self._ev.emit("cp_ring_hop", tick=self._tick, hops=hops,
                           bytes=bts)
 
-    def _prefill_tick(self) -> int:
-        """One ``chunk``-token slice for EVERY prefilling slot
-        (:meth:`_prefill_calls`: only those slots' rows are computed).
-        Slots whose slice covers the last prompt row sample their first
-        token (TTFT) and move to DECODE."""
+    def _dispatch_prefill(self) -> Optional[Dict[str, Any]]:
+        """One ``chunk``-token slice for EVERY prefilling slot, dispatched
+        (:meth:`_prefill_calls`: only those slots' rows are computed) and
+        left on the device: what :meth:`_land_prefill` takes once the
+        tick's decode call is queued behind it.  None: nobody prefills."""
         pre = [i for i, s in enumerate(self._slots) if s.state == PREFILL]
         if not pre:
-            return 0
-        C = self.chunk
+            return None
         self._tick_prefill_rids = [self._slots[i].rid for i in pre]
         # how many calls it took, by the engine's own count (a test stands
-        # another function of the same two results in for _prefill_calls)
+        # another function of the same results in for _prefill_calls)
         calls_before = self.stats["prefill_calls"]
-        tok, keys = self._prefill_calls(pre)
-        with span("tdp:engine.absorb"):
-            self._book_prefill(self.stats["prefill_calls"] - calls_before)
-            self._walk_prefilled(pre, tok, keys)
-        self.stats["prefill_chunks"] += 1
-        return len(pre)
+        slots = [(i, (self._slots[i].rid, self._slots[i].t_admit))
+                 for i in pre]
+        fetch = self._prefill_calls(pre)
+        return {"slots": slots, "fetch": fetch,
+                "calls": self.stats["prefill_calls"] - calls_before}
 
-    def _walk_prefilled(self, pre: List[int], tok: np.ndarray,
-                        keys: np.ndarray) -> None:
-        """Book one fetched prefill slice a slot of ``pre``."""
+    def _land_prefill(self, call: Optional[Dict[str, Any]]) -> None:
+        """Fetch what a tick's prefill calls returned and book it.  Slots
+        whose slice covers the last prompt row have sampled their first
+        token (TTFT) and move to DECODE: the NEXT tick's decode call is
+        their first, since this tick's was dispatched before this fetch."""
+        if call is None:
+            return
+        tok, keys, routing = call["fetch"]()
+        with span("tdp:engine.absorb"):
+            self._book_prefill(call["calls"])
+            self._walk_prefilled(call["slots"], tok, keys, routing)
+        self.stats["prefill_chunks"] += 1
+
+    def _walk_prefilled(self, slots: List[Tuple[int, Tuple[int, float]]],
+                        tok: np.ndarray, keys: np.ndarray,
+                        routing: Dict[int, np.ndarray]) -> None:
+        """Book one fetched prefill slice a slot of ``slots`` (``(slot,
+        (rid, t_admit))`` as the calls were dispatched: a slot that was
+        cancelled, preempted or requeued meanwhile has its slice dropped,
+        as ``run_ahead``'s flight drops a token)."""
         C = self.chunk
         if self.chaos is not None:
             tok = self.chaos.perturb_engine_tokens(self._tick, tok)
         now = time.perf_counter()
-        for i in pre:
-            s = self._slots[i]
+        for i, s in self._still(slots, PREFILL):
+            if i in routing:  # record_routing
+                s.routing.append(routing[i])
             s.off += C
             if s.off >= len(s.prompt):  # final slice: first token sampled
                 if self._token_poisoned(int(tok[i])):
@@ -1858,20 +1896,29 @@ class ServingEngine:
                 s.generated.append(int(tok[i]))
                 self._tick_emitted += 1
                 self._maybe_retire(i, int(tok[i]), now)
+                if s.state == DECODE and not self.hold_decode:
+                    # not retired by its first token: it sat this tick's
+                    # decode call out and joins the next
+                    self.stats["late_joins"] += 1
 
-    def _decode_tick(self) -> int:
+    def _dispatch_decode(self) -> Optional[Dict[str, Any]]:
+        """The tick's decode call (a speculative engine's verify call),
+        built from the slots that are in DECODE now and dispatched behind
+        the tick's prefill calls, whose results are still on the device: a
+        slot whose prompt they end is not among them.  Returns the call for
+        :meth:`_absorb_decode`: its outputs, its slots as ``(slot, (rid,
+        t_admit))`` and what its fetch span says of it.  None: no slot
+        decodes, or decoding is another replica's."""
         if self.hold_decode:
             # disaggregated prefill tier: decoding is another replica's
             # job — parked slots wait for the router's export
-            return 0
+            return None
         if self.spec_k:
-            return self._spec_decode_tick()
-        flight = self._flight
+            return self._dispatch_verify()
         with span("tdp:engine.build"):
-            built = self._build_decode(flight)
+            built = self._build_decode(self._flight)
         if built is None:
-            self._absorb_decode(flight)
-            return 0
+            return None
         args, slots, attrs = built
         if self.window:
             with span("tdp:engine.handon"):
@@ -1882,17 +1929,9 @@ class ServingEngine:
             out = self._dispatch(self._decode_fn, args)
         self.stats["decode_steps"] += 1
         self.stats["decode_slot_steps"] += len(slots)
-        # what the fetch span says of the call it waits for
-        call = {"out": out, "slots": slots,
+        return {"out": out, "slots": slots,
                 "waits_for": {k: attrs[k] for k in ("call", "first")
                               if k in attrs}}
-        if self.run_ahead:
-            # the call before this one: the device has it done, or nearly
-            self._absorb_decode(flight)
-            self._flight = call
-        else:
-            self._absorb_decode(call)
-        return len(slots)
 
     def _build_decode(self, flight: Optional[Dict[str, Any]]) -> Optional[
             Tuple[Tuple[Any, ...], List[Tuple[int, Tuple[int, float]]],
@@ -1907,10 +1946,7 @@ class ServingEngine:
         ahead = np.zeros(self.num_slots, bool)
         if flight is not None:
             # run_ahead: the slots whose newest token is still on the device
-            for i, who in flight["slots"]:
-                s = self._slots[i]
-                if s.state != DECODE or (s.rid, s.t_admit) != who:
-                    continue
+            for i, s in self._still(flight["slots"], DECODE):
                 if len(s.generated) + 1 >= s.req.max_new_tokens:
                     mask[i], tables[i] = False, 0  # that token is its last
                 else:
@@ -2026,7 +2062,8 @@ class ServingEngine:
         preempted or requeued meanwhile has its token dropped."""
         if call is None:
             return
-        self._flight = None
+        if self.spec_k:
+            return self._absorb_verified(call)
         out = call["out"]
         with span("tdp:engine.fetch", **call["waits_for"]) as sp:
             tok = np.asarray(out[0])
@@ -2045,10 +2082,7 @@ class ServingEngine:
             if self.chaos is not None:
                 tok = self.chaos.perturb_engine_tokens(self._tick, tok)
             now = time.perf_counter()
-            for i, who in call["slots"]:
-                s = self._slots[i]
-                if s.state != DECODE or (s.rid, s.t_admit) != who:
-                    continue
+            for i, s in self._still(call["slots"], DECODE):
                 if routing is not None:  # record_routing
                     s.routing.append(routing[i])
                 if self._token_poisoned(int(tok[i])):
@@ -2093,7 +2127,7 @@ class ServingEngine:
             cand.append(cand[-1] if cand else hist[-1])
         return cand[:K]
 
-    def _spec_decode_tick(self) -> int:
+    def _dispatch_verify(self) -> Optional[Dict[str, Any]]:
         """The speculative decode tick: the drafter proposes a STATIC
         ``spec_k`` tokens per decoding slot, ONE compiled verify program
         scores all k+1 positions in a single paged-attention step, and
@@ -2103,7 +2137,9 @@ class ServingEngine:
         before it can ever be attended, exactly the
         ``speculative_generate`` argument).  Emits 1..k+1 tokens per slot
         per tick at one decode-signature — the decode latency floor
-        broken without touching the compile-once contract."""
+        broken without touching the compile-once contract.  This half
+        drafts and dispatches; :meth:`_absorb_verified` fetches and walks,
+        in the same tick (the next tick's drafts need its tokens)."""
         K = self.spec_k
         with span("tdp:engine.build"):
             mask, tables = self._masked(DECODE)
@@ -2111,17 +2147,16 @@ class ServingEngine:
             tokens = np.zeros((self.num_slots, K + 1), np.int32)
             offsets = np.where(mask, self._lengths, 0).astype(np.int32)
         if n_active == 0:
-            return 0
-        rids = []
+            return None
+        slots = []
         with span("tdp:engine.draft"):
-            for i, s in enumerate(self._slots):
-                if s.state != DECODE:
-                    continue
-                rids.append(s.rid)
+            for i in np.flatnonzero(mask):
+                s = self._slots[i]
+                slots.append((int(i), (s.rid, s.t_admit)))
                 tokens[i, 0] = self._last_tok[i]
                 tokens[i, 1:] = self._draft(s)
         with span("tdp:engine.build"):  # the draft is a phase of its own
-            self._tick_decode_rids = rids
+            rids = self._tick_decode_rids = [who[0] for _, who in slots]
             self._ev.emit("spec_draft", k=K, n_slots=len(rids), rids=rids)
             self._call += 1
             waits_for = {"call": self._call,
@@ -2129,32 +2164,36 @@ class ServingEngine:
             samp = self._samp()
         with span("tdp:engine.decode", slots=n_active, rids=rids,
                   **waits_for):
-            self.cache, verify, accept, keys = self._verify_fn(
+            self.cache, *out = self._verify_fn(
                 self.params, self.cache, tokens, tables, offsets, samp,
                 self._keys)
-        with span("tdp:engine.fetch", **waits_for):
-            verify = np.asarray(verify)
-            accept = np.asarray(accept)
-            keys = np.asarray(keys)
-        with span("tdp:engine.absorb"):
-            self._walk_verified(tokens, verify, accept, keys, n_active)
-        return n_active
+        return {"out": out, "slots": slots, "tokens": tokens,
+                "waits_for": waits_for}
 
-    def _walk_verified(self, tokens: np.ndarray, verify: np.ndarray,
-                       accept: np.ndarray, keys: np.ndarray,
-                       n_active: int) -> None:
-        """Book one fetched verify call: every decoding slot's accepted
-        draft prefix and the model's own token behind it."""
-        K, rids = self.spec_k, self._tick_decode_rids
+    def _absorb_verified(self, call: Dict[str, Any]) -> None:
+        """Fetch one verify call and book it: every slot's accepted draft
+        prefix and the model's own token behind it, for the slots the call
+        was built from."""
+        with span("tdp:engine.fetch", **call["waits_for"]):
+            verify, accept, keys = (np.asarray(o) for o in call["out"])
+        with span("tdp:engine.absorb"):
+            self._walk_verified(call["slots"], call["tokens"], verify,
+                                accept, keys)
+
+    def _walk_verified(self, slots: List[Tuple[int, Tuple[int, float]]],
+                       tokens: np.ndarray, verify: np.ndarray,
+                       accept: np.ndarray, keys: np.ndarray) -> None:
+        """Book one fetched verify call: the accepted draft prefix and the
+        model's own token behind it for each of ``slots`` (``tokens``: what
+        the call was handed, a slot's last token and its drafts)."""
+        K, rids, n_active = self.spec_k, self._tick_decode_rids, len(slots)
         if self.telemetry is not None:
             self.telemetry.end_step(active_slots=n_active)
         if self.chaos is not None:
             verify = self.chaos.perturb_engine_tokens(self._tick, verify)
         now = time.perf_counter()
         emitted_total = accepted_total = 0
-        for i, s in enumerate(self._slots):
-            if s.state != DECODE:
-                continue
+        for i, s in self._still(slots, DECODE):
             # accepted draft prefix, then the model's correction (or the
             # bonus token when every draft survived)
             emitted: List[int] = []
@@ -2442,6 +2481,31 @@ class ServingEngine:
         -> admit (with preemption) -> one prefill slice -> one decode
         step.  Returns what happened (all zeros = idle).
 
+        **Every device call of the tick is dispatched before any call of
+        the tick is fetched**: build and dispatch the prefill calls P, build
+        and dispatch the decode call D behind them (the pool and the state
+        are donated and chained on the device, so D needs nothing of P's on
+        the host), absorb ``run_ahead``'s flight (the decode call of the
+        tick before), fetch P and walk its slots, and without ``run_ahead``
+        fetch D and absorb it.  The device runs P and D back to back while
+        P's results travel, are walked, and the next tick audits, admits and
+        builds.  D is built from the slots that are in DECODE when the
+        tick's calls begin, so a slot whose prompt ends in P takes its first
+        decode step in the NEXT tick's decode call, its token from the host
+        like every newly decoding slot's: the same tokens as before, the
+        time to the first token unmoved (it is P's), the SECOND token one
+        tick later, once a request, and the slot held one tick longer.
+        ``stats['late_joins']`` counts those slots and
+        ``stats['ticks_queued_whole']`` the ticks that had both kinds of
+        call (``serving_summary()['tick_accounting']`` and every tick
+        record carry both).  A first token that ends its request (EOS,
+        ``max_new_tokens == 1``) retires at the walk and joins nothing; a
+        slot cancelled, preempted or requeued between P's dispatch and its
+        fetch has its slice dropped.  ``hold_decode`` has no D and fetches
+        P at once; a speculative tick's verify call takes D's place and is
+        fetched in its own tick (the next draft needs its tokens), behind
+        P's fetch.
+
         The tick is a ``tdp:engine.tick`` span with one child span a phase
         (``tdp:engine.audit`` / ``sched`` / ``prefill`` / ``draft`` /
         ``decode`` / ``fetch``; utils/profiling.py: the process-wide ring,
@@ -2456,7 +2520,10 @@ class ServingEngine:
         request-lifecycle trace — serving/tracing.py), and exported live
         through ``metrics_sink`` under the ``serving_metrics`` schema.  All
         of it is wall-clock bookkeeping around the SAME two compiled calls:
-        zero extra device dispatches, ``decode_signatures`` stays 1."""
+        zero extra device dispatches, ``decode_signatures`` stays 1.  A
+        ``tdp:engine.fetch`` names the call it waits for (``call``): the
+        prefill calls' fetch opens after the decode call's dispatch span has
+        closed and still carries the PREFILL calls' count."""
         with span("tdp:engine.tick", tick=self._tick + 1) as tick:
             self._tick += 1
             self._tick_prefill_rids = []
@@ -2465,6 +2532,7 @@ class ServingEngine:
             self._tick_moe = dict.fromkeys(_MOE_CALL_STATS, 0.0)
             self._tick_dsa = [0, 0]
             self._tick_window = [0, 0]
+            joins_before = self.stats["late_joins"]
             if self.chaos is not None:
                 self.chaos.before_engine_tick(self._tick, self)
             self.stats["audits"] += 1
@@ -2473,8 +2541,21 @@ class ServingEngine:
             with span("tdp:engine.sched"):
                 expired = self._expire_queue(time.perf_counter())
                 admitted = self._admit()
-            prefilled = self._prefill_tick()
-            decoded = self._decode_tick()
+            # every call of the tick is on its way before any is waited for
+            prefill = self._dispatch_prefill()
+            decode = self._dispatch_decode()
+            prefilled = len(prefill["slots"]) if prefill else 0
+            decoded = len(decode["slots"]) if decode else 0
+            queued_whole = bool(prefill and decode)
+            self.stats["ticks_queued_whole"] += queued_whole
+            if self.run_ahead:
+                # the call of the tick before: the device has it done
+                decode, self._flight = self._flight, decode
+                self._absorb_decode(decode)
+                self._land_prefill(prefill)
+            else:
+                self._land_prefill(prefill)
+                self._absorb_decode(decode)
             with span("tdp:engine.record"):
                 busy = self.n_busy
                 if not busy:
@@ -2502,13 +2583,17 @@ class ServingEngine:
                         else 0.8 * self._tick_ewma + 0.2 * dt)
                 self._record_tick(tick, t_end, admitted=admitted,
                                   expired=expired, prefilled=prefilled,
-                                  decoded=decoded, busy=busy, util=util)
+                                  decoded=decoded, busy=busy, util=util,
+                                  queued_whole=queued_whole,
+                                  late_joins=(self.stats["late_joins"]
+                                              - joins_before))
         return {"admitted": admitted, "prefill_slots": prefilled,
                 "decode_slots": decoded, "busy": busy, "expired": expired}
 
     def _record_tick(self, tick: span, t_end: float, *, admitted: int,
                      expired: int, prefilled: int, decoded: int, busy: int,
-                     util: float) -> None:
+                     util: float, queued_whole: bool,
+                     late_joins: int) -> None:
         """The tick-level accounting record: the phase decomposition,
         summed from the tick's child spans (the residual ``host`` phase is
         everything the six phase spans did not cover — queue sorts, table
@@ -2543,6 +2628,10 @@ class ServingEngine:
             "batch_util": round(decoded / self.num_slots, 4),
             "pool_util": round(util, 4),
             "emitted_tokens": self._tick_emitted,
+            # the decode call was dispatched before the prefill calls were
+            # fetched; slots whose prompt ended here and wait for the next
+            "queued_whole": queued_whole,
+            "late_joins": late_joins,
             "prefix_hit_rate": round(
                 st["prefix_cached_tokens"] / st["prefix_prompt_tokens"], 4)
             if st["prefix_prompt_tokens"] else 0.0,
@@ -3066,6 +3155,7 @@ class ServingEngine:
                       "imports_aborted": 0,
                       "cp_ring_hops": 0, "cp_ring_bytes": 0,
                       "blocks_handed_on": 0,
+                      "ticks_queued_whole": 0, "late_joins": 0,
                       **dict.fromkeys(_MOE_CALL_STATS, 0.0)}
         self._decode_sigs: set = set()
         self._prefill_sigs: set = set()
@@ -3241,6 +3331,15 @@ class ServingEngine:
                             if ticks else 0.0),
             "phases_mean_s": {k: round(v, 9)
                               for k, v in phases_mean.items()},
+            # the tick's order: of the ticks that made prefill calls AND a
+            # decode call (by the records kept), those whose decode call
+            # was dispatched before the prefill calls were fetched (every
+            # one, by the engine's count), and the slots that took their
+            # first decode step a tick after their prompt's last slice
+            "ticks_prefill_and_decode": sum(
+                1 for t in ticks if t["prefill_slots"] and t["decode_slots"]),
+            "ticks_queued_whole": st["ticks_queued_whole"],
+            "late_joins": st["late_joins"],
         }
         # --- live expert-load (MoE families): moe_load_stats over the
         # accumulated per-expert routed-token counts.  The overflow
