@@ -464,7 +464,7 @@ def main() -> None:
                  f"(have {sorted(meshes)})")
     cache_dir = compile_cache()
     compiles = CompileCounter()
-    # the repo's ~1B GPT (bench.py --big), every layer
+    # the repo's ~1B GPT, every layer
     cfg = SmokeConfig(
         model=GPTConfig(
             vocab_size=32768, dim=2048, nheads=16, nlayers=16, max_seq=2048,

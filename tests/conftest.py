@@ -124,7 +124,7 @@ def devices8():
 
 # ---------------------------------------------- compiled-bundle registry
 #
-# ROADMAP 5b down payment: compiles dominate the tier-1 budget, and the
+# Compiles dominate the tier-1 budget, and the
 # most expensive ones are "canonical reference" bundles (a golden engine
 # run, a baseline forward) that several tests in a module — or several
 # modules — each rebuild from scratch.  The bank memoizes those bundles

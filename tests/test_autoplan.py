@@ -156,9 +156,8 @@ def test_awkward_chip_counts_factor():
 def test_pp_candidates_modeled_and_executable():
     """Pipeline splits are in the search space (schedule-aware bubble on
     the compute term, ppermute comm term over the pipe axis) AND — PR 14
-    — in the executable set: bench's pipeline runner drives the 1F1B/ZB
-    schedules, so ``executable_only`` keeps pp>1 arms (restricted to the
-    dp layout, no compression).  Every pp row records which schedule the
+    — in the executable set: ``executable_only`` keeps pp>1 arms
+    (restricted to the dp layout, no compression).  Every pp row records which schedule the
     planner priced it under and that schedule's tick-model bubble."""
     res = ap.plan(TINY_DICT, 8, global_batch=8, memory="analytic",
                   emit=False, top=64)
@@ -412,7 +411,7 @@ def measured_bundle():
     total in this file)."""
     # allow_pp=False: this bundle exercises the dp/tp GSPMD runner
     # layouts (the pipelined runner has its own goldens in
-    # tests/test_pipeline.py and the bench.py --autoplan pp audit)
+    # tests/test_pipeline.py)
     result = ap.plan(
         TINY, 8, global_batch=8, comm_model=_cpu_model(),
         memory="model", executable_only=True, compression=False,

@@ -12,8 +12,8 @@ the prefill-tier -> decode-replica handoff — while ``decode_signatures``
 stays 1 (the S_in=1 signature compiles the local-slice + psum-combine
 decode, not a second ring program).
 
-Reference engines are banked per session (``bundle_bank`` in conftest —
-ROADMAP 5b): every test here shares one golden run per model family.
+Reference engines are banked per session (``bundle_bank`` in conftest):
+every test here shares one golden run per model family.
 """
 
 import jax
@@ -262,7 +262,7 @@ def test_cp_ring_hops_priced_per_hop(refs, devices8):
     ov = cp_ring_overlap(led)
     assert ov["cp_hops"] == 8
     assert ov["cp_hop_bytes"] == sum(c["bytes"] for c in perms)
-    assert ov["cp_async_hops"] >= 0  # CPU HLO: sync; on-chip in ROADMAP 5c
+    assert ov["cp_async_hops"] >= 0  # CPU HLO: sync; async only on the chip
 
 
 # ------------------------------------------------------------ validation

@@ -12,8 +12,8 @@ The load-bearing claims:
   chose from, ``handoff_decision``/``rebalance_decision`` for every
   cross-replica move, counts reconciling EXACTLY with ``Router.stats``;
 - ``Router.alive`` flips land ``replica_up``/``replica_down`` (with
-  reason/role/zone) on the timeline — the ROADMAP 2(a) autoscaler
-  switch is auditable today;
+  reason/role/zone) on the timeline — the autoscaler's switch is
+  auditable;
 - a request that prefills on replica A and decodes on replica B
   reconstructs from the event timeline ALONE as one ordered journey and
   one flow-linked Perfetto track (the PR-11 acceptance idiom, now
@@ -137,7 +137,7 @@ def test_shed_decision_carries_reason_and_fallthrough(event_log):
 
 
 def test_replica_up_down_events_on_timeline(event_log):
-    """The ROADMAP 2(a) switch: ``set_alive`` flips emit
+    """The autoscaler's switch: ``set_alive`` flips emit
     ``replica_up``/``replica_down`` with reason/role/zone/n_alive (no-op
     on an already-matching bit), evacuation lands its ``replica_down``
     with the evacuation reason, and routing honours the dead set on the
@@ -340,8 +340,7 @@ def test_trace_replay_small_run_is_valid_and_attributable(tmp_path,
     through the real Router on stub engines completes in-process,
     produces a schema-valid FLEETREPORT with a non-``unknown`` verdict,
     reconciles the decision ledger exactly, and the CLI emits the
-    bench_trend-consumable JSON line + writes report/ledger/trace
-    artifacts."""
+    metric JSON line + writes report/ledger/trace artifacts."""
     from torchdistpackage_tpu.tools.trace_replay import main
 
     report = tmp_path / "FLEETREPORT.json"
@@ -445,7 +444,7 @@ def test_trace_replay_autoscale_chaos_twin(tmp_path, capsys):
              if ln.startswith("{")]
     (rec,) = [r for r in lines if r.get("metric") == "trace-replay"]
     (ab,) = [r for r in lines if r.get("metric") == "trace-replay-ab"]
-    # the bench_trend AUX columns ride the metric line
+    # the fleet counters ride the metric line
     assert {"autoscale_actions", "migration_retry_count",
             "transport_fallback_count"} <= set(rec)
     assert rec["report_valid"] and rec["attribution_complete"]

@@ -240,8 +240,7 @@ def test_int8_decode_golden_and_dequant_inside_scan():
     these seeds). (b) Structural proof: the int8->float upcast happens
     INSIDE the decode lax.scan body — the [L, ...] stacked weights enter
     the scan as int8 xs and dequantize per layer slice, so HBM holds int8
-    weights, which is the entire point (decode is weight-bandwidth-bound,
-    docs/ROADMAP.md)."""
+    weights, which is the entire point (decode is weight-bandwidth-bound)."""
     from torchdistpackage_tpu.models.generate import forward_cached, init_kv_cache
     from torchdistpackage_tpu.tools.surgery import (
         QuantizedLinear,
@@ -357,8 +356,8 @@ def test_moe_ep_sharded_decode_matches_serial(devices8):
 
 def test_int8_kv_cache_decode():
     """int8 KV-cache quantization (the decode-bandwidth lever AFTER
-    weight-only int8 — docs/BENCH_AB.md 6b: at long ctx the cache bytes,
-    not the weights, bound decode).  (a) quality: per-vector-scaled int8
+    weight-only int8: at long ctx the cache bytes, not the weights, bound
+    decode).  (a) quality: per-vector-scaled int8
     KV keeps greedy decode token-identical to the dense cache on both
     families at these seeds, and the prefill-position logits stay close.
     (b) structure: the decode scan CARRIES int8 cache leaves (jaxpr), so

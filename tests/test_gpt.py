@@ -905,8 +905,8 @@ def test_gpt_remat_flash_policy_matches_and_saves_residuals():
 
 
 def test_offload_guardrail():
-    """remat='flash_offload' where plain 'flash' fits is a measured ~2.4x
-    loss (docs/BENCH_AB.md) — the trace-time advisory must fire there, stay
+    """remat='flash_offload' where plain 'flash' fits pays a host round
+    trip for nothing — the trace-time advisory must fire there, stay
     quiet when the footprint is genuinely HBM-scale, and stay quiet on
     backends that report no memory limit (the CPU sim)."""
     import warnings

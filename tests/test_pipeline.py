@@ -234,7 +234,7 @@ def serial_1f1b_ref():
     """Module-scope cache of the serial (loss, grads) reference per
     microbatch count M — the (2, 4) and (4, 4) schedule combos share one
     compiled serial program instead of re-deriving it per test (the PR-5
-    shared-bundle pattern; tier-1 budget, ROADMAP item 1)."""
+    shared-bundle pattern; tier-1 budget)."""
     cache = {}
 
     def get(m):

@@ -23,7 +23,7 @@ capture's events with the wall clock and a ring record can be put beside
 them only through pairs of both clocks.
 
 ``XLA_FLAGS`` writes are banned everywhere but ``dist/overlap.py`` (the
-whole repo: package, examples, tests, bench.py, __graft_entry__.py).  The
+whole repo: package, examples, tests, chip_smoke.py, __graft_entry__.py).  The
 variable is parsed once at backend init and an unknown flag is a FATAL
 abort, so scattered ad-hoc writes are both a too-late trap and a crash
 trap; overlap.py owns the merge/validate/apply logic (presets, user-flag
@@ -34,7 +34,14 @@ COPIED env dict for a child process is fine — the rule matches
 """
 
 import ast
+import functools
+import io
+import json
 import pathlib
+import re
+import tokenize
+
+import pytest
 
 PKG = pathlib.Path(__file__).resolve().parent.parent / "torchdistpackage_tpu"
 REPO = PKG.parent
@@ -45,14 +52,11 @@ ALLOWLIST = {
     # torchdistpackage_tpu/__init__.py), so master_print (which needs
     # jax.process_index) is unavailable; it is single-process by nature.
     "tools/slurm_job_monitor.py",
-    # bench-round trend gate: same deal — a jax-free login-node/CI CLI
-    # over BENCH_r0*.json artifacts.
-    "tools/bench_trend.py",
     # A/B run-parity diff CLI (PR 7): jax-free gate over RUNREPORT/JSONL
-    # artifacts on disk, same login-node deal as bench_trend.
+    # artifacts on disk, same login-node deal as the job monitor.
     "tools/parity_diff.py",
     # auto-sharding planner CLI (PR 13): jax-free capacity-planning tool
-    # over a JSON model config, same login-node deal as bench_trend.
+    # over a JSON model config, same login-node deal as the job monitor.
     "tools/autoplan.py",
 }
 
@@ -185,7 +189,7 @@ def _repo_python_files():
     yield from sorted(PKG.rglob("*.py"))
     yield from sorted((REPO / "examples").glob("*.py"))
     yield from sorted((REPO / "tests").glob("*.py"))
-    for name in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
+    for name in ("__graft_entry__.py", "chip_smoke.py"):
         p = REPO / name
         if p.exists():
             yield p
@@ -489,7 +493,7 @@ def test_router_event_kinds_registered_and_emitted():
     ``request_migrated``/``blocks_migrated`` are the rebalance/handoff
     trail the migration accounting reads, and ``replica_degraded`` is
     the router's degradation watch; a kind that stopped being emitted
-    would silently blind the fleet section and the bench_trend columns."""
+    would silently blind the fleet section."""
     from torchdistpackage_tpu.obs.events import EVENT_KINDS
 
     router_kinds = {
@@ -581,9 +585,8 @@ def test_fastpath_event_kinds_registered_and_emitted():
     """The serving fast-path kinds (PR 10) are in the registry AND each
     is actually emitted from ``serving/`` — the prefix-cache hit/COW/
     eviction trail and the speculative draft/verify pair are the
-    evidence the hit-rate and accept-rate summary fields (and the
-    bench_trend AUX columns) are built on; a kind that stopped being
-    emitted would silently zero them."""
+    evidence the hit-rate and accept-rate summary fields are built on; a
+    kind that stopped being emitted would silently zero them."""
     from torchdistpackage_tpu.obs.events import EVENT_KINDS
 
     fast_kinds = {
@@ -758,3 +761,141 @@ def test_compile_cache_has_one_owner():
         and any(n in p.read_text() for n in names)
     }
     assert owners == {"torchdistpackage_tpu/dist/overlap.py"}, owners
+
+
+# ------------------------------------ the documents name files that exist
+
+#: README, every docs/*.md and the verify skill: what a new owner reads.
+_DOCUMENTS = sorted(
+    str(p.relative_to(REPO))
+    for p in [REPO / "README.md", *(REPO / "docs").glob("*.md"),
+              REPO / ".claude" / "skills" / "verify" / "SKILL.md"]
+    if p.exists())
+
+#: a token that names a source, document or record file
+_FILE_TOKEN = re.compile(r"[\w./\[\]*-]*[\w\]*]\.(?:py|md|json)\b")
+#: bare names a RUN writes (reports, traces, a tool's arguments), not files
+#: of the repo
+_RUN_OUTPUTS = re.compile(
+    r"^(\w|(RUNREPORT|FLEETREPORT|NORTHSTAR|TESTS_LAST_RUN|trace|out|model|"
+    r"cfg|plan|manifest|\.last_call)[\w.-]*)\.(md|json)$")
+_REPO_DIRS = ("docs", "tools", "torchdistpackage_tpu", "benchmarks",
+              "tests", "examples")
+
+
+@functools.lru_cache(maxsize=None)
+def _known_files():
+    """(tracked files' names, their repo-relative paths, the names of the
+    reference project's files: SURVEY.md lists those, and the migration
+    tables map them onto ours)."""
+    tracked = _tracked_files()
+    reference = {t.rpartition("/")[2] for t in _FILE_TOKEN.findall(
+        (REPO / "SURVEY.md").read_text())}
+    return ({p.name for p in tracked},
+            {str(p.relative_to(REPO)) for p in tracked}, reference)
+
+
+def _missing_files(text: str, py_names: bool = True):
+    """Tokens of ``text`` that name a repo file which is not tracked: a
+    token under one of ``_REPO_DIRS`` (``tools/`` is the package's), or a
+    bare ``*.py`` / ``*.md`` / ``*.json`` name that is neither a tracked
+    file's name nor a file of the reference project."""
+    names, rels, reference = _known_files()
+    missing = []
+    for tok in sorted(set(_FILE_TOKEN.findall(text))):
+        tok = tok.lstrip("./")
+        if "*" in tok or "[" in tok:
+            continue  # a glob
+        if tok.endswith(".py") and not py_names:
+            continue
+        if "/" not in tok:
+            ok = (tok in names or tok in reference
+                  or bool(_RUN_OUTPUTS.match(tok)))
+        elif tok.split("/")[0] in _REPO_DIRS:
+            ok = tok in rels or f"torchdistpackage_tpu/{tok}" in rels
+        else:
+            continue  # an absolute path, a URL, a path inside the package
+        if not ok:
+            missing.append(tok)
+    return missing
+
+
+@pytest.mark.parametrize("doc", _DOCUMENTS)
+def test_documents_name_files_that_exist(doc):
+    """A document that sends its reader to a file sends them to one the
+    repo holds: sixteen files cited a deleted docs page for two months."""
+    missing = _missing_files((REPO / doc).read_text())
+    assert not missing, f"{doc} names files the repo does not hold: {missing}"
+
+
+def test_source_comments_name_documents_that_exist():
+    """The same over every tracked ``.py`` file's comments and docstrings,
+    for ``*.md`` / ``*.json`` names (a ``.py`` name there is as often a
+    module of another project)."""
+    hits = {}
+    for path in _tracked_files():
+        if path.suffix != ".py":
+            continue
+        source = path.read_text()
+        prose = [t.string for t in tokenize.generate_tokens(
+            io.StringIO(source).readline) if t.type == tokenize.COMMENT]
+        prose += [ast.get_docstring(n, clean=False) or ""
+                  for n in ast.walk(ast.parse(source))
+                  if isinstance(n, (ast.Module, ast.ClassDef,
+                                    ast.FunctionDef, ast.AsyncFunctionDef))]
+        missing = _missing_files("\n".join(prose), py_names=False)
+        if missing:
+            hits[str(path.relative_to(REPO))] = missing
+    assert not hits, f"comments name documents the repo does not hold: {hits}"
+
+
+# --------------------------------------- PERF.md: every cell, and readable
+
+_CELLS = [w["name"] for w in json.loads(
+    (REPO / "BENCHMARK.json").read_text())["workloads"]]
+
+
+def _perf_sections():
+    """PERF.md split at its ``## `` headings: {heading number: text}."""
+    parts = re.split(r"(?m)^## ", (REPO / "PERF.md").read_text())
+    return {p.split(".", 1)[0].strip(): p for p in parts[1:]}
+
+
+@pytest.mark.parametrize("cell", _CELLS)
+def test_perf_md_has_every_cell(cell):
+    """Each cell of the benchmark has a table row in §4 (what it is) and
+    in §5 (where its time goes): a cell added without them is unreadable
+    to the session that has to speed it up."""
+    sections = _perf_sections()
+    for number in ("4", "5"):
+        rows = [ln for ln in sections[number].splitlines()
+                if ln.startswith("|") and f"`{cell}`" in ln.split("|")[1]]
+        assert rows, f"PERF.md §{number} has no row for {cell}"
+
+
+def test_perf_md_fits_a_reader():
+    """Every session reads all of PERF.md before it writes a line: under
+    100 KB, no section over 20 KB, no line over 2,500 characters (the
+    ledger and ``git log -p PERF.md`` keep what is cut)."""
+    text = (REPO / "PERF.md").read_text()
+    assert len(text.encode()) < 100_000, len(text.encode())
+    big = {k: len(v.encode()) for k, v in _perf_sections().items()
+           if len(v.encode()) > 20_000}
+    assert not big, f"sections over 20 KB: {big}"
+    long = [i for i, ln in enumerate(text.splitlines(), 1) if len(ln) > 2500]
+    assert not long, f"lines over 2,500 characters: {long}"
+
+
+_PEAKS = {k: v for k, v in json.loads(
+    (REPO / "benchmarks" / "peaks.json").read_text()).items()
+    if isinstance(v, dict)}
+
+
+@pytest.mark.parametrize("device_kind", sorted(_PEAKS))
+def test_peak_table_matches_benchmark(device_kind):
+    """``obs.telemetry.PEAK_BF16_FLOPS`` is the package's own table (it may
+    not import from ``benchmarks/``); for every device kind the benchmark
+    knows it says what ``benchmarks/peaks.json`` says."""
+    from torchdistpackage_tpu.obs.telemetry import peak_flops_for
+
+    assert peak_flops_for(device_kind) == _PEAKS[device_kind]["bf16_flops"]

@@ -42,7 +42,7 @@ def serial_golden():
     """The serial reference, computed ONCE for the whole file as a single
     ``value_and_grad(has_aux=True)`` program: forward output, loss, and
     grads all come out of ONE compile (tier-1 budget: fwd+grad pairs fold
-    into one program, ROADMAP item 1)."""
+    into one program)."""
     params = init_transformer_params(jax.random.PRNGKey(0), CFG)
     x = jax.random.normal(jax.random.PRNGKey(1), (B, S, CFG.dim))
 

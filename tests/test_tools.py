@@ -1,7 +1,6 @@
 """Tests for the tools layer: profiler, NaN hunting, surgery/int8, SLURM
-monitor (subprocess-mocked), and the bench-round trend gate."""
+monitor (subprocess-mocked)."""
 
-import pathlib
 import subprocess
 from unittest import mock
 
@@ -189,278 +188,6 @@ def test_check_tensors_emit_lands_on_timeline():
         assert len(log.of_kind("nan_watchdog")) == 1
     finally:
         set_default_event_log(None)
-
-
-# -------------------------------------------------------- bench trend gate
-
-
-def test_bench_trend_regression_detection_and_numerics_columns(tmp_path):
-    """The gate actually bites (a forged losing round exits nonzero) and
-    the PR-7 ``grad_norm_final`` numerics column renders next to the
-    throughput it certifies."""
-    import json as _json
-
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, main, trend
-
-    assert "grad_norm_final" in AUX_KEYS
-    line = {"metric": "m", "value": 100.0, "unit": "tok/s",
-            "grad_norm_final": 0.37, "mfu": 0.4, "config": "c"}
-    rounds = [(1, [line]), (2, [dict(line, value=90.0)])]
-    report, warnings = trend(rounds, threshold=0.05)
-    assert any("REGRESSION" in w for w in warnings)
-    assert any("grad_norm_final=0.37" in ln for ln in report)
-    for n, lines in rounds:
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
-            _json.dumps({"n": n, "tail": "\n".join(
-                _json.dumps(l) for l in lines)}))
-    assert main(["--dir", str(tmp_path)]) == 1
-
-
-def test_bench_trend_overload_columns():
-    """The PR-9 stress columns: a ``serve-overload`` line's goodput gates
-    (``value``) with ``shed_rate``/``preempt_count`` rendered alongside —
-    a goodput hold bought by shedding more is visible, not hidden."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert {"shed_rate", "preempt_count"} <= set(AUX_KEYS)
-    line = {"metric": "serve-overload", "value": 850.0,
-            "shed_rate": 0.21, "preempt_count": 3, "config": "c"}
-    report, warnings = trend(
-        [(1, [line]), (2, [dict(line, value=700.0, shed_rate=0.4)])],
-        threshold=0.05)
-    assert any("shed_rate=0.21" in ln for ln in report)
-    assert any("preempt_count=3" in ln for ln in report)
-    assert any("REGRESSION serve-overload" in w for w in warnings)
-
-
-def test_bench_trend_fastpath_columns():
-    """The PR-10 fast-path columns: ``serve-prefix-*`` / ``serve-spec-*``
-    lines gate on tokens/s (``value``) with ``prefix_hit_rate`` /
-    ``spec_accept_rate`` rendered alongside — a throughput hold with a
-    collapsed hit or accept rate (the win evaporating) is visible in the
-    trend, and a regression still trips the gate."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert {"prefix_hit_rate", "spec_accept_rate"} <= set(AUX_KEYS)
-    warm = {"metric": "serve-prefix-warm", "value": 1850.0,
-            "prefix_hit_rate": 0.95, "config": "c"}
-    spec = {"metric": "serve-spec-on", "value": 1000.0,
-            "spec_accept_rate": 0.27, "config": "c"}
-    report, warnings = trend(
-        [(1, [warm, spec]),
-         (2, [dict(warm, value=1200.0, prefix_hit_rate=0.1),
-              dict(spec, value=990.0, spec_accept_rate=0.25)])],
-        threshold=0.05)
-    assert any("prefix_hit_rate=0.95" in ln for ln in report)
-    assert any("spec_accept_rate=0.27" in ln for ln in report)
-    assert any("REGRESSION serve-prefix-warm" in w for w in warnings)
-    assert not any("serve-spec-on" in w for w in warnings)  # -1% holds
-
-
-def test_bench_trend_slo_columns():
-    """The PR-11 SLO columns: the ``serve-overload`` line's raw tokens/s
-    still gates (``value``), and ``goodput_tok_s`` / ``slo_attainment``
-    render alongside — a throughput hold bought by missing every
-    deadline (goodput collapsing under a steady headline) is visible in
-    the trend, and a goodput-line regression still trips the gate when
-    trended as its own series."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert {"slo_attainment", "goodput_tok_s"} <= set(AUX_KEYS)
-    line = {"metric": "serve-overload", "value": 850.0,
-            "shed_rate": 0.2, "preempt_count": 3,
-            "goodput_tok_s": 800.0, "slo_attainment": 0.92, "config": "c"}
-    report, warnings = trend(
-        [(1, [line]),
-         (2, [dict(line, goodput_tok_s=120.0, slo_attainment=0.15)])],
-        threshold=0.05)
-    assert any("goodput_tok_s=800.0" in ln for ln in report)
-    assert any("slo_attainment=0.92" in ln for ln in report)
-    assert any("slo_attainment=0.15" in ln for ln in report)
-    # headline held -> no gate trip; the collapse is VISIBLE in the aux
-    assert not warnings
-
-
-def test_bench_trend_router_columns():
-    """The PR-15 fleet columns: the ``serve-router-fleet`` line gates on
-    fleet tokens/s (``value``) with ``fleet_goodput_tok_s`` /
-    ``affinity_hit_rate`` / ``migration_bytes`` rendered alongside — a
-    throughput hold with a collapsed affinity hit rate (warm traffic no
-    longer landing on its KV) or ballooning migration bytes (handoffs
-    shipping whole contexts instead of tails) is visible in the trend,
-    and a fleet-line regression still trips the gate."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert {"fleet_goodput_tok_s", "affinity_hit_rate",
-            "migration_bytes"} <= set(AUX_KEYS)
-    line = {"metric": "serve-router-fleet", "value": 900.0,
-            "fleet_goodput_tok_s": 900.0, "affinity_hit_rate": 0.88,
-            "migration_bytes": 147456, "config": "c"}
-    report, warnings = trend(
-        [(1, [line]),
-         (2, [dict(line, value=500.0, affinity_hit_rate=0.05,
-                   migration_bytes=1200000)])],
-        threshold=0.05)
-    assert any("affinity_hit_rate=0.88" in ln for ln in report)
-    assert any("fleet_goodput_tok_s=900.0" in ln for ln in report)
-    assert any("migration_bytes=147456" in ln for ln in report)
-    assert any("affinity_hit_rate=0.05" in ln for ln in report)
-    assert any("REGRESSION serve-router-fleet" in w for w in warnings)
-
-
-def test_bench_trend_fleet_slo_columns():
-    """The PR-17 fleet-observability columns: ``fleet_slo_attainment``
-    and ``migration_count`` ride the ``serve-router-fleet`` line (and
-    the ``trace-replay`` line) — a fleet tokens/s hold with collapsing
-    SLO attainment means throughput is being bought from deadline
-    misses, and a migration-count explosion means the disaggregation
-    tier started thrashing; both are visible in the trend and a
-    headline regression still trips the gate."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert {"fleet_slo_attainment", "migration_count"} <= set(AUX_KEYS)
-    line = {"metric": "serve-router-fleet", "value": 900.0,
-            "fleet_goodput_tok_s": 900.0, "fleet_slo_attainment": 0.97,
-            "migration_count": 12, "config": "c"}
-    report, warnings = trend(
-        [(1, [line]),
-         (2, [dict(line, value=500.0, fleet_slo_attainment=0.4,
-                   migration_count=480)])],
-        threshold=0.05)
-    assert any("fleet_slo_attainment=0.97" in ln for ln in report)
-    assert any("migration_count=12" in ln for ln in report)
-    assert any("fleet_slo_attainment=0.4" in ln for ln in report)
-    assert any("migration_count=480" in ln for ln in report)
-
-
-def test_bench_trend_moe_columns():
-    """The PR-18 MoE dispatch columns: ``moe_pallas_tok_s`` and
-    ``expert_imbalance`` ride the ``serve-moe-ab`` line — a speedup
-    hold earned while the imbalance column climbs means the router is
-    feeding the fused kernel ever-more-skewed batches (capacity drops
-    coming), and a headline regression still trips the gate."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert {"moe_pallas_tok_s", "expert_imbalance"} <= set(AUX_KEYS)
-    line = {"metric": "serve-moe-ab", "value": 1.2,
-            "moe_pallas_tok_s": 900.0, "expert_imbalance": 0.45,
-            "config": "c"}
-    report, warnings = trend(
-        [(1, [line]),
-         (2, [dict(line, value=0.9, expert_imbalance=1.8)])],
-        threshold=0.05)
-    assert any("moe_pallas_tok_s=900.0" in ln for ln in report)
-    assert any("expert_imbalance=0.45" in ln for ln in report)
-    assert any("expert_imbalance=1.8" in ln for ln in report)
-    assert any("REGRESSION serve-moe-ab" in w for w in warnings)
-
-
-def test_bench_trend_paged_kernel_column():
-    """The PR-12 paged-kernel columns: ``serve-paged-{gather,pallas}``
-    lines gate on tokens/s (``value``) as their own series, and the
-    ``serve-paged-ab`` line renders ``paged_pallas_tok_s`` in the aux
-    trail — a pallas-arm regression trips the gate on its line and stays
-    visible on the A/B roll-up."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert "paged_pallas_tok_s" in AUX_KEYS
-    pallas = {"metric": "serve-paged-pallas", "value": 1850.0,
-              "attn_impl": "pallas", "config": "c"}
-    ab = {"metric": "serve-paged-ab", "value": 1.4,
-          "paged_pallas_tok_s": 1850.0, "config": "c"}
-    report, warnings = trend(
-        [(1, [pallas, ab]),
-         (2, [dict(pallas, value=1200.0),
-              dict(ab, paged_pallas_tok_s=1200.0)])],
-        threshold=0.05)
-    assert any("paged_pallas_tok_s=1850.0" in ln for ln in report)
-    assert any("REGRESSION serve-paged-pallas" in w for w in warnings)
-
-
-def test_bench_trend_autoplan_columns():
-    """The PR-13 planner columns: the ``bench.py --autoplan`` planned
-    arm's line gates on tokens/s (``value``) with ``autoplan_tok_s`` /
-    ``plan_modeled_step_s`` rendered alongside — a throughput hold with a
-    drifting modeled step (the planner steering on stale numbers) is
-    visible in the trend, and a planned-arm regression still trips the
-    gate."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert {"autoplan_tok_s", "plan_modeled_step_s"} <= set(AUX_KEYS)
-    line = {"metric": "gpt-tiny-train-throughput", "value": 530.0,
-            "autoplan": "planned", "plan": "dp8",
-            "autoplan_tok_s": 530.0, "plan_modeled_step_s": 0.0019,
-            "config": "c ap-planned"}
-    report, warnings = trend(
-        [(1, [line]),
-         (2, [dict(line, value=400.0, autoplan_tok_s=400.0)])],
-        threshold=0.05)
-    assert any("autoplan_tok_s=530.0" in ln for ln in report)
-    assert any("plan_modeled_step_s=0.0019" in ln for ln in report)
-    assert any("REGRESSION gpt-tiny-train-throughput" in w for w in warnings)
-
-
-def test_bench_trend_bubble_columns():
-    """The PR-14 pipeline columns (mirrors the ``autoplan_tok_s``
-    pattern): a pp-plan line gates on tokens/s (``value``) with
-    ``bubble_fraction`` / ``plan_pp_schedule`` rendered alongside — a
-    throughput hold whose bubble crept back up, or whose schedule arm
-    silently flipped from ``zb`` back to classic ``1f1b``, is visible in
-    the trend, and a pp-line regression still trips the gate."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert {"bubble_fraction", "plan_pp_schedule"} <= set(AUX_KEYS)
-    line = {"metric": "gpt-tiny-train-throughput", "value": 520.0,
-            "autoplan": "planned", "plan": "dp2·pp4",
-            "bubble_fraction": 0.5, "plan_pp_schedule": "zb",
-            "config": "c ap-planned"}
-    report, warnings = trend(
-        [(1, [line]),
-         (2, [dict(line, value=430.0, bubble_fraction=0.6,
-                   plan_pp_schedule="1f1b")])],
-        threshold=0.05)
-    assert any("bubble_fraction=0.5" in ln for ln in report)
-    assert any("plan_pp_schedule=zb" in ln for ln in report)
-    assert any("plan_pp_schedule=1f1b" in ln for ln in report)
-    assert any("REGRESSION gpt-tiny-train-throughput" in w for w in warnings)
-
-
-def test_bench_trend_long_context_columns():
-    """The PR-20 context-parallel prefill columns: the
-    ``serve-longctx-ab`` line gates on the cp1/cpN TTFT speedup
-    (``value``) with ``cp_prefill_ttft_s`` / ``long_ctx_tok_s`` rendered
-    alongside — a speedup hold earned while the CP arm's absolute TTFT
-    creeps up means both arms regressed together (the ratio hides it),
-    and a headline regression still trips the gate."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert {"cp_prefill_ttft_s", "long_ctx_tok_s"} <= set(AUX_KEYS)
-    line = {"metric": "serve-longctx-ab", "value": 1.6, "cp": 2,
-            "context": 131072, "cp_prefill_ttft_s": 2.1,
-            "long_ctx_tok_s": 240.0, "config": "c"}
-    report, warnings = trend(
-        [(1, [line]),
-         (2, [dict(line, value=1.1, cp_prefill_ttft_s=4.7,
-                   long_ctx_tok_s=110.0)])],
-        threshold=0.05)
-    assert any("cp_prefill_ttft_s=2.1" in ln for ln in report)
-    assert any("long_ctx_tok_s=240.0" in ln for ln in report)
-    assert any("cp_prefill_ttft_s=4.7" in ln for ln in report)
-    assert any("REGRESSION serve-longctx-ab" in w for w in warnings)
-
-
-def test_bench_trend_comm_bytes_column():
-    """The PR-8 wire-bytes column: a line carrying ``comm_bytes_per_dim``
-    renders its TOTAL in the aux trail, so a compressed collective
-    silently re-inflating shows up in the trend."""
-    from torchdistpackage_tpu.tools.bench_trend import AUX_KEYS, trend
-
-    assert "comm_bytes_per_dim" in AUX_KEYS
-    line = {"metric": "m", "value": 100.0, "unit": "tok/s",
-            "comm_bytes_per_dim": {"dp": 1_000_000, "tp": 500_000},
-            "config": "c"}
-    report, _ = trend([(1, [line])], threshold=0.05)
-    assert any("comm_bytes=1,500,000" in ln for ln in report)
 
 
 # ------------------------------------------------------------- surgery/int8

@@ -48,12 +48,12 @@ terms assume zero compute/comm overlap (the same serialized convention as
 the RUNREPORT comm section's ``modeled_comm_s``), the vocab-parallel
 cross-entropy reductions and optimizer-update traffic are unmodeled, and
 TP compute is assumed to scale perfectly.  The ranking is validated
-against measured CPU-sim steps in ``tests/test_autoplan.py`` and the
-``bench.py --autoplan`` arm; disagreements are disclosed in the section's
-``modeled_vs_measured`` record rather than hidden.
+against measured CPU-sim steps in ``tests/test_autoplan.py``;
+disagreements are disclosed in the section's ``modeled_vs_measured``
+record rather than hidden.
 
 Module scope is deliberately jax-free (``tools/autoplan.py`` is a
-login-node CLI over a JSON model config, like ``bench_trend``): jax is
+login-node CLI over a JSON model config): jax is
 imported lazily and only by the executable-side helpers and the
 ``memory='model'`` estimator.
 """
@@ -300,10 +300,10 @@ def param_table(d: ModelDims) -> List[LeafRow]:
 
 
 def flops_per_token(d: ModelDims) -> float:
-    """The bench.py 6N+12LSD accounting: 6 FLOPs per matmul param per
+    """The 6N+12LSD accounting: 6 FLOPs per matmul param per
     token (embedding tables excluded — gathers, not matmuls) plus the
-    attention score/value matmuls.  ``bench.py --autoplan`` replaces this
-    with the compiled step's own ``cost_analysis`` count when it has one.
+    attention score/value matmuls.  A caller with a compiled step passes
+    its ``cost_analysis`` count as ``fpt`` to :func:`plan` in its place.
     Expert leaves count at their capacity-inflated ``flop_weight`` — a
     token runs ``top_k`` of ``E`` experts, padded to capacity — so a MoE
     stack prices its *activated* FLOPs, not the full parameter count."""
@@ -692,8 +692,7 @@ def score_candidate(
     dgrad/wgrad recompute honestly) — and the row records the cheaper one
     as ``pp_schedule`` plus its slot-accounting ``bubble_fraction``
     (``obs.aggregate.pipeline_bubble_fraction``), so the planner's
-    schedule choice is auditable against the measured pair
-    ``bench.py --autoplan`` attaches."""
+    schedule choice is auditable against a measured pair."""
     from ..obs.aggregate import (
         pipeline_bubble_fraction,
         pipeline_time_inflation,
@@ -765,8 +764,8 @@ def plan(
       per-generation table model for ``device_kind`` (no kind named =
       the table's ``cpu`` placeholder row; an unlisted kind raises).
     - ``effective_flops``: sustained per-device FLOP/s.  Feed the value a
-      measured step implies (``bench.py --autoplan`` does: HLO FLOPs /
-      measured step time) to close the loop; default = 40% of the chip's
+      measured step implies (HLO FLOPs / measured step time) to close
+      the loop; default = 40% of the chip's
       table peak when recognized, else :data:`ASSUMED_FLOPS`.
     - ``fpt``: FLOPs/token for the compute term — pass the compiled
       step's ``cost_analysis`` count when one exists; default = the
@@ -895,7 +894,7 @@ def plan_prefill_tier(
     prefills ``chunk/cp`` rows) are skipped as non-executable.
 
     The hop and compute terms are summed SERIALLY — the honest model
-    until the on-chip overlap round lands (ROADMAP 5c); the returned
+    until the ring's overlap is measured on the chip; the returned
     ``basis`` says so.  ``emit`` lands ``plan_rejected_oom`` /
     ``plan_selected`` events like :func:`autoplan`."""
     from ..obs.mem_ledger import headroom_verdict
@@ -1022,7 +1021,7 @@ def plan_prefill_tier(
             "compute": compute_basis,
             "flops_per_token_fwd": fpt,
             "effective_flops": eff,
-            "overlap": "serial (compute + ring summed; ROADMAP 5c)",
+            "overlap": "serial (compute + ring summed)",
         },
     }
 

@@ -285,8 +285,7 @@ def configure(
     Call BEFORE the first device touch (``jax.devices()``, mesh building,
     ``setup_distributed``).  If backends are already initialized, a
     warning is issued and nothing is written unless ``force=True`` — the
-    flags then only affect child processes (bench.py's per-candidate
-    children use exactly that).
+    flags then only affect child processes.
 
     ``validate`` probes the merged flags in a subprocess first and drops
     the ones this jaxlib's parser rejects (which would otherwise abort

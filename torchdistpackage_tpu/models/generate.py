@@ -67,8 +67,7 @@ def init_kv_cache(
     ``(q8, scale)`` pair (scale [L, B, Hkv, max_len] f32, one symmetric
     scale per written position-vector, computed at append time).  Decode
     reads the cache once per token, so at long context the KV bytes — not
-    the weights — bound throughput (docs/BENCH_AB.md 6b); int8 halves
-    them vs bf16.  Dequant happens in-register inside the attention
+    the weights — bound throughput; int8 halves them vs bf16.  Dequant happens in-register inside the attention
     einsums (:func:`_cached_attention` folds the k-scale into the score
     and the v-scale into the probabilities).  The pair is a pytree, so
     the decode scan slices/stacks it like any dense cache leaf."""
@@ -537,12 +536,12 @@ def speculative_generate(
     model's greedy argmax on its certified prefix, whatever the draft
     proposes — a random draft only makes it slow, never wrong (the test
     asserts bit-equality with :func:`generate` for good, quantized AND
-    adversarial drafts).  Decode is weight-bandwidth-bound
-    (docs/BENCH_AB.md 6b), and a (K+1)-row verify forward reads the
-    weights ONCE — so accepted drafts amortize the target's HBM traffic
-    over up to K+1 tokens.  The natural self-speculative pairing is
-    ``draft_params = tools.surgery.quantize_decode_params(params)``:
-    the int8 draft is ~1.7x faster per token and near-always agrees.
+    adversarial drafts).  Decode is weight-bandwidth-bound, and a
+    (K+1)-row verify forward reads the weights ONCE — so accepted drafts
+    amortize the target's HBM traffic over up to K+1 tokens.  The natural
+    self-speculative pairing is ``draft_params =
+    tools.surgery.quantize_decode_params(params)``: the int8 draft reads
+    half the weight bytes per token and near-always agrees.
 
     Static-shape design: both KV caches are fixed buffers; stale entries
     past the certified position are never attended (the position mask

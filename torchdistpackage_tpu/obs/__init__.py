@@ -2,17 +2,16 @@
 
 The framework could train and serve but not *report on itself*: throughput,
 MFU, memory peaks, pipeline bubble fraction, and MoE load balance were
-computed ad hoc (or not at all) in ``bench.py``, ``utils/metrics.py`` and
-``tools/decode_bench.py`` with no shared schema, no cross-host view, and no
-event timeline (VERDICT round 5).  This subpackage is the one shared
-telemetry layer every train loop, example, and bench emits through:
+computed ad hoc (or not at all) with no shared schema, no cross-host view,
+and no event timeline.  This subpackage is the one shared telemetry layer
+every train loop and example emits through:
 
 - :mod:`.telemetry` — :class:`Telemetry`, a run-session object that wraps a
   jitted train/decode step, records per-step spans (data / dispatch /
   device / fetch), detects recompiles, polls ``device.memory_stats()``, and
   computes MFU + bytes-moved from XLA ``cost_analysis`` of the *compiled*
-  step (compiler ground truth — cross-checked against the 6N+12LSD hand
-  formula in ``bench.py``).
+  step (compiler ground truth, kept beside the caller's 6N+12LSD hand
+  formula).
 - :mod:`.events` — append-only structured event log (compile, checkpoint
   save/restore, preemption, NaN-watchdog trip, loss-scale change,
   straggler alert) with monotonic timestamps and process index.

@@ -576,7 +576,7 @@ def ledger_from_compiled(compiled, mesh=None) -> Optional[Dict[str, Any]]:
 
 
 def render_table(ledger: Optional[Dict[str, Any]]) -> str:
-    """Human summary table (bench.py prints this next to MFU)."""
+    """Human summary table of one ledger."""
     if not ledger or not ledger.get("n_collectives"):
         return "comm ledger: no collectives in the compiled step (single-device program?)"
     L = ["comm ledger (per compiled step):",

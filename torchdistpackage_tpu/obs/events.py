@@ -24,7 +24,6 @@ package only.)
 ``nan_watchdog``    a ``nan_guard``-ed function produced non-finite output
 ``loss_scale``      dynamic loss-scale change
 ``straggler``       a host's step time is an outlier (obs.aggregate)
-``decode_cell``     one decode-bench latency cell (tools.decode_bench)
 ``overlap_configure``  XLA latency-hiding flag outcome (dist.overlap)
 ``xla_trace_start/stop``  scoped jax.profiler capture window (obs.trace)
 ==================  =====================================================
@@ -222,8 +221,8 @@ the outcome:
                             per-replica queue depths it saw, the spread,
                             and how many requests it stole and landed
 ``replica_up``              a replica entered rotation (``set_alive``;
-                            record carries the reason — the autoscaler
-                            seam of ROADMAP 2(a))
+                            record carries the reason — the autoscaler's
+                            seam)
 ``replica_down``            a replica left rotation: ``set_alive`` or an
                             evacuation (reason ``manual`` /
                             ``faults_detected`` / policy-specific)
@@ -288,7 +287,7 @@ EVENT_KINDS: FrozenSet[str] = frozenset({
     # numerics + hosts
     "nan_watchdog", "loss_scale", "straggler",
     # tools / comm
-    "decode_cell", "overlap_configure", "xla_trace_start", "xla_trace_stop",
+    "overlap_configure", "xla_trace_start", "xla_trace_stop",
     # resilience (PR 4)
     "fault_injected", "ckpt_retry", "ckpt_quarantine", "rollback",
     "resilience_abort", "hang_suspected", "hang_resolved", "hang_abort",

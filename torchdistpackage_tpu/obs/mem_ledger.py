@@ -336,44 +336,6 @@ def mem_report(
     return section
 
 
-# ------------------------------------------------------------- human table
-
-
-def render_table(ledger: Optional[Dict[str, Any]]) -> str:
-    """Human summary of one static ledger (bench.py prints this next to
-    the comm table)."""
-    if not ledger:
-        return "mem ledger: backend reports no memory analysis"
-    L = ["mem ledger (per compiled program, per device):"]
-    for key in ("argument_bytes", "output_bytes", "temp_bytes",
-                "generated_code_bytes", "alias_bytes",
-                "peak_estimate_bytes"):
-        tag = ("donation savings" if key == "alias_bytes"
-               else key.replace("_bytes", "").replace("_", " "))
-        L.append(f"  {tag:>18}: {_fmt_bytes(ledger[key]):>10}")
-    if ledger.get("n_leaves"):
-        L.append(
-            f"  {'arguments':>18}: {ledger['n_leaves']} leaves "
-            f"({ledger['sharded_leaves']} sharded, "
-            f"{ledger['replicated_leaves']} replicated)")
-        rows = sorted(ledger["per_leaf"],
-                      key=lambda r: -r["resident_bytes"])[:8]
-        for r in rows:
-            L.append(
-                f"    {_fmt_bytes(r['resident_bytes']):>10} "
-                f"{'rep' if r['replicated'] else '1/' + str(r['shard_count']):>5}"
-                f"  {r['path']}")
-    return "\n".join(L)
-
-
-def _fmt_bytes(n: float) -> str:
-    for unit in ("B", "KiB", "MiB", "GiB"):
-        if abs(n) < 1024 or unit == "GiB":
-            return f"{n:.1f}{unit}" if unit != "B" else f"{int(n)}B"
-        n /= 1024
-    return f"{n:.1f}GiB"
-
-
 # ------------------------------------------------------------ planner model
 
 

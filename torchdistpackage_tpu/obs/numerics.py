@@ -387,31 +387,6 @@ def dtype_ledger_from_compiled(
     return dtype_ledger_from_hlo(text, label=label)
 
 
-def render_dtype_table(ledger: Optional[Dict[str, Any]]) -> str:
-    """Human summary (bench.py prints this next to the comm/mem tables)."""
-    if not ledger or not ledger.get("per_dtype"):
-        return "dtype ledger: no typed instructions parsed"
-    L = ["dtype ledger (per compiled step):",
-         f"{'dtype':>8} {'ops':>6} {'bytes':>12} {'matmul flops':>14}"]
-    for dt, b in ledger["per_dtype"].items():
-        L.append(
-            f"{dt:>8} {b['ops']:>6} {_fmt_bytes(b['bytes']):>12} "
-            + (f"{b['flops']:.3e}" if b["flops"] else "-").rjust(14))
-    fr = ledger.get("flop_frac")
-    if fr:
-        L.append("  matmul flop mix: " + ", ".join(
-            f"{dt} {f:.1%}" for dt, f in fr.items()))
-    return "\n".join(L)
-
-
-def _fmt_bytes(n: float) -> str:
-    for unit in ("B", "KiB", "MiB", "GiB"):
-        if abs(n) < 1024 or unit == "GiB":
-            return f"{n:.1f}{unit}" if unit != "B" else f"{int(n)}B"
-        n /= 1024
-    return f"{n:.1f}GiB"
-
-
 # ---------------------------------------------------------- report section
 
 

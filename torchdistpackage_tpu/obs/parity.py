@@ -23,7 +23,7 @@ module is the reusable harness:
 ``tools/parity_diff.py`` is the CLI over the same functions: point it at
 two RUNREPORT.json / records.jsonl files and it renders the drift table,
 the per-dtype ledger shift between the arms, and the verdict (nonzero
-exit on ``diverged`` — a CI gate, like ``tools/bench_trend``).
+exit on ``diverged`` — a CI gate).
 
 Deliberately jax-free except :func:`param_divergence` (lazy import), so
 the CLI runs on login nodes without touching a backend.

@@ -31,10 +31,10 @@ dimension, a dtype flip) — and emits a ``recompile`` event plus a
 MFU ground truth: the first compilation of each signature goes through
 AOT ``lower().compile()``, so XLA's own ``cost_analysis`` of the compiled
 step (FLOPs, bytes accessed) is captured as a side effect — no second
-compile, no hand-counting.  ``bench.py`` cross-checks this number against
-its 6N+12LSD hand formula; disagreement is printed, not hidden (remat
-recompute and non-matmul ops are IN the XLA count and NOT in the model-
-FLOPs count, so the two bracket the truth from opposite sides).
+compile, no hand-counting.  The report shows this number beside the
+caller's 6N+12LSD hand formula (``flops_per_token``): remat recompute and
+non-matmul ops are IN the XLA count and NOT in the model-FLOPs count, so
+the two bracket the truth from opposite sides.
 
 Memory: ``mem_ledger.live_memory()`` (the repo's one ``memory_stats()``
 reader) is polled each step (guarded — the CPU sim reports nothing) into
@@ -66,7 +66,8 @@ from . import report as _report
 from .events import EventLog, set_default_event_log
 
 # Peak dense bf16 FLOP/s per chip by device_kind substring (public specs).
-# The one lookup table for the whole repo — bench.py imports it from here.
+# The package's one lookup table (it may not import from benchmarks/, a
+# higher layer; tests/test_repo_lint.py holds it to benchmarks/peaks.json).
 # The CPU has no peak worth a utilization (None); a kind with no row is an
 # error, not a silently dropped MFU.
 PEAK_BF16_FLOPS = [
@@ -174,7 +175,7 @@ class Telemetry:
         (JSONL/TensorBoard/Prometheus — :mod:`.exporters`).  Optional: the
         in-memory history + RUNREPORT always work.
     tokens_per_step: enables tokens/sec throughput accounting.
-    flops_per_token: the HAND formula (e.g. bench.py's 6N+12LSD) — kept
+    flops_per_token: the HAND formula (e.g. 6N+12LSD) — kept
         separate from the XLA-measured FLOPs so the report can show both.
     peak_flops: per-chip peak FLOP/s; default looked up from the device
         kind (:func:`peak_flops_for`), None on CPU.

@@ -616,7 +616,7 @@ class DataParallel:
             return jit_for(params, opt_state, batch)(params, opt_state, batch)
 
         # AOT hook: callers that need the compiled executable's artifacts
-        # (Telemetry's ledgers, bench.py's cost analysis) lower through the
+        # (Telemetry's ledgers, a caller's cost analysis) lower through the
         # same cache — `hasattr(step, "lower")` is the Telemetry contract.
         jitted.lower = lambda p, s, b: jit_for(p, s, b).lower(p, s, b)
         return jitted

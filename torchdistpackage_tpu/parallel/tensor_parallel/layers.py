@@ -720,8 +720,7 @@ def dropout(
 #: (no checkpointing), True (full-block), 'flash' (block checkpoint whose
 #: policy saves the flash kernel's named (o, lse) residuals — tagged in
 #: ops/flash_attention._flash_fwd_rule — so the backward skips the Pallas
-#: fwd re-run and recomputes only LN/einsum/MLP; measured +5.3% on the v5e
-#: 125M bench, docs/BENCH_AB.md session 4), and 'flash_offload' ('flash'
+#: fwd re-run and recomputes only LN/einsum/MLP), and 'flash_offload' ('flash'
 #: whose saved o residual lives in ``pinned_host`` memory instead of HBM —
 #: XLA schedules the device->host DMA behind the remaining forward and the
 #: host->device prefetch behind the backward, so the HBM cost of the
@@ -757,10 +756,10 @@ def offload_advice(
     hbm_bytes: Optional[int] = None,
 ) -> Optional[str]:
     """Guard-rail for ``remat='flash_offload'``: the offload trades HBM for
-    a measured ~2.4x step-time loss at S=2048 and only reaches parity with
-    plain ``'flash'`` at S>=8192 (docs/BENCH_AB.md) — so flag configs where
-    the flash-resident footprint comfortably fits HBM and the flag is pure
-    loss.
+    a host round trip of every block's saved o, which short and medium
+    sequences cannot hide behind compute (no benchmark cell sets it: not
+    measured in the ledger) — so flag configs where the flash-resident
+    footprint comfortably fits HBM and the flag is pure loss.
 
     Returns a human-readable warning string, or None when the offload is
     plausibly load-bearing (footprint >= half of HBM, or HBM unknown).
@@ -782,8 +781,9 @@ def offload_advice(
     return (
         f"remat='flash_offload': the 'flash' policy's resident activations "
         f"are ~{total / 1e9:.2f} GB for this config vs ~{hbm_bytes / 1e9:.1f} GB "
-        f"HBM — plain remat='flash' should fit and measures ~2.4x FASTER at "
-        f"short/medium sequence (parity only from S~8192, docs/BENCH_AB.md). "
+        f"HBM — plain remat='flash' should fit and skips the host round "
+        f"trip of every block's saved output, which short and medium "
+        f"sequences cannot hide. "
         f"Use 'flash_offload' only when 'flash' actually OOMs."
     )
 

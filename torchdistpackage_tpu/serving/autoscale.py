@@ -1,6 +1,6 @@
 """Goodput-driven autoscaler — the elastic-fleet control loop.
 
-ROADMAP 2(a): the router already has the actuator (``set_alive`` — the
+The router already has the actuator (``set_alive`` — the
 rotation bit, with ``replica_up``/``replica_down`` ledger events) and
 the sensors (per-replica SLO attainment, goodput, queue depth, and the
 PR-11 TTFT-calibration bias); this module closes the loop.  An

@@ -409,7 +409,7 @@ class ServingEngine:
         ``serving_summary()['attn_impl']``.
     metrics_sink: any obs exporter sink (``write(record)`` — e.g.
         :class:`~..obs.exporters.PrometheusTextfileSink` or ``JsonlSink``);
-        every ``metrics_every``-th tick writes a ``serving_metrics``
+        every tick writes a ``serving_metrics``
         record (:data:`~.tracing.SERVING_METRICS_SCHEMA`) so an external
         scraper can watch queue depth, slot occupancy, batch utilization,
         and the per-phase tick breakdown of a RUNNING engine.
@@ -483,7 +483,6 @@ class ServingEngine:
         spec_k: int = 0,
         attn_impl: str = "auto",
         metrics_sink: Optional[Any] = None,
-        metrics_every: int = 1,
         tick_history: int = 4096,
         device_step: Optional[Any] = None,
         record_routing: bool = False,
@@ -642,10 +641,7 @@ class ServingEngine:
         #: default; interpreter-mode pallas on CPU is correct but slow).
         #: docs/serving.md "Paged attention kernel".
         self.attn_impl = resolve_attn_impl(attn_impl)
-        if metrics_every < 1:
-            raise ValueError(f"metrics_every must be >= 1, got {metrics_every}")
         self.metrics_sink = metrics_sink
-        self.metrics_every = int(metrics_every)
         self.tick_history = int(tick_history)
         self._ev: EventLog = (
             telemetry.events if telemetry is not None else default_event_log())
@@ -2602,7 +2598,7 @@ class ServingEngine:
         no phases), plus the per-tick gauges.  Appended to ``tick_records`` (bounded), emitted
         as an ``engine_tick`` event WHEN THE TICK DID WORK (idle polls
         stay off the timeline), and written to ``metrics_sink`` every
-        ``metrics_every`` ticks under :data:`SERVING_METRICS_SCHEMA`."""
+        tick under :data:`SERVING_METRICS_SCHEMA`."""
         st = self.stats
         t_start = tick.t0
         phases = dict.fromkeys(TICK_PHASES, 0.0)
@@ -2654,8 +2650,7 @@ class ServingEngine:
                 prefill_rids=list(self._tick_prefill_rids),
                 decode_rids=list(self._tick_decode_rids),
                 spans=[[c[2], c[3], c[4]] for c in tick.children], **rec)
-        if (self.metrics_sink is not None
-                and self._tick % self.metrics_every == 0):
+        if self.metrics_sink is not None:
             try:
                 self.metrics_sink.write(serving_metrics_record(rec))
             except OSError:

@@ -90,8 +90,7 @@ the only new compiled program is the per-pair ``migrate_blocks`` copy.
 replica's full ``serving_summary()`` plus the validated fleet roll-up
 (fleet tokens/s + goodput, affinity hit rate, migration count/bytes,
 rebalance/evacuation counts, per-replica verdicts) —
-``obs.report._validate_router`` checks it, ``decode_bench --router``
-measures it against one big engine at equal total slots.
+``obs.report._validate_router`` checks it.
 """
 
 from __future__ import annotations
@@ -761,8 +760,8 @@ class Router:
     def set_alive(self, i: int, alive: bool, reason: str = "manual") -> None:
         """Flip replica ``i``'s rotation bit, emitting ``replica_up`` /
         ``replica_down`` with the reason — the ledger half of the
-        ROADMAP 2(a) autoscaler switch (today flipped by evacuations and
-        by hand; an autoscaler would call exactly this).  Bringing a
+        autoscaler's switch (flipped by evacuations, by hand, and by
+        ``serving/autoscale.py``).  Bringing a
         replica back up re-enters it into routing with whatever engine
         state it still holds; a drained replica comes back EMPTY (its
         requests were rehomed) but keeps its prefix cache, so revived

@@ -15,7 +15,7 @@ against either backend:
   mesh/shard_map path.  Constructed by default; an engine built without
   a ``device_step=`` argument is bit-for-bit the engine before this seam
   existed.
-- :class:`StubDeviceStep` — a host-only double (ROADMAP 5(a)).  No jax
+- :class:`StubDeviceStep` — a host-only double.  No jax
   dispatch, no compilation, no model params (pass ``params=None``): the
   pool is a tiny int8 pytree with the real block layout (dim 1 = blocks,
   ``shape[3] = block_size``, so ``pool_bytes`` / ``block_size_of`` and
@@ -156,11 +156,11 @@ class CompiledDeviceStep(DeviceStep):
 
 class LatencyModel:
     """Predicted seconds per stub dispatch — the 'calibrated' half of
-    ROADMAP 5(a)'s replay stub.  An affine model per program:
-    ``base_s + per_token_s * (rows * width)``, the shape every measured
-    decode_bench curve has at serving batch sizes (dispatch overhead +
-    linear token work).  Fit the coefficients from a real container's
-    ``decode_bench --serve`` medians when absolute numbers matter; the
+    the replay stub.  An affine model per program:
+    ``base_s + per_token_s * (rows * width)``, the shape a decode tick's
+    time has at serving batch sizes (dispatch overhead + linear token
+    work).  Fit the coefficients from a real engine's tick medians (a
+    traced ``benchmarks/run.py`` cell) when absolute numbers matter; the
     defaults are CPU-sim magnitudes, good for RELATIVE policy curves
     (which routing knob moved goodput), not for absolute TTFT claims."""
 

@@ -32,9 +32,8 @@ dicts; no device call, no new compiled program — the engine's
   counter tracks (queue depth,
   slot occupancy, batch utilization, pool utilization, live hit/accept
   rates).  ``obs.trace.chrome_trace_events`` appends all of it
-  automatically when serving events are present, so
-  ``decode_bench --serve --trace out.json`` (and ``TDP_TRACE``) just
-  work.
+  automatically when serving events are present, so ``TDP_TRACE``
+  just works.
 - **Fleet stitching** (:func:`assemble_fleet_request_timelines`,
   :func:`fleet_trace_events`).  A multi-replica timeline — every engine
   tagged ``replica=i`` by the Router, router decisions interleaved —
@@ -55,8 +54,7 @@ dicts; no device call, no new compiled program — the engine's
   (Prometheus-textfile gauges / JSONL lines an external scraper can
   watch while the engine runs).
 - **Operator table** (:func:`phase_table`) — the per-tick phase
-  breakdown as text, printed by ``decode_bench --serve --trace`` next
-  to the latency tables, with the time lost to stalls under it.
+  breakdown as text, with the time lost to stalls under it.
 - **Stalls by phase** (:func:`stalls`) — which ticks (or gaps between two
   ticks) took far longer than their kind does, how much time that lost,
   and in which child span of the tick the excess lies.
@@ -874,9 +872,7 @@ def phase_table(events: Iterable[Dict[str, Any]]) -> str:
     """Text table of the per-tick phase breakdown over ``engine_tick``
     records — totals, mean ms, and share of accounted tick time per
     phase — and under it the time :func:`stalls` finds lost in slow
-    ticks: in the wait for the device (``fetch``) and anywhere else.
-    ``decode_bench --serve --trace`` prints it next to the latency
-    tables."""
+    ticks: in the wait for the device (``fetch``) and anywhere else."""
     ticks = [e for e in events if e.get("kind") == "engine_tick"]
     if not ticks:
         return "tick phase breakdown: no engine_tick records"

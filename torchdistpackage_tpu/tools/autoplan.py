@@ -12,14 +12,14 @@ enumerates mesh shapes x layer layouts x compression arms
 (``dist/autoplan.py``), prunes candidates over the ``--hbm-gb`` budget,
 scores the rest with the alpha-beta comm model for ``--chip`` plus the
 6N+12LSD compute term, renders the ranked table, and prints ONE JSON
-plan line (the machine-readable result, like ``bench.py``'s output).
+plan line (the machine-readable result).
 
 Exit code: 0 = a plan was chosen, 1 = EVERY candidate is over the memory
 budget (the clean all-OOM verdict — the table shows how far over), 2 =
 usage / unreadable config.
 
 Deliberately jax-free (a login-node / capacity-planning CLI, like
-``bench_trend`` / ``parity_diff``), hence the bare prints: the analytic
+``parity_diff``), hence the bare prints: the analytic
 memory mirror (pinned byte-identical to ``MemoryModel.estimate`` by
 ``tests/test_autoplan.py``) replaces the jax-side estimator, and the
 per-generation CommModel tables replace calibration.  Feed a calibrated

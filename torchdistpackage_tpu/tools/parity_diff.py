@@ -22,11 +22,10 @@ files (one JSON step record per line).  The tool prints:
 - one final JSON line with the verdict and the headline deltas.
 
 Exit code: 0 for ``exact``/``bounded``, 1 for ``diverged``, 2 for usage/
-input errors — a CI gate over quantization/optimization A/Bs, the way
-``tools/bench_trend`` gates the bench rounds.
+input errors — a CI gate over quantization/optimization A/Bs.
 
 Deliberately jax-free (a login-node / CI gate tool over artifacts on
-disk, like ``bench_trend``), hence the bare prints.
+disk), hence the bare prints.
 """
 
 from __future__ import annotations

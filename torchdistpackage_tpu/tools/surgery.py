@@ -137,8 +137,7 @@ def quantize_decode_params(
     ``[L, ...]`` block leaves keep per-layer scales) with a
     :class:`QuantizedLinear`.  Embeddings, biases and norms stay dense —
     the win is HBM weight bandwidth on the matmuls, which is what bounds
-    incremental decode (docs/ROADMAP.md analysis: decode reads every
-    weight once per token).  The model functions dispatch structurally
+    incremental decode (it reads every weight once per token).  The model functions dispatch structurally
     (``tensor_parallel.layers.dense``), so the quantized tree drops into
     ``models.generate``/``forward_cached`` unchanged — golden + jaxpr
     proof in tests/test_generate.py."""

@@ -1,6 +1,6 @@
 """Trace replay: routing policy at 10^5-request scale, with no devices.
 
-ROADMAP 2(c)+5(a): a routing policy ("prefix-affinity vs load", "when
+A routing policy ("prefix-affinity vs load", "when
 to rebalance", "how tight can deadlines get") can only be MEASURED at
 a scale no test fleet reaches — millions of requests, diurnal load,
 long-tailed prefix sharing.  This tool closes that gap on one CPU: it
@@ -59,8 +59,8 @@ Usage::
         --autoscale --chaos \
         --report /tmp/FLEETREPORT.json --ledger /tmp/ledger.jsonl
 
-Prints one ``{"metric": "trace-replay", ...}`` JSON line (the
-bench_trend contract) plus the fleet summary line.
+Prints one ``{"metric": "trace-replay", ...}`` JSON line plus the
+fleet summary line.
 """
 
 from __future__ import annotations
