@@ -109,10 +109,12 @@ def _cache_write(c, val: jnp.ndarray, offset):
     return jax.lax.dynamic_update_slice(c, val.astype(c.dtype), (0, 0, offset, 0))
 
 
-def _cached_attention(q: jnp.ndarray, ck, cv, offset, window=None) -> jnp.ndarray:
+def _cached_attention(q: jnp.ndarray, ck, cv, offset, window=None,
+                      sm_scale: Optional[float] = None) -> jnp.ndarray:
     """Grouped-query attention of q [B, H, S_in, hd] against the full cache
     ck/cv [B, Hkv, T, hd], masked to ``key_pos <= offset + query_row``.
-    f32 softmax, 1/sqrt(hd) scale — the mha_reference conventions.
+    f32 softmax, 1/sqrt(hd) scale (or ``sm_scale``) — the mha_reference
+    conventions.
 
     ``offset`` is a scalar (every row at the same position — the
     ``generate()`` batch) OR a [B] vector of per-row positions — the
@@ -140,7 +142,7 @@ def _cached_attention(q: jnp.ndarray, ck, cv, offset, window=None) -> jnp.ndarra
     ).astype(jnp.float32)
     if k_scale is not None:
         s = s * k_scale[:, :, None, None, :]
-    s = s * (1.0 / math.sqrt(hd))
+    s = s * (1.0 / math.sqrt(hd) if sm_scale is None else sm_scale)
     key_pos = jnp.arange(T)
     qpos = jnp.asarray(offset)[..., None] + jnp.arange(S_in)  # [S_in] | [B, S_in]
     mask = key_pos[None, :] <= qpos[..., None]

@@ -47,8 +47,22 @@ attention + FFN is two layers here (``"LD"``, ``"LE"``, ``"CE"``); a layer
 with a ``post_norm`` leaf norms what its mixer gives as well, ``x <- x +
 RMSNorm_post(mixer(RMSNorm(x)))`` (a block of four norms is two such
 layers), and one with a ``res`` leaf mixes by four learned vectors
-instead, ``x <- a_h x + b_h + a_y y + b_y``.  The embedding is scaled by
-``embed_scale`` where a model says so.  A final RMSNorm, then the head: ``params["head"]``
+instead, ``x <- a_h x + b_h + a_y y + b_y``.  A published block of a mixer
+and an MLP behind it, each under a SCALED residual (``h <- h + r
+mixer(RMSNorm(h))``, then ``h <- h + r mlp(RMSNorm(h))``: Granite 4.0-H's
+``residual_multiplier``), is two one-mixer layers, ``"MD"`` or ``"*D"``,
+with ``residual_scale`` = r on both.  Three more constants a model may
+state, each defaulting to what every other model computes:
+``embed_scale`` (the embedding's rows are multiplied by it), ``attn_scale``
+(the softmax scale of the ``*`` / ``W`` / ``C`` layers where it is not
+``head_dim ** -0.5``; it reaches the paged kernel and its gathered oracle as
+their ``sm_scale``, so the query is multiplied by nothing and rounded no
+further) and ``logits_scale`` (the head's logits are multiplied by it).
+Heads NARROWER than a lane tile (``head_dim`` 64 under 128 lanes): the pool
+lays ``kv_pack`` KV heads side by side in one 128-wide row
+(:attr:`HybridConfig.kv_pack`, serving/paged_cache.py "Narrow heads"), since
+a TPU holds a 64-wide minor dimension at 128 and the paged kernel cannot
+slice it.  A final RMSNorm, then the head: ``params["head"]``
 [D, V], or the embedding table itself where the tree has no such leaf (a
 tied head).  The biases are the convolutions', the network router's
 (``moe_score='mlp'``) and the ``res`` leaves'; no projection has one.
@@ -152,6 +166,14 @@ class HybridConfig:
     window: int = 0
     #: what the embedding's rows are multiplied by (1: as they lie)
     embed_scale: float = 1.0
+    #: what every layer's output is multiplied by before it joins the
+    #: residual stream, ``h <- h + residual_scale * y`` (1: as it is)
+    residual_scale: float = 1.0
+    #: the softmax scale of the '*' / 'W' / 'C' layers (None: ``head_dim **
+    #: -0.5``); handed to the kernel as its ``sm_scale``, never folded into q
+    attn_scale: Optional[float] = None
+    #: what the head's logits are multiplied by (1: as they are)
+    logits_scale: float = 1.0
     rope_theta: float = 10000.0
     #: a rope-scaling dict as ``rope_cache`` takes it (yarn), or None
     rope_scaling: Optional[Dict[str, Any]] = None
@@ -255,6 +277,24 @@ class HybridConfig:
         """What one position caches for the indexer of an 'S' layer beside
         its keys and values (0: no such leaf in the pool)."""
         return self.idx_dim if "S" in self.pattern else 0
+
+    @property
+    def kv_pack(self) -> int:
+        """KV heads that share one row of the pool (1: a head a row).  A
+        TPU tiles a bfloat16 array's minor dimension by 128 lanes: a pool
+        whose rows are one head of 64 is held at twice its bytes, Mosaic
+        cannot slice it, and XLA copies it whole for every call that does
+        (PERF.md section 6, PR 46: compiled for a described v5e).  So heads
+        narrower than 128 that fill a row exactly, ``kv_heads`` of them in
+        whole rows, lie ``128 / head_dim`` to a row, and the attention ops
+        reach each through zeroed lanes of the query
+        (serving/paged_cache.py "Narrow heads").  Indexed and latent
+        attention read their pools with kernels of their own: 1."""
+        hd = self.head_dim
+        if (set("SL") & set(self.pattern) or not 0 < hd < 128 or 128 % hd
+                or self.kv_heads % (128 // hd)):
+            return 1
+        return 128 // hd
 
     @property
     def mla_scale(self) -> float:
@@ -831,6 +871,9 @@ def hybrid_paged_forward(
                 "a_h", "b_h", "a_y", "b_y"))
             h = (a_h * h.astype(F32) + b_h + a_y * y.astype(F32)
                  + b_y).astype(h.dtype)
+        elif cfg.residual_scale != 1.0:
+            h = (h.astype(F32) + cfg.residual_scale * y.astype(F32)).astype(
+                h.dtype)
         else:
             h = h + y
     state = {"ssm": tuple(ssm), "conv": tuple(conv), "tail": tuple(tails)}
@@ -848,6 +891,8 @@ def hybrid_paged_forward(
         logits = dense(h, params["head"])
     else:   # tied: the table as it lies, contracted over its rows' width
         logits = jnp.einsum("bsd,vd->bsv", h, params["tok_emb"])
+    if cfg.logits_scale != 1.0:
+        logits = (logits.astype(F32) * cfg.logits_scale).astype(logits.dtype)
     return cache, state, logits[:, 0, :], metrics
 
 

@@ -253,6 +253,26 @@ class Request:
                 f"deadline_s must be > 0, got {self.deadline_s}")
 
 
+def _device_bytes_in_use() -> Optional[int]:
+    """What the local devices hold now, by their own count; None where the
+    backend gives none (the CPU)."""
+    from ..obs.mem_ledger import live_memory
+
+    mem = live_memory()
+    return mem["live_bytes"] if mem["reported"] else None
+
+
+def _device_bytes_taken(before: Optional[int], tree: Any) -> Dict[str, int]:
+    """``{'device_bytes': ...}`` for an init span: what allocating ``tree``
+    took of the devices since ``before`` (:func:`_device_bytes_in_use`),
+    tiling and padding included, to stand beside the arrays' logical
+    ``bytes``; nothing where the backend gives no count."""
+    if before is None:
+        return {}
+    jax.block_until_ready(tree)
+    return {"device_bytes": _device_bytes_in_use() - before}
+
+
 def _split_keys(keys: jnp.ndarray):
     """[B, 2] uint32 -> (carried keys, this step's sample keys)."""
     ks = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
@@ -698,8 +718,10 @@ class ServingEngine:
         self.device_step = device_step
         device_step.bind(self)
         with span("tdp:engine.init.pool") as sp:
+            before = _device_bytes_in_use()
             self.cache = device_step.init_cache()
-            sp.attrs.update(bytes=pool_bytes(self.cache), **self._walk_attrs())
+            sp.attrs.update(bytes=pool_bytes(self.cache), **self._walk_attrs(),
+                            **_device_bytes_taken(before, self.cache))
             if "idx" in self.cache:  # of which the indexer's keys
                 sp.attrs.update(index_bytes=index_bytes(self.cache))
             if self.window:  # of which the window layers' pool
@@ -713,8 +735,14 @@ class ServingEngine:
         self.state_bytes = 0
         if self.state_model:
             self.state_bytes = int(cfg.state_bytes(num_slots))
-            with span("tdp:engine.init.state", bytes=self.state_bytes):
+            with span("tdp:engine.init.state", bytes=self.state_bytes) as sp:
+                before = _device_bytes_in_use()
                 self.state = device_step.init_state()
+                # of which the recurrent state, and the convolutions' rows
+                sp.attrs.update(
+                    ssm_bytes=pool_bytes(self.state.get("ssm", ())),
+                    conv_bytes=pool_bytes(self.state.get("conv", ())),
+                    **_device_bytes_taken(before, self.state))
         #: run_ahead's first decode call: no call before it to take from
         self._no_flight = {"out": (jnp.zeros(num_slots, jnp.int32),
                                    jnp.zeros((num_slots, 2), jnp.uint32))
@@ -938,7 +966,8 @@ class ServingEngine:
         tp = int(self.mesh.shape[self.axis]) if (
             self.mesh is not None and self.axis) else 1
         blk, ops = self.cfg.block, paged_attention_ops
-        groups, hkv = blk.nheads // blk.kv_head_count, arr.shape[2] // tp
+        # by the pool's own head axis: narrow heads lie several to a row
+        groups, hkv = blk.nheads // arr.shape[2], arr.shape[2] // tp
         bs = arr.shape[3]
 
         def walk(s_in, window):  # as the wrapper asks

@@ -35,6 +35,21 @@ compiles ONCE):
   table of its own: a sequence holds at most :func:`window_reach` of its
   blocks, and the engine hands a block that fell behind the window on to a
   column ahead (docs/serving.md "Two pools").
+  **Narrow heads.**  A TPU holds a bfloat16 array in tiles of 128 lanes
+  along its minor dimension: a pool of 64-wide heads would be held at twice
+  its bytes, the paged kernel cannot slice it (Mosaic: "slice shape must be
+  aligned to tiling (128), but is 64") and XLA copies it whole into a padded
+  form for each call that reads it.  A model whose heads fill a lane row
+  exactly in twos or fours (``cfg.kv_pack``, models/hybrid.py) gets the pool
+  ``[L, num_blocks, Hkv / pack, block_size, pack * hd]``: ``pack`` KV heads
+  of one position side by side in a row (:func:`pack_heads`).  The write
+  lays a call's rows out that way; the attention ops, kernel and gathered
+  oracle alike, are handed a query whose head lies in the lanes of ITS KV
+  head with zeros in the others (the score is then the head's own, term for
+  term), an explicit ``sm_scale``, and give back a ``pack * hd`` wide row of
+  which the head's lanes are kept (:func:`paged_attention`).  A pool of
+  ``Hkv / pack`` heads of 128: the kernel, the tables and the allocator see
+  nothing else.
 - **Block tables**: ``[num_slots, max_blocks]`` int32 per-slot rows.  Block
   ``i`` of a slot's table covers its positions ``[i*bs, (i+1)*bs)``, so the
   table IS the page table and position arithmetic is two integer ops.
@@ -65,6 +80,7 @@ int32 tables between compiled steps (see ``serving/engine.py``).
 from __future__ import annotations
 
 import functools
+import math
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -135,7 +151,13 @@ def init_paged_kv(
             f"kv_heads {cfg.block.kv_head_count} not divisible by tp "
             f"{axis_size} (whole KV heads per shard)"
         )
-    shape = (_kv_layers(cfg), num_blocks, hkv, block_size, cfg.block.head_dim)
+    pack = _kv_pack(cfg)
+    if pack > 1 and (quantized or axis_size != 1):
+        raise NotImplementedError(
+            "a pool of heads narrower than a lane row has no int8 form and "
+            "no tensor-parallel split yet (ROADMAP queue 2)")
+    shape = (_kv_layers(cfg), num_blocks, hkv // pack, block_size,
+             pack * cfg.block.head_dim)
     if _index_width(cfg):
         if quantized or axis_size != 1:
             raise NotImplementedError(
@@ -156,6 +178,21 @@ def init_paged_kv(
         pool["win"] = {"k": jnp.zeros(wshape, cfg.dtype),
                        "v": jnp.zeros(wshape, cfg.dtype)}
     return pool
+
+
+def _kv_pack(cfg) -> int:
+    """KV heads that share one row of the pool (models/hybrid.py
+    ``kv_pack``: heads narrower than a lane row); 1 = a head a row."""
+    return getattr(cfg, "kv_pack", 1)
+
+
+def pack_heads(val: jnp.ndarray, pack: int) -> jnp.ndarray:
+    """``[B, Hkv, S, hd] -> [B, Hkv / pack, S, pack * hd]``: ``pack``
+    consecutive KV heads of one position side by side, as a pool of narrow
+    heads keeps them."""
+    B, Hkv, S, hd = val.shape
+    return val.reshape(B, Hkv // pack, pack, S, hd).swapaxes(2, 3).reshape(
+        B, Hkv // pack, S, pack * hd)
 
 
 def _window_layers(cfg) -> int:
@@ -312,6 +349,9 @@ def paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray,
         whole = paged_write(jax.tree.map(lambda a: a[None], c), val, offset,
                             tables=tables, layer=0)
         return jax.tree.map(lambda a: a[0], whole)
+    width = (c[0] if isinstance(c, tuple) else c).shape[4]
+    if width != val.shape[3]:  # narrow heads, several to a row of the pool
+        val = pack_heads(val, width // val.shape[3])
     B, Hkv, S_in, hd = val.shape
     bs = (c[0] if isinstance(c, tuple) else c).shape[3]
     blk, src, valid = _write_blocks(
@@ -358,7 +398,7 @@ def gather_kv(c, tables: jnp.ndarray, layer=None):
 
 def paged_attention(
     q: jnp.ndarray, ck, cv, offset, *, tables: jnp.ndarray, window=None,
-    impl: str = "gather", layer=None,
+    impl: str = "gather", layer=None, sm_scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Attention of q [B, H, S_in, hd] against each slot's paged context
     in layer ``layer`` of the pools ``ck`` / ``cv`` (``None``: they are one
@@ -371,18 +411,46 @@ def paged_attention(
     call.  ``impl='pallas'``: the fused Pallas kernel
     (:func:`~..ops.paged_attention.paged_decode_attention`) walks the
     block table in-kernel — no gathered view, int8 pools dequantized
-    in-register, HBM traffic bounded by the slot's live length."""
+    in-register, HBM traffic bounded by the slot's live length.
+
+    ``sm_scale``: the softmax scale (None: ``hd ** -0.5``).  A pool of
+    narrow heads (its rows ``pack`` heads wide, module docstring): each
+    query head is spread to the row's width, its values in the lanes of its
+    own KV head and zeros in the others, so that either implementation sees
+    ``Hkv / pack`` KV heads of ``pack * hd`` and computes the head's own
+    scores term for term; of the row that comes back the head's lanes are
+    kept."""
+    B, H, S_in, hd = q.shape
+    rows, width = (ck[0] if isinstance(ck, tuple) else ck).shape[-3::2]
+    pack = width // hd
+    if pack > 1:
+        if sm_scale is None:
+            sm_scale = 1.0 / math.sqrt(hd)
+        # query head h reads KV head h // groups, which lies in lanes
+        # [e * hd, (e + 1) * hd) of its row, e = (h // groups) % pack
+        Hkv = rows * pack
+        lane = (jnp.arange(H) // (H // Hkv)) % pack
+        own = (lane[:, None] == jnp.arange(pack)[None, :])     # [H, pack]
+        q = jnp.where(own[None, :, None, :, None], q[:, :, :, None, :],
+                      jnp.zeros((), q.dtype)).reshape(B, H, S_in, pack * hd)
     if impl == "pallas":
         from ..ops.paged_attention import paged_decode_attention
 
-        return paged_decode_attention(q, ck, cv, tables, offset,
-                                      layer=layer, window=window)
-    return _cached_attention(
-        q, gather_kv(ck, tables, layer), gather_kv(cv, tables, layer),
-        offset, window=window)
+        out = paged_decode_attention(q, ck, cv, tables, offset, layer=layer,
+                                     window=window, sm_scale=sm_scale)
+    else:
+        out = _cached_attention(
+            q, gather_kv(ck, tables, layer), gather_kv(cv, tables, layer),
+            offset, window=window, sm_scale=sm_scale)
+    if pack > 1:
+        out = jnp.take_along_axis(
+            out.reshape(B, H, S_in, pack, hd),
+            lane[None, :, None, None, None], axis=3)[:, :, :, 0]
+    return out
 
 
-def _paged_cache_ops(tables: jnp.ndarray, attn_impl: str, layer):
+def _paged_cache_ops(tables: jnp.ndarray, attn_impl: str, layer,
+                     sm_scale: Optional[float] = None):
     """The ``cache_ops`` pair ``cached_block_forward`` needs to run one
     layer on the block pool instead of the contiguous buffer: the cache it
     threads through is the WHOLE pool, and ``layer`` (a python int in an
@@ -390,7 +458,8 @@ def _paged_cache_ops(tables: jnp.ndarray, attn_impl: str, layer):
     into it.  The one way a layer reaches the pool."""
     def attend(q, ck, cv, offset, window=None):
         return paged_attention(q, ck, cv, offset, tables=tables,
-                               window=window, impl=attn_impl, layer=layer)
+                               window=window, impl=attn_impl, layer=layer,
+                               sm_scale=sm_scale)
     return functools.partial(paged_write, tables=tables, layer=layer), attend
 
 
@@ -775,13 +844,15 @@ def paged_forward_hybrid(
     window_ops = None
     if _window_layers(cfg):
         tables, wtables = tables
-        window_ops = functools.partial(_paged_cache_ops, wtables, attn_impl)
+        window_ops = functools.partial(_paged_cache_ops, wtables, attn_impl,
+                                       sm_scale=cfg.attn_scale)
     if _latent_width(cfg):
         ops = functools.partial(_latent_cache_ops, tables, attn_impl, cfg)
     elif _index_width(cfg):
         ops = functools.partial(_indexed_cache_ops, tables, attn_impl, cfg)
     else:
-        ops = functools.partial(_paged_cache_ops, tables, attn_impl)
+        ops = functools.partial(_paged_cache_ops, tables, attn_impl,
+                                sm_scale=cfg.attn_scale)
     mine = state
     if rows is not None:
         def own(a):
