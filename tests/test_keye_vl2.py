@@ -22,6 +22,7 @@ from torchdistpackage_tpu.models import init_hybrid_params
 from torchdistpackage_tpu.models.hybrid import (
     hybrid_paged_forward, init_state, mrope_cache)
 from torchdistpackage_tpu.ops import dsa_attention as D
+from torchdistpackage_tpu.ops import paged_attention as P
 from torchdistpackage_tpu.parallel.tensor_parallel.layers import rope_cache
 from torchdistpackage_tpu.serving import (
     Request, ServingEngine, expected_pool_bytes, init_paged_kv, pool_bytes)
@@ -504,14 +505,58 @@ def _case(rng, B, S_in, mb, bs, offs, H=4, Hkv=2, hd=16, J=2, di=8):
                 offs=jnp.asarray(offs, jnp.int32))
 
 
-@pytest.mark.parametrize("S_in,offs", [(1, (37, 5, 90)), (16, (24, 0, 70)),
-                                       (32, (8, 64, 0))],
-                         ids=["decode", "chunk16", "chunk32"])
-def test_the_kernels_equal_their_oracle(S_in, offs):
+#: a case's own walk: ``H`` query heads over the two KV heads, ``topk``, and
+#: the constants of ``ops/paged_attention.py`` that size a walk, shrunk to
+#: the toy's 13 columns of 8 keys; ``walk``: what ``shape_walk`` must then
+#: give, ``(split, fw, hb, T)``, so that no case can stop testing its shape
+_WALKS = {
+    "decode": dict(S_in=1, offs=(37, 5, 90)),
+    "chunk16": dict(S_in=16, offs=(24, 0, 70)),
+    "chunk32": dict(S_in=32, offs=(8, 64, 0)),
+    # 256 rows a head walk the grid in four tiles of 4 blocks: dead
+    # sub-blocks in a live tile (slot 0 ends in column 4), three sub-blocks
+    # past the table's 13th column, slot 1 full to its last position (104)
+    "ragged_tiles": dict(S_in=32, offs=(8, 72, 40), H=16,
+                         patch={"_CHUNK_TILE_KEYS": 32}, walk=(1, 4, 1, 0)),
+    # 512 rows a head pass the cap: two programs a head, four query heads each
+    "split_heads": dict(S_in=64, offs=(40, 0, 8), H=16,
+                        patch={"_CHUNK_TILE_KEYS": 32, "_PROGRAM_ROWS": 256},
+                        walk=(2, 4, 1, 0)),
+    # deep slots that keep 4 positions: rows whose first tile holds none
+    "empty_first_tile": dict(S_in=32, offs=(64, 72, 50), H=16, topk=4,
+                             patch={"_CHUNK_TILE_KEYS": 32},
+                             walk=(1, 4, 1, 0)),
+    # few rows a head: both heads one program, the decode walk's tile on
+    # the grid, 13 columns in four tiles of 4
+    "few_rows": dict(S_in=16, offs=(24, 88, 70),
+                     patch={"_KV_TILE_KEYS": 32}, walk=(1, 1, 2, 4)),
+}
+
+
+@pytest.fixture
+def fresh_walk():
+    """``dsa_chunk``'s walk is chosen as the call is traced: a case that
+    shrinks a constant must neither meet nor leave a traced call."""
+    D._chunk_attention_pallas.clear_cache()
+    yield
+    D._chunk_attention_pallas.clear_cache()
+
+
+@pytest.mark.parametrize("name", list(_WALKS))
+def test_the_kernels_equal_their_oracle(name, fresh_walk, monkeypatch):
     """``dsa_index``, ``dsa_select`` and ``dsa_decode`` / ``dsa_chunk`` in
     interpret mode against the gathered oracle, layer 1 of a two-layer
     pool, slots at unequal depths (one short of ``topk``)."""
-    c = _case(np.random.RandomState(4), 3, S_in, 13, 8, offs)
+    case = _WALKS[name]
+    S_in, offs, topk = case["S_in"], case["offs"], case.get("topk", 16)
+    H = case.get("H", 4)
+    for const, value in case.get("patch", {}).items():
+        monkeypatch.setattr(P, const, value)
+    if "walk" in case:
+        split, _cols, _rows, fw, hb, T = P.shape_walk(
+            H // 2, S_in, 2, 13, 8, 8 * 16 * 4)
+        assert (split, fw, hb, T) == case["walk"]
+    c = _case(np.random.RandomState(4), 3, S_in, 13, 8, offs, H=H)
     args = (c["qi"], c["w"], c["ip"], c["tables"], c["offs"])
     sc = {impl: D.index_scores(*args, layer=1, impl=impl)
           for impl in ("gather", "pallas")}
@@ -519,12 +564,14 @@ def test_the_kernels_equal_their_oracle(S_in, offs):
                                atol=1e-5)
     # the kernels' layout is by block; both from the oracle's scores
     given = {"gather": sc["gather"], "pallas": D.by_block(sc["gather"], 8)}
-    bias = {impl: D.select_bias(given[impl], c["offs"], 16, impl=impl)
+    bias = {impl: D.select_bias(given[impl], c["offs"], topk, impl=impl)
             for impl in ("gather", "pallas")}
     np.testing.assert_array_equal(D.natural(bias["pallas"]), bias["gather"])
     kept = np.asarray((bias["gather"] == 0).sum(-1))
     ctx = np.asarray(c["offs"])[:, None] + np.arange(S_in)[None] + 1
-    np.testing.assert_array_equal(kept, np.minimum(16, ctx))
+    np.testing.assert_array_equal(kept, np.minimum(topk, ctx))
+    if name == "empty_first_tile":   # rows that keep nothing of tile 0
+        assert (np.asarray(bias["gather"])[..., :32] != 0).all(-1).any()
     out = {impl: D.selected_attention(
         c["q"], c["kp"], c["vp"], bias[impl], c["tables"], c["offs"],
         layer=1, impl=impl) for impl in ("gather", "pallas")}
@@ -539,6 +586,45 @@ def test_the_kernels_equal_their_oracle(S_in, offs):
         bits.reshape(3, S_in, -1)[..., :13 * 8], np.asarray(bias["gather"] == 0))
     np.testing.assert_array_equal(
         ref.pack_mask(jnp.asarray(bias["gather"][0] == 0)), words["gather"][0])
+
+
+@pytest.mark.parametrize("chunk", [32, 96], ids=["few_rows", "grid_tile"])
+def test_the_pool_span_says_the_walk_dsa_chunk_makes(
+        toy, chunk, fresh_walk, monkeypatch):
+    """``chunk_rows``, ``chunk_tile_keys`` and ``chunk_programs`` on
+    ``tdp:engine.init.pool`` are what ``_chunk_attention_pallas`` asks
+    ``pallas_call`` for at the engine's prefill call: the rows of a
+    program's query block, the K blocks of a grid step, the programs a
+    slot (64 rows a head: both heads one program and the decode walk's
+    tile; 192: a head a program and ``chunk_tile``'s)."""
+    from torchdistpackage_tpu.utils.profiling import spans
+    _, cfg, params = toy
+    eng = ServingEngine(params, cfg, num_slots=2, block_size=8, chunk=chunk,
+                        max_ctx=192, attn_impl="pallas")
+    said = [r[5] for r in spans.snapshot()
+            if r[2] == "tdp:engine.init.pool"][-1]
+    asked, real = [], D.pl.pallas_call
+
+    def spy(kernel, **kw):
+        if kw["name"] == "dsa_chunk":
+            asked.append(kw["grid_spec"])
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(D.pl, "pallas_call", spy)
+    k, W = eng.cache["k"], eng.prefill_width
+    sds = jax.ShapeDtypeStruct
+    jax.eval_shape(
+        lambda *a: D.selected_attention(*a, layer=0, impl="pallas"),
+        sds((W, cfg.block.nheads, chunk, k.shape[-1]), k.dtype), k,
+        eng.cache["v"], sds((W, eng.max_blocks, chunk, 8), F32),
+        sds((W, eng.max_blocks), jnp.int32), sds((W,), jnp.int32))
+    (spec,) = asked
+    (_one, hb, rows, _hd), tile = spec.in_specs[0].block_shape, (
+        len(spec.in_specs) - 1) // 3
+    assert spec.grid == (W, k.shape[2] // hb, -(-eng.max_blocks // tile))
+    assert (said["chunk_rows"], said["chunk_tile_keys"],
+            said["chunk_programs"]) == (rows, tile * 8, spec.grid[1])
+    assert (hb, tile) == {32: (2, 24), 96: (1, 24)}[chunk]
 
 
 def test_equal_scores_keep_the_lower_position():

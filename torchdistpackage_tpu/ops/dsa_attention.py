@@ -32,8 +32,13 @@ view, the parity oracle and the CPU's default):
    65,536 positions a layer as DMAs (or as an XLA gather) cost more than
    the whole walk at contexts of 4k-14k (PERF.md section 6, PR 39).  The
    decode shape walks in the kernel (one DMA a live block, a key tile a
-   softmax step); a prefill chunk walks the grid in query tiles of 128
-   positions, each its own causal extent.
+   softmax step); a prefill chunk walks the grid as the paged kernel's
+   chunk does (``ops.paged_attention.shape_walk``): a KV head's query rows
+   of the WHOLE chunk are one program (past ``_PROGRAM_ROWS`` the fewest
+   that come under it), so a head's blocks are fetched once a chunk, a
+   grid step's blocks are one key tile of up to 1,024 keys and one softmax
+   step, and the bias is the only mask: it reads :data:`NEG_INF` behind
+   every query already, so the chunk kernel compares no positions.
 
 Under ``impl='pallas'`` scores and bias lie BY BLOCK, ``[B, max_blocks, S_in,
 bs]`` (column block, then query row, then the block's positions), from one
@@ -53,7 +58,8 @@ program's selection (a score on the other side of the ``topk``-th after
 rounding is another key read, as a flipped expert is another function).
 
 The kernels of ``ops/paged_attention.py`` are not touched: the two
-attention kernels here are their bodies with one more operand.
+attention kernels here are their bodies with one more operand, and their
+walks are the ones that module's ``call_walk`` / ``shape_walk`` give a shape.
 """
 
 from __future__ import annotations
@@ -74,18 +80,21 @@ from .paged_attention import (
     NEG_INF,
     _accumulate,
     _stacked,
-    _step_params,
     call_walk,
+    shape_walk,
 )
 
 F32 = jnp.float32
-#: query positions of one tile of the chunk kernels
+#: query positions of one tile of ``dsa_index``'s chunk kernel
 _Q_TILE = 128
 #: index-key blocks a grid step of ``dsa_index`` fetches at most
 _INDEX_FETCH = 16
 #: rows of one ``dsa_select`` program (a float32 sublane tile)
 _SELECT_ROWS = 8
-#: VMEM ``dsa_chunk`` may take (the chip has 128 MiB)
+#: VMEM ``dsa_chunk`` may take (the chip has 128 MiB).  Compiled alone for a
+#: described v5e (PR 47) a program of 4,096 rows x a tile of 1,024 keys asks
+#: for 36.37 MB: the float32 scores and probabilities, 8 bias blocks of
+#: ``[512, 128]`` float32 twice over, q, out and the (acc, m, l) scratch
 _CHUNK_VMEM_LIMIT = 64 << 20
 
 
@@ -679,25 +688,51 @@ def _decode_attention_pallas(q, k_pool, v_pool, bias, tables, offs, lay, *,
     return out[:, :, :G].reshape(B, H, 1, hd)
 
 
+def _accumulate_biased(s, pv, acc_ref, m_ref, l_ref):
+    """``ops.paged_attention._accumulate`` for scores whose bias is their
+    only mask: one key tile's online-softmax step on the (acc, m, l)
+    scratch, ``s`` the scaled, biased f32 scores ``[..., S_in, keys]``,
+    ``pv(p)`` the f32 ``[..., S_in, hd]`` product of the tile's
+    probabilities with its values.  No ``where`` over the scores."""
+    m = m_ref[..., :1]
+    l = l_ref[..., :1]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l_ref[...] = jnp.broadcast_to(
+        l * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+    acc_ref[...] = acc_ref[...] * corr + pv(p)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+
 def _chunk_kernel(tab_ref, off_ref, lay_ref, q_ref, *refs,
-                  S_in, ts, bs, mb, fw, G, sm_scale):
-    """Grid ``(slot b, kv head h, query tile qt, key step j)``: the grid's
-    walk of ``ops.paged_attention._kernel`` with the query rows in tiles of
-    ``ts`` positions (row ``g * ts + s`` of a tile is group g's query at
-    the tile's position s; a tile walks the columns its OWN last position
-    can see), the selection's bias ``[ts, bs]`` a sub-block (the same for
-    every group), and ONE online-softmax step a grid step: the ``fw``
-    fetched blocks side by side are one key tile (a step a block spent more
-    on rescaling the accumulator than on its two products).  A dead
-    sub-block of a live step holds a block fetched earlier: its positions
-    lie behind every query of the tile, so the causal mask takes it out.
-    ``refs``: ``fw`` x (K block, V block, bias block), the output, the (acc,
-    m, l) scratch ``[G, ts, ...]``."""
+                  S_in, bs, mb, fw, sm_scale):
+    """Grid ``(slot b, program h, key step j)``: the grid's walk of
+    ``ops.paged_attention._kernel``.  A program is ``hb`` KV heads' query
+    rows of the WHOLE chunk (``q_ref`` [1, hb, rows, hd], rows group-major:
+    row ``g * S_in + s`` is group g's query at the chunk's position s; past
+    ``_PROGRAM_ROWS`` one of the ``split`` shares of one head's groups), so
+    a head's K and V blocks are fetched once a chunk.  A step's ``fw``
+    blocks side by side are ONE key tile and one online-softmax step.  The
+    selection's bias ``[S_in, fw * bs]`` of the tile, the same for every
+    group, is the ONLY mask: it reads :data:`NEG_INF` at every position that
+    is unselected, behind its query, or in a column past the slot's live
+    blocks, so no position is compared with any other.  A dead sub-block of
+    a live tile (its column past the slot's last live one, or past the
+    table's last) holds K, V and bias blocks fetched earlier
+    (:func:`_held_column`: the pipeline fetches nothing); ONE scalar test a
+    sub-block puts a block's worth of :data:`NEG_INF` in place of the bias
+    it holds.  A tile none of whose positions a row selected leaves that
+    row's ``m`` at :data:`NEG_INF` and its probabilities at 1; the next tile
+    with a selected position rescales that away (``exp(NEG_INF - m)`` is 0),
+    and every query keeps a position at or before its own.
+    ``refs``: ``fw`` x (K block [1, 1, hb, bs, hd], V block, bias block
+    [1, 1, S_in, bs]), the output, the (acc, m, l) scratch ``[hb x groups,
+    S_in, ...]``."""
     kv_refs, o_ref = refs[:3 * fw], refs[3 * fw]
     acc_ref, m_ref, l_ref = refs[3 * fw + 1:]
-    b, qt, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    off = off_ref[b]
-    hi1 = _live_columns(off, jnp.minimum((qt + 1) * ts, S_in), bs, mb)
+    b, j = pl.program_id(0), pl.program_id(2)
+    hi1 = _live_columns(off_ref[b], S_in, bs, mb)
     K = fw * bs
 
     @pl.when(j == 0)
@@ -708,92 +743,99 @@ def _chunk_kernel(tab_ref, off_ref, lay_ref, q_ref, *refs,
 
     @pl.when(j * fw <= hi1)
     def _compute():
-        side = lambda n, axis: jnp.concatenate(
-            [kv_refs[3 * i + n][0, 0, 0] for i in range(fw)], axis=axis)
-        k, v = side(0, 0), side(1, 0)            # [K, hd]
+        side = lambda n: jnp.concatenate(
+            [kv_refs[3 * i + n][0, 0] for i in range(fw)], axis=1)
+        k, v = side(0), side(1)                      # [hb, K, hd]
         bias = jnp.concatenate(
-            [kv_refs[3 * i + 2][0, 0] for i in range(fw)], axis=1)  # [ts, K]
-        s = jax.lax.dot_general(
-            q_ref[0, 0, 0], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=F32).reshape(G, ts, K)
-        qpos = off + qt * ts + jax.lax.broadcasted_iota(
-            jnp.int32, (G, ts, K), 1)
-        kpos = j * K + jax.lax.broadcasted_iota(jnp.int32, (G, ts, K), 2)
-        _accumulate(
-            s * sm_scale + bias, kpos <= qpos,
-            lambda p: jnp.dot(p.astype(v.dtype).reshape(G * ts, K), v,
-                              preferred_element_type=F32).reshape(G, ts, -1),
+            [jnp.where(j * fw + i <= hi1, kv_refs[3 * i + 2][0, 0], NEG_INF)
+             for i in range(fw)], axis=1)            # [S_in, K]
+        q = q_ref[0]                                 # [hb, rows, hd]
+        hb, rows, _hd = q.shape
+        s = jnp.einsum("hrd,hkd->hrk", q, k, preferred_element_type=F32)
+        _accumulate_biased(
+            s.reshape(-1, S_in, K) * sm_scale + bias,
+            lambda p: jnp.einsum(
+                "hrk,hkd->hrd", p.astype(v.dtype).reshape(hb, rows, K), v,
+                preferred_element_type=F32).reshape(acc_ref.shape),
             acc_ref, m_ref, l_ref)
 
     @pl.when(j == hi1 // fw)
     def _write():
-        o_ref[0, 0, 0] = (acc_ref[...] / l_ref[..., :1]).reshape(
-            G * ts, -1).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[..., :1]).reshape(
+            o_ref.shape[1:]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale",))
 def _chunk_attention_pallas(q, k_pool, v_pool, bias, tables, offs, lay, *,
                             sm_scale: float):
+    """The walk follows from the call's shape alone, as the paged wrapper's
+    (``ops.paged_attention.shape_walk``: what ``ServingEngine`` writes on its
+    ``tdp:engine.init.pool`` span as ``chunk_rows`` / ``chunk_tile_keys`` /
+    ``chunk_programs``): the programs a KV head's rows are dealt to, the KV
+    heads of one program and the blocks of one key tile."""
     B, H, S_in, hd = q.shape
     _L, _nb, Hkv, bs, _hd = k_pool.shape
     mb = tables.shape[-1]
-    G, ts = H // Hkv, _tile(S_in)
-    nqt, rows = S_in // ts, G * ts
-    fw, _pad = _step_params(mb, None, None)
-    qr = q.reshape(B, Hkv, G, nqt, ts, hd).transpose(0, 1, 3, 2, 4, 5).reshape(
-        B, Hkv, nqt, rows, hd)
+    G = H // Hkv
+    split, cols, rows, fw, hb, T = shape_walk(
+        G, S_in, Hkv, mb, bs, bs * hd * k_pool.dtype.itemsize)
+    fw = T or fw    # a few rows a head: the decode walk's tile, on the grid
+    if rows != G * S_in // split:
+        raise ValueError(
+            f"a chunk of {S_in} positions: a program's {G * S_in // split} "
+            f"query rows would be padded to {rows}; a multiple of 8")
+    progs = Hkv * split // hb
 
-    def column(b, qt, j, off, i):
-        hi1 = _live_columns(off[b], jnp.minimum((qt + 1) * ts, S_in), bs, mb)
-        col, live = _held_column(hi1, j, i, fw)
+    def column(b, j, off, i):
+        col, live = _held_column(
+            _live_columns(off[b], S_in, bs, mb), j, i, fw)
         return jnp.minimum(col, mb - 1), live
 
-    def qidx(b, h, qt, j, tab, off, lay):
-        return (b, h, qt, 0, 0)
+    def qidx(b, h, j, tab, off, lay):
+        return (b, h, 0, 0)
 
-    def kvidx(b, h, qt, j, tab, off, lay, i=0):
-        col, live = column(b, qt, j, off, i)
+    def kvidx(b, h, j, tab, off, lay, i=0):
+        col, live = column(b, j, off, i)
         return (lay[0], jnp.where(live, tab[b, col], 0),
-                jnp.where(live, h, 0), 0, 0)
+                jnp.where(live, h // split, 0), 0, 0)
 
-    def bidx(b, h, qt, j, tab, off, lay, i=0):
-        col, live = column(b, qt, j, off, i)
-        return (b, jnp.where(live, col, 0), qt, 0)
+    def bidx(b, h, j, tab, off, lay, i=0):
+        col, live = column(b, j, off, i)
+        return (b, jnp.where(live, col, 0), 0, 0)
 
-    in_specs, operands = [pl.BlockSpec((1, 1, 1, rows, hd), qidx)], [qr]
+    in_specs = [pl.BlockSpec((1, hb, rows, hd), qidx)]
+    operands = [q.reshape(B, Hkv * split, rows, hd)]
     for i in range(fw):
         for pool in (k_pool, v_pool):
             in_specs.append(pl.BlockSpec(
-                (1, 1, 1, bs, hd), functools.partial(kvidx, i=i)))
+                (1, 1, hb, bs, hd), functools.partial(kvidx, i=i)))
             operands.append(pool)
-        in_specs.append(pl.BlockSpec((1, 1, ts, bs),
+        in_specs.append(pl.BlockSpec((1, 1, S_in, bs),
                                      functools.partial(bidx, i=i)))
         operands.append(bias)
+    groups = hb * rows // S_in
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, Hkv, nqt, -(-mb // fw)),
+        grid=(B, progs, -(-cols // fw)),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, rows, hd), qidx),
+        out_specs=pl.BlockSpec((1, hb, rows, hd), qidx),
         scratch_shapes=[
-            pltpu.VMEM((G, ts, hd), F32),       # acc
-            pltpu.VMEM((G, ts, _LANES), F32),   # m
-            pltpu.VMEM((G, ts, _LANES), F32),   # l
+            pltpu.VMEM((groups, S_in, hd), F32),       # acc
+            pltpu.VMEM((groups, S_in, _LANES), F32),   # m
+            pltpu.VMEM((groups, S_in, _LANES), F32),   # l
         ],
     )
-    kernel = functools.partial(_chunk_kernel, S_in=S_in, ts=ts, bs=bs, mb=mb,
-                               fw=fw, G=G, sm_scale=sm_scale)
-    # a key tile's float32 scores and probabilities are [G * ts, fw * bs]
-    # each (3 MB at 1,024 rows x 768 keys), past the default scoped limit
+    kernel = functools.partial(_chunk_kernel, S_in=S_in, bs=bs, mb=mb, fw=fw,
+                               sm_scale=sm_scale)
     params = None if _interpret() else pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=_CHUNK_VMEM_LIMIT)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=_out_struct((B, Hkv, nqt, rows, hd), q.dtype, q),
+        out_shape=_out_struct((B, Hkv * split, rows, hd), q.dtype, q),
         compiler_params=params, interpret=_interpret(), name="dsa_chunk",
     )(tables.astype(jnp.int32), offs, lay, *operands)
-    return out.reshape(B, Hkv, nqt, G, ts, hd).transpose(
-        0, 1, 3, 2, 4, 5).reshape(B, H, S_in, hd)
+    return out.reshape(B, H, S_in, hd)
 
 
 def selected_attention(q, k_pool, v_pool, bias, tables, offsets, *,
