@@ -467,10 +467,16 @@ def test_run_ahead_drops_the_token_in_flight_of_a_cancelled_request(toy):
     assert got.audit(heal=False)["ok"]
 
 
-def test_run_ahead_is_the_state_models_only():
-    with pytest.raises(NotImplementedError, match="run_ahead"):
-        ServingEngine(None, GPTConfig(vocab_size=8, dim=8, nheads=2,
-                                      nlayers=1, max_seq=8), run_ahead=True)
+def test_an_explicit_run_ahead_raises_where_the_engine_keeps_the_serial_order():
+    """``run_ahead`` is every single-device engine's own choice now (the
+    dense family's too: tests/test_tick_order.py); what is left of the
+    refusal is an explicit ``True`` where the step has no ``prev`` form,
+    here a speculative engine, whose next draft needs this tick's tokens."""
+    cfg = GPTConfig(vocab_size=8, dim=8, nheads=2, nlayers=1, max_seq=8)
+    with pytest.raises(NotImplementedError, match="run_ahead with spec_k"):
+        ServingEngine(None, cfg, spec_k=2, run_ahead=True)
+    with pytest.raises(NotImplementedError, match="record_routing"):
+        ServingEngine(None, cfg, record_routing=True)
 
 
 @pytest.mark.parametrize("kw", [{"prefix_cache": True}, {"spec_k": 2}],

@@ -152,6 +152,44 @@ def test_telemetry_wrap_plain_function_and_fallback():
     assert tel.history[0]["step"] == 0
 
 
+@pytest.mark.parametrize("wait", [True, False], ids=["waits", "runs_ahead"])
+def test_end_step_blocks_on_the_newest_call_unless_the_caller_runs_ahead(wait):
+    """``end_step`` waits for the newest wrapped call's outputs; a caller
+    that runs a call ahead (the serving engine's ``run_ahead``) closes the
+    step BEFORE that one with ``wait=False`` and blocks on nothing.  Either
+    way the spans sum to the time between two ``end_step`` calls."""
+    import time
+
+    blocked = []
+
+    class Out:
+        def block_until_ready(self):
+            blocked.append(1)
+            return self
+
+    tel = Telemetry(run="w", report_path=None, poll_memory=False)
+    wrapped = tel.wrap_step(lambda x: x + 1)
+    t_prev = None
+    for i in range(3):
+        wrapped(jnp.zeros((3,)))
+        tel._pending_out = Out()   # what the call handed back, observable
+        time.sleep(0.002)
+        rec = tel.end_step(step=i, wait=wait)
+        assert len(blocked) == (i + 1 if wait else 0)
+        assert tel._pending_out is None
+        if t_prev is not None:
+            assert rec["step_time_s"] == pytest.approx(
+                rec["t_end_s"] - t_prev, abs=5e-4)
+        t_prev = rec["t_end_s"]
+    # no call since the step before: the time since its end is the wait
+    time.sleep(0.002)
+    rec = tel.end_step(step=3, wait=wait)
+    if not wait:
+        assert rec["span_device_s"] >= 0.002
+        assert rec["step_time_s"] == pytest.approx(rec["t_end_s"] - t_prev,
+                                                   abs=5e-4)
+
+
 # ------------------------------------------------------------- aggregation
 
 

@@ -431,13 +431,34 @@ def _sampled_rows(kind):
 SAMPLING = dict(temperature=1.0, top_k=16, top_p=0.9)
 
 
-def test_one_sampling_row_among_greedy_rows(bundles):
+def _discipline(b, run_ahead):
+    """The bundle's engine as it is constructed (``run_ahead``), or a serial
+    one of the same geometry, built once a module."""
+    if run_ahead:
+        assert b["eng"].run_ahead
+        return b["eng"]
+    if "serial" not in b:
+        b["serial"] = ServingEngine(b["params"], b["cfg"], num_slots=2,
+                                    block_size=4, chunk=4, run_ahead=False)
+    return b["serial"]
+
+
+DISCIPLINES = pytest.mark.parametrize("run_ahead", [False, True],
+                                      ids=["serial", "run_ahead"])
+
+
+@DISCIPLINES
+def test_one_sampling_row_among_greedy_rows(bundles, run_ahead):
     """A batch with ONE sampling row: its tokens are those it draws when
     served alone from the same seed, its greedy neighbour's are the
     all-greedy run's, and ``sampled_rows`` on the spans says which calls
-    took the sampler's drawing branch: 0 on the all-greedy run's."""
+    took the sampler's drawing branch: 0 on the all-greedy run's.  With
+    ``run_ahead`` the host sees a retirement one decode call later: the
+    sampling slot sits the call after its last out, its temperature still
+    among the call's (no call is built for a slot that sits out alone)."""
     b = bundles("dense")
-    eng = b["eng"]
+    eng = _discipline(b, run_ahead)
+    lag = int(run_ahead)
 
     def serve(*reqs):
         eng.reset_metrics()
@@ -452,8 +473,8 @@ def test_one_sampling_row_among_greedy_rows(bundles):
                                Request(p1, NEW, seed=7, **SAMPLING))
     # the greedy request outlives the sampling one: the last calls are
     # all-greedy again
-    assert pre == [1, 1] and dec[:NEW - 1] == [1] * (NEW - 1)
-    assert dec[NEW - 1:] == [0] * 3
+    assert pre == [1, 1] and dec[:NEW - 1 + lag] == [1] * (NEW - 1 + lag)
+    assert dec[NEW - 1 + lag:] == [0] * (3 - lag)
     (alone,), (pre, dec) = serve(Request(p1, NEW, seed=7, **SAMPLING))
     assert pre == [1, 1] and dec == [1] * (NEW - 1)
     np.testing.assert_array_equal(s, alone)
@@ -465,13 +486,16 @@ def test_one_sampling_row_among_greedy_rows(bundles):
     assert not np.array_equal(s, g1)  # the draw was a draw
 
 
-def test_a_late_samplers_draws_do_not_depend_on_the_ticks_before(bundles):
+@DISCIPLINES
+def test_a_late_samplers_draws_do_not_depend_on_the_ticks_before(bundles,
+                                                                 run_ahead):
     """The key stream: a request that starts sampling after N all-greedy
     ticks draws what it draws when its neighbour sampled throughout, and
     what it draws alone.  And a greedy slot's key advances on every call
-    it is in, though no call of its run drew from it."""
+    it is in, though no call of its run drew from it (with ``run_ahead``
+    the host holds the key of the last call it has BOOKED, one behind)."""
     b = bundles("dense")
-    eng = b["eng"]
+    eng = _discipline(b, run_ahead)
     p0, p1 = (p.tolist() for p in b["prompts"])
     N = 4
 
@@ -494,7 +518,7 @@ def test_a_late_samplers_draws_do_not_depend_on_the_ticks_before(bundles):
     want = jax.random.PRNGKey(3)
     for _ in range(emitted):
         want = jax.random.split(want, 2)[0]
-    assert emitted >= N - 1
+    assert emitted == N - 1 - int(run_ahead)
     np.testing.assert_array_equal(key, np.asarray(want))
     after_sampling, before, _ = late(**SAMPLING)
     assert before == [1] * len(before)

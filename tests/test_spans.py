@@ -113,7 +113,9 @@ def test_engine_spans_lie_on_the_profilers_clock(params, tmp_path):
 
     eng = _engine(params)
     eng.submit(Request(tokens=[1, 2, 3, 4, 5], max_new_tokens=8))
-    eng.step()   # compiles outside the capture
+    for _ in range(2):   # both compile outside the capture, and from the
+        eng.step()       # third tick on a decode call is in flight
+    assert eng.run_ahead and eng._flight is not None
     spans.clear()
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -231,12 +233,15 @@ def test_tick_phases_are_what_they_were_and_the_children_cover_the_tick(params):
     assert covered[len(covered) // 2] >= 0.90
 
 
-def test_a_dispatch_and_the_fetch_that_waits_for_it_share_a_call(params):
+@pytest.mark.parametrize("run_ahead", [False, None],
+                         ids=["serial", "run_ahead"])
+def test_a_dispatch_and_the_fetch_that_waits_for_it_share_a_call(params,
+                                                                 run_ahead):
     spans.clear()
-    eng = _engine(params)
+    eng = _engine(params, run_ahead=run_ahead)
     eng.prefill_width = 2
     for i in range(3):   # three prefilling slots: two calls under one span
-        eng.submit(Request(tokens=[1] * (3 + i), max_new_tokens=3))
+        eng.submit(Request(tokens=[1] * (3 + i), max_new_tokens=5))
     eng.run_until_idle(max_ticks=20)
     ring = sorted((r for r in spans.snapshot()
                    if r[2] in ("tdp:engine.prefill", "tdp:engine.decode",
@@ -245,12 +250,32 @@ def test_a_dispatch_and_the_fetch_that_waits_for_it_share_a_call(params):
     # LAST call's, and every fetch follows the dispatch it names
     assert ring[0][2:3] == ("tdp:engine.prefill",)
     assert (ring[0][5]["calls"], ring[0][5]["call"]) == (2, 2)
-    made = 0
-    for disp, fetch in zip(ring[::2], ring[1::2]):
-        assert fetch[2] == "tdp:engine.fetch" and disp[2] != fetch[2]
-        made += disp[5].get("calls", 1)
-        assert disp[5]["call"] == fetch[5]["call"] == made
-    assert made == eng.stats["prefill_calls"] + eng.stats["decode_steps"]
+    made = eng.stats["prefill_calls"] + eng.stats["decode_steps"]
+    disps = [r for r in ring if r[2] != "tdp:engine.fetch"]
+    fetches = [r for r in ring if r[2] == "tdp:engine.fetch"]
+    assert [d[5]["call"] for d in disps] == sorted(d[5]["call"]
+                                                   for d in disps)
+    assert disps[-1][5]["call"] == made
+    # every call is waited for once, in the device's order
+    assert [f[5]["call"] for f in fetches] == [d[5]["call"] for d in disps]
+    tick_of = {r[0]: r[5]["tick"] for r in spans.snapshot()
+               if r[2] == "tdp:engine.tick"}
+    by_call = {d[5]["call"]: d for d in disps}
+    lag = set()
+    for f in fetches:
+        d = by_call[f[5]["call"]]
+        assert d[4] <= f[3]
+        if d[2] == "tdp:engine.decode":
+            lag.add(tick_of[f[1]] - tick_of[d[1]])
+        else:   # the prefill calls are fetched in their own tick
+            assert tick_of[f[1]] == tick_of[d[1]]
+    # run_ahead: a decode call is fetched by the tick AFTER its dispatch,
+    # behind that tick's own decode dispatch span
+    assert lag == ({1} if eng.run_ahead else {0})
+    if not eng.run_ahead:   # serial: dispatch, fetch, dispatch, fetch
+        for disp, fetch in zip(ring[::2], ring[1::2]):
+            assert fetch[2] == "tdp:engine.fetch" and disp[2] != fetch[2]
+            assert disp[5]["call"] == fetch[5]["call"]
 
 
 # ---------------------------------------------- the ring's clock, collections
@@ -345,15 +370,18 @@ def test_prefill_span_counts_real_tokens_against_dispatched_rows(params):
     assert (s["prefill_chunks"], s["prefill_calls"]) == (4, 5)
 
 
-def test_a_wave_at_the_default_width_is_one_span_of_calls_and_one_fetch(params):
+@pytest.mark.parametrize("run_ahead", [False, None],
+                         ids=["serial", "run_ahead"])
+def test_a_wave_at_the_default_width_is_one_span_of_calls_and_one_fetch(
+        params, run_ahead):
     """An engine as it is constructed: a first wave of more prompts than
     ``PREFILL_WIDTH`` is ``ceil(n / W)`` calls of the one signature under
     ONE dispatch span, and one fetch behind them that names the last."""
     W = PREFILL_WIDTH
     n = 2 * W + 1
     eng = ServingEngine(params, CFG, num_slots=n, block_size=8, chunk=CHUNK,
-                        max_ctx=64)
-    assert eng.prefill_width == W
+                        max_ctx=64, run_ahead=run_ahead)
+    assert eng.prefill_width == W and eng.run_ahead == (run_ahead is None)
     spans.clear()
     rids = [eng.submit(Request(tokens=[1] * (2 + i % 5), max_new_tokens=4))
             for i in range(n)]
@@ -366,7 +394,13 @@ def test_a_wave_at_the_default_width_is_one_span_of_calls_and_one_fetch(params):
     assert pre[5]["tokens"] == sum(2 + i % 5 for i in range(n))
     # the wave's one fetch opens after the last dispatch returned
     assert [f[5]["call"] for f in fetches] == [3] and fetches[0][3] >= pre[4]
-    eng.step()   # the wave's first decode call, and its fetch
+    eng.step()   # the wave's first decode call, and (serial) its fetch
+    if eng.run_ahead:   # which the NEXT tick makes, behind its own dispatch
+        assert [f[5]["call"] for f in _by_name(
+            spans.snapshot(), "tdp:engine.fetch")] == [3]
+        eng.step()
+        assert _by_name(spans.snapshot(), "tdp:engine.decode")[-1][5][
+            "call"] == 5
     assert [f[5]["call"] for f in _by_name(spans.snapshot(),
                                            "tdp:engine.fetch")] == [3, 4]
     assert [s.state for s in eng._slots] == ["decode"] * n
@@ -461,24 +495,33 @@ def test_pool_span_says_the_chunks_tile(params, kw, want):
     assert "window_chunk_tile_keys" not in attrs
 
 
-def test_fetch_ends_at_the_tokens_and_telemetry_falls_into_host(params):
+@pytest.mark.parametrize("run_ahead", [False, None],
+                         ids=["serial", "run_ahead"])
+def test_fetch_ends_at_the_tokens_and_telemetry_falls_into_host(params,
+                                                                run_ahead):
     """``Telemetry.end_step`` is called after the fetch span has closed, and
-    its step record still holds the wait for the device."""
+    its step record still holds the wait for the device.  With ``run_ahead``
+    it closes the step of the call BEFORE the newest and waits for nothing
+    (``wait=False``): the newest call's outputs are never blocked on."""
     from torchdistpackage_tpu.obs import Telemetry
 
     last_closed = []
 
+    waited = []
+
     class Tel(Telemetry):
         def end_step(self, *a, **kw):
             last_closed.append(spans.snapshot()[-1][2])
+            waited.append(kw.get("wait", True))
             return super().end_step(*a, **kw)
 
     tel = Tel(run="t", sinks=[], poll_memory=False)
     spans.clear()
-    eng = _engine(params, telemetry=tel)
-    eng.submit(Request(tokens=[1, 2, 3], max_new_tokens=3))
+    eng = _engine(params, telemetry=tel, run_ahead=run_ahead)
+    eng.submit(Request(tokens=[1, 2, 3], max_new_tokens=5))
     eng.run_until_idle(max_ticks=20)
     assert last_closed and set(last_closed) == {"tdp:engine.fetch"}
+    assert set(waited) == {not eng.run_ahead}
     steps = [r for r in tel.history if r.get("type") == "step"]
     assert len(steps) == len(last_closed)
     # the device span runs from the dispatch's return, so it covers the

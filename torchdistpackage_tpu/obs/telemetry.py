@@ -463,6 +463,7 @@ class Telemetry:
         step: Optional[int] = None,
         *,
         numerics: Optional[Dict[str, Any]] = None,
+        wait: bool = True,
         **scalars: Any,
     ) -> Dict[str, Any]:
         """Close the step opened by the wrapped call: block on its outputs
@@ -471,6 +472,14 @@ class Telemetry:
         device there), fetch the passed scalars (fetch span), build the
         record, feed the sinks.  Returns the record with host floats — use
         ``rec["loss"]`` instead of a second ``float(loss)``.
+
+        ``wait=False``: the caller runs a call ahead (the serving engine's
+        ``run_ahead``): it closes the step BEFORE the newest wrapped call's,
+        whose outputs it has fetched itself, so nothing is blocked on, and
+        the device span is the time since the newest call returned (since
+        the ``end_step`` before, where no call was made since), which holds
+        the caller's wait for the step it closes.  The spans still sum to
+        the time from one ``end_step`` to the next.
 
         ``numerics``: the in-step :func:`~.numerics.numerics_stats` dict
         (device scalars).  It is fetched with the other scalars (same
@@ -483,11 +492,14 @@ class Telemetry:
         t0 = time.perf_counter()
         if self._pending_out is not None:
             t0 = self._dispatch_end
-            try:
-                jax.block_until_ready(self._pending_out)
-            except Exception:
-                pass
+            if wait:
+                try:
+                    jax.block_until_ready(self._pending_out)
+                except Exception:
+                    pass
             self._pending_out = None
+        elif not wait and self._last_fetch_end is not None:
+            t0 = self._last_fetch_end  # no call since the step before's end
         t1 = time.perf_counter()
         rec: Dict[str, Any] = {
             "type": "step",

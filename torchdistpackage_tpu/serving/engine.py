@@ -210,6 +210,9 @@ PREFILL_WIDTH = 4
 _MOE_CALL_STATS = ("moe_rows_routed", "moe_rows_held", "experts_touched",
                    "moe_layers_run", "moe_layers_batched",
                    "prefill_moe_layers_run", "prefill_moe_layers_batched")
+#: engine counters (``stats``, ``serving_summary()['tick_accounting']``) that
+#: every tick record carries as the tick's own count
+_TICK_COUNTS = ("late_joins", "ahead_rows", "flight_dropped")
 
 
 @dataclasses.dataclass
@@ -277,6 +280,18 @@ def _split_keys(keys: jnp.ndarray):
     """[B, 2] uint32 -> (carried keys, this step's sample keys)."""
     ks = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
     return ks[:, 0], ks[:, 1]
+
+
+def _take_prev(tokens: jnp.ndarray, keys: jnp.ndarray, prev: Optional[Tuple]):
+    """``run_ahead``'s decode call: ``prev = (tok, keys, take)`` is the call
+    before's sampled tokens and advanced keys as they lie on the device, and
+    the rows that take theirs from there (``take > 0``); every other row's
+    come from the host.  None (a prefill call): all of them do."""
+    if prev is None:
+        return tokens, keys
+    take = prev[2][:, None] > 0
+    return (jnp.where(take, prev[0][:, None], tokens),
+            jnp.where(take, prev[1], keys))
 
 
 def _filtered_logits(
@@ -461,19 +476,45 @@ class ServingEngine:
         ``finished[rid]['routing']`` is the LIST of the calls' pieces
         ``[positions, width]`` as they were fetched: putting 145 MB a
         request together is the reader's, not a serving tick's.
-    run_ahead: a state model only: the decode call of a tick is dispatched
-        BEFORE the call of the tick before it is fetched.  A slot whose
-        newest token is still on the device is fed it (and its sampling
-        key) from there, so the host's walk over the slots, its tick
-        record, the caller's loop, audit, admission and the building of
-        the next call's arrays run while the device computes and not
-        between its calls.  Every sequence's tokens are the ones the
-        unpipelined engine gives; what the host sees (``finished``, the
-        tick records' ``emitted_tokens``) lags one decode call, so a slot
-        freed by a retirement is filled one tick later.  A slot whose
-        in-flight token is its last by count sits the next call out; one
-        that ends on ``eos_id``, is cancelled, preempted or requeued with a
-        token in flight has that token dropped when it arrives.
+    run_ahead: the decode discipline, which the engine chooses itself
+        (``None``, the default).  Ahead: the decode call of a tick is
+        dispatched BEFORE the call of the tick before it is fetched.  A slot
+        whose newest token is still on the device is fed it (and its
+        sampling key) from there (:func:`_take_prev`), so the host's walk
+        over the slots, its tick record, the caller's loop, audit,
+        admission and the building of the next call's arrays run while the
+        device computes and not between its calls.  Every sequence's
+        tokens, greedy or sampled, are the ones the serial engine gives;
+        what the host sees (``finished``, the tick records'
+        ``emitted_tokens``) lags one decode call, so a slot freed by a
+        retirement is filled one tick later.  A slot whose in-flight token
+        is its last by count sits the next call out.  One that leaves
+        DECODE with a token in flight (it ends on ``eos_id``, is cancelled,
+        preempted, requeued after a poisoned token or a failed audit,
+        exported, drained) has that token dropped when it arrives
+        (``stats['flight_dropped']``; ``stats['ahead_rows']`` counts the
+        rows that took their token from the device) and goes on from what
+        the host had: a descriptor carries the tokens booked so far and the
+        key that samples the next, so the importer or the replay computes
+        the dropped token again, the same one.  The pool is donated and
+        chained call to call, so what is dispatched behind a call in flight
+        (a prefill into a freed slot's blocks, a copy-on-write, a
+        migration's copy) runs behind it on the device; a retired slot's
+        last in-flight row writes at a position past its prompt, never into
+        a block the prefix cache has registered.
+        ``None`` means ahead wherever the decode step takes ``prev`` and
+        runs beside the host: every single-device engine, dense, MoE,
+        window, ``kv_quant``, ``prefix_cache`` and state model alike.  The
+        engine keeps the SERIAL order (dispatch, fetch, walk inside one
+        tick), chosen from its own constructor arguments, with ``spec_k``
+        (the next draft needs this tick's tokens), with a ``mesh`` or
+        ``cp_axis`` (the ``shard_map``'d steps' specs carry no ``prev``)
+        and with a ``host_only`` ``device_step`` (its step runs in the
+        caller's thread, so nothing can run beside it); ``hold_decode`` has
+        no decode call at all.  An explicit ``True`` there raises; an
+        explicit ``False`` is the serial engine, the oracle that the tests
+        hold the other to.  (The keyword stays because the benchmark's
+        family runner passes it: ROADMAP queue 3 item 4(i).)
     """
 
     @scope_decorator(name="tdp:engine.init")
@@ -506,7 +547,7 @@ class ServingEngine:
         tick_history: int = 4096,
         device_step: Optional[Any] = None,
         record_routing: bool = False,
-        run_ahead: bool = False,
+        run_ahead: Optional[bool] = None,
     ) -> None:
         if (axis is not None or dp_axis is not None) and mesh is None:
             raise ValueError("axis/dp_axis need a mesh")
@@ -580,12 +621,10 @@ class ServingEngine:
                 raise ValueError(
                     f"chunk ({chunk}) must be at most the model's "
                     f"recurrence chunk ({q}) or a multiple of it")
-        elif record_routing or run_ahead:
+        elif record_routing:
             raise NotImplementedError(
-                "record_routing and run_ahead are written for the state "
-                "model's step only")
+                "record_routing is written for the state model's step only")
         self.record_routing = bool(record_routing)
-        self.run_ahead = bool(run_ahead)
         #: run_ahead: the decode call whose outputs are still on the device
         #: (:meth:`_absorb_decode` books them one tick later)
         self._flight: Optional[Dict[str, Any]] = None
@@ -710,9 +749,27 @@ class ServingEngine:
 
         if device_step is None:
             device_step = CompiledDeviceStep()
-        if getattr(device_step, "host_only", False) and mesh is not None:
+        host_only = getattr(device_step, "host_only", False)
+        if host_only and mesh is not None:
             raise ValueError(
                 "a host-only DeviceStep cannot shard a pool over a mesh")
+        # the decode discipline is the engine's own choice (docstring,
+        # ``run_ahead``): ahead wherever the decode step has a ``prev`` form
+        # that runs beside the host
+        serial = next((why for on, why in (
+            (spec_k, "spec_k (the next draft needs this tick's tokens)"),
+            (cp_axis is not None, "cp_axis (the ring step takes no prev)"),
+            (mesh is not None, "a mesh (the shard_map'd step's specs carry "
+                               "no prev)"),
+            (host_only, "a host-only DeviceStep (its step runs in the "
+                        "caller's thread: nothing can run beside it)"),
+        ) if on), None)
+        if run_ahead and serial:
+            raise NotImplementedError(
+                f"run_ahead with {serial} is not supported: such an engine "
+                f"keeps the serial order")
+        self.run_ahead = serial is None if run_ahead is None else bool(
+            run_ahead)
         #: the device-program seam (serving/sim.py): compiled pair or
         #: host-only stub — every device touch below goes through it
         self.device_step = device_step
@@ -839,7 +896,10 @@ class ServingEngine:
         prefilling) — two signatures of the same program, compiled once
         each.  The row count comes from ``tokens.shape[0]`` and the pool
         is reached through ``tables`` alone, so nothing here is
-        ``num_slots`` wide.
+        ``num_slots`` wide.  A decode call with ``run_ahead`` also takes
+        ``prev`` (:func:`_take_prev`: always, ``_no_flight``'s zeros on the
+        first, so the decode signature stays one program); a prefill call
+        never does.
 
         The pool is a DONATED argument of every program that takes it
         (this one, the state, mesh, ring, verify and copy-on-write
@@ -857,7 +917,9 @@ class ServingEngine:
             return self._build_state_step()
         fwd = self._fwd(moe_stats=moe)
 
-        def step(params, cache, tokens, tables, offsets, last_idx, samp, keys):
+        def step(params, cache, tokens, tables, offsets, last_idx, samp, keys,
+                 prev=None):
+            tokens, keys = _take_prev(tokens, keys, prev)
             if moe:
                 cache, logits, mstats = fwd(
                     params, tokens, cfg, cache, tables, offsets,
@@ -906,9 +968,7 @@ class ServingEngine:
         expert layers that ran batched]`` (one vector: a transfer costs by
         the array) and, with ``record_routing``, every position's chosen
         experts.
-        ``prev`` (``run_ahead``'s decode call): ``(tok, keys, take)``, the
-        call before's sampled tokens and advanced keys as they lie on the
-        device, and the rows that take theirs from there."""
+        ``prev``: as in :meth:`_build_step` (:func:`_take_prev`)."""
         from .paged_cache import paged_forward_hybrid
 
         cfg, attn_impl = self.cfg, self.attn_impl
@@ -917,10 +977,7 @@ class ServingEngine:
 
         def step(params, cache, state, tokens, tables, offsets, last_idx,
                  samp, keys, rows, n_valid, prev=None):
-            if prev is not None:
-                take = prev[2][:, None] > 0
-                tokens = jnp.where(take, prev[0][:, None], tokens)
-                keys = jnp.where(take, prev[1], keys)
+            tokens, keys = _take_prev(tokens, keys, prev)
             cache, state, logits, m = paged_forward_hybrid(
                 params, tokens, cfg, cache, state, tables, offsets, n_valid,
                 rows=rows, last_idx=last_idx, attn_impl=attn_impl)
@@ -1994,6 +2051,7 @@ class ServingEngine:
         if self.run_ahead:
             args += ((flight or self._no_flight)["out"][:2]
                      + (ahead.astype(np.int32),),)
+            self.stats["ahead_rows"] += int(ahead.sum())
         self._call += 1
         attrs = dict(slots=n_active, rids=self._tick_decode_rids,
                      live_tokens=int(offsets.sum()) + n_active,
@@ -2103,11 +2161,16 @@ class ServingEngine:
             routing = np.asarray(out[5]) if len(out) > 5 else None
         with span("tdp:engine.absorb"):
             if self.telemetry is not None:
-                self.telemetry.end_step(active_slots=len(call["slots"]))
+                # run_ahead: the newest wrapped call is the one just
+                # dispatched, and nobody waits for that here
+                self.telemetry.end_step(active_slots=len(call["slots"]),
+                                        wait=not self.run_ahead)
             if self.chaos is not None:
                 tok = self.chaos.perturb_engine_tokens(self._tick, tok)
             now = time.perf_counter()
-            for i, s in self._still(call["slots"], DECODE):
+            still = list(self._still(call["slots"], DECODE))
+            self.stats["flight_dropped"] += len(call["slots"]) - len(still)
+            for i, s in still:
                 if routing is not None:  # record_routing
                     s.routing.append(routing[i])
                 if self._token_poisoned(int(tok[i])):
@@ -2121,6 +2184,13 @@ class ServingEngine:
                 s.tpot_s.append(now - s.t_last)
                 s.t_last = now
                 self._maybe_retire(i, int(tok[i]), now)
+
+    def _drop_flight(self) -> None:
+        """``run_ahead``: forget the decode call in flight once every slot
+        it was built from has left; its tokens are never fetched."""
+        if self._flight is not None:
+            self.stats["flight_dropped"] += len(self._flight["slots"])
+            self._flight = None
 
     # ------------------------------------------------------ speculative decode
 
@@ -2557,7 +2627,7 @@ class ServingEngine:
             self._tick_moe = dict.fromkeys(_MOE_CALL_STATS, 0.0)
             self._tick_dsa = [0, 0]
             self._tick_window = [0, 0]
-            joins_before = self.stats["late_joins"]
+            before = {k: self.stats[k] for k in _TICK_COUNTS}
             if self.chaos is not None:
                 self.chaos.before_engine_tick(self._tick, self)
             self.stats["audits"] += 1
@@ -2584,7 +2654,7 @@ class ServingEngine:
             with span("tdp:engine.record"):
                 busy = self.n_busy
                 if not busy:
-                    self._flight = None  # run_ahead: nobody left to take it
+                    self._drop_flight()  # run_ahead: nobody left to take it
                 self._occ_sum += busy / self.num_slots
                 util = float(np.mean(
                     [a.utilization() for a in self._allocs]))
@@ -2610,15 +2680,15 @@ class ServingEngine:
                                   expired=expired, prefilled=prefilled,
                                   decoded=decoded, busy=busy, util=util,
                                   queued_whole=queued_whole,
-                                  late_joins=(self.stats["late_joins"]
-                                              - joins_before))
+                                  counts={k: self.stats[k] - n
+                                          for k, n in before.items()})
         return {"admitted": admitted, "prefill_slots": prefilled,
                 "decode_slots": decoded, "busy": busy, "expired": expired}
 
     def _record_tick(self, tick: span, t_end: float, *, admitted: int,
                      expired: int, prefilled: int, decoded: int, busy: int,
                      util: float, queued_whole: bool,
-                     late_joins: int) -> None:
+                     counts: Dict[str, int]) -> None:
         """The tick-level accounting record: the phase decomposition,
         summed from the tick's child spans (the residual ``host`` phase is
         everything the six phase spans did not cover — queue sorts, table
@@ -2654,9 +2724,13 @@ class ServingEngine:
             "pool_util": round(util, 4),
             "emitted_tokens": self._tick_emitted,
             # the decode call was dispatched before the prefill calls were
-            # fetched; slots whose prompt ended here and wait for the next
+            # fetched
             "queued_whole": queued_whole,
-            "late_joins": late_joins,
+            # late_joins: slots whose prompt ended here and wait for the
+            # next decode call; run_ahead: ahead_rows, the rows of this
+            # tick's decode call that took their token from the device, and
+            # flight_dropped, in-flight tokens dropped on arrival
+            **counts,
             "prefix_hit_rate": round(
                 st["prefix_cached_tokens"] / st["prefix_prompt_tokens"], 4)
             if st["prefix_prompt_tokens"] else 0.0,
@@ -2741,7 +2815,9 @@ class ServingEngine:
         are shed with reason ``draining``) and unwind every in-flight slot
         and queued request into restartable descriptors — prompt, emitted
         tokens, sampling params, the carried PRNG key.  Blocks are freed
-        and slots reset, so the engine is idle afterwards.
+        and slots reset, so the engine is idle afterwards (``run_ahead``'s
+        call in flight is forgotten: its tokens were not booked, and the
+        replay samples them again from the carried key).
 
         ``persist_path`` writes the payload as JSON plus a
         ``<path>.manifest.json`` SHA-256 sidecar (the ``ckpt_guard``
@@ -2773,6 +2849,7 @@ class ServingEngine:
             self._inject.pop(s.rid, None)
             self._ttft_pred.pop(s.rid, None)
             s.reset()
+        self._drop_flight()  # run_ahead: the descriptors hold what was booked
         n_queued = len(self.queue)
         for req, _t in self.queue:
             inj = self._inject.pop(req.rid, None)
@@ -2910,7 +2987,11 @@ class ServingEngine:
         and deliver of every transport, and the bounce back into this
         engine) has to be dispatched before this engine steps again, and
         a caller that wants the bytes for longer copies them out first
-        (``ChunkedWireTransport.fetch`` does).  The slot is released
+        (``ChunkedWireTransport.fetch`` does).  With ``run_ahead`` the pool
+        is the one the decode call in flight hands back (a read of it waits
+        for that call), and the slot's token in flight is dropped when it
+        arrives: the descriptor holds what was booked, and the importer's
+        first step computes that token again.  The slot is released
         immediately (blocks freed refcount-aware, rows cleared) — the
         request now lives only in the descriptor, which the router must
         either import somewhere or resume (never both: the
@@ -3179,7 +3260,8 @@ class ServingEngine:
                       "imports_aborted": 0,
                       "cp_ring_hops": 0, "cp_ring_bytes": 0,
                       "blocks_handed_on": 0,
-                      "ticks_queued_whole": 0, "late_joins": 0,
+                      "ticks_queued_whole": 0,
+                      **dict.fromkeys(_TICK_COUNTS, 0),
                       **dict.fromkeys(_MOE_CALL_STATS, 0.0)}
         self._decode_sigs: set = set()
         self._prefill_sigs: set = set()
@@ -3363,7 +3445,9 @@ class ServingEngine:
             "ticks_prefill_and_decode": sum(
                 1 for t in ticks if t["prefill_slots"] and t["decode_slots"]),
             "ticks_queued_whole": st["ticks_queued_whole"],
-            "late_joins": st["late_joins"],
+            # and with run_ahead the rows of the decode calls that took their
+            # token from the device, the in-flight tokens dropped on arrival
+            **{k: st[k] for k in _TICK_COUNTS},
         }
         # --- live expert-load (MoE families): moe_load_stats over the
         # accumulated per-expert routed-token counts.  The overflow
