@@ -589,6 +589,70 @@ def test_chunk_keeps_one_head_a_step(fw):
                       + [(1, hkv, 8, hd)])
 
 
+#: unequal widths (keys 24 wide transposed in the pool, values 16), GQA 8 /
+#: 2 or 8 / 4 over a table of six blocks of 8: name -> (KV heads, rows a
+#: slot, window, a sink?).  Four query rows a head or fewer: the in-kernel
+#: walk; 4 x 40 rows a head: the grid's walk (``shape_walk``'s ``T``)
+WIDE_CASES = {
+    "window-sink-decode": (4, 1, 8, True),
+    "window-sink-verify": (4, 3, 8, True),
+    "window-sink-chunk": (2, 40, 8, True),
+    "global-decode": (2, 1, None, False),
+    "global-chunk": (2, 40, None, False),
+    "global-sink-chunk": (2, 40, None, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_CASES))
+def test_unequal_widths_and_a_sink_match_the_gathered_oracle(case):
+    """Both walks on a pool whose K leaf lies transposed (``[.., 24, bs]``
+    beside V's ``[.., bs, 16]``), with a sink a query head in the softmax's
+    denominator and a window of ONE block, against the gathered oracle and
+    against dense attention spelled out (the sink one more column that is
+    dropped): slots at their own depths, the first inside its first block."""
+    from torchdistpackage_tpu.ops.paged_attention import shape_walk
+    from torchdistpackage_tpu.serving.paged_cache import paged_write
+
+    hkv, s_in, window, sunk = WIDE_CASES[case]
+    B, H, hd, hv, bs, mb = 3, 8, 24, 16, 8, 6
+    *_, T = shape_walk(H // hkv, s_in, hkv, mb, bs, bs * 20 * 4, window)
+    assert bool(T) == (s_in <= 3), (case, T)
+    rs = np.random.RandomState(7)
+    tables = jnp.asarray(1 + rs.permutation(B * mb).reshape(B, mb), jnp.int32)
+    kk = jnp.asarray(rs.randn(B, hkv, mb * bs, hd), jnp.float32)
+    vv = jnp.asarray(rs.randn(B, hkv, mb * bs, hv), jnp.float32)
+    zero = jnp.zeros(B, jnp.int32)
+    kp = paged_write(jnp.full((2, 1 + B * mb, hkv, hd, bs), jnp.nan),
+                     kk, zero, tables=tables, layer=1, transposed=True)
+    vp = paged_write(jnp.full((2, 1 + B * mb, hkv, bs, hv), jnp.nan),
+                     vv, zero, tables=tables, layer=1)
+    q = jnp.asarray(rs.randn(B, H, s_in, hd), jnp.float32)
+    sink = jnp.asarray(rs.randn(H) + 1.0, jnp.float32) if sunk else None
+    offs = jnp.asarray([3, 8, 48 - s_in], jnp.int32)
+    got = {impl: np.asarray(paged_attention(
+        q, kp, vp, offs, tables=tables, window=window, impl=impl, layer=1,
+        sink=sink)) for impl in ("gather", "pallas")}
+    assert got["pallas"].shape == (B, H, s_in, hv)
+    qpos = np.asarray(offs)[:, None] + np.arange(s_in)
+    kpos = np.arange(mb * bs)
+    want = np.zeros((B, H, s_in, hv))
+    for b in range(B):
+        for h in range(H):
+            sc = np.asarray(q[b, h]) @ np.asarray(kk[b, h * hkv // H]).T
+            keep = kpos[None] <= qpos[b][:, None]
+            if window:
+                keep &= kpos[None] > qpos[b][:, None] - window
+            sc = np.where(keep, sc / np.sqrt(hd), -np.inf)
+            if sunk:
+                sc = np.concatenate(
+                    [sc, np.full((s_in, 1), float(sink[h]))], -1)
+            e = np.exp(sc - sc.max(-1, keepdims=True))
+            pr = (e / e.sum(-1, keepdims=True))[:, :mb * bs]
+            want[b, h] = pr @ np.asarray(vv[b, h * hkv // H])
+    for impl in got:
+        np.testing.assert_allclose(got[impl], want, atol=3e-6, err_msg=impl)
+
+
 @pytest.mark.parametrize("fw", (1, 2, 3, 4, 6))
 def test_fetch_rule_asks_for_live_blocks_only(fw):
     """The mechanism's counter, without a chip: walk the grid in its order

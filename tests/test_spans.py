@@ -575,6 +575,24 @@ def _kernel_cases():
                  S((2, 1 + B * mb, 4, bs, 128), bf),
                  S((B, mb), jnp.int32), S((B,), jnp.int32)))
 
+    def wide(s_in, hkv, window):
+        """MiMo-V2's two kinds of layer: 64 query heads of 192 over values
+        of 128, the K pool transposed (``[.., 192, bs]``); a window layer
+        (8 KV heads, a window of one block of a table of eight, a sink a
+        query head) or a global one (4 KV heads, no sink)."""
+        from torchdistpackage_tpu.ops.paged_attention import (
+            paged_decode_attention)
+
+        args = (S((B, 64, s_in, 192), bf), S((2, 1 + B * 8, hkv, 192, bs), bf),
+                S((2, 1 + B * 8, hkv, bs, 128), bf), S((B, 8), jnp.int32),
+                S((B,), jnp.int32))
+        if window is None:
+            return (lambda q, k, v, t, o: paged_decode_attention(
+                q, k, v, t, o, layer=1), args)
+        return (lambda q, k, v, t, o, sink: paged_decode_attention(
+            q, k, v, t, o, layer=1, window=window, sink=sink),
+                args + (S((64,), jnp.float32),))
+
     def carry():
         from torchdistpackage_tpu.ops.paged_attention import (
             paged_carry_attention)
@@ -604,18 +622,26 @@ def _kernel_cases():
         "paged_carry": carry,
         "mla_decode": lambda: latent(1),
         "mla_chunk": lambda: latent(64),
+        "swa_decode-hd192": lambda: wide(1, 8, 128),
+        "swa_chunk-hd192": lambda: wide(512, 8, 128),
+        "paged_decode-hd192": lambda: wide(1, 4, None),
+        "paged_chunk-hd192": lambda: wide(512, 4, None),
     }
 
 
 @pytest.mark.parametrize("kernel", [
     "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
     "paged_chunk", "paged_decode-hd64", "paged_chunk-hd64", "paged_carry",
-    "mla_decode", "mla_chunk"])
+    "mla_decode", "mla_chunk", "swa_decode-hd192", "swa_chunk-hd192",
+    "paged_decode-hd192", "paged_chunk-hd192"])
 def test_kernel_lowers_under_its_name(monkeypatch, kernel):
     """XLA names a Mosaic custom call after the name-stack component before
     ``pallas_call``: that is the kernel's ``name=``, which the device
     trace then shows (``%flash_fwd.1 = ... custom-call``).  Lowered for TPU
-    from the CPU: every kernel the package holds lowers for the chip."""
+    from the CPU: every kernel the package holds lowers for the chip.  The
+    cases at unequal widths (``-hd192``) are also COMPILED, for a described
+    v5e: Mosaic takes the transposed key tile, the contraction over 192 and
+    the sinks as a fourth scalar operand, and no copy carries a pool."""
     fn, args = _kernel_cases()[kernel]()
     # every module imported BEFORE any is patched: two of them take their
     # `_interpret` from flash_attention as they are imported, and one first
@@ -632,3 +658,17 @@ def test_kernel_lowers_under_its_name(monkeypatch, kernel):
     assert kernel.split("-")[0] in named
     # no kernel under another name
     assert named <= {k.split("-")[0] for k in _kernel_cases()}
+    if kernel.endswith("-hd192"):
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        from torchdistpackage_tpu.ops import paged_attention as P
+
+        monkeypatch.setattr(P, "default_paged_params",
+                            lambda: P.paged_params_for("TPU v5 lite"))
+        one = SingleDeviceSharding(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0])
+        hlo = jax.jit(fn).lower(*(jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one) for a in args)).compile().as_text()
+        assert re.search(rf"%{kernel.split('-')[0]}[.0-9]* = ", hlo)
+        assert not re.search(r"= bf16\[2,17,\d,\d+,128\]\S* copy\(", hlo)

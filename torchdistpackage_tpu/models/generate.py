@@ -110,7 +110,8 @@ def _cache_write(c, val: jnp.ndarray, offset):
 
 
 def _cached_attention(q: jnp.ndarray, ck, cv, offset, window=None,
-                      sm_scale: Optional[float] = None) -> jnp.ndarray:
+                      sm_scale: Optional[float] = None,
+                      sink: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Grouped-query attention of q [B, H, S_in, hd] against the full cache
     ck/cv [B, Hkv, T, hd], masked to ``key_pos <= offset + query_row``.
     f32 softmax, 1/sqrt(hd) scale (or ``sm_scale``) — the mha_reference
@@ -126,7 +127,12 @@ def _cached_attention(q: jnp.ndarray, ck, cv, offset, window=None,
     Quantized caches pass ``(q8, scale)`` pairs: the int8 payload is upcast
     in-register and the per-position scale folds into the scores (k) or
     the probabilities (v) — both exact because the scale is constant along
-    the contracted hd dim, so HBM only ever moves int8 cache bytes."""
+    the contracted hd dim, so HBM only ever moves int8 cache bytes.
+
+    ``cv`` may be narrower than ``ck`` ([B, Hkv, T, hv]): the output rows
+    are the values' width.  ``sink`` [H] float32: one more column of every
+    row's softmax (the scalar of the row's query head), dropped before the
+    probabilities meet the values."""
     B, H, S_in, hd = q.shape
     k_scale = v_scale = None
     if isinstance(ck, tuple):
@@ -151,7 +157,14 @@ def _cached_attention(q: jnp.ndarray, ck, cv, offset, window=None,
     if mask.ndim == 2:  # scalar offset: broadcast over the batch
         mask = mask[None]
     s = jnp.where(mask[:, None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, Hkv, g, 1, 1),
+            s.shape[:-1] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, col], axis=-1),
+                           axis=-1)[..., :-1]
     if v_scale is not None:
         p = p * v_scale[:, :, None, None, :]
         out = jnp.einsum("bkgqt,bkth->bkgqh", p, cv.astype(jnp.float32))
@@ -159,7 +172,7 @@ def _cached_attention(q: jnp.ndarray, ck, cv, offset, window=None,
     else:
         p = p.astype(cv.dtype)
         out = jnp.einsum("bkgqt,bkth->bkgqh", p, cv)
-    return out.reshape(B, H, S_in, hd)
+    return out.reshape(B, H, S_in, cv.shape[-1])
 
 
 def cached_block_forward(

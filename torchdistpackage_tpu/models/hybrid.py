@@ -27,13 +27,29 @@ layer is ONE mixer and not attention + MLP (the ``nemotron_h`` shape):
   head, half-split pairs): a query at ``t`` reads the keys in ``(t -
   window, t]`` and nothing before them.  It stands beside ``*`` layers in
   one model (three window layers to one global layer, say), and the two
-  kinds keep different amounts of cache: the model has TWO block pools of
-  one block shape, the ``*`` layers' holding every position of a sequence
-  and the ``W`` layers' the last ``window`` (+ a call's rows) alone
+  kinds keep different amounts of cache: the model has TWO block pools (of
+  one block shape, or of two: below), the ``*`` layers' holding every
+  position of a sequence and the ``W`` layers' the last ``window`` (+ a
+  call's rows) alone
   (serving/paged_cache.py, docs/serving.md "Two pools").  Both kinds take
   two optional sets of leaves: ``wg`` (an output gate, ``y = (o *
   sigmoid(x W_g)) W_o``) and ``q_norm`` / ``k_norm`` (a learned RMSNorm
-  over each query head and each key head, before the rotation);
+  over each query head and each key head, before the rotation).  What a
+  model may state further of the two kinds, each defaulting to the above
+  (MiMo-V2's block states all of it): value heads NARROWER than key heads
+  (``v_head_dim``: 64 query heads of 192 over values of 128; the three
+  projections are then ONE leaf ``wqkv``, and the pool lays such keys
+  transposed, serving/paged_cache.py "Unequal widths"); ANOTHER number of
+  KV heads in the window layers (``window_kv_heads``: the two pools then
+  have two block shapes); rotation of a head's leading ``rope_dims`` alone;
+  a theta a kind (``rope_theta`` the ``W`` layers', ``global_rope_theta``
+  the ``*`` layers', which are then rotated too); ``value_scale`` (the
+  value states are multiplied by it before they are cached, rounded once);
+  and a ``sink`` leaf ``[nheads]`` float32 on a layer of either kind: one
+  more column of every row's softmax, ``p_j = exp(s_j) / (exp(sink_h) +
+  sum_i exp(s_i))``, which takes its share of the mass and gives no value
+  (the online softmax of both paged walks starts at ``(m, l, acc) =
+  (sink_h, 1, 0)``, ops/paged_attention.py);
 - ``S``  indexed (sparse) attention (:func:`indexed_attention_mixer`):
   rotated GQA with a learned norm a head, behind an INDEXER that scores
   every cached position with a second, small key (one ``idx_dim`` row a
@@ -164,6 +180,20 @@ class HybridConfig:
     mrope_section: Optional[Tuple[int, ...]] = None
     #: windowed attention ('W'): the keys a query reads, itself included
     window: int = 0
+    #: a VALUE head's width in the '*' / 'W' layers (0: ``head_dim``, the
+    #: key's); narrower values: one ``wqkv`` leaf, keys transposed in the pool
+    v_head_dim: int = 0
+    #: the 'W' layers' KV heads (0: ``kv_heads``, the '*' layers')
+    window_kv_heads: int = 0
+    #: leading dims of a query / key head that a '*' / 'W' layer rotates
+    #: (0: the whole head)
+    rope_dims: int = 0
+    #: the '*' layers' rope theta (None: they carry no positions);
+    #: ``rope_theta`` is the 'W' layers'
+    global_rope_theta: Optional[float] = None
+    #: what the '*' / 'W' layers' value states are multiplied by before they
+    #: are cached (1: as projected)
+    value_scale: float = 1.0
     #: what the embedding's rows are multiplied by (1: as they lie)
     embed_scale: float = 1.0
     #: what every layer's output is multiplied by before it joins the
@@ -212,6 +242,17 @@ class HybridConfig:
             if self.dim % self.nheads:
                 raise ValueError("dim does not divide by nheads: say head_dim")
             object.__setattr__(self, "head_dim", self.dim // self.nheads)
+        if (self.v_head_dim or self.window_kv_heads or self.rope_dims
+                or self.global_rope_theta is not None):
+            if set("CSL") & set(self.pattern):
+                raise ValueError(
+                    "v_head_dim, window_kv_heads, rope_dims and "
+                    "global_rope_theta are the '*' / 'W' layers' alone")
+            if (self.nheads % self.kv_heads or self.nheads % self.window_heads
+                    or self.rope_dims % 2 or self.rope_dims > self.head_dim):
+                raise ValueError(
+                    "KV heads of either kind must divide nheads, and "
+                    "rope_dims be even within a head")
         if "C" in self.pattern and (
                 self.kv_heads % 2 or self.nheads % self.kv_heads
                 or min(self.cca_time0, self.cca_time1) < 1
@@ -268,6 +309,16 @@ class HybridConfig:
         return self.pattern.count("W")
 
     @property
+    def value_width(self) -> int:
+        """A value head's width in the '*' / 'W' layers."""
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def window_heads(self) -> int:
+        """The 'W' layers' KV heads."""
+        return self.window_kv_heads or self.kv_heads
+
+    @property
     def latent_width(self) -> int:
         """What one position caches in an 'L' layer (0: a K/V pool)."""
         return (self.mla_latent + self.mla_rope) if "L" in self.pattern else 0
@@ -292,7 +343,8 @@ class HybridConfig:
         attention read their pools with kernels of their own: 1."""
         hd = self.head_dim
         if (set("SL") & set(self.pattern) or not 0 < hd < 128 or 128 % hd
-                or self.kv_heads % (128 // hd)):
+                or self.kv_heads % (128 // hd) or self.value_width != hd
+                or self.window_heads != self.kv_heads):
             return 1
         return 128 // hd
 
@@ -519,34 +571,66 @@ def mamba2_mixer(
 def attention_mixer(p, x, cfg: HybridConfig, ck, cv, offset, cache_ops,
                     window: Optional[int] = None):
     """GQA on a block pool: x [B, S, D] (normed) -> (y, ck, cv).  ``window``
-    None: a ``*`` layer, position-free, every key ``<= t``; else a ``W``
-    layer: queries and keys rotated over the whole head, keys in ``(t -
-    window, t]``.  Leaves ``q_norm`` / ``k_norm``: a learned RMSNorm over
-    each query and key head (before the rotation); ``wg``: an output gate,
-    ``y = (o * sigmoid(x W_g)) W_o``.  ``cache_ops`` is the ``(write,
-    attend)`` pair of ``serving/paged_cache.py`` for the layer's own pool,
-    as ``cached_block_forward`` takes it."""
+    None: a ``*`` layer, every key ``<= t``, position-free unless the model
+    states ``global_rope_theta``; else a ``W`` layer: queries and keys
+    rotated by ``rope_theta``, keys in ``(t - window, t]``.  A rotation
+    turns the whole head, or its leading ``rope_dims`` (half-split pairs
+    within them, the rest as it lies).  Leaves ``q_norm`` / ``k_norm``: a
+    learned RMSNorm over each query and key head (before the rotation);
+    ``wg``: an output gate, ``y = (o * sigmoid(x W_g)) W_o``; ``wqkv`` in
+    place of ``wq`` and ``wkv``: the three projections side by side, ``[q
+    | k | v]``, the one layout that holds value heads of another width than
+    the key heads (``v_head_dim``; the KV heads are counted from the leaf);
+    ``sink`` [nheads] float32: one more column of every row's softmax, which
+    gives no value.  The value states are multiplied by ``value_scale`` in
+    float32 and rounded once, BEFORE they are cached: folded into ``W_v`` or
+    ``W_o`` it would round a weight the reference does not round.
+    ``cache_ops`` is the ``(write, attend)`` pair of
+    ``serving/paged_cache.py`` for the layer's own pool, as
+    ``cached_block_forward`` takes it."""
     B, S, _ = x.shape
-    hd = cfg.head_dim
+    hd, hv = cfg.head_dim, cfg.value_width
     write, attend = cache_ops
-    q = dense(x, p["wq"]).reshape(B, S, -1, hd)
-    kv = dense(x, p["wkv"], "bsd,tdh->tbsh")
-    k = kv[0].reshape(B, S, -1, hd)
+    if "wqkv" in p:
+        qkv = dense(x, p["wqkv"])
+        dq = cfg.nheads * hd
+        dk = (qkv.shape[-1] - dq) // (hd + hv) * hd
+        q = qkv[..., :dq].reshape(B, S, -1, hd)
+        k = qkv[..., dq:dq + dk].reshape(B, S, -1, hd)
+        v = qkv[..., dq + dk:].reshape(B, S, -1, hv)
+    else:
+        q = dense(x, p["wq"]).reshape(B, S, -1, hd)
+        kv = dense(x, p["wkv"], "bsd,tdh->tbsh")
+        # v is taken out behind the transposes below, where it always was:
+        # the other models' traces keep their order of operations
+        k, v = kv[0].reshape(B, S, -1, hd), None
     if "q_norm" in p:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
-    v = kv[1].reshape(B, S, -1, hd).transpose(0, 2, 1, 3)
-    if window is not None:
+    if v is None:
+        v = kv[1].reshape(B, S, -1, hd)
+    v = v.transpose(0, 2, 1, 3)
+    if cfg.value_scale != 1.0:
+        v = (v.astype(F32) * cfg.value_scale).astype(v.dtype)
+    theta = cfg.rope_theta if window is not None else cfg.global_rope_theta
+    if theta is not None:
+        rd = cfg.rope_dims or hd
         pos = offset[:, None] + jnp.arange(S)[None, :]
-        cos, sin = rope_cache(pos.reshape(-1), hd, cfg.rope_theta,
+        cos, sin = rope_cache(pos.reshape(-1), rd, theta,
                               scaling=cfg.rope_scaling)
         rope = (cos.reshape(B, 1, S, -1), sin.reshape(B, 1, S, -1))
-        q, k = apply_rope(q, cache=rope), apply_rope(k, cache=rope)
+        if rd == hd:
+            q, k = apply_rope(q, cache=rope), apply_rope(k, cache=rope)
+        else:
+            q, k = (jnp.concatenate(
+                [apply_rope(a[..., :rd], cache=rope), a[..., rd:]], axis=-1)
+                    for a in (q, k))
     ck = write(ck, k, offset)
     cv = write(cv, v, offset)
-    out = attend(q, ck, cv, offset, window=window)
-    out = out.transpose(0, 2, 1, 3).reshape(B, S, q.shape[1] * hd)
+    sink = {"sink": p["sink"]} if "sink" in p else {}
+    out = attend(q, ck, cv, offset, window=window, **sink)
+    out = out.transpose(0, 2, 1, 3).reshape(B, S, q.shape[1] * hv)
     if "wg" in p:
         out = out * jax.nn.sigmoid(dense(x, p["wg"]).astype(F32)).astype(
             out.dtype)
@@ -936,10 +1020,16 @@ def init_hybrid_params(key, cfg: HybridConfig, scaled_residual: bool = False,
                 "out_proj": normal(ks[4], (di, D), di),
             }
         elif kind in "*W":
-            dq, dkv = cfg.nheads * hd, cfg.kv_heads * hd
-            lp = {"wq": normal(ks[0], (D, dq), D),
-                  "wkv": normal(ks[1], (2, D, dkv), D),
-                  "wo": normal(ks[2], (dq, D), dq)}
+            hkv = cfg.window_heads if kind == "W" else cfg.kv_heads
+            dq, dkv, hv = cfg.nheads * hd, hkv * hd, cfg.value_width
+            if hv != hd:   # the three projections side by side
+                lp = {"wqkv": normal(ks[0], (D, dq + dkv + hkv * hv), D),
+                      "wo": normal(ks[2], (cfg.nheads * hv, D),
+                                   cfg.nheads * hv)}
+            else:
+                lp = {"wq": normal(ks[0], (D, dq), D),
+                      "wkv": normal(ks[1], (2, D, dkv), D),
+                      "wo": normal(ks[2], (dq, D), dq)}
         elif kind == "S":
             dq, dkv = cfg.nheads * hd, cfg.kv_heads * hd
             J, di = cfg.idx_heads, cfg.idx_dim

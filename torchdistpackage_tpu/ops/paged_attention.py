@@ -54,6 +54,17 @@ greedy tokens bit-match the gather goldens (tests/test_paged_attention.py
 locks dense, GQA, sliding-window, vector offsets, and the K+1 verify
 shape).  The gather path stays in-tree as the parity oracle.
 
+Two things a model may ask of either walk, each absent from every other
+model's program.  UNEQUAL WIDTHS: value heads narrower than key heads (192
+over 128): the pool's K leaf then lies transposed, ``[L, nb, Hkv, hd,
+bs]`` (serving/paged_cache.py "Unequal widths"), a key tile is ``[hd,
+keys]`` and a score ``q [rows, hd] . k [hd, keys]``; the accumulator and the
+output are the values' width.  A SINK: a learned scalar a query head that
+stands in every row's softmax as one more column and gives no value, ``p_j
+= exp(s_j) / (exp(sink_h) + sum_i exp(s_i))``: the online softmax starts at
+``(m, l, acc) = (sink_h, 1, 0)`` where it started at ``(-inf, 0, 0)``
+(:func:`_start`), the sinks a fourth scalar-prefetch operand.
+
 On CPU the kernel runs in Pallas interpreter mode automatically (same
 ``_interpret`` switch as ops/flash_attention.py), so every test exercises
 the identical code path the TPU compiles.
@@ -257,6 +268,35 @@ def fetched_block(tab, off, b, h, j, i, *, S_in: int, bs: int, fw: int,
             jnp.where(live_here, h, 0))
 
 
+def _start(acc_ref, m_ref, l_ref, sink_ref=None, head0=None, gp=0, S_in=0):
+    """The online softmax before its first key tile: ``(m, l, acc) = (-inf,
+    0, 0)``.  With a sink (``sink_ref`` [H] float32 in SMEM) a row starts
+    from its query head's one more column instead, ``(sink_h, 1, 0)``: the
+    rows of a program are group-major, ``gp`` query heads of ``S_in`` rows
+    a KV head, the first of them head ``head0``, so each head's scalar goes
+    to a slab of ``S_in`` rows (padding rows behind them keep ``-inf``)."""
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    if sink_ref is None:
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        return
+    l_ref[...] = jnp.ones_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    for kvh in range(m_ref.shape[0]):
+        for g in range(gp):
+            m_ref[kvh, pl.ds(g * S_in, S_in), :] = jnp.full(
+                (S_in, m_ref.shape[2]), sink_ref[head0 + kvh * gp + g],
+                jnp.float32)
+
+
+def _sunk(kernel):
+    """``kernel`` as a call with a sink hands it over: the sinks are the
+    fourth scalar-prefetch operand, the body's ``sink_ref``."""
+    def body(tab_ref, off_ref, lay_ref, sink_ref, *refs):
+        return kernel(tab_ref, off_ref, lay_ref, *refs, sink_ref=sink_ref)
+    return body
+
+
 def _accumulate(s, keep, pv, acc_ref, m_ref, l_ref):
     """One KV block's online-softmax step on the (acc, m, l) scratch:
     ``s`` the scaled f32 scores ``[..., rows, bs]`` (a leading head axis or
@@ -365,7 +405,8 @@ def shape_walk(groups: int, S_in: int, Hkv: int, mb: int, bs: int,
 def _walk_kernel(
     tab_ref, off_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
     kbuf, vbuf, sem, par_ref, acc_ref, m_ref, l_ref,
-    *, S_in, bs, mb, window, sm_scale, rows, hb, T, bound,
+    *, S_in, bs, mb, window, sm_scale, rows, hb, T, bound, kt=False,
+    sink_ref=None, gp=0,
 ):
     """The decode shape's walk.  Grid ``(slot b, kv-head group h)``, run in
     order; the pools stay in HBM and program ``(b, h)`` loops over the
@@ -386,7 +427,12 @@ def _walk_kernel(
     slot's :func:`first_column` and not the table's column 0, so a block
     that lies wholly behind the window is not fetched, waited for or
     multiplied by, whatever its table column names.  Without it the body is
-    the one from column 0, operation for operation."""
+    the one from column 0, operation for operation.
+
+    ``kt`` (static): the K pool lies transposed, ``[L, nb, Hkv, hd, bs]``,
+    and so does its tile, ``kbuf`` ``[2, hb, hd, T x bs]``: a block is
+    copied beside the last along the LANES.  ``sink_ref`` / ``gp``:
+    :func:`_start`."""
     b, h = pl.program_id(0), pl.program_id(1)
     nh = pl.num_programs(1)
     lay = lay_ref[0]
@@ -409,9 +455,11 @@ def _walk_kernel(
         def block(i, carry):
             src = (lay, tab_ref[b, column(b, t * T + i)], pl.ds(h * hb, hb))
             dst = (half, slice(None), pl.ds(pl.multiple_of(i * bs, bs), bs))
-            for pool, buf, side in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            kdst = dst[:2] + (slice(None),) + dst[2:] if kt else dst
+            for pool, buf, side, to in ((k_hbm, kbuf, 0, kdst),
+                                        (v_hbm, vbuf, 1, dst)):
                 act(pltpu.make_async_copy(
-                    pool.at[src], buf.at[dst], sem.at[half, side]))
+                    pool.at[src], buf.at[to], sem.at[half, side]))
             return carry
 
         jax.lax.fori_loop(
@@ -429,9 +477,8 @@ def _walk_kernel(
     last = off + S_in  # positions written so far, this call's rows included
     tiles = (live_blocks(b) + T - 1) // T
     par0 = par_ref[0]
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    _start(acc_ref, m_ref, l_ref, sink_ref,
+           None if sink_ref is None else h * hb * gp, gp, S_in)
 
     q = q_ref[0]  # [hb, rows, hd]
     # row r covers query position off + (r % S_in) (group-major rows);
@@ -454,9 +501,9 @@ def _walk_kernel(
                         0, 1 - half, start)
 
         tile_copies(b, h, t, half, wait)
-        k = kbuf[half]  # [hb, T*bs, hd]
+        k = kbuf[half]  # [hb, T*bs, hd]; transposed: [hb, hd, T*bs]
         v = vbuf[half]
-        s = jnp.einsum("hrd,hkd->hrk", q, k,
+        s = jnp.einsum("hrd,hdk->hrk" if kt else "hrd,hkd->hrk", q, k,
                        preferred_element_type=jnp.float32)
         # the tile's first key position
         pos0 = column(b, t * T) * bs if bound else t * (T * bs)
@@ -485,6 +532,7 @@ def _walk_kernel(
 def _kernel(
     tab_ref, off_ref, lay_ref, q_ref, *refs,
     S_in, bs, window, sm_scale, quantized, fetch_width, rows, hb, bound,
+    kt=False, sink_ref=None, gp=0,
 ):
     """Grid ``(slot b, kv-head group h, kv-step j)``, ``hb`` KV heads a
     group, the batch axis of every product in here; ``lay_ref`` (the layer
@@ -504,7 +552,9 @@ def _kernel(
     even as 0 x NaN.  A step whose first block is dead is skipped whole.
     ``bound`` (static; :func:`window_binds`): step 0 stands at the slot's
     :func:`first_column`, as the index map's, and the grid is only as long
-    as a window's columns."""
+    as a window's columns.  ``kt`` (static): a K block lies transposed,
+    ``[hb, hd, bs]``, and a step's blocks stand side by side along the
+    lanes.  ``sink_ref`` / ``gp``: :func:`_start`."""
     per = 4 if quantized else 2
     fw = fetch_width
     kv_refs = refs[:fw * per]
@@ -518,11 +568,12 @@ def _kernel(
     col0 = first_column(off, window, bs) if bound else None
     blk0 = j * fw + col0 if bound else j * fw  # the tile's first column
 
+    # the first query head of this program's rows (a sink's index)
+    head0 = None if sink_ref is None else pl.program_id(1) * hb * gp
+
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _start(acc_ref, m_ref, l_ref, sink_ref, head0, gp, S_in)
 
     @pl.when(blk0 < hi)
     def _compute():
@@ -538,7 +589,8 @@ def _kernel(
             return jnp.where(pos < last, x, jnp.zeros_like(x))
 
         # scores [hb, rows, K] = q . k over hd; update [hb, rows, hd] = p . v
-        qk = functools.partial(jnp.einsum, "hrd,hkd->hrk",
+        qk = functools.partial(jnp.einsum,
+                               "hrd,hdk->hrk" if kt else "hrd,hkd->hrk",
                                preferred_element_type=jnp.float32)
         pv = functools.partial(jnp.einsum, "hrk,hkd->hrd",
                                preferred_element_type=jnp.float32)
@@ -549,7 +601,8 @@ def _kernel(
             s = qk(q.astype(jnp.float32), k8.astype(jnp.float32)) * ks
             upd = lambda p: pv(p * vs, v8.astype(jnp.float32))
         else:
-            k, v = side(0, 1), side(1, 1, written=True)  # [hb, K, hd]
+            # [hb, K, hd]; transposed keys: [hb, hd, K]
+            k, v = side(0, 2 if kt else 1), side(1, 1, written=True)
             s = qk(q, k)
             upd = lambda p: pv(p.astype(v.dtype), v)
         # row r covers query position off + (r % S_in) (group-major rows);
@@ -584,6 +637,7 @@ def paged_decode_attention(
     sm_scale: Optional[float] = None,
     fetch_width: Optional[int] = None,
     q_pad_to: Optional[int] = None,
+    sink: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Attention of ``q`` [B, H, S_in, hd] against each slot's paged
     context, walking the block table in-kernel.
@@ -600,6 +654,10 @@ def paged_decode_attention(
     additionally bounds below).  Returns [B, H, S_in, hd] in ``q.dtype``
     — drop-in for the gather path's ``_cached_attention`` output
     (float-tolerance equal; the engine goldens assert token bit parity).
+    A pool of UNEQUAL widths (``k_pool`` ``[L, num_blocks, Hkv, hd, bs]``,
+    transposed, beside ``v_pool`` ``[.., bs, hv]``): returns [B, H, S_in,
+    hv].  ``sink`` [H] float32: one more column of every row's softmax, the
+    scalar of the row's query head, which gives no value (module docstring).
 
     Jitted, the layer an operand: a program whose python-unrolled layers
     call it with one shape traces and lowers the kernel ONCE.  Beside a TPU
@@ -611,8 +669,15 @@ def paged_decode_attention(
     B, H, S_in, hd = q.shape
     k_pool, v_pool, lay = _stacked(k_pool, v_pool, layer)
     quantized = isinstance(k_pool, tuple)
-    k_arr = k_pool[0] if quantized else k_pool
-    _L, nb, Hkv, bs, _hd = k_arr.shape
+    k_arr, v_arr = (k_pool[0], v_pool[0]) if quantized else (k_pool, v_pool)
+    _L, nb, Hkv, bs, hv = v_arr.shape
+    # unequal widths: the K leaf lies transposed, [L, nb, Hkv, hd, bs]
+    kt = k_arr.shape[3:] != v_arr.shape[3:]
+    if kt and (quantized or k_arr.shape[3:] != (hd, bs)):
+        raise NotImplementedError(
+            f"a K leaf {k_arr.shape} beside a V leaf {v_arr.shape}: keys of "
+            f"another width than the values lie [.., {hd}, {bs}], bfloat16 "
+            f"or float32")
     groups, rem = divmod(H, Hkv)
     if rem:
         raise ValueError(
@@ -630,9 +695,10 @@ def paged_decode_attention(
     # rows a KV head than one program takes: its query heads go to ``split``
     # programs, each fetching the head's blocks
     bound = window_binds(window, mb, bs)
+    # a head's block of K and of V, their mean: what a tile holds of each
     split, cols, rows, fw, hb, T = shape_walk(
-        groups, S_in, Hkv, mb, bs, bs * hd * k_arr.dtype.itemsize, window,
-        quantized, fetch_width, q_pad_to)
+        groups, S_in, Hkv, mb, bs, bs * (hd + hv) // 2 * k_arr.dtype.itemsize,
+        window, quantized, fetch_width, q_pad_to)
     R //= split
     qr = q.reshape(B, Hkv * split, R, hd)
     if rows != R:
@@ -640,16 +706,22 @@ def paged_decode_attention(
     name = ("swa_" if bound else "paged_") + (
         "decode" if S_in == 1 else "chunk")
     scalars = (tables.astype(jnp.int32), offs, lay)
+    more = {}   # what no call of equal widths and no sink carries
+    if kt:
+        more["kt"] = True
+    if sink is not None:
+        scalars += (sink.astype(jnp.float32),)
+        more["gp"] = groups // split
     if T:
-        return _walk_call(qr, k_pool, v_pool, scalars, S_in=S_in,
+        return _walk_call(qr, k_pool, v_pool, scalars, more, S_in=S_in,
                           window=window, sm_scale=float(sm_scale), hb=hb,
                           T=T, name=name, bound=bound,
-                          )[:, :, :R].reshape(B, H, S_in, hd)
+                          )[:, :, :R].reshape(B, H, S_in, hv)
 
-    def qidx(b, h, j, tab, off, lay):
+    def qidx(b, h, j, *_):
         return (b, h, 0, 0)
 
-    def kvidx(b, h, j, tab, off, lay, i=0, own_layer=False):
+    def kvidx(b, h, j, tab, off, lay, *_, i=0, own_layer=False):
         blk, hg = fetched_block(tab, off, b, h if split == 1 else h // split,
                                 j, i, S_in=S_in, bs=bs, fw=fw,
                                 window=window if bound else None)
@@ -661,10 +733,12 @@ def paged_decode_attention(
     operands = [qr]
     scales = [_scale_rows(pool[1], lay) if quantized else None
               for pool in (k_pool, v_pool)]
+    blocks = ((1, 1, hb, hd, bs) if kt else (1, 1, hb, bs, hd),
+              (1, 1, hb, bs, hv))
     for i in range(fw):
-        for pool, scale in zip((k_pool, v_pool), scales):
+        for pool, scale, block in zip((k_pool, v_pool), scales, blocks):
             in_specs.append(pl.BlockSpec(
-                (1, 1, hb, bs, hd), functools.partial(kvidx, i=i)))
+                block, functools.partial(kvidx, i=i)))
             operands.append(pool[0] if quantized else pool)
             if quantized:
                 in_specs.append(pl.BlockSpec(
@@ -673,68 +747,75 @@ def paged_decode_attention(
                 operands.append(scale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(B, Hkv * split // hb, -(-cols // fw)),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hb, rows, hd), qidx),
+        out_specs=pl.BlockSpec((1, hb, rows, hv), qidx),
         scratch_shapes=[
-            pltpu.VMEM((hb, rows, hd), jnp.float32),      # acc
+            pltpu.VMEM((hb, rows, hv), jnp.float32),      # acc
             pltpu.VMEM((hb, rows, _LANES), jnp.float32),  # m
             pltpu.VMEM((hb, rows, _LANES), jnp.float32),  # l
         ],
     )
     kernel = functools.partial(
         _kernel, S_in=S_in, bs=bs, window=window, sm_scale=float(sm_scale),
-        quantized=quantized, fetch_width=fw, rows=rows, hb=hb, bound=bound)
+        quantized=quantized, fetch_width=fw, rows=rows, hb=hb, bound=bound,
+        **more)
     out = pl.pallas_call(
-        kernel,
+        _sunk(kernel) if sink is not None else kernel,
         grid_spec=grid_spec,
-        out_shape=_out_struct((B, Hkv * split, rows, hd), q.dtype, q),
+        out_shape=_out_struct((B, Hkv * split, rows, hv), q.dtype, q),
         compiler_params=_compiler_params(_GRID_VMEM_LIMIT),
         interpret=_interpret(),
         name=name,
     )(*scalars, *operands)
-    return out[:, :, :R].reshape(B, H, S_in, hd)
+    return out[:, :, :R].reshape(B, H, S_in, hv)
 
 
-def _walk_call(qr, k_pool, v_pool, scalars, *, S_in, window, sm_scale, hb, T,
-               name, bound):
+def _walk_call(qr, k_pool, v_pool, scalars, more, *, S_in, window, sm_scale,
+               hb, T, name, bound):
     """The ``pallas_call`` of :func:`_walk_kernel` over padded group-major
-    rows ``qr`` [B, Hkv, rows, hd] and the stacked pools."""
+    rows ``qr`` [B, Hkv, rows, hd] and the stacked pools; ``more``: the
+    kernel's ``kt`` (the K pool transposed) and ``gp`` (a call whose fourth
+    scalar is the sinks), where the call has either."""
     B, Hkv, rows, hd = qr.shape
-    bs = k_pool.shape[3]
+    bs, hv = v_pool.shape[3:]
     mb = scalars[0].shape[-1]
 
-    def qidx(b, h, tab, off, lay):
+    def qidx(b, h, *_):
         return (b, h, 0, 0)
 
-    tile = pltpu.VMEM((2, hb, T * bs, hd), k_pool.dtype)
+    tile = pltpu.VMEM((2, hb, T * bs, hv), v_pool.dtype)
+    ktile = (pltpu.VMEM((2, hb, hd, T * bs), k_pool.dtype)
+             if more.get("kt") else tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(B, Hkv // hb),
         in_specs=[pl.BlockSpec((1, hb, rows, hd), qidx),
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, hb, rows, hd), qidx),
+        out_specs=pl.BlockSpec((1, hb, rows, hv), qidx),
         scratch_shapes=[
-            tile, tile,                                   # K, V tiles x 2
+            ktile, tile,                                  # K, V tiles x 2
             pltpu.SemaphoreType.DMA((2, 2)),              # [half, K | V]
             pltpu.SMEM((1,), jnp.int32),                  # first tile's half
-            pltpu.VMEM((hb, rows, hd), jnp.float32),      # acc
+            pltpu.VMEM((hb, rows, hv), jnp.float32),      # acc
             pltpu.VMEM((hb, rows, _LANES), jnp.float32),  # m
             pltpu.VMEM((hb, rows, _LANES), jnp.float32),  # l
         ],
     )
     kernel = functools.partial(
         _walk_kernel, S_in=S_in, bs=bs, mb=mb, window=window,
-        sm_scale=sm_scale, rows=rows, hb=hb, T=T, bound=bound)
+        sm_scale=sm_scale, rows=rows, hb=hb, T=T, bound=bound, **more)
+    if "gp" in more:
+        kernel = _sunk(kernel)
     # programs run in order: each starts the next one's first copies
     params = None if _interpret() else pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=_out_struct((B, Hkv, rows, hd), qr.dtype, qr),
+        out_shape=_out_struct((B, Hkv, rows, hv), qr.dtype, qr),
         compiler_params=params,
         interpret=_interpret(),
         name=name,

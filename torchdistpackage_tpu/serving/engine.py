@@ -164,6 +164,7 @@ from .paged_cache import (
     paged_forward,
     paged_forward_moe,
     pool_bytes,
+    keys_transposed,
     window_bytes,
     window_reach,
 )
@@ -274,6 +275,22 @@ def _device_bytes_taken(before: Optional[int], tree: Any) -> Dict[str, int]:
         return {}
     jax.block_until_ready(tree)
     return {"device_bytes": _device_bytes_in_use() - before}
+
+
+def _pool_shape_attrs(cache: Dict[str, Any], quantized: bool) -> Dict[str, int]:
+    """What a K/V pool's leaves say of a head and of each pool, for the
+    ``tdp:engine.init.pool`` span: ``key_width`` / ``value_width`` (a
+    head's; a packed pool's row) and ``kv_heads``, with ``window_kv_heads``
+    where window layers have their own pool (of another block shape where
+    the model gives them another count)."""
+    k, v = ((leaf[0] if quantized else leaf).shape
+            for leaf in (cache["k"], cache["v"]))
+    # a K leaf of another width than V's lies transposed, [.., width, bs]
+    out = {"key_width": k[3 if keys_transposed(cache["k"], cache["v"]) else 4],
+           "value_width": v[4], "kv_heads": v[2]}
+    if "win" in cache:
+        out["window_kv_heads"] = cache["win"]["v"].shape[2]
+    return out
 
 
 def _split_keys(keys: jnp.ndarray):
@@ -784,6 +801,8 @@ class ServingEngine:
             if self.window:  # of which the window layers' pool
                 sp.attrs.update(window_bytes=window_bytes(self.cache),
                                 window_blocks=self.window_blocks)
+            if "v" in self.cache:  # a head's widths and each pool's heads
+                sp.attrs.update(_pool_shape_attrs(self.cache, self.kv_quant))
         #: state models: the recurrent state, one row a slot, beside the
         #: pool (``models.hybrid.init_state``); like the pool, the compiled
         #: step is handed it as a donated argument and the engine keeps
@@ -1019,27 +1038,34 @@ class ServingEngine:
         k = self.cache.get("k")
         if self.attn_impl != "pallas" or k is None:
             return {}
-        arr = k[0] if self.kv_quant else k
         tp = int(self.mesh.shape[self.axis]) if (
             self.mesh is not None and self.axis) else 1
         blk, ops = self.cfg.block, paged_attention_ops
-        # by the pool's own head axis: narrow heads lie several to a row
-        groups, hkv = blk.nheads // arr.shape[2], arr.shape[2] // tp
-        bs = arr.shape[3]
 
-        def walk(s_in, window):  # as the wrapper asks
-            return ops.shape_walk(
-                groups, s_in, hkv, self.max_blocks, bs,
-                bs * arr.shape[4] * arr.dtype.itemsize, window, self.kv_quant)
+        def walk(pool, s_in, window):  # as the wrapper asks
+            """``shape_walk`` of a call on ``pool``, with its KV heads and
+            its block: by the pool's own head axis (narrow heads lie several
+            to a row; window layers may have another count), its block size
+            the V leaf's (a K leaf of another width lies transposed), a
+            head's block of K and of V their mean."""
+            ka, va = ((leaf[0] if self.kv_quant else leaf)
+                      for leaf in (pool["k"], pool["v"]))
+            hkv, bs = va.shape[2] // tp, va.shape[3]
+            keys = ka.shape[3 if keys_transposed(ka, va) else 4]
+            return (hkv, bs) + ops.shape_walk(
+                blk.nheads // va.shape[2], s_in, hkv, self.max_blocks, bs,
+                bs * (keys + va.shape[4]) // 2 * va.dtype.itemsize, window,
+                self.kv_quant)
 
         window = getattr(blk, "sliding_window", None)
-        *_, hb, T = walk(self.spec_k + 1, window)
-        split, _cols, rows, fw, chb, cT = walk(self.chunk, window)
+        *_, hb, T = walk(self.cache, self.spec_k + 1, window)
+        hkv, bs, split, _cols, rows, fw, chb, cT = walk(
+            self.cache, self.chunk, window)
         attrs = {"kv_heads_per_step": hb, "kv_tile_blocks": T,
                  "chunk_rows": rows, "chunk_tile_keys": (cT or fw) * bs,
                  "chunk_programs": hkv * split // chb}
         if self.window:  # the window layers' chunk walks the window's columns
-            *_, fw, _hb, cT = walk(self.chunk, self.window)
+            *_, fw, _hb, cT = walk(self.cache["win"], self.chunk, self.window)
             attrs["window_chunk_tile_keys"] = (cT or fw) * bs
         return attrs
 

@@ -29,8 +29,9 @@ compiles ONCE):
   blocks are and written by the same op.  Everything below that names blocks
   (tables, allocator, copy-on-write, migration) is the same for all three.
   A model with WINDOW layers beside global ones (kind ``W``) keeps two
-  different amounts of cache and so has a SECOND pool of the same block
-  shape under ``'win'``, ``{'k','v': [window_layers, window_blocks, Hkv,
+  different amounts of cache and so has a SECOND pool (of the same block
+  shape, or of its own: "Unequal widths, two block shapes" below) under
+  ``'win'``, ``{'k','v': [window_layers, window_blocks, Hkv,
   block_size, hd]}``, with block ids, a NULL block, an allocator and a
   table of its own: a sequence holds at most :func:`window_reach` of its
   blocks, and the engine hands a block that fell behind the window on to a
@@ -50,6 +51,26 @@ compiles ONCE):
   which the head's lanes are kept (:func:`paged_attention`).  A pool of
   ``Hkv / pack`` heads of 128: the kernel, the tables and the allocator see
   nothing else.
+  **Unequal widths, two block shapes.**  A model may state value heads
+  narrower than its key heads and another number of KV heads in its window
+  layers (``cfg.v_head_dim``, ``cfg.window_kv_heads``, models/hybrid.py:
+  MiMo-V2 has 64 query heads of 192 over values of 128, 4 KV heads in the
+  global layers and 8 in the window layers).  The two pools then have two
+  block shapes, each taken from its own head count, and K and V are leaves
+  of unequal width in both.  A 192-wide key is a lane tile and a half: a
+  ``[.., block_size, 192]`` bfloat16 leaf would be held at 256 lanes, a
+  third of it padding (and a 64-wide remainder is what Mosaic refused to
+  slice, above).  So wherever the widths differ the K leaf lies
+  TRANSPOSED, ``[L, num_blocks, Hkv, key_width, block_size]``, as the
+  latent and the indexer leaves do: the positions are the lanes, whole
+  tiles at a block of 128, the 192 dims are sublanes (twelve bfloat16
+  tiles of 16), and the leaf holds its logical bytes.  A score is then
+  ``q [rows, 192] . k^T [192, keys]``, the product as the MXU takes it,
+  with no transpose of the key tile.  (Two leaves, the rotated 64 dims
+  packed two heads to a row beside the other 128, hold the same bytes but
+  make every score two products and every write two scatters.)  The V
+  leaf lies as ever, ``[.., Hkv, block_size, value_width]``.  Equal widths:
+  the layout above, leaf for leaf.
 - **Block tables**: ``[num_slots, max_blocks]`` int32 per-slot rows.  Block
   ``i`` of a slot's table covers its positions ``[i*bs, (i+1)*bs)``, so the
   table IS the page table and position arithmetic is two integer ops.
@@ -120,10 +141,14 @@ def init_paged_kv(
     one-leaf pool ``{'kv': [L, num_blocks, 1, latent_width, block_size]}``
     instead: nothing to divide over a tensor axis, no int8 form yet.
 
-    A model with window layers (``cfg.window_layers``) gets a SECOND pool of
-    the same block shape under ``'win'``: ``{'k','v': [window_layers,
-    window_blocks, Hkv, block_size, hd]}``, with block ids, a NULL block and
-    a table of its own."""
+    A model with window layers (``cfg.window_layers``) gets a SECOND pool
+    under ``'win'``: ``{'k','v': [window_layers, window_blocks, Hkv,
+    block_size, hd]}``, with block ids, a NULL block and a table of its own;
+    ``Hkv`` is the window layers' own count where the model states one
+    (``cfg.window_kv_heads``).  Value heads of another width than the key
+    heads (``cfg.v_head_dim``): every V leaf is ``[.., block_size, value
+    width]`` and every K leaf lies transposed, ``[.., key width,
+    block_size]`` (module docstring, "Unequal widths")."""
     if _window_layers(cfg):
         if quantized or axis_size != 1:
             raise NotImplementedError(
@@ -172,11 +197,20 @@ def init_paged_kv(
             return (jnp.zeros(shape, jnp.int8),
                     jnp.ones(shape[:-1], jnp.float32))
         return {"k": entry(), "v": entry()}
-    pool = {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    vw = _value_width(cfg)
+
+    def leaves(layers, blocks, heads):
+        """One pool's K and V: of one shape, or (unequal widths) the keys
+        transposed, ``[.., key_width, block_size]``."""
+        k = v = (layers, blocks, heads // pack, block_size, pack * vw)
+        if vw != cfg.block.head_dim:
+            k = (layers, blocks, heads, cfg.block.head_dim, block_size)
+        return {"k": jnp.zeros(k, cfg.dtype), "v": jnp.zeros(v, cfg.dtype)}
+
+    pool = leaves(shape[0], num_blocks, hkv)
     if _window_layers(cfg):
-        wshape = (_window_layers(cfg), window_blocks) + shape[2:]
-        pool["win"] = {"k": jnp.zeros(wshape, cfg.dtype),
-                       "v": jnp.zeros(wshape, cfg.dtype)}
+        pool["win"] = leaves(_window_layers(cfg), window_blocks,
+                             _window_kv_heads(cfg))
     return pool
 
 
@@ -193,6 +227,26 @@ def pack_heads(val: jnp.ndarray, pack: int) -> jnp.ndarray:
     B, Hkv, S, hd = val.shape
     return val.reshape(B, Hkv // pack, pack, S, hd).swapaxes(2, 3).reshape(
         B, Hkv // pack, S, pack * hd)
+
+
+def _value_width(cfg) -> int:
+    """A value head's width (models/hybrid.py ``v_head_dim``); every other
+    family's is its key head's."""
+    return getattr(cfg, "v_head_dim", 0) or cfg.block.head_dim
+
+
+def _window_kv_heads(cfg) -> int:
+    """The window layers' KV heads (models/hybrid.py ``window_kv_heads``);
+    the other layers' where the model states none."""
+    return getattr(cfg, "window_kv_heads", 0) or cfg.block.kv_head_count
+
+
+def keys_transposed(ck, cv) -> bool:
+    """Whether a pool's K leaf lies transposed, ``[.., key_width,
+    block_size]`` beside V's ``[.., block_size, value_width]``: wherever
+    the two widths differ (module docstring, "Unequal widths")."""
+    first = lambda c: c[0] if isinstance(c, tuple) else c
+    return first(ck).shape[-2:] != first(cv).shape[-2:]
 
 
 def _window_layers(cfg) -> int:
@@ -250,8 +304,8 @@ def block_size_of(cache: Dict[str, Any]) -> int:
     latent pool's blocks lie transposed, positions last."""
     if "kv" in cache:
         return cache["kv"].shape[4]
-    k = cache["k"]
-    return (k[0] if isinstance(k, tuple) else k).shape[3]
+    v = cache["v"]   # the K leaf lies transposed where the widths differ
+    return (v[0] if isinstance(v, tuple) else v).shape[3]
 
 
 def is_quantized(cache: Dict[str, Any]) -> bool:
@@ -282,24 +336,27 @@ def expected_pool_bytes(
     independent half of the pool-accounting cross-check.  A latent pool
     is ONE leaf of ``L * num_blocks * block_size * latent_width``; an
     indexed pool adds ``L * num_blocks * block_size * index_width``; a
-    window pool ``2 * window_layers * window_blocks * Hkv * block_size *
-    hd``."""
+    window pool ``window_layers * window_blocks * window Hkv * block_size *
+    (hd + value width)`` (``2 hd`` a position and head wherever the widths
+    are equal)."""
     if _latent_width(cfg):
         return (_kv_layers(cfg) * num_blocks * block_size
                 * _latent_width(cfg) * jnp.dtype(cfg.dtype).itemsize)
     hkv = cfg.block.kv_head_count // axis_size
     entries = _kv_layers(cfg) * num_blocks * hkv * block_size
     hd = cfg.block.head_dim
+    # a key's and a value's width together
+    kv = hd + _value_width(cfg)
     if quantized:
-        per_kv = entries * hd * 1 + entries * 4  # int8 q + f32 scale
+        per_kv = entries * kv * 1 + 2 * entries * 4  # int8 q + f32 scale
     else:
-        per_kv = entries * hd * jnp.dtype(cfg.dtype).itemsize
+        per_kv = entries * kv * jnp.dtype(cfg.dtype).itemsize
     # k and v, the indexer's key a position where attention is indexed, and
     # the window layers' k and v
-    return (2 * per_kv
+    return (per_kv
             + (_kv_layers(cfg) * num_blocks * block_size * _index_width(cfg)
-               + 2 * _window_layers(cfg) * window_blocks * hkv * block_size
-               * hd) * jnp.dtype(cfg.dtype).itemsize)
+               + _window_layers(cfg) * window_blocks * _window_kv_heads(cfg)
+               * block_size * kv) * jnp.dtype(cfg.dtype).itemsize)
 
 
 def _write_blocks(tables: jnp.ndarray, offset: jnp.ndarray, S_in: int,
@@ -326,7 +383,7 @@ def _write_blocks(tables: jnp.ndarray, offset: jnp.ndarray, S_in: int,
 
 
 def paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray,
-                layer=None):
+                layer=None, transposed: bool = False):
     """Write ``val`` [B, Hkv, S_in, hd] into layer ``layer`` of the pool
     ``c`` ([L, num_blocks, Hkv, bs, hd] or its quantized pair) at per-slot
     positions ``offset[b] + arange(S_in)`` via the block tables, and return
@@ -344,11 +401,17 @@ def paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray,
     pool as it lies, so every layer paid two copies of the pool between
     the two layouts (PERF.md section 6, PR 27).  Live slots own disjoint
     blocks, so the scatter has no racing duplicates (only the NULL block
-    absorbs colliding writes, and it is never read)."""
+    absorbs colliding writes, and it is never read).
+
+    ``transposed``: ``c`` is a K leaf of a pool of unequal widths, ``[L,
+    num_blocks, Hkv, hd, bs]`` (module docstring): the same whole blocks,
+    a call's rows laid over them as their COLUMNS."""
     if layer is None:
         whole = paged_write(jax.tree.map(lambda a: a[None], c), val, offset,
-                            tables=tables, layer=0)
+                            tables=tables, layer=0, transposed=transposed)
         return jax.tree.map(lambda a: a[0], whole)
+    if transposed:
+        return _write_transposed(c, val, offset, tables, layer)
     width = (c[0] if isinstance(c, tuple) else c).shape[4]
     if width != val.shape[3]:  # narrow heads, several to a row of the pool
         val = pack_heads(val, width // val.shape[3])
@@ -376,13 +439,33 @@ def paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray,
     return put(c, val)
 
 
-def gather_kv(c, tables: jnp.ndarray, layer=None):
+def _write_transposed(pool, val, offset, tables, layer):
+    """:func:`paged_write` for a transposed K leaf ``[L, nb, Hkv, hd, bs]``
+    (:func:`latent_write` with a head axis): the blocks the rows fall in
+    are read at ``[layer, blk]``, the call's rows ``val`` [B, Hkv, S_in, hd]
+    become their columns, and the blocks are scattered back whole."""
+    B, Hkv, S_in, hd = val.shape
+    bs = pool.shape[4]
+    blk, src, valid = _write_blocks(
+        tables, jnp.asarray(offset, jnp.int32), S_in, bs)
+    n = blk.shape[1]
+    new = jnp.take_along_axis(
+        val, src.reshape(B, 1, n * bs, 1), axis=2).reshape(B, Hkv, n, bs, hd)
+    new = jnp.where(valid[:, :, None, None, :],
+                    new.transpose(0, 2, 1, 4, 3).astype(pool.dtype),
+                    pool[layer, blk])                 # [B, n, Hkv, hd, bs]
+    return pool.at[layer, blk.reshape(-1)].set(
+        new.reshape((B * n,) + new.shape[2:]))
+
+
+def gather_kv(c, tables: jnp.ndarray, layer=None, transposed: bool = False):
     """Layer ``layer`` of the pool (``None``: ``c`` is one layer's) ->
     dense per-slot view [B, Hkv, max_blocks*bs, hd] (or its quantized
     pair) through the block tables: ONE gather at ``[layer, tables]``, the
     layer is never sliced out first.  Gathered index == slot-relative
     position, so the result drops straight into ``_cached_attention`` in
-    place of the contiguous buffer."""
+    place of the contiguous buffer.  ``transposed``: a K leaf ``[L, nb,
+    Hkv, hd, bs]`` (unequal widths)."""
     at = (lambda a: a[tables]) if layer is None else (
         lambda a: a[layer, tables])
     if isinstance(c, tuple):
@@ -392,6 +475,8 @@ def gather_kv(c, tables: jnp.ndarray, layer=None):
         gs = at(scale).transpose(0, 2, 1, 3).reshape(B, Hkv, nb * bs)
         return (g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, nb * bs, hd), gs)
     g = at(c)
+    if transposed:   # a K leaf of unequal widths: [B, nb, Hkv, hd, bs]
+        g = g.swapaxes(3, 4)
     B, nb, Hkv, bs, hd = g.shape
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, nb * bs, hd)
 
@@ -399,6 +484,7 @@ def gather_kv(c, tables: jnp.ndarray, layer=None):
 def paged_attention(
     q: jnp.ndarray, ck, cv, offset, *, tables: jnp.ndarray, window=None,
     impl: str = "gather", layer=None, sm_scale: Optional[float] = None,
+    sink: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Attention of q [B, H, S_in, hd] against each slot's paged context
     in layer ``layer`` of the pools ``ck`` / ``cv`` (``None``: they are one
@@ -419,10 +505,16 @@ def paged_attention(
     own KV head and zeros in the others, so that either implementation sees
     ``Hkv / pack`` KV heads of ``pack * hd`` and computes the head's own
     scores term for term; of the row that comes back the head's lanes are
-    kept."""
+    kept.
+
+    A pool of unequal widths (:func:`keys_transposed`: its K leaf ``[..,
+    hd, bs]``): the output rows are the VALUE heads' width.  ``sink`` [H]
+    float32: one more column of every row's softmax, a scalar a query head,
+    which takes its share of the mass and gives no value."""
     B, H, S_in, hd = q.shape
+    kt = keys_transposed(ck, cv)
     rows, width = (ck[0] if isinstance(ck, tuple) else ck).shape[-3::2]
-    pack = width // hd
+    pack = 1 if kt else width // hd
     if pack > 1:
         if sm_scale is None:
             sm_scale = 1.0 / math.sqrt(hd)
@@ -437,11 +529,13 @@ def paged_attention(
         from ..ops.paged_attention import paged_decode_attention
 
         out = paged_decode_attention(q, ck, cv, tables, offset, layer=layer,
-                                     window=window, sm_scale=sm_scale)
+                                     window=window, sm_scale=sm_scale,
+                                     sink=sink)
     else:
         out = _cached_attention(
-            q, gather_kv(ck, tables, layer), gather_kv(cv, tables, layer),
-            offset, window=window, sm_scale=sm_scale)
+            q, gather_kv(ck, tables, layer, transposed=kt),
+            gather_kv(cv, tables, layer), offset, window=window,
+            sm_scale=sm_scale, sink=sink)
     if pack > 1:
         out = jnp.take_along_axis(
             out.reshape(B, H, S_in, pack, hd),
@@ -450,17 +544,26 @@ def paged_attention(
 
 
 def _paged_cache_ops(tables: jnp.ndarray, attn_impl: str, layer,
-                     sm_scale: Optional[float] = None):
+                     sm_scale: Optional[float] = None, key_width: int = 0):
     """The ``cache_ops`` pair ``cached_block_forward`` needs to run one
     layer on the block pool instead of the contiguous buffer: the cache it
     threads through is the WHOLE pool, and ``layer`` (a python int in an
     unrolled loop, the scan's counter otherwise) is where both ops reach
-    into it.  The one way a layer reaches the pool."""
-    def attend(q, ck, cv, offset, window=None):
+    into it.  The one way a layer reaches the pool.  ``key_width``: a key
+    head's width where it is NOT the value head's (0: one width): rows of
+    that width are keys, and their leaf lies transposed."""
+    def attend(q, ck, cv, offset, window=None, sink=None):
         return paged_attention(q, ck, cv, offset, tables=tables,
                                window=window, impl=attn_impl, layer=layer,
-                               sm_scale=sm_scale)
-    return functools.partial(paged_write, tables=tables, layer=layer), attend
+                               sm_scale=sm_scale, sink=sink)
+    if not key_width:
+        return (functools.partial(paged_write, tables=tables, layer=layer),
+                attend)
+
+    def write(c, val, offset):
+        return paged_write(c, val, offset, tables=tables, layer=layer,
+                           transposed=val.shape[3] == key_width)
+    return write, attend
 
 
 def latent_write(pool: jnp.ndarray, rows: jnp.ndarray, offset, *,
@@ -842,17 +945,20 @@ def paged_forward_hybrid(
 
     offset = jnp.asarray(offset, jnp.int32)
     window_ops = None
+    # unequal widths: the ops are told which rows are keys
+    wide = ({"key_width": cfg.head_dim}
+            if _value_width(cfg) != cfg.block.head_dim else {})
     if _window_layers(cfg):
         tables, wtables = tables
         window_ops = functools.partial(_paged_cache_ops, wtables, attn_impl,
-                                       sm_scale=cfg.attn_scale)
+                                       sm_scale=cfg.attn_scale, **wide)
     if _latent_width(cfg):
         ops = functools.partial(_latent_cache_ops, tables, attn_impl, cfg)
     elif _index_width(cfg):
         ops = functools.partial(_indexed_cache_ops, tables, attn_impl, cfg)
     else:
         ops = functools.partial(_paged_cache_ops, tables, attn_impl,
-                                sm_scale=cfg.attn_scale)
+                                sm_scale=cfg.attn_scale, **wide)
     mine = state
     if rows is not None:
         def own(a):
