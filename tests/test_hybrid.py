@@ -673,6 +673,7 @@ def test_a_prefill_call_with_few_real_rows_batches_its_experts(
         with jax.default_matmul_precision("highest"):
             eng = ServingEngine(params, cfg, num_slots=3, block_size=8,
                                 chunk=8, max_ctx=32, attn_impl="gather")
+            eng.prefill_width = 3   # the call this test is about
             for n in prompts:
                 eng.submit(Request(tokens=rng.randint(0, 211, n).tolist(),
                                    max_new_tokens=5))
@@ -742,6 +743,7 @@ def test_the_prefill_program_holds_one_conditional_an_expert_layer_and_the_decod
     monkeypatch.setattr(M, "_BATCHED_EXPERTS_MAX_ROWS", 8)
     eng = ServingEngine(params, cfg, num_slots=3, block_size=8, chunk=8,
                         max_ctx=32, attn_impl="gather")
+    eng.prefill_width = 3   # a call of 3 x 8 rows, past the capacity of 8
     called = {}
     dispatch = eng._dispatch
 

@@ -31,7 +31,7 @@ from torchdistpackage_tpu.models import (
 )
 from torchdistpackage_tpu.obs.events import EventLog, set_default_event_log
 from torchdistpackage_tpu.serving.engine import (
-    PREFILL_WIDTH, _filtered_logits, _slot_sample)
+    PREFILL_WIDTH_EXPERTS, _filtered_logits, _slot_sample)
 from torchdistpackage_tpu.serving import (
     BlockAllocator,
     NULL_BLOCK,
@@ -772,7 +772,7 @@ def test_compact_prefill_unequal_dp_groups(bundles, devices8):
         b["params"], gpt_param_specs(cfg, tp_axis="tensor"))
     eng = ServingEngine(sharded, cfg, num_slots=4, block_size=4, chunk=4,
                         mesh=mesh, axis="tensor", dp_axis="data")
-    assert eng.prefill_width == eng.slots_per_group == 2  # never wider
+    assert eng.prefill_width <= eng.slots_per_group == 2
     eng.prefill_width = W = 1
     rows = (0, 1, 0)
     rids = [eng.submit(Request(b["prompts"][r].tolist(), NEW)) for r in rows]
@@ -810,10 +810,15 @@ def _hybrid_toy(mod):
 
 
 #: every family the engine serves: dense GQA, routed experts, and the state
-#: model's three shapes (recurrent state + held experts, latent attention +
-#: held gated experts, convolved attention with a tail a slot)
+#: model's four shapes (recurrent state + held experts, latent attention +
+#: held gated experts, convolved attention with a tail a slot, recurrent
+#: state with a dense MLP and no expert anywhere)
 SERVED = {"gqa": None, "moe": None, "state": "test_hybrid",
-          "latent": "test_sarvam_mla", "cca": "test_zaya"}
+          "latent": "test_sarvam_mla", "cca": "test_zaya",
+          "ssm": "test_granite_hybrid"}
+#: the width each family's engine gives itself: 2 where the model has
+#: expert layers, 1 where it has none
+WIDTH = {"gqa": 1, "moe": 2, "state": 2, "latent": 2, "cca": 2, "ssm": 1}
 
 
 @pytest.fixture(scope="module")
@@ -834,7 +839,7 @@ def width_pairs():
                           attn_impl="gather")
             eng, ref = (ServingEngine(params, cfg, num_slots=SLOTS8, **kw)
                         for _ in "ab")
-            assert eng.prefill_width == PREFILL_WIDTH < SLOTS8
+            assert eng.prefill_width == WIDTH[name] < SLOTS8
             ref.prefill_width = SLOTS8
             cache[name] = (cfg, eng, ref)
         return cache[name]
@@ -882,11 +887,11 @@ def _serve_one_by_one(eng, cfg):
 @pytest.mark.parametrize("family", list(SERVED))
 @pytest.mark.parametrize("scenario", ["first_wave", "one_by_one"])
 def test_default_width_matches_full_width_call(width_pairs, family, scenario):
-    """At ``PREFILL_WIDTH`` every served family's greedy tokens equal, token
-    for token, those of an engine whose one prefill call carries every
-    slot: a first wave of ``num_slots`` prompts (``ceil(n / W)`` calls a
-    tick, then every slot decoding, the pool's audit clean) and prompts
-    admitted one at a time."""
+    """At the width the engine gave itself every served family's greedy
+    tokens equal, token for token, those of an engine whose one prefill
+    call carries every slot: a first wave of ``num_slots`` prompts
+    (``ceil(n / W)`` calls a tick, then every slot decoding, the pool's
+    audit clean) and prompts admitted one at a time."""
     cfg, eng, ref = width_pairs(family)
     serve = _serve_first_wave if scenario == "first_wave" else _serve_one_by_one
     got, wave = serve(eng, cfg)
@@ -904,6 +909,40 @@ def test_default_width_matches_full_width_call(width_pairs, family, scenario):
         assert [a["rows"] for a in wave] == [a["calls"] * W * C for a in wave]
         assert [a["calls"] for a in full] == [1, 1]
         assert s["prefill_calls"] == sum(a["calls"] for a in wave)
+
+
+@pytest.mark.parametrize("family", list(SERVED))
+def test_the_engine_takes_its_prefill_width_from_its_models_layers(
+        width_pairs, family):
+    """The width is the engine's own choice at construction, from one thing
+    it can see of the model it was handed: 2 with expert layers, 1 without
+    (a dense model, a state model under a dense MLP).  Never more than a dp
+    group's slots, and ONE prefill signature whatever arrives in a tick."""
+    cfg, eng, _ = width_pairs(family)
+    assert bool(cfg.moe_experts) == (WIDTH[family] == 2)
+    assert eng.prefill_width == WIDTH[family] <= eng.slots_per_group
+    eng.reset_metrics()
+    C = eng.chunk
+    rids = [eng.submit(Request(_prompt(cfg, 60 + i, n), NEW))
+            for i, n in enumerate((3, C + 2, 2 * C))]   # three in one tick
+    spans.clear()
+    eng.step()
+    (pre,) = [r[5] for r in spans.snapshot() if r[2] == "tdp:engine.prefill"]
+    assert pre["calls"] == -(-3 // WIDTH[family])
+    assert pre["rows"] == pre["calls"] * WIDTH[family] * C
+    eng.submit(Request(_prompt(cfg, 66, 4), NEW))   # one alone, steady
+    _drain(eng)
+    assert all(eng.finished[r]["new_tokens"] == NEW for r in rids)
+    s = eng.serving_summary()
+    assert s["prefill_signatures"] == s["decode_signatures"] == 1
+
+
+def test_the_prefill_width_never_exceeds_a_groups_slots():
+    """An engine of ONE slot carries one slot's rows whatever its model."""
+    cfg, params = CFGS["moe"], _init("moe")
+    assert cfg.moe_experts and PREFILL_WIDTH_EXPERTS > 1
+    eng = ServingEngine(params, cfg, num_slots=1, block_size=4, chunk=4)
+    assert eng.prefill_width == eng.slots_per_group == 1
 
 
 def test_a_ticks_prefill_calls_are_dispatched_back_to_back(width_pairs,

@@ -19,8 +19,9 @@ batching).  This engine is that scheduler, built TPU-first:
 - **Chunked prefill, and the tick's order.**  Prompts enter through the
   same paged forward in ``chunk``-token slices, one a tick for every
   prefilling slot, in compact ``[dp * prefill_width, chunk]`` calls
-  (``ceil(n / W)`` of the ONE signature back to back: ``_prefill_batches``,
-  ``PREFILL_WIDTH``), so an admission costs its own rows.  A tick dispatches
+  (``ceil(n / W)`` of the ONE signature back to back: ``_prefill_batches``;
+  W is 1 or 2 slots, ``PREFILL_WIDTH``), so an admission costs its own
+  rows.  A tick dispatches
   ALL of its calls before it fetches any: the prefill calls, the decode call
   behind them, then the fetches, so the device runs both while results
   travel and the host walks.  A prompt's last slice samples the first token
@@ -180,25 +181,29 @@ DRAIN_SCHEMA = "tdp-engine-drain/v1"
 #: engine with fewer slots a group carries them all): a tick with n slots
 #: prefilling makes ceil(n / W) calls of this ONE signature, queued back to
 #: back.  A call costs a fixed part F (one pass over the weights, an expert
-#: layer's experts at their form's price, a launch and a fetch) and
-#: p a slot, and a steady tick of a full engine admits one or two prompts:
-#: every row beyond theirs is computed for nobody.  On a v5e one steady
-#: call at W = 8 / 4 / 2 / 1 reads 96 / 49 / 27 / 17 ms (a dense 7B at half
-#: depth, chunk 256: F ~2, p ~12), 66 / 50 / 43 / 20 (a state model with
-#: 128 held experts, chunk 128: F ~34) and 80 / 51 / 38 / 33, 42 / 34 /
-#: 28 / 20 (two expert models, chunk 256: F ~22).  What a narrower call
-#: costs is the first wave of a full engine, once: ceil(num_slots / W)
-#: calls a tick while every first prompt prefills.  4 is the narrowest of
-#: 1, 2, 4 at which that wave added under 5% to the set-up of every cell
-#: of the benchmark (+1.5 to +3.2%; 2 read +8.8% and +9.8% in the two
-#: cells whose F is most of the call).  8 was PR 25's, chosen while a call
-#: also copied the KV pool (~30 of its ~43 ms fixed; PR 27 took the copy
-#: away).  PERF.md section 6, PR 37, has every reading.  Since PR 40 the
-#: experts of such a call run batched wherever no held expert got more
-#: than 128 real rows (parallel/moe.py), which took ~21 and ~12 ms of F
-#: out of the first two expert models' calls (W = 4 reads 29 and 39 ms
-#: now); the width was not chosen again.
-PREFILL_WIDTH = 4
+#: layer's experts at their form's price, a launch and a fetch) and p a
+#: slot, and a steady tick of a full engine admits ONE prompt (74-96% of
+#: the ticks that prefill in the benchmark's eight serving cells, two in
+#: 4-18%): every row beyond its own is computed for nobody.  What a
+#: narrower call costs is F once more in the ticks with more prompts than
+#: W, and the first wave of a full engine, once: ceil(num_slots / W) calls
+#: a tick while every first prompt prefills, inside the set-up.  On a v5e
+#: one steady call beside a decode call reads, at W = 4 / 2 / 1, ms
+#: (PERF.md section 6, PR 50, has the grid): 45 / 23 / 13 (a dense 7B at
+#: half depth, chunk 256) and 53 / 27 / 14 (a state model under a dense
+#: MLP, chunk 256): F is small, every halving pays (the rate +9% and +5%
+#: at 1 over 2) and the set-up does not move.  With expert layers F is a
+#: pass over the held experts: 24 / 17 / 15, 34 / 22 / 18 and 23 / 17 / 14
+#: (chunk 128 and 256, 64-128 slots), where W = 1 gives the rate 0 to +2%
+#: over W = 2 and its wave of 64-128 calls adds 3-10% to the set-up; 42 /
+#: 26 / 16, 72 / 45 / 29 and 106 / 55 / 31 (chunk 512, 32 slots), where
+#: W = 1 would give 9-23% more for 2-4% of set-up: that takes a rule that
+#: reads the chunk and the slots too (ROADMAP queue 1 item 1(b)).  So the
+#: engine takes its width from the one thing that decides F and that it
+#: can see of its own model, whether it has expert layers: 1 without, 2
+#: with.
+PREFILL_WIDTH = 1
+PREFILL_WIDTH_EXPERTS = 2
 
 
 #: What a state model's calls report of their expert layers, in ``stats``
@@ -734,8 +739,11 @@ class ServingEngine:
             raise ValueError(
                 f"num_slots {num_slots} not divisible by dp {self.dp}")
         self.slots_per_group = num_slots // self.dp
-        #: slots of a dp group in one compiled prefill call
-        self.prefill_width = min(PREFILL_WIDTH, self.slots_per_group)
+        #: slots of a dp group in one compiled prefill call: by whether the
+        #: model has expert layers, never more than the group holds
+        self.prefill_width = min(
+            PREFILL_WIDTH_EXPERTS if cfg.moe_experts else PREFILL_WIDTH,
+            self.slots_per_group)
         if num_blocks is None:
             num_blocks = 1 + self.slots_per_group * self.max_blocks
             if self.cp > 1:  # pool shards evenly over the context axis
