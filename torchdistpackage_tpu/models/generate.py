@@ -49,6 +49,7 @@ from ..parallel.tensor_parallel.layers import (
     mlp_partial,
     rope_cache,
 )
+from ..utils import profiling as prof
 from .gpt import GPTConfig, gpt_head, vocab_parallel_embed
 
 PyTree = Any
@@ -96,6 +97,7 @@ def _kv_quant(x: jnp.ndarray):
     return q, scale
 
 
+@prof.scoped(prof.KV_WRITE)
 def _cache_write(c, val: jnp.ndarray, offset):
     """Append ``val`` [B, Hkv, S_in, hd] at ``offset`` — dense array or
     quantized (q8, scale) pair, one code path for both."""
@@ -109,6 +111,7 @@ def _cache_write(c, val: jnp.ndarray, offset):
     return jax.lax.dynamic_update_slice(c, val.astype(c.dtype), (0, 0, offset, 0))
 
 
+@prof.scoped(prof.ATTEND)
 def _cached_attention(q: jnp.ndarray, ck, cv, offset, window=None,
                       sm_scale: Optional[float] = None,
                       sink: Optional[jnp.ndarray] = None) -> jnp.ndarray:
@@ -208,35 +211,40 @@ def cached_block_forward(
     B, S_in, D = x.shape
     write, attend = cache_ops if cache_ops is not None else (
         _cache_write, _cached_attention)
-    h = layer_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = compute_qkv(p["attn"], h, cfg, rope=rope)
-    ck = write(ck, k, offset)
-    cv = write(cv, v, offset)
-    if cache_ops is None and isinstance(offset, int) and offset == 0 and S_in > 1:
-        # prefill: every cached key IS this call's k, so causal attention
-        # over (q, k, v) equals the cache-masked form — and runs the
-        # model's own kernel via the shared core_attention dispatch (flash
-        # on TPU) instead of materializing the [S_in, total] masked score
-        # matrix
-        from ..parallel.tensor_parallel.layers import core_attention
+    with jax.named_scope(prof.MIXER):
+        h = layer_norm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = compute_qkv(p["attn"], h, cfg, rope=rope)
+        ck = write(ck, k, offset)
+        cv = write(cv, v, offset)
+        if (cache_ops is None and isinstance(offset, int) and offset == 0
+                and S_in > 1):
+            # prefill: every cached key IS this call's k, so causal
+            # attention over (q, k, v) equals the cache-masked form — and
+            # runs the model's own kernel via the shared core_attention
+            # dispatch (flash on TPU) instead of materializing the [S_in,
+            # total] masked score matrix
+            from ..parallel.tensor_parallel.layers import core_attention
 
-        out = core_attention(q, k, v, cfg)
-    else:
-        out = attend(q, ck, cv, offset, window=cfg.sliding_window)
-    out = out.transpose(0, 2, 1, 3).reshape(B, S_in, q.shape[1] * cfg.head_dim)
-    y = dense(out, p["attn"]["wo"])
-    y = _close_row_parallel(y, p["attn"]["bo"], axis, False)
-    x = x + y
+            out = core_attention(q, k, v, cfg)
+        else:
+            out = attend(q, ck, cv, offset, window=cfg.sliding_window)
+        out = out.transpose(0, 2, 1, 3).reshape(
+            B, S_in, q.shape[1] * cfg.head_dim)
+        y = dense(out, p["attn"]["wo"])
+        y = _close_row_parallel(y, p["attn"]["bo"], axis, False)
+        x = x + y
 
-    h = layer_norm(x, p["ln2"], cfg.norm_eps)
-    if ffn is None:
-        z = mlp_partial(p["mlp"], h)
-        z = _close_row_parallel(z, p["mlp"]["b2"], axis, False)
-    else:
-        z = ffn(p, h)
-    return x + z, ck, cv
+    with jax.named_scope(prof.FFN):
+        h = layer_norm(x, p["ln2"], cfg.norm_eps)
+        if ffn is None:
+            z = mlp_partial(p["mlp"], h)
+            z = _close_row_parallel(z, p["mlp"]["b2"], axis, False)
+        else:
+            z = ffn(p, h)
+        return x + z, ck, cv
 
 
+@prof.scoped(prof.EMBED)
 def _embed_at(
     params: Dict[str, PyTree],
     tokens: jnp.ndarray,
@@ -370,6 +378,7 @@ def forward_cached_moe(
     return cache, logits[:, 0, :]
 
 
+@prof.scoped(prof.HEAD)
 def _full_logits(logits: jnp.ndarray, cfg: GPTConfig, axis: Optional[str]):
     """Vocab-local [..., V_local] -> full [..., V] (psum-assembled shard
     slabs; tiny at a handful of positions per sequence).  Identity when

@@ -48,6 +48,7 @@ from ..parallel.tensor_parallel import (
     norm_param_specs,
     split_to_sp,
 )
+from ..utils import profiling as prof
 
 PyTree = Any
 
@@ -239,6 +240,7 @@ def vocab_parallel_embed(
     return jax.lax.psum(emb, axis)
 
 
+@prof.scoped(prof.LOSS)
 def vocab_parallel_xent(
     logits: jnp.ndarray, targets: jnp.ndarray, axis: Optional[str] = None
 ) -> jnp.ndarray:
@@ -267,6 +269,7 @@ def vocab_parallel_xent(
 # -------------------------------------------------------------------- forward
 
 
+@prof.scoped(prof.EMBED)
 def gpt_embed(
     params: Dict[str, PyTree],
     tokens: jnp.ndarray,
@@ -295,6 +298,7 @@ def gpt_embed(
     return h + jax.lax.dynamic_slice_in_dim(params["pos_emb"], off, S, axis=0)
 
 
+@prof.scoped(prof.HEAD)
 def gpt_head(
     params: Dict[str, PyTree],
     h: jnp.ndarray,
@@ -365,6 +369,7 @@ def gpt_hidden(
     )
 
 
+@prof.scoped(prof.LOSS)
 def streamed_head_loss(
     params: Dict[str, PyTree],
     h: jnp.ndarray,

@@ -112,6 +112,7 @@ from ..parallel.tensor_parallel.layers import (
     rms_norm,
     rope_cache,
 )
+from ..utils import profiling as prof
 
 PyTree = Any
 F32 = jnp.float32
@@ -489,6 +490,7 @@ def _ssd_step(x, dt, A, Bm, Cm, S0):
     return y, S1
 
 
+@prof.scoped(prof.MIXER)
 def mamba2_mixer(
     p: Dict[str, jnp.ndarray], x: jnp.ndarray, cfg: HybridConfig,
     ssm: jnp.ndarray, conv: jnp.ndarray, n_valid: jnp.ndarray,
@@ -513,47 +515,49 @@ def mamba2_mixer(
     z, xbc, dt = (zxbcdt[..., :di], zxbcdt[..., di:di + cfg.conv_channels],
                   zxbcdt[..., di + cfg.conv_channels:])
 
-    # depthwise causal convolution over (x, B, C): position t sees the
-    # K-1 rows before it, the first of them from the carried tail
-    cat = jnp.concatenate([conv.astype(xbc.dtype), xbc], axis=1)
-    w = p["conv_w"].astype(F32)
-    acc = p["conv_b"].astype(F32) + sum(
-        cat[:, k:k + S].astype(F32) * w[k] for k in range(K))
-    xbc_c = jax.nn.silu(acc)                              # float32 [B, S, C]
-    # the tail after this call: the K-1 rows that end at the last REAL one
-    conv = jax.vmap(
-        lambda c, n: jax.lax.dynamic_slice_in_dim(c, n, K - 1, axis=0)
-    )(cat, n_valid).astype(conv.dtype)
+    with jax.named_scope(prof.SCAN):
+        # depthwise causal convolution over (x, B, C): position t sees the
+        # K-1 rows before it, the first of them from the carried tail
+        cat = jnp.concatenate([conv.astype(xbc.dtype), xbc], axis=1)
+        w = p["conv_w"].astype(F32)
+        acc = p["conv_b"].astype(F32) + sum(
+            cat[:, k:k + S].astype(F32) * w[k] for k in range(K))
+        xbc_c = jax.nn.silu(acc)                          # float32 [B, S, C]
+        # the tail after this call: the K-1 rows that end at the last REAL one
+        conv = jax.vmap(
+            lambda c, n: jax.lax.dynamic_slice_in_dim(c, n, K - 1, axis=0)
+        )(cat, n_valid).astype(conv.dtype)
 
-    xs = xbc_c[..., :di].reshape(B, S, G, R, P)
-    Bm = xbc_c[..., di:di + G * N].reshape(B, S, G, N)
-    Cm = xbc_c[..., di + G * N:].reshape(B, S, G, N)
-    dt = jax.nn.softplus(dt.astype(F32) + p["dt_bias"].astype(F32))
-    dt = jnp.where(valid[..., None], dt, 0.0).reshape(B, S, G, R)
-    A = -jnp.exp(p["A_log"].astype(F32)).reshape(G, R)
-    S0 = ssm.astype(F32).reshape(B, G, R, P, N)
+        xs = xbc_c[..., :di].reshape(B, S, G, R, P)
+        Bm = xbc_c[..., di:di + G * N].reshape(B, S, G, N)
+        Cm = xbc_c[..., di + G * N:].reshape(B, S, G, N)
+        dt = jax.nn.softplus(dt.astype(F32) + p["dt_bias"].astype(F32))
+        dt = jnp.where(valid[..., None], dt, 0.0).reshape(B, S, G, R)
+        A = -jnp.exp(p["A_log"].astype(F32)).reshape(G, R)
+        S0 = ssm.astype(F32).reshape(B, G, R, P, N)
 
-    if S == 1:
-        y, S1 = _ssd_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], S0)
-        y = y[:, None]
-    else:
-        Q = min(cfg.ssm_chunk, S)
-        if S % Q:
-            raise ValueError(
-                f"{S} positions do not divide into chunks of {Q}")
+        if S == 1:
+            y, S1 = _ssd_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], S0)
+            y = y[:, None]
+        else:
+            Q = min(cfg.ssm_chunk, S)
+            if S % Q:
+                raise ValueError(
+                    f"{S} positions do not divide into chunks of {Q}")
 
-        def chunks(a):   # [B, S, ...] -> [S/Q, B, Q, ...]
-            return jnp.moveaxis(a.reshape((B, S // Q, Q) + a.shape[2:]), 1, 0)
+            def chunks(a):   # [B, S, ...] -> [S/Q, B, Q, ...]
+                return jnp.moveaxis(
+                    a.reshape((B, S // Q, Q) + a.shape[2:]), 1, 0)
 
-        def body(Sc, c):
-            yc, Sc = _ssd_chunk(*c[:2], A, *c[2:], Sc)
-            return Sc, yc
+            def body(Sc, c):
+                yc, Sc = _ssd_chunk(*c[:2], A, *c[2:], Sc)
+                return Sc, yc
 
-        S1, ys = jax.lax.scan(
-            body, S0, (chunks(xs), chunks(dt), chunks(Bm), chunks(Cm)))
-        y = jnp.moveaxis(ys, 0, 1).reshape(B, S, G, R, P)
-    y = y + p["D"].astype(F32).reshape(G, R)[..., None] * xs
-    ssm = S1.reshape(B, H, P, N).astype(ssm.dtype)
+            S1, ys = jax.lax.scan(
+                body, S0, (chunks(xs), chunks(dt), chunks(Bm), chunks(Cm)))
+            y = jnp.moveaxis(ys, 0, 1).reshape(B, S, G, R, P)
+        y = y + p["D"].astype(F32).reshape(G, R)[..., None] * xs
+        ssm = S1.reshape(B, H, P, N).astype(ssm.dtype)
 
     # gate, then RMSNorm within each of the G groups of d_inner / G
     y = y.reshape(B, S, di) * jax.nn.silu(z.astype(F32))
@@ -568,6 +572,7 @@ def mamba2_mixer(
 # ---------------------------------------------------------------- attention
 
 
+@prof.scoped(prof.MIXER)
 def attention_mixer(p, x, cfg: HybridConfig, ck, cv, offset, cache_ops,
                     window: Optional[int] = None):
     """GQA on a block pool: x [B, S, D] (normed) -> (y, ck, cv).  ``window``
@@ -637,6 +642,7 @@ def attention_mixer(p, x, cfg: HybridConfig, ck, cv, offset, cache_ops,
     return dense(out, p["wo"]), ck, cv
 
 
+@prof.scoped(prof.MIXER)
 def cca_mixer(p, x, cfg: HybridConfig, ck, cv, tail, offset, n_valid,
               cache_ops):
     """Compressed convolutional attention (arXiv:2510.04476) on the block
@@ -718,10 +724,11 @@ def cca_mixer(p, x, cfg: HybridConfig, ck, cv, tail, offset, n_valid,
     def ends_at(c, n, rows):
         return jax.lax.dynamic_slice_in_dim(c, n, rows, axis=0).reshape(-1)
 
-    tail = jnp.concatenate(
-        [jax.vmap(lambda c, n: ends_at(c, n, T))(cat, n_valid),
-         jax.vmap(lambda c, n: ends_at(c, n, 1))(vcat, n_valid)],
-        axis=-1).astype(tail.dtype)
+    with jax.named_scope(prof.KV_WRITE):
+        tail = jnp.concatenate(
+            [jax.vmap(lambda c, n: ends_at(c, n, T))(cat, n_valid),
+             jax.vmap(lambda c, n: ends_at(c, n, 1))(vcat, n_valid)],
+            axis=-1).astype(tail.dtype)
 
     ck = write(ck, k, offset)
     cv = write(cv, v, offset)
@@ -748,6 +755,7 @@ def mrope_cache(positions: jnp.ndarray, head_dim: int, theta: float,
     return jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
 
 
+@prof.scoped(prof.MIXER)
 def indexed_attention_mixer(p, x, cfg: HybridConfig, cache, offset,
                             cache_ops, positions=None):
     """Indexed (sparse) attention on the block pool: x [B, S, D] (normed)
@@ -819,6 +827,7 @@ def indexed_attention_mixer(p, x, cfg: HybridConfig, cache, offset,
     return dense(out, p["wo"]), cache, kept
 
 
+@prof.scoped(prof.MIXER)
 def latent_attention_mixer(p, x, cfg: HybridConfig, pool, offset, cache_ops):
     """Latent attention in the absorbed form: x [B, S, D] (normed) -> (y,
     pool).  A position caches ONE row, ``[RMSNorm(c) | rope(k_rope)]``
@@ -897,9 +906,10 @@ def hybrid_paged_forward(
 
     S = tokens.shape[1]
     valid = jnp.arange(S)[None, :] < n_valid[:, None]
-    h = jnp.take(params["tok_emb"], tokens, axis=0)
-    if cfg.embed_scale != 1.0:
-        h = (h.astype(F32) * cfg.embed_scale).astype(h.dtype)
+    with jax.named_scope(prof.EMBED):
+        h = jnp.take(params["tok_emb"], tokens, axis=0)
+        if cfg.embed_scale != 1.0:
+            h = (h.astype(F32) * cfg.embed_scale).astype(h.dtype)
     cache, kv_layer = dict(cache), 0
     win = dict(cache["win"]) if "win" in cache else None
     w_layer = 0
@@ -907,59 +917,63 @@ def hybrid_paged_forward(
     mcfg = cfg.moe if cfg.moe_experts else None
     depth = None
     for kind, lp in zip(cfg.pattern, params["layers"]):
-        x = rms_norm(h, lp["norm"], cfg.norm_eps)
-        if kind == "M":
-            m = len(ssm)
-            y, s_m, c_m = mamba2_mixer(
-                lp, x, cfg, state["ssm"][m], state["conv"][m], n_valid)
-            ssm.append(s_m)
-            conv.append(c_m)
-        elif kind == "*":
-            y, cache["k"], cache["v"] = attention_mixer(
-                lp, x, cfg, cache["k"], cache["v"], offset,
-                cache_ops(kv_layer))
-            kv_layer += 1
-        elif kind == "W":
-            y, win["k"], win["v"] = attention_mixer(
-                lp, x, cfg, win["k"], win["v"], offset,
-                window_ops(w_layer), window=cfg.window)
-            w_layer += 1
-        elif kind == "C":
-            y, cache["k"], cache["v"], tail = cca_mixer(
-                lp, x, cfg, cache["k"], cache["v"],
-                state["tail"][len(tails)], offset, n_valid,
-                cache_ops(kv_layer))
-            tails.append(tail)
-            kv_layer += 1
-        elif kind == "S":
-            y, cache, rows_kept = indexed_attention_mixer(
-                lp, x, cfg, cache, offset, cache_ops(kv_layer),
-                positions=positions)
-            kept.append(rows_kept)
-            kv_layer += 1
-        elif kind == "L":
-            y, cache["kv"] = latent_attention_mixer(
-                lp, x, cfg, cache["kv"], offset, cache_ops(kv_layer))
-            kv_layer += 1
-        elif kind == "D":
-            y = dense(_unbiased_act(dense(x, lp["w1"]), "swiglu"), lp["w2"])
-        else:
-            y, met, *stream = moe_serve_forward(
-                lp, x, mcfg, return_metrics=True, valid=valid, depth=depth)
-            mets.append(met)
-            depth = stream[0] if stream else None
-        if "post_norm" in lp:
-            y = rms_norm(y, lp["post_norm"], cfg.norm_eps)
-        if "res" in lp:
-            a_h, b_h, a_y, b_y = (lp["res"][k].astype(F32) for k in (
-                "a_h", "b_h", "a_y", "b_y"))
-            h = (a_h * h.astype(F32) + b_h + a_y * y.astype(F32)
-                 + b_y).astype(h.dtype)
-        elif cfg.residual_scale != 1.0:
-            h = (h.astype(F32) + cfg.residual_scale * y.astype(F32)).astype(
-                h.dtype)
-        else:
-            h = h + y
+        # a block half, from its norm to the residual add behind it
+        with jax.named_scope(prof.FFN if kind in "DE" else prof.MIXER):
+            x = rms_norm(h, lp["norm"], cfg.norm_eps)
+            if kind == "M":
+                m = len(ssm)
+                y, s_m, c_m = mamba2_mixer(
+                    lp, x, cfg, state["ssm"][m], state["conv"][m], n_valid)
+                ssm.append(s_m)
+                conv.append(c_m)
+            elif kind == "*":
+                y, cache["k"], cache["v"] = attention_mixer(
+                    lp, x, cfg, cache["k"], cache["v"], offset,
+                    cache_ops(kv_layer))
+                kv_layer += 1
+            elif kind == "W":
+                y, win["k"], win["v"] = attention_mixer(
+                    lp, x, cfg, win["k"], win["v"], offset,
+                    window_ops(w_layer), window=cfg.window)
+                w_layer += 1
+            elif kind == "C":
+                y, cache["k"], cache["v"], tail = cca_mixer(
+                    lp, x, cfg, cache["k"], cache["v"],
+                    state["tail"][len(tails)], offset, n_valid,
+                    cache_ops(kv_layer))
+                tails.append(tail)
+                kv_layer += 1
+            elif kind == "S":
+                y, cache, rows_kept = indexed_attention_mixer(
+                    lp, x, cfg, cache, offset, cache_ops(kv_layer),
+                    positions=positions)
+                kept.append(rows_kept)
+                kv_layer += 1
+            elif kind == "L":
+                y, cache["kv"] = latent_attention_mixer(
+                    lp, x, cfg, cache["kv"], offset, cache_ops(kv_layer))
+                kv_layer += 1
+            elif kind == "D":
+                y = dense(_unbiased_act(dense(x, lp["w1"]), "swiglu"),
+                          lp["w2"])
+            else:
+                y, met, *stream = moe_serve_forward(
+                    lp, x, mcfg, return_metrics=True, valid=valid,
+                    depth=depth)
+                mets.append(met)
+                depth = stream[0] if stream else None
+            if "post_norm" in lp:
+                y = rms_norm(y, lp["post_norm"], cfg.norm_eps)
+            if "res" in lp:
+                a_h, b_h, a_y, b_y = (lp["res"][k].astype(F32) for k in (
+                    "a_h", "b_h", "a_y", "b_y"))
+                h = (a_h * h.astype(F32) + b_h + a_y * y.astype(F32)
+                     + b_y).astype(h.dtype)
+            elif cfg.residual_scale != 1.0:
+                h = (h.astype(F32)
+                     + cfg.residual_scale * y.astype(F32)).astype(h.dtype)
+            else:
+                h = h + y
     state = {"ssm": tuple(ssm), "conv": tuple(conv), "tail": tuple(tails)}
     if win is not None:
         cache["win"] = win
@@ -970,13 +984,15 @@ def hybrid_paged_forward(
         metrics["routing"] = routing
         if kept:
             metrics["selection"] = jnp.stack(kept, axis=2)
-    h = rms_norm(_select_row(h, last_idx), params["ln_f"], cfg.norm_eps)
-    if "head" in params:
-        logits = dense(h, params["head"])
-    else:   # tied: the table as it lies, contracted over its rows' width
-        logits = jnp.einsum("bsd,vd->bsv", h, params["tok_emb"])
-    if cfg.logits_scale != 1.0:
-        logits = (logits.astype(F32) * cfg.logits_scale).astype(logits.dtype)
+    with jax.named_scope(prof.HEAD):
+        h = rms_norm(_select_row(h, last_idx), params["ln_f"], cfg.norm_eps)
+        if "head" in params:
+            logits = dense(h, params["head"])
+        else:   # tied: the table as it lies, contracted over its rows' width
+            logits = jnp.einsum("bsd,vd->bsv", h, params["tok_emb"])
+        if cfg.logits_scale != 1.0:
+            logits = (logits.astype(F32) * cfg.logits_scale).astype(
+                logits.dtype)
     return cache, state, logits[:, 0, :], metrics
 
 

@@ -42,6 +42,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..dist.topology import DATA_AXIS, tpc
+from ..utils import profiling as prof
 
 AxisName = Union[str, Tuple[str, ...]]
 PyTree = Any
@@ -490,6 +491,7 @@ class DataParallel:
         data_axes = (axis,) if isinstance(axis, str) else tuple(axis)
 
         def make_reduce_fn(policy):
+            @prof.scoped(prof.GRAD_REDUCE)
             def reduce_fn(grads):
                 return reduce_gradients(
                     grads, axis, self.reduce_op, self.grad_reduce_overrides,
@@ -529,7 +531,9 @@ class DataParallel:
                 dax = _vaxes(loss, data_axes)
                 if dax:
                     loss = _reduce_loss(loss, dax, self.reduce_op)
-                updates, opt_state = optimizer.update(grads, opt_state, params)
+                with jax.named_scope(prof.OPTIMIZER):
+                    updates, opt_state = optimizer.update(
+                        grads, opt_state, params)
                 if numerics:
                     # monitoring rides in the SAME compiled program as
                     # training: norms over the reduced grads, the pre-update
@@ -539,7 +543,8 @@ class DataParallel:
 
                     nstats = numerics_stats(
                         grads, params=params, updates=updates)
-                params = jax.tree.map(jnp.add, params, updates)
+                with jax.named_scope(prof.OPTIMIZER):
+                    params = jax.tree.map(jnp.add, params, updates)
                 if numerics:
                     return params, opt_state, loss, nstats
                 return params, opt_state, loss
@@ -610,6 +615,9 @@ class DataParallel:
                     out_specs=out_specs,
                 )
                 cache[key] = jax.jit(sm, donate_argnums=(0, 1) if donate else ())
+                # the one call of a signature that makes its program ready
+                prof.note_program("train", cache[key],
+                                  (params, opt_state, batch))
             return cache[key]
 
         def jitted(params, opt_state, batch):

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..dist.topology import EXPERT_AXIS, MOE_DATA_AXIS
+from ..utils import profiling as prof
 from .tensor_parallel.layers import rms_norm
 
 PyTree = Any
@@ -175,6 +176,7 @@ def _use_sorted(dispatch: str, T: int, E: int, capacity: int) -> bool:
     return dispatch == "sorted"
 
 
+@prof.scoped(prof.ROUTE)
 def _top_k_route(
     probs: jnp.ndarray, k: int, capacity: int, priority: str = "choice"
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -283,6 +285,7 @@ def _load_balance_loss(probs: jnp.ndarray, dispatched: jnp.ndarray) -> jnp.ndarr
 # ------------------------------------------------------------------- experts
 
 
+@prof.scoped(prof.EXPERTS)
 def _expert_ffn(p: Dict[str, jnp.ndarray], x: jnp.ndarray) -> jnp.ndarray:
     """Per-expert MLP on stacked experts.  x: [E, G, D] -> [E, G, D].
     A 4-dim ``w1`` ([E, 2, D, F]) is the stacked gate/up SwiGLU expert
@@ -364,6 +367,7 @@ def check_expert_overflow(
     return False
 
 
+@prof.scoped(prof.FFN)
 def moe_forward(
     params: Dict[str, PyTree],
     x: jnp.ndarray,
@@ -531,6 +535,7 @@ def moe_forward(
     return out + (metrics,) if return_metrics else out
 
 
+@prof.scoped(prof.ROUTE)
 def _mlp_route(router: Dict[str, PyTree], tokens: jnp.ndarray,
                cfg: MoEConfig, depth: Optional[jnp.ndarray]):
     """The router that is a network (ZAYA1, arXiv:2511.17127): tokens [T, D]
@@ -560,6 +565,7 @@ def _mlp_route(router: Dict[str, PyTree], tokens: jnp.ndarray,
     return probs, jnp.take_along_axis(probs, gate_idx, axis=-1), gate_idx, u
 
 
+@prof.scoped(prof.ROUTE)
 def _serve_route(router: Dict[str, jnp.ndarray], tokens: jnp.ndarray,
                  cfg: MoEConfig):
     """tokens [T, D] -> (probs [T, E], gate_vals [T, k], gate_idx [T, k]).
@@ -610,6 +616,7 @@ def _unbiased_act(h: jnp.ndarray, act: str) -> jnp.ndarray:
 _BATCHED_EXPERTS_MAX_ROWS = 128
 
 
+@prof.scoped(prof.EXPERTS)
 def _batched_experts(ex, rows, sorted_expert, group_sizes, C: int, act: str,
                      fetch: bool = False):
     """``rows`` [R, d] sorted by expert (``sorted_expert`` [R]; values past
@@ -638,6 +645,7 @@ def _batched_experts(ex, rows, sorted_expert, group_sizes, C: int, act: str,
     return out.at[slot].get(mode="fill", fill_value=0)
 
 
+@prof.scoped(prof.EXPERTS)
 def _grouped_experts(ex, rows, group_sizes, act: str):
     """The same experts as ``ragged_dot`` groups: time follows the experts
     touched, whatever their rows; rows past the last group are undefined."""
@@ -645,6 +653,7 @@ def _grouped_experts(ex, rows, group_sizes, act: str):
     return jax.lax.ragged_dot(h, ex["w2"], group_sizes)
 
 
+@prof.scoped(prof.FFN)
 def moe_serve_forward(
     params: Dict[str, PyTree],
     x: jnp.ndarray,
@@ -729,10 +738,11 @@ def moe_serve_forward(
     # ``n_held`` = not held (sorts last, belongs to no group)
     local_idx = gate_idx
     if cfg.held is not None:
-        here = (gate_idx >= first) & (gate_idx < first + n_held)
-        if valid is not None:
-            here &= valid.reshape(T, 1)
-        local_idx = jnp.where(here, gate_idx - first, n_held)
+        with jax.named_scope(prof.DISPATCH):
+            here = (gate_idx >= first) & (gate_idx < first + n_held)
+            if valid is not None:
+                here &= valid.reshape(T, 1)
+            local_idx = jnp.where(here, gate_idx - first, n_held)
 
     def _with_metrics(y: jnp.ndarray):
         out = (y, _counters()) if return_metrics else (y,)
@@ -740,6 +750,7 @@ def moe_serve_forward(
             out += (depth,)
         return out if len(out) > 1 else y
 
+    @prof.scoped(prof.DISPATCH)
     def _counters():
         if cfg.held is None and valid is None:
             counts = jnp.bincount(gate_idx.reshape(-1), length=E)
@@ -765,29 +776,36 @@ def moe_serve_forward(
             metrics["layers_batched"] = batched
         return metrics
 
+    # the GEMMs of the layer (the latent's two projections, the experts,
+    # the shared expert) are ``experts``; what moves rows to them and back
+    # is ``dispatch`` and ``combine``
     src = tokens
     if cfg.latent_dim:
-        src = tokens @ params["latent"]["down"]
-    flat_expert = local_idx.reshape(-1)  # [T*k] token-major
-    order = jnp.argsort(flat_expert, stable=True)
-    sorted_tok = (order // k).astype(jnp.int32)  # token of each sorted row
-    sorted_expert = flat_expert[order]
-    rows = src[sorted_tok]  # [T*k, D] gather, expert-grouped
-    # with a held range there is one more bin, for the rows of no expert
-    # here; it is counted and cut off, so the groups end before those rows
-    group_sizes = jnp.bincount(
-        flat_expert, length=n_held + (cfg.held is not None)
-    )[:n_held].astype(jnp.int32)
+        with jax.named_scope(prof.EXPERTS):
+            src = tokens @ params["latent"]["down"]
+    with jax.named_scope(prof.DISPATCH):
+        flat_expert = local_idx.reshape(-1)  # [T*k] token-major
+        order = jnp.argsort(flat_expert, stable=True)
+        sorted_tok = (order // k).astype(jnp.int32)  # token of a sorted row
+        sorted_expert = flat_expert[order]
+        rows = src[sorted_tok]  # [T*k, D] gather, expert-grouped
+        # with a held range there is one more bin, for the rows of no expert
+        # here; it is counted and cut off, so the groups end before those
+        # rows
+        group_sizes = jnp.bincount(
+            flat_expert, length=n_held + (cfg.held is not None)
+        )[:n_held].astype(jnp.int32)
 
     ex = params["experts"]
     batched = None   # the unbiased path alone: 1.0 where it ran batched
     if ex["w1"].ndim == 4:  # swiglu: [E, 2, D, F] stacked gate/up
-        F = ex["w1"].shape[-1]
-        w1 = ex["w1"].transpose(0, 2, 1, 3).reshape(-1, D, 2 * F)
-        gu = jax.lax.ragged_dot(rows, w1, group_sizes)
-        gu = gu + ex["b1"].reshape(-1, 2 * F)[sorted_expert]
-        h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
-        out = jax.lax.ragged_dot(h, ex["w2"], group_sizes)
+        with jax.named_scope(prof.EXPERTS):
+            F = ex["w1"].shape[-1]
+            w1 = ex["w1"].transpose(0, 2, 1, 3).reshape(-1, D, 2 * F)
+            gu = jax.lax.ragged_dot(rows, w1, group_sizes)
+            gu = gu + ex["b1"].reshape(-1, 2 * F)[sorted_expert]
+            h = jax.nn.silu(gu[:, :F]) * gu[:, F:]
+            out = jax.lax.ragged_dot(h, ex["w2"], group_sizes)
     elif cfg.act in ("relu2", "swiglu"):  # no biases; swiglu: [E, D, 2F]
         C = _BATCHED_EXPERTS_MAX_ROWS
         if T <= C:  # a small call: no expert can get more than T rows
@@ -804,26 +822,30 @@ def moe_serve_forward(
                 lambda r: _grouped_experts(ex, r, group_sizes, cfg.act),
                 rows)
     else:
-        h = jax.lax.ragged_dot(rows, ex["w1"], group_sizes)
-        h = jax.nn.gelu(h + ex["b1"][sorted_expert])
-        out = jax.lax.ragged_dot(h, ex["w2"], group_sizes)
+        with jax.named_scope(prof.EXPERTS):
+            h = jax.lax.ragged_dot(rows, ex["w1"], group_sizes)
+            h = jax.nn.gelu(h + ex["b1"][sorted_expert])
+            out = jax.lax.ragged_dot(h, ex["w2"], group_sizes)
     if "b2" in ex:
-        out = out + ex["b2"][sorted_expert]
+        with jax.named_scope(prof.EXPERTS):
+            out = out + ex["b2"][sorted_expert]
 
-    g = gate_vals.reshape(-1)[order].astype(out.dtype)
-    if cfg.held is not None:
-        # rows behind the last group are no expert's: whatever the grouped
-        # matmul left there counts for nothing
-        kept = sorted_expert < n_held
-        out = jnp.where(kept[:, None], out, 0)
-        g = jnp.where(kept, g, 0)
-    y = jnp.zeros((T, out.shape[-1]), out.dtype).at[sorted_tok].add(
-        g[:, None] * out)
-    if cfg.latent_dim:
-        y = y @ params["latent"]["up"]
-    if cfg.shared_ffn:
-        sh = params["shared"]
-        y = y + _unbiased_act(tokens @ sh["w1"], cfg.act) @ sh["w2"]
+    with jax.named_scope(prof.COMBINE):
+        g = gate_vals.reshape(-1)[order].astype(out.dtype)
+        if cfg.held is not None:
+            # rows behind the last group are no expert's: whatever the
+            # grouped matmul left there counts for nothing
+            kept = sorted_expert < n_held
+            out = jnp.where(kept[:, None], out, 0)
+            g = jnp.where(kept, g, 0)
+        y = jnp.zeros((T, out.shape[-1]), out.dtype).at[sorted_tok].add(
+            g[:, None] * out)
+    with jax.named_scope(prof.EXPERTS):
+        if cfg.latent_dim:
+            y = y @ params["latent"]["up"]
+        if cfg.shared_ffn:
+            sh = params["shared"]
+            y = y + _unbiased_act(tokens @ sh["w1"], cfg.act) @ sh["w2"]
     return _with_metrics(y.reshape(B, S, D).astype(x.dtype))
 
 

@@ -34,6 +34,7 @@ from jax.lax import axis_size
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ...utils import profiling as prof
 from .tp_utils import (
     gather_from_sp,
     reduce_from_tp,
@@ -517,6 +518,7 @@ def attention_partial(
     return dense(out, p["wo"])  # [B,S,D] — partial sum across TP shards
 
 
+@prof.scoped(prof.ATTEND)
 def core_attention(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, cfg: TransformerConfig
 ) -> jnp.ndarray:
@@ -846,31 +848,37 @@ def block_forward(
     if dropout_key is not None and cfg.dropout_rate > 0.0:
         k_attn, k_mlp = jax.random.split(dropout_key)
     use_cm = _use_cm(cfg, x, axis, sp)
-    h = layer_norm(x, p["ln1"], cfg.norm_eps)
-    # quantized SP boundaries (cfg.ag_compress): the entering all-gather
-    # and the closing reduce-scatter carry int8 payloads; their custom
-    # VJPs quantize the backward's mirror collectives too
-    qc = _sp_compress(cfg, h, axis, sp)
-    if use_cm:
-        # ring path: gather⊕QKV-matmul and WO-matmul⊕scatter decomposed;
-        # the ring already reduced over TP, so only the bias remains
-        y = attention_partial_cm(p["attn"], h, cfg, axis, rope=rope)
-        y = y + p["attn"]["bo"]
-    else:
-        full = gather_from_sp(h, axis, compress=qc) if (axis and sp) else h
-        y = attention_partial(p["attn"], full, cfg, rope=rope)
-        y = _close_row_parallel(y, p["attn"]["bo"], axis, sp, compress=qc)
-    x = x + dropout(y, cfg.dropout_rate, k_attn)
+    with jax.named_scope(prof.MIXER):
+        h = layer_norm(x, p["ln1"], cfg.norm_eps)
+        # quantized SP boundaries (cfg.ag_compress): the entering all-gather
+        # and the closing reduce-scatter carry int8 payloads; their custom
+        # VJPs quantize the backward's mirror collectives too
+        qc = _sp_compress(cfg, h, axis, sp)
+        if use_cm:
+            # ring path: gather⊕QKV-matmul and WO-matmul⊕scatter decomposed;
+            # the ring already reduced over TP, so only the bias remains
+            y = attention_partial_cm(p["attn"], h, cfg, axis, rope=rope)
+            y = y + p["attn"]["bo"]
+        else:
+            full = (gather_from_sp(h, axis, compress=qc) if (axis and sp)
+                    else h)
+            y = attention_partial(p["attn"], full, cfg, rope=rope)
+            y = _close_row_parallel(y, p["attn"]["bo"], axis, sp,
+                                    compress=qc)
+        x = x + dropout(y, cfg.dropout_rate, k_attn)
 
-    h = layer_norm(x, p["ln2"], cfg.norm_eps)
-    qc = _sp_compress(cfg, h, axis, sp)
-    if use_cm:
-        z = mlp_partial_cm(p["mlp"], h, axis) + p["mlp"]["b2"]
-    else:
-        full = gather_from_sp(h, axis, compress=qc) if (axis and sp) else h
-        z = mlp_partial(p["mlp"], full)
-        z = _close_row_parallel(z, p["mlp"]["b2"], axis, sp, compress=qc)
-    return x + dropout(z, cfg.dropout_rate, k_mlp)
+    with jax.named_scope(prof.FFN):
+        h = layer_norm(x, p["ln2"], cfg.norm_eps)
+        qc = _sp_compress(cfg, h, axis, sp)
+        if use_cm:
+            z = mlp_partial_cm(p["mlp"], h, axis) + p["mlp"]["b2"]
+        else:
+            full = (gather_from_sp(h, axis, compress=qc) if (axis and sp)
+                    else h)
+            z = mlp_partial(p["mlp"], full)
+            z = _close_row_parallel(z, p["mlp"]["b2"], axis, sp,
+                                    compress=qc)
+        return x + dropout(z, cfg.dropout_rate, k_mlp)
 
 
 def transformer_forward(

@@ -154,6 +154,7 @@ from ..models.gpt import GPTConfig
 from ..obs.aggregate import percentiles
 from ..obs.events import EventLog, default_event_log
 from ..ops import paged_attention as paged_attention_ops
+from ..utils import profiling as prof
 from ..utils.profiling import scope_decorator, span
 from .paged_cache import (
     BlockAllocator,
@@ -298,12 +299,14 @@ def _pool_shape_attrs(cache: Dict[str, Any], quantized: bool) -> Dict[str, int]:
     return out
 
 
+@prof.scoped(prof.SAMPLE)
 def _split_keys(keys: jnp.ndarray):
     """[B, 2] uint32 -> (carried keys, this step's sample keys)."""
     ks = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
     return ks[:, 0], ks[:, 1]
 
 
+@prof.scoped(prof.SAMPLE)
 def _take_prev(tokens: jnp.ndarray, keys: jnp.ndarray, prev: Optional[Tuple]):
     """``run_ahead``'s decode call: ``prev = (tok, keys, take)`` is the call
     before's sampled tokens and advanced keys as they lie on the device, and
@@ -346,6 +349,7 @@ def _filtered_logits(
     return jnp.where(xs < cutoff, neg, xs)
 
 
+@prof.scoped(prof.SAMPLE)
 def _slot_sample(
     logits: jnp.ndarray,
     keys: jnp.ndarray,
@@ -887,11 +891,9 @@ class ServingEngine:
         self._decode_fn = (
             telemetry.wrap_step(self._step_fn) if wrap else self._step_fn)
         self._cow_fn = device_step.cow_fn() if self.prefix_cache else None
-        if self.spec_k:
-            vfn = device_step.verify_fn()
-            self._verify_fn = telemetry.wrap_step(vfn) if wrap else vfn
-        else:
-            self._verify_fn = None
+        self._verify_jit = device_step.verify_fn() if self.spec_k else None
+        self._verify_fn = (telemetry.wrap_step(self._verify_jit)
+                           if wrap and self.spec_k else self._verify_jit)
         self.reset_metrics()
 
     # ------------------------------------------------------------ compiled step
@@ -1089,6 +1091,17 @@ class ServingEngine:
             self.state, out = out[1], out[:1] + out[2:]
         self.cache = out[0]
         return out[1:]
+
+    def _note_program(self, attrs: Dict[str, Any], jitted: Callable,
+                      args: Tuple[Any, ...]) -> None:
+        """Before the ``first`` call of a signature (``attrs``: its
+        dispatch span's): the program it makes ready goes into
+        ``utils.profiling``'s table under the span's ``program``, as the
+        jitted step and the SHAPES of what the call hands it."""
+        if attrs.get("first"):
+            held = (self.params, self.cache) + (
+                () if self.state is None else (self.state,))
+            prof.note_program(attrs["program"], jitted, held + tuple(args))
 
     def _needs_snapshots(self, what: str) -> None:
         """A state model's requests cannot leave the engine mid-flight: the
@@ -1759,18 +1772,22 @@ class ServingEngine:
         return (tokens.shape, str(tokens.dtype), self.num_slots,
                 self.max_blocks)
 
-    def _first_call(self, kind: str, tokens: np.ndarray) -> Dict[str, bool]:
-        """Count the call's signature.  Returns the ``first=True`` attr of
-        the dispatch span and of the fetch after it on the one call of each
-        signature that compiles or loads its program, else nothing:
+    def _first_call(self, kind: str, tokens: np.ndarray) -> Tuple[
+            str, Dict[str, bool]]:
+        """Count the call's signature.  Returns the dispatch span's
+        ``program`` (``decode[64,1]``: the key of the call's compiled
+        program in ``utils.profiling.op_scopes``) and the ``first=True``
+        attr of the dispatch span and of the fetch after it on the one call
+        of each signature that compiles or loads its program, else nothing:
         ``_called_sigs`` outlives :meth:`reset_metrics`, which forgets the
         counted ones."""
         sig = (kind,) + self._sig(tokens)
         (self._prefill_sigs if kind == "prefill" else self._decode_sigs).add(sig)
+        program = f"{kind}[{','.join(map(str, tokens.shape))}]"
         if sig in self._called_sigs:
-            return {}
+            return program, {}
         self._called_sigs.add(sig)
-        return {"first": True}
+        return program, {"first": True}
 
     def _token_poisoned(self, tok: int) -> bool:
         """An out-of-range sampled token is the host-visible face of a
@@ -1853,7 +1870,7 @@ class ServingEngine:
         C = self.chunk
         with span("tdp:engine.build"):
             batches = self._prefill_batches(pre)
-            first = self._first_call("prefill", batches[0][1][0])
+            program, first = self._first_call("prefill", batches[0][1][0])
             self._call += len(batches)
             # what the fetch says of the calls it waits for: the decode
             # call's build moves the engine's count on before it opens
@@ -1873,7 +1890,7 @@ class ServingEngine:
                 calls=len(batches),
                 rows=sum(args[0].size for _, args in batches),
                 sampled_rows=np.count_nonzero(self._temps[pre] > 0),
-                rids=self._tick_prefill_rids, **waits_for)
+                rids=self._tick_prefill_rids, program=program, **waits_for)
             if self.state_model:
                 attrs["state_slots"] = len(pre)
             if self._idx_topk:
@@ -1887,6 +1904,7 @@ class ServingEngine:
         with span("tdp:engine.prefill", **attrs):
             # back to back: the pool is donated and chained call to call,
             # so a queued call holds its small inputs only
+            self._note_program(attrs, self._step_fn, batches[0][1])
             outs = [self._dispatch(self._step_fn, args)
                     for _, args in batches]
         self.stats["prefill_calls"] += len(batches)
@@ -2042,6 +2060,7 @@ class ServingEngine:
                     args, np.where(args[7] > 0, np.arange(self.num_slots),
                                    -1), attrs)
         with span("tdp:engine.decode", **attrs):
+            self._note_program(attrs, self._step_fn, args)
             out = self._dispatch(self._decode_fn, args)
         self.stats["decode_steps"] += 1
         self.stats["decode_slot_steps"] += len(slots)
@@ -2087,10 +2106,11 @@ class ServingEngine:
                      + (ahead.astype(np.int32),),)
             self.stats["ahead_rows"] += int(ahead.sum())
         self._call += 1
+        program, first = self._first_call("decode", tokens)
         attrs = dict(slots=n_active, rids=self._tick_decode_rids,
                      live_tokens=int(offsets.sum()) + n_active,
                      sampled_rows=np.count_nonzero(self._temps > 0),
-                     call=self._call, **self._first_call("decode", tokens))
+                     call=self._call, program=program, **first)
         if self._idx_topk:
             attrs.update(self._indexed_attrs(offsets, mask))
         return args, slots, attrs
@@ -2288,14 +2308,15 @@ class ServingEngine:
             rids = self._tick_decode_rids = [who[0] for _, who in slots]
             self._ev.emit("spec_draft", k=K, n_slots=len(rids), rids=rids)
             self._call += 1
-            waits_for = {"call": self._call,
-                         **self._first_call("decode", tokens)}
+            program, first = self._first_call("decode", tokens)
+            waits_for = {"call": self._call, **first}
             samp = self._samp()
         with span("tdp:engine.decode", slots=n_active, rids=rids,
-                  **waits_for):
-            self.cache, *out = self._verify_fn(
-                self.params, self.cache, tokens, tables, offsets, samp,
-                self._keys)
+                  program=program, **waits_for):
+            args = (tokens, tables, offsets, samp, self._keys)
+            self._note_program({"program": program, **first},
+                               self._verify_jit, args)
+            self.cache, *out = self._verify_fn(self.params, self.cache, *args)
         return {"out": out, "slots": slots, "tokens": tokens,
                 "waits_for": waits_for}
 

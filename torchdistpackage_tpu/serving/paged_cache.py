@@ -117,6 +117,7 @@ from ..models.generate import (
 )
 from ..models.gpt import GPTConfig, gpt_head
 from ..parallel.tensor_parallel.layers import rope_cache
+from ..utils import profiling as prof
 
 PyTree = Any
 
@@ -382,6 +383,7 @@ def _write_blocks(tables: jnp.ndarray, offset: jnp.ndarray, S_in: int,
     return jnp.where(live, blk, NULL_BLOCK), jnp.clip(src, 0, S_in - 1), valid
 
 
+@prof.scoped(prof.KV_WRITE)
 def paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray,
                 layer=None, transposed: bool = False):
     """Write ``val`` [B, Hkv, S_in, hd] into layer ``layer`` of the pool
@@ -439,6 +441,7 @@ def paged_write(c, val: jnp.ndarray, offset, *, tables: jnp.ndarray,
     return put(c, val)
 
 
+@prof.scoped(prof.KV_WRITE)
 def _write_transposed(pool, val, offset, tables, layer):
     """:func:`paged_write` for a transposed K leaf ``[L, nb, Hkv, hd, bs]``
     (:func:`latent_write` with a head axis): the blocks the rows fall in
@@ -481,6 +484,7 @@ def gather_kv(c, tables: jnp.ndarray, layer=None, transposed: bool = False):
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, nb * bs, hd)
 
 
+@prof.scoped(prof.ATTEND)
 def paged_attention(
     q: jnp.ndarray, ck, cv, offset, *, tables: jnp.ndarray, window=None,
     impl: str = "gather", layer=None, sm_scale: Optional[float] = None,
@@ -566,6 +570,7 @@ def _paged_cache_ops(tables: jnp.ndarray, attn_impl: str, layer,
     return write, attend
 
 
+@prof.scoped(prof.KV_WRITE)
 def latent_write(pool: jnp.ndarray, rows: jnp.ndarray, offset, *,
                  tables: jnp.ndarray, layer) -> jnp.ndarray:
     """:func:`paged_write` for a latent pool ``[L, nb, 1, W, bs]``: ``rows``
@@ -597,6 +602,7 @@ def _latent_cache_ops(tables: jnp.ndarray, attn_impl: str, cfg, layer):
     attend = (M.mla_paged_attention if attn_impl == "pallas"
               else M.mla_gather_attention)
 
+    @prof.scoped(prof.ATTEND)
     def attend_layer(q, pool, offset):
         return attend(q, pool, tables, offset, latent=cfg.mla_latent,
                       sm_scale=cfg.mla_scale, layer=layer)
@@ -615,6 +621,7 @@ def _indexed_cache_ops(tables: jnp.ndarray, attn_impl: str, cfg, layer):
     gives the output and the kept positions as bits."""
     from ..ops import dsa_attention as D
 
+    @prof.scoped(prof.ATTEND)
     def attend(q, ck, cv, cidx, qi, w, offset):
         return D.indexed_attention(
             q, ck, cv, cidx, qi, w, tables, offset, topk=cfg.idx_topk,
@@ -624,6 +631,7 @@ def _indexed_cache_ops(tables: jnp.ndarray, attn_impl: str, cfg, layer):
             attend)
 
 
+@prof.scoped(prof.MIXER)
 def _batched_rope(bcfg, positions: jnp.ndarray):
     """Per-slot rope tables: positions [B, S] -> (cos, sin) [B, 1, S,
     hd/2].  Reuses ``rope_cache`` on the flattened positions so each
@@ -639,6 +647,7 @@ def _batched_rope(bcfg, positions: jnp.ndarray):
     return (cos.reshape(B, S, half)[:, None], sin.reshape(B, S, half)[:, None])
 
 
+@prof.scoped(prof.HEAD)
 def _select_row(h: jnp.ndarray, last_idx) -> jnp.ndarray:
     """h [B, S, D] -> [B, 1, D] at per-slot row ``last_idx`` ([B] int32);
     None = the last row (the decode case, bitwise the contiguous slice)."""
@@ -718,10 +727,12 @@ def _cp_paged_cache_ops(tables: jnp.ndarray, cp_axis: str, attn_impl: str,
     because a ``chunk == cp`` sub-chunk is one row, like decode."""
     from ..ops.ring_paged import ring_paged_attend, ring_paged_write
 
+    @prof.scoped(prof.KV_WRITE)
     def write(c, val, offset):
         return ring_paged_write(c, val, offset, tables=tables, layer=layer,
                                 cp_axis=cp_axis, prefill=prefill)
 
+    @prof.scoped(prof.ATTEND)
     def attend(q, ck, cv, offset, window=None):
         return ring_paged_attend(q, ck, cv, offset, tables=tables,
                                  layer=layer, cp_axis=cp_axis, window=window,
@@ -966,13 +977,15 @@ def paged_forward_hybrid(
             fresh = (offset == 0).reshape((-1,) + (1,) * (a.ndim - 1))
             return jnp.where(fresh, jnp.zeros((), a.dtype), a)
 
-        mine = jax.tree.map(own, state)
+        with jax.named_scope(prof.STATE):
+            mine = jax.tree.map(own, state)
     cache, mine, logits, metrics = hybrid_paged_forward(
         params, tokens, cfg, cache, mine, n_valid, ops, offset,
         last_idx=last_idx, window_ops=window_ops)
     if rows is not None:
-        mine = jax.tree.map(
-            lambda a, new: a.at[rows].set(new, mode="drop"), state, mine)
+        with jax.named_scope(prof.STATE):
+            mine = jax.tree.map(
+                lambda a, new: a.at[rows].set(new, mode="drop"), state, mine)
     return cache, mine, logits, metrics
 
 

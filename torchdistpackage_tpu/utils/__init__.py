@@ -22,6 +22,8 @@ from .logging import (
     master_print,
 )
 from .profiling import (
+    note_program,
+    op_scopes,
     prof_start,
     prof_stop,
     scope_decorator,
@@ -52,6 +54,8 @@ __all__ = [
     "is_master",
     "master_only",
     "master_print",
+    "note_program",
+    "op_scopes",
     "prof_start",
     "prof_stop",
     "scope_decorator",

@@ -33,6 +33,18 @@ has ended: benchmarks/layer_metrics/idle_by_phase.py).
 Garbage collections are spans too: ``gc.callbacks`` closes a
 ``tdp:host.gc`` record (attr ``generation``) into the ring for each one,
 under whatever span was open, and nothing when none runs.
+
+Scopes inside the compiled programs.  The model's code opens
+``jax.named_scope`` with the names of ONE closed vocabulary,
+:data:`SCOPES` (``tdp:mixer``, ``tdp:ffn.experts``, ...: no layer index, no
+shape), so every device operation of a decode, prefill or train call
+carries the component that made it in its compiled ``op_name``; in
+TensorBoard / Perfetto / xprof the operation's name-stack field shows it.
+:func:`note_program` keeps, for each program the engine or the train step
+has made ready, its jitted callable and the SHAPES of its arguments, and
+:func:`op_scopes` gives the same offline: instruction name -> ``op_name``
+of that program as it was compiled, read on the first ask from the
+executable JAX already holds (no compile) and never on the hot path.
 """
 
 from __future__ import annotations
@@ -42,11 +54,13 @@ import collections
 import functools
 import gc
 import itertools
+import re
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import numpy as np
 
 
 def prof_start(logdir: str = "/tmp/jax-trace") -> None:
@@ -195,3 +209,169 @@ class span:
             parent.children.append(rec)
         if self.t1 >= spans._anchor_due:
             spans.anchor()
+
+
+# ------------------------------------------- scopes inside compiled programs
+
+#: The closed vocabulary of ``jax.named_scope`` names the package opens
+#: inside its compiled programs (docs/profiling.md says what each covers).
+#: A backward operation keeps its forward scope inside
+#: ``transpose(jvp(...))``, a recomputed one inside ``checkpoint`` /
+#: ``rematted_computation``: the names are not doubled for either.
+EMBED = "tdp:embed"
+MIXER = "tdp:mixer"
+KV_WRITE = "tdp:mixer.kv_write"
+ATTEND = "tdp:mixer.attend"
+SCAN = "tdp:mixer.scan"
+STATE = "tdp:state"
+FFN = "tdp:ffn"
+ROUTE = "tdp:ffn.route"
+DISPATCH = "tdp:ffn.dispatch"
+EXPERTS = "tdp:ffn.experts"
+COMBINE = "tdp:ffn.combine"
+HEAD = "tdp:head"
+SAMPLE = "tdp:sample"
+LOSS = "tdp:loss"
+OPTIMIZER = "tdp:optimizer"
+GRAD_REDUCE = "tdp:grad_reduce"
+SCOPES = (EMBED, MIXER, KV_WRITE, ATTEND, SCAN, STATE, FFN, ROUTE, DISPATCH,
+          EXPERTS, COMBINE, HEAD, SAMPLE, LOSS, OPTIMIZER, GRAD_REDUCE)
+
+
+def scoped(name: str) -> Callable[[Callable], Callable]:
+    """Decorator: the function's operations are traced under
+    ``jax.named_scope(name)``, a name of :data:`SCOPES`.  (A scope object
+    used as a decorator itself is ONE context manager for every call: a
+    function that calls itself leaves its name on the stack behind it.)"""
+    def deco(f: Callable) -> Callable:
+        @functools.wraps(f)
+        def wrapper(*args, **kwargs):
+            with jax.named_scope(name):
+                return f(*args, **kwargs)
+
+        return wrapper
+
+    return deco
+
+
+#: key -> (the jitted callable, its arguments with every array a shape)
+_programs: Dict[str, Tuple[Any, Tuple[Any, ...]]] = {}
+_op_scopes: Dict[str, Dict[str, str]] = {}
+
+# a compiled program's text, line by line: an instruction (its name, its
+# opcode, what follows), the header of a computation, and inside an
+# instruction where its operands end, the names it mentions, the
+# computation it applies and the name it was traced under
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([a-z][a-z0-9\-]*)\((.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_OPERANDS_END = re.compile(r"\), [a-z_]+=")
+_MENTIONS = re.compile(r"%([\w.\-]+)")
+_APPLIES = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_SCOPE = re.compile(r"tdp:[\w.]+")
+#: what stands between an instruction's own ``op_name`` and the one it is
+#: credited under, where the compiler gave it none of the program's
+OWNER = "=>"
+
+
+def note_program(key: str, jitted: Callable, args: Tuple[Any, ...]) -> None:
+    """Remember the program that ``jitted(*args)`` makes ready, under
+    ``key`` (``decode[64,1]``, ``prefill[2,512]``, ``train``; the newest of a
+    key stands).  Called at the ONE call of a signature that compiles or
+    loads it.  Of ``args`` only the form is kept: every array leaf as a
+    ``jax.ShapeDtypeStruct`` (a committed array's with its sharding, as the
+    call saw it), any other leaf as it is; no device buffer is held."""
+    def form(x: Any) -> Any:
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=x.sharding if x.committed else None)
+        if isinstance(x, np.ndarray):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    _programs[key] = (jitted, jax.tree.map(form, args))
+    _op_scopes.pop(key, None)
+
+
+def parse_op_scopes(text: str) -> Dict[str, str]:
+    """A compiled program's text -> instruction name (no ``%``) -> its
+    ``op_name`` ('' where the compiler gave none), for every instruction a
+    device trace can show: those of the entry, of while bodies and
+    conditions, of conditional branches and of called computations, not
+    those inside a fusion or a reducer.
+
+    What the compiler makes itself has no name of the program's: a weight's
+    prefetch (``copy-start`` / ``slice-done``), a copy into another layout,
+    a scan's slice of its stacked operand, a ``ragged-dot`` custom call.
+    Such an instruction (no ``tdp:`` token of its own) is credited to what
+    CONSUMES its result, the first of its users in the program's order that
+    has a scope (through further nameless ones), else to the scope under
+    which the ``while`` / ``conditional`` / ``call`` that runs its
+    computation was traced, and its entry reads ``<its own op_name>=><its
+    owner's>``; one with no such owner (a scan's stacking of its results)
+    keeps its own."""
+    inside = set()   # computations that a fusion, a reduce, a sort ... applies
+    found: Dict[str, List[Tuple[str, str, List[str], List[str]]]] = {}
+    rows = None
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            head = _COMPUTATION.match(line)
+            if head is not None:
+                rows = found.setdefault(head.group(1), [])
+            continue
+        name, opcode, rest = m.groups()
+        applies = _APPLIES.search(rest)
+        if applies is not None and opcode != "call":
+            inside.add(applies.group(1))
+        if rows is not None:
+            end = _OPERANDS_END.search(rest)
+            cut = end.start() if end else len(rest)
+            op = _OP_NAME.search(rest)
+            # the computations a while, a conditional or a call runs
+            runs = (_MENTIONS.findall(rest[cut:])
+                    if opcode in ("while", "conditional", "call") else [])
+            rows.append((name, op.group(1) if op else "",
+                         _MENTIONS.findall(rest[:cut]), runs))
+    out: Dict[str, str] = {}
+    caller: Dict[str, Optional[str]] = {}   # computation -> its owner
+    # callers stand behind what they call, users behind what they use:
+    # backwards, every owner is known before it is asked for
+    for comp in reversed(list(found)):
+        if comp in inside:
+            continue
+        owner: Dict[str, Optional[str]] = {}
+        users: Dict[str, List[str]] = {}
+        for name, _, operands, _ in found[comp]:
+            for operand in operands:
+                users.setdefault(operand, []).append(name)
+        for name, op_name, _, runs in reversed(found[comp]):
+            owner[name] = op_name if _SCOPE.search(op_name) else next(
+                (owner[u] for u in users.get(name, ()) if owner.get(u)),
+                None)
+            for callee in runs:   # by where the caller was TRACED alone
+                caller.setdefault(callee, op_name if _SCOPE.search(op_name)
+                                  else caller.get(comp))
+        for name, op_name, _, _ in found[comp]:
+            got = owner[name] or caller.get(comp)
+            out[name] = (op_name if not got or got == op_name
+                         else op_name + OWNER + got.rpartition(OWNER)[2])
+    return out
+
+
+def op_scopes(key: str) -> Dict[str, str]:
+    """Instruction name (``fusion.237``) -> ``op_name``
+    (``jit(step)/tdp:ffn/tdp:ffn.experts/dot_general``) of the program noted
+    under ``key``; empty where none was, or where it was no jitted callable
+    (a host stub).  The first ask lowers the callable for the noted shapes
+    and takes the executable that JAX's in-memory caches hold since the
+    call (no compile request); the answer is kept."""
+    got = _op_scopes.get(key)
+    if got is None:
+        jitted, args = _programs.get(key, (None, ()))
+        got = _op_scopes[key] = (
+            parse_op_scopes(jitted.lower(*args).compile().as_text())
+            if hasattr(jitted, "lower") else {})
+    return got
